@@ -9,6 +9,7 @@ the native path — it just gets faster with it.
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
@@ -57,10 +58,18 @@ def get_lib():
             _SO
         ) < os.path.getmtime(_SRC):
             if not _build():
+                logging.getLogger(__name__).warning(
+                    "could not build %s with g++; taking the pure-Python "
+                    "copy path", _SO,
+                )
                 return None
         try:
             lib = ctypes.CDLL(_SO)
-        except OSError:
+        except OSError as e:
+            logging.getLogger(__name__).warning(
+                "could not load %s (%s); taking the pure-Python copy path",
+                _SO, e,
+            )
             return None
         lib.rt_copy.argtypes = [
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_uint64

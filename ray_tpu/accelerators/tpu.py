@@ -34,6 +34,7 @@ TPU_NAME_ENV = "TPU_NAME"  # slice name, unique per slice
 TPU_WORKER_HOSTNAMES_ENV = "TPU_WORKER_HOSTNAMES"  # comma list, GKE
 
 TPU_VISIBLE_CHIPS_ENV = "TPU_VISIBLE_CHIPS"
+JAX_PLATFORMS_ENV = "JAX_PLATFORMS"
 NOSET_TPU_VISIBLE_CHIPS_ENV = "RAY_TPU_NOSET_TPU_VISIBLE_CHIPS"
 TPU_CHIPS_PER_HOST_BOUNDS_ENV = "TPU_CHIPS_PER_HOST_BOUNDS"
 TPU_HOST_BOUNDS_ENV = "TPU_HOST_BOUNDS"
@@ -238,16 +239,29 @@ class TPUAcceleratorManager(AcceleratorManager):
 
     @staticmethod
     def set_current_process_visible_accelerator_ids(ids: list) -> None:
-        """Scope this process (and its JAX runtime) to ``ids`` chips.
+        """Scope this process (and its JAX runtime) to ``ids`` chips. Call
+        it before jax is imported: jax reads JAX_PLATFORMS once.
 
         Sub-host visibility needs TPU_CHIPS_PER_HOST_BOUNDS +
         TPU_HOST_BOUNDS alongside TPU_VISIBLE_CHIPS so libtpu carves the
-        chip grid correctly (reference: tpu.py:388–428).
+        chip grid correctly (reference: tpu.py:388–428). Measured on a v5e
+        2x2 host (libtpu 0.0.34): four one-chip processes and two two-chip
+        processes (chips 0,1 and 2,3) initialise side by side with these
+        bounds; TPU_VISIBLE_CHIPS alone fails on libtpu's lockfile.
         """
         if os.environ.get(NOSET_TPU_VISIBLE_CHIPS_ENV):
             return
         ids = [str(i) for i in ids]
         os.environ[TPU_VISIBLE_CHIPS_ENV] = ",".join(ids)
+        if not ids:
+            # libtpu reads an empty list as "every chip", so a process that
+            # owns none is kept off the TPU platform altogether.
+            os.environ[JAX_PLATFORMS_ENV] = "cpu"
+            return
+        # A process that owns chips asks for them by name: a chip it cannot
+        # open is then an error here, never a silent CPU run. A platform
+        # pinned from outside (tests pin cpu) stands.
+        os.environ.setdefault(JAX_PLATFORMS_ENV, "tpu,cpu")
         n = len(ids)
         bounds = _CHIPS_PER_HOST_BOUNDS.get(n)
         if bounds is not None and n < _DEFAULT_CHIPS_PER_HOST:

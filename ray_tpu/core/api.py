@@ -19,6 +19,7 @@ from typing import Any, Optional, Sequence
 
 import cloudpickle
 
+from ray_tpu.accelerators import detect_node_accelerators
 from ray_tpu.core.config import GLOBAL_CONFIG
 from ray_tpu.core.core_worker import CoreWorker
 from ray_tpu.core.errors import RayTpuError
@@ -115,22 +116,15 @@ def _default_resources(num_cpus: float | None) -> dict:
             resources["memory"] = float(page * phys)
     except (ValueError, OSError, AttributeError):
         pass
-    try:
-        from ray_tpu.accelerators import tpu as tpu_accel
-
-        resources.update(tpu_accel.detect_resources())
-    except Exception:  # raylint: disable=RL006 -- TPU detection on non-TPU hosts; resources fall back to CPU-only
-        pass
+    # No try/except: with no chip on the host there is nothing to detect and
+    # nothing that raises; on a host that has chips, an identity the
+    # environment spells wrongly must stop the node, not start it chipless.
+    resources.update(detect_node_accelerators()[0])
     return resources
 
 
 def _default_labels() -> dict:
-    try:
-        from ray_tpu.accelerators import tpu as tpu_accel
-
-        return tpu_accel.detect_labels()
-    except Exception:  # raylint: disable=RL006 -- TPU label detection on non-TPU hosts; no labels to add
-        return {}
+    return detect_node_accelerators()[1]
 
 
 class _ClientRuntime:

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import asyncio
 import json
+import math
 import os
 import socket
 import subprocess
@@ -114,6 +115,15 @@ def _pg_of_demand(resources: dict) -> str | None:
     return None
 
 
+def _tpu_demand(resources: dict) -> int:
+    """Whole chips a demand asks for, as plain ``TPU`` or as a placement
+    group's formatted ``TPU_group_*`` (a demand carries one of the two)."""
+    for k, v in resources.items():
+        if k == "TPU" or k.startswith("TPU_group_"):
+            return math.ceil(v)
+    return 0
+
+
 @dataclass
 class WorkerInfo:
     worker_id: str
@@ -124,6 +134,11 @@ class WorkerInfo:
     ready: asyncio.Event = field(default_factory=asyncio.Event)
     idle_since: float = 0.0  # monotonic time it last entered the idle pool
     env_hash: str = ""  # runtime-env identity; pool reuse must match
+    # TPU chips the process was started on. libtpu opens them once and
+    # keeps them until the process exits, so this never changes: the worker
+    # serves leases of exactly these chips (or, when empty, leases with no
+    # TPU) and is retired when someone else needs one of them.
+    chips: tuple = ()
 
 
 @dataclass
@@ -132,6 +147,7 @@ class Lease:
     worker_id: str
     resources: dict
     pg_id: str | None = None
+    chips: tuple = ()  # TPU chip ids the lease owns on this node
     granted_at: float = field(default_factory=time.monotonic)
 
 
@@ -164,6 +180,11 @@ class NodeManager:
         self.workers: dict[str, WorkerInfo] = {}
         self.idle_workers: list[str] = []
         self.leases: dict[str, Lease] = {}
+        # Chips picked by a grant that is still waiting for its worker (the
+        # lease is not in self.leases yet), and the last process started on
+        # each chip (it may outlive its WorkerInfo while it dies).
+        self._chips_pending: set[int] = set()
+        self._chip_procs: dict[int, subprocess.Popen] = {}
         # placement-group bundles: (pg_id, index) -> original resources
         self.bundle_reservations: dict[tuple, dict] = {}
         self.committed_bundles: dict[tuple, dict] = {}
@@ -974,7 +995,9 @@ class NodeManager:
 
     # -- worker pool ---------------------------------------------------------
 
-    def _spawn_worker(self, runtime_env: dict | None = None) -> WorkerInfo:
+    def _spawn_worker(
+        self, runtime_env: dict | None = None, chips: tuple = ()
+    ) -> WorkerInfo:
         worker_id = WorkerID.random().hex()
         env = dict(os.environ)
         env.update(self.extra_env)
@@ -1002,6 +1025,13 @@ class NodeManager:
                 self.shm_root,
                 "--session-id",
                 self.session_id,
+                # On a node with chips every worker is told which are its
+                # own (possibly none) and scopes itself before jax loads.
+                *(
+                    ["--tpu-chips", ",".join(map(str, chips))]
+                    if self.total.get("TPU")
+                    else []
+                ),
             ],
             env=env,
             stdout=(out_f := self._worker_log_file(worker_id, "out")),
@@ -1033,8 +1063,11 @@ class NodeManager:
             worker_id=worker_id,
             proc=proc,
             env_hash=(runtime_env or {}).get("hash", ""),
+            chips=tuple(chips),
         )
         self.workers[worker_id] = info
+        for c in chips:
+            self._chip_procs[c] = proc
         return info
 
     def _worker_log_file(self, worker_id: str, stream: str):
@@ -1081,21 +1114,75 @@ class NodeManager:
             if not fut.done():
                 fut.set_result(None)
 
-    def _pop_idle_matching(self, env_hash: str) -> Optional[WorkerInfo]:
-        """Claim an idle worker whose runtime-env identity matches."""
+    def _pop_idle_matching(
+        self, env_hash: str, chips: tuple = ()
+    ) -> Optional[WorkerInfo]:
+        """Claim an idle worker whose runtime-env identity and chips match."""
         for i in range(len(self.idle_workers) - 1, -1, -1):
             wid = self.idle_workers[i]
             info = self.workers.get(wid)
             if info is None:
                 self.idle_workers.pop(i)
                 continue
-            if info.env_hash == env_hash:
+            if info.env_hash == env_hash and info.chips == chips:
                 self.idle_workers.pop(i)
                 return info
         return None
 
+    def _pick_chips(self, n: int, env_hash: str) -> tuple:
+        """``n`` chip ids that no lease holds. An idle worker already
+        sitting on a free set of that size is preferred (it is reused as it
+        is); otherwise an aligned run, because libtpu only carves such
+        sets out of a host's chip grid (on a v5e 2x2 host chips 0,1 and
+        2,3 initialise together, 0,2 and 1,3 do not)."""
+        busy = set(self._chips_pending)
+        for lease in self.leases.values():
+            busy.update(lease.chips)
+        for wid in self.idle_workers:
+            w = self.workers.get(wid)
+            if (
+                w is not None
+                and len(w.chips) == n
+                and w.env_hash == env_hash
+                and not busy.intersection(w.chips)
+            ):
+                return w.chips
+        total = int(self.total.get("TPU", 0))
+        for start in range(0, total - n + 1, n):
+            block = tuple(range(start, start + n))
+            if not busy.intersection(block):
+                return block
+        raise SchedulingError(
+            f"no aligned run of {n} free TPU chips on node "
+            f"{self.node_id[:8]} (chips in use: {sorted(busy)} of {total})"
+        )
+
+    async def _vacate_chips(self, chips: tuple) -> None:
+        """Make sure no process still has ``chips`` open before a new one
+        is started on them: idle workers bound to any of them are retired,
+        and a process that was already told to die is waited for."""
+        for wid in list(self.idle_workers):
+            w = self.workers.get(wid)
+            if w is not None and set(w.chips).intersection(chips):
+                self.idle_workers.remove(wid)
+                self.workers.pop(wid, None)
+                self._cgroup_retire(wid)
+        deadline = time.monotonic() + GLOBAL_CONFIG.worker_start_timeout_s
+        for proc in {self._chip_procs.get(c) for c in chips} - {None}:
+            # Chips no lease holds belong to an idle or dying process.
+            while proc.poll() is None:
+                if time.monotonic() > deadline:
+                    raise SchedulingError(
+                        f"pid {proc.pid} still holds TPU chips {chips}"
+                    )
+                proc.kill()
+                await asyncio.sleep(0.02)
+
     async def _get_idle_worker(
-        self, for_actor: bool = False, runtime_env: dict | None = None
+        self,
+        for_actor: bool = False,
+        runtime_env: dict | None = None,
+        chips: tuple = (),
     ) -> WorkerInfo:
         """Claim an idle worker, spawning one if the pool is below its cap.
         At the cap, wait for a lease to return a worker instead — an
@@ -1109,7 +1196,7 @@ class NodeManager:
         )
         env_hash = (runtime_env or {}).get("hash", "")
         while True:
-            match = self._pop_idle_matching(env_hash)
+            match = self._pop_idle_matching(env_hash, chips)
             if match is not None:
                 return match
             at_cap = self._task_worker_count() >= self._worker_cap()
@@ -1128,7 +1215,9 @@ class NodeManager:
                         self._terminated_procs.append(victim.proc)
                 at_cap = False
             if for_actor or not at_cap:
-                info = self._spawn_worker(runtime_env)
+                if chips:
+                    await self._vacate_chips(chips)
+                info = self._spawn_worker(runtime_env, chips)
                 try:
                     await asyncio.wait_for(
                         info.ready.wait(),
@@ -1681,11 +1770,19 @@ class NodeManager:
     ):
         if not pre_reserved:
             subtract(self.available, req.resources)
+        chips: tuple = ()
         try:
+            n_chips = _tpu_demand(req.resources)
+            if n_chips:
+                chips = self._pick_chips(
+                    n_chips, (req.runtime_env or {}).get("hash", "")
+                )
+                self._chips_pending.update(chips)
             info = await self._get_idle_worker(
-                for_actor=for_actor, runtime_env=req.runtime_env
+                for_actor=for_actor, runtime_env=req.runtime_env, chips=chips
             )
         except Exception:
+            self._chips_pending.difference_update(chips)
             add(self.available, req.resources)
             raise
         info.state = LEASED
@@ -1694,8 +1791,10 @@ class NodeManager:
             info.worker_id,
             req.resources,
             pg_id=_pg_of_demand(req.resources),
+            chips=chips,
         )
         self.leases[lease.lease_id] = lease
+        self._chips_pending.difference_update(chips)
         return {
             "lease_id": lease.lease_id,
             "worker_addr": info.addr,

@@ -251,9 +251,6 @@ def device_get(ref: DeviceRef, *, to_device: bool = True, sharding=None):
         )
     if not to_device:
         return host
-    from ray_tpu.experimental.transfer import _repin_platform
-
-    _repin_platform()
     import jax
 
     if sharding is not None:

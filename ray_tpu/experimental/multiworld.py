@@ -31,7 +31,7 @@ from __future__ import annotations
 
 from typing import Any, Optional, Sequence
 
-from ray_tpu.experimental.transfer import _repin_platform, fabric
+from ray_tpu.experimental.transfer import fabric
 
 
 def _normalize_box(index, shape) -> tuple:
@@ -57,7 +57,6 @@ def _overlap(a: tuple, b: tuple) -> Optional[tuple]:
 
 def export_shards(array) -> dict:
     """Catalog of THIS process's addressable shards — pure metadata."""
-    _repin_platform()
     import jax
 
     shards = []
@@ -81,7 +80,6 @@ def arm_shards(array, positions: Sequence[int], *, oid: str | None = None) -> di
     """Arm this process's addressable shards at ``positions`` for ONE
     pull each. Returns {"address", "armed": {pos: uuid}}. Entries ride
     the fabric's armed table (TTL/cap evicted like single-world arms)."""
-    _repin_platform()
     import time
     import uuid as _uuid
 
@@ -105,7 +103,6 @@ def plan_pulls(catalogs: Sequence[dict], target_sharding, global_shape) -> dict:
     """{producer process_index: [pos, ...]} — the producer shards THIS
     consumer process needs (overlap with any of its addressable target
     regions)."""
-    _repin_platform()
 
     idx_map = target_sharding.addressable_devices_indices_map(
         tuple(global_shape)
@@ -140,7 +137,6 @@ def pull_and_assemble(
     descriptor is ``arm_shards``'s return). Each needed shard is pulled
     ONCE per consumer process (first needing device), reused across local
     devices via on-device copies. Returns the global jax.Array."""
-    _repin_platform()
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -181,7 +177,9 @@ def pull_and_assemble(
                         sharding=SingleDeviceSharding(dev),
                     )
                     conn = fab._connect(desc["address"])
-                    [arr] = conn.pull(uid, [spec])
+                    [arr] = fab._landed(
+                        conn.pull(uid, [spec]), desc["address"]
+                    )
                     with fab._lock:
                         fab._stats["pulls"] += 1
                     pulled[key] = arr

@@ -44,178 +44,6 @@ from typing import Any, Optional, Sequence
 _AXIS_PREFIX = "_xfer"
 
 
-def _recv_exact(sock, n: int) -> bytes:
-    """Read exactly ``n`` bytes or raise (a short read means the peer died
-    or never armed the uid — callers surface that as a failed pull)."""
-    chunks = []
-    while n:
-        piece = sock.recv(min(n, 1 << 20))
-        if not piece:
-            raise ConnectionError("transfer peer closed mid-message")
-        chunks.append(piece)
-        n -= len(piece)
-    return b"".join(chunks)
-
-
-def _np_dtype(name: str):
-    """Resolve a dtype name numpy may not know natively (bfloat16 and
-    friends live in ml_dtypes, which jax always ships)."""
-    import numpy as np
-
-    try:
-        return np.dtype(name)
-    except TypeError:
-        import ml_dtypes
-
-        return np.dtype(getattr(ml_dtypes, name))
-
-
-class _SocketCompatConnection:
-    """Puller half of the jax<0.5 compat transport (see
-    :class:`_SocketTransferServer`). One TCP connection per pull."""
-
-    def __init__(self, address: str):
-        self._address = address
-
-    def pull(self, uid: int, specs: Sequence) -> list:
-        import json
-        import socket
-        import struct
-
-        import jax
-        import numpy as np
-
-        host, _, port = self._address.rpartition(":")
-        out = []
-        with socket.create_connection((host, int(port)), timeout=120.0) as s:
-            s.sendall(struct.pack(">Q", int(uid)))
-            status = _recv_exact(s, 1)
-            if status != b"\x01":
-                raise KeyError(
-                    f"transfer uid {uid} not armed at {self._address} "
-                    f"(already served, TTL-evicted, or never armed)"
-                )
-            (count,) = struct.unpack(">I", _recv_exact(s, 4))
-            if count != len(specs):
-                raise ValueError(
-                    f"armed entry has {count} buffers, pull expected "
-                    f"{len(specs)}"
-                )
-            for spec in specs:
-                (hlen,) = struct.unpack(">I", _recv_exact(s, 4))
-                meta = json.loads(_recv_exact(s, hlen))
-                (nbytes,) = struct.unpack(">Q", _recv_exact(s, 8))
-                raw = _recv_exact(s, nbytes)
-                arr = np.frombuffer(raw, dtype=_np_dtype(meta["dtype"]))
-                arr = arr.reshape(meta["shape"])
-                sharding = getattr(spec, "sharding", None)
-                out.append(
-                    jax.device_put(arr, sharding)
-                    if sharding is not None
-                    else jax.device_put(arr)
-                )
-        return out
-
-
-class _SocketTransferServer:
-    """Arm/pull transport for jax builds that predate
-    ``jax.experimental.transfer`` (< 0.5, e.g. the 0.4.37 on CPU dev
-    boxes): the same serve-once ``await_pull``/``connect().pull`` surface
-    over one plain TCP listener. Buffers cross as raw bytes (gathered
-    host-side), so this arm trades the XLA engine's true device path for
-    availability — on new-jax TPU pods the real engine is used and this
-    class never instantiates. ``transfer_stats()['transport']`` says which
-    one a process is running."""
-
-    def __init__(self, host: str):
-        import socket
-
-        self._lock = threading.Lock()
-        self._armed: dict[int, list] = {}
-        self._sock = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
-        self._sock.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
-        self._sock.bind((host, 0))
-        self._sock.listen(32)
-        self._host = host
-        self._port = self._sock.getsockname()[1]
-        threading.Thread(
-            target=self._serve, name="xfer-compat-server", daemon=True
-        ).start()
-
-    def address(self) -> str:
-        return f"{self._host}:{self._port}"
-
-    def await_pull(self, uid: int, arrays: Sequence) -> None:
-        with self._lock:
-            self._armed[int(uid)] = list(arrays)
-
-    def release(self, uid: int) -> None:
-        """Unschedule a never-pulled arm (the XLA engine cannot do this;
-        the compat server can and must — without it, released fabric
-        entries would leak their staged arrays in this dict forever)."""
-        with self._lock:
-            self._armed.pop(int(uid), None)
-
-    def connect(self, address: str) -> _SocketCompatConnection:
-        return _SocketCompatConnection(address)
-
-    def _serve(self) -> None:
-        while True:
-            try:
-                conn, _ = self._sock.accept()
-            except OSError:
-                return  # listener closed: process teardown
-            threading.Thread(
-                target=self._handle, args=(conn,), daemon=True
-            ).start()
-
-    def _handle(self, conn) -> None:
-        import json
-        import struct
-
-        import numpy as np
-
-        try:
-            with conn:
-                (uid,) = struct.unpack(">Q", _recv_exact(conn, 8))
-                with self._lock:
-                    arrays = self._armed.pop(uid, None)  # serve-once
-                if arrays is None:
-                    conn.sendall(b"\x00")
-                    return
-                conn.sendall(b"\x01" + struct.pack(">I", len(arrays)))
-                for a in arrays:
-                    npa = np.ascontiguousarray(np.asarray(a))
-                    meta = json.dumps(
-                        {"shape": list(npa.shape), "dtype": str(npa.dtype)}
-                    ).encode()
-                    conn.sendall(
-                        struct.pack(">I", len(meta))
-                        + meta
-                        + struct.pack(">Q", npa.nbytes)
-                    )
-                    # tobytes(), not a memoryview cast: custom dtypes
-                    # (bfloat16 via ml_dtypes) have no buffer format char.
-                    conn.sendall(npa.tobytes())
-        except Exception:  # raylint: disable=RL006 -- best-effort serve thread: a dying puller sees the short read and fails its own pull
-            pass
-
-
-def _repin_platform() -> None:
-    """Honor JAX_PLATFORMS where a TPU plugin overrides it at import time
-    (same guard as device_objects / the LLM engine / worker bootstrap)."""
-    import os
-
-    plat = os.environ.get("JAX_PLATFORMS")
-    if plat:
-        import jax
-
-        try:
-            jax.config.update("jax_platforms", plat)
-        except Exception:  # raylint: disable=RL006 -- jax platform re-pin is advisory; absent/old jax keeps its default
-            pass
-
-
 class _Fabric:
     """Per-process transfer server + connection cache (lazily started)."""
 
@@ -229,6 +57,13 @@ class _Fabric:
     # pull has certainly failed or timed out.
     ARMED_CAP = 16
     ARMED_TTL_S = 120.0
+    # The engine's pull returns at once with arrays that fill in later, and
+    # a producer that died never fails them: they simply never become
+    # ready, and whatever consumes one blocks for good. Every caller's
+    # fallback (drop the fragment, prefill locally, restore a checkpoint)
+    # hangs off an exception, so a pull that has not landed by this
+    # deadline raises.
+    PULL_TIMEOUT_S = 20.0
 
     def __init__(self):
         import collections
@@ -245,7 +80,6 @@ class _Fabric:
 
         self._armed_cap = int(GLOBAL_CONFIG.xfer_armed_cap)
         self._stats = {"arms": 0, "pulls": 0, "fallbacks": 0}
-        self._transport = "unstarted"
 
     # -- server ----------------------------------------------------------------
 
@@ -254,29 +88,19 @@ class _Fabric:
             return self._server
         with self._lock:
             if self._server is None:
-                _repin_platform()
+                import jax
+                from jax.experimental import transfer
+
                 from ray_tpu.util.net import local_ip
 
                 ip = local_ip()
-                try:
-                    import jax
-                    from jax.experimental import transfer
-
-                    client = jax.local_devices()[0].client
-                    # Explicit socket transport addresses: the default local
-                    # bulk transport only pairs processes created by one
-                    # runtime and aborts across unrelated ones.
-                    self._server = transfer.start_transfer_server(
-                        client, f"{ip}:0", [f"{ip}:0"]
-                    )
-                    self._transport = "xla"
-                except ImportError:
-                    # jax < 0.5: no XLA transfer engine. Same arm/pull
-                    # contract over the socket-compat server, so the fabric
-                    # (and everything built on it — RDT objects, multiworld
-                    # hand-offs, KV shipping) stays live on old-jax boxes.
-                    self._server = _SocketTransferServer(ip)
-                    self._transport = "socket-compat"
+                client = jax.local_devices()[0].client
+                # Explicit socket transport addresses: the default local
+                # bulk transport only pairs processes created by one
+                # runtime and aborts across unrelated ones.
+                self._server = transfer.start_transfer_server(
+                    client, f"{ip}:0", [f"{ip}:0"]
+                )
         return self._server
 
     def address(self) -> str:
@@ -296,7 +120,6 @@ class _Fabric:
     def arm(self, oid: str, array, partitions: Sequence[int]) -> dict:
         """Re-layout ``array`` to ``partitions`` on local devices and schedule
         it for one remote pull. Returns the pull descriptor."""
-        _repin_platform()
         import jax
 
         partitions = _normalize_partitions(array.shape, partitions)
@@ -322,7 +145,6 @@ class _Fabric:
         import time
 
         evicted = []
-        evicted_uids = []
         now = time.monotonic()
         with self._lock:
             self._armed[uid] = (oid, staged, now)
@@ -332,9 +154,7 @@ class _Fabric:
                     break  # young entries: pull may still be in flight
                 del self._armed[old_uid]
                 evicted.append(entry)
-                evicted_uids.append(old_uid)
             self._stats["arms"] += 1
-        self._server_release(evicted_uids)
         # A TTL-evicted entry's fetch budget was consumed at arm time and
         # its pull can no longer land; refund it so the object is not lost
         # (every other failure path refunds the same way). oid None =
@@ -350,11 +170,10 @@ class _Fabric:
     def arm_group(self, arrays: Sequence) -> dict:
         """Stage SEVERAL arrays under ONE uid for one remote pull — the
         trajectory-plane unit (a rollout fragment's columns travel
-        together: one arm RPC worth of descriptor, one pull, one TCP
-        connection on the socket-compat arm). Single-device layout on both
+        together: one arm RPC worth of descriptor, one pull). Single-device
+        layout on both
         ends; a consumer that wants a sharded landing re-lays-out after
         the pull, exactly like an over-decomposed :meth:`arm`."""
-        _repin_platform()
         import jax
         import jax.numpy as jnp
 
@@ -375,7 +194,6 @@ class _Fabric:
     def pull_group(self, desc: dict) -> list:
         """Pull an :meth:`arm_group` entry: every member array lands on
         local devices (single-device layout, matching the producer's)."""
-        _repin_platform()
         import jax
         import jax.numpy as jnp
 
@@ -388,21 +206,27 @@ class _Fabric:
             for s in desc["specs"]
         ]
         conn = self._connect(desc["address"])
-        out = conn.pull(desc["uuid"], specs)
+        out = self._landed(conn.pull(desc["uuid"], specs), desc["address"])
         with self._lock:
             self._stats["pulls"] += 1
         return out
 
-    def _server_release(self, uids: Sequence[int]) -> None:
-        """Unschedule never-pulled arms server-side where the transport
-        supports it (the socket-compat server holds its own uid->arrays
-        dict; without this, releasing only our bookkeeping would leak the
-        staged copies there). The XLA engine has no unschedule — its
-        entries die with the pull or the process."""
-        release = getattr(self._server, "release", None)
-        if release is not None:
-            for uid in uids:
-                release(uid)
+    def _landed(self, arrays: list, address: str) -> list:
+        """``arrays`` once the transfer engine has filled them all in;
+        TimeoutError if that takes longer than PULL_TIMEOUT_S."""
+        import time
+
+        deadline = time.monotonic() + self.PULL_TIMEOUT_S
+        pause = 0.0005
+        while not all(a.is_ready() for a in arrays):
+            if time.monotonic() > deadline:
+                raise TimeoutError(
+                    f"pull from {address} did not land within "
+                    f"{self.PULL_TIMEOUT_S:.0f}s (producer gone?)"
+                )
+            time.sleep(pause)
+            pause = min(pause * 2, 0.05)
+        return arrays
 
     def release_armed(self, oid: str) -> None:
         """Drop armed entries for an oid (object freed before any pull)."""
@@ -412,14 +236,12 @@ class _Fabric:
             ]
             for uid in uids:
                 del self._armed[uid]
-        self._server_release(uids)
 
     def release_uuid(self, uid: int):
         """Drop one armed entry (pull completed, or consumer unarms after a
         failed pull). Returns (oid, staged_array) or None."""
         with self._lock:
             entry = self._armed.pop(int(uid), None)
-        self._server_release([int(uid)])
         return entry
 
     # -- consumer side ---------------------------------------------------------
@@ -427,7 +249,6 @@ class _Fabric:
     def pull(self, desc: dict, target_sharding=None):
         """Pull an armed array from ``desc`` into local devices; optionally
         re-layout into ``target_sharding`` afterwards (on-device)."""
-        _repin_platform()
         import jax
         import jax.numpy as jnp
 
@@ -436,7 +257,9 @@ class _Fabric:
         sharding = _decomposed_sharding(desc["partitions"])
         spec = jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
         conn = self._connect(desc["address"])
-        [out] = conn.pull(desc["uuid"], [spec])
+        [out] = self._landed(
+            conn.pull(desc["uuid"], [spec]), desc["address"]
+        )
         with self._lock:
             self._stats["pulls"] += 1
         if target_sharding is not None and out.sharding != target_sharding:
@@ -449,10 +272,7 @@ class _Fabric:
 
     def stats(self) -> dict:
         with self._lock:
-            return dict(
-                self._stats, armed=len(self._armed),
-                transport=self._transport,
-            )
+            return dict(self._stats, armed=len(self._armed))
 
 
 _fabric: Optional[_Fabric] = None
@@ -472,7 +292,6 @@ def transfer_stats() -> dict:
     """Counters for tests/observability ({arms, pulls, fallbacks, armed})."""
     return fabric().stats() if _fabric is not None else {
         "arms": 0, "pulls": 0, "fallbacks": 0, "armed": 0,
-        "transport": "unstarted",
     }
 
 
@@ -527,8 +346,7 @@ def max_local_decomposition(shape) -> tuple[int, ...]:
     a reasonable default when the consumer has no target sharding: spreads
     the pull across devices (parallel transfer streams) without exceeding
     either side's device count."""
-    _repin_platform()  # often the first jax touch on this path: pin BEFORE
-    import jax  # the backend initializes, or the repin can never take
+    import jax
 
     n = len(jax.local_devices())
     if not shape:

@@ -22,6 +22,7 @@ from ray_tpu.llm.config import LLMConfig, SamplingParams
 from ray_tpu.llm.engine import LLMEngine
 from ray_tpu.serve import api as serve_api
 from ray_tpu.util import metrics as _metrics
+from ray_tpu.util.compile_cache import CacheCounter
 from ray_tpu.util.tasks import spawn
 
 # Replica-level serving view on top of the engine's own series (TTFT/ITL/
@@ -45,6 +46,7 @@ class LLMServer:
 
     def __init__(self, config: LLMConfig):
         self.config = config
+        self._cache_counter = CacheCounter()  # before the engine compiles
         self.engine = LLMEngine(config)
         self._counter = itertools.count()
         self._finished: dict[str, object] = {}  # request_id -> _Request
@@ -199,6 +201,26 @@ class LLMServer:
             self._delivered.pop(rid, None)
             self._finished.pop(rid, None)
             self._events.pop(rid, None)
+
+    def engine_report(self) -> dict:
+        """What this replica runs on and what its engine has done so far,
+        read inside the replica's own process (``chip_smoke.py`` takes its
+        evidence from here; reach it with
+        ``ray_tpu.ActorHandle(replica_id, "Replica").handle``)."""
+        import os
+
+        import jax
+
+        devices = jax.devices()
+        return {
+            "pid": os.getpid(),
+            "platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_ids": [d.id for d in devices],
+            "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
+            "stats": dict(self.engine.stats),
+            "compile_cache": self._cache_counter.snapshot(),
+        }
 
     def router_state(self) -> dict:
         """Routing advertisement, pushed by the hosting ReplicaActor's

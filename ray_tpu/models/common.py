@@ -126,10 +126,8 @@ def pipelined_blocks(blocks, x, block_fn, mesh, *, n_micro):
 
         # Carries become device-varying over pp after the first ppermute;
         # mark the (replicated-zero) initial values accordingly.
-        from ray_tpu.util.jax_compat import pcast_varying
-
         init = jax.tree.map(
-            lambda z: pcast_varying(z, ("pp",)),
+            lambda z: jax.lax.pcast(z, ("pp",), to="varying"),
             (
                 jnp.zeros_like(xs[0]),
                 jnp.zeros_like(xs),
@@ -149,10 +147,8 @@ def pipelined_blocks(blocks, x, block_fn, mesh, *, n_micro):
         aux = jax.lax.psum(aux, "pp") / n_micro
         return outs.reshape(B, *x_full.shape[1:]), aux
 
-    from ray_tpu.util.jax_compat import shard_map
-
     layer_specs = jax.tree.map(lambda _: P("pp"), blocks)
-    return shard_map(  # raylint: disable=RL102 -- constructed under the enclosing jit trace of the model fwd; rebuilt once per outer trace, not per step
+    return jax.shard_map(  # raylint: disable=RL102 -- constructed under the enclosing jit trace of the model fwd; rebuilt once per outer trace, not per step
         pipelined,
         mesh=mesh,
         in_specs=(layer_specs, P()),

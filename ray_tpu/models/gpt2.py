@@ -204,7 +204,7 @@ def _layer_norm(x, scale, bias, eps=1e-5):
     return (y * scale + bias).astype(x.dtype)
 
 
-def _attn_sublayer(x, p, cfg: GPT2Config, mesh=None):
+def _attn_sublayer(x, p, cfg: GPT2Config, mesh=None, ring=False):
     B, S, D = x.shape
     H, Dh = cfg.n_head, cfg.head_dim
     h = _layer_norm(x, p["ln1_scale"], p["ln1_bias"])
@@ -214,8 +214,7 @@ def _attn_sublayer(x, p, cfg: GPT2Config, mesh=None):
     def heads(t):  # [B,S,D] -> [B,H,S,Dh]
         return t.reshape(B, S, H, Dh).transpose(0, 2, 1, 3)
 
-    sp_size = mesh.shape.get("sp", 1) if mesh is not None else 1
-    if sp_size > 1 and S % sp_size == 0:
+    if ring:
         # Sequence sharded over sp: ring attention keeps K/V distributed
         # and rotates chunks over ICI instead of letting XLA re-gather the
         # full sequence per chip (SURVEY §5.7 — must-build).
@@ -230,6 +229,7 @@ def _attn_sublayer(x, p, cfg: GPT2Config, mesh=None):
             impl=cfg.attn_impl,
             block_q=cfg.attn_block_q,
             block_k=cfg.attn_block_k,
+            mesh=mesh,
         )
     attn = attn.transpose(0, 2, 1, 3).reshape(B, S, D)
     return x + attn @ p["proj_w"].astype(cfg.dtype) + p["proj_b"].astype(cfg.dtype)
@@ -295,10 +295,10 @@ def _moe_sublayer(x, p, cfg: GPT2Config):
     return x + y.reshape(B, S, D).astype(x.dtype), aux
 
 
-def _block(x, p, cfg: GPT2Config, mesh=None):
+def _block(x, p, cfg: GPT2Config, mesh=None, ring=False):
     """One transformer block -> (x, moe_aux). x: [B, S, D]; p: one layer's
     params; moe_aux is 0 for dense layers."""
-    h = _attn_sublayer(x, p, cfg, mesh=mesh)
+    h = _attn_sublayer(x, p, cfg, mesh=mesh, ring=ring)
     if cfg.n_experts > 0:
         return _moe_sublayer(h, p, cfg)
     return _mlp_sublayer(h, p, cfg), jnp.zeros((), jnp.float32)
@@ -333,9 +333,11 @@ def hidden(
     remat = {True: "full", False: "none"}.get(cfg.remat, cfg.remat)
     if remat == "mlp" and cfg.n_experts > 0:
         remat = "dots"  # the "mlp" policy checkpoints the DENSE sublayer
-    uses_ring = (
-        not pipelined and sp_size > 1 and S % sp_size == 0
-    )  # must mirror _attn_sublayer's dispatch
+    # Inside the pp pipeline's shard_map attention gets no mesh: neither the
+    # ring (a shard_map over sp) nor the per-shard kernel wrapper nests
+    # there, so it is a plain call that XLA reshards around.
+    attn_mesh = None if pipelined else mesh
+    uses_ring = attn_mesh is not None and sp_size > 1 and S % sp_size == 0
     if remat == "mlp" and (
         uses_ring
         or not uses_flash_kernel(
@@ -343,6 +345,7 @@ def hidden(
             impl=cfg.attn_impl,
             block_q=cfg.attn_block_q,
             block_k=cfg.attn_block_k,
+            mesh=attn_mesh,
         )
     ):
         # "mlp" exists to preserve the flash kernel's o/lse residuals. On
@@ -350,18 +353,15 @@ def hidden(
         # kernel, and leaving attention un-checkpointed would stack
         # O(L*B*H*S^2[/sp]) softmax residuals.
         remat = "dots"
-    # Ring attention (sp) nests a shard_map; inside the pp pipeline's
-    # shard_map that nesting is unsupported, so attention falls back to
-    # XLA's automatic resharding there.
-    attn_mesh = None if pipelined else mesh
+    attn = {"mesh": attn_mesh, "ring": uses_ring}
     dots_policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
     if remat == "full":
         block_fn = jax.checkpoint(
-            functools.partial(_block, cfg=cfg, mesh=attn_mesh)
+            functools.partial(_block, cfg=cfg, **attn)
         )
     elif remat == "dots":
         block_fn = jax.checkpoint(
-            functools.partial(_block, cfg=cfg, mesh=attn_mesh),
+            functools.partial(_block, cfg=cfg, **attn),
             policy=dots_policy,
         )
     elif remat == "mlp":
@@ -375,13 +375,13 @@ def hidden(
 
         def block_fn(x, layer_params):
             out = mlp_ckpt(
-                _attn_sublayer(x, layer_params, cfg, mesh=attn_mesh),
+                _attn_sublayer(x, layer_params, cfg, **attn),
                 layer_params,
             )
             return out, jnp.zeros((), jnp.float32)
 
     elif remat == "none":
-        block_fn = functools.partial(_block, cfg=cfg, mesh=attn_mesh)
+        block_fn = functools.partial(_block, cfg=cfg, **attn)
     else:
         raise ValueError(f"unknown remat policy {cfg.remat!r}")
 

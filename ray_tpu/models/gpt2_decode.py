@@ -67,11 +67,13 @@ def prefill(
     lengths: jax.Array,  # [B] true prompt lengths (<= T)
     cache,
     cfg: GPT2Config,
+    mesh=None,
 ):
     """Process prompts, fill cache[: , :, :T], return (cache, last_logits).
 
     last_logits[b] is the logits after token lengths[b]-1 — what the first
-    sampled token conditions on.
+    sampled token conditions on. ``mesh`` is the mesh the params are sharded
+    over (the engine's tp mesh), if any.
     """
     if cfg.n_experts > 0:
         raise NotImplementedError("decode path is dense-GPT2 only")
@@ -81,7 +83,7 @@ def prefill(
 
     def body(x, p):
         q, k, v = _qkv(x, p, cfg)
-        attn = causal_attention(q, k, v, impl=cfg.attn_impl)
+        attn = causal_attention(q, k, v, impl=cfg.attn_impl, mesh=mesh)
         return _finish_block(x, attn, p, cfg), (k, v)
 
     x, (ks, vs) = jax.lax.scan(body, x, params["blocks"])

@@ -164,7 +164,7 @@ def _apply_rope(t, cos, sin):
     return jnp.concatenate([t1 * c - t2 * s, t1 * s + t2 * c], axis=-1)
 
 
-def _attn_sublayer(x, p, cfg: LlamaConfig, cos, sin, mesh=None):
+def _attn_sublayer(x, p, cfg: LlamaConfig, cos, sin, mesh=None, ring=False):
     B, S, D = x.shape
     H, KH, Dh = cfg.n_head, cfg.n_kv_head, cfg.head_dim
     h = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
@@ -182,8 +182,7 @@ def _attn_sublayer(x, p, cfg: LlamaConfig, cos, sin, mesh=None):
     group = H // KH
     kk = jnp.repeat(kk, group, axis=1)
     v = jnp.repeat(v, group, axis=1)
-    sp_size = mesh.shape.get("sp", 1) if mesh is not None else 1
-    if sp_size > 1 and S % sp_size == 0:
+    if ring:
         # Sequence sharded over sp: ring attention keeps K/V distributed,
         # rotating chunks over ICI (same dispatch as gpt2._attn_sublayer).
         from ray_tpu.ops.ring_attention import ring_attention
@@ -195,6 +194,7 @@ def _attn_sublayer(x, p, cfg: LlamaConfig, cos, sin, mesh=None):
             impl=cfg.attn_impl,
             block_q=cfg.attn_block_q,
             block_k=cfg.attn_block_k,
+            mesh=mesh,
         )
     attn = attn.transpose(0, 2, 1, 3).reshape(B, S, D)
     return x + attn @ p["wo"].astype(cfg.dtype)
@@ -220,17 +220,17 @@ def hidden(
     sp_size = mesh.shape.get("sp", 1) if mesh is not None else 1
     x = params["wte"].astype(cfg.dtype)[tokens]
     cos, sin = rope_tables(cfg, S)
-    # Ring attention nests a shard_map; unsupported inside the pp
-    # pipeline's shard_map (same constraint as gpt2.hidden).
-    attn_mesh = None if pipelined else mesh
-
     remat = cfg.remat
-    uses_ring = not pipelined and sp_size > 1 and S % sp_size == 0
+    # No mesh for attention inside the pp pipeline (same as gpt2.hidden).
+    attn_mesh = None if pipelined else mesh
+    uses_ring = attn_mesh is not None and sp_size > 1 and S % sp_size == 0
+    attn = {"mesh": attn_mesh, "ring": uses_ring}
     if remat == "mlp" and (
         uses_ring
         or not uses_flash_kernel(
             S, impl=cfg.attn_impl,
             block_q=cfg.attn_block_q, block_k=cfg.attn_block_k,
+            mesh=attn_mesh,
         )
     ):
         remat = "dots"  # same rationale as gpt2.hidden
@@ -239,7 +239,7 @@ def hidden(
     def block(x, p):
         return (
             _mlp_sublayer(
-                _attn_sublayer(x, p, cfg, cos, sin, mesh=attn_mesh), p, cfg
+                _attn_sublayer(x, p, cfg, cos, sin, **attn), p, cfg
             ),
             jnp.zeros((), jnp.float32),
         )
@@ -256,7 +256,7 @@ def hidden(
         def block_fn(x, p):
             return (
                 mlp_ckpt(
-                    _attn_sublayer(x, p, cfg, cos, sin, mesh=attn_mesh), p
+                    _attn_sublayer(x, p, cfg, cos, sin, **attn), p
                 ),
                 jnp.zeros((), jnp.float32),
             )

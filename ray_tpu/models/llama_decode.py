@@ -71,8 +71,10 @@ def prefill(
     lengths: jax.Array,  # [B]
     cache,
     cfg: LlamaConfig,
+    mesh=None,
 ):
-    """Fill cache[:, :, :, :T]; return (cache, last_logits [B, vocab])."""
+    """Fill cache[:, :, :, :T]; return (cache, last_logits [B, vocab]).
+    ``mesh`` is the mesh the params are sharded over, if any."""
     B, T = tokens.shape
     group = cfg.n_head // cfg.n_kv_head
     x = params["wte"].astype(cfg.dtype)[tokens]
@@ -82,7 +84,7 @@ def prefill(
         q, k, v = _qkv_rope(x, p, cfg, cos, sin)
         attn = causal_attention(
             q, _expand_kv(k, group), _expand_kv(v, group),
-            impl=cfg.attn_impl,
+            impl=cfg.attn_impl, mesh=mesh,
         )
         attn = attn.transpose(0, 2, 1, 3).reshape(B, T, cfg.d_model)
         x = x + attn @ p["wo"].astype(cfg.dtype)

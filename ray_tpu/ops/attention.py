@@ -27,13 +27,14 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pltpu only imports on TPU-capable installs; fall back gracefully.
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 _NEG_INF = -1e30
+# Mesh axes (ray_tpu.parallel.mesh.AXIS_NAMES) the [B, H, S, D] operands are
+# sharded over: batch over the data axes, heads over tensor parallel.
+_BATCH_AXES = ("dp", "fsdp")
+_HEAD_AXIS = "tp"
 
 
 def _masked_scores(q, k, scale):
@@ -120,33 +121,56 @@ def _flash_fwd_kernel(
     lse_ref[0, 0] = m + jnp.log2(l)
 
 
+def _per_shard(fn, mesh):
+    """``fn`` over [B, H, S, ·] operands, run on each device's shard.
+
+    XLA cannot partition a Mosaic call ("Mosaic kernels cannot be
+    automatically partitioned"), so under a mesh the kernels run inside a
+    shard_map: batch over the data axes, heads over ``tp``. Mesh axes the
+    spec does not name see replicated operands and repeat the work, which
+    is what automatic partitioning does with them too."""
+    if mesh is None or mesh.size == 1:
+        return fn
+    batch = tuple(a for a in _BATCH_AXES if a in mesh.shape)
+    spec = P(batch or None, _HEAD_AXIS if _HEAD_AXIS in mesh.shape else None)
+    return jax.shard_map(  # raylint: disable=RL102 -- built under the caller's jit trace, once per trace
+        fn, mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False
+    )
+
+
 @functools.partial(
-    jax.jit, static_argnames=("scale", "block_q", "block_k", "interpret")
+    jax.jit,
+    static_argnames=("scale", "block_q", "block_k", "interpret", "mesh"),
 )
-def _flash_attention_fwd_impl(q, k, v, scale, block_q, block_k, interpret=False):
-    B, H, S, D = q.shape
-    grid = (B, H, S // block_q)
+def _flash_attention_fwd_impl(
+    q, k, v, scale, block_q, block_k, interpret=False, mesh=None
+):
     kernel = functools.partial(
         _flash_fwd_kernel, scale=scale, block_q=block_q, block_k=block_k
     )
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, S, D), lambda b, h, i: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, S, D), lambda b, h, i: (b, h, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i: (b, h, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct((B, H, S, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q, k, v)
+
+    def shard(q, k, v):
+        B, H, S, D = q.shape
+        return pl.pallas_call(
+            kernel,
+            grid=(B, H, S // block_q),
+            in_specs=[
+                pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0)),
+                pl.BlockSpec((1, 1, S, D), lambda b, h, i: (b, h, 0, 0)),
+                pl.BlockSpec((1, 1, S, D), lambda b, h, i: (b, h, 0, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, 1, block_q, D), lambda b, h, i: (b, h, i, 0)),
+                pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i: (b, h, i, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct(q.shape, q.dtype),
+                jax.ShapeDtypeStruct((B, H, S, 1), jnp.float32),
+            ],
+            interpret=interpret,
+        )(q, k, v)
+
+    return _per_shard(shard, mesh)(q, k, v)
 
 
 def _flash_bwd_fused_kernel(
@@ -228,83 +252,93 @@ def _flash_bwd_fused_kernel(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("scale", "block_q", "block_k", "interpret")
+    jax.jit,
+    static_argnames=("scale", "block_q", "block_k", "interpret", "mesh"),
 )
 def _flash_attention_bwd_impl(
-    q, k, v, o, lse, g, scale, block_q, block_k, interpret=False
+    q, k, v, o, lse, g, scale, block_q, block_k, interpret=False, mesh=None
 ):
-    B, H, S, D = q.shape
-    # delta_i = rowsum(dO_i * O_i): cheap elementwise+reduce, XLA fuses it.
-    delta = jnp.sum(
-        g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1, keepdims=True
-    )  # [B, H, S, 1]
+    def shard(q, k, v, o, lse, g):
+        B, H, S, D = q.shape
+        # delta_i = rowsum(dO_i * O_i): cheap elementwise+reduce, XLA fuses it.
+        delta = jnp.sum(
+            g.astype(jnp.float32) * o.astype(jnp.float32),
+            axis=-1,
+            keepdims=True,
+        )  # [B, H, S, 1]
 
-    full_spec = pl.BlockSpec((1, 1, S, D), lambda b, h, j: (b, h, 0, 0))
-    fullrow_spec = pl.BlockSpec((1, 1, S, 1), lambda b, h, j: (b, h, 0, 0))
-    kd_spec = pl.BlockSpec((1, 1, block_k, D), lambda b, h, j: (b, h, j, 0))
+        full_spec = pl.BlockSpec((1, 1, S, D), lambda b, h, j: (b, h, 0, 0))
+        fullrow_spec = pl.BlockSpec((1, 1, S, 1), lambda b, h, j: (b, h, 0, 0))
+        kd_spec = pl.BlockSpec((1, 1, block_k, D), lambda b, h, j: (b, h, j, 0))
+        dk, dv, dq = pl.pallas_call(
+            functools.partial(
+                _flash_bwd_fused_kernel,
+                scale=scale,
+                block_q=block_q,
+                block_k=block_k,
+                seq_len=S,
+            ),
+            grid=(B, H, S // block_k),
+            in_specs=[
+                full_spec, kd_spec, kd_spec, full_spec, fullrow_spec,
+                fullrow_spec,
+            ],
+            out_specs=[kd_spec, kd_spec, full_spec],
+            out_shape=[
+                jax.ShapeDtypeStruct(k.shape, k.dtype),
+                jax.ShapeDtypeStruct(v.shape, v.dtype),
+                jax.ShapeDtypeStruct(q.shape, q.dtype),
+            ],
+            scratch_shapes=[pltpu.VMEM((S, D), jnp.float32)],
+            interpret=interpret,
+        )(q, k, v, g, lse, delta)
+        return dq, dk, dv
 
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError(
-            "flash attention backward needs pallas TPU support (pltpu) for "
-            "its VMEM scratch; use impl='reference' on this install"
-        )
-    scratch = [pltpu.VMEM((S, D), jnp.float32)]
-    dk, dv, dq = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_fused_kernel,
-            scale=scale,
-            block_q=block_q,
-            block_k=block_k,
-            seq_len=S,
-        ),
-        grid=(B, H, S // block_k),
-        in_specs=[
-            full_spec, kd_spec, kd_spec, full_spec, fullrow_spec, fullrow_spec,
-        ],
-        out_specs=[kd_spec, kd_spec, full_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
-        ],
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(q, k, v, g, lse, delta)
-    return dq, dk, dv
+    return _per_shard(shard, mesh)(q, k, v, o, lse, g)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_attention(q, k, v, scale, block_q, block_k, interpret=False):
-    o, _ = _flash_attention_fwd_impl(q, k, v, scale, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_attention(q, k, v, scale, block_q, block_k, interpret=False, mesh=None):
+    o, _ = _flash_attention_fwd_impl(
+        q, k, v, scale, block_q, block_k, interpret, mesh
+    )
     return o
 
 
-def _flash_fwd(q, k, v, scale, block_q, block_k, interpret=False):
+def _flash_fwd(q, k, v, scale, block_q, block_k, interpret=False, mesh=None):
     o, lse = _flash_attention_fwd_impl(
-        q, k, v, scale, block_q, block_k, interpret
+        q, k, v, scale, block_q, block_k, interpret, mesh
     )
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(scale, block_q, block_k, interpret, res, g):
+def _flash_bwd(scale, block_q, block_k, interpret, mesh, res, g):
     q, k, v, o, lse = res
     return _flash_attention_bwd_impl(
-        q, k, v, o, lse, g, scale, block_q, block_k, interpret
+        q, k, v, o, lse, g, scale, block_q, block_k, interpret, mesh
     )
 
 
 _flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover  # raylint: disable=RL006 -- backend probe; an unqueryable backend is not a TPU
-        return False
+def _platform(mesh) -> str:
+    """The platform the attention is being built for: the mesh's devices
+    when a mesh is in scope (which also holds when compiling ahead of time
+    for a topology this process has no devices of), else the default
+    backend."""
+    if mesh is not None:
+        return mesh.devices.flat[0].platform
+    return jax.default_backend()
 
 
 def uses_flash_kernel(
-    seq: int, *, impl: str = "auto", block_q: int = 256, block_k: int = 256
+    seq: int,
+    *,
+    impl: str = "auto",
+    block_q: int = 256,
+    block_k: int = 256,
+    mesh=None,
 ) -> bool:
     """Whether causal_attention with these settings dispatches to the Pallas
     kernel (used by model code to pick a remat policy: the flash kernel saves
@@ -314,8 +348,7 @@ def uses_flash_kernel(
     if impl != "auto":
         return False
     return (
-        pltpu is not None
-        and _on_tpu()
+        _platform(mesh) == "tpu"
         and seq % min(block_q, seq) == 0
         and seq % min(block_k, seq) == 0
     )
@@ -331,11 +364,14 @@ def causal_attention(
     block_q: int = 256,
     block_k: int = 256,
     interpret: bool = False,
+    mesh=None,
 ) -> jax.Array:
     """Causal attention over [batch, heads, seq, head_dim] tensors.
 
     impl: "auto" (pallas on TPU, reference otherwise), "pallas", "reference".
     interpret: run the pallas kernel in interpreter mode (CPU testing).
+    mesh: the mesh the operands are sharded over, if any; the kernel then
+    runs per shard (batch over dp/fsdp, heads over tp).
     """
     if q.ndim != 4:
         raise ValueError(f"expected [B, H, S, D], got shape {q.shape}")
@@ -343,7 +379,8 @@ def causal_attention(
         scale = 1.0 / math.sqrt(q.shape[-1])
     if impl == "auto":
         use_pallas = uses_flash_kernel(
-            q.shape[2], impl="auto", block_q=block_q, block_k=block_k
+            q.shape[2], impl="auto", block_q=block_q, block_k=block_k,
+            mesh=mesh,
         )
         impl = "pallas" if use_pallas else "reference"
     if impl == "reference":
@@ -359,4 +396,4 @@ def causal_attention(
             f"S={S}, block_q={bq}, block_k={bk}. Use impl='auto' to allow "
             f"fallback or pick dividing blocks."
         )
-    return _flash_attention(q, k, v, scale, bq, bk, interpret)
+    return _flash_attention(q, k, v, scale, bq, bk, interpret, mesh)

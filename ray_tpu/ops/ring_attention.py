@@ -77,10 +77,8 @@ def ring_attention(
         shape = q_l.shape[:3]
         # Fresh zero/neg-inf constants are device-invariant; the scan carry
         # becomes sp-varying after the first step — mark them up front.
-        from ray_tpu.util.jax_compat import pcast_varying
-
         acc0, m0, l0 = jax.tree.map(
-            lambda z: pcast_varying(z, (axis,)),
+            lambda z: jax.lax.pcast(z, (axis,), to="varying"),
             (
                 jnp.zeros(q_l.shape, jnp.float32),
                 jnp.full(shape, _NEG_INF, jnp.float32),
@@ -93,10 +91,8 @@ def ring_attention(
         )
         return (acc / l[..., None]).astype(q_l.dtype)
 
-    from ray_tpu.util.jax_compat import shard_map
-
     seq_spec = P(None, None, axis, None)
-    return shard_map(  # raylint: disable=RL102 -- constructed under the enclosing jit trace of the attention caller; rebuilt once per outer trace, not per step
+    return jax.shard_map(  # raylint: disable=RL102 -- constructed under the enclosing jit trace of the attention caller; rebuilt once per outer trace, not per step
         local,
         mesh=mesh,
         in_specs=(seq_spec, seq_spec, seq_spec),

@@ -155,9 +155,7 @@ class AppoLearner(Learner):
         grads, stats = self._grad_appo(self.params, self.target_params, mb)
         if self._group_name is not None and self._world_size > 1:
             grads = self._allreduce_grads(grads)
-        self.params, self.opt_state = self._apply(
-            self.params, self.opt_state, grads
-        )
+        self._apply_grads(grads)
         self._steps_since_target += 1
         if self._steps_since_target >= self.appo.target_update_freq:
             self.target_params = jax.tree.map(jnp.copy, self.params)

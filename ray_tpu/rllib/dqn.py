@@ -294,9 +294,7 @@ class DQNLearner(Learner):
         grads, stats = self._grad(self.params, mb)
         if self._group_name is not None and self._world_size > 1:
             grads = self._allreduce_grads(grads)
-        self.params, self.opt_state = self._apply(
-            self.params, self.opt_state, grads
-        )
+        self._apply_grads(grads)
         out = dict(stats)
         self._maybe_refresh_target(1, out)
         return out

@@ -171,9 +171,7 @@ class ImpalaLearner(Learner):
         grads, stats = self._grad(self.params, mb)
         if self._group_name is not None and self._world_size > 1:
             grads = self._allreduce_grads(grads)
-        self.params, self.opt_state = self._apply(
-            self.params, self.opt_state, grads
-        )
+        self._apply_grads(grads)
         out = {k: float(v) for k, v in stats.items()}
         out["num_grad_steps"] = 1
         return out

@@ -17,6 +17,7 @@ TPU-first:
 from __future__ import annotations
 
 import dataclasses
+import threading
 from typing import Any, Callable
 
 import jax
@@ -59,6 +60,12 @@ class Learner:
         self._group_name = group_name
         self._world_size = world_size
         self._built = False
+        # The apply step donates params and opt_state. A host read of them
+        # from another thread (podracer's supervisor calls get_weights to
+        # seed a respawned runner while the learner thread is mid-update)
+        # that races the donating dispatch deadlocks jax's CPU runtime, so
+        # the two exclude each other.
+        self._state_lock = threading.Lock()
 
     # -- to be implemented by algorithms ------------------------------------
     def loss(self, params, minibatch: dict) -> tuple[jax.Array, dict]:
@@ -102,14 +109,16 @@ class Learner:
 
     # -- weights ------------------------------------------------------------
     def get_weights(self):
-        return to_numpy(self.params)
+        with self._state_lock:
+            return to_numpy(self.params)
 
     def flat_weights(self):
         """The live params raveled into one contiguous device vector — the
         unit the podracer weight publisher arms on the transfer fabric
         (one buffer per publish, no per-leaf descriptors; consumers
         unravel against their own params structure)."""
-        flat, _ = jax.flatten_util.ravel_pytree(self.params)
+        with self._state_lock:
+            flat, _ = jax.flatten_util.ravel_pytree(self.params)
         return flat
 
     def set_weights(self, params) -> bool:
@@ -119,10 +128,11 @@ class Learner:
         return True
 
     def get_state(self) -> dict:
-        return {
-            "params": to_numpy(self.params),
-            "opt_state": to_numpy(self.opt_state),
-        }
+        with self._state_lock:
+            return {
+                "params": to_numpy(self.params),
+                "opt_state": to_numpy(self.opt_state),
+            }
 
     def set_state(self, state: dict) -> bool:
         self.params = jax.device_put(
@@ -158,6 +168,13 @@ class Learner:
             )
         return unravel(reduced / self._world_size)
 
+    def _apply_grads(self, grads) -> None:
+        """One optimizer step in place (params and opt_state donated)."""
+        with self._state_lock:
+            self.params, self.opt_state = self._apply(
+                self.params, self.opt_state, grads
+            )
+
     def update(self, batch: SampleBatch) -> dict:
         """SGD epochs over shuffled equal-size minibatches. Returns the
         final-minibatch stats plus grad-step count."""
@@ -177,9 +194,7 @@ class Learner:
                 grads, stats = self._grad(self.params, mb_dev)
                 if self._group_name is not None and self._world_size > 1:
                     grads = self._allreduce_grads(grads)
-                self.params, self.opt_state = self._apply(
-                    self.params, self.opt_state, grads
-                )
+                self._apply_grads(grads)
                 steps += 1
         out = {k: float(v) for k, v in stats.items()}
         out["num_grad_steps"] = steps

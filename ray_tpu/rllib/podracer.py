@@ -16,8 +16,8 @@ into five concurrent planes on top of the existing core:
   under a batching-window/size knob (``raytpu_rl_inference_batch_size``
   is the coalescing histogram).
 - **Trajectory plane** — runners stage each fragment's columns on the
-  transfer fabric (:meth:`_Fabric.arm_group`: one uid, one pull, the
-  socket-compat arm included) and push the descriptor into a bounded
+  transfer fabric (:meth:`_Fabric.arm_group`: one uid, one pull) and
+  push the descriptor into a bounded
   queue; the learner pulls fragments device-to-device into a
   :class:`~ray_tpu.rllib.replay_buffer.DeviceReplay` ring and updates
   through :meth:`DQNLearner.update_device` — no host SampleBatch staging
@@ -326,8 +326,7 @@ class WeightPublisher:
     (``descriptor()`` arms the cached flat vector per consumer — N
     consumers cost N arms, not N full-model ravels); ``descriptor()``
     arms ONE serve-once flat-params entry (per consumer per version —
-    the socket-compat arm pops entries on pull, the XLA engine serves
-    once). Entries ``staleness_steps + 1`` publishes old are released:
+    the XLA engine serves an entry once). Entries ``staleness_steps + 1`` publishes old are released:
     the gate lets a consumer trail by ``staleness_steps`` versions, so
     applies for anything newer may still legitimately be in flight."""
 
@@ -844,6 +843,9 @@ class PodracerDQN(DQN):
             for th in samplers:
                 th.join(timeout=60)
             learner_t.join(timeout=60)
+        # The ring is the learner thread's (its scatter donates the
+        # buffers): only a learner that has exited hands it over.
+        ring_free = not learner_t.is_alive()
         elapsed = time.perf_counter() - t0
         # Drain what the learner left behind so nothing stays armed and
         # the NEXT run (or a train() call) starts from an empty queue —
@@ -856,7 +858,7 @@ class PodracerDQN(DQN):
                 break
             leftover += 1
             cols = load_fragment(entry)
-            if cols is not None and self._dreplay is not None:
+            if cols is not None and self._dreplay is not None and ring_free:
                 self._dreplay.add(cols, rows=entry["steps"])
         infer_stats = {}
         try:

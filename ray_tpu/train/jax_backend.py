@@ -23,7 +23,7 @@ from ray_tpu.train.backend import Backend, BackendConfig
 class JaxConfig(BackendConfig):
     """distributed: run jax.distributed.initialize across the group (turn off
     for single-worker debug runs). platform: pin a jax platform in workers
-    ("cpu" in tests — the TPU plugin otherwise grabs the chip)."""
+    ("cpu" in tests, which never take a chip)."""
 
     distributed: bool = True
     platform: Optional[str] = None
@@ -37,11 +37,9 @@ def _jax_shutdown_worker():
     """Tear down a live jax.distributed runtime inside a surviving worker
     so the elastic re-formation can re-initialize at the new world size
     (jax refuses a second initialize() while the old one is up)."""
-    from ray_tpu.util.tpu import jax_distributed_initialized
+    import jax
 
-    if jax_distributed_initialized():
-        import jax
-
+    if jax.distributed.is_initialized():
         jax.distributed.shutdown()
     return True
 
@@ -61,9 +59,7 @@ def _jax_init_worker(
 
     if platform:
         jax.config.update("jax_platforms", platform)
-    from ray_tpu.util.tpu import jax_distributed_initialized
-
-    if coordinator is not None and not jax_distributed_initialized():
+    if coordinator is not None and not jax.distributed.is_initialized():
         jax.distributed.initialize(
             coordinator_address=coordinator,
             num_processes=num_processes,

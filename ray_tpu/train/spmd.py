@@ -119,16 +119,11 @@ def compile_train_step(
     donation semantics the jit had; flops_per_step comes from the
     executable's own ``cost_analysis()`` — a device-verified number to
     cross-check tok/s against (None when the backend reports no cost
-    model, e.g. some plugin versions)."""
+    model)."""
     compiled = step.lower(state, batch).compile()
     flops: float | None = None
     try:
-        analysis = compiled.cost_analysis()
-        # jax returned a per-device list of dicts before 0.4.31, a single
-        # dict after; accept both.
-        if isinstance(analysis, (list, tuple)):
-            analysis = analysis[0] if analysis else {}
-        value = float((analysis or {}).get("flops", 0.0))
+        value = float((compiled.cost_analysis() or {}).get("flops", 0.0))
         flops = value if value > 0 else None
     except Exception:  # raylint: disable=RL006 -- cost model is advisory; backends without one must not fail setup
         flops = None

@@ -635,8 +635,6 @@ def build_xla_hier_allreduce(
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from ray_tpu.util.jax_compat import shard_map
-
     pad = k * shard_len - n
 
     def body(x):
@@ -676,7 +674,7 @@ def build_xla_hier_allreduce(
         return full[:n].reshape(shape)
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             body,
             mesh=hmesh,
             in_specs=P(("dcn", "ici")),

@@ -66,11 +66,9 @@ class XlaGroup(Communicator):
 
         if self._world_size == 1:
             return
-        from ray_tpu.util.tpu import jax_distributed_initialized
-
         # NB: don't probe jax.process_count() here — it would initialize the
         # XLA backend, after which jax.distributed.initialize() refuses to run.
-        if jax_distributed_initialized():
+        if jax.distributed.is_initialized():
             # Multi-controller runtime already up (e.g. the train tier ran
             # jax.distributed.initialize); reuse it.
             if jax.process_count() != self._world_size:
@@ -149,8 +147,6 @@ class XlaGroup(Communicator):
         local shard of the result (device-resident)."""
         import jax
         from jax.sharding import PartitionSpec as P
-
-        from ray_tpu.util.jax_compat import shard_map
 
         garr = self._global_array(tensor)
         cache_key = (kind, tuple(sorted(static.items())))
@@ -232,7 +228,7 @@ class XlaGroup(Communicator):
             else:
                 raise ValueError(kind)
             fn = jax.jit(
-                shard_map(
+                jax.shard_map(
                     body,
                     mesh=self._mesh,
                     in_specs=P("ranks"),

@@ -33,7 +33,6 @@ from ray_tpu.util.placement_group import (
 )
 
 __all__ = [
-    "jax_distributed_initialized",
     "get_tpu_version_from_type",
     "get_current_pod_name",
     "get_current_pod_worker_count",
@@ -44,27 +43,6 @@ __all__ = [
     "SlicePlacementGroup",
     "slice_placement_group",
 ]
-
-
-def jax_distributed_initialized() -> bool:
-    """Whether this process already joined a multi-controller JAX runtime.
-
-    ``jax.distributed.is_initialized()`` only exists on newer jax; on the
-    pinned toolchain (0.4.x without it) the authoritative signal is the
-    distributed global state's client handle. Never imports-fails: a jax
-    too old to have either simply reports False (initialize() then raises
-    its own clear error if someone double-initializes)."""
-    import jax
-
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is not None:
-        return bool(probe())
-    try:
-        from jax._src import distributed as _dist
-
-        return _dist.global_state.client is not None
-    except Exception:  # raylint: disable=RL006 -- jax.distributed state probe; unqueryable means uninitialized
-        return False
 
 
 def get_tpu_version_from_type(accelerator_type: str) -> str:

@@ -61,7 +61,6 @@ class ProducerRank:
 
         from ray_tpu.util import collective as col
 
-        jax.config.update("jax_platforms", "cpu")
         self._comm = col.init_collective_group(
             world, rank, backend="xla", group_name="mw_prod", timeout_s=90.0
         )
@@ -118,11 +117,8 @@ class ConsumerRank:
         return Mesh(_np.array(picked), (axis,))
 
     def __init__(self, world, rank):
-        import jax
-
         from ray_tpu.util import collective as col
 
-        jax.config.update("jax_platforms", "cpu")
         self._comm = col.init_collective_group(
             world, rank, backend="xla", group_name="mw_cons", timeout_s=90.0
         )

@@ -84,3 +84,28 @@ def test_explicit_pallas_rejects_indivisible_seq():
     q, k, v = _qkv(jax.random.key(3), S=100, D=16)
     with pytest.raises(ValueError, match="divisible"):
         causal_attention(q, k, v, impl="pallas", block_q=32, block_k=32)
+
+
+def test_flash_per_shard_under_a_mesh_matches_reference(devices8):
+    """Under a mesh the kernels run per shard (batch over dp/fsdp, heads
+    over tp); values and gradients are those of the unsharded reference."""
+    from ray_tpu.parallel import MeshSpec, make_mesh
+
+    mesh = make_mesh(MeshSpec(dp=2, fsdp=2, tp=2), devices8)
+    q, k, v = _qkv(jax.random.key(5), B=4, H=4, S=64, D=16)
+
+    def loss(impl, **kw):
+        def f(q, k, v):
+            return jnp.sum(causal_attention(q, k, v, impl=impl, **kw) ** 2)
+
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2)))
+
+    ref, g_ref = loss("reference")(q, k, v)
+    out, g = loss(
+        "pallas", block_q=32, block_k=32, interpret=True, mesh=mesh
+    )(q, k, v)
+    np.testing.assert_allclose(float(out), float(ref), rtol=1e-4)
+    for a, b in zip(g, g_ref):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-3, atol=1e-3
+        )

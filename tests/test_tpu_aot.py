@@ -1,0 +1,119 @@
+"""Compile the TPU programs for a v5e from the CPU.
+
+``jax.experimental.topologies`` describes a 2x2 v5e host without a chip, and
+XLA's TPU compiler (libtpu) runs anywhere, so the Mosaic kernels and the
+sharded train step are lowered and compiled here exactly as they would be on
+the host. The virtual CPU mesh cannot stand in for this: ``attn_impl="auto"``
+picks the jnp reference off-TPU, so it never meets the rule that XLA does not
+partition a Mosaic call.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental import topologies
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from ray_tpu.models import gpt2
+from ray_tpu.ops import attention
+from ray_tpu.parallel import (
+    DEFAULT_RULES,
+    MeshSpec,
+    make_mesh,
+    shardings_from_logical,
+)
+from ray_tpu.train.spmd import default_optimizer, make_train_step
+
+pytestmark = pytest.mark.timeout(600)
+
+MOSAIC = "tpu_custom_call"
+
+
+@pytest.fixture(scope="module")
+def v5e_2x2():
+    devices = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2"
+    ).devices
+    assert len(devices) == 4 and devices[0].device_kind == "TPU v5 lite"
+    return devices
+
+
+def test_flash_kernels_compile_on_one_chip(v5e_2x2):
+    one = NamedSharding(Mesh(np.array(v5e_2x2[:1]), ("x",)), P())
+    q = jax.ShapeDtypeStruct((8, 12, 1024, 64), jnp.bfloat16, sharding=one)
+
+    def fwd(q, k, v):
+        return attention.causal_attention(
+            q, k, v, impl="pallas", block_q=512, block_k=512
+        )
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    assert MOSAIC in jax.jit(fwd).lower(q, q, q).compile().as_text()
+    bwd = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q).compile()
+    assert bwd.as_text().count(MOSAIC) == 2  # forward + fused backward
+
+
+@pytest.mark.parametrize(
+    "spec", [MeshSpec(dp=4), MeshSpec(fsdp=2, tp=2)], ids=["dp4", "fsdp2-tp2"]
+)
+def test_gpt2_125m_train_step_compiles_on_four_chips(v5e_2x2, spec):
+    """The step chip_smoke.py runs: full-width GPT-2-125M, 8 sequences per
+    chip, S=1024, attn_impl="auto", loss_chunk=0, donated state."""
+    cfg = dataclasses.replace(gpt2.GPT2Config.gpt2_125m(), loss_chunk=0)
+    mesh = make_mesh(spec, v5e_2x2)
+    shardings = shardings_from_logical(
+        gpt2.param_logical_specs(cfg), DEFAULT_RULES, mesh
+    )
+    opt = default_optimizer(total_steps=100)
+    step = make_train_step(
+        lambda p, b: gpt2.loss_fn(p, b, cfg, mesh=mesh),
+        opt,
+        mesh=mesh,
+        batch_spec=P(("dp", "fsdp")),
+        param_shardings=shardings,
+    )
+
+    def init(key):
+        params = gpt2.init_params(key, cfg)
+        return {
+            "params": params,
+            "opt_state": opt.init(params),
+            "step": jnp.zeros((), jnp.int32),
+        }
+
+    def placed(tree, sharding_tree):
+        return jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+            tree,
+            sharding_tree,
+        )
+
+    shapes = jax.eval_shape(init, jax.random.key(0))
+    replicated = NamedSharding(mesh, P())
+    state = {
+        "params": placed(shapes["params"], shardings),
+        "opt_state": jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=replicated
+            ),
+            shapes["opt_state"],
+        ),
+        "step": jax.ShapeDtypeStruct((), jnp.int32, sharding=replicated),
+    }
+    tokens = jax.ShapeDtypeStruct(
+        (32, cfg.max_seq),
+        jnp.int32,
+        sharding=NamedSharding(mesh, P(("dp", "fsdp"))),
+    )
+    compiled = step.lower(state, {"tokens": tokens, "targets": tokens}).compile()
+    # The flash kernel ran, not the reference: forward and fused backward.
+    assert compiled.as_text().count(MOSAIC) == 2
+    memory = compiled.memory_analysis()
+    assert (
+        memory.temp_size_in_bytes + memory.argument_size_in_bytes < 16e9
+    ), "does not fit one v5e chip's 16 GB"

@@ -11,6 +11,7 @@ import ray_tpu
 from ray_tpu.accelerators import detect_node_accelerators
 from ray_tpu.accelerators.tpu import (
     TPU_SLICE_NAME_LABEL,
+    TPU_TOPOLOGY_LABEL,
     TPU_WORKER_ID_LABEL,
     TPUAcceleratorManager,
     chips_per_host,
@@ -129,6 +130,29 @@ def test_visible_chips_injection(monkeypatch):
     assert m.get_current_process_visible_accelerator_ids() == ["0", "1"]
 
 
+def test_visible_chips_pin_the_jax_platform(monkeypatch):
+    for var in ("TPU_CHIPS_PER_HOST_BOUNDS", "TPU_HOST_BOUNDS"):
+        monkeypatch.delenv(var, raising=False)
+    monkeypatch.setenv("TPU_VISIBLE_CHIPS", "stale")
+    m = TPUAcceleratorManager
+    # Owning chips asks for the TPU by name (a chip that cannot be opened is
+    # then an error, not a CPU run) ...
+    monkeypatch.delenv("JAX_PLATFORMS")
+    m.set_current_process_visible_accelerator_ids([2])
+    assert os.environ["TPU_VISIBLE_CHIPS"] == "2"
+    assert os.environ["JAX_PLATFORMS"] == "tpu,cpu"
+    # ... unless the platform was pinned from outside, as the tests do.
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    m.set_current_process_visible_accelerator_ids([2])
+    assert os.environ["JAX_PLATFORMS"] == "cpu"
+    # Owning none keeps the process off the TPU whatever was inherited.
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    m.set_current_process_visible_accelerator_ids([])
+    assert os.environ["TPU_VISIBLE_CHIPS"] == ""
+    assert os.environ["JAX_PLATFORMS"] == "cpu"
+    assert m.get_current_process_visible_accelerator_ids() == []
+
+
 def test_validate_request_quantity():
     ok, _ = TPUAcceleratorManager.validate_resource_request_quantity(4)
     assert ok
@@ -145,6 +169,91 @@ def test_detect_node_accelerators_off_tpu(monkeypatch):
     )
     resources, labels = detect_node_accelerators()
     assert resources == {} and labels == {}
+
+
+def test_init_defaults_advertise_detected_chips(monkeypatch):
+    from ray_tpu.core.api import _default_labels, _default_resources
+
+    monkeypatch.delenv("TPU_VISIBLE_CHIPS", raising=False)
+    for var in ("TPU_ACCELERATOR_TYPE", "TPU_NAME", "TPU_WORKER_ID"):
+        monkeypatch.delenv(var, raising=False)
+    monkeypatch.setenv("TPU_TOPOLOGY", "2x2")
+    monkeypatch.setattr(
+        TPUAcceleratorManager, "get_current_node_num_accelerators", lambda: 4
+    )
+    # A bare TPU VM: the chip count is all that is certain.
+    assert _default_resources(2.0)["TPU"] == 4.0
+    assert _default_labels()[TPU_TOPOLOGY_LABEL] == "2x2"
+
+    # On a host that has chips a detection error stops the node.
+    def broken():
+        raise RuntimeError("unreadable slice identity")
+
+    monkeypatch.setattr(
+        TPUAcceleratorManager, "get_current_node_accelerator_labels", broken
+    )
+    with pytest.raises(RuntimeError, match="slice identity"):
+        _default_resources(2.0)
+
+
+# -- TPU leases own their chips (fake 4-chip node) ----------------------------
+
+
+@pytest.fixture
+def four_chip_node():
+    runtime = ray_tpu.init(num_cpus=4, resources={"TPU": 4.0})
+    yield runtime
+    ray_tpu.shutdown()
+
+
+def _scope_fn():
+    # Nested so that it travels to the workers by value.
+    def scope():
+        return {
+            "pid": os.getpid(),
+            "chips": os.environ.get("TPU_VISIBLE_CHIPS"),
+            "bounds": os.environ.get("TPU_CHIPS_PER_HOST_BOUNDS"),
+            "platform": os.environ.get("JAX_PLATFORMS"),
+        }
+
+    return scope
+
+
+def test_tpu_actors_own_disjoint_chips(four_chip_node):
+    @ray_tpu.remote
+    class Scoped:
+        scope = staticmethod(_scope_fn())
+
+    a = Scoped.options(num_tpus=1).remote()
+    b = Scoped.options(num_tpus=1).remote()
+    plain = Scoped.remote()
+    sa, sb, sp = ray_tpu.get(
+        [a.scope.remote(), b.scope.remote(), plain.scope.remote()], timeout=60
+    )
+    assert {sa["chips"], sb["chips"]} == {"0", "1"}
+    assert sa["bounds"] == sb["bounds"] == "1,1,1"
+    # No TPU in the lease: no chip, and no way to open one.
+    assert sp["chips"] == "" and sp["platform"] == "cpu"
+    assert len({sa["pid"], sb["pid"], sp["pid"]}) == 3
+
+
+def test_tpu_worker_is_reused_only_for_its_own_chips(four_chip_node):
+    scope = ray_tpu.remote(_scope_fn())
+    first = ray_tpu.get(scope.options(num_tpus=1).remote(), timeout=60)
+    assert first["chips"] == "0"
+    # The returned worker still holds chip 0 in libtpu: a lease without a TPU
+    # does not land in it ...
+    plain = ray_tpu.get(scope.remote(), timeout=60)
+    assert plain["pid"] != first["pid"] and plain["chips"] == ""
+    # ... the same chip comes back to the same process ...
+    again = ray_tpu.get(scope.options(num_tpus=1).remote(), timeout=60)
+    assert (again["pid"], again["chips"]) == (first["pid"], "0")
+    # ... and a lease that needs chip 0 in another set retires it first.
+    pair = ray_tpu.get(scope.options(num_tpus=2).remote(), timeout=60)
+    assert pair["chips"] == "0,1" and pair["bounds"] == "1,2,1"
+    assert pair["pid"] != first["pid"]
+    with pytest.raises(OSError):
+        os.kill(first["pid"], 0)
 
 
 # -- slice reservation on a fake multi-slice cluster -------------------------
