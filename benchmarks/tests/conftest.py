@@ -1,0 +1,13 @@
+"""Tests of the benchmark's own arithmetic: CPU, seconds, and nothing here
+touches the chip (``python -m pytest benchmarks/tests -q``)."""
+
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ.setdefault(
+    "XLA_FLAGS", "--xla_force_host_platform_device_count=4"
+)
+sys.path.insert(
+    0, os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+)
