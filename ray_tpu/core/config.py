@@ -379,18 +379,20 @@ class Config:
     # collapses to one predicate check and the planes behave
     # byte-identically to the pre-recorder tree (no ring writes, no extra
     # RPC fields, no dump files — the A/B baseline of
-    # tools/ab_tracing.py / ray_perf --no-flightrec). On, each plane
+    # ray_perf --no-flightrec). On, each plane
     # (serve, llm, train, data, gcs, fleet_emu, faults) keeps a bounded
     # in-process ring of phase events (monotonic ts + wall anchor,
     # request/task/node ids, live tracing span ids) that
     # tools/trace_export.py turns into a Chrome-trace timeline and a
     # per-request critical-path breakdown. ``flightrec_ring_size`` is the
     # per-plane event capacity (older events are overwritten and counted
-    # in raytpu_obs_ring_drops_total). ``flightrec_dump_dir`` is where
+    # in raytpu_obs_ring_drops_total); 16,384 holds a minute and a half
+    # of a serving engine's llm plane, seven events a 42 ms decode step.
+    # ``flightrec_dump_dir`` is where
     # postmortem snapshots land on a chaos fault firing, an actor death,
     # or an OverloadedError shed (empty = /tmp/ray_tpu_flightrec).
     flightrec: bool = True
-    flightrec_ring_size: int = 4096
+    flightrec_ring_size: int = 16384
     flightrec_dump_dir: str = ""
     # Elastic pod-scale training (round 21). ``elastic_train`` is the
     # kill switch (RAY_TPU_ELASTIC_TRAIN=0): off, a membership change

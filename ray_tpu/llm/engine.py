@@ -20,7 +20,6 @@ Reference parity: the role vLLM's engine plays under ray.llm
 from __future__ import annotations
 
 import dataclasses
-import functools
 import pickle
 import time as _time
 from typing import Optional
@@ -162,6 +161,12 @@ class _Request:
     # timestamp (TTFT / inter-token latency).
     t_admit: float = 0.0
     t_last_token: float = 0.0
+    # Flight-recorder anchors (monotonic): when the request was handed
+    # over (llm.queue starts here), and the prefill whose logits have not
+    # reached the host yet, as (phase, start, extra): the span ends at
+    # the read-back of those logits, where the host waits anyway.
+    t_queued: float = 0.0
+    pf_open: Optional[tuple] = None
 
 
 class LLMEngine:
@@ -230,25 +235,48 @@ class LLMEngine:
             self.block_mgr = BlockManager(n)
             self.pool = paged.init_block_pool(cfg, n, bs)
             self.block_tables = np.zeros((B, self._table_width), np.int32)
-            self._pg_prefill = jax.jit(
-                functools.partial(paged.paged_prefill, cfg=cfg, block_size=bs)
-            )
-            self._pg_decode = jax.jit(
-                functools.partial(paged.paged_decode, cfg=cfg, block_size=bs)
-            )
+
+            # Functions with names of their own, not functools.partial: a
+            # device trace then lists the programs as jit_paged_prefill /
+            # jit_paged_decode and not as jit__unknown(<hash>).
+            def paged_prefill(params, tokens, length, start, table, pool):
+                return paged.paged_prefill(
+                    params, tokens, length, start, table, pool,
+                    cfg=cfg, block_size=bs,
+                )
+
+            def paged_decode(params, last_tokens, positions, tables, pool):
+                return paged.paged_decode(
+                    params, last_tokens, positions, tables, pool,
+                    cfg=cfg, block_size=bs,
+                )
+
+            self._pg_prefill = jax.jit(paged_prefill)
+            self._pg_decode = jax.jit(paged_decode)
         else:
             self.cache = self._decode_mod.init_kv_cache(cfg, B, S)
+
             # cfg binds as a jit-static closure constant; one compile per
-            # prefill bucket + one for decode.
-            self._prefill = jax.jit(
-                functools.partial(self._prefill_impl, cfg=cfg)
-            )
-            self._decode = jax.jit(
-                functools.partial(self._decode_mod.decode_step, cfg=cfg)
-            )
-            self._prefill_cont = jax.jit(
-                functools.partial(self._prefill_cont_impl, cfg=cfg)
-            )
+            # prefill bucket + one for decode. Named functions, so that a
+            # device trace lists jit_dense_prefill and so on.
+            def dense_prefill(params, tokens, length, cache, slot):
+                return self._prefill_impl(
+                    params, tokens, length, cache, slot, cfg
+                )
+
+            def dense_prefill_cont(params, tokens, length, start, cache, slot):
+                return self._prefill_cont_impl(
+                    params, tokens, length, start, cache, slot, cfg
+                )
+
+            def dense_decode(params, last_tokens, positions, cache):
+                return self._decode_mod.decode_step(
+                    params, last_tokens, positions, cache, cfg=cfg
+                )
+
+            self._prefill = jax.jit(dense_prefill)
+            self._decode = jax.jit(dense_decode)
+            self._prefill_cont = jax.jit(dense_prefill_cont)
             self._copy_prefix_in = jax.jit(self._copy_prefix_in_impl)
             self._copy_prefix_out = jax.jit(
                 self._copy_prefix_out_impl, static_argnames=("length",)
@@ -385,11 +413,15 @@ class LLMEngine:
         prompt: "str | list",
         sampling: SamplingParams | None = None,
         prefill_only: bool = False,
+        t_queued: float | None = None,
     ) -> None:
         """Admit a request. ``prefill_only`` (disaggregated serving's
         prefill leg; paged mode only) finishes the request at its first
         sampled token with the prompt KV exported as ``handoff_out``
-        instead of joining the decode batch."""
+        instead of joining the decode batch. ``t_queued`` is the
+        monotonic time the caller took the request in, where that was
+        earlier than this call (the flight recorder's ``llm.queue`` span
+        starts there)."""
         if prefill_only and not self.paged:
             raise ValueError(
                 "prefill_only requests need the paged KV cache "
@@ -417,6 +449,7 @@ class LLMEngine:
             stop_token=stop,
             prefill_only=prefill_only,
             t_admit=_time.perf_counter(),
+            t_queued=_time.monotonic() if t_queued is None else t_queued,
         )
         if _metrics.metrics_enabled():
             _REQUESTS.inc(1.0)
@@ -427,6 +460,7 @@ class LLMEngine:
         request_id: str,
         handoff: dict,
         sampling: SamplingParams | None = None,
+        t_queued: float | None = None,
     ) -> None:
         """Admit a disaggregated request from a prefill replica's handoff:
         the prompt KV arrives over the transfer fabric at admission and
@@ -450,6 +484,7 @@ class LLMEngine:
             stop_token=stop,
             handoff=dict(handoff),
             t_admit=_time.perf_counter(),
+            t_queued=_time.monotonic() if t_queued is None else t_queued,
         )
         if not self.paged:
             # Dense engines cannot land shipped blocks: degrade to a plain
@@ -544,16 +579,24 @@ class LLMEngine:
         waiting = [
             r for r in self.requests.values() if r.slot < 0 and not r.finished
         ]
+        fr = _flightrec.on()
         for req in waiting:
             try:
                 slot = self.slot_free.index(True)
             except ValueError:
                 return admit_finished
+            if fr:  # where this attempt starts, and the counters then
+                mark = (
+                    _time.monotonic(), self.stats["prefill_tokens"],
+                    self.stats["prefix_tokens_reused"],
+                )
             if req.handoff is not None:
                 verdict = self._admit_handoff(req, slot)
                 if verdict == "wait":
                     return admit_finished
                 if verdict == "done":
+                    if fr and req.error is None:
+                        self._rec_admitted(req, *mark)
                     if req.finished:
                         admit_finished.append(req)
                     continue
@@ -573,11 +616,17 @@ class LLMEngine:
             if req.prefilling:
                 # Chunked prefill took the slot but defers its first
                 # sample to _advance_prefills; keep admitting.
+                if fr:
+                    self._rec_admitted(req, *mark)
                 continue
             if logits is None:
                 return admit_finished
             T = len(req.prompt)
-            tok = self._sample(np.asarray(logits), req)  # raylint: disable=RL101 -- admission sampling: first token sampled host-side from the last-logits readback
+            logits_np = np.asarray(logits)  # raylint: disable=RL101 -- admission sampling: first token sampled host-side from the last-logits readback
+            self._close_prefill_span(req)
+            tok = self._sample(logits_np, req)
+            if fr:
+                self._rec_admitted(req, *mark)
             req.slot = slot
             self.slot_free[slot] = False
             self._slot_req[slot] = req
@@ -617,6 +666,51 @@ class LLMEngine:
         _flightrec.record(
             "llm", "llm.first_token",
             t=_time.monotonic() - ttft, dur_s=ttft, rid=req.request_id,
+        )
+
+    def _rec_admitted(
+        self, req: _Request, t_adm: float, paid: int, reused: int
+    ) -> None:
+        """The two flight-recorder spans of one admission, recorded once
+        it has gone through: ``llm.queue`` from the hand-over of the
+        request to the start of the attempt that admitted it (the step in
+        flight, earlier prefills, a slot and blocks), and ``llm.admit``
+        from there to now: its first token sampled (reservation, prefix
+        lookup, prefill, read-back, the first sample), or, for a chunked
+        prefill or a handoff, its slot taken. ``tokens`` and ``reused``
+        are what this admission added to the engine's counters of prompt
+        tokens prefilled and taken from the prefix pool."""
+        _flightrec.record(
+            "llm", "llm.queue", t=req.t_queued,
+            dur_s=t_adm - req.t_queued, rid=req.request_id,
+        )
+        _flightrec.record(
+            "llm", "llm.admit", t=t_adm,
+            dur_s=_time.monotonic() - t_adm, rid=req.request_id,
+            tokens=self.stats["prefill_tokens"] - paid,
+            reused=self.stats["prefix_tokens_reused"] - reused,
+        )
+
+    @staticmethod
+    def _open_prefill_span(req: _Request, phase: str, t_pf: float, **extra):
+        """Note on ``req`` the prefill that was just dispatched; its span
+        is recorded by ``_close_prefill_span``."""
+        if _flightrec.on():
+            req.pf_open = (phase, t_pf, extra)
+
+    @staticmethod
+    def _close_prefill_span(req: _Request) -> None:
+        """End the prefill span that the dispatch left open on ``req``.
+        Called where that prefill's logits have just been read back, so
+        the span holds the device's work and not only the launch; a chunk
+        whose logits nobody reads is closed right after its dispatch."""
+        if req.pf_open is None:
+            return
+        phase, t_pf, extra = req.pf_open
+        req.pf_open = None
+        _flightrec.record(
+            "llm", phase, t=t_pf, dur_s=_time.monotonic() - t_pf,
+            rid=req.request_id, **extra,
         )
 
     def _admit_handoff(self, req: _Request, slot: int) -> str:
@@ -819,15 +913,9 @@ class LLMEngine:
             self.pool,
         )
         self.stats["prefill_tokens"] += rem
-        if _flightrec.on():
-            # Dispatch-side duration: JAX returns before the device
-            # finishes, so this phase is the host cost of the prefill
-            # launch; device truth lives in the jax trace.
-            _flightrec.record(
-                "llm", "llm.prefill", t=t_pf,
-                dur_s=_time.monotonic() - t_pf,
-                rid=req.request_id, tokens=rem, reused=P,
-            )
+        self._open_prefill_span(
+            req, "llm.prefill", t_pf, tokens=rem, reused=P, bucket=bucket
+        )
         self._insert_prefix(req.prompt, slot, blocks=table)
         return logits
 
@@ -909,12 +997,9 @@ class LLMEngine:
                 slot,
             )
             self.stats["prefill_tokens"] += rem
-            if _flightrec.on():
-                _flightrec.record(
-                    "llm", "llm.prefill", t=t_pf,
-                    dur_s=_time.monotonic() - t_pf,
-                    rid=req.request_id, tokens=rem, reused=P,
-                )
+            self._open_prefill_span(
+                req, "llm.prefill", t_pf, tokens=rem, reused=P, bucket=bucket
+            )
         else:
             if self._chunks_feasible(0, T):
                 self._begin_chunked_prefill(req, slot, 0)
@@ -934,12 +1019,9 @@ class LLMEngine:
                 slot,
             )
             self.stats["prefill_tokens"] += T
-            if _flightrec.on():
-                _flightrec.record(
-                    "llm", "llm.prefill", t=t_pf,
-                    dur_s=_time.monotonic() - t_pf,
-                    rid=req.request_id, tokens=T, reused=0,
-                )
+            self._open_prefill_span(
+                req, "llm.prefill", t_pf, tokens=T, reused=0, bucket=bucket
+            )
         self._insert_prefix(req.prompt, slot)
         return logits
 
@@ -1031,12 +1113,10 @@ class LLMEngine:
         self.stats["prefill_chunks"] += 1
         if _metrics.metrics_enabled():
             _PREFILL_CHUNKS.inc(1.0)
-        if _flightrec.on():
-            _flightrec.record(
-                "llm", "llm.prefill_chunk", t=t_pf,
-                dur_s=_time.monotonic() - t_pf,
-                rid=req.request_id, tokens=clen, start=start,
-            )
+        self._open_prefill_span(
+            req, "llm.prefill_chunk", t_pf,
+            tokens=clen, start=start, bucket=bucket,
+        )
         req.pf_next = start + clen
         self.positions[req.slot] = req.pf_next
         return logits
@@ -1063,9 +1143,12 @@ class LLMEngine:
         logits = self._prefill_one_chunk(req)
         T = len(req.prompt)
         if req.pf_next < T:
+            self._close_prefill_span(req)  # logits never read: the launch
             return []
         req.prefilling = False
-        tok = self._sample(np.asarray(logits), req)  # raylint: disable=RL101 -- final-chunk sampling: first token sampled host-side from the chunk's last-logits
+        logits_np = np.asarray(logits)  # raylint: disable=RL101 -- final-chunk sampling: first token sampled host-side from the chunk's last-logits
+        self._close_prefill_span(req)
+        tok = self._sample(logits_np, req)
         self._insert_prefix(
             req.prompt, req.slot,
             blocks=req.blocks if self.paged else None,
@@ -1141,6 +1224,7 @@ class LLMEngine:
         if active and self._spec is not None and self._spec_eligible(active):
             finished += self._spec.step(active)
         elif active:
+            fr = _flightrec.on()
             t_dec = _time.monotonic()
             if self.paged:
                 self.pool, logits = self._pg_decode(
@@ -1157,7 +1241,9 @@ class LLMEngine:
                     jnp.asarray(self.positions),
                     self.cache,
                 )
+            t_disp = _time.monotonic() if fr else 0.0
             logits_np = np.asarray(logits)  # raylint: disable=RL101 -- the decode step's ONE intended sync: batched logits readback feeding host-side sampling
+            t_read = _time.monotonic() if fr else 0.0
             now = _time.perf_counter()
             for req in active:
                 slot = req.slot
@@ -1172,12 +1258,28 @@ class LLMEngine:
                 self._maybe_finish(req)
                 if req.finished:
                     finished.append(req)
-            if _flightrec.on():
-                # Batch-wide phase (no rid): dispatch + logits readback +
-                # host sampling for every active slot this step.
+            if fr:
+                # Batch-wide phases (no rid). The step, and its three
+                # parts end to end: the uploads and the launch, the wait
+                # for the device with the copy of the logits, and the
+                # sampling of every active slot on the host.
+                t_end = _time.monotonic()
+                batch = len(active)
+                _flightrec.record(
+                    "llm", "llm.decode_dispatch", t=t_dec,
+                    dur_s=t_disp - t_dec, batch=batch,
+                )
+                _flightrec.record(
+                    "llm", "llm.decode_readback", t=t_disp,
+                    dur_s=t_read - t_disp, bytes=logits_np.nbytes,
+                )
+                _flightrec.record(
+                    "llm", "llm.decode_sample", t=t_read,
+                    dur_s=t_end - t_read, batch=batch,
+                )
                 _flightrec.record(
                     "llm", "llm.decode_step", t=t_dec,
-                    dur_s=_time.monotonic() - t_dec, batch=len(active),
+                    dur_s=t_end - t_dec, batch=batch,
                 )
         self._steps += 1
         if instrument:

@@ -21,6 +21,7 @@ import time
 from ray_tpu.llm.config import LLMConfig, SamplingParams
 from ray_tpu.llm.engine import LLMEngine
 from ray_tpu.serve import api as serve_api
+from ray_tpu.util import flightrec as _flightrec
 from ray_tpu.util import metrics as _metrics
 from ray_tpu.util.compile_cache import CacheCounter
 from ray_tpu.util.tasks import spawn
@@ -62,25 +63,52 @@ class LLMServer:
         self._pending: list[tuple] = []
         self._pending_lock = threading.Lock()
         self._pump_task = None
+        # Monotonic time the last engine step returned while the pump
+        # still had work (None once it ran dry): the next step's entry
+        # closes the flight recorder's llm.pump_gap span from it.
+        self._t_step_returned: float | None = None
 
     def _ensure_pump(self) -> None:
         if self._pump_task is None or self._pump_task.done():
             self._pump_task = spawn(self._pump(), name="llm engine pump")
 
     def _step_with_admissions(self) -> list:
+        fr = _flightrec.on()
+        t_in = time.monotonic()
         with self._pending_lock:
             batch, self._pending = self._pending, []
-        for rid, prompt, sampling, prefill_only, handoff in batch:
+        if fr and self._t_step_returned is not None:
+            # The hop between two turns of a busy engine: back to the
+            # event loop, the token push, and into the executor again.
+            _flightrec.record(
+                "llm", "llm.pump_gap", t=self._t_step_returned,
+                dur_s=t_in - self._t_step_returned, pending=len(batch),
+            )
+        for rid, prompt, sampling, prefill_only, handoff, t_queued in batch:
             if handoff is not None:
-                self.engine.add_handoff_request(rid, handoff, sampling)
+                self.engine.add_handoff_request(
+                    rid, handoff, sampling, t_queued=t_queued
+                )
             else:
                 self.engine.add_request(
-                    rid, prompt, sampling, prefill_only=prefill_only
+                    rid, prompt, sampling, prefill_only=prefill_only,
+                    t_queued=t_queued,
                 )
         finished = self.engine.step()
         for req in finished:
             self.engine.requests.pop(req.request_id, None)
         more = self.engine.has_unfinished()
+        self._t_step_returned = time.monotonic()
+        if fr:
+            # This whole turn in the executor thread. With llm.pump_gap it
+            # tiles a busy engine's time without a seam, so that whatever
+            # the engine's finer spans (admit, prefill, the decode step
+            # and its parts) leave open still has the engine's name on it.
+            _flightrec.record(
+                "llm", "llm.step", t=t_in,
+                dur_s=self._t_step_returned - t_in,
+                admitted=len(batch), finished=len(finished),
+            )
         return finished, more
 
     def _push_new_tokens(self, finished: list) -> None:
@@ -117,14 +145,22 @@ class LLMServer:
                 _ACTIVE_REQUESTS.set(
                     float(len(self.engine.requests)), _replica_tags()
                 )
+            t_push = time.monotonic()
             self._push_new_tokens(finished)
             for req in finished:
                 self._finished[req.request_id] = req
                 ev = self._events.pop(req.request_id, None)
                 if ev is not None:
                     ev.set()
+            if _flightrec.on():
+                _flightrec.record(
+                    "llm", "llm.push_tokens", t=t_push,
+                    dur_s=time.monotonic() - t_push,
+                    streams=len(self._token_queues),
+                )
             with self._pending_lock:
                 if not more and not self._pending:
+                    self._t_step_returned = None  # ran dry: no gap to name
                     return
 
     def _admit(
@@ -135,9 +171,7 @@ class LLMServer:
         handoff: dict | None = None,
     ) -> str:
         rid = f"req-{next(self._counter)}"
-        from ray_tpu.util import flightrec
-
-        if flightrec.on():
+        if _flightrec.on():
             # Stitch the router's flight-recorder request id (propagated via
             # the replica's contextvar) to the engine-local req-N id, so the
             # timeline exporter can join serve hops to engine phases.
@@ -145,9 +179,13 @@ class LLMServer:
 
             frid = current_frid()
             if frid is not None:
-                flightrec.record("llm", "llm.bind", rid=rid, frid=frid)
+                _flightrec.record("llm", "llm.bind", rid=rid, frid=frid)
         with self._pending_lock:
-            self._pending.append((rid, prompt, sampling, prefill_only, handoff))
+            # The hand-over time rides along: the engine's llm.queue span
+            # starts here, not where the pump drains the list.
+            self._pending.append(
+                (rid, prompt, sampling, prefill_only, handoff, time.monotonic())
+            )
         return rid
 
     async def _generate(

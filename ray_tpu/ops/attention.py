@@ -168,6 +168,7 @@ def _flash_attention_fwd_impl(
                 jax.ShapeDtypeStruct((B, H, S, 1), jnp.float32),
             ],
             interpret=interpret,
+            name="flash_fwd",
         )(q, k, v)
 
     return _per_shard(shard, mesh)(q, k, v)
@@ -291,6 +292,7 @@ def _flash_attention_bwd_impl(
             ],
             scratch_shapes=[pltpu.VMEM((S, D), jnp.float32)],
             interpret=interpret,
+            name="flash_bwd",
         )(q, k, v, g, lse, delta)
         return dq, dk, dv
 

@@ -15,10 +15,13 @@ from __future__ import annotations
 import asyncio
 import json
 import math
+import time
 from urllib.parse import parse_qs, urlparse
 
 from ray_tpu.core.errors import OverloadedError
+from ray_tpu.serve import router as _router
 from ray_tpu.serve.handle import DeploymentHandle
+from ray_tpu.util import flightrec as _flightrec
 
 _ASGI = object()  # _route's "raw ASGI response" status sentinel
 
@@ -105,6 +108,10 @@ class HTTPProxyActor:
                     body = await reader.readexactly(
                         int(headers["content-length"])
                     )
+                if _flightrec.on():
+                    # Head and body are read: the replica's serve.hop_in
+                    # span starts here (the router carries the time).
+                    _router.note_ingress(time.time())
                 parsed = self._parse_body(body)
                 if self._wants_stream(headers, parsed):
                     await self._route_stream(

@@ -36,6 +36,33 @@ def current_frid():
     task/thread, or None (recorder off, or not inside a serve request)."""
     return _active_frid.get()
 
+
+def _rec_hop_in(frid, t_ingress) -> None:
+    """``serve.hop_in``: from the wall time the ingress had read the
+    request (the proxy's, else the router's entry) to now, the entry of
+    ``handle`` / ``handle_streaming``: the proxy's parse, the router's
+    admission and pick, the actor call and its deserialising. The start
+    comes from another process's wall clock: on one host that is the same
+    clock; across hosts the span carries the hosts' skew."""
+    if frid is None or t_ingress is None or not _flightrec.on():
+        return
+    dur = max(0.0, _time.time() - t_ingress)
+    _flightrec.record(
+        "serve", "serve.hop_in", t=_time.monotonic() - dur, dur_s=dur,
+        rid=frid,
+    )
+
+
+def _rec_first_chunk(frid, t_entry: float) -> bool:
+    """``serve.replica_first_chunk``: entry of ``handle_streaming`` to
+    its first ``yield``. Returns False: the caller's "first chunk still
+    to come" flag."""
+    _flightrec.record(
+        "serve", "serve.replica_first_chunk", t=t_entry,
+        dur_s=_time.monotonic() - t_entry, rid=frid,
+    )
+    return False
+
 # Replica-side half of the serve request breakdown (router wait is
 # recorded by the routing process): user-callable execution time and the
 # queue-length gauge the autoscaler's table is fed from — exported here
@@ -214,17 +241,20 @@ class ReplicaActor:
         return getattr(self._callable, method)
 
     async def handle(
-        self, method: str, payload: bytes, model_id: str = "", frid=None
+        self, method: str, payload: bytes, model_id: str = "", frid=None,
+        t_ingress=None,
     ):
         """Execute one request. Requests are (method, pickled (args, kwargs));
         sync user code runs in the worker's executor thread so the replica
         keeps answering pings while busy. ``model_id`` (multiplexing) binds
         serve.get_multiplexed_model_id() for the duration of the call.
-        ``frid`` is the router's flight-recorder request id — only ever
-        passed when RAY_TPU_FLIGHTREC is on (the wire call is otherwise
-        byte-identical to the pre-recorder tree)."""
+        ``frid`` is the router's flight-recorder request id and
+        ``t_ingress`` the wall time the ingress had read the request —
+        only ever passed when RAY_TPU_FLIGHTREC is on (the wire call is
+        otherwise byte-identical to the pre-recorder tree)."""
         from ray_tpu.serve.multiplex import _set_model_id
 
+        _rec_hop_in(frid, t_ingress)
         self._ensure_reporter()
         self._check_queue_cap()
         args, kwargs = serialization.loads(payload)[0]
@@ -288,7 +318,8 @@ class ReplicaActor:
                 _QUEUE_LEN.set(float(self._inflight), tags)
 
     async def handle_streaming(
-        self, method: str, payload: bytes, model_id: str = "", frid=None
+        self, method: str, payload: bytes, model_id: str = "", frid=None,
+        t_ingress=None,
     ):
         """Streaming twin of ``handle``: an async generator the router
         invokes with num_returns="streaming", so each yielded chunk flows
@@ -298,6 +329,8 @@ class ReplicaActor:
         methods (single-chunk stream)."""
         from ray_tpu.serve.multiplex import _set_model_id
 
+        _rec_hop_in(frid, t_ingress)
+        t_x = _time.monotonic()
         self._ensure_reporter()
         # Streams share the bounded-queue fail-fast but NOT the execution
         # semaphore: a continuous-batching replica multiplexes its streams
@@ -310,7 +343,7 @@ class ReplicaActor:
         _set_model_id(model_id)
         fr = frid is not None and _flightrec.on()
         frid_token = _active_frid.set(frid) if fr else None
-        t_x = _time.monotonic() if fr else 0.0
+        first = fr  # the first chunk is still to come, and is recorded
         instrument = _metrics.metrics_enabled()
         t0 = _time.perf_counter() if instrument else 0.0
         self._inflight += 1
@@ -319,10 +352,14 @@ class ReplicaActor:
         try:
             if inspect.isasyncgenfunction(fn):
                 async for item in fn(*args, **kwargs):
+                    if first:
+                        first = _rec_first_chunk(frid, t_x)
                     yield item
                 return
             if inspect.isgeneratorfunction(fn):
                 for item in fn(*args, **kwargs):
+                    if first:
+                        first = _rec_first_chunk(frid, t_x)
                     yield item
                 return
             if inspect.iscoroutinefunction(fn):
@@ -335,11 +372,17 @@ class ReplicaActor:
                 )
             if inspect.isasyncgen(result):
                 async for item in result:
+                    if first:
+                        first = _rec_first_chunk(frid, t_x)
                     yield item
             elif inspect.isgenerator(result):
                 for item in result:
+                    if first:
+                        first = _rec_first_chunk(frid, t_x)
                     yield item
             else:
+                if first:
+                    first = _rec_first_chunk(frid, t_x)
                 yield result
         finally:
             if fr:

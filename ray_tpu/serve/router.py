@@ -10,6 +10,7 @@ from the controller.
 from __future__ import annotations
 
 import asyncio
+import contextvars
 import itertools
 import os as _os
 import random
@@ -40,6 +41,25 @@ _frid_counter = itertools.count()
 
 def _next_frid() -> str:
     return f"fr-{_os.getpid()}-{next(_frid_counter)}"
+
+
+# Wall time at which the ingress had read the whole of the request that
+# this task is routing (the HTTP proxy sets it; nobody else does). It
+# rides to the replica beside the frid, recorder on only, and starts the
+# replica's serve.hop_in span. A call that came through no proxy starts
+# that span at the router's own entry.
+_ingress_wall: contextvars.ContextVar = contextvars.ContextVar(
+    "ray_tpu_ingress_wall", default=None
+)
+
+
+def note_ingress(wall_s: float) -> None:
+    _ingress_wall.set(wall_s)
+
+
+def _ingress_time() -> float:
+    t = _ingress_wall.get()
+    return _time.time() if t is None else t
 
 # Serve request SLO series, recorded in the routing process (driver or
 # proxy) and shipped through the standard push path. Request latency
@@ -728,6 +748,7 @@ class Router:
         t0 = _time.perf_counter() if instrument else 0.0
         fr = _flightrec.on()
         frid = _next_frid() if fr else None
+        t_in = _ingress_time() if fr else 0.0
         t_req = _time.monotonic() if fr else 0.0
         last_err: Exception | None = None
         adm = _RequestAdmission(self, args, kwargs, tenant, priority)
@@ -809,7 +830,7 @@ class Router:
                 t_ph = _time.monotonic() if fr else 0.0
                 if frid is not None:
                     ref = replica.handle.remote(
-                        method, payload, model_id, frid
+                        method, payload, model_id, frid, t_in
                     )
                 else:
                     ref = replica.handle.remote(method, payload, model_id)
@@ -902,6 +923,7 @@ class Router:
         t0 = _time.perf_counter() if instrument else 0.0
         fr = _flightrec.on()
         frid = _next_frid() if fr else None
+        t_in = _ingress_time() if fr else 0.0
         t_req = _time.monotonic() if fr else 0.0
         last_err: Exception | None = None
         adm = _RequestAdmission(self, args, kwargs, tenant, priority)
@@ -980,7 +1002,7 @@ class Router:
                 if frid is not None:
                     gen = replica.handle_streaming.options(
                         num_returns="streaming"
-                    ).remote(method, payload, model_id, frid)
+                    ).remote(method, payload, model_id, frid, t_in)
                 else:
                     gen = replica.handle_streaming.options(
                         num_returns="streaming"

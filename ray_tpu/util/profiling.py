@@ -112,18 +112,52 @@ def sample_collapsed_stacks(
     }
 
 
+# The host event capture_jax_trace writes at a wall time it knows, so that
+# the trace's clock can be laid on the wall clock (and with it on the
+# flight recorder's spans, which carry a wall anchor of their own).
+CLOCK_ANCHOR = "raytpu_clock_anchor"
+FLIGHTREC_SNAPSHOT = "flightrec_snapshot.json"
+
+
 def capture_jax_trace(trace_dir: str, duration_s: float = 3.0) -> dict:
     """Capture a jax.profiler (XLA/XPlane) trace of THIS process for
     ``duration_s`` — device ops included when a TPU is attached. The
-    output dir loads in TensorBoard's profile plugin / XProf."""
+    output dir loads in TensorBoard's profile plugin / XProf.
+
+    So that the trace can be laid against the program's own spans, the
+    capture opens with a host annotation named ``CLOCK_ANCHOR`` whose wall
+    time is returned as ``anchor_wall_ns`` (wall = trace time + the
+    difference at that event), and closes by saving
+    ``flightrec.snapshot()`` as ``FLIGHTREC_SNAPSHOT`` in ``trace_dir``:
+    every ring of this process, with the wall anchor its monotonic times
+    are read against."""
+    import json
+    import os
+
     import jax
+
+    from ray_tpu.util import flightrec
 
     jax.profiler.start_trace(trace_dir)
     try:
+        with jax.profiler.TraceAnnotation(CLOCK_ANCHOR):
+            anchor_wall_ns = time.time_ns()
+            time.sleep(0.001)
         time.sleep(duration_s)
     finally:
         jax.profiler.stop_trace()
-    return {"trace_dir": trace_dir, "duration_s": duration_s}
+    snapshot_path = os.path.join(trace_dir, FLIGHTREC_SNAPSHOT)
+    snap = flightrec.snapshot()
+    snap["anchor_wall_ns"] = anchor_wall_ns
+    snap["clock_anchor"] = CLOCK_ANCHOR
+    with open(snapshot_path, "w") as f:
+        json.dump(snap, f, separators=(",", ":"), default=str)
+    return {
+        "trace_dir": trace_dir,
+        "duration_s": duration_s,
+        "anchor_wall_ns": anchor_wall_ns,
+        "flightrec_snapshot": snapshot_path,
+    }
 
 
 # -- driver-side helpers ------------------------------------------------------

@@ -1,0 +1,381 @@
+"""The flight recorder's spans on the serving path: the inside of the decode
+step, a prefill span that ends where its logits reach the host, queue and
+admission, the pump's gap, the hop into the replica; and the names the
+jitted programs carry into a device trace. Toy engine, on the CPU.
+"""
+
+import asyncio
+import dataclasses
+import http.client
+import json
+import time
+
+import cloudpickle
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import ray_tpu
+from ray_tpu.core import serialization
+from ray_tpu.core.config import GLOBAL_CONFIG, Config
+from ray_tpu.llm import LLMConfig, LLMEngine, SamplingParams
+from ray_tpu.llm.serve_llm import LLMServer
+from ray_tpu.models import gpt2
+from ray_tpu.serve.replica import ReplicaActor
+from ray_tpu.util import flightrec, trace_export
+
+pytestmark = pytest.mark.timeout(300)
+
+MS = 1e-3
+
+
+@pytest.fixture(autouse=True)
+def _recorder_on_and_empty():
+    saved = GLOBAL_CONFIG.flightrec
+    GLOBAL_CONFIG.flightrec = True
+    flightrec.reset()
+    yield
+    GLOBAL_CONFIG.flightrec = saved
+    flightrec.reset()
+
+
+def llm_config(block_size=16, **kw):
+    model = dataclasses.replace(
+        gpt2.GPT2Config.tiny(vocab_size=512, max_seq=128),
+        dtype=jnp.float32, attn_impl="reference",
+    )
+    return LLMConfig(**{
+        "model_config": model, "max_slots": 2, "max_seq": 64,
+        "prefill_buckets": (16, 32), "kv_block_size": block_size,
+        "prefix_chunk": 16, "seed": 0, "enable_prefix_caching": False, **kw,
+    })
+
+
+def events(plane="llm"):
+    ring = flightrec.snapshot()["rings"].get(plane, {"events": []})
+    return ring["events"]
+
+
+def of(phase, plane="llm"):
+    return [e for e in events(plane) if e["phase"] == phase]
+
+
+def end(e):
+    return e["t"] + e["dur_s"]
+
+
+MODES = pytest.mark.parametrize("block_size", [16, 0], ids=["paged", "dense"])
+
+
+@MODES
+def test_the_three_parts_of_a_decode_step_add_up_to_it(block_size):
+    eng = LLMEngine(llm_config(block_size))
+    eng.generate(["hello there", "abc"], SamplingParams(max_tokens=5))
+    steps = of("llm.decode_step")
+    parts = [of(p) for p in
+             ("llm.decode_dispatch", "llm.decode_readback", "llm.decode_sample")]
+    assert len(steps) == 4 and all(len(p) == 4 for p in parts)
+    for step, dispatch, readback, sample in zip(steps, *parts):
+        assert dispatch["t"] == step["t"]
+        assert end(dispatch) == readback["t"] and end(readback) == sample["t"]
+        total = dispatch["dur_s"] + readback["dur_s"] + sample["dur_s"]
+        assert abs(total - step["dur_s"]) < MS
+        assert dispatch["extra"]["batch"] == sample["extra"]["batch"] == 2
+        assert step["extra"]["batch"] == 2
+        assert readback["extra"]["bytes"] == 2 * 512 * 4  # [max_slots, vocab] f32
+
+
+class SlowLogits:
+    """Logits whose copy to the host takes a while, as a device's would:
+    ``np.asarray`` calls ``__array__``, and notes when it was done."""
+
+    def __init__(self, logits, done: list):
+        self.logits, self.done = logits, done
+
+    def __array__(self, dtype=None, copy=None):
+        time.sleep(0.03)
+        out = np.asarray(self.logits)
+        self.done.append(time.monotonic())
+        return out
+
+
+def slow_readback(eng, attr, called: list, done: list):
+    """Wrap a jitted prefill program of the engine: note when the dispatch
+    was entered and left, and hand back logits that are slow to read."""
+    program = getattr(eng, attr)
+
+    def wrapped(*args):
+        t_in = time.monotonic()
+        cache, logits = program(*args)
+        called.append((t_in, time.monotonic()))
+        return cache, SlowLogits(logits, done)
+
+    setattr(eng, attr, wrapped)
+
+
+@pytest.mark.parametrize("case", ["paged", "dense", "prefill_only", "chunked"])
+def test_a_prefill_span_ends_where_its_logits_reach_the_host(case):
+    kw = {"prefill_chunk_tokens": 16} if case == "chunked" else {}
+    eng = LLMEngine(llm_config(0 if case == "dense" else 16, **kw))
+    called, done = [], []
+    slow_readback(eng, "_prefill" if case == "dense" else "_pg_prefill", called, done)
+    prompt = list(range(3, 3 + (30 if case == "chunked" else 12)))
+    t_before = time.monotonic()
+    eng.add_request("r", prompt, SamplingParams(max_tokens=2),
+                    prefill_only=case == "prefill_only")
+    while eng.has_unfinished():
+        eng.step()
+    spans = of("llm.prefill_chunk" if case == "chunked" else "llm.prefill")
+    assert len(spans) == len(called) == (2 if case == "chunked" else 1)
+    for span, (t_in, t_out) in zip(spans, called):
+        # it starts where it did: just before the dispatch is entered
+        assert t_before <= span["t"] <= t_in
+        assert end(span) >= t_out
+        assert span["rid"] == "r" and span["extra"]["bucket"] in (16, 32)
+    # only the logits that are sampled from are read; their span waits for them
+    assert len(done) == 1
+    last = spans[-1]
+    assert end(last) >= done[0] and last["dur_s"] >= 0.03
+    assert end(last) - done[0] < 5 * MS
+    for early in spans[:-1]:  # chunks nobody reads end with their dispatch
+        assert end(early) < done[0] - 0.03
+    first_token = of("llm.first_token")[0]
+    assert end(last) <= end(first_token) + MS
+
+
+@MODES
+def test_queue_and_admit_lie_inside_the_first_token_interval(block_size):
+    eng = LLMEngine(llm_config(block_size))
+    for rid, prompt in (("a", "hello there"), ("b", "abc")):
+        eng.add_request(rid, prompt, SamplingParams(max_tokens=2))
+    while eng.has_unfinished():
+        eng.step()
+    for rid in ("a", "b"):
+        (queue,) = [e for e in of("llm.queue") if e["rid"] == rid]
+        (admit,) = [e for e in of("llm.admit") if e["rid"] == rid]
+        (first,) = [e for e in of("llm.first_token") if e["rid"] == rid]
+        (prefill,) = [e for e in of("llm.prefill") if e["rid"] == rid]
+        assert first["t"] - MS <= queue["t"] <= end(queue) == admit["t"]
+        assert end(admit) <= end(first) + MS
+        assert admit["t"] <= prefill["t"] and end(prefill) <= end(admit)
+        assert admit["extra"] == {
+            "tokens": prefill["extra"]["tokens"], "reused": 0}
+    # "b" waited for "a"'s prefill: its queue span covers a's admission
+    (qb,) = [e for e in of("llm.queue") if e["rid"] == "b"]
+    (aa,) = [e for e in of("llm.admit") if e["rid"] == "a"]
+    assert qb["t"] <= aa["t"] and end(qb) >= end(aa)
+
+
+def test_queue_starts_at_the_hand_over_a_caller_names():
+    eng = LLMEngine(llm_config())
+    t_handed = time.monotonic() - 0.25
+    eng.add_request("r", "abc", SamplingParams(max_tokens=1), t_queued=t_handed)
+    eng.step()
+    (queue,) = of("llm.queue")
+    assert queue["t"] == t_handed and 0.25 <= queue["dur_s"] < 0.25 + 50 * MS
+
+
+def test_pump_gap_lies_between_two_steps_and_not_after_a_dry_engine():
+    server = LLMServer(llm_config())
+
+    async def stream(n):
+        return [p async for p in server._stream_tokens("hello", SamplingParams(max_tokens=n))]
+
+    async def scenario():
+        await stream(4)
+        await server._pump_task  # the pump has run dry and returned
+        first = len(of("llm.decode_step")), len(of("llm.pump_gap"))
+        await stream(3)
+        await server._pump_task
+        return first
+
+    steps1, gaps1 = asyncio.run(scenario())
+    steps = of("llm.decode_step")
+    gaps = of("llm.pump_gap")
+    pushes = of("llm.push_tokens")
+    # one stream: the admitting step decodes too, so n tokens take n - 1 steps
+    assert (steps1, len(steps)) == (3, 5)
+    # a gap before every step but the first of each stream: none is recorded
+    # across the time the engine had run dry
+    assert (gaps1, len(gaps)) == (2, 3)
+    assert len(pushes) == 5 and pushes[0]["extra"]["streams"] == 1
+    dry = steps[3]["t"] - end(steps[2])
+    assert all(g["dur_s"] < dry for g in gaps)
+    for gap in gaps:  # from one step's return to the next one's entry
+        before = max((s for s in steps if end(s) <= gap["t"] + MS), key=end)
+        after = min((s for s in steps if s["t"] >= end(gap) - MS), key=lambda s: s["t"])
+        assert steps.index(after) == steps.index(before) + 1
+        assert gap["extra"]["pending"] == 0
+        inside = [p for p in pushes if gap["t"] <= p["t"] and end(p) <= end(gap)]
+        assert len(inside) == 1
+    (queue,) = [e for e in of("llm.queue") if e["rid"] == "req-1"]
+    assert queue["dur_s"] < dry
+    # llm.step is a whole turn in the executor thread; with the gaps it
+    # tiles a busy engine's time without a seam
+    turns = of("llm.step")
+    assert [t["extra"]["admitted"] for t in turns] == [1, 0, 0, 1, 0]
+    assert sum(t["extra"]["finished"] for t in turns) == 2
+    for gap in gaps:
+        assert any(end(t) == gap["t"] for t in turns)
+        assert any(t["t"] == end(gap) for t in turns)
+    for step in steps:
+        assert any(t["t"] <= step["t"] and end(step) <= end(t) for t in turns)
+
+
+# -- the hop into the replica -------------------------------------------------
+
+
+def streaming_echo(request):
+    for i in range(3):
+        yield {"i": i, "x": request["x"]}
+
+
+def replica_of(fn):
+    return ReplicaActor(
+        "d", cloudpickle.dumps(fn), serialization.dumps(((), {}))[0], None
+    )
+
+
+def drain(agen):
+    async def run():
+        return [item async for item in agen]
+
+    return asyncio.run(run())
+
+
+def test_the_replica_records_hop_in_and_first_chunk_under_the_routers_id():
+    replica = replica_of(streaming_echo)
+    payload = serialization.dumps((({"x": 7},), {}))[0]
+    t_ingress = time.time() - 0.2
+    out = drain(replica.handle_streaming("__call__", payload, "", "fr-1-0", t_ingress))
+    assert [o["i"] for o in out] == [0, 1, 2]
+    (hop,) = of("serve.hop_in", "serve")
+    (first,) = of("serve.replica_first_chunk", "serve")
+    (whole,) = of("serve.replica_exec", "serve")
+    assert hop["rid"] == first["rid"] == whole["rid"] == "fr-1-0"
+    assert 0.2 <= hop["dur_s"] < 0.2 + 50 * MS
+    assert abs(end(hop) - first["t"]) < 5 * MS  # the entry of the handler
+    assert whole["t"] == first["t"] and first["dur_s"] <= whole["dur_s"]
+
+    async def plain():
+        return await replica.handle("__call__", payload, "", "fr-1-1", time.time())
+
+    assert len(asyncio.run(plain())) == 3  # a generator, drained to a list
+    assert [e["rid"] for e in of("serve.hop_in", "serve")] == ["fr-1-0", "fr-1-1"]
+
+
+@pytest.mark.parametrize("recorder", ["off", "no_id"])
+def test_the_wire_call_without_an_id_records_nothing(recorder):
+    """With the recorder off the router sends (method, payload, model_id)
+    and nothing more; a replica called so records nothing, whether its own
+    recorder is on or not."""
+    GLOBAL_CONFIG.flightrec = recorder != "off"
+    replica = replica_of(streaming_echo)
+    payload = serialization.dumps((({"x": 7},), {}))[0]
+    out = drain(replica.handle_streaming("__call__", payload, ""))
+    assert [o["x"] for o in out] == [7, 7, 7]
+    assert flightrec.snapshot()["rings"] == {}
+
+
+@pytest.fixture(scope="module")
+def cluster():
+    runtime = ray_tpu.init(num_cpus=8)
+    yield runtime
+    from ray_tpu.serve import api as serve
+
+    serve.shutdown()
+    ray_tpu.shutdown()
+
+
+def test_a_streamed_request_through_the_proxy_records_the_hop(cluster):
+    from ray_tpu.serve import api as serve
+
+    @serve.deployment(num_replicas=1)
+    class Words:
+        async def __call__(self, request):
+            async def words():
+                for w in request["body"]["text"].split():
+                    await asyncio.sleep(0.01)
+                    yield {"word": w}
+
+            return words()
+
+    serve.run(Words.bind())
+    conn = http.client.HTTPConnection("127.0.0.1", serve.proxy_port(), timeout=60)
+    conn.request(
+        "POST", "/Words", body=json.dumps({"text": "a b c"}),
+        headers={"Content-Type": "application/json", "Accept": "text/event-stream"},
+    )
+    body = conn.getresponse().read().decode()
+    conn.close()
+    assert body.count('"word"') == 3 and "[DONE]" in body
+
+    deadline = time.time() + 30
+    while True:
+        by_phase: dict = {}
+        for snap in trace_export.collect_snapshots(cluster=True):
+            for e in snap["rings"].get("serve", {"events": []})["events"]:
+                by_phase.setdefault(e["phase"], []).append((snap["pid"], e))
+        if "serve.replica_first_chunk" in by_phase or time.time() > deadline:
+            break
+        time.sleep(0.2)
+    (r_pid, hop), = by_phase["serve.hop_in"]
+    (f_pid, first), = by_phase["serve.replica_first_chunk"]
+    (p_pid, routed), = by_phase["serve.first_chunk"]  # the router's, in the proxy
+    assert r_pid == f_pid != p_pid
+    assert hop["rid"] == first["rid"] == routed["rid"]
+    assert hop["rid"].startswith(f"fr-{p_pid}-")
+    assert 0 < hop["dur_s"] < 5.0 and 0.01 <= first["dur_s"] < 5.0
+
+
+# -- names on the device, and the ring ----------------------------------------
+
+
+@pytest.fixture(scope="module")
+def engines():
+    return {bs: LLMEngine(llm_config(bs)) for bs in (16, 0)}
+
+
+def _module_name(jitted, *args):
+    return jitted.lower(*args).as_text().split("module @", 1)[1].split(" ", 1)[0]
+
+
+@pytest.mark.parametrize("program", [
+    "paged_prefill", "paged_decode", "dense_prefill", "dense_prefill_cont",
+    "dense_decode",
+])
+def test_a_jitted_program_carries_its_name_into_the_trace(engines, program):
+    """The profiler's ``XLA Modules`` line names a run after the module, and
+    the module after the jitted function: ``jit_paged_decode``, where a
+    ``functools.partial`` gave ``jit__unknown``."""
+    eng = engines[16 if program.startswith("paged") else 0]
+    toks = jnp.zeros((1, 16), jnp.int32)
+    n, z = jnp.asarray(4, jnp.int32), jnp.asarray(0, jnp.int32)
+    last, pos = jnp.asarray(eng.last_tokens), jnp.asarray(eng.positions)
+    if program == "paged_prefill":
+        row = jnp.asarray(eng.block_tables[0])
+        name = _module_name(eng._pg_prefill, eng.params, toks, n, z, row, eng.pool)
+    elif program == "paged_decode":
+        name = _module_name(
+            eng._pg_decode, eng.params, last, pos, jnp.asarray(eng.block_tables), eng.pool)
+    elif program == "dense_prefill":
+        name = _module_name(eng._prefill, eng.params, toks, n, eng.cache, 0)
+    elif program == "dense_prefill_cont":
+        name = _module_name(eng._prefill_cont, eng.params, toks, n, z, eng.cache, 0)
+    else:
+        name = _module_name(eng._decode, eng.params, last, pos, eng.cache)
+    assert name == f"jit_{program}"
+
+
+def test_the_default_ring_holds_a_benchmark_window():
+    assert Config().flightrec_ring_size == 16384
+    saved = GLOBAL_CONFIG.flightrec_ring_size
+    GLOBAL_CONFIG.flightrec_ring_size = Config().flightrec_ring_size
+    try:
+        for i in range(16384):
+            flightrec.record("llm", "llm.decode_step", dur_s=0.0, batch=i)
+        assert flightrec.drops("llm") == 0
+        flightrec.record("llm", "llm.decode_step", dur_s=0.0, batch=-1)
+        assert flightrec.drops("llm") == 1
+    finally:
+        GLOBAL_CONFIG.flightrec_ring_size = saved

@@ -129,6 +129,42 @@ def test_jax_trace_capture(cluster, tmp_path):
     assert any(os.path.getsize(a) > 0 for a in artifacts)
 
 
+def test_jax_trace_carries_a_clock_anchor_and_the_flight_recorder(tmp_path):
+    """An operator's trace can be laid against the program's spans: the
+    capture writes a host event at a wall time it returns, and saves this
+    process's flight-recorder rings beside the trace."""
+    import os
+
+    from jax.profiler import ProfileData
+
+    from ray_tpu.util import flightrec
+
+    flightrec.record("llm", "llm.decode_step", dur_s=0.04, batch=3)
+    t0 = time.time_ns()
+    out = profiling.capture_jax_trace(str(tmp_path / "trace"), 0.2)
+    assert t0 <= out["anchor_wall_ns"] <= time.time_ns()
+    with open(out["flightrec_snapshot"]) as f:
+        snap = json.load(f)
+    assert os.path.dirname(out["flightrec_snapshot"]) == out["trace_dir"]
+    assert snap["anchor_wall_ns"] == out["anchor_wall_ns"]
+    assert snap["clock_anchor"] == profiling.CLOCK_ANCHOR
+    assert {"mono_anchor", "wall_anchor"} <= set(snap)
+    assert any(
+        e["phase"] == "llm.decode_step" and e["extra"] == {"batch": 3}
+        for e in snap["rings"]["llm"]["events"]
+    )
+    (xplane,) = [
+        os.path.join(d, f) for d, _, files in os.walk(out["trace_dir"])
+        for f in files if f.endswith(".xplane.pb")
+    ]
+    anchors = [
+        e for plane in ProfileData.from_file(xplane).planes
+        for line in plane.lines for e in line.events
+        if e.name == profiling.CLOCK_ANCHOR
+    ]
+    assert len(anchors) == 1 and anchors[0].duration_ns >= 1_000_000
+
+
 def test_dashboard_profile_routes(cluster):
     from ray_tpu.dashboard import DashboardHead
 

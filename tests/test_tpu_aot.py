@@ -58,6 +58,32 @@ def test_flash_kernels_compile_on_one_chip(v5e_2x2):
     assert bwd.as_text().count(MOSAIC) == 2  # forward + fused backward
 
 
+@pytest.mark.parametrize("meshed", [False, True], ids=["one-chip", "fsdp4"])
+def test_flash_kernels_keep_their_names_in_the_compiled_program(v5e_2x2, meshed):
+    """A device trace names an operation after its HLO instruction. The
+    Mosaic calls are ``flash_fwd.<n>`` and ``flash_bwd.<n>`` there, under a
+    mesh too, where they used to take the ``shard_map``'s name and number."""
+    import re
+
+    devices = v5e_2x2 if meshed else v5e_2x2[:1]
+    mesh = make_mesh(MeshSpec(fsdp=len(devices)), devices)
+    sharding = NamedSharding(mesh, P(("dp", "fsdp")))
+    q = jax.ShapeDtypeStruct((8, 12, 1024, 64), jnp.bfloat16, sharding=sharding)
+
+    def loss(q, k, v):
+        return attention.causal_attention(
+            q, k, v, impl="pallas", block_q=512, block_k=512,
+            mesh=mesh if meshed else None,
+        ).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q).compile().as_text()
+    calls = re.findall(
+        r"^\s*(?:ROOT )?%?([\w.\-]+) = [^\n]*custom_call_target=\"" + MOSAIC + '"',
+        text, re.M,
+    )
+    assert sorted(re.sub(r"\.\d+$", "", c) for c in calls) == ["flash_bwd", "flash_fwd"]
+
+
 @pytest.mark.parametrize(
     "spec", [MeshSpec(dp=4), MeshSpec(fsdp=2, tp=2)], ids=["dp4", "fsdp2-tp2"]
 )

@@ -1355,9 +1355,8 @@ def main() -> int:
         action="store_true",
         help="kill switch: no flight-recorder phase events anywhere "
         "(equivalent to RAY_TPU_FLIGHTREC=0) — the A/B baseline for the "
-        "observability plane; the ON arm must stay within ~3%% on the "
-        "serve p99 probe (bench.py's obs_overhead record rides "
-        "--serve-overload via tools/ab_tracing.py)",
+        "observability plane (PERF.md gives the recorder's cost as "
+        "measured on the chip's host)",
     )
     ap.add_argument(
         "--faults",
