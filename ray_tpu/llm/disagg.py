@@ -38,6 +38,7 @@ of the fabric's own cap/TTL eviction.
 
 from __future__ import annotations
 
+import functools
 import time as _time
 
 import jax
@@ -78,9 +79,10 @@ def _gather_blocks(pool, idx):
     return jnp.stack([pool["k"][:, idx], pool["v"][:, idx]])
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=0)
 def _scatter_blocks(pool, kv, idx):
-    """Write a pulled KV block-stack into the pool rows at ``idx``."""
+    """Write a pulled KV block-stack into the pool rows at ``idx``, in
+    place: the pool passed in is donated and the caller rebinds."""
     return {
         "k": pool["k"].at[:, idx].set(kv[0]),
         "v": pool["v"].at[:, idx].set(kv[1]),

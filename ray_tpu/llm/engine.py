@@ -238,7 +238,11 @@ class LLMEngine:
 
             # Functions with names of their own, not functools.partial: a
             # device trace then lists the programs as jit_paged_prefill /
-            # jit_paged_decode and not as jit__unknown(<hash>).
+            # jit_paged_decode and not as jit__unknown(<hash>). The pool is
+            # donated: the layer scan carries it and scatters in place, so
+            # the output is the input's buffer and no step copies 2 x
+            # [L, N, KH, block, Dh]. Every call rebinds self.pool; the
+            # array passed in is deleted and nothing may keep it.
             def paged_prefill(params, tokens, length, start, table, pool):
                 return paged.paged_prefill(
                     params, tokens, length, start, table, pool,
@@ -251,8 +255,8 @@ class LLMEngine:
                     cfg=cfg, block_size=bs,
                 )
 
-            self._pg_prefill = jax.jit(paged_prefill)
-            self._pg_decode = jax.jit(paged_decode)
+            self._pg_prefill = jax.jit(paged_prefill, donate_argnums=5)
+            self._pg_decode = jax.jit(paged_decode, donate_argnums=4)
         else:
             self.cache = self._decode_mod.init_kv_cache(cfg, B, S)
 

@@ -179,20 +179,26 @@ class SpecDecoder:
             self.pool = paged.init_block_pool(
                 draft_cfg, engine.block_mgr.num_blocks, bs
             )
+            # The pools (the draft's own, and the engine's through
+            # _verify) are donated, as the engine donates its own: each
+            # call below rebinds the pool it passed in.
             self._d_prefill = jax.jit(
                 functools.partial(
                     paged.paged_prefill, cfg=draft_cfg, block_size=bs
-                )
+                ),
+                donate_argnums=5,
             )
             self._d_decode = jax.jit(
                 functools.partial(
                     paged.paged_decode, cfg=draft_cfg, block_size=bs
-                )
+                ),
+                donate_argnums=4,
             )
             self._verify = jax.jit(
                 functools.partial(
                     paged.paged_verify, cfg=target_cfg, block_size=bs
-                )
+                ),
+                donate_argnums=4,
             )
         else:
             self.cache = self._decode_mod.init_kv_cache(

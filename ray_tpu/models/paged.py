@@ -12,11 +12,18 @@ kernels:
   actually used, not per ``max_seq`` slot row. Block 0 is a reserved
   scratch block: padded/garbage writes land there and are never read.
 - **Scatter-then-gather attention.** New K/V are scattered straight into
-  their (block, offset) homes; the attending pass gathers the request's
-  blocks back into a dense ``[KH, S, Dh]`` row (a *transient* — XLA frees
-  it after the layer) and runs the same masked grouped-head einsums as
-  the dense cache path. Identical math ⇒ exact-logit parity with
-  :mod:`gpt2_decode` / :mod:`llama_decode`, which the tests assert.
+  their (layer, block, offset) homes; the attending pass gathers the
+  request's blocks back into a dense ``[KH, S, Dh]`` row (a *transient* —
+  XLA frees it after the layer) and runs the same masked grouped-head
+  einsums as the dense cache path. Identical math ⇒ exact-logit parity
+  with :mod:`gpt2_decode` / :mod:`llama_decode`, which the tests assert.
+- **The pool is written in place.** The layer scan carries the whole pool
+  and scans over the layer index, so each layer's scatter writes a few
+  rows into the buffer it was handed; the only slab-sized work left is
+  the gather. A caller that donates the pool (the engine, the speculative
+  decoder) gets its own buffer back as the output and must rebind it; one
+  that does not (``benchmarks/check.py``) keeps its input and pays one
+  copy of the pool at entry, which the compiler inserts.
 - **Static shapes everywhere**: W, block, and the prefill bucket are
   compile-time constants; positions/tables are traced operands. Two
   compiled programs (prefill-per-bucket + decode), like the dense path.
@@ -142,6 +149,31 @@ def _family(cfg, S: int):
 # Paged ops
 
 
+def _write_read(pool_kv, l, bids, offs, new, tables):
+    """Layer ``l`` of one pool tensor [L, N, KH, block, Dh]: scatter
+    ``new`` [..., KH, Dh] to the (block, offset) homes ``bids`` / ``offs``
+    [...], then gather the rows of ``tables`` [..., W] back as
+    [..., W, KH, block, Dh]. Both index by (layer, block) at once: slicing
+    the layer out first would bring its whole slab back as a temporary."""
+    khi = jnp.arange(pool_kv.shape[2])
+    pool_kv = pool_kv.at[l, bids[..., None], khi, offs[..., None]].set(new)
+    return pool_kv, pool_kv[l, tables]
+
+
+def _scan_layers(body, x, params, pool):
+    """Run ``body`` over the layers with the pool in the carry, so that
+    layer l's scatter writes into the buffer layer l+1 reads: a scanned
+    input and a stacked output are two buffers, and cost a slab copy a
+    layer each way."""
+    L = pool["k"].shape[0]
+    (x, pk, pv), _ = jax.lax.scan(
+        body,
+        (x, pool["k"], pool["v"]),
+        (params["blocks"], jnp.arange(L, dtype=jnp.int32)),
+    )
+    return x, {"k": pk, "v": pv}
+
+
 def paged_prefill(
     params: Params,
     tokens: jax.Array,  # [1, T] int32 — suffix tokens (whole prompt if
@@ -172,34 +204,29 @@ def paged_prefill(
     x = embed(params, tokens, pos[None])
     bids = table[pos // block_size]  # [T] physical blocks to write
     offs = pos % block_size
-    khi = jnp.arange(KH)
     cols = jnp.arange(S)
     mask = cols[None, :] <= pos[:, None]  # [T, S]
     scale = 1.0 / (Dh**0.5)
 
-    def body(x, layer):
-        p, pk, pv = layer  # pk/pv: [N, KH, block, Dh]
+    def body(carry, layer):
+        x, pk, pv = carry  # pk/pv: the whole pool, [L, N, KH, block, Dh]
+        p, l = layer
         q, k, v = qkv(x, p, pos[None])  # q [1,H,T,Dh], k/v [1,KH,T,Dh]
         kt = k[0].transpose(1, 0, 2)  # [T, KH, Dh]
         vt = v[0].transpose(1, 0, 2)
-        pk = pk.at[bids[:, None], khi[None, :], offs[:, None]].set(kt)
-        pv = pv.at[bids[:, None], khi[None, :], offs[:, None]].set(vt)
-        # Gather this request's row (transient): [W,KH,block,Dh]->[KH,S,Dh]
-        kd = pk[table].transpose(1, 0, 2, 3).reshape(KH, S, Dh)
-        vd = pv[table].transpose(1, 0, 2, 3).reshape(KH, S, Dh)
+        # This request's row (transient): [W,KH,block,Dh] -> [KH,S,Dh]
+        pk, kd = _write_read(pk, l, bids, offs, kt, table)
+        pv, vd = _write_read(pv, l, bids, offs, vt, table)
+        kd = kd.transpose(1, 0, 2, 3).reshape(KH, S, Dh)
+        vd = vd.transpose(1, 0, 2, 3).reshape(KH, S, Dh)
         qg = q[0].reshape(KH, group, T, Dh)
         s = jnp.einsum("kgtd,ksd->kgts", qg, kd).astype(jnp.float32) * scale
         s = jnp.where(mask[None, None], s, -1e30)
         pa = jax.nn.softmax(s, axis=-1).astype(vd.dtype)
         attn = jnp.einsum("kgts,ksd->kgtd", pa, vd).reshape(1, H, T, Dh)
-        return finish(x, attn, p), (pk, pv)
+        return (finish(x, attn, p), pk, pv), None
 
-    x, (ks, vs) = jax.lax.scan(
-        lambda c, lyr: body(c, lyr),
-        x,
-        (params["blocks"], pool["k"], pool["v"]),
-    )
-    pool = {"k": ks, "v": vs}
+    x, pool = _scan_layers(body, x, params, pool)
     last = jax.lax.dynamic_index_in_dim(
         x[0], (length - 1).astype(jnp.int32), axis=0, keepdims=False
     )
@@ -238,37 +265,28 @@ def paged_verify(
     rows = jnp.arange(B)
     bids = tables[rows[:, None], pos2d // block_size]  # [B, T]
     offs = pos2d % block_size
-    khi = jnp.arange(KH)
     cols = jnp.arange(S)
     mask = cols[None, None, :] <= pos2d[:, :, None]  # [B, T, S]
     scale = 1.0 / (Dh**0.5)
 
-    def body(x, layer):
-        p, pk, pv = layer  # [N, KH, block, Dh]
+    def body(carry, layer):
+        x, pk, pv = carry  # pk/pv: the whole pool, [L, N, KH, block, Dh]
+        p, l = layer
         q, k, v = qkv(x, p, pos2d)  # q [B,H,T,Dh], k/v [B,KH,T,Dh]
         kt = k.transpose(0, 2, 1, 3)  # [B, T, KH, Dh]
         vt = v.transpose(0, 2, 1, 3)
-        pk = pk.at[
-            bids[:, :, None], khi[None, None, :], offs[:, :, None]
-        ].set(kt)
-        pv = pv.at[
-            bids[:, :, None], khi[None, None, :], offs[:, :, None]
-        ].set(vt)
-        kd = pk[tables].transpose(0, 2, 1, 3, 4).reshape(B, KH, S, Dh)
-        vd = pv[tables].transpose(0, 2, 1, 3, 4).reshape(B, KH, S, Dh)
+        pk, kd = _write_read(pk, l, bids, offs, kt, tables)
+        pv, vd = _write_read(pv, l, bids, offs, vt, tables)
+        kd = kd.transpose(0, 2, 1, 3, 4).reshape(B, KH, S, Dh)
+        vd = vd.transpose(0, 2, 1, 3, 4).reshape(B, KH, S, Dh)
         qg = q.reshape(B, KH, group, T, Dh)
         s = jnp.einsum("bkgtd,bksd->bkgts", qg, kd).astype(jnp.float32)
         s = jnp.where(mask[:, None, None], s * scale, -1e30)
         pa = jax.nn.softmax(s, axis=-1).astype(vd.dtype)
         attn = jnp.einsum("bkgts,bksd->bkgtd", pa, vd).reshape(B, H, T, Dh)
-        return finish(x, attn, p), (pk, pv)
+        return (finish(x, attn, p), pk, pv), None
 
-    x, (ks, vs) = jax.lax.scan(
-        lambda c, lyr: body(c, lyr),
-        x,
-        (params["blocks"], pool["k"], pool["v"]),
-    )
-    pool = {"k": ks, "v": vs}
+    x, pool = _scan_layers(body, x, params, pool)
     D = x.shape[-1]
     logits = final(params, x.reshape(B * T, D)).reshape(B, T, -1)
     return pool, logits
@@ -298,34 +316,25 @@ def paged_decode(
     rows = jnp.arange(B)
     bids = tables[rows, positions // block_size]  # [B]
     offs = positions % block_size
-    khi = jnp.arange(KH)
     cols = jnp.arange(S)
     mask = cols[None, :] <= positions[:, None]  # [B, S]
     scale = 1.0 / (Dh**0.5)
 
-    def body(x, layer):
-        p, pk, pv = layer  # [N, KH, block, Dh]
+    def body(carry, layer):
+        x, pk, pv = carry  # pk/pv: the whole pool, [L, N, KH, block, Dh]
+        p, l = layer
         q, k, v = qkv(x, p, positions[:, None])  # [B,{H,KH},1,Dh]
-        pk = pk.at[bids[:, None], khi[None, :], offs[:, None]].set(
-            k[:, :, 0, :]
-        )
-        pv = pv.at[bids[:, None], khi[None, :], offs[:, None]].set(
-            v[:, :, 0, :]
-        )
-        kd = pk[tables].transpose(0, 2, 1, 3, 4).reshape(B, KH, S, Dh)
-        vd = pv[tables].transpose(0, 2, 1, 3, 4).reshape(B, KH, S, Dh)
+        pk, kd = _write_read(pk, l, bids, offs, k[:, :, 0, :], tables)
+        pv, vd = _write_read(pv, l, bids, offs, v[:, :, 0, :], tables)
+        kd = kd.transpose(0, 2, 1, 3, 4).reshape(B, KH, S, Dh)
+        vd = vd.transpose(0, 2, 1, 3, 4).reshape(B, KH, S, Dh)
         qg = q[:, :, 0, :].reshape(B, KH, group, Dh)
         s = jnp.einsum("bkgd,bksd->bkgs", qg, kd).astype(jnp.float32) * scale
         s = jnp.where(mask[:, None, None, :], s, -1e30)
         pa = jax.nn.softmax(s, axis=-1).astype(vd.dtype)
         attn = jnp.einsum("bkgs,bksd->bkgd", pa, vd).reshape(B, H, 1, Dh)
-        return finish(x, attn, p), (pk, pv)
+        return (finish(x, attn, p), pk, pv), None
 
-    x, (ks, vs) = jax.lax.scan(
-        lambda c, lyr: body(c, lyr),
-        x,
-        (params["blocks"], pool["k"], pool["v"]),
-    )
-    pool = {"k": ks, "v": vs}
+    x, pool = _scan_layers(body, x, params, pool)
     logits = final(params, x[:, 0, :])
     return pool, logits

@@ -9,6 +9,7 @@ more admitted requests at equal HBM for mixed-length workloads.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -369,3 +370,140 @@ def test_paged_prefix_pool_evicted_under_allocation_pressure():
     done = eng.pop_finished()
     assert len(done) == 1 and done[0].error is None
     assert len(eng._prefix_pool) < 2  # at least one entry was evicted
+
+
+# -- the pool is carried through the layer scan and donated -------------------
+
+
+def _scan_layers_as_before(body, x, params, pool):
+    """Reference for ``paged._scan_layers``: the pool as a scanned input
+    and a stacked output, each layer's slab scattered and gathered on its
+    own — what the three programs did before the pool rode in the carry."""
+
+    def step(x, layer):
+        p, pk, pv = layer  # one layer's slab, [N, KH, block, Dh]
+        (x, pk, pv), _ = body((x, pk[None], pv[None]), (p, jnp.int32(0)))
+        return x, (pk[0], pv[0])
+
+    x, (ks, vs) = jax.lax.scan(
+        step, x, (params["blocks"], pool["k"], pool["v"])
+    )
+    return x, {"k": ks, "v": vs}
+
+
+def _family_case(family):
+    """Three slots over a pool holding stale values: slots 0 and 1 share
+    prefix block 7 and own scattered blocks, slot 2 is free (table -> 0)."""
+    if family == "llama":
+        from ray_tpu.models import llama
+        from ray_tpu.models.llama import LlamaConfig
+
+        cfg = LlamaConfig.tiny(
+            n_layer=3, d_model=64, n_head=4, n_kv_head=2, max_seq=128
+        )
+        cfg = dataclasses.replace(cfg, dtype=jnp.float32)
+        params = llama.init_params(jax.random.key(0), cfg)
+    else:
+        cfg = tiny_cfg()
+        params = gpt2.init_params(jax.random.key(0), cfg)
+    bs = 8
+    shape = paged.init_block_pool(cfg, num_blocks=12, block_size=bs)["k"].shape
+    pool = {
+        "k": jax.random.normal(jax.random.key(2), shape, cfg.dtype),
+        "v": jax.random.normal(jax.random.key(3), shape, cfg.dtype),
+    }
+    tables = np.array([[7, 3, 9, 0], [7, 5, 2, 0], [0, 0, 0, 0]], np.int32)
+    positions = np.array([13, 17, 0], np.int32)
+    return cfg, params, bs, pool, tables, positions
+
+
+def _run_program(program, cfg, params, bs, pool, tables, positions):
+    """Jitted undonated, the way benchmarks/check.py calls them."""
+    fn = jax.jit(
+        functools.partial(getattr(paged, program), cfg=cfg, block_size=bs)
+    )
+    toks = jax.random.randint(jax.random.key(4), (3, 16), 0, cfg.vocab_size)
+    if program == "paged_decode":
+        return fn(params, toks[:, 0], jnp.asarray(positions),
+                  jnp.asarray(tables), pool)
+    if program == "paged_verify":
+        return fn(params, toks[:, :3], jnp.asarray(positions),
+                  jnp.asarray(tables), pool)
+    # Prefill slot 1's suffix behind the shared prefix block, then slot 0
+    # whole (start 0), in a 16 bucket: two calls chained through the pool.
+    i32 = lambda n: jnp.asarray(n, jnp.int32)  # noqa: E731
+    pool, a = fn(params, toks[1:2], i32(11), i32(8), jnp.asarray(tables[1]), pool)
+    pool, b = fn(params, toks[0:1], i32(14), i32(0), jnp.asarray(tables[0]), pool)
+    return pool, jnp.stack([a, b])
+
+
+@pytest.mark.parametrize("family", ["gpt2", "llama"])
+@pytest.mark.parametrize(
+    "program", ["paged_prefill", "paged_decode", "paged_verify"]
+)
+def test_carried_pool_equals_scanned_pool_bitwise(monkeypatch, family, program):
+    """Same mathematics, another buffer: logits and every pool row equal
+    the scanned-input / stacked-output form, and an undonated caller keeps
+    its input pool."""
+    case = _family_case(family)
+    pool = case[3]
+    before = jax.tree.map(np.asarray, pool)
+    new_pool, new_logits = _run_program(program, *case)
+    monkeypatch.setattr(paged, "_scan_layers", _scan_layers_as_before)
+    old_pool, old_logits = _run_program(program, *case)
+    np.testing.assert_array_equal(np.asarray(new_logits), np.asarray(old_logits))
+    for kv in ("k", "v"):
+        np.testing.assert_array_equal(
+            np.asarray(new_pool[kv]), np.asarray(old_pool[kv])
+        )
+        assert not pool[kv].is_deleted()
+        np.testing.assert_array_equal(np.asarray(pool[kv]), before[kv])
+        assert not np.array_equal(np.asarray(new_pool[kv]), before[kv])
+
+
+def _paged_engine():
+    return LLMEngine(
+        LLMConfig(
+            model_config=tiny_cfg(), max_slots=2, max_seq=64,
+            prefill_buckets=(16,), kv_block_size=16, seed=0,
+            enable_prefix_caching=False,
+        )
+    )
+
+
+def _prefill_then_decode(eng):
+    """One request that ends at its prefill, then one decoded to six
+    tokens; returns (tokens, pool held before the prefill, pool held
+    before a step that only decodes)."""
+    held_pf = eng.pool
+    eng.add_request("one", [5, 6, 7, 8], SamplingParams(max_tokens=1))
+    eng.step()
+    assert len(eng.pop_finished()[0].generated) == 1
+    assert eng.stats["tokens_generated"] == 1  # the prefill's; no decode ran
+    eng.add_request(
+        "two", [9] * 10, SamplingParams(max_tokens=6, temperature=0.0)
+    )
+    eng.step()
+    held_dec, n = eng.pool, eng.stats["tokens_generated"]
+    eng.step()  # nothing to admit: one decode step
+    assert eng.stats["tokens_generated"] == n + 1
+    while eng.has_unfinished():
+        eng.step()
+    return eng.pop_finished()[0].generated, held_pf, held_dec
+
+
+def test_engine_donates_the_pool_to_prefill_and_decode():
+    """The engine's two programs take the pool's buffer: what was
+    ``engine.pool`` before a prefill, and before a decode step, is deleted,
+    and the engine goes on to the tokens an undonated engine gives."""
+    eng = _paged_engine()
+    tokens, *held = _prefill_then_decode(eng)
+    assert all(p[kv].is_deleted() for p in held for kv in ("k", "v"))
+    assert not eng.pool["k"].is_deleted()
+
+    ref = _paged_engine()
+    ref._pg_prefill = jax.jit(ref._pg_prefill.__wrapped__)
+    ref._pg_decode = jax.jit(ref._pg_decode.__wrapped__)
+    ref_tokens, *held = _prefill_then_decode(ref)
+    assert not any(p[kv].is_deleted() for p in held for kv in ("k", "v"))
+    assert tokens == ref_tokens and len(tokens) == 6

@@ -34,9 +34,12 @@ MOSAIC = "tpu_custom_call"
 
 @pytest.fixture(scope="module")
 def v5e_2x2():
-    devices = topologies.get_topology_desc(
-        platform="tpu", topology_name="v5e:2x2"
-    ).devices
+    try:
+        devices = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        ).devices
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     assert len(devices) == 4 and devices[0].device_kind == "TPU v5 lite"
     return devices
 
@@ -143,3 +146,55 @@ def test_gpt2_125m_train_step_compiles_on_four_chips(v5e_2x2, spec):
     assert (
         memory.temp_size_in_bytes + memory.argument_size_in_bytes < 16e9
     ), "does not fit one v5e chip's 16 GB"
+
+
+@pytest.mark.parametrize("program", ["paged_prefill", "paged_decode"])
+def test_engine_paged_programs_write_the_pool_in_place(v5e_2x2, program):
+    """The engine's two paged programs, compiled for one chip: the pool is
+    carried through the layer scan and donated, so the output aliases the
+    input, nothing slab-sized is a temporary (the scan used to slice each
+    layer's slab out, copy it for the scatter and write it into a stacked
+    output), and the loop body holds no dynamic-update-slice of the pool."""
+    from ray_tpu.llm import LLMConfig, LLMEngine
+    from ray_tpu.models.llama import LlamaConfig
+
+    cfg = LlamaConfig.tiny(
+        n_layer=3, d_model=256, n_head=2, n_kv_head=1, max_seq=128
+    )
+    eng = LLMEngine(
+        LLMConfig(
+            model_config=cfg, max_slots=4, max_seq=128,
+            prefill_buckets=(32,), kv_block_size=16, num_kv_blocks=8193,
+            seed=0,
+        )
+    )
+    one = jax.sharding.SingleDeviceSharding(v5e_2x2[0])
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)  # noqa: E731
+    on_chip = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: sds(x.shape, x.dtype), tree
+    )
+    i32, W = jnp.int32, eng.block_tables.shape[1]
+    if program == "paged_prefill":
+        lowered = eng._pg_prefill.lower(
+            on_chip(eng.params), sds((1, 32), i32), sds((), i32),
+            sds((), i32), sds((W,), i32), on_chip(eng.pool),
+        )
+    else:
+        lowered = eng._pg_decode.lower(
+            on_chip(eng.params), sds((4,), i32), sds((4,), i32),
+            sds((4, W), i32), on_chip(eng.pool),
+        )
+    compiled = lowered.compile()
+    pool_k = eng.pool["k"]
+    pool_bytes = 2 * pool_k.nbytes
+    slab_bytes = pool_k.nbytes // cfg.n_layer
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 2 * slab_bytes
+    pool_shape = "[" + ",".join(map(str, pool_k.shape)) + "]"
+    # "%name = type[shape]{layout} op(": an instruction's name and result.
+    results = [ln.split("(", 1)[0] for ln in compiled.as_text().splitlines()]
+    assert any(pool_shape in r for r in results)  # the pattern can match
+    assert not [
+        r for r in results if "dynamic-update-slice" in r and pool_shape in r
+    ]
