@@ -1,18 +1,22 @@
-"""Compile the cells' real programs for a described v5e, with no chip.
+"""Compile a cell's real programs for a described v5e, with no chip.
 
-    JAX_PLATFORMS=cpu python benchmarks/aot_rehearsal.py [train] [serve]
+    JAX_PLATFORMS=cpu python benchmarks/aot_rehearsal.py --workload <cell> [<cell> ...]
 
-The third rehearsal of the on-chip-measurement guide: the GPT-2 XL train step
-on an ``fsdp=4`` mesh of a described ``v5e:2x2`` host, and the Mistral paged
-prefill (every bucket) and decode programs on one described chip, each with
-``memory_analysis()``. What the chip's compiler would refuse (a kernel it
+The third rehearsal of the on-chip-measurement guide. The cell's files are
+found by name and its model is built by its family's file, as in a run; the
+mix's ``kind`` picks what is compiled: the train step on the job's mesh over
+as many chips of a described ``v5e:2x2`` host as the cell asks for, or the
+paged prefill (every bucket) and decode programs on one described chip, each
+with ``memory_analysis()``. What the chip's compiler would refuse (a kernel it
 cannot partition, a program that does not fit) it refuses here, at no chip
-time. Nothing runs, so this gives no time and no result; PERF.md quotes the
-memory analysis as what decided the depth cut.
+time, and the bytes say whether a new cell reaches a quarter of a chip's
+memory before it asks for the chip. Nothing runs, so this gives no time and
+no result; PERF.md quotes the memory analysis as what decided the depth cut.
 """
 
 from __future__ import annotations
 
+import argparse
 import functools
 import os
 import sys
@@ -27,7 +31,7 @@ import jax.numpy as jnp  # noqa: E402
 from jax.experimental import topologies  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding  # noqa: E402
 
-from benchmarks import harness, model_build  # noqa: E402
+from benchmarks import harness  # noqa: E402
 
 
 def _analysis(compiled) -> dict:
@@ -35,20 +39,22 @@ def _analysis(compiled) -> dict:
     return {k: getattr(m, k + "_size_in_bytes") for k in ("temp", "argument", "output", "alias")}
 
 
-def train(topo) -> None:
-    from ray_tpu.models import gpt2
+def _nbytes(tree) -> int:
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+
+def train(cell: dict, c: dict, job: dict, devices) -> None:
     from ray_tpu.parallel import DEFAULT_RULES, MeshSpec, make_mesh, shardings_from_logical
     from ray_tpu.train.spmd import default_optimizer, make_train_step
 
     from benchmarks.train_driver import optimizer_shardings
 
-    cell = harness.cell("train-gpt2xl-fsdp4")
-    c, job = harness.config_of(cell), harness.traffic_of(cell)
-    cfg = model_build.gpt2_config(c, job)
-    mesh = make_mesh(MeshSpec(**job["mesh"]), topo.devices)
-    shardings = shardings_from_logical(gpt2.param_logical_specs(cfg), DEFAULT_RULES, mesh)
+    fam = harness.family(c)
+    cfg = fam.model_config(c, job)
+    mesh = make_mesh(MeshSpec(**job["mesh"]), devices[: cell["chips"]])
+    shardings = shardings_from_logical(fam.param_logical_specs(cfg), DEFAULT_RULES, mesh)
     opt = default_optimizer(**{k: v for k, v in job["optimizer"].items() if k != "name"})
-    params = jax.eval_shape(lambda k: gpt2.init_params(k, cfg), jax.random.key(0))
+    params = jax.eval_shape(lambda k: fam.init_params(k, cfg), jax.random.key(0))
     params = jax.tree.map(
         lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s), params, shardings
     )
@@ -66,41 +72,40 @@ def train(topo) -> None:
     bsh = NamedSharding(mesh, P(("dp", "fsdp")))
     tok = jax.ShapeDtypeStruct((job["global_batch"], job["seq_len"]), jnp.int32, sharding=bsh)
     step = make_train_step(
-        lambda p, b: gpt2.loss_fn(p, b, cfg, mesh=mesh), opt,
+        lambda p, b: fam.loss_fn(p, b, cfg, mesh=mesh), opt,
         mesh=mesh, batch_spec=P(("dp", "fsdp")), param_shardings=shardings,
     )
     t = time.time()
     compiled = step.lower(state, {"tokens": tok, "targets": tok}).compile()
     text = compiled.as_text()
-    print(f"train step gpt2-xl fsdp=4 B={job['global_batch']} S={job['seq_len']} "
-          f"remat={job['remat']}: compiled in {time.time() - t:.0f}s; per device "
+    print(f"{cell['name']}: train step of {c['name']} on {job['mesh']} B={job['global_batch']} "
+          f"S={job['seq_len']} remat={job['remat']}: parameters {_nbytes(params)} B in all; "
+          f"compiled in {time.time() - t:.0f}s; per device "
           f"{_analysis(compiled)}; tpu_custom_call x{text.count('tpu_custom_call')}; "
           f"all-gather x{text.count('all-gather(') + text.count('all-gather-start(')}, "
           f"reduce-scatter x{text.count('reduce-scatter(')}, all-reduce x{text.count('all-reduce(') + text.count('all-reduce-start(')}",
           flush=True)
 
 
-def serve(topo) -> None:
-    from ray_tpu.models import llama, paged
+def serve(cell: dict, c: dict, mix: dict, devices) -> None:
+    from ray_tpu.models import paged
 
-    cell = harness.cell("serve-code-mistral7b")
-    c, mix = harness.config_of(cell), harness.traffic_of(cell)
+    fam = harness.family(c)
     e = mix["engine"]
-    cfg = model_build.llama_config(c, e["max_seq"])
-    one = SingleDeviceSharding(topo.devices[0])
+    cfg = fam.model_config(c, mix)
+    one = SingleDeviceSharding(devices[0])
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)  # noqa: E731
     params = jax.tree.map(
         lambda x: sds(x.shape, x.dtype),
-        jax.eval_shape(lambda k: llama.init_params(k, cfg), jax.random.key(0)),
+        jax.eval_shape(lambda k: fam.init_params(k, cfg), jax.random.key(0)),
     )
     bs, N, B, W = e["kv_block_size"], e["num_kv_blocks"], e["max_slots"], e["max_seq"] // e["kv_block_size"]
     pool = jax.tree.map(
         lambda x: sds(x.shape, x.dtype),
         jax.eval_shape(lambda: paged.init_block_pool(cfg, N, bs)),
     )
-    nbytes = lambda tree: sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))  # noqa: E731
-    print(f"mistral-7b-v0.3 at {cfg.n_layer} layers: weights {nbytes(params)} B, "
-          f"KV pool of {N} blocks {nbytes(pool)} B", flush=True)
+    print(f"{cell['name']}: {c['name']} as served: weights {_nbytes(params)} B, "
+          f"KV pool of {N} blocks {_nbytes(pool)} B", flush=True)
     i32 = jnp.int32
     decode = jax.jit(functools.partial(paged.paged_decode, cfg=cfg, block_size=bs))
     t = time.time()
@@ -115,11 +120,23 @@ def serve(topo) -> None:
         print(f"paged_prefill T={T}: compiled in {time.time() - t:.0f}s; {_analysis(compiled)}", flush=True)
 
 
-if __name__ == "__main__":
+KINDS = {"open-loop": serve, "closed-loop": serve, "train": train}
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True, nargs="+", help="cells of BENCHMARK.json")
+    args = ap.parse_args()
     jax.config.update("jax_enable_compilation_cache", False)
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    what = sys.argv[1:] or ["serve", "train"]
-    if "serve" in what:
-        serve(topo)
-    if "train" in what:
-        train(topo)
+    for name in args.workload:
+        cell = harness.cell(name)
+        config, traffic = harness.config_of(cell), harness.traffic_of(cell)
+        if traffic["kind"] not in KINDS:
+            raise SystemExit(f"traffic kind {traffic['kind']!r} has nothing to compile here")
+        KINDS[traffic["kind"]](cell, config, traffic, topo.devices)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -18,6 +18,7 @@ T_PROCESS_START = time.time()  # imported first thing by run.py
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 _T0 = time.monotonic()
+MARK = "RAYTPU_BENCH_RUN_MARK"  # in the environment of every process of a run
 
 
 def log(msg: str) -> None:
@@ -79,15 +80,29 @@ def metrics_of(cell_name: str, group: str) -> list:
     ]
 
 
-def reader(group_dir: str, name: str):
-    """``benchmarks/<group_dir>/<name>.py``, which holds ``read(records)``."""
+def _module(group_dir: str, name: str):
+    """``benchmarks/<group_dir>/<name>.py`` as a module, found by name."""
     path = os.path.join(HERE, group_dir, name + ".py")
+    if not os.path.exists(path):
+        raise SystemExit(f"no file {path}")
     spec = importlib.util.spec_from_file_location(
         f"benchmarks.{group_dir}.{name.replace('.', '_').replace('-', '_')}", path
     )
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(group_dir: str, name: str):
+    """``benchmarks/<group_dir>/<name>.py``, which holds ``read(records)``."""
+    return _module(group_dir, name).read
+
+
+def family(config: dict):
+    """``benchmarks/families/<config["family"]>.py``: the builder, the check,
+    the rehearsal sizes and the operation counts of the configuration's
+    family, as far as its cells need them (README.md lists the interface)."""
+    return _module("families", config["family"])
 
 
 def read_metrics(cell_name: str, trace: bool, records: dict) -> dict:
@@ -110,11 +125,20 @@ def read_metrics(cell_name: str, trace: bool, records: dict) -> dict:
 
 def prepare_environment(out_dir: str, rehearsal_chips: int) -> None:
     """Before ``ray_tpu`` or ``jax`` is imported. Workers inherit it all."""
+    # While a worker opens its chips the node in this process sends the GCS
+    # in this process no heartbeat for 2.4-9.0 s in a serve cell and up to
+    # 11 s in the four-chip cell (every run's flight-recorder dump, my chip
+    # runs, PR 28). Past the default 10 s the GCS declares its own node dead
+    # and every actor on it: the train cell shrugs that off, a serve cell
+    # waits for a deployment that never comes up until the run is cut. One
+    # host has no node to lose, so the run gives the silence two minutes.
+    os.environ.setdefault("RAY_TPU_NODE_DEATH_TIMEOUT_S", "120")
     from ray_tpu.util.compile_cache import ensure_compile_cache
 
     os.environ["PYTHONPATH"] = os.pathsep.join(
         [ROOT, *filter(None, [os.environ.get("PYTHONPATH")])]
     )
+    os.environ[MARK] = f"{os.getpid()}.{time.time_ns()}"
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     os.environ["RAY_TPU_FLIGHTREC_DUMP_DIR"] = os.path.join(out_dir, "flightrec_dumps")
     os.environ.setdefault("RAY_TPU_FLIGHTREC_RING_SIZE", "65536")
@@ -140,7 +164,10 @@ def start_runtime(chips: int, rehearsal_chips: int):
     else:
         ray_tpu.init()
     have = int(ray_tpu.cluster_resources().get("TPU", 0))
-    log(f"runtime up, {have} TPU chip(s) advertised")
+    from ray_tpu.core.config import GLOBAL_CONFIG
+
+    log(f"runtime up, {have} TPU chip(s) advertised; a node is dead after "
+        f"{GLOBAL_CONFIG.node_death_timeout_s:.0f} s of silence")
     if have < chips:
         ray_tpu.shutdown()
         raise SystemExit(f"this host advertises {have} TPU chip(s); the cell needs {chips}")
@@ -184,14 +211,9 @@ def save(out_dir: str, name: str, obj) -> None:
 
 def shrink_for_rehearsal(config: dict, traffic: dict) -> tuple:
     """Tiny sizes for a run on the CPU that only debugs the benchmark's own
-    code (``--cpu-rehearsal``); by family and kind, never by name."""
-    c, t = dict(config), json.loads(json.dumps(traffic))
-    if c["family"] == "llama":
-        c.update(hidden_size=128, intermediate_size=256, num_attention_heads=4,
-                 num_key_value_heads=2, head_dim=32, num_hidden_layers=2, vocab_size=512)
-    elif c["family"] == "gpt2":
-        c.update(n_embd=128, n_head=4, n_layer=2, n_positions=128, n_ctx=128)
-        c["assumed"] = {**c["assumed"], "padded_vocab_size": 512}
+    code (``--cpu-rehearsal``): the configuration by its family's file, the
+    mix by its kind, never by name."""
+    c, t = family(config).shrink(config), json.loads(json.dumps(traffic))
     if t["kind"] == "train":
         t.update(seq_len=128, global_batch=4, attn_impl="reference")
         t["trace_window"] = {"seconds": 1.0}
@@ -285,29 +307,80 @@ def _ended(pid: int, table: dict) -> bool:
     return state == "Z" and threads <= 1
 
 
-def wait_until_ended(pids: set, patience_s: float = 90.0) -> None:
+def marked() -> set:
+    """Every other process that carries this run's mark in its environment
+    (``prepare_environment`` sets it, and whatever the run starts inherits
+    it): also one whose parent has died, which ``descendants`` cannot see."""
+    want = f"{MARK}={os.environ.get(MARK)}".encode()
+    found = set()
+    for name in os.listdir("/proc"):
+        if not name.isdigit() or int(name) == os.getpid():
+            continue
+        try:
+            with open(f"/proc/{name}/environ", "rb") as f:
+                if want in f.read().split(b"\0"):
+                    found.add(int(name))
+        except OSError:
+            continue  # gone, or not ours to read
+    return found
+
+
+def run_processes() -> set:
+    """Every process of this run that still runs, but this one."""
+    return descendants() | marked()
+
+
+def _cmdline(pid: int) -> str:
+    try:
+        with open(f"/proc/{pid}/cmdline", "rb") as f:
+            return f.read().replace(b"\0", b" ").decode(errors="replace").strip()[:160]
+    except OSError:
+        return "?"
+
+
+def wait_until_ended(pids: set, patience_s: float = 90.0, more=None) -> None:
     """Wait until each of ``pids`` has ended. A zombie whose threads still
     end has not: a killed worker that held four chips stays one for some
     15 s while its mappings are taken apart on three cores, and a process
     started meanwhile imports jax in 10 s instead of 3 (my chip runs, PR
     25), so the next run's set-up paid for this run's end. The next process
     to open the chip must not meet the old one there either. What outlives
-    the patience is killed and waited for."""
+    the patience is killed and waited for. ``more`` is asked on every turn
+    for processes that were not in ``pids``: one started while the runtime
+    stopped, or one that lost its parent before ``pids`` was taken. Nothing
+    stops those any more, so they are killed at once, named in the log, and
+    waited for like the others."""
     import signal
 
     t = time.time()
-    killed = False
+    pids, late, killed = set(pids), set(), set()
     while True:
+        if more is not None:
+            new = more() - pids - late
+            for p in new:
+                log(f"process {p} was not stopped with the runtime: {_cmdline(p)}")
+            late |= new
         table = _proc_table()
-        alive = {p for p in pids if not _ended(p, table)}
+        alive = {p for p in pids | late if not _ended(p, table)}
         if not alive:
             break
-        if time.time() - t > patience_s and not killed:
-            for p in alive:
-                try:
-                    os.kill(p, signal.SIGKILL)
-                except OSError:
-                    pass
-            killed = True
+        overdue = alive if time.time() - t > patience_s else alive & late
+        for p in overdue - killed:
+            try:
+                os.kill(p, signal.SIGKILL)
+            except OSError:
+                pass
+        killed |= overdue
+        if time.time() - t > patience_s + 120.0:
+            log(f"gave up waiting for {sorted(alive)}: killed and still there")
+            break
         time.sleep(0.25)
-    log(f"{len(pids)} process(es) of the runtime ended {time.time() - t:.1f}s after it stopped")
+    if pids or late:
+        log(f"{len(pids | late)} process(es) of the run ended {time.time() - t:.1f}s "
+            f"after the runtime stopped ({len(late)} of them killed here)")
+
+
+def end_run() -> None:
+    """The last thing a run does, on every path out of it: whatever it
+    started and has not seen end is killed and waited for."""
+    wait_until_ended(set(), patience_s=0.0, more=run_processes)

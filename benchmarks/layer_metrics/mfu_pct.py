@@ -1,6 +1,6 @@
-"""Model FLOP/s utilisation: the operations forward and backward require per token (flops_bytes.py; recomputation not counted) times tokens per second, over chips times the bf16 peak."""
+"""Model FLOP/s utilisation: the operations forward and backward require per token (the family's train_flops_per_token; recomputation not counted) times tokens per second, over chips times the bf16 peak."""
 
-from benchmarks import flops_bytes
+from benchmarks import harness
 
 
 def read(records):
@@ -9,9 +9,7 @@ def read(records):
     t = records["train"]
     t0, t1 = records["window"]
     c, job = records["config"], records["traffic"]
-    per_token = flops_bytes.gpt2_train_flops_per_token(
-        c, job["seq_len"], c["assumed"]["padded_vocab_size"]
-    )
+    per_token = harness.family(c).train_flops_per_token(c, job)
     rate = t["steps"] * t["tokens_per_step"] / (t1 - t0)
     peak = t["device"]["count"] * records["peaks"]["bf16_flops_per_s"]
     return 100.0 * per_token * rate / peak, "%"

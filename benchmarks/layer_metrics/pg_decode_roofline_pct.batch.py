@@ -1,6 +1,6 @@
-"""The paged decode program against its roofline: the bytes (and operations) a decode step needs, from flops_bytes.py, over the chip's peaks, over the program's device time in the trace. Memory-bound at these batch sizes."""
+"""The paged decode program against its roofline: the bytes (and operations) a decode step needs, from the family's decode_step, over the chip's peaks, over the program's device time in the trace. Memory-bound at these batch sizes."""
 
-from benchmarks import flops_bytes, stats, trace_reduce
+from benchmarks import flops_bytes, harness, stats, trace_reduce
 
 
 def read(records):
@@ -25,6 +25,6 @@ def read(records):
         for r in records["requests"]
         for k, t in enumerate(r["tokens"]) if t0 <= t < t1
     ) / len(steps)
-    ops, nbytes = flops_bytes.llama_decode_step(records["config"], batch, context)
+    ops, nbytes = harness.family(records["config"]).decode_step(records["config"], batch, context)
     share, _bound = flops_bytes.roofline_pct(ops, nbytes, sum(runs) / len(runs), records["peaks"])
     return share, "%"

@@ -1,4 +1,4 @@
-"""Share of the engine's device time that goes to prefill: device seconds of the prefill programs over those of the prefill and decode programs, in the traced window. (The llm.prefill span itself times only the dispatch: JAX returns before the device is done.)"""
+"""Share of the engine's device time that goes to prefill: device seconds of the prefill programs over those of the prefill and decode programs, in the traced window. (Device time, not the llm.prefill span: since PR 26 the span ends where the host has the logits, so it also holds the launch and the copy back; prefill_span_ms_p50 reads it.)"""
 
 from benchmarks import trace_reduce
 

@@ -49,8 +49,19 @@ def main() -> int:
     if kind not in DRIVERS:
         raise SystemExit(f"traffic kind {kind!r} has no driver")
     import importlib
+    import signal
 
-    return importlib.import_module(f"benchmarks.{DRIVERS[kind]}").run(cell, args, out_dir)
+    def cut(signum, _frame):  # told to stop: leave nothing behind, print no result
+        signal.signal(signum, signal.SIG_IGN)  # `timeout` signals the child and its group
+        harness.log(f"signal {signum}: ending the run's processes")
+        harness.end_run()
+        os._exit(128 + signum)
+
+    signal.signal(signal.SIGTERM, cut)
+    try:
+        return importlib.import_module(f"benchmarks.{DRIVERS[kind]}").run(cell, args, out_dir)
+    finally:
+        harness.end_run()
 
 
 if __name__ == "__main__":

@@ -206,12 +206,14 @@ def teardown(ray_tpu) -> None:
     started has ended: only then is the chip free for the next process."""
     from ray_tpu.serve import api as serve
 
-    started = harness.descendants()
+    started = harness.run_processes()
     try:
         serve.shutdown()
     finally:
-        ray_tpu.shutdown()
-    harness.wait_until_ended(started)
+        try:
+            ray_tpu.shutdown()
+        finally:
+            harness.wait_until_ended(started, more=harness.run_processes)
 
 
 def run(cell: dict, args, out_dir: str) -> int:

@@ -1,5 +1,6 @@
 """A run waits for the processes it started until they have really ended."""
 
+import os
 import subprocess
 import sys
 import time
@@ -66,3 +67,80 @@ def test_an_ended_process_is_not_waited_for():
     harness.wait_until_ended({child.pid})  # a zombie with no thread left
     assert time.time() - t < 1.0
     child.wait()
+
+
+def _alive(pid: int) -> bool:
+    table = harness._proc_table()
+    return pid in table and table[pid][1] != "Z"
+
+
+def test_a_process_that_lost_its_parent_is_found_by_the_mark_and_ended(monkeypatch):
+    """``descendants`` cannot see a process whose parent has died; the mark
+    in its environment can, and ``end_run`` kills it and waits for it."""
+    monkeypatch.setenv(harness.MARK, "test-mark-1")
+    outer = subprocess.Popen(
+        [sys.executable, "-c",
+         "import subprocess, sys\n"
+         "c = subprocess.Popen([sys.executable, '-c', 'import time; time.sleep(60)'],\n"
+         "                     start_new_session=True)\n"
+         "print(c.pid, flush=True)"],
+        stdout=subprocess.PIPE,
+    )
+    orphan = int(outer.stdout.readline())
+    outer.wait()
+    try:
+        assert orphan not in harness.descendants()
+        assert orphan in harness.marked() and orphan in harness.run_processes()
+        monkeypatch.setenv(harness.MARK, "another-run")
+        assert orphan not in harness.marked()
+        monkeypatch.setenv(harness.MARK, "test-mark-1")
+        t = time.time()
+        harness.end_run()
+        assert time.time() - t < 10.0
+        assert not _alive(orphan)
+    finally:
+        if _alive(orphan):
+            os.kill(orphan, 9)
+
+
+def test_a_process_started_after_the_list_was_taken_is_killed_without_patience():
+    first = subprocess.Popen([sys.executable, "-c", "import time; time.sleep(0.5)"])
+    listed = harness.descendants()
+    late = subprocess.Popen([sys.executable, "-c", "import time; time.sleep(60)"])
+    t = time.time()
+    harness.wait_until_ended(listed, patience_s=30.0, more=harness.descendants)
+    assert time.time() - t < 10.0  # `first` ended by itself; `late` got no patience
+    assert not _alive(first.pid) and not _alive(late.pid)
+
+
+def test_a_run_that_is_told_to_stop_leaves_no_process(tmp_path):
+    """SIGTERM in the middle of a serve cell's rehearsal: the replica, the
+    proxy and the controller are ended before the run is, with no result.
+    (The driver's check of PR 28 met a run whose deployment never came up:
+    cut at its time limit, it left its workers behind.)"""
+    import signal
+
+    root = os.path.dirname(harness.HERE)
+    cell = next(w["name"] for w in harness.benchmark()["workloads"]
+                if harness.traffic_of(w)["kind"] != "train")
+    run = subprocess.Popen(
+        [sys.executable, os.path.join(harness.HERE, "run.py"), "--workload", cell, "--seed", "11", "--seconds", "60", "--trace", "0",
+         "--cpu-rehearsal", "--out", str(tmp_path)],
+        cwd=root, stdout=subprocess.PIPE, stderr=open(tmp_path / "err", "w"),
+    )
+
+    def children():
+        return {p for p, row in harness._proc_table().items() if row[0] == run.pid}
+
+    deadline = time.time() + 90
+    while len(children()) < 2 and time.time() < deadline:
+        time.sleep(0.2)
+    started = children()
+    assert len(started) >= 2
+    run.send_signal(signal.SIGTERM)
+    out, _ = run.communicate(timeout=60)
+    assert run.returncode == 128 + signal.SIGTERM
+    assert not any(line.startswith(b"{") for line in out.splitlines())
+    assert not any(_alive(p) for p in started)
+    # and the runtime was told not to take a worker's slow start for a dead node
+    assert "a node is dead after 120 s of silence" in (tmp_path / "err").read_text()

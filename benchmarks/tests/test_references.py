@@ -15,9 +15,9 @@ def _tiny(cell_name):
 @pytest.mark.parametrize("seed", [1, 2**31 + 7, 3000000011])
 def test_llama_program_agrees_and_the_controls_do_not(seed):
     c, mix = _tiny("serve-code-mistral7b")
-    program = check.check_llama(c, mix["engine"], seed, "program")["logits_rel_err"]
-    fp8 = check.check_llama(c, mix["engine"], seed, "fp8")["logits_rel_err"]
-    displaced = check.check_llama(c, mix["engine"], seed, "displaced")["logits_rel_err"]
+    program = check.check_one(c, mix, seed, "program")["logits_rel_err"]
+    fp8 = check.check_one(c, mix, seed, "fp8")["logits_rel_err"]
+    displaced = check.check_one(c, mix, seed, "displaced")["logits_rel_err"]
     assert program < 0.02
     assert fp8 > 3 * program
     assert displaced > 10 * program
@@ -26,8 +26,8 @@ def test_llama_program_agrees_and_the_controls_do_not(seed):
 @pytest.mark.parametrize("seed", [1, 3000000012])
 def test_gpt2_program_agrees_and_the_control_does_not(seed):
     c, job = _tiny("train-gpt2xl-fsdp4")
-    program = check.check_gpt2(c, job, seed, "program")
-    fp8 = check.check_gpt2(c, job, seed, "fp8")
+    program = check.check_one(c, job, seed, "program")
+    fp8 = check.check_one(c, job, seed, "fp8")
     assert program["grad_rel_err"] < 0.03 and program["logits_rel_err"] < 0.03
     assert fp8["grad_rel_err"] > 3 * program["grad_rel_err"]
     assert fp8["logits_rel_err"] > 3 * program["logits_rel_err"]
@@ -39,7 +39,6 @@ def test_llama_reference_is_causal_and_matches_the_programs_forward_in_float32()
     import jax.numpy as jnp
     import numpy as np
 
-    from benchmarks import model_build
     from benchmarks.reference import llama_ref
     from ray_tpu.models import llama
 
@@ -48,7 +47,7 @@ def test_llama_reference_is_causal_and_matches_the_programs_forward_in_float32()
     w = llama_ref.init_weights(3, c)
     tokens = np.random.default_rng(3).integers(0, c["vocab_size"], (2, 40)).astype(np.int32)
     ref = llama_ref.forward(w, jnp.asarray(tokens), c)
-    cfg = model_build.llama_config(c, 64)
+    cfg = harness.family(c).model_config(c, {"engine": {"max_seq": 64}})
     import dataclasses
     with jax.default_matmul_precision("highest"):
         got = llama.forward(w, jnp.asarray(tokens), dataclasses.replace(cfg, attn_impl="reference"))

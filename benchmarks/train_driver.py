@@ -65,10 +65,9 @@ def _train_loop(run: dict) -> None:
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from benchmarks import check, model_build, traffic_gen
+    from benchmarks import traffic_gen
     from benchmarks.reference.common import fold
     from ray_tpu import train
-    from ray_tpu.models import gpt2
     from ray_tpu.parallel import DEFAULT_RULES, MeshSpec, make_mesh, shardings_from_logical
     from ray_tpu.train.input import DevicePrefetchIterator
     from ray_tpu.train.spmd import (
@@ -77,16 +76,17 @@ def _train_loop(run: dict) -> None:
 
     c, job, seed = run["config"], run["job"], run["seed"]
     devices = jax.devices()
-    cfg = model_build.gpt2_config(c, job)
+    fam = harness.family(c)
+    cfg = fam.model_config(c, job)
     mesh = make_mesh(MeshSpec(**job["mesh"]), devices)
-    shardings = shardings_from_logical(gpt2.param_logical_specs(cfg), DEFAULT_RULES, mesh)
+    shardings = shardings_from_logical(fam.param_logical_specs(cfg), DEFAULT_RULES, mesh)
     opt_kw = {k: v for k, v in job["optimizer"].items() if k != "name"}
     assert job["optimizer"]["name"] == "adamw"
     opt = default_optimizer(**opt_kw)
-    state = sharded_train_state(lambda k: gpt2.init_params(k, cfg), opt, fold(seed), shardings, mesh)
+    state = sharded_train_state(lambda k: fam.init_params(k, cfg), opt, fold(seed), shardings, mesh)
     batch_sharding = NamedSharding(mesh, P(("dp", "fsdp")))
     step = make_train_step(
-        lambda p, b: gpt2.loss_fn(p, b, cfg, mesh=mesh), opt,
+        lambda p, b: fam.loss_fn(p, b, cfg, mesh=mesh), opt,
         mesh=mesh, batch_spec=P(("dp", "fsdp")), param_shardings=shardings,
         donate_state=devices[0].platform != "cpu",
     )
@@ -153,7 +153,7 @@ def _train_loop(run: dict) -> None:
     # The output check, with the state gone: the loss function's loss,
     # gradients and logits against the float32 reference on a seeded batch.
     del state, metrics
-    report["check"] = check.check_gpt2(c, job, seed, "program", devices)
+    report["check"] = fam.check(c, job, seed, "program", devices)
     report["compile_events_after_check"] = len(compiles.events)
     train.report({"bench": json.dumps(report)})
 
@@ -181,9 +181,11 @@ def run(cell: dict, args, out_dir: str) -> int:
             ),
         ).fit()
     finally:
-        started = harness.descendants()
-        ray_tpu.shutdown()
-        harness.wait_until_ended(started)
+        started = harness.run_processes()
+        try:
+            ray_tpu.shutdown()
+        finally:
+            harness.wait_until_ended(started, more=harness.run_processes)
     history = result.metrics_history
     rep = json.loads(history[-1]["bench"])
     harness.check_device(rep["device"], cell["chips"], args.cpu_rehearsal)
