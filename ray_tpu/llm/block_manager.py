@@ -7,6 +7,13 @@ bookkeeping — nothing here touches an array.
 
 Block 0 is reserved as the scratch block: free slots and padded prefill
 tails write there, so it is never allocatable.
+
+A block is ``block_size`` positions of whatever the family caches by
+position: keys and values per head, or latent rows. What a family keeps per
+sequence and not per position (a recurrent state, a convolution tail) lives
+by slot beside the pool and is none of this class's business: it is sized by
+``max_slots``, reset by the prefill that starts a sequence, and so neither
+allocated nor freed.
 """
 
 from __future__ import annotations

@@ -26,7 +26,10 @@ class LLMConfig:
     # None -> GPT2Config.gpt2_125m(); tests pass a tiny config.
     model_config: Any = None
     # Serving shape
-    max_slots: int = 16  # concurrent sequences (continuous-batching slots)
+    # Concurrent sequences (continuous-batching slots). For a family that
+    # keeps a recurrent state per sequence (models/paged.py, "What a pool
+    # is now") it also sizes that state: max_slots + 1 rows of it a layer.
+    max_slots: int = 16
     max_seq: int = 2048  # cache length (prompt + generation)
     prefill_buckets: tuple = (32, 64, 128, 256, 512, 1024, 2048)
     # Paged KV cache (reference: the block/gpu-memory knobs vLLM exposes,
@@ -36,6 +39,12 @@ class LLMConfig:
     # requests stop paying max_seq-sized slot rows. 0 -> legacy dense
     # per-slot cache. num_kv_blocks None -> half the dense-equivalent
     # (2x oversubscription), floored at one max-length request + 1.
+    # What a block holds is the family's business: keys and values per
+    # head, or latent rows (one [kv_lora_rank + rope] row a token, no head
+    # axis) beside a state per slot that no block holds. A family with
+    # such a state is served paged only, and without the prefix cache,
+    # speculative decoding, tensor parallelism and the disaggregated
+    # handoff: the engine says so by name at construction.
     kv_block_size: int = 16
     num_kv_blocks: Optional[int] = None
     # Parallelism: tensor-parallel degree (mesh `tp` axis over local devices)

@@ -116,15 +116,44 @@ def _model_ops(cfg):
     """(model_module, decode_module) for a model-family config — the ONE
     dispatch point; everything else in the engine is family-agnostic
     (the cache pytree layouts agree: [L, B, heads, S, Dh])."""
-    from ray_tpu.models.llama import LlamaConfig
-
-    if isinstance(cfg, LlamaConfig):
+    if cfg.family == "llama":
         from ray_tpu.models import llama, llama_decode
 
         return llama, llama_decode
+    if cfg.family == "kimi_linear":
+        from ray_tpu.models import kimi_linear
+
+        return kimi_linear, None  # served through the paged programs only
     from ray_tpu.models import gpt2_decode
 
     return gpt2, gpt2_decode
+
+
+def _has_recurrent_state(cfg, config: LLMConfig) -> bool:
+    """Whether the family keeps a recurrent state per slot beside its
+    blocks. What the engine cannot do for such a family is refused here, at
+    construction and by name, not as a shape error at the first request."""
+    from ray_tpu.models import paged
+
+    if not paged.has_recurrent_state(cfg):
+        return False
+    why = f"the family {cfg.family!r} keeps a recurrent state per slot"
+    if config.kv_block_size <= 0:
+        raise ValueError(
+            f"kv_block_size=0 (the dense cache): {why} and is served "
+            "through the paged programs only"
+        )
+    if config.spec_decode_tokens > 0:
+        raise ValueError(
+            f"spec_decode_tokens > 0 (speculative verification): {why}, "
+            "and rejected tokens cannot be taken back out of it"
+        )
+    if config.tensor_parallelism > 1:
+        raise ValueError(
+            f"tensor_parallelism > 1: {why}, and neither it nor the "
+            "family's experts have sharding rules yet"
+        )
+    return True
 
 
 @dataclasses.dataclass
@@ -177,6 +206,7 @@ class LLMEngine:
         if cfg.vocab_size < self.tokenizer.vocab_size:
             raise ValueError("model vocab smaller than tokenizer vocab")
         self.model_config = cfg
+        self._recurrent = _has_recurrent_state(cfg, config)
         self._model, self._decode_mod = _model_ops(cfg)
         devices = jax.devices()
         tp = config.tensor_parallelism
@@ -233,30 +263,68 @@ class LLMEngine:
                 (B * self._table_width) // 2, self._table_width + 1
             ) + 1  # +1: block 0 is scratch
             self.block_mgr = BlockManager(n)
-            self.pool = paged.init_block_pool(cfg, n, bs)
+            # A family with a recurrent state keeps one row of it a slot
+            # (and a scratch row) beside the blocks: max_slots sizes it.
+            self.pool = paged.init_block_pool(cfg, n, bs, B)
             self.block_tables = np.zeros((B, self._table_width), np.int32)
 
             # Functions with names of their own, not functools.partial: a
             # device trace then lists the programs as jit_paged_prefill /
             # jit_paged_decode and not as jit__unknown(<hash>). The pool is
-            # donated: the layer scan carries it and scatters in place, so
+            # donated: the programs carry it and scatter in place, so
             # the output is the input's buffer and no step copies 2 x
             # [L, N, KH, block, Dh]. Every call rebinds self.pool; the
             # array passed in is deleted and nothing may keep it.
-            def paged_prefill(params, tokens, length, start, table, pool):
-                return paged.paged_prefill(
-                    params, tokens, length, start, table, pool,
-                    cfg=cfg, block_size=bs,
-                )
+            if self._recurrent:
+                # The same two names for a family with a state per slot.
+                # Its programs take the slot (prefill) and the live slots
+                # (decode) as well, and every small operand of a call
+                # rides in ONE int32 array (``meta``), handed over as
+                # numpy: an upload costs the host 0.5-0.6 ms a piece, and
+                # at a 13 ms step three more of them were a tenth of the
+                # step and most of its run-to-run noise (PERF.md section
+                # 6, PR 29). The programs' counters are packed behind the
+                # logits, so that they ride the one read-back a step
+                # makes anyway (_take_counters unpacks them).
+                def paged_prefill(params, tokens, meta, pool):
+                    # meta [3 + W]: length, start, slot, the block table
+                    pool, logits, counts = paged.paged_prefill(
+                        params, tokens, meta[0], meta[1], meta[3:], pool,
+                        cfg=cfg, block_size=bs, slot=meta[2],
+                    )
+                    return pool, jnp.concatenate(
+                        [logits, counts.reshape(-1).astype(logits.dtype)]
+                    )
 
-            def paged_decode(params, last_tokens, positions, tables, pool):
-                return paged.paged_decode(
-                    params, last_tokens, positions, tables, pool,
-                    cfg=cfg, block_size=bs,
-                )
+                def paged_decode(params, meta, pool):
+                    # meta [B, 3 + W]: last token, position, live, table
+                    pool, logits, counts = paged.paged_decode(
+                        params, meta[:, 0], meta[:, 1], meta[:, 3:], pool,
+                        cfg=cfg, block_size=bs, live=meta[:, 2] > 0,
+                    )
+                    row = jnp.pad(
+                        counts.reshape(1, -1).astype(logits.dtype),
+                        ((0, 0), (0, logits.shape[1] - counts.size)),
+                    )
+                    return pool, jnp.concatenate([logits, row])
 
-            self._pg_prefill = jax.jit(paged_prefill, donate_argnums=5)
-            self._pg_decode = jax.jit(paged_decode, donate_argnums=4)
+                self._pg_prefill = jax.jit(paged_prefill, donate_argnums=3)
+                self._pg_decode = jax.jit(paged_decode, donate_argnums=2)
+            else:
+                def paged_prefill(params, tokens, length, start, table, pool):
+                    return paged.paged_prefill(
+                        params, tokens, length, start, table, pool,
+                        cfg=cfg, block_size=bs,
+                    )
+
+                def paged_decode(params, last_tokens, positions, tables, pool):
+                    return paged.paged_decode(
+                        params, last_tokens, positions, tables, pool,
+                        cfg=cfg, block_size=bs,
+                    )
+
+                self._pg_prefill = jax.jit(paged_prefill, donate_argnums=5)
+                self._pg_decode = jax.jit(paged_decode, donate_argnums=4)
         else:
             self.cache = self._decode_mod.init_kv_cache(cfg, B, S)
 
@@ -313,6 +381,16 @@ class LLMEngine:
             "spec_drafted": 0,
             "spec_accepted": 0,
         }
+        if self.paged:
+            for part, arr in self.pool.items():  # bytes of each cache part
+                self.stats[f"cache_bytes_{part}"] = int(arr.nbytes)
+        if self._recurrent:
+            # Prefills that began a sequence and so began from zero state,
+            # whatever the slot held; admissions that would have looked a
+            # prefix up and could not (a hit needs the state at the
+            # prefix's end: snapshots are not kept).
+            self.stats["state_resets"] = 0
+            self.stats["prefix_cache_bypassed"] = 0
         # Host-side slot state (numpy: mutated per step)
         self.positions = np.zeros(B, np.int32)  # next write position
         self.last_tokens = np.zeros(B, np.int32)
@@ -426,6 +504,12 @@ class LLMEngine:
         monotonic time the caller took the request in, where that was
         earlier than this call (the flight recorder's ``llm.queue`` span
         starts there)."""
+        if prefill_only and self._recurrent:
+            raise ValueError(
+                "prefill_only (the disaggregated KV export): the family "
+                f"{self.model_config.family!r} keeps a recurrent state per "
+                "slot, which a handoff of pool blocks does not carry"
+            )
         if prefill_only and not self.paged:
             raise ValueError(
                 "prefill_only requests need the paged KV cache "
@@ -473,6 +557,12 @@ class LLMEngine:
         fails, in which case admission falls back to the local, chunked
         when configured, prefill path). Counts neither requests_total nor
         prompt_tokens: the prefill replica already did."""
+        if self._recurrent:
+            raise ValueError(
+                "a disaggregated handoff (KV import): the family "
+                f"{self.model_config.family!r} keeps a recurrent state per "
+                "slot, which a handoff of pool blocks does not carry"
+            )
         sampling = sampling or SamplingParams()
         stop = (
             sampling.stop_token
@@ -519,8 +609,10 @@ class LLMEngine:
     def _find_prefix(self, prompt: list):
         """Longest pooled prefix of ``prompt``; returns (entry | None).
         Hits are verified against the stored tokens, so a hash collision
-        can never serve another prompt's KV."""
-        if not self.config.enable_prefix_caching:
+        can never serve another prompt's KV. A family with a recurrent
+        state is never served from the pool: a hit would need the state
+        at the prefix's end."""
+        if not self.config.enable_prefix_caching or self._recurrent:
             return None
         self.stats["prefix_lookups"] += 1
         chain = self._chain_hashes(prompt)
@@ -537,7 +629,7 @@ class LLMEngine:
         slot's cache rows out; paged mode just takes a reference on the
         request's first P/block blocks — sharing, not copying (the
         round-4 verdict's missing #1)."""
-        if not self.config.enable_prefix_caching:
+        if not self.config.enable_prefix_caching or self._recurrent:
             return
         p = self._aligned_prefix_len(len(prompt))
         if p < self.config.prefix_chunk or p > self.config.max_prefix_cache_tokens:
@@ -626,7 +718,7 @@ class LLMEngine:
             if logits is None:
                 return admit_finished
             T = len(req.prompt)
-            logits_np = np.asarray(logits)  # raylint: disable=RL101 -- admission sampling: first token sampled host-side from the last-logits readback
+            logits_np = self._take_counters(np.asarray(logits), req)  # raylint: disable=RL101 -- admission sampling: first token sampled host-side from the last-logits readback
             self._close_prefill_span(req)
             tok = self._sample(logits_np, req)
             if fr:
@@ -716,6 +808,41 @@ class LLMEngine:
             "llm", phase, t=t_pf, dur_s=_time.monotonic() - t_pf,
             rid=req.request_id, **extra,
         )
+
+    def _take_counters(self, out: np.ndarray, req: _Request) -> np.ndarray:
+        """The logits of a prefill whose read-back is ``out``. A family
+        with a state per slot packs its counters behind them (__init__):
+        they go onto the prefill span still open on ``req``."""
+        if not self._recurrent:
+            return out
+        V = self.model_config.vocab_size
+        if req.pf_open is not None:
+            phase, t_pf, extra = req.pf_open
+            extra = {
+                **extra,
+                **self._model.span_fields(
+                    self.model_config, out[V:], extra["tokens"], 1
+                ),
+            }
+            req.pf_open = (phase, t_pf, extra)
+        return out[:V]
+
+    def _run_prefill(self, toks, n: int, start: int, row, slot: int):
+        """Dispatch one paged prefill of ``n`` tokens from position
+        ``start`` into ``slot``; rebinds the donated pool and returns the
+        program's second output, still on the device."""
+        if self._recurrent:
+            if start == 0:  # begins from zero state, whatever the slot held
+                self.stats["state_resets"] += 1
+            meta = np.concatenate([[n, start, slot], row]).astype(np.int32)
+            args = (self.params, toks, meta)
+        else:
+            args = (
+                self.params, jnp.asarray(toks), jnp.asarray(n, jnp.int32),
+                jnp.asarray(start, jnp.int32), jnp.asarray(row),
+            )
+        self.pool, out = self._pg_prefill(*args, self.pool)
+        return out
 
     def _admit_handoff(self, req: _Request, slot: int) -> str:
         """Admit a disaggregated handoff: reserve blocks, pull the shipped
@@ -902,20 +1029,15 @@ class LLMEngine:
         if entry is not None:
             self.stats["prefix_hits"] += 1
             self.stats["prefix_tokens_reused"] += P
+        if self._recurrent and self.config.enable_prefix_caching:
+            self.stats["prefix_cache_bypassed"] += 1  # once an admission
         if self._chunks_feasible(P, T):
             self._begin_chunked_prefill(req, slot, P)
             return None
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :rem] = req.prompt[P:]
         t_pf = _time.monotonic()
-        self.pool, logits = self._pg_prefill(
-            self.params,
-            jnp.asarray(toks),
-            jnp.asarray(rem, jnp.int32),
-            jnp.asarray(P, jnp.int32),
-            jnp.asarray(row),
-            self.pool,
-        )
+        logits = self._run_prefill(toks, rem, P, row, slot)
         self.stats["prefill_tokens"] += rem
         self._open_prefill_span(
             req, "llm.prefill", t_pf, tokens=rem, reused=P, bucket=bucket
@@ -1096,13 +1218,8 @@ class LLMEngine:
         toks[0, :clen] = req.prompt[start : start + clen]
         t_pf = _time.monotonic()
         if self.paged:
-            self.pool, logits = self._pg_prefill(
-                self.params,
-                jnp.asarray(toks),
-                jnp.asarray(clen, jnp.int32),
-                jnp.asarray(start, jnp.int32),
-                jnp.asarray(self.block_tables[req.slot]),
-                self.pool,
+            logits = self._run_prefill(
+                toks, clen, start, self.block_tables[req.slot], req.slot
             )
         else:
             self.cache, logits = self._prefill_cont(
@@ -1150,7 +1267,7 @@ class LLMEngine:
             self._close_prefill_span(req)  # logits never read: the launch
             return []
         req.prefilling = False
-        logits_np = np.asarray(logits)  # raylint: disable=RL101 -- final-chunk sampling: first token sampled host-side from the chunk's last-logits
+        logits_np = self._take_counters(np.asarray(logits), req)  # raylint: disable=RL101 -- final-chunk sampling: first token sampled host-side from the chunk's last-logits
         self._close_prefill_span(req)
         tok = self._sample(logits_np, req)
         self._insert_prefix(
@@ -1230,7 +1347,20 @@ class LLMEngine:
         elif active:
             fr = _flightrec.on()
             t_dec = _time.monotonic()
-            if self.paged:
+            if self._recurrent:
+                # Slots that are free or still prefilling step on the
+                # scratch row of the state and are routed to no expert.
+                live = np.zeros(len(self._slot_req), np.int32)
+                live[[r.slot for r in active]] = 1
+                meta = np.concatenate(
+                    [
+                        self.last_tokens[:, None], self.positions[:, None],
+                        live[:, None], self.block_tables,
+                    ],
+                    axis=1,
+                )
+                self.pool, logits = self._pg_decode(self.params, meta, self.pool)
+            elif self.paged:
                 self.pool, logits = self._pg_decode(
                     self.params,
                     jnp.asarray(self.last_tokens),
@@ -1248,6 +1378,13 @@ class LLMEngine:
             t_disp = _time.monotonic() if fr else 0.0
             logits_np = np.asarray(logits)  # raylint: disable=RL101 -- the decode step's ONE intended sync: batched logits readback feeding host-side sampling
             t_read = _time.monotonic() if fr else 0.0
+            moe = {}
+            if self._recurrent:  # the counters' row behind the logits
+                if fr:
+                    moe = self._model.span_fields(
+                        self.model_config, logits_np[-1], len(active), len(active)
+                    )
+                logits_np = logits_np[:-1]
             now = _time.perf_counter()
             for req in active:
                 slot = req.slot
@@ -1283,7 +1420,7 @@ class LLMEngine:
                 )
                 _flightrec.record(
                     "llm", "llm.decode_step", t=t_dec,
-                    dur_s=t_end - t_dec, batch=batch,
+                    dur_s=t_end - t_dec, batch=batch, **moe,
                 )
         self._steps += 1
         if instrument:

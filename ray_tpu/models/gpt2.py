@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any
+from typing import Any, ClassVar
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +39,8 @@ Params = dict
 
 @dataclasses.dataclass(frozen=True)
 class GPT2Config:
+    family: ClassVar[str] = "gpt2"  # what models/paged.py and the engine dispatch on
+
     vocab_size: int = 50304  # 50257 rounded up to a multiple of 128 (lane tiling)
     n_layer: int = 12
     n_head: int = 12

@@ -1,0 +1,581 @@
+"""Kimi Linear (arXiv:2510.26692, ``model_type: kimi_linear``): a hybrid
+decoder of linear-attention layers (KDA: a gated delta rule with a decay per
+key channel and short causal convolutions), latent-attention layers (MLA
+without rotation) every fourth layer, and a routed mixture of experts with
+one shared expert after a leading dense layer. Third model family of the
+serving tier, and the first whose cache is not keys and values per head.
+
+Block: ``x += Mixer(RMSNorm(x)); x += FFN(RMSNorm(x))``; final RMSNorm; untied
+head. Layers are numbered from 1 as in the published ``linear_attn_config``.
+
+What this file holds, top down:
+
+- :class:`KimiLinearConfig` and :func:`init_params`. Layers differ in kind, so
+  ``params["layers"]`` is a list with one dict a layer and the programs unroll
+  it; nothing is stacked and nothing is sliced out of a stack.
+- the mixers, each in the two forms serving needs: :func:`kda_prefill` /
+  :func:`kda_decode` over :mod:`ray_tpu.ops.delta_rule`, :func:`mla_prefill`
+  (latent rows expanded to keys and values per head) / :func:`mla_decode`
+  (``kv_b`` absorbed into the query and the output, so that a step reads latent
+  rows only).
+- :func:`moe_ffn`: routing over all experts of the model, computing the part
+  of the result that the experts held here give (``experts_held`` of them from
+  ``expert_offset``). No capacity and no dropped token: the (token, pick)
+  pairs that land here are sorted by expert and run through grouped matrix
+  products. What absent experts would add is left out; on one chip the layer
+  runs without its exchange.
+- the paged programs :func:`paged_prefill` / :func:`paged_decode` and
+  :func:`init_pool`, which :mod:`ray_tpu.models.paged` hands a
+  ``KimiLinearConfig`` to. The cache is ``{"ckv": [L_mla, N, block, 576],
+  "state": [L_kda, slots + 1, H, d_k, d_v] float32, "conv": [L_kda, slots + 1,
+  3, 3 H d]}``: latent rows in blocks under the engine's block tables, and a
+  recurrent state and a convolution tail per slot. Row ``slots`` of the last
+  two is scratch: slots that are free, or still prefilling, step there.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import Any, ClassVar
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models.llama import _mlp_sublayer, _rms_norm
+from ray_tpu.ops.delta_rule import kda_chunked, kda_step
+
+Params = dict
+_F32 = jnp.float32
+
+
+@dataclasses.dataclass(frozen=True)
+class KimiLinearConfig:
+    """Published key meanings (``config.json``); defaults are the published
+    Kimi-Linear-48B-A3B sizes, uncut."""
+
+    family: ClassVar[str] = "kimi_linear"
+
+    vocab_size: int = 163840  # rows of the embedding and the head held here
+    n_layer: int = 27
+    d_model: int = 2304
+    kda_layers: tuple = (1, 2, 3, 5, 6, 7, 9, 10, 11, 13, 14, 15, 17, 18, 19, 21, 22, 23, 25, 26)
+    mla_layers: tuple = (4, 8, 12, 16, 20, 24, 27)
+    # KDA (linear_attn_config)
+    kda_heads: int = 32
+    kda_head_dim: int = 128  # d_k = d_v
+    conv_kernel: int = 4
+    kda_gate_rank: int = 128  # of the decay's and the output gate's low-rank pairs
+    # MLA
+    n_head: int = 32
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64  # the shared key part; not rotated (mla_use_nope)
+    v_head_dim: int = 128
+    # Feed-forward
+    d_ff: int = 9216  # the dense layers'
+    first_k_dense: int = 1
+    moe_d_ff: int = 1024
+    n_experts: int = 256  # the router's width: all routed experts of the model
+    experts_held: int = 256  # of them, the ones whose weights are here ...
+    expert_offset: int = 0  # ... starting from this one
+    experts_per_token: int = 8
+    n_shared_experts: int = 1
+    routed_scaling: float = 2.446
+    renormalize: bool = True
+    # Serving
+    max_seq: int = 2048
+    state_slots: int = 16  # state rows where the caller names no count
+    rms_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.bfloat16
+
+    def __post_init__(self):
+        layers = sorted(self.kda_layers + self.mla_layers)
+        assert layers == list(range(1, self.n_layer + 1)), layers
+        assert 0 <= self.expert_offset
+        assert self.expert_offset + self.experts_held <= self.n_experts
+
+    @property
+    def latent_dim(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        return 3 * self.kda_heads * self.kda_head_dim
+
+    def mixer(self, layer: int) -> str:
+        return "mla" if layer in self.mla_layers else "kda"
+
+    def is_moe(self, layer: int) -> bool:
+        return layer > self.first_k_dense
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layer - self.first_k_dense
+
+    @staticmethod
+    def tiny(
+        n_layer: int = 5,
+        vocab_size: int = 512,
+        max_seq: int = 256,
+        experts_held: int = 8,
+        expert_offset: int = 0,
+        **kw,
+    ) -> "KimiLinearConfig":
+        """A CPU-test size with every kind of layer: dense + KDA first, an
+        MLA layer every fourth."""
+        mla = tuple(i for i in range(1, n_layer + 1) if i % 4 == 0)
+        return KimiLinearConfig(**{**dict(
+            vocab_size=vocab_size, n_layer=n_layer, d_model=64,
+            kda_layers=tuple(i for i in range(1, n_layer + 1) if i not in mla),
+            mla_layers=mla, kda_heads=2, kda_head_dim=16, kda_gate_rank=8,
+            n_head=2, kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+            v_head_dim=16, d_ff=128, moe_d_ff=32, n_experts=8,
+            experts_held=experts_held, expert_offset=expert_offset,
+            experts_per_token=2, max_seq=max_seq,
+            state_slots=4, dtype=jnp.float32, param_dtype=jnp.float32,
+        ), **kw})
+
+
+# ---------------------------------------------------------------------------
+# Parameters
+
+
+# What init_params balances the routers' selection bias over (balance_routers).
+_BALANCE_ROUNDS, _BALANCE_TOKENS = 96, 1024
+
+
+@functools.partial(jax.jit, static_argnames="cfg")
+def init_params(key: jax.Array, cfg: KimiLinearConfig) -> Params:
+    """Random weights (:func:`draw_params`) with each router's selection bias
+    balanced as a served checkpoint's is (:func:`balance_routers`). One
+    program (a replica that draws three hundred tensors one by one spends a
+    minute on it, my chip run, PR 29), which the compile cache keeps."""
+    key, sub = jax.random.split(key)
+    return balance_routers(
+        draw_params(key, cfg), sub, cfg, _BALANCE_ROUNDS, min(_BALANCE_TOKENS, cfg.max_seq)
+    )
+
+
+def draw_params(key: jax.Array, cfg: KimiLinearConfig) -> Params:
+    """Random weights, drawn tensor by tensor in the parameter dtype: no
+    float32 copy of an expert stack is ever live. N(0, 0.02), residual
+    projections scaled by 1/sqrt(2 L); the router in float32 with unit-variance
+    logits and a zero selection bias; the decay's ``A_log`` and ``dt_bias`` as
+    the published modelling code draws them (A in [1, 16], a time step in
+    [0.001, 0.1])."""
+    pd = cfg.param_dtype
+    D, H, dk = cfg.d_model, cfg.kda_heads, cfg.kda_head_dim
+    r, Fm, E = cfg.kda_gate_rank, cfg.moe_d_ff, cfg.experts_held
+    std = 0.02
+    resid = std / (2 * cfg.n_layer) ** 0.5
+    keys = iter(jax.random.split(key, 32 * cfg.n_layer + 8))
+
+    def w(shape, s=std, dtype=pd):
+        return jax.random.normal(next(keys), shape, dtype) * jnp.asarray(s, dtype)
+
+    def kda():
+        dt = jnp.exp(jax.random.uniform(
+            next(keys), (H * dk,), _F32, jnp.log(0.001), jnp.log(0.1)))
+        return {
+            "wqkv": w((D, cfg.conv_dim)),
+            "conv": w((cfg.conv_kernel, cfg.conv_dim), cfg.conv_kernel**-0.5),
+            "f_down": w((D, r)), "f_up": w((r, H * dk)),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1(dt)
+            "A_log": jnp.log(jax.random.uniform(next(keys), (H,), _F32, 1.0, 16.0)),
+            "wb": w((D, H)),
+            "g_down": w((D, r)), "g_up": w((r, H * dk)),
+            "o_norm": jnp.ones((dk,), pd),
+            "wo": w((H * dk, D), resid),
+        }
+
+    def mla():
+        Hm = cfg.n_head
+        return {
+            "wq": w((D, Hm * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim))),
+            "wkva": w((D, cfg.latent_dim)),
+            "kv_norm": jnp.ones((cfg.kv_lora_rank,), pd),
+            "wkvb": w((cfg.kv_lora_rank, Hm * (cfg.qk_nope_head_dim + cfg.v_head_dim))),
+            "wo": w((Hm * cfg.v_head_dim, D), resid),
+        }
+
+    def dense():
+        return {"w_gate": w((D, cfg.d_ff)), "w_up": w((D, cfg.d_ff)),
+                "w_down": w((cfg.d_ff, D), resid)}
+
+    def moe():
+        Fs = Fm * cfg.n_shared_experts
+        return {
+            "router": w((D, cfg.n_experts), D**-0.5, _F32),
+            "router_bias": jnp.zeros((cfg.n_experts,), _F32),
+            "e_gate": w((E, D, Fm)), "e_up": w((E, D, Fm)),
+            "e_down": w((E, Fm, D), resid),
+            "s_gate": w((D, Fs)), "s_up": w((D, Fs)), "s_down": w((Fs, D), resid),
+        }
+
+    layers = []
+    for i in range(1, cfg.n_layer + 1):
+        layers.append({
+            "attn_norm": jnp.ones((D,), pd),
+            **(mla() if cfg.mixer(i) == "mla" else kda()),
+            "mlp_norm": jnp.ones((D,), pd),
+            **(moe() if cfg.is_moe(i) else dense()),
+        })
+    return {
+        "wte": w((cfg.vocab_size, D)),
+        "layers": layers,
+        "final_norm": jnp.ones((D,), pd),
+        "lm_head": w((D, cfg.vocab_size)),
+    }
+
+
+# ---------------------------------------------------------------------------
+# KDA mixer
+
+
+def _kda_inputs(h, mixed, p, cfg: KimiLinearConfig):
+    """From the normed input ``h`` [..., D] and the convolved, SiLU'd
+    projections ``mixed`` [..., 3 H d]: ``(q, k, v, g, beta)`` with heads
+    split out, ``q`` and ``k`` normalised, ``g`` the log decay."""
+    H, d = cfg.kda_heads, cfg.kda_head_dim
+    dt = cfg.dtype
+    q, k, v = (
+        a.reshape(*a.shape[:-1], H, d).astype(_F32)
+        for a in jnp.split(mixed, 3, axis=-1)
+    )
+    l2 = lambda a: a * jax.lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)  # noqa: E731
+    f = (h @ p["f_down"].astype(dt)) @ p["f_up"].astype(dt)
+    g = -jnp.exp(p["A_log"].astype(_F32))[:, None] * jax.nn.softplus(
+        (f.astype(_F32) + p["dt_bias"].astype(_F32)).reshape(*f.shape[:-1], H, d)
+    )
+    beta = jax.nn.sigmoid((h @ p["wb"].astype(dt)).astype(_F32))
+    return l2(q) * d**-0.5, l2(k), v, g, beta
+
+
+def _kda_output(h, o, p, cfg: KimiLinearConfig):
+    """RMSNorm per head, the sigmoid output gate, ``W_o``."""
+    dt = cfg.dtype
+    gate = (h @ p["g_down"].astype(dt)) @ p["g_up"].astype(dt)
+    o = _rms_norm(o, p["o_norm"].astype(_F32), cfg.rms_eps)  # o is float32
+    o = o.reshape(*o.shape[:-2], -1) * jax.nn.sigmoid(gate.astype(_F32))
+    return o.astype(dt) @ p["wo"].astype(dt)
+
+
+def kda_prefill(h, p, cfg: KimiLinearConfig, S0, tail, length):
+    """``h`` [T, D] normed, of which the first ``length`` rows are tokens;
+    ``S0`` [H, d_k, d_v] and ``tail`` [K-1, 3 H d] are the state and the last
+    pre-convolution rows before row 0 (zeros at the start of a sequence).
+    Returns ``(out [T, D], S, tail)`` as of row ``length``: padded rows do not
+    touch the state."""
+    T, K = h.shape[0], cfg.conv_kernel
+    dt = cfg.dtype
+    x = jnp.concatenate([tail.astype(dt), h @ p["wqkv"].astype(dt)])  # [K-1+T, C]
+    conv = p["conv"].astype(dt)
+    mixed = sum(conv[j] * x[j : j + T] for j in range(K))
+    q, k, v, g, beta = _kda_inputs(h, jax.nn.silu(mixed), p, cfg)
+    live = (jnp.arange(T) < length)[:, None]
+    o, S = kda_chunked(q, k, v, g * live[..., None], beta * live, S0)
+    tail = jax.lax.dynamic_slice_in_dim(x, length, K - 1, axis=0)
+    return _kda_output(h, o, p, cfg), S, tail
+
+
+def kda_decode(h, p, cfg: KimiLinearConfig, S, tail):
+    """One token a row: ``h`` [B, D], ``S`` [B, H, d_k, d_v], ``tail`` [B,
+    K-1, 3 H d]. Returns ``(out [B, D], S, tail)``."""
+    dt = cfg.dtype
+    x = jnp.concatenate([tail.astype(dt), (h @ p["wqkv"].astype(dt))[:, None]], axis=1)
+    mixed = jnp.einsum("kc,bkc->bc", p["conv"].astype(dt), x)
+    q, k, v, g, beta = _kda_inputs(h, jax.nn.silu(mixed), p, cfg)
+    o, S = kda_step(q, k, v, g, beta, S)
+    return _kda_output(h, o, p, cfg), S, x[:, 1:]
+
+
+# ---------------------------------------------------------------------------
+# MLA mixer (no rotation: position enters through the KDA layers)
+
+
+def mla_latent(h, p, cfg: KimiLinearConfig):
+    """The cache row of each token: ``[RMSNorm(c); k_pe]``, [..., 576]."""
+    ckv = h @ p["wkva"].astype(cfg.dtype)
+    c, k_pe = jnp.split(ckv, [cfg.kv_lora_rank], axis=-1)
+    return jnp.concatenate([_rms_norm(c, p["kv_norm"], cfg.rms_eps), k_pe], axis=-1)
+
+
+def _mla_scale(cfg) -> float:
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+
+
+def mla_prefill(h, rows, mask, p, cfg: KimiLinearConfig):
+    """``h`` [T, D] normed queries; ``rows`` [S, 576] the latent rows they may
+    see under ``mask`` [T, S]. Expands keys and values per head."""
+    T, S = mask.shape
+    H, dn, dv = cfg.n_head, cfg.qk_nope_head_dim, cfg.v_head_dim
+    dt = cfg.dtype
+    q = (h @ p["wq"].astype(dt)).reshape(T, H, -1)
+    c, k_pe = jnp.split(rows, [cfg.kv_lora_rank], axis=-1)
+    kv = (c @ p["wkvb"].astype(dt)).reshape(S, H, dn + dv)
+    k = jnp.concatenate(
+        [kv[..., :dn], jnp.broadcast_to(k_pe[:, None, :], (S, H, k_pe.shape[-1]))], axis=-1
+    )
+    s = jnp.einsum("thd,shd->hts", q, k).astype(_F32) * _mla_scale(cfg)
+    pa = jax.nn.softmax(jnp.where(mask[None], s, -1e30), axis=-1).astype(dt)
+    o = jnp.einsum("hts,shd->thd", pa, kv[..., dn:])
+    return o.reshape(T, H * dv) @ p["wo"].astype(dt)
+
+
+def mla_decode(h, rows, mask, p, cfg: KimiLinearConfig):
+    """One query a row: ``h`` [B, D], ``rows`` [B, S, 576], ``mask`` [B, S].
+    ``kv_b`` is absorbed: its key half into the query, its value half into
+    the output, so attention runs over latent rows as they lie in the pool."""
+    B = h.shape[0]
+    H, dn, dv, R = cfg.n_head, cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
+    dt = cfg.dtype
+    q = (h @ p["wq"].astype(dt)).reshape(B, H, -1)
+    wkvb = p["wkvb"].astype(dt).reshape(R, H, dn + dv)
+    q_lat = jnp.einsum("bhd,rhd->bhr", q[..., :dn], wkvb[..., :dn])
+    ql = jnp.concatenate([q_lat, q[..., dn:]], axis=-1)  # [B, H, 576]
+    s = jnp.einsum("bhc,bsc->bhs", ql, rows).astype(_F32) * _mla_scale(cfg)
+    pa = jax.nn.softmax(jnp.where(mask[:, None], s, -1e30), axis=-1).astype(dt)
+    o_lat = jnp.einsum("bhs,bsr->bhr", pa, rows[..., :R])
+    o = jnp.einsum("bhr,rhd->bhd", o_lat, wkvb[..., dn:])
+    return o.reshape(B, H * dv) @ p["wo"].astype(dt)
+
+
+# ---------------------------------------------------------------------------
+# Expert feed-forward
+
+
+def route(h, p, cfg: KimiLinearConfig):
+    """``(experts [T, k] int32, weights [T, k] float32)`` of each token: the
+    router in float32 over all experts of the model, chosen by score plus
+    selection bias, weighted by score, renormalised over the chosen and
+    scaled. (``num_expert_group`` 1: the grouped top-k is a plain one.)"""
+    s = jax.nn.sigmoid(jnp.dot(
+        h.astype(_F32), p["router"].astype(_F32), precision=jax.lax.Precision.HIGHEST
+    ))
+    _, idx = jax.lax.top_k(s + p["router_bias"].astype(_F32), cfg.experts_per_token)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if cfg.renormalize:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), w * cfg.routed_scaling
+
+
+def moe_ffn(h, p, cfg: KimiLinearConfig, valid=None):
+    """``h`` [T, D] normed -> ``(y [T, D], counts int32 [2], picks [T, k])``:
+    the experts held here on the picks that land on them, plus the shared
+    expert. ``valid`` [T] bool marks real tokens: the others are routed
+    nowhere, so they touch no expert. ``counts`` is (picks that landed on a
+    held expert, held experts with at least one pick)."""
+    T, D = h.shape
+    E, k = cfg.experts_held, cfg.experts_per_token
+    dt = cfg.dtype
+    idx, w = route(h, p, cfg)
+    local = idx - cfg.expert_offset
+    here = (local >= 0) & (local < E)
+    if valid is not None:
+        here &= valid[:, None]
+    # Sort the (token, pick) pairs by expert; those that land elsewhere get
+    # the number past the last expert and so sort behind every group.
+    expert = jnp.where(here, local, E).reshape(T * k)
+    order = jnp.argsort(expert, stable=True)
+    sizes = jnp.sum(
+        expert[:, None] == jnp.arange(E, dtype=jnp.int32)[None, :], axis=0, dtype=jnp.int32
+    )
+    xs = h[order // k]  # [T k, D], grouped by expert
+    mid = jax.nn.silu(jax.lax.ragged_dot(xs, p["e_gate"].astype(dt), sizes)) * (
+        jax.lax.ragged_dot(xs, p["e_up"].astype(dt), sizes)
+    )
+    ys = jax.lax.ragged_dot(mid, p["e_down"].astype(dt), sizes)
+    # Back to (token, pick) order; a row behind the groups holds nothing.
+    ys = ys[jnp.argsort(order)].reshape(T, k, D).astype(_F32)
+    y = jnp.sum(jnp.where(here[..., None], ys * w[..., None], 0.0), axis=1)
+    shared = (jax.nn.silu(h @ p["s_gate"].astype(dt)) * (h @ p["s_up"].astype(dt))) @ (
+        p["s_down"].astype(dt)
+    )
+    counts = jnp.stack([jnp.sum(here, dtype=jnp.int32), jnp.sum(sizes > 0, dtype=jnp.int32)])
+    return y.astype(dt) + shared, counts, idx
+
+
+def _ffn(x, p, cfg: KimiLinearConfig, layer: int, valid, seen: list):
+    """The feed-forward sublayer with its residual; an expert layer's
+    counts and picks are appended to ``seen``."""
+    if not cfg.is_moe(layer):
+        return _mlp_sublayer(x, p, cfg)
+    y, counts, picks = moe_ffn(_rms_norm(x, p["mlp_norm"], cfg.rms_eps), p, cfg, valid)
+    seen.append((counts, picks))
+    return x + y
+
+
+def _outputs(pool, logits, seen, with_picks: bool):
+    counts = jnp.stack([c for c, _ in seen])
+    if with_picks:
+        return pool, logits, counts, jnp.stack([p for _, p in seen])
+    return pool, logits, counts
+
+
+def span_fields(cfg: KimiLinearConfig, counts, tokens: int, slots: int) -> dict:
+    """What the engine writes on the span of one program run over ``tokens``
+    real tokens of ``slots`` sequences: the program's counters (flat, as read
+    back; two a layer, anything behind them is padding) summed over the
+    expert layers, and the rows of the state the run stepped."""
+    counts = counts[: 2 * cfg.n_moe_layers].reshape(-1, 2)
+    return {
+        "picks": tokens * cfg.experts_per_token * cfg.n_moe_layers,
+        "picks_here": int(counts[:, 0].sum()),
+        "experts_touched": int(counts[:, 1].sum()),
+        "experts_held": cfg.experts_held * cfg.n_moe_layers,
+        "state_slots": slots,
+    }
+
+
+def _final(params, last, cfg: KimiLinearConfig):
+    h = _rms_norm(last, params["final_norm"], cfg.rms_eps)
+    return (h @ params["lm_head"].astype(cfg.dtype)).astype(_F32)
+
+
+# ---------------------------------------------------------------------------
+# The paged programs (models/paged.py dispatches here by cfg.family)
+
+has_recurrent_state = True
+
+
+def init_pool(cfg: KimiLinearConfig, num_blocks: int, block_size: int, slots=None):
+    """The zeroed cache: latent rows in blocks, state and convolution tail by
+    slot with one scratch row more (docstring of this module)."""
+    slots = cfg.state_slots if slots is None else slots
+    H, d = cfg.kda_heads, cfg.kda_head_dim
+    Lk, Lm = len(cfg.kda_layers), len(cfg.mla_layers)
+    return {
+        "ckv": jnp.zeros((Lm, num_blocks, block_size, cfg.latent_dim), cfg.dtype),
+        "state": jnp.zeros((Lk, slots + 1, H, d, d), _F32),
+        "conv": jnp.zeros((Lk, slots + 1, cfg.conv_kernel - 1, cfg.conv_dim), cfg.dtype),
+    }
+
+
+def _layers(params, cfg):
+    """(layer number, its parameters, its index among layers of its kind)."""
+    seen = {"kda": 0, "mla": 0}
+    for i, p in enumerate(params["layers"], start=1):
+        kind = cfg.mixer(i)
+        yield i, p, kind, seen[kind]
+        seen[kind] += 1
+
+
+def paged_prefill(
+    params, tokens, length, start, table, pool, cfg: KimiLinearConfig, *,
+    block_size: int, slot=None, with_picks: bool = False,
+):
+    """Prefill positions [start, start + T) of one sequence; operands as
+    :func:`ray_tpu.models.paged.paged_prefill`, plus ``slot``, the row of the
+    state and the convolution tail that belongs to the sequence (None: the
+    scratch row). ``start == 0`` begins from zero state, whatever the slot
+    held; ``start > 0`` continues from the slot's (a later chunk). Returns
+    ``(pool, last_logits [vocab] float32, counts int32 [expert layers, 2])``,
+    and with ``with_picks`` the chosen experts [expert layers, T, k] (for the
+    benchmark's comparison of routing)."""
+    T = tokens.shape[1]
+    S = table.shape[0] * block_size
+    ckv, state, conv = pool["ckv"], pool["state"], pool["conv"]
+    slot = state.shape[1] - 1 if slot is None else slot
+    fresh = start == 0
+
+    pos = start + jnp.arange(T, dtype=jnp.int32)
+    valid = jnp.arange(T) < length
+    bids, offs = table[pos // block_size], pos % block_size
+    mask = jnp.arange(S)[None, :] <= pos[:, None]  # [T, S]
+    x = params["wte"].astype(cfg.dtype)[tokens[0]]
+    seen: list = []
+    for i, p, kind, l in _layers(params, cfg):
+        h = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
+        if kind == "kda":
+            S0 = jnp.where(fresh, 0.0, state[l, slot])
+            tail = jnp.where(fresh, 0, conv[l, slot])
+            out, S1, tail = kda_prefill(h, p, cfg, S0, tail, length)
+            state = state.at[l, slot].set(S1)
+            conv = conv.at[l, slot].set(tail.astype(conv.dtype))
+        else:
+            ckv = ckv.at[l, bids, offs].set(mla_latent(h, p, cfg))
+            rows = ckv[l, table].reshape(S, cfg.latent_dim)
+            out = mla_prefill(h, rows, mask, p, cfg)
+        x = _ffn(x + out, p, cfg, i, valid, seen)
+    last = jax.lax.dynamic_index_in_dim(x, (length - 1).astype(jnp.int32), 0, keepdims=False)
+    logits = _final(params, last[None], cfg)[0]
+    return _outputs({"ckv": ckv, "state": state, "conv": conv}, logits, seen, with_picks)
+
+
+def paged_decode(
+    params, last_tokens, positions, tables, pool, cfg: KimiLinearConfig, *,
+    block_size: int, live=None, with_picks: bool = False,
+):
+    """One token a slot; operands as :func:`ray_tpu.models.paged.paged_decode`,
+    plus ``live`` [B] bool: a slot that is not live (free, or still prefilling
+    in chunks) steps on the scratch row of the state and is routed to no
+    expert; its logits mean nothing. None: every slot is live. Returns
+    ``(pool, logits [B, vocab] float32, counts int32 [expert layers, 2])``."""
+    B = last_tokens.shape[0]
+    S = tables.shape[1] * block_size
+    ckv, state, conv = pool["ckv"], pool["state"], pool["conv"]
+    rows_of = jnp.arange(B)
+    if live is not None:
+        rows_of = jnp.where(live, rows_of, state.shape[1] - 1)
+    bids = tables[jnp.arange(B), positions // block_size]
+    offs = positions % block_size
+    mask = jnp.arange(S)[None, :] <= positions[:, None]  # [B, S]
+    x = params["wte"].astype(cfg.dtype)[last_tokens]
+    seen: list = []
+    for i, p, kind, l in _layers(params, cfg):
+        h = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
+        if kind == "kda":
+            out, S1, tail = kda_decode(h, p, cfg, state[l, rows_of], conv[l, rows_of])
+            state = state.at[l, rows_of].set(S1)
+            conv = conv.at[l, rows_of].set(tail.astype(conv.dtype))
+        else:
+            ckv = ckv.at[l, bids, offs].set(mla_latent(h, p, cfg))
+            rows = ckv[l, tables].reshape(B, S, cfg.latent_dim)
+            out = mla_decode(h, rows, mask, p, cfg)
+        x = _ffn(x + out, p, cfg, i, live, seen)
+    return _outputs(
+        {"ckv": ckv, "state": state, "conv": conv}, _final(params, x, cfg), seen, with_picks
+    )
+
+
+# ---------------------------------------------------------------------------
+# The selection bias of a served checkpoint
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "rounds", "tokens"))
+def balance_routers(params, key, cfg: KimiLinearConfig, rounds: int, tokens: int):
+    """``params`` with each expert layer's ``router_bias`` set by the published
+    rule of balancing without an auxiliary loss: round after round over
+    seeded random tokens, the bias of an expert that got less than its share
+    of the picks goes up by a step and that of one that got more goes down.
+    A trained checkpoint is served with a bias that has balanced its experts;
+    random weights with a zero bias are not balanced at all (SiLU's positive
+    mean gives every hidden state a common part, so every token favours the
+    same few experts, and which chip's share they fall into changes with the
+    seed: PERF.md section 6, PR 29). The bias enters the selection only."""
+    bs = 16
+    table = jnp.arange(1, tokens // bs + 1, dtype=jnp.int32)
+    pool = init_pool(cfg, tokens // bs + 1, bs, 0)
+    at = [n for n, p in enumerate(params["layers"]) if "router_bias" in p]
+    length, start = jnp.asarray(tokens, jnp.int32), jnp.asarray(0, jnp.int32)
+
+    def with_biases(biases):
+        layers = list(params["layers"])
+        for n, b in zip(at, biases):
+            layers[n] = {**layers[n], "router_bias": b}
+        return {**params, "layers": layers}
+
+    def one_round(r, biases):
+        toks = jax.random.randint(jax.random.fold_in(key, r), (1, tokens), 0, cfg.vocab_size)
+        *_, picks = paged_prefill(
+            with_biases(biases), toks, length, start, table, pool, cfg,
+            block_size=bs, with_picks=True,
+        )
+        load = jnp.mean(jax.nn.one_hot(picks, cfg.n_experts, dtype=_F32), axis=(1, 2))
+        step = 0.02 * (1.0 - r / rounds)  # of a score in (0, 1); shrinking, so it settles
+        return biases + step * jnp.sign(1.0 / cfg.n_experts - load)
+
+    biases = jnp.stack([params["layers"][n]["router_bias"] for n in at])
+    return with_biases(jax.lax.fori_loop(0, rounds, one_round, biases))

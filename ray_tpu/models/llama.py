@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any
+from typing import Any, ClassVar
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +36,8 @@ Params = dict
 
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
+    family: ClassVar[str] = "llama"  # what models/paged.py and the engine dispatch on
+
     vocab_size: int = 32000
     n_layer: int = 12
     n_head: int = 12

@@ -32,22 +32,59 @@ kernels:
   blocks (host-side refcount) — no device copy at all, where the dense
   engine had to copy pooled KV into the slot row.
 
-Family dispatch (GPT-2 learned-position MHA vs Llama RoPE GQA) is a small
-hook table; everything else — scatter, gather, masking, grouped
-attention — is family-agnostic because GQA with group=1 *is* MHA.
+Family dispatch is by the configuration's ``family`` name. GPT-2
+(learned-position MHA) and Llama (RoPE GQA) share everything here — scatter,
+gather, masking, grouped attention — behind a small hook table, because GQA
+with group=1 *is* MHA. A family whose cache is not keys and values per head
+supplies its cache and its layer bodies itself (``_OWN_PROGRAMS``):
+
+- **What a pool is now.** Either blocks of keys and values, as above, or —
+  for ``kimi_linear`` — latent rows in blocks (``"ckv": [L_mla, N, block,
+  576]``, no head axis, under the same block tables and the same
+  ``BlockManager``) beside a recurrent state and a convolution tail *per
+  slot* (``"state": [L_kda, slots + 1, H, d_k, d_v]`` float32, ``"conv"``),
+  which no block table reaches. Stale keys are masked away by position; a
+  stale state is not, so a prefill from position 0 starts from zero state
+  and a later chunk continues from its slot's. Row ``slots`` is scratch:
+  slots that are free, or still prefilling, step there. Such a pool cannot
+  be shared by prefix, verified speculatively or exported by blocks alone,
+  and :func:`has_recurrent_state` says so to the engine.
 """
 
 from __future__ import annotations
+
+import importlib
 
 import jax
 import jax.numpy as jnp
 
 Params = dict
 
+# Families that bring their own cache and layer bodies: the module holds
+# ``init_pool``, ``paged_prefill``, ``paged_decode`` and ``has_recurrent_state``.
+_OWN_PROGRAMS = {"kimi_linear": "ray_tpu.models.kimi_linear"}
 
-def init_block_pool(cfg, num_blocks: int, block_size: int):
+
+def _own_programs(cfg):
+    name = _OWN_PROGRAMS.get(cfg.family)
+    return importlib.import_module(name) if name else None
+
+
+def has_recurrent_state(cfg) -> bool:
+    """Whether part of the family's cache is a state per slot that block
+    tables do not reach (module docstring)."""
+    own = _own_programs(cfg)
+    return own is not None and own.has_recurrent_state
+
+
+def init_block_pool(cfg, num_blocks: int, block_size: int, slots=None):
     """Zeroed pool pytree {"k","v"}: [L, N, KH, block, Dh] in activation
-    dtype. KH is the KV-head count (unexpanded GQA for Llama)."""
+    dtype. KH is the KV-head count (unexpanded GQA for Llama). A family with
+    a state per slot sizes it for ``slots`` sequences (the engine's
+    ``max_slots``) and a scratch row."""
+    own = _own_programs(cfg)
+    if own is not None:
+        return own.init_pool(cfg, num_blocks, block_size, slots)
     kh = getattr(cfg, "n_kv_head", None) or cfg.n_head
     shape = (cfg.n_layer, num_blocks, kh, block_size, cfg.head_dim)
     return {
@@ -60,12 +97,6 @@ def init_block_pool(cfg, num_blocks: int, block_size: int):
 # Family hooks
 
 
-def _is_llama(cfg) -> bool:
-    from ray_tpu.models.llama import LlamaConfig
-
-    return isinstance(cfg, LlamaConfig)
-
-
 def _family(cfg, S: int):
     """Hook table: embed / qkv (position-aware) / finish / final.
 
@@ -73,7 +104,7 @@ def _family(cfg, S: int):
     ``start + arange(T)`` broadcast over one row, decode passes per-slot
     ``positions[:, None]``; the same hooks serve both.
     """
-    if _is_llama(cfg):
+    if cfg.family == "llama":
         from ray_tpu.models.llama import (
             _mlp_sublayer,
             _rms_norm,
@@ -118,7 +149,7 @@ def _family(cfg, S: int):
                 jnp.float32
             )
 
-    else:
+    elif cfg.family == "gpt2":
         from ray_tpu.models.gpt2 import _layer_norm
         from ray_tpu.models.gpt2_decode import _finish_block, _qkv
 
@@ -141,6 +172,9 @@ def _family(cfg, S: int):
             return (h @ params["wte"].astype(cfg.dtype).T).astype(
                 jnp.float32
             )
+
+    else:
+        raise ValueError(f"no key/value hooks for the family {cfg.family!r}")
 
     return embed, qkv, finish, final, H, KH, Dh
 
@@ -187,13 +221,22 @@ def paged_prefill(
     cfg,
     *,
     block_size: int,
+    slot=None,  # scalar int32 — a family with a state per slot: the
+    #             sequence's row of it (None: the scratch row)
 ):
     """Prefill positions [start, start+T) into the pool; return
-    (pool, last_logits [vocab] f32).
+    (pool, last_logits [vocab] f32), and a third value, its counters, from
+    a family that has some.
 
     The one prefill program serves both the fresh path (start=0) and the
     prefix-continue path — attention always spans the full gathered row
     under the mask ``col <= start + row`` (the static-shape trade)."""
+    own = _own_programs(cfg)
+    if own is not None:
+        return own.paged_prefill(
+            params, tokens, length, start, table, pool, cfg,
+            block_size=block_size, slot=slot,
+        )
     B, T = tokens.shape
     W = table.shape[0]
     S = W * block_size
@@ -254,6 +297,11 @@ def paged_verify(
     Callers must keep positions + T <= max_seq (the engine falls back to
     plain decode near the boundary): out-of-range scatter indices would
     clamp into the slot's last real block and corrupt it."""
+    if has_recurrent_state(cfg):
+        raise ValueError(
+            f"paged_verify cannot serve the family {cfg.family!r}: rejected "
+            "tokens would have to be taken back out of its recurrent state"
+        )
     B, T = tokens.shape
     W = tables.shape[1]
     S = W * block_size
@@ -301,11 +349,20 @@ def paged_decode(
     cfg,
     *,
     block_size: int,
+    live=None,  # [B] bool — a family with a state per slot: which slots
+    #             hold a decoding sequence (None: all)
 ):
     """One token per slot against the shared pool; returns
-    (pool, logits [B, vocab] f32). Free slots must point their table at
+    (pool, logits [B, vocab] f32), and a third value, its counters, from a
+    family that has some. Free slots must point their table at
     the scratch block (id 0) so their garbage writes never land in a
     block another request owns."""
+    own = _own_programs(cfg)
+    if own is not None:
+        return own.paged_decode(
+            params, last_tokens, positions, tables, pool, cfg,
+            block_size=block_size, live=live,
+        )
     B = last_tokens.shape[0]
     W = tables.shape[1]
     S = W * block_size

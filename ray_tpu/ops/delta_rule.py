@@ -1,0 +1,99 @@
+"""Gated delta rule with a decay per key channel (KDA, Kimi Linear,
+arXiv:2510.26692): the recurrence of a linear-attention layer, per head, on
+a state ``S`` of ``[d_k, d_v]``:
+
+    S' = Diag(alpha_t) S_{t-1}
+    S_t = S' + beta_t k_t (v_t - S'^T k_t)^T
+    o_t = S_t^T q_t
+
+``alpha_t = exp(g_t)`` with ``g_t <= 0`` per key channel, ``beta_t`` in (0, 1).
+
+Two forms of the same mathematics:
+
+- :func:`kda_step`, one token a sequence, for decode: reads and writes the
+  state once.
+- :func:`kda_chunked`, chunks of :data:`CHUNK` tokens under ``lax.scan``, for
+  prefill. Inside a chunk that starts from ``S_0`` the writes ``u_t = beta_t
+  (v_t - S'^T_t k_t)`` satisfy a unit lower-triangular system (the WY / UT
+  form), which is solved exactly; outputs and the next state are then matrix
+  products. With ``G_t`` the running sum of ``g`` inside the chunk, every
+  decay that appears is ``exp(G_t - G_i)`` with ``i <= t``, ``exp(G_t)`` or
+  ``exp(G_C - G_i)``: exponents at or below zero, so nothing is ever divided
+  by a product of ``alpha`` and nothing overflows; a product that underflows
+  is one the recurrence would have lost too.
+
+A position with ``beta = 0`` and ``g = 0`` leaves the state as it was (the
+padded tail of a prefill bucket). State and accumulation are float32; plain
+``jax.numpy``, no kernel.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+CHUNK = 64
+_F32 = jnp.float32
+# The state is float32 and feeds back into itself: its products are taken at
+# full precision (on a TPU the default rounds float32 operands to bfloat16).
+# They are under a tenth of a KDA layer's operations.
+_PREC = jax.lax.Precision.HIGHEST
+
+
+def kda_step(q, k, v, g, beta, S):
+    """One token. ``q, k, g``: [..., d_k]; ``v``: [..., d_v]; ``beta``:
+    [...]; ``S``: [..., d_k, d_v] float32. Returns ``(o [..., d_v] float32,
+    S_t)``."""
+    q, k, v, g = (a.astype(_F32) for a in (q, k, v, g))
+    beta = beta.astype(_F32)
+    S = jnp.exp(g)[..., None] * S
+    u = beta[..., None] * (v - jnp.einsum("...kv,...k->...v", S, k, precision=_PREC))
+    S = S + k[..., None] * u[..., None, :]
+    return jnp.einsum("...kv,...k->...v", S, q, precision=_PREC), S
+
+
+def _chunk(S, inputs):
+    """One chunk of every head: ``q, k, g`` [H, C, d_k], ``v`` [H, C, d_v],
+    ``beta`` [H, C], ``S`` [H, d_k, d_v]."""
+    q, k, v, g, beta = inputs
+    C = q.shape[1]
+    G = jnp.cumsum(g, axis=1)  # [H, C, d_k], decreasing
+    # decay[t, i] = exp(G_t - G_i) per channel, wanted for i <= t only.
+    decay = jnp.exp(jnp.minimum(G[:, :, None, :] - G[:, None, :, :], 0.0))
+    kd = decay * k[:, None, :, :]  # k_i as position t sees it
+    kk = jnp.sum(k[:, :, None, :] * kd, axis=-1)  # [H, C(t), C(i)]
+    qk = jnp.sum(q[:, :, None, :] * kd, axis=-1)
+    eG = jnp.exp(G)
+    system = jnp.eye(C, dtype=_F32) + jnp.tril(kk, -1) * beta[..., None]
+    rhs = beta[..., None] * (
+        v - jnp.einsum("htc,hcv->htv", k * eG, S, precision=_PREC)
+    )
+    u = jax.scipy.linalg.solve_triangular(system, rhs, lower=True, unit_diagonal=True)
+    o = jnp.einsum("htc,hcv->htv", q * eG, S, precision=_PREC) + jnp.einsum(
+        "hti,hiv->htv", jnp.tril(qk), u, precision=_PREC,
+    )
+    to_end = jnp.exp(G[:, -1:, :] - G)  # exp(G_C - G_i)
+    S = eG[:, -1, :, None] * S + jnp.einsum(
+        "hic,hiv->hcv", k * to_end, u, precision=_PREC
+    )
+    return S, o
+
+
+def kda_chunked(q, k, v, g, beta, S0):
+    """A whole sequence. ``q, k, g``: [T, H, d_k]; ``v``: [T, H, d_v];
+    ``beta``: [T, H]; ``S0``: [H, d_k, d_v]. ``T`` need not be a multiple of
+    the chunk: the tail is padded with positions that leave the state alone.
+    Returns ``(o [T, H, d_v] float32, S_T float32)``."""
+    T = q.shape[0]
+    pad = -T % CHUNK
+    n = (T + pad) // CHUNK
+
+    def chunks(a):  # [T, H, ...] -> [n, H, CHUNK, ...], zero-padded
+        a = jnp.pad(a.astype(_F32), [(0, pad)] + [(0, 0)] * (a.ndim - 1))
+        return jnp.moveaxis(a.reshape(n, CHUNK, *a.shape[1:]), 1, 2)
+
+    S, o = jax.lax.scan(
+        _chunk, S0.astype(_F32), tuple(chunks(a) for a in (q, k, v, g, beta))
+    )
+    H, d_v = o.shape[1], o.shape[3]  # o: [n, H, CHUNK, d_v]
+    return jnp.moveaxis(o, 2, 1).reshape(n * CHUNK, H, d_v)[:T], S
