@@ -198,7 +198,11 @@ class Config:
     # Default per-replica concurrency budget (was a hard-coded 8 in
     # serve/router.py and the controller's max_concurrent_queries
     # fallbacks): the router's saturation-spill margin and the replica
-    # actor's max_concurrency derive from it.
+    # actor's max_concurrency derive from it. It is the width of every
+    # deployment that states none. An LLM deployment states its own:
+    # llm/serve_llm.py:build_openai_app passes its engine's
+    # LLMConfig.max_slots as max_concurrent_queries, so a replica lets in
+    # as many requests as the decode program has rows.
     serve_max_concurrent: int = 8
     # Bounded replica queue: an admission-enabled replica fails a request
     # fast (OverloadedError, reason="queue_full") once its in-flight count

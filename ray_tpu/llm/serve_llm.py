@@ -460,6 +460,16 @@ def build_openai_app(
     rolling p95 TTFT, so the ttft_high_ms/ttft_low_ms watermarks are
     live for this deployment.
 
+    A replica executes as many requests at once as its engine has slots:
+    the deployment's ``max_concurrent_queries`` is ``config.max_slots``,
+    not the cluster default ``serve_max_concurrent``. The decode program
+    always runs ``max_slots`` rows, so a narrower replica pays for rows
+    it never fills. The routing table's ``max_concurrent``, the replica
+    actor's ``max_concurrency`` (two more: they wait in the engine's own
+    queue, so the admitting turn refills a freed slot), the execution
+    gate and the bounded queue's cap all follow from that one number, in
+    both roles of a disaggregated deployment.
+
     ``prefill_replicas`` > 0 opts into DISAGGREGATED serving: the
     deployment runs ``prefill_replicas`` prefill-role replicas plus
     ``num_replicas`` decode-role replicas, roles advertised in the
@@ -485,6 +495,7 @@ def build_openai_app(
         LLMServer,
         name=name,
         num_replicas=num_replicas,
+        max_concurrent_queries=config.max_slots,
         admission_config=admission_config,
         disagg_config=disagg_config,
         ray_actor_options=dict(config.placement),
