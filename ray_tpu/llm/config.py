@@ -32,19 +32,20 @@ class LLMConfig:
     max_slots: int = 16
     max_seq: int = 2048  # cache length (prompt + generation)
     prefill_buckets: tuple = (32, 64, 128, 256, 512, 1024, 2048)
-    # Paged KV cache (reference: the block/gpu-memory knobs vLLM exposes,
-    # vllm_models.py:89). kv_block_size > 0 -> requests hold block tables
-    # over a shared HBM pool sized num_kv_blocks; admission reserves
-    # ceil(min(prompt+max_tokens, max_seq)/block) blocks, so short
-    # requests stop paying max_seq-sized slot rows. 0 -> legacy dense
-    # per-slot cache. num_kv_blocks None -> half the dense-equivalent
-    # (2x oversubscription), floored at one max-length request + 1.
+    # The KV cache (reference: the block/gpu-memory knobs vLLM exposes,
+    # vllm_models.py:89): requests hold block tables of kv_block_size
+    # tokens a block over a shared HBM pool of num_kv_blocks; admission
+    # reserves ceil(min(prompt+max_tokens, max_seq)/block) blocks, so
+    # short requests do not pay max_seq-sized rows. It is the engine's one
+    # cache: kv_block_size must be positive and divide max_seq.
+    # num_kv_blocks None -> half of max_slots x max_seq positions (2x
+    # oversubscription), floored at one max-length request + 1.
     # What a block holds is the family's business: keys and values per
     # head, or latent rows (one [kv_lora_rank + rope] row a token, no head
     # axis) beside a state per slot that no block holds. A family with
-    # such a state is served paged only, and without the prefix cache,
-    # speculative decoding, tensor parallelism and the disaggregated
-    # handoff: the engine says so by name at construction.
+    # such a state is served without the prefix cache, speculative
+    # decoding, tensor parallelism and the disaggregated handoff: the
+    # engine says so by name at construction.
     kv_block_size: int = 16
     num_kv_blocks: Optional[int] = None
     # Parallelism: tensor-parallel degree (mesh `tp` axis over local devices)
@@ -70,14 +71,14 @@ class LLMConfig:
     # decoders instead of stalling a whole slot-batch for its full prefill
     # (bounds p99 ITL under mixed-length traffic). 0 = disabled (the whole
     # suffix prefills at admission — the pre-round-12 behavior and the
-    # kill-switch arm of the A/B). Paged mode requires a multiple of
-    # kv_block_size, same as prefix_chunk.
+    # kill-switch arm of the A/B). Must be a multiple of kv_block_size,
+    # as prefix_chunk must.
     prefill_chunk_tokens: int = 0
     # Speculative decoding (reference: the draft/target scheme vLLM runs
     # under ray.llm; the Gemma-on-TPU serving playbook in PAPERS.md): a
     # small draft model proposes up to this many greedy tokens per engine
     # step and the target model verifies them in ONE multi-token forward
-    # (models.paged.paged_verify / the dense twin) — each step then yields
+    # (models.paged.paged_verify) — each step then yields
     # 1..k+1 tokens instead of exactly 1, at one target forward per step.
     # Greedy outputs are token-identical to vanilla decode (CI-pinned).
     # 0 = off. RAY_TPU_SPEC_DECODE=0 is the cluster kill switch.

@@ -300,15 +300,12 @@ class LLMServer:
         prompt, sample the first token, and return the handoff descriptor
         — prompt ids, the first token, and the armed KV-block export the
         decode replica pulls over the transfer fabric. Returns
-        {"unsupported": True} when this replica cannot export (dense
-        cache, or the RAY_TPU_DISAGG kill switch landed here first) — the
-        router then falls back to unified routing."""
+        {"unsupported": True} when this replica cannot export (the
+        RAY_TPU_DISAGG kill switch landed here first) — the router then
+        falls back to unified routing."""
         from ray_tpu.core.config import GLOBAL_CONFIG
 
-        if (
-            not getattr(self.engine, "paged", False)
-            or not GLOBAL_CONFIG.disagg
-        ):
+        if not GLOBAL_CONFIG.disagg:
             return {"unsupported": True}
         body = request.get("body") or {}
         if not isinstance(body, dict):
@@ -477,18 +474,12 @@ def build_openai_app(
     replica (prefix-digest bias preserved), ships the finished KV blocks
     to a decode replica over the transfer fabric (the handoff carries the
     first sampled token), and decode replicas never run whole-suffix
-    prefill — see README "Disaggregated serving". Requires the paged KV
-    cache; RAY_TPU_DISAGG=0 restores unified serving byte-identically."""
+    prefill — see README "Disaggregated serving". RAY_TPU_DISAGG=0
+    restores unified serving byte-identically."""
     from ray_tpu.util.prefix_digest import BYTE_BOS_SCHEME
 
     disagg_config = None
     if prefill_replicas > 0:
-        if config.kv_block_size <= 0:
-            raise ValueError(
-                "disaggregated serving (prefill_replicas > 0) requires "
-                "the paged KV cache (kv_block_size > 0): handoffs ship "
-                "pool blocks over the transfer fabric"
-            )
         disagg_config = {"prefill_replicas": int(prefill_replicas)}
         num_replicas = int(num_replicas) + int(prefill_replicas)
     dep = serve_api.deployment(

@@ -4,12 +4,11 @@ Reference parity: the draft/target speculative scheme vLLM supplies under
 ray.llm (and the Gemma-on-TPU serving playbook in PAPERS.md). A small
 draft model proposes ``k`` greedy tokens per engine step; the target model
 scores the carried last token plus all ``k`` proposals in ONE multi-token
-forward (:func:`ray_tpu.models.paged.paged_verify`, or :func:`dense_verify`
-below for the dense cache) and accepts the longest matching prefix plus
-one corrected token — each step yields 1..k+1 tokens at one target
-forward. **Greedy verification is token-identical to vanilla decode by
-construction** (CI-pinned): every accepted token is exactly the argmax
-the vanilla loop would have produced in sequence.
+forward (:func:`ray_tpu.models.paged.paged_verify`) and accepts the
+longest matching prefix plus one corrected token — each step yields 1..k+1
+tokens at one target forward. **Greedy verification is token-identical to
+vanilla decode by construction** (CI-pinned): every accepted token is
+exactly the argmax the vanilla loop would have produced in sequence.
 
 The draft **shares the paged pool's structure**: one BlockManager, one
 block-table array — the draft KV is a parallel ``{"k","v"}`` pytree
@@ -45,7 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.models.paged import _family
+from ray_tpu.models import paged
 from ray_tpu.util import metrics as _metrics
 
 # Telemetry rides the engine histograms/counters (ITL is observed by the
@@ -70,71 +69,15 @@ _SPEC_ACCEPT_RATE = _metrics.Gauge(
 )
 
 
-def dense_verify(
-    params,
-    tokens: jax.Array,  # [B, T] int32 — token t of row b sits at absolute
-    #                      position positions[b] + t
-    positions: jax.Array,  # [B] int32 — first write position per slot
-    cache,
-    cfg,
-):
-    """Multi-token decode on the dense slot cache ([L, B, KH, S, Dh]) —
-    the dense twin of :func:`ray_tpu.models.paged.paged_verify` (T=1
-    degenerates to the decode step). Returns (cache, logits [B, T, vocab]
-    f32): logits[b, t] is the next-token distribution after consuming
-    tokens[b, t]."""
-    B, T = tokens.shape
-    S = cache["k"].shape[3]
-    embed, qkv, finish, final, H, KH, Dh = _family(cfg, S)
-    group = H // KH
-
-    pos2d = positions[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-    x = embed(params, tokens, pos2d)  # [B, T, D]
-    rows = jnp.arange(B)
-    khi = jnp.arange(KH)
-    cols = jnp.arange(S)
-    mask = cols[None, None, :] <= pos2d[:, :, None]  # [B, T, S]
-    scale = 1.0 / (Dh**0.5)
-
-    def body(x, layer):
-        p, ck, cv = layer  # ck/cv: [B, KH, S, Dh]
-        q, k, v = qkv(x, p, pos2d)  # q [B,H,T,Dh], k/v [B,KH,T,Dh]
-        ck = ck.at[
-            rows[:, None, None], khi[None, :, None], pos2d[:, None, :]
-        ].set(k)
-        cv = cv.at[
-            rows[:, None, None], khi[None, :, None], pos2d[:, None, :]
-        ].set(v)
-        qg = q.reshape(B, KH, group, T, Dh)
-        s = jnp.einsum("bkgtd,bksd->bkgts", qg, ck).astype(jnp.float32)
-        s = jnp.where(mask[:, None, None], s * scale, -1e30)
-        pa = jax.nn.softmax(s, axis=-1).astype(cv.dtype)
-        attn = jnp.einsum("bkgts,bksd->bkgtd", pa, cv).reshape(B, H, T, Dh)
-        return finish(x, attn, p), (ck, cv)
-
-    x, (ks, vs) = jax.lax.scan(
-        lambda c, lyr: body(c, lyr),
-        x,
-        (params["blocks"], cache["k"], cache["v"]),
-    )
-    cache = {"k": ks, "v": vs}
-    D = x.shape[-1]
-    logits = final(params, x.reshape(B * T, D)).reshape(B, T, -1)
-    return cache, logits
-
-
 class SpecDecoder:
     """Draft model + verification programs bolted onto one LLMEngine.
 
-    Owns the draft params and the draft KV (a block-id-parallel pool in
-    paged mode, a slot-parallel dense cache otherwise) and runs the
-    propose→verify→accept cycle of one engine step. The engine decides
-    WHEN a spec step is legal; this class only executes it.
+    Owns the draft params and the draft KV (a block-id-parallel pool) and
+    runs the propose→verify→accept cycle of one engine step. The engine
+    decides WHEN a spec step is legal; this class only executes it.
     """
 
     def __init__(self, engine, draft_cfg, k: int):
-        from ray_tpu.llm.engine import _model_ops
-
         if k < 1:
             raise ValueError(f"spec_decode_tokens must be >= 1, got {k}")
         target_cfg = engine.model_config
@@ -157,7 +100,7 @@ class SpecDecoder:
                 draft_cfg, max_seq=engine.config.max_seq
             )
         self.cfg = draft_cfg
-        self._model, self._decode_mod = _model_ops(draft_cfg)
+        self._model = paged.family(draft_cfg)
         if engine.config.draft_weights_path:
             # Trained/distilled draft checkpoint (same pickled-pytree
             # contract as LLMConfig.weights_path for the target): the
@@ -171,71 +114,31 @@ class SpecDecoder:
             self.params = self._model.init_params(
                 jax.random.key(engine.config.seed), draft_cfg
             )
-        B = engine.config.max_slots
-        if engine.paged:
-            from ray_tpu.models import paged
-
-            bs = engine._block_size
-            self.pool = paged.init_block_pool(
-                draft_cfg, engine.block_mgr.num_blocks, bs
-            )
-            # The pools (the draft's own, and the engine's through
-            # _verify) are donated, as the engine donates its own: each
-            # call below rebinds the pool it passed in.
-            self._d_prefill = jax.jit(
-                functools.partial(
-                    paged.paged_prefill, cfg=draft_cfg, block_size=bs
-                ),
-                donate_argnums=5,
-            )
-            self._d_decode = jax.jit(
-                functools.partial(
-                    paged.paged_decode, cfg=draft_cfg, block_size=bs
-                ),
-                donate_argnums=4,
-            )
-            self._verify = jax.jit(
-                functools.partial(
-                    paged.paged_verify, cfg=target_cfg, block_size=bs
-                ),
-                donate_argnums=4,
-            )
-        else:
-            self.cache = self._decode_mod.init_kv_cache(
-                draft_cfg, B, engine.config.max_seq
-            )
-            self._d_prefill = jax.jit(
-                functools.partial(self._dense_prefill_impl, cfg=draft_cfg)
-            )
-            self._d_decode = jax.jit(
-                functools.partial(
-                    self._decode_mod.decode_step, cfg=draft_cfg
-                )
-            )
-            self._verify = jax.jit(
-                functools.partial(dense_verify, cfg=target_cfg)
-            )
-
-    # -- draft prefill --------------------------------------------------------
-
-    def _dense_prefill_impl(self, params, tokens, length, cache, slot, cfg):
-        """Prefill ONE slot of the draft's dense cache (the engine's
-        slot-merge pattern, against the draft's own modules)."""
-        sub = {
-            "k": jax.lax.dynamic_slice_in_dim(cache["k"], slot, 1, axis=1),
-            "v": jax.lax.dynamic_slice_in_dim(cache["v"], slot, 1, axis=1),
-        }
-        sub, _logits = self._decode_mod.prefill(
-            params, tokens, length[None], sub, cfg
+        bs = engine._block_size
+        self.pool = paged.init_block_pool(
+            draft_cfg, engine.block_mgr.num_blocks, bs
         )
-        return {
-            "k": jax.lax.dynamic_update_slice_in_dim(
-                cache["k"], sub["k"], slot, axis=1
+        # The pools (the draft's own, and the engine's through
+        # _verify) are donated, as the engine donates its own: each
+        # call below rebinds the pool it passed in.
+        self._d_prefill = jax.jit(
+            functools.partial(
+                paged.paged_prefill, cfg=draft_cfg, block_size=bs
             ),
-            "v": jax.lax.dynamic_update_slice_in_dim(
-                cache["v"], sub["v"], slot, axis=1
+            donate_argnums=5,
+        )
+        self._d_decode = jax.jit(
+            functools.partial(
+                paged.paged_decode, cfg=draft_cfg, block_size=bs
             ),
-        }
+            donate_argnums=4,
+        )
+        self._verify = jax.jit(
+            functools.partial(
+                paged.paged_verify, cfg=target_cfg, block_size=bs
+            ),
+            donate_argnums=4,
+        )
 
     def prefill_draft(self, req) -> bool:
         """Run the draft model over ``req``'s WHOLE prompt so its KV covers
@@ -258,37 +161,28 @@ class SpecDecoder:
             return False
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :T] = req.prompt
-        if eng.paged:
-            self.pool, _ = self._d_prefill(
-                self.params,
-                jnp.asarray(toks),
-                jnp.asarray(T, jnp.int32),
-                jnp.asarray(0, jnp.int32),
-                jnp.asarray(eng.block_tables[req.slot]),
-                self.pool,
-            )
-        else:
-            self.cache = self._d_prefill(
-                self.params,
-                jnp.asarray(toks),
-                jnp.asarray(T, jnp.int32),
-                self.cache,
-                req.slot,
-            )
+        self.pool, _ = self._d_prefill(
+            self.params,
+            jnp.asarray(toks),
+            jnp.asarray(T, jnp.int32),
+            jnp.asarray(0, jnp.int32),
+            jnp.asarray(eng.block_tables[req.slot]),
+            self.pool,
+        )
         return True
 
     # -- the spec step --------------------------------------------------------
 
     def step(self, active: list) -> list:
         """One propose→verify→accept cycle for the whole decode batch.
-        Mutates the engine's pool/cache/positions/last_tokens exactly as a
+        Mutates the engine's pool/positions/last_tokens exactly as a
         run of vanilla steps would; returns the requests that finished."""
         eng = self.engine
         k = self.k
         instrument = _metrics.metrics_enabled()
         last = jnp.asarray(eng.last_tokens)
         pos = jnp.asarray(eng.positions)
-        tables = jnp.asarray(eng.block_tables) if eng.paged else None
+        tables = jnp.asarray(eng.block_tables)
         # 1) Draft proposes k tokens autoregressively. The chain stays
         # device-resident (each proposal feeds the next draft decode as a
         # jax array); only the final [B, k+1] token block and the verify
@@ -296,14 +190,9 @@ class SpecDecoder:
         proposals = []
         dlast, dpos = last, pos
         for _ in range(k):
-            if eng.paged:
-                self.pool, dlogits = self._d_decode(
-                    self.params, dlast, dpos, tables, self.pool
-                )
-            else:
-                self.cache, dlogits = self._d_decode(
-                    self.params, dlast, dpos, self.cache
-                )
+            self.pool, dlogits = self._d_decode(
+                self.params, dlast, dpos, tables, self.pool
+            )
             dlast = jnp.argmax(dlogits, axis=-1).astype(jnp.int32)
             proposals.append(dlast)
             dpos = dpos + 1
@@ -311,14 +200,9 @@ class SpecDecoder:
             [last[:, None]] + [p[:, None] for p in proposals], axis=1
         )  # [B, k+1]
         # 2) Target verifies all k+1 tokens in one forward.
-        if eng.paged:
-            eng.pool, logits = self._verify(
-                eng.params, tokens, pos, tables, eng.pool
-            )
-        else:
-            eng.cache, logits = self._verify(
-                eng.params, tokens, pos, eng.cache
-            )
+        eng.pool, logits = self._verify(
+            eng.params, tokens, pos, tables, eng.pool
+        )
         greedy = np.asarray(jnp.argmax(logits, axis=-1))  # raylint: disable=RL101 -- the spec step's intended sync: verify argmax readback feeding host-side acceptance
         prop = np.asarray(tokens)[:, 1:]  # raylint: disable=RL101 -- proposal readback paired with the verify argmax (host-side accept loop)
         # 3) Host-side acceptance per active slot: longest matching draft

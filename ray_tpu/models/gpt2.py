@@ -206,35 +206,46 @@ def _layer_norm(x, scale, bias, eps=1e-5):
     return (y * scale + bias).astype(x.dtype)
 
 
-def _attn_sublayer(x, p, cfg: GPT2Config, mesh=None, ring=False):
-    B, S, D = x.shape
-    H, Dh = cfg.n_head, cfg.head_dim
+def _qkv(x, p, cfg: GPT2Config):
+    """x [B, T, D] -> q, k, v, each [B, H, T, Dh]."""
+    B, T, D = x.shape
     h = _layer_norm(x, p["ln1_scale"], p["ln1_bias"])
     qkv = h @ p["qkv_w"].astype(cfg.dtype) + p["qkv_b"].astype(cfg.dtype)
-    q, k_, v = jnp.split(qkv, 3, axis=-1)
+    q, k, v = jnp.split(qkv, 3, axis=-1)
 
-    def heads(t):  # [B,S,D] -> [B,H,S,Dh]
-        return t.reshape(B, S, H, Dh).transpose(0, 2, 1, 3)
+    def heads(t):
+        return t.reshape(B, T, cfg.n_head, cfg.head_dim).transpose(0, 2, 1, 3)
 
+    return heads(q), heads(k), heads(v)
+
+
+def _attn_out(x, attn, p, cfg: GPT2Config):
+    """attn [B, H, T, Dh] through the output projection, onto x."""
+    B, H, T, Dh = attn.shape
+    attn = attn.transpose(0, 2, 1, 3).reshape(B, T, H * Dh)
+    return x + attn @ p["proj_w"].astype(cfg.dtype) + p["proj_b"].astype(cfg.dtype)
+
+
+def _attn_sublayer(x, p, cfg: GPT2Config, mesh=None, ring=False):
+    q, k_, v = _qkv(x, p, cfg)
     if ring:
         # Sequence sharded over sp: ring attention keeps K/V distributed
         # and rotates chunks over ICI instead of letting XLA re-gather the
         # full sequence per chip (SURVEY §5.7 — must-build).
         from ray_tpu.ops.ring_attention import ring_attention
 
-        attn = ring_attention(heads(q), heads(k_), heads(v), mesh=mesh)
+        attn = ring_attention(q, k_, v, mesh=mesh)
     else:
         attn = causal_attention(
-            heads(q),
-            heads(k_),
-            heads(v),
+            q,
+            k_,
+            v,
             impl=cfg.attn_impl,
             block_q=cfg.attn_block_q,
             block_k=cfg.attn_block_k,
             mesh=mesh,
         )
-    attn = attn.transpose(0, 2, 1, 3).reshape(B, S, D)
-    return x + attn @ p["proj_w"].astype(cfg.dtype) + p["proj_b"].astype(cfg.dtype)
+    return _attn_out(x, attn, p, cfg)
 
 
 def _mlp_sublayer(x, p, cfg: GPT2Config):
@@ -242,6 +253,31 @@ def _mlp_sublayer(x, p, cfg: GPT2Config):
     h = h @ p["fc_w"].astype(cfg.dtype) + p["fc_b"].astype(cfg.dtype)
     h = jax.nn.gelu(h, approximate=True)
     return x + h @ p["fc2_w"].astype(cfg.dtype) + p["fc2_b"].astype(cfg.dtype)
+
+
+def kv_hooks(cfg: GPT2Config, S: int):
+    """The hook table :mod:`ray_tpu.models.paged` serves this family
+    through (``paged.family``): learned positions, one key/value head a
+    query head, the dense MLP."""
+    H, Dh = cfg.n_head, cfg.head_dim
+
+    def embed(params, tokens, pos2d):
+        return (
+            params["wte"].astype(cfg.dtype)[tokens]
+            + params["wpe"].astype(cfg.dtype)[pos2d]
+        )
+
+    def qkv(x, p, pos2d):
+        return _qkv(x, p, cfg)
+
+    def finish(x, attn, p):
+        return _mlp_sublayer(_attn_out(x, attn, p, cfg), p, cfg)
+
+    def final(params, last):
+        h = _layer_norm(last, params["lnf_scale"], params["lnf_bias"])
+        return (h @ params["wte"].astype(cfg.dtype).T).astype(jnp.float32)
+
+    return embed, qkv, finish, final, H, H, Dh
 
 
 def _moe_sublayer(x, p, cfg: GPT2Config):

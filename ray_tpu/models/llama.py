@@ -209,6 +209,51 @@ def _mlp_sublayer(x, p, cfg: LlamaConfig):
     return x + (jax.nn.silu(gate) * up) @ p["w_down"].astype(cfg.dtype)
 
 
+def kv_hooks(cfg: LlamaConfig, S: int):
+    """The hook table :mod:`ray_tpu.models.paged` serves this family
+    through (``paged.family``): RoPE by gathered absolute position, keys and
+    values for the ``n_kv_head`` heads unexpanded."""
+    H, KH, Dh = cfg.n_head, cfg.n_kv_head, cfg.head_dim
+    cos_full, sin_full = rope_tables(cfg, S)
+
+    def embed(params, tokens, pos2d):
+        return params["wte"].astype(cfg.dtype)[tokens]
+
+    def qkv(x, p, pos2d):
+        B, T, _ = x.shape
+        h = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
+        q = (h @ p["wq"].astype(cfg.dtype)).reshape(B, T, H, Dh)
+        k = (h @ p["wk"].astype(cfg.dtype)).reshape(B, T, KH, Dh)
+        v = (h @ p["wv"].astype(cfg.dtype)).reshape(B, T, KH, Dh)
+        cos = cos_full[pos2d][:, :, None, :]  # [B, T, 1, half]
+        sin = sin_full[pos2d][:, :, None, :]
+
+        def rope(t):
+            t1, t2 = jnp.split(t, 2, axis=-1)
+            c = cos.astype(t.dtype)
+            s = sin.astype(t.dtype)
+            return jnp.concatenate(
+                [t1 * c - t2 * s, t1 * s + t2 * c], axis=-1
+            )
+
+        heads = lambda t: t.transpose(0, 2, 1, 3)
+        return heads(rope(q)), heads(rope(k)), heads(v)
+
+    def finish(x, attn, p):  # attn [B, H, T, Dh]
+        B, Hh, T, _ = attn.shape
+        a = attn.transpose(0, 2, 1, 3).reshape(B, T, cfg.d_model)
+        x = x + a @ p["wo"].astype(cfg.dtype)
+        return _mlp_sublayer(x, p, cfg)
+
+    def final(params, last):  # last [B, D] -> [B, vocab] f32
+        h = _rms_norm(last, params["final_norm"], cfg.rms_eps)
+        return (h @ params["lm_head"].astype(cfg.dtype)).astype(
+            jnp.float32
+        )
+
+    return embed, qkv, finish, final, H, KH, Dh
+
+
 def hidden(
     params: Params, tokens: jax.Array, cfg: LlamaConfig, mesh=None
 ) -> jax.Array:

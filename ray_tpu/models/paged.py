@@ -15,8 +15,9 @@ kernels:
   their (layer, block, offset) homes; the attending pass gathers the
   request's blocks back into a dense ``[KH, S, Dh]`` row (a *transient* —
   XLA frees it after the layer) and runs the same masked grouped-head
-  einsums as the dense cache path. Identical math ⇒ exact-logit parity
-  with :mod:`gpt2_decode` / :mod:`llama_decode`, which the tests assert.
+  einsums as the training forward (``gpt2.forward``, ``llama.forward``).
+  Identical math ⇒ logit parity with it position by position, which the
+  tests assert.
 - **The pool is written in place.** The layer scan carries the whole pool
   and scans over the layer index, so each layer's scatter writes a few
   rows into the buffer it was handed; the only slab-sized work left is
@@ -26,17 +27,17 @@ kernels:
   copy of the pool at entry, which the compiler inserts.
 - **Static shapes everywhere**: W, block, and the prefill bucket are
   compile-time constants; positions/tables are traced operands. Two
-  compiled programs (prefill-per-bucket + decode), like the dense path.
+  compiled programs (prefill-per-bucket + decode).
 - **Prefix sharing is free**: a pooled prefix is a list of block ids; a
   hit points the new request's first P/block table entries at the shared
-  blocks (host-side refcount) — no device copy at all, where the dense
-  engine had to copy pooled KV into the slot row.
+  blocks (host-side refcount) — no device copy at all.
 
-Family dispatch is by the configuration's ``family`` name. GPT-2
-(learned-position MHA) and Llama (RoPE GQA) share everything here — scatter,
-gather, masking, grouped attention — behind a small hook table, because GQA
-with group=1 *is* MHA. A family whose cache is not keys and values per head
-supplies its cache and its layer bodies itself (``_OWN_PROGRAMS``):
+Family dispatch is by the configuration's ``family`` name, looked up once
+(:func:`family`). GPT-2 (learned-position MHA) and Llama (RoPE GQA) share
+everything here — scatter, gather, masking, grouped attention — and each
+supplies a small hook table (``kv_hooks``), because GQA with group=1 *is*
+MHA. A family whose cache is not keys and values per head supplies its cache
+and its layer bodies itself:
 
 - **What a pool is now.** Either blocks of keys and values, as above, or —
   for ``kimi_linear`` — latent rows in blocks (``"ckv": [L_mla, N, block,
@@ -60,21 +61,39 @@ import jax.numpy as jnp
 
 Params = dict
 
-# Families that bring their own cache and layer bodies: the module holds
-# ``init_pool``, ``paged_prefill``, ``paged_decode`` and ``has_recurrent_state``.
-_OWN_PROGRAMS = {"kimi_linear": "ray_tpu.models.kimi_linear"}
+# Family name -> its module: the one place a family is looked up by name.
+_FAMILIES = {
+    "gpt2": "ray_tpu.models.gpt2",
+    "llama": "ray_tpu.models.llama",
+    "kimi_linear": "ray_tpu.models.kimi_linear",
+}
 
 
-def _own_programs(cfg):
-    name = _OWN_PROGRAMS.get(cfg.family)
-    return importlib.import_module(name) if name else None
+def family(cfg):
+    """The module of the configuration's family. It supplies ``init_params``,
+    ``param_logical_specs`` where the family has sharding rules, and either
+    ``kv_hooks(cfg, S)`` (keys and values per head: the pool and the
+    programs below serve it) or a cache and programs of its own
+    (``init_pool``, ``paged_prefill``, ``paged_decode``,
+    ``has_recurrent_state``).
+
+    ``kv_hooks`` returns ``(embed, qkv, finish, final, H, KH, Dh)``. The
+    hooks take ``pos2d``, always [B, T] absolute positions — prefill passes
+    ``start + arange(T)`` broadcast over one row, decode passes per-slot
+    ``positions[:, None]``; the same hooks serve both."""
+    name = _FAMILIES.get(cfg.family)
+    if name is None:
+        raise ValueError(
+            f"no paged programs for the family {cfg.family!r} "
+            f"(known: {', '.join(_FAMILIES)})"
+        )
+    return importlib.import_module(name)
 
 
 def has_recurrent_state(cfg) -> bool:
     """Whether part of the family's cache is a state per slot that block
     tables do not reach (module docstring)."""
-    own = _own_programs(cfg)
-    return own is not None and own.has_recurrent_state
+    return getattr(family(cfg), "has_recurrent_state", False)
 
 
 def init_block_pool(cfg, num_blocks: int, block_size: int, slots=None):
@@ -82,101 +101,15 @@ def init_block_pool(cfg, num_blocks: int, block_size: int, slots=None):
     dtype. KH is the KV-head count (unexpanded GQA for Llama). A family with
     a state per slot sizes it for ``slots`` sequences (the engine's
     ``max_slots``) and a scratch row."""
-    own = _own_programs(cfg)
-    if own is not None:
-        return own.init_pool(cfg, num_blocks, block_size, slots)
+    mod = family(cfg)
+    if not hasattr(mod, "kv_hooks"):
+        return mod.init_pool(cfg, num_blocks, block_size, slots)
     kh = getattr(cfg, "n_kv_head", None) or cfg.n_head
     shape = (cfg.n_layer, num_blocks, kh, block_size, cfg.head_dim)
     return {
         "k": jnp.zeros(shape, cfg.dtype),
         "v": jnp.zeros(shape, cfg.dtype),
     }
-
-
-# ---------------------------------------------------------------------------
-# Family hooks
-
-
-def _family(cfg, S: int):
-    """Hook table: embed / qkv (position-aware) / finish / final.
-
-    ``pos2d`` is always [B, T] absolute positions — prefill passes
-    ``start + arange(T)`` broadcast over one row, decode passes per-slot
-    ``positions[:, None]``; the same hooks serve both.
-    """
-    if cfg.family == "llama":
-        from ray_tpu.models.llama import (
-            _mlp_sublayer,
-            _rms_norm,
-            rope_tables,
-        )
-
-        H, KH, Dh = cfg.n_head, cfg.n_kv_head, cfg.head_dim
-        cos_full, sin_full = rope_tables(cfg, S)
-
-        def embed(params, tokens, pos2d):
-            return params["wte"].astype(cfg.dtype)[tokens]
-
-        def qkv(x, p, pos2d):
-            B, T, _ = x.shape
-            h = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
-            q = (h @ p["wq"].astype(cfg.dtype)).reshape(B, T, H, Dh)
-            k = (h @ p["wk"].astype(cfg.dtype)).reshape(B, T, KH, Dh)
-            v = (h @ p["wv"].astype(cfg.dtype)).reshape(B, T, KH, Dh)
-            cos = cos_full[pos2d][:, :, None, :]  # [B, T, 1, half]
-            sin = sin_full[pos2d][:, :, None, :]
-
-            def rope(t):
-                t1, t2 = jnp.split(t, 2, axis=-1)
-                c = cos.astype(t.dtype)
-                s = sin.astype(t.dtype)
-                return jnp.concatenate(
-                    [t1 * c - t2 * s, t1 * s + t2 * c], axis=-1
-                )
-
-            heads = lambda t: t.transpose(0, 2, 1, 3)
-            return heads(rope(q)), heads(rope(k)), heads(v)
-
-        def finish(x, attn, p):  # attn [B, H, T, Dh]
-            B, Hh, T, _ = attn.shape
-            a = attn.transpose(0, 2, 1, 3).reshape(B, T, cfg.d_model)
-            x = x + a @ p["wo"].astype(cfg.dtype)
-            return _mlp_sublayer(x, p, cfg)
-
-        def final(params, last):  # last [B, D] -> [B, vocab] f32
-            h = _rms_norm(last, params["final_norm"], cfg.rms_eps)
-            return (h @ params["lm_head"].astype(cfg.dtype)).astype(
-                jnp.float32
-            )
-
-    elif cfg.family == "gpt2":
-        from ray_tpu.models.gpt2 import _layer_norm
-        from ray_tpu.models.gpt2_decode import _finish_block, _qkv
-
-        H, KH, Dh = cfg.n_head, cfg.n_head, cfg.head_dim
-
-        def embed(params, tokens, pos2d):
-            return (
-                params["wte"].astype(cfg.dtype)[tokens]
-                + params["wpe"].astype(cfg.dtype)[pos2d]
-            )
-
-        def qkv(x, p, pos2d):
-            return _qkv(x, p, cfg)
-
-        def finish(x, attn, p):
-            return _finish_block(x, attn, p, cfg)
-
-        def final(params, last):
-            h = _layer_norm(last, params["lnf_scale"], params["lnf_bias"])
-            return (h @ params["wte"].astype(cfg.dtype).T).astype(
-                jnp.float32
-            )
-
-    else:
-        raise ValueError(f"no key/value hooks for the family {cfg.family!r}")
-
-    return embed, qkv, finish, final, H, KH, Dh
 
 
 # ---------------------------------------------------------------------------
@@ -231,16 +164,16 @@ def paged_prefill(
     The one prefill program serves both the fresh path (start=0) and the
     prefix-continue path — attention always spans the full gathered row
     under the mask ``col <= start + row`` (the static-shape trade)."""
-    own = _own_programs(cfg)
-    if own is not None:
-        return own.paged_prefill(
+    mod = family(cfg)
+    if not hasattr(mod, "kv_hooks"):
+        return mod.paged_prefill(
             params, tokens, length, start, table, pool, cfg,
             block_size=block_size, slot=slot,
         )
     B, T = tokens.shape
     W = table.shape[0]
     S = W * block_size
-    embed, qkv, finish, final, H, KH, Dh = _family(cfg, S)
+    embed, qkv, finish, final, H, KH, Dh = mod.kv_hooks(cfg, S)
     group = H // KH
 
     pos = start + jnp.arange(T, dtype=jnp.int32)  # [T]
@@ -305,7 +238,7 @@ def paged_verify(
     B, T = tokens.shape
     W = tables.shape[1]
     S = W * block_size
-    embed, qkv, finish, final, H, KH, Dh = _family(cfg, S)
+    embed, qkv, finish, final, H, KH, Dh = family(cfg).kv_hooks(cfg, S)
     group = H // KH
 
     pos2d = positions[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
@@ -357,16 +290,16 @@ def paged_decode(
     family that has some. Free slots must point their table at
     the scratch block (id 0) so their garbage writes never land in a
     block another request owns."""
-    own = _own_programs(cfg)
-    if own is not None:
-        return own.paged_decode(
+    mod = family(cfg)
+    if not hasattr(mod, "kv_hooks"):
+        return mod.paged_decode(
             params, last_tokens, positions, tables, pool, cfg,
             block_size=block_size, live=live,
         )
     B = last_tokens.shape[0]
     W = tables.shape[1]
     S = W * block_size
-    embed, qkv, finish, final, H, KH, Dh = _family(cfg, S)
+    embed, qkv, finish, final, H, KH, Dh = mod.kv_hooks(cfg, S)
     group = H // KH
 
     x = embed(params, last_tokens[:, None], positions[:, None])  # [B,1,D]

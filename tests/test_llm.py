@@ -1,17 +1,15 @@
-"""LLM tier: KV-cache decode parity, continuous batching, OpenAI serving.
+"""LLM tier: continuous batching, OpenAI serving.
 
 Reference parity: python/ray/llm tests (engine + serve integration),
-compressed; the decode-vs-forward parity test is the correctness anchor the
-reference outsources to vLLM's own suite.
+compressed; the decode-vs-forward parity tests, the correctness anchor the
+reference outsources to vLLM's own suite, are in test_llm_paged_kv.py.
 """
 
 import dataclasses
 import json
 import urllib.request
 
-import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 import ray_tpu
@@ -24,7 +22,6 @@ from ray_tpu.llm import (
     build_openai_app,
 )
 from ray_tpu.models import gpt2
-from ray_tpu.models.gpt2_decode import decode_step, init_kv_cache, prefill
 
 
 def tiny_cfg(**kw):
@@ -32,43 +29,6 @@ def tiny_cfg(**kw):
     return dataclasses.replace(
         cfg, dtype=jnp.float32, attn_impl="reference", **kw
     )
-
-
-def test_decode_matches_full_forward():
-    """Teacher-forced decode through the KV cache must reproduce the
-    training path's logits position by position."""
-    cfg = tiny_cfg()
-    params = gpt2.init_params(jax.random.key(0), cfg)
-    toks = np.asarray(
-        jax.random.randint(jax.random.key(1), (2, 12), 0, cfg.vocab_size)
-    )
-    full = np.asarray(gpt2.forward(params, jnp.asarray(toks), cfg))
-
-    T0 = 5  # prompt length; rest decoded token-by-token
-    cache = init_kv_cache(cfg, n_slots=2, max_seq=32)
-    cache, logits = prefill(
-        params,
-        jnp.asarray(toks[:, :T0]),
-        jnp.full((2,), T0, jnp.int32),
-        cache,
-        cfg,
-    )
-    np.testing.assert_allclose(
-        np.asarray(logits), full[:, T0 - 1], rtol=1e-4, atol=1e-4
-    )
-    positions = np.full((2,), T0, np.int32)
-    for t in range(T0, toks.shape[1]):
-        cache, logits = decode_step(
-            params,
-            jnp.asarray(toks[:, t]),
-            jnp.asarray(positions),
-            cache,
-            cfg,
-        )
-        np.testing.assert_allclose(
-            np.asarray(logits), full[:, t], rtol=1e-4, atol=1e-4
-        )
-        positions += 1
 
 
 def test_engine_greedy_deterministic():
@@ -230,7 +190,7 @@ def test_openai_serving_e2e(cluster):
 
 def test_llama_family_engine_generates_and_prefix_caches():
     """The engine serves the Llama family through the same slot machinery:
-    GQA cache ([L, B, KV_HEADS, S, Dh] — smaller than MHA), RoPE-aware
+    GQA block pool (KV heads unexpanded — smaller than MHA), RoPE-aware
     prefill/continue/decode, prefix caching included."""
     from ray_tpu.llm.config import LLMConfig, SamplingParams
     from ray_tpu.llm.engine import LLMEngine
@@ -249,7 +209,6 @@ def test_llama_family_engine_generates_and_prefix_caches():
         )
     )
     # GQA block pool stores KV heads unexpanded: [L, N, KH, block, Dh].
-    assert eng.paged
     assert eng.pool["k"].shape[0] == 2  # layers
     assert eng.pool["k"].shape[2] == 2  # n_kv_head, NOT n_head=4
     assert eng.pool["k"].shape[4] == 16  # head_dim

@@ -297,15 +297,6 @@ def test_controller_strips_roles_under_kill_switch():
         asyncio.set_event_loop(None)
 
 
-def test_disagg_requires_paged_cache():
-    from ray_tpu.llm.serve_llm import build_openai_app
-
-    with pytest.raises(ValueError, match="paged"):
-        build_openai_app(
-            _cfg(kv_block_size=0), name="x", prefill_replicas=1
-        )
-
-
 def test_disagg_two_hop_e2e_bit_identical(cluster):
     """Serve e2e: a 1-prefill + 1-decode deployment answers exactly like
     a unified single replica (greedy), handoffs counted once per request,

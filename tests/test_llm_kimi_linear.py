@@ -119,7 +119,6 @@ def test_padded_bucket_tails_leave_the_state_alone():
 
 @pytest.mark.parametrize("what, kw, match", [
     ("speculative verification", {"spec_decode_tokens": 2}, "spec_decode_tokens"),
-    ("the dense cache", {"kv_block_size": 0}, "kv_block_size=0"),
     ("tensor parallelism", {"tensor_parallelism": 2}, "tensor_parallelism"),
     ("the disaggregated export", "prefill_only", "prefill_only"),
     ("the disaggregated import", "handoff", "handoff"),

@@ -3,8 +3,8 @@
 Reference parity: the serving-memory capability vLLM gives the reference
 (paged attention + refcounted prefix blocks,
 python/ray/llm/_internal/serve/engines/vllm/vllm_models.py:89) — the
-round-4 verdict's missing #1. The parity tests pin the paged path to the
-dense cache modules bit-for-bit-close; the A/B pins the point of paging:
+round-4 verdict's missing #1. The parity tests pin the paged programs to
+the training forward, logit by logit; the A/B pins the point of paging:
 more admitted requests at equal HBM for mixed-length workloads.
 """
 
@@ -19,7 +19,6 @@ import pytest
 from ray_tpu.llm import LLMConfig, LLMEngine, SamplingParams
 from ray_tpu.llm.block_manager import BlockManager
 from ray_tpu.models import gpt2, paged
-from ray_tpu.models import gpt2_decode
 
 
 def tiny_cfg(**kw):
@@ -49,148 +48,213 @@ def test_block_manager_alloc_refcount_free():
         m.alloc(8)
 
 
-# -- exact-logit parity vs the dense cache path -------------------------------
+# -- the paged programs against the training forward ---------------------------
+#
+# The training forward (gpt2.forward / llama.forward: float32, the reference
+# attention) is the plain, independent writing of the model; the paged
+# programs must give its logits position by position, whatever the block
+# layout. One window of 32 positions in blocks of 8, the tables scattered.
+
+BLOCK, WINDOW = 8, 32
+TABLES = np.array([[5, 2, 7, 3], [1, 6, 4, 8]], np.int32)  # block 0: scratch
+TOL = dict(rtol=1e-4, atol=1e-4)
+TOL_CHAINED = dict(rtol=2e-4, atol=2e-4)  # a result of two programs in a row
 
 
-def _paged_greedy_logits(cfg, params, toks, T0, block_size=8):
-    """Prefill [0,T0) then teacher-forced decode, via the paged path."""
-    W = 32 // block_size
-    pool = paged.init_block_pool(cfg, num_blocks=2 * W + 1, block_size=block_size)
-    table = np.zeros(W, np.int32)
-    need = -(-toks.shape[1] // block_size)
-    table[:need] = np.arange(1, need + 1)
-    pf = jax.jit(
-        lambda p, t, l, s, tb, pl: paged.paged_prefill(
-            p, t, l, s, tb, pl, cfg, block_size=block_size
+def _family_model(family):
+    """(config, module, params): float32, the reference attention."""
+    if family == "llama":
+        from ray_tpu.models import llama as mod
+        from ray_tpu.models.llama import LlamaConfig
+
+        cfg = LlamaConfig.tiny(
+            n_layer=2, d_model=64, n_head=4, n_kv_head=2, max_seq=128
         )
-    )
-    dc = jax.jit(
-        lambda p, lt, po, tb, pl: paged.paged_decode(
-            p, lt, po, tb, pl, cfg, block_size=block_size
+        cfg = dataclasses.replace(cfg, dtype=jnp.float32, attn_impl="reference")
+    else:
+        mod, cfg = gpt2, tiny_cfg()
+    return cfg, mod, mod.init_params(jax.random.key(0), cfg)
+
+
+def _programs(cfg):
+    """The three programs jitted undonated, with a prefill that pads its
+    tokens into a 16 bucket as the engine does."""
+    kw = dict(cfg=cfg, block_size=BLOCK)
+    prefill = jax.jit(functools.partial(paged.paged_prefill, **kw))
+    i32 = lambda n: jnp.asarray(n, jnp.int32)  # noqa: E731
+
+    def fill(params, toks, start, table, pool):
+        padded = np.zeros((1, 16), np.int32)
+        padded[0, : len(toks)] = toks
+        return prefill(
+            params, jnp.asarray(padded), i32(len(toks)), i32(start),
+            jnp.asarray(table), pool,
         )
+
+    return (
+        fill,
+        jax.jit(functools.partial(paged.paged_decode, **kw)),
+        jax.jit(functools.partial(paged.paged_verify, **kw)),
     )
-    pool, logits = pf(
-        params,
-        jnp.asarray(toks[:1, :T0]),
-        jnp.asarray(T0, jnp.int32),
-        jnp.asarray(0, jnp.int32),
-        jnp.asarray(table),
-        pool,
+
+
+@pytest.mark.parametrize(
+    "phase", ["whole_prefill", "continued_prefill", "decode", "verify"]
+)
+@pytest.mark.parametrize("family", ["gpt2", "llama"])
+def test_paged_programs_match_the_training_forward(family, phase):
+    """Every paged program reproduces the training path's logits: a whole
+    prefill in a padded bucket, a prefill continued behind a block boundary
+    (the prefix-cache and chunked-prefill path), teacher-forced decode of
+    two slots at unlike positions, and ``paged_verify`` of k + 1 tokens a
+    slot followed by a decode step over what it wrote."""
+    cfg, mod, params = _family_model(family)
+    toks = np.asarray(
+        jax.random.randint(jax.random.key(1), (2, 24), 0, cfg.vocab_size)
     )
-    out = [np.asarray(logits)]
-    positions = np.full((1,), T0, np.int32)
-    for t in range(T0, toks.shape[1]):
-        pool, logits = dc(
-            params,
-            jnp.asarray(toks[:1, t]),
-            jnp.asarray(positions),
-            jnp.asarray(table[None]),
-            pool,
+    full = np.asarray(mod.forward(params, jnp.asarray(toks), cfg))  # [2, 24, V]
+    fill, decode, verify = _programs(cfg)
+    pool = paged.init_block_pool(cfg, num_blocks=9, block_size=BLOCK)
+    assert TABLES.shape[1] * BLOCK == WINDOW
+
+    if phase == "whole_prefill":
+        pool, logits = fill(params, toks[0, :13], 0, TABLES[0], pool)
+        np.testing.assert_allclose(np.asarray(logits), full[0, 12], **TOL)
+        return
+    if phase == "continued_prefill":
+        pool, first = fill(params, toks[0, :BLOCK], 0, TABLES[0], pool)
+        np.testing.assert_allclose(np.asarray(first), full[0, BLOCK - 1], **TOL)
+        pool, logits = fill(params, toks[0, BLOCK:19], BLOCK, TABLES[0], pool)
+        np.testing.assert_allclose(np.asarray(logits), full[0, 18], **TOL_CHAINED)
+        return
+
+    # Two slots prefilled to unlike lengths, then stepped together.
+    lengths = np.array([5, 11], np.int32)
+    for b in range(2):
+        pool, logits = fill(params, toks[b, : lengths[b]], 0, TABLES[b], pool)
+        np.testing.assert_allclose(
+            np.asarray(logits), full[b, lengths[b] - 1], **TOL
         )
-        out.append(np.asarray(logits)[0])
+    rows, tables = np.arange(2), jnp.asarray(TABLES)
+    positions = lengths.copy()
+    if phase == "verify":
+        k1 = 5  # the carried token and four proposals
+        window = np.stack([toks[b, positions[b] : positions[b] + k1] for b in rows])
+        pool, logits = verify(
+            params, jnp.asarray(window), jnp.asarray(positions), tables, pool
+        )
+        want = np.stack([full[b, positions[b] : positions[b] + k1] for b in rows])
+        np.testing.assert_allclose(np.asarray(logits), want, **TOL_CHAINED)
+        positions += k1
+    for _ in range(6 if phase == "decode" else 1):
+        pool, logits = decode(
+            params, jnp.asarray(toks[rows, positions]), jnp.asarray(positions),
+            tables, pool,
+        )
+        np.testing.assert_allclose(
+            np.asarray(logits), full[rows, positions], **TOL_CHAINED
+        )
         positions += 1
+
+
+def _greedy_rollout(mod, cfg, params, prompt, max_tokens, stop, window=64):
+    """Greedy tokens from the training forward alone: the whole sequence
+    through ``forward`` for every token, no cache of any kind. Causal, so
+    the padding behind the last token changes nothing."""
+    forward = jax.jit(functools.partial(mod.forward, cfg=cfg))
+    seq, out = list(prompt), []
+    while len(out) < max_tokens:
+        padded = np.zeros((1, window), np.int32)
+        padded[0, : len(seq)] = seq
+        logits = np.asarray(forward(params, jnp.asarray(padded)))[0, len(seq) - 1]
+        out.append(int(np.argmax(logits)))
+        seq.append(out[-1])
+        if out[-1] == stop:
+            break
     return out
 
 
-def test_paged_logits_match_dense_gpt2():
-    """Paged prefill+decode reproduce the dense cache path's logits —
-    the scatter/gather layout change must not change a single output."""
-    cfg = tiny_cfg()
-    params = gpt2.init_params(jax.random.key(0), cfg)
-    toks = np.asarray(
-        jax.random.randint(jax.random.key(1), (1, 12), 0, cfg.vocab_size)
-    )
-    T0 = 5
-    cache = gpt2_decode.init_kv_cache(cfg, n_slots=1, max_seq=32)
-    cache, logits = gpt2_decode.prefill(
-        params, jnp.asarray(toks[:, :T0]), jnp.full((1,), T0, jnp.int32),
-        cache, cfg,
-    )
-    dense = [np.asarray(logits)[0]]
-    positions = np.full((1,), T0, np.int32)
-    for t in range(T0, toks.shape[1]):
-        cache, logits = gpt2_decode.decode_step(
-            params, jnp.asarray(toks[:, t]), jnp.asarray(positions),
-            cache, cfg,
-        )
-        dense.append(np.asarray(logits)[0])
-        positions += 1
-
-    paged_out = _paged_greedy_logits(cfg, params, toks, T0)
-    assert len(paged_out) == len(dense)
-    for a, b in zip(paged_out, dense):
-        np.testing.assert_allclose(
-            np.ravel(a), np.ravel(b), rtol=1e-4, atol=1e-4
-        )
-
-
-def test_paged_logits_match_dense_llama_gqa():
-    """Same parity for the Llama family: RoPE positions and the
-    unexpanded-GQA grouped attention survive the block layout."""
-    from ray_tpu.models import llama, llama_decode
-    from ray_tpu.models.llama import LlamaConfig
-
-    cfg = LlamaConfig.tiny(
-        n_layer=2, d_model=64, n_head=4, n_kv_head=2, max_seq=128
-    )
-    cfg = dataclasses.replace(cfg, dtype=jnp.float32)
-    params = llama.init_params(jax.random.key(0), cfg)
-    toks = np.asarray(
-        jax.random.randint(jax.random.key(1), (1, 10), 0, cfg.vocab_size)
-    )
-    T0 = 4
-    cache = llama_decode.init_kv_cache(cfg, n_slots=1, max_seq=32)
-    cache, logits = llama_decode.prefill(
-        params, jnp.asarray(toks[:, :T0]), jnp.full((1,), T0, jnp.int32),
-        cache, cfg,
-    )
-    dense = [np.asarray(logits)[0]]
-    positions = np.full((1,), T0, np.int32)
-    for t in range(T0, toks.shape[1]):
-        cache, logits = llama_decode.decode_step(
-            params, jnp.asarray(toks[:, t]), jnp.asarray(positions),
-            cache, cfg,
-        )
-        dense.append(np.asarray(logits)[0])
-        positions += 1
-
-    paged_out = _paged_greedy_logits(cfg, params, toks, T0)
-    for a, b in zip(paged_out, dense):
-        np.testing.assert_allclose(
-            np.ravel(a), np.ravel(b), rtol=1e-4, atol=1e-4
-        )
-
-
-# -- engine-level: paged vs dense token parity --------------------------------
-
-
-def test_engine_paged_tokens_match_dense_engine():
-    """Greedy generations from the paged engine equal the dense engine's,
-    including with a shared prefix in play (block sharing on)."""
-    model = tiny_cfg()
+@pytest.mark.parametrize("family", ["gpt2", "llama"])
+def test_engine_greedy_tokens_match_the_forward_rollout(family):
+    """Greedy generations from the engine are the training forward's own
+    greedy rollout, with a shared prefix in play (block sharing on) and
+    more requests than slots."""
+    cfg, mod, _ = _family_model(family)
     shared = list(range(3, 35))  # 32-token aligned prefix
     prompts = [shared + [40], shared + [41], [7, 8, 9]]
-    sampling = SamplingParams(max_tokens=6, temperature=0.0)
-
-    def run(block_size):
-        eng = LLMEngine(
-            LLMConfig(
-                model_config=model, max_slots=2, max_seq=64,
-                prefill_buckets=(16, 32, 64), kv_block_size=block_size,
-                prefix_chunk=16, seed=0,
-            )
+    eng = LLMEngine(
+        LLMConfig(
+            model_config=cfg, max_slots=2, max_seq=64,
+            prefill_buckets=(16, 32, 64), kv_block_size=16, prefix_chunk=16,
+            seed=0,
         )
-        return [o["token_ids"] for o in eng.generate(prompts, sampling)], eng
+    )
+    outs = eng.generate(prompts, SamplingParams(max_tokens=6, temperature=0.0))
+    assert eng.stats["prefix_hits"] >= 1
+    stop = eng.tokenizer.eos_id
+    for prompt, out in zip(prompts, outs):
+        assert out["token_ids"] == _greedy_rollout(
+            mod, cfg, eng.params, prompt, 6, stop
+        )
 
-    paged_toks, eng_p = run(16)
-    dense_toks, _ = run(0)
-    assert paged_toks == dense_toks
-    assert eng_p.paged and eng_p.stats["prefix_hits"] >= 1
+
+# -- one cache ------------------------------------------------------------------
+
+
+def _tiny_model_of(family):
+    if family == "kimi_linear":
+        from ray_tpu.models.kimi_linear import KimiLinearConfig
+
+        return KimiLinearConfig.tiny(max_seq=128)
+    return _family_model(family)[0]
+
+
+@pytest.mark.parametrize("family", ["gpt2", "llama", "kimi_linear"])
+def test_kv_block_size_is_a_block_size_not_a_switch(family):
+    """The engine has one cache, the block pool: a ``kv_block_size`` that
+    is no block size is refused by name at construction, for every family."""
+    for size in (0, -16):
+        with pytest.raises(ValueError, match="kv_block_size") as e:
+            LLMEngine(
+                LLMConfig(
+                    model_config=_tiny_model_of(family), max_slots=2,
+                    max_seq=64, prefill_buckets=(16,), kv_block_size=size,
+                )
+            )
+        assert "one cache" in str(e.value)
+
+
+def test_a_family_is_looked_up_once_and_an_unknown_one_is_refused():
+    """``paged.family`` is the one lookup by name: every family's module
+    brings ``init_params`` and either its hooks or its own programs, and a
+    name it does not know is a ``ValueError`` that says the name."""
+    for name in ("gpt2", "llama", "kimi_linear"):
+        mod = paged.family(_tiny_model_of(name))
+        assert callable(mod.init_params)
+        own = all(
+            hasattr(mod, f) for f in ("init_pool", "paged_prefill", "paged_decode")
+        )
+        assert hasattr(mod, "kv_hooks") != own
+        assert paged.has_recurrent_state(_tiny_model_of(name)) == (
+            name == "kimi_linear"
+        )
+
+    @dataclasses.dataclass(frozen=True)
+    class Other:
+        family = "mamba"
+
+    for call in (
+        lambda: paged.family(Other()),
+        lambda: paged.init_block_pool(Other(), 4, 16),
+        lambda: paged.has_recurrent_state(Other()),
+    ):
+        with pytest.raises(ValueError, match="mamba"):
+            call()
 
 
 def test_engine_paged_prefix_shares_blocks_without_copy():
     """A pooled-prefix hit points the new request at the SAME physical
-    blocks (refcount > 1) — no device copy, where dense mode copied."""
+    blocks (refcount > 1) — no device copy."""
     model = tiny_cfg()
     eng = LLMEngine(
         LLMConfig(
@@ -221,18 +285,13 @@ def test_engine_paged_prefix_shares_blocks_without_copy():
 
 
 def test_paged_admits_4x_concurrency_at_equal_hbm():
-    """The A/B the verdict asked for: equal KV HBM, mixed short requests —
-    the paged engine admits >= 4x the dense engine's concurrency."""
+    """Equal KV HBM, mixed short requests: where a row of ``max_seq``
+    positions a request would hold two requests, the block pool admits
+    at least four times as many."""
     model = tiny_cfg()
-    # Dense: 2 slots x 256 rows = 512 cache rows.
-    dense = LLMEngine(
-        LLMConfig(
-            model_config=model, max_slots=2, max_seq=256,
-            prefill_buckets=(16,), kv_block_size=0, seed=0,
-            enable_prefix_caching=False,
-        )
-    )
-    # Paged: same 512 rows = 32 blocks of 16, but 16 slots.
+    # 512 cache positions = 32 blocks of 16. Held as whole rows of
+    # max_seq = 256 positions a request they are room for 512 // 256 = 2.
+    rows_of_max_seq = 512 // 256
     pag = LLMEngine(
         LLMConfig(
             model_config=model, max_slots=16, max_seq=256,
@@ -241,14 +300,11 @@ def test_paged_admits_4x_concurrency_at_equal_hbm():
         )
     )
     sampling = SamplingParams(max_tokens=8)  # 8+8 tokens -> 1 block each
-    for i, eng in enumerate((dense, pag)):
-        for r in range(16):
-            eng.add_request(f"q{r}", [10 + r] * 8, sampling)
-        eng.step()
-    dense_active = sum(r is not None for r in dense._slot_req)
+    for r in range(16):
+        pag.add_request(f"q{r}", [10 + r] * 8, sampling)
+    pag.step()
     paged_active = sum(r is not None for r in pag._slot_req)
-    assert dense_active == 2
-    assert paged_active >= 4 * dense_active  # 16 in practice
+    assert paged_active >= 4 * rows_of_max_seq  # 16 in practice
     assert pag.kv_stats()["blocks_used"] == paged_active
     # And everything still completes correctly.
     while pag.has_unfinished():
@@ -394,18 +450,7 @@ def _scan_layers_as_before(body, x, params, pool):
 def _family_case(family):
     """Three slots over a pool holding stale values: slots 0 and 1 share
     prefix block 7 and own scattered blocks, slot 2 is free (table -> 0)."""
-    if family == "llama":
-        from ray_tpu.models import llama
-        from ray_tpu.models.llama import LlamaConfig
-
-        cfg = LlamaConfig.tiny(
-            n_layer=3, d_model=64, n_head=4, n_kv_head=2, max_seq=128
-        )
-        cfg = dataclasses.replace(cfg, dtype=jnp.float32)
-        params = llama.init_params(jax.random.key(0), cfg)
-    else:
-        cfg = tiny_cfg()
-        params = gpt2.init_params(jax.random.key(0), cfg)
+    cfg, _, params = _family_model(family)
     bs = 8
     shape = paged.init_block_pool(cfg, num_blocks=12, block_size=bs)["k"].shape
     pool = {

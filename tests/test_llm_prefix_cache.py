@@ -5,11 +5,6 @@ serve/_private/request_router/prefix_aware/prefix_aware_router.py —
 round-3 verdict missing #4.
 """
 
-import dataclasses
-
-import numpy as np
-import pytest
-
 from ray_tpu.llm.config import LLMConfig, SamplingParams
 from ray_tpu.llm.engine import LLMEngine
 from ray_tpu.models.gpt2 import GPT2Config
@@ -27,59 +22,6 @@ def _tiny_config(**kw):
     )
     defaults.update(kw)
     return LLMConfig(**defaults)
-
-
-def test_prefill_continue_matches_full_prefill():
-    """Logits from (cached prefix + continue) == full prefill, so prefix
-    reuse cannot change sampled outputs."""
-    import jax
-    import jax.numpy as jnp
-
-    from ray_tpu.models import gpt2
-    from ray_tpu.models.gpt2_decode import (
-        init_kv_cache,
-        prefill,
-        prefill_continue,
-    )
-
-    cfg = GPT2Config.tiny(n_layer=2, d_model=64, n_head=2, max_seq=128)
-    params = gpt2.init_params(jax.random.key(0), cfg)
-    prompt = list(range(2, 50))  # 48 tokens
-    P = 32  # cached prefix
-    T = len(prompt)
-
-    full_cache = init_kv_cache(cfg, 1, 128)
-    toks = jnp.asarray([prompt], jnp.int32)
-    full_cache, full_logits = prefill(
-        params, toks, jnp.asarray([T], jnp.int32), full_cache, cfg
-    )
-
-    # Path 2: prefill the prefix, then continue with the suffix.
-    part_cache = init_kv_cache(cfg, 1, 128)
-    part_cache, _ = prefill(
-        params,
-        jnp.asarray([prompt[:P]], jnp.int32),
-        jnp.asarray([P], jnp.int32),
-        part_cache,
-        cfg,
-    )
-    part_cache, cont_logits = prefill_continue(
-        params,
-        jnp.asarray([prompt[P:]], jnp.int32),
-        jnp.asarray([T - P], jnp.int32),
-        jnp.asarray(P, jnp.int32),
-        part_cache,
-        cfg,
-    )
-    np.testing.assert_allclose(
-        np.asarray(cont_logits), np.asarray(full_logits), atol=2e-2, rtol=2e-2
-    )
-    # Cache rows [0, T) agree too (later decode steps read them).
-    np.testing.assert_allclose(
-        np.asarray(part_cache["k"][:, :, :, :T, :], dtype=np.float32),
-        np.asarray(full_cache["k"][:, :, :, :T, :], dtype=np.float32),
-        atol=2e-2, rtol=2e-2,
-    )
 
 
 def test_shared_prefix_skips_prefill_compute():

@@ -20,7 +20,7 @@ from ray_tpu.core import serialization
 from ray_tpu.core.config import GLOBAL_CONFIG, Config
 from ray_tpu.llm import LLMConfig, LLMEngine, SamplingParams
 from ray_tpu.llm.serve_llm import LLMServer
-from ray_tpu.models import gpt2
+from ray_tpu.models import gpt2, llama
 from ray_tpu.serve.replica import ReplicaActor
 from ray_tpu.util import flightrec, trace_export
 
@@ -39,14 +39,16 @@ def _recorder_on_and_empty():
     flightrec.reset()
 
 
-def llm_config(block_size=16, **kw):
-    model = dataclasses.replace(
-        gpt2.GPT2Config.tiny(vocab_size=512, max_seq=128),
-        dtype=jnp.float32, attn_impl="reference",
-    )
+def llm_config(family="gpt2", **kw):
+    tiny = {
+        "gpt2": gpt2.GPT2Config.tiny(vocab_size=512, max_seq=128),
+        "llama": llama.LlamaConfig.tiny(
+            n_layer=2, d_model=64, n_head=4, n_kv_head=2, max_seq=128),
+    }[family]
+    model = dataclasses.replace(tiny, dtype=jnp.float32, attn_impl="reference")
     return LLMConfig(**{
         "model_config": model, "max_slots": 2, "max_seq": 64,
-        "prefill_buckets": (16, 32), "kv_block_size": block_size,
+        "prefill_buckets": (16, 32), "kv_block_size": 16,
         "prefix_chunk": 16, "seed": 0, "enable_prefix_caching": False, **kw,
     })
 
@@ -64,12 +66,12 @@ def end(e):
     return e["t"] + e["dur_s"]
 
 
-MODES = pytest.mark.parametrize("block_size", [16, 0], ids=["paged", "dense"])
+FAMILIES = pytest.mark.parametrize("family", ["gpt2", "llama"])
 
 
-@MODES
-def test_the_three_parts_of_a_decode_step_add_up_to_it(block_size):
-    eng = LLMEngine(llm_config(block_size))
+@FAMILIES
+def test_the_three_parts_of_a_decode_step_add_up_to_it(family):
+    eng = LLMEngine(llm_config(family))
     eng.generate(["hello there", "abc"], SamplingParams(max_tokens=5))
     steps = of("llm.decode_step")
     parts = [of(p) for p in
@@ -113,12 +115,12 @@ def slow_readback(eng, attr, called: list, done: list):
     setattr(eng, attr, wrapped)
 
 
-@pytest.mark.parametrize("case", ["paged", "dense", "prefill_only", "chunked"])
+@pytest.mark.parametrize("case", ["whole", "llama", "prefill_only", "chunked"])
 def test_a_prefill_span_ends_where_its_logits_reach_the_host(case):
     kw = {"prefill_chunk_tokens": 16} if case == "chunked" else {}
-    eng = LLMEngine(llm_config(0 if case == "dense" else 16, **kw))
+    eng = LLMEngine(llm_config("llama" if case == "llama" else "gpt2", **kw))
     called, done = [], []
-    slow_readback(eng, "_prefill" if case == "dense" else "_pg_prefill", called, done)
+    slow_readback(eng, "_pg_prefill", called, done)
     prompt = list(range(3, 3 + (30 if case == "chunked" else 12)))
     t_before = time.monotonic()
     eng.add_request("r", prompt, SamplingParams(max_tokens=2),
@@ -143,9 +145,9 @@ def test_a_prefill_span_ends_where_its_logits_reach_the_host(case):
     assert end(last) <= end(first_token) + MS
 
 
-@MODES
-def test_queue_and_admit_lie_inside_the_first_token_interval(block_size):
-    eng = LLMEngine(llm_config(block_size))
+@FAMILIES
+def test_queue_and_admit_lie_inside_the_first_token_interval(family):
+    eng = LLMEngine(llm_config(family))
     for rid, prompt in (("a", "hello there"), ("b", "abc")):
         eng.add_request(rid, prompt, SamplingParams(max_tokens=2))
     while eng.has_unfinished():
@@ -331,40 +333,54 @@ def test_a_streamed_request_through_the_proxy_records_the_hop(cluster):
 # -- names on the device, and the ring ----------------------------------------
 
 
-@pytest.fixture(scope="module")
-def engines():
-    return {bs: LLMEngine(llm_config(bs)) for bs in (16, 0)}
-
-
 def _module_name(jitted, *args):
     return jitted.lower(*args).as_text().split("module @", 1)[1].split(" ", 1)[0]
 
 
-@pytest.mark.parametrize("program", [
-    "paged_prefill", "paged_decode", "dense_prefill", "dense_prefill_cont",
-    "dense_decode",
-])
-def test_a_jitted_program_carries_its_name_into_the_trace(engines, program):
+@pytest.fixture(scope="module")
+def engine_of():
+    built = {}
+
+    def get(family):
+        if family in built:
+            return built[family]
+        if family == "kimi_linear":
+            from ray_tpu.models.kimi_linear import KimiLinearConfig
+
+            config = llm_config(model_config=KimiLinearConfig.tiny(max_seq=128))
+        else:
+            config = llm_config(family)
+        return built.setdefault(family, LLMEngine(config))
+
+    return get
+
+
+@pytest.mark.parametrize("program", ["paged_prefill", "paged_decode"])
+@pytest.mark.parametrize("family", ["gpt2", "llama", "kimi_linear"])
+def test_a_jitted_program_carries_its_name_into_the_trace(engine_of, family, program):
     """The profiler's ``XLA Modules`` line names a run after the module, and
-    the module after the jitted function: ``jit_paged_decode``, where a
-    ``functools.partial`` gave ``jit__unknown``."""
-    eng = engines[16 if program.startswith("paged") else 0]
-    toks = jnp.zeros((1, 16), jnp.int32)
-    n, z = jnp.asarray(4, jnp.int32), jnp.asarray(0, jnp.int32)
-    last, pos = jnp.asarray(eng.last_tokens), jnp.asarray(eng.positions)
-    if program == "paged_prefill":
-        row = jnp.asarray(eng.block_tables[0])
-        name = _module_name(eng._pg_prefill, eng.params, toks, n, z, row, eng.pool)
-    elif program == "paged_decode":
-        name = _module_name(
-            eng._pg_decode, eng.params, last, pos, jnp.asarray(eng.block_tables), eng.pool)
-    elif program == "dense_prefill":
-        name = _module_name(eng._prefill, eng.params, toks, n, eng.cache, 0)
-    elif program == "dense_prefill_cont":
-        name = _module_name(eng._prefill_cont, eng.params, toks, n, z, eng.cache, 0)
+    the module after the jitted function: ``jit_paged_decode`` for every
+    family, whatever operands its program takes, where a
+    ``functools.partial`` gave ``jit__unknown``. The benchmark's trace
+    readers find the programs by these names."""
+    eng = engine_of(family)
+    toks = np.zeros((1, 16), np.int32)
+    if family == "kimi_linear":  # every small operand in one int32 array
+        width = 3 + eng.block_tables.shape[1]
+        operands = {
+            "paged_prefill": (toks, np.zeros(width, np.int32)),
+            "paged_decode": (np.zeros((len(eng.block_tables), width), np.int32),),
+        }[program]
+    elif program == "paged_prefill":
+        n, z = jnp.asarray(4, jnp.int32), jnp.asarray(0, jnp.int32)
+        operands = (jnp.asarray(toks), n, z, jnp.asarray(eng.block_tables[0]))
     else:
-        name = _module_name(eng._decode, eng.params, last, pos, eng.cache)
-    assert name == f"jit_{program}"
+        operands = (
+            jnp.asarray(eng.last_tokens), jnp.asarray(eng.positions),
+            jnp.asarray(eng.block_tables),
+        )
+    jitted = {"paged_prefill": eng._pg_prefill, "paged_decode": eng._pg_decode}[program]
+    assert _module_name(jitted, eng.params, *operands, eng.pool) == f"jit_{program}"
 
 
 def test_the_default_ring_holds_a_benchmark_window():

@@ -2,10 +2,13 @@
 
 Round-16 tentpole coverage, leg 2: a small draft model proposes k greedy
 tokens per engine step, the target verifies them in one batched forward
-(paged_verify / dense_verify), and greedy outputs are CI-pinned
+(paged_verify), and greedy outputs are CI-pinned
 bit-identical to vanilla decode. RAY_TPU_SPEC_DECODE=0 restores the
 round-12 engine byte-identically.
 """
+
+import dataclasses
+import pickle
 
 import pytest
 
@@ -13,14 +16,40 @@ from ray_tpu.core.config import GLOBAL_CONFIG
 from ray_tpu.llm.config import LLMConfig, SamplingParams
 from ray_tpu.llm.engine import LLMEngine
 from ray_tpu.models.gpt2 import GPT2Config
+from ray_tpu.models.llama import LlamaConfig
 
 
-def _model():
+def _model(family="gpt2"):
+    if family == "llama":
+        return LlamaConfig.tiny(
+            n_layer=2, d_model=64, n_head=4, n_kv_head=2, max_seq=256
+        )
     return GPT2Config.tiny(n_layer=2, d_model=64, n_head=2, max_seq=256)
 
 
 def _draft():
     return GPT2Config.tiny(n_layer=1, d_model=32, n_head=2, max_seq=256)
+
+
+def _first_layer_draft(target, tmp_path):
+    """A draft cut from the target: its first layer between its embedding
+    and its head, as ``draft_weights_path`` loads it. A random Llama draft
+    agrees with a random target nowhere (no tied embedding pulls both to
+    the last token, as GPT-2's does), and a step must accept some and
+    reject some for both halves of the verification to run."""
+    import jax
+    import numpy as np
+
+    from ray_tpu.models import llama
+
+    params = llama.init_params(jax.random.key(_cfg().seed), target)
+    params["blocks"] = jax.tree.map(lambda a: a[:1], params["blocks"])
+    path = tmp_path / "draft.pkl"
+    path.write_bytes(pickle.dumps(jax.tree.map(np.asarray, params)))
+    return {
+        "draft_model_config": dataclasses.replace(target, n_layer=1),
+        "draft_weights_path": str(path),
+    }
 
 
 def _cfg(**kw):
@@ -44,22 +73,26 @@ PROMPTS = [
 GREEDY = SamplingParams(max_tokens=12, temperature=0.0)
 
 
-@pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
-def test_greedy_spec_decode_token_identical(paged):
+@pytest.mark.parametrize("family", ["gpt2", "llama"])
+def test_greedy_spec_decode_token_identical(family, tmp_path):
     """The tentpole contract: speculative decoding is a THROUGHPUT change,
-    not a sampling change — greedy outputs bit-equal vanilla decode on
-    both cache layouts, while the spec counters prove speculation ran."""
-    kw = {} if paged else {"kv_block_size": 0}
+    not a sampling change — greedy outputs bit-equal vanilla decode for
+    both key/value families (draft and target of one family), while the
+    spec counters prove speculation ran."""
+    kw = {"model_config": _model(family)}
     van = LLMEngine(_cfg(**kw))
     out_v = [r["token_ids"] for r in van.generate(PROMPTS, GREEDY)]
-    spec = LLMEngine(
-        _cfg(spec_decode_tokens=4, draft_model_config=_draft(), **kw)
+    draft = (
+        _first_layer_draft(kw["model_config"], tmp_path)
+        if family == "llama"
+        else {"draft_model_config": _draft()}
     )
+    spec = LLMEngine(_cfg(spec_decode_tokens=4, **draft, **kw))
     out_s = [r["token_ids"] for r in spec.generate(PROMPTS, GREEDY)]
     assert out_s == out_v
     assert van.stats["spec_steps"] == 0
     assert spec.stats["spec_steps"] >= 1
-    assert spec.stats["spec_drafted"] > 0
+    assert 0 < spec.stats["spec_accepted"] < spec.stats["spec_drafted"]
     # Fewer engine steps than tokens generated: speculation actually
     # compressed the decode loop (vanilla needs one step per token).
     assert spec._steps < van._steps

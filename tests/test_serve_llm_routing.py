@@ -17,6 +17,7 @@ from ray_tpu.core.config import GLOBAL_CONFIG
 from ray_tpu.llm.config import LLMConfig, SamplingParams
 from ray_tpu.llm.engine import LLMEngine
 from ray_tpu.models.gpt2 import GPT2Config
+from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.util.prefix_digest import (
     BYTE_BOS_SCHEME,
     chain_digests,
@@ -24,8 +25,13 @@ from ray_tpu.util.prefix_digest import (
 )
 
 
-def _tiny_config(**kw):
-    model = GPT2Config.tiny(n_layer=2, d_model=64, n_head=2, max_seq=256)
+def _tiny_config(family="gpt2", **kw):
+    if family == "llama":
+        model = LlamaConfig.tiny(
+            n_layer=2, d_model=64, n_head=4, n_kv_head=2, max_seq=256
+        )
+    else:
+        model = GPT2Config.tiny(n_layer=2, d_model=64, n_head=2, max_seq=256)
     defaults = dict(
         model_config=model,
         max_slots=4,
@@ -77,9 +83,9 @@ def test_chain_digests_strict_vs_pool():
 
 
 def test_chunk_knobs_validated_as_block_multiples():
-    """prefix_chunk and prefill_chunk_tokens share one validation: paged
-    mode requires both to be kv_block_size multiples; 0 disables chunked
-    prefill; dense mode (kv_block_size=0) skips the constraint."""
+    """prefix_chunk and prefill_chunk_tokens share one validation: both
+    must be kv_block_size multiples, whatever the block size; 0 disables
+    chunked prefill."""
     with pytest.raises(ValueError, match="multiple of kv_block_size"):
         LLMEngine(_tiny_config(prefix_chunk=24))  # not a 16-multiple
     with pytest.raises(ValueError, match="multiple of kv_block_size"):
@@ -93,19 +99,19 @@ def test_chunk_knobs_validated_as_block_multiples():
     LLMEngine(_tiny_config(prefix_chunk=24, enable_prefix_caching=False))
     # 0 = chunked prefill disabled, always valid.
     LLMEngine(_tiny_config(prefill_chunk_tokens=0))
-    # Dense mode: no block constraint on either knob.
-    LLMEngine(_tiny_config(kv_block_size=0, prefill_chunk_tokens=24))
+    # The constraint follows the block size: 24 is a multiple of 8.
+    LLMEngine(_tiny_config(kv_block_size=8, prefill_chunk_tokens=24))
 
 
 # -- chunked prefill ---------------------------------------------------------
 
 
-@pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
-def test_chunked_prefill_token_identical(paged):
+@pytest.mark.parametrize("family", ["gpt2", "llama"])
+def test_chunked_prefill_token_identical(family):
     """Chunked prefill is a scheduling change, not a math change: greedy
-    outputs are identical to the unchunked path on CPU, while the chunk
-    counter proves the chunked path actually ran."""
-    kw = {} if paged else {"kv_block_size": 0}
+    outputs are identical to the unchunked path on CPU for both key/value
+    families, while the chunk counter proves the chunked path actually ran."""
+    kw = {"family": family}
     prompts = [
         list(range(2, 120)),  # long: chunks
         list(range(3, 20)),  # short: below one chunk, unchunked
