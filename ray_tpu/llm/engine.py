@@ -298,7 +298,7 @@ class LLMEngine:
             def paged_decode(params, last_tokens, positions, tables, pool):
                 return paged.paged_decode(
                     params, last_tokens, positions, tables, pool,
-                    cfg=cfg, block_size=bs,
+                    cfg=cfg, block_size=bs, mesh=self.mesh,
                 )
 
             self._pg_prefill = jax.jit(paged_prefill, donate_argnums=5)
@@ -330,7 +330,16 @@ class LLMEngine:
             "spec_steps": 0,
             "spec_drafted": 0,
             "spec_accepted": 0,
+            # Plain decode steps, by the arm their program was built with
+            # (paged.decode_attends_in_place: platform and shapes decide):
+            "decode_attn_kernel_steps": 0,  # live blocks read in place
+            "decode_attn_gather_steps": 0,  # whole tables gathered
         }
+        self._decode_arm = (
+            "decode_attn_kernel_steps"
+            if paged.decode_attends_in_place(cfg, bs, mesh=self.mesh)
+            else "decode_attn_gather_steps"
+        )
         for part, arr in self.pool.items():  # bytes of each cache part
             self.stats[f"cache_bytes_{part}"] = int(arr.nbytes)
         if self._recurrent:
@@ -1125,6 +1134,11 @@ class LLMEngine:
         elif active:
             fr = _flightrec.on()
             t_dec = _time.monotonic()
+            self.stats[self._decode_arm] += 1
+            if fr:  # blocks this step's rows hold, before they advance
+                bs = self._block_size
+                at = self.positions[[r.slot for r in active]]
+                kv_blocks_live = int(((at + bs) // bs).sum())
             if self._recurrent:
                 # Slots that are free or still prefilling step on the
                 # scratch row of the state and are routed to no expert.
@@ -1189,9 +1203,14 @@ class LLMEngine:
                     "llm", "llm.decode_sample", t=t_read,
                     dur_s=t_end - t_read, batch=batch,
                 )
+                # How much of the tables the traffic fills: the blocks
+                # the live rows attend (ceil((position + 1) / block)
+                # each) over the B x W entries a gather would bring back.
                 _flightrec.record(
                     "llm", "llm.decode_step", t=t_dec,
-                    dur_s=t_end - t_dec, batch=batch, **moe,
+                    dur_s=t_end - t_dec, batch=batch,
+                    kv_blocks_live=kv_blocks_live,
+                    kv_blocks_table=self.block_tables.size, **moe,
                 )
         self._steps += 1
         if instrument:

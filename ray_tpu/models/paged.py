@@ -11,17 +11,24 @@ kernels:
   ``W = max_seq // block`` — so HBM is allocated per ~block tokens
   actually used, not per ``max_seq`` slot row. Block 0 is a reserved
   scratch block: padded/garbage writes land there and are never read.
-- **Scatter-then-gather attention.** New K/V are scattered straight into
-  their (layer, block, offset) homes; the attending pass gathers the
-  request's blocks back into a dense ``[KH, S, Dh]`` row (a *transient* —
-  XLA frees it after the layer) and runs the same masked grouped-head
-  einsums as the training forward (``gpt2.forward``, ``llama.forward``).
-  Identical math ⇒ logit parity with it position by position, which the
-  tests assert.
+- **Scatter, then attend.** New K/V are scattered straight into their
+  (layer, block, offset) homes before anything reads them. *Prefill and
+  verify* then gather the request's blocks back into a dense
+  ``[KH, S, Dh]`` row (a *transient* — XLA frees it after the layer) and
+  run the same masked grouped-head einsums as the training forward
+  (``gpt2.forward``, ``llama.forward``). *Decode* reads the live blocks
+  where they lie: on a TPU, at shapes that tile, one kernel a layer walks
+  each slot's table and attends its ``ceil((position + 1) / block)`` live
+  blocks (``ops/paged_attention.py``), so a step costs what is cached and
+  not ``max_seq`` a slot; elsewhere decode gathers too, and that gather is
+  what the tests hold the kernel to. Identical math (bf16 operands,
+  float32 scores and softmax, the mask ``col <= position``) ⇒ logit
+  parity with the training forward position by position, which the tests
+  assert of both.
 - **The pool is written in place.** The layer scan carries the whole pool
   and scans over the layer index, so each layer's scatter writes a few
   rows into the buffer it was handed; the only slab-sized work left is
-  the gather. A caller that donates the pool (the engine, the speculative
+  prefill's gather. A caller that donates the pool (the engine, the speculative
   decoder) gets its own buffer back as the output and must rebind it; one
   that does not (``benchmarks/check.py``) keeps its input and pays one
   copy of the pool at entry, which the compiler inserts.
@@ -54,10 +61,13 @@ and its layer bodies itself:
 
 from __future__ import annotations
 
+import functools
 import importlib
 
 import jax
 import jax.numpy as jnp
+
+from ray_tpu.ops import paged_attention
 
 Params = dict
 
@@ -104,8 +114,7 @@ def init_block_pool(cfg, num_blocks: int, block_size: int, slots=None):
     mod = family(cfg)
     if not hasattr(mod, "kv_hooks"):
         return mod.init_pool(cfg, num_blocks, block_size, slots)
-    kh = getattr(cfg, "n_kv_head", None) or cfg.n_head
-    shape = (cfg.n_layer, num_blocks, kh, block_size, cfg.head_dim)
+    shape = (cfg.n_layer, num_blocks, _kv_heads(cfg), block_size, cfg.head_dim)
     return {
         "k": jnp.zeros(shape, cfg.dtype),
         "v": jnp.zeros(shape, cfg.dtype),
@@ -116,15 +125,80 @@ def init_block_pool(cfg, num_blocks: int, block_size: int, slots=None):
 # Paged ops
 
 
-def _write_read(pool_kv, l, bids, offs, new, tables):
+def _write(pool_kv, l, bids, offs, new):
     """Layer ``l`` of one pool tensor [L, N, KH, block, Dh]: scatter
     ``new`` [..., KH, Dh] to the (block, offset) homes ``bids`` / ``offs``
-    [...], then gather the rows of ``tables`` [..., W] back as
-    [..., W, KH, block, Dh]. Both index by (layer, block) at once: slicing
-    the layer out first would bring its whole slab back as a temporary."""
+    [...]. Indexed by (layer, block) at once: slicing the layer out first
+    would bring its whole slab back as a temporary."""
     khi = jnp.arange(pool_kv.shape[2])
-    pool_kv = pool_kv.at[l, bids[..., None], khi, offs[..., None]].set(new)
+    return pool_kv.at[l, bids[..., None], khi, offs[..., None]].set(new)
+
+
+def _write_read(pool_kv, l, bids, offs, new, tables):
+    """:func:`_write`, then gather the rows of ``tables`` [..., W] back as
+    [..., W, KH, block, Dh], by (layer, block) at once as well."""
+    pool_kv = _write(pool_kv, l, bids, offs, new)
     return pool_kv, pool_kv[l, tables]
+
+
+def _attend_gathered(qg, pk, pv, l, tables, lengths):
+    """Decode attention by gather: each slot's whole table brought back as
+    a dense row [B, KH, S, Dh] and masked to its first ``lengths[b]``
+    positions. ``qg`` [B, KH, group, Dh]; returns the same shape. What
+    :func:`ops.paged_attention.paged_decode_attention` computes from the
+    live blocks alone."""
+    B, KH, _, Dh = qg.shape
+    S = tables.shape[1] * pk.shape[3]
+    kd = pk[l, tables].transpose(0, 2, 1, 3, 4).reshape(B, KH, S, Dh)
+    vd = pv[l, tables].transpose(0, 2, 1, 3, 4).reshape(B, KH, S, Dh)
+    s = jnp.einsum("bkgd,bksd->bkgs", qg, kd).astype(jnp.float32)
+    s = s * (1.0 / (Dh**0.5))
+    mask = jnp.arange(S)[None, :] < lengths[:, None]  # [B, S]
+    s = jnp.where(mask[:, None, None, :], s, -1e30)
+    pa = jax.nn.softmax(s, axis=-1).astype(vd.dtype)
+    return jnp.einsum("bkgs,bksd->bkgd", pa, vd)
+
+
+def decode_attends_in_place(cfg, block_size: int, *, mesh=None) -> bool:
+    """Whether :func:`paged_decode`, lowered for this process's default
+    backend, attends the live blocks in place (the kernel) or gathers each
+    table whole: the kernel on a TPU, for a family of keys and values per
+    head whose head and block sizes are whole TPU tiles and fit VMEM, outside a mesh
+    (the compiler cannot partition a Mosaic call). Decided by what the
+    code can see, like ``ops.attention.uses_flash_kernel``; nothing a user
+    sets reaches it."""
+    return (
+        jax.default_backend() == "tpu"
+        and hasattr(family(cfg), "kv_hooks")
+        and _kernel_fits(cfg, block_size, mesh)
+    )
+
+
+def _kv_heads(cfg) -> int:
+    return getattr(cfg, "n_kv_head", None) or cfg.n_head
+
+
+def _kernel_fits(cfg, block_size, mesh) -> bool:
+    return (mesh is None or mesh.size == 1) and paged_attention.fits(
+        _kv_heads(cfg), cfg.head_dim, block_size, jnp.dtype(cfg.dtype).itemsize
+    )
+
+
+def _decode_attention(cfg, block_size, mesh, interpret):
+    """The decode step's attention over the scattered pool: the kernel
+    where the shapes fit and the program is lowered for a TPU (decided at
+    lowering, so a program compiled here for a described chip holds what
+    the chip will run), the gather elsewhere. ``interpret`` runs the
+    kernel in the Pallas interpreter whatever the platform and the shapes
+    (the tests)."""
+    kernel = paged_attention.paged_decode_attention
+    if interpret:
+        return functools.partial(kernel, interpret=True)
+    if not _kernel_fits(cfg, block_size, mesh):
+        return _attend_gathered
+    return functools.partial(
+        jax.lax.platform_dependent, tpu=kernel, default=_attend_gathered
+    )
 
 
 def _scan_layers(body, x, params, pool):
@@ -284,12 +358,19 @@ def paged_decode(
     block_size: int,
     live=None,  # [B] bool — a family with a state per slot: which slots
     #             hold a decoding sequence (None: all)
+    mesh=None,  # the mesh the operands are sharded over, if any
+    interpret: bool = False,  # the attention kernel in the Pallas
+    #             interpreter, whatever the platform and shapes (tests)
 ):
     """One token per slot against the shared pool; returns
     (pool, logits [B, vocab] f32), and a third value, its counters, from a
     family that has some. Free slots must point their table at
     the scratch block (id 0) so their garbage writes never land in a
-    block another request owns."""
+    block another request owns.
+
+    Each layer scatters the step's key and value, then attends positions
+    [0, position] of every slot: over the live blocks in place or over the
+    gathered table (:func:`_decode_attention`)."""
     mod = family(cfg)
     if not hasattr(mod, "kv_hooks"):
         return mod.paged_decode(
@@ -301,28 +382,22 @@ def paged_decode(
     S = W * block_size
     embed, qkv, finish, final, H, KH, Dh = mod.kv_hooks(cfg, S)
     group = H // KH
+    attend = _decode_attention(cfg, block_size, mesh, interpret)
 
     x = embed(params, last_tokens[:, None], positions[:, None])  # [B,1,D]
     rows = jnp.arange(B)
     bids = tables[rows, positions // block_size]  # [B]
     offs = positions % block_size
-    cols = jnp.arange(S)
-    mask = cols[None, :] <= positions[:, None]  # [B, S]
-    scale = 1.0 / (Dh**0.5)
+    lengths = positions + 1  # the step's own key is attended
 
     def body(carry, layer):
         x, pk, pv = carry  # pk/pv: the whole pool, [L, N, KH, block, Dh]
         p, l = layer
         q, k, v = qkv(x, p, positions[:, None])  # [B,{H,KH},1,Dh]
-        pk, kd = _write_read(pk, l, bids, offs, k[:, :, 0, :], tables)
-        pv, vd = _write_read(pv, l, bids, offs, v[:, :, 0, :], tables)
-        kd = kd.transpose(0, 2, 1, 3, 4).reshape(B, KH, S, Dh)
-        vd = vd.transpose(0, 2, 1, 3, 4).reshape(B, KH, S, Dh)
+        pk = _write(pk, l, bids, offs, k[:, :, 0, :])
+        pv = _write(pv, l, bids, offs, v[:, :, 0, :])
         qg = q[:, :, 0, :].reshape(B, KH, group, Dh)
-        s = jnp.einsum("bkgd,bksd->bkgs", qg, kd).astype(jnp.float32) * scale
-        s = jnp.where(mask[:, None, None, :], s, -1e30)
-        pa = jax.nn.softmax(s, axis=-1).astype(vd.dtype)
-        attn = jnp.einsum("bkgs,bksd->bkgd", pa, vd).reshape(B, H, 1, Dh)
+        attn = attend(qg, pk, pv, l, tables, lengths).reshape(B, H, 1, Dh)
         return (finish(x, attn, p), pk, pv), None
 
     x, pool = _scan_layers(body, x, params, pool)

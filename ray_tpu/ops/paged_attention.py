@@ -1,0 +1,222 @@
+"""Decode attention over a block pool, read where it lies.
+
+One query position a slot attends that slot's *live* blocks only: a Pallas
+TPU kernel walks the block table, copies each live block of the layer's keys
+and values from the pool in HBM into VMEM (one contiguous block of every KV
+head a copy, double-buffered a chunk of blocks at a time, the next slot's
+first chunk in flight behind this slot's last) and folds the chunk into a
+running (max, denominator, accumulator) online softmax in float32 — the
+flash forward of ``ops/attention.py`` with the key axis taken from a table.
+The work of a slot is ``ceil(length / block)`` blocks, whatever the table's
+width: gathering a slot's whole table to a dense row, re-laying it and
+masking most of it away costs ``max_seq`` a slot a layer, and at a table a
+fifth full that was a third of the decode step.
+
+The pool ``[L, N, KH, block, Dh]`` never becomes a value of the kernel's
+caller: it is handed over whole in ``memory_space=ANY`` with the layer as a
+prefetched scalar, so no layer's slab is sliced out as a temporary.
+
+Matmuls run on the MXU in the pool's dtype with float32 accumulation;
+scores, softmax statistics and the accumulator are float32; the mask is the
+length (``col < length``, the gather path's ``col <= position``).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops.attention import _LOG2E, _NEG_INF
+
+# Key positions folded into the softmax at a time. Live blocks are copied
+# one by one, so a longer chunk saves loop turns and costs masked arithmetic
+# on the dead end of a slot's last chunk: on a v5e, sixteen slots of 128-768
+# positions take 49 us a layer at 128, 43 at 256 and 43 at 512 (PERF.md).
+_CHUNK = 256
+# Query heads of one KV head are padded to the bf16 sublane tile, so that
+# each head's [group, Dh] operand and [group, chunk] scores are whole tiles.
+_GROUP_TILE = 16
+
+
+# The four chunk buffers (keys and values, two each) may take this much of
+# a core's VMEM (16 MiB by default on a v5e), the rest left to the scores.
+_VMEM_BUFFER_BYTES = 8 * 2**20
+
+
+def _pages(block_size: int) -> int:
+    """Blocks a chunk: a block at least, however long."""
+    return max(1, _CHUNK // block_size)
+
+
+def fits(kv_heads: int, head_dim: int, block_size: int, itemsize: int) -> bool:
+    """Whether the kernel's copies and matmuls are whole TPU tiles at these
+    shapes (the lane width in ``head_dim``, the bf16 sublane tile in the
+    block) and its chunk buffers, of ``itemsize`` bytes an element, fit
+    VMEM."""
+    chunk = _pages(block_size) * block_size
+    return (
+        head_dim % 128 == 0
+        and block_size % 16 == 0
+        and 4 * kv_heads * chunk * head_dim * itemsize <= _VMEM_BUFFER_BYTES
+    )
+
+
+def _kernel(
+    layer_ref, lengths_ref, tables_ref,  # scalar prefetch (SMEM)
+    q_ref,  # [1, KH, G, Dh] VMEM
+    k_hbm, v_hbm,  # [L, N, KH, block, Dh], left in HBM
+    o_ref,  # [1, KH, G, Dh] VMEM
+    kbuf, vbuf,  # [2, KH, pages * block, Dh] VMEM
+    sems,  # DMA semaphores [2 (k, v), 2 (buffer)]
+    parity,  # SMEM [1]: the buffer the slot's first chunk was copied to
+    *, block, pages, width, scale,
+):
+    b = pl.program_id(0)
+    slots = pl.num_programs(0)
+    layer = layer_ref[0]
+    chunk = pages * block
+
+    def live_pages(slot):
+        return (lengths_ref[slot] + block - 1) // block
+
+    def copies(slot, i, buf, j):
+        """The two copies of live block ``j`` of the slot's chunk ``i``:
+        every KV head of one block is contiguous in the pool, and lands
+        strided, at its positions of each head's row of the buffer."""
+        page = tables_ref[slot * width + i * pages + j]
+        rows = pl.ds(pl.multiple_of(j * block, block), block)
+        return (
+            pltpu.make_async_copy(
+                k_hbm.at[layer, page], kbuf.at[buf, :, rows, :], sems.at[0, buf]
+            ),
+            pltpu.make_async_copy(
+                v_hbm.at[layer, page], vbuf.at[buf, :, rows, :], sems.at[1, buf]
+            ),
+        )
+
+    def for_each_copy(slot, i, buf, act):
+        n = jnp.minimum(pages, live_pages(slot) - i * pages)
+
+        def body(j, _):
+            for c in copies(slot, i, buf, j):
+                act(c)
+            return _
+
+        jax.lax.fori_loop(0, n, body, None)
+
+    @pl.when(b == 0)
+    def _first():
+        # Rows past a slot's live blocks keep what an earlier chunk left
+        # there; before any chunk that is whatever VMEM held, and a masked
+        # probability of zero times a NaN is a NaN.
+        vbuf[...] = jnp.zeros_like(vbuf)
+        parity[0] = 0
+        for_each_copy(0, 0, 0, lambda c: c.start())
+
+    first = parity[0]
+    length = lengths_ref[b]
+    n_chunks = (live_pages(b) + pages - 1) // pages
+    q = q_ref[0]  # [KH, G, Dh]
+    KH, G, Dh = q.shape
+    cols = jax.lax.broadcasted_iota(jnp.int32, (KH, G, chunk), 2)
+
+    def body(i, carry):
+        m, l, acc = carry
+        buf = (first + i) % 2
+        # The chunk after this one, of this slot or the first of the next,
+        # goes into the other buffer while this one is attended.
+        last = i + 1 == n_chunks
+        nslot = jnp.where(last, b + 1, b)
+        ni = jnp.where(last, 0, i + 1)
+
+        @pl.when(nslot < slots)
+        def _prefetch():
+            for_each_copy(nslot, ni, 1 - buf, lambda c: c.start())
+
+        for_each_copy(b, i, buf, lambda c: c.wait())
+        k = kbuf[buf]  # [KH, chunk, Dh]
+        v = vbuf[buf]
+        s = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        ) * (scale * _LOG2E)  # [KH, G, chunk] f32, base-2
+        s = jnp.where(i * chunk + cols < length, s, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp2(s - m_new)
+        alpha = jnp.exp2(m - m_new)
+        l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+        acc_new = acc * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )
+        return m_new, l_new, acc_new
+
+    m0 = jnp.full((KH, G, 1), _NEG_INF, jnp.float32)
+    l0 = jnp.zeros((KH, G, 1), jnp.float32)
+    acc0 = jnp.zeros((KH, G, Dh), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(0, n_chunks, body, (m0, l0, acc0))
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+    parity[0] = (first + n_chunks) % 2
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def paged_decode_attention(
+    q: jax.Array,  # [B, KH, group, Dh] — one query position a slot
+    pool_k: jax.Array,  # [L, N, KH, block, Dh]
+    pool_v: jax.Array,
+    layer: jax.Array,  # scalar int32 — the layer of the pool to read
+    tables: jax.Array,  # [B, W] int32 block tables
+    lengths: jax.Array,  # [B] int32, >= 1 — positions attended, a slot
+    *,
+    interpret: bool = False,
+) -> jax.Array:
+    """softmax(q k^T / sqrt(Dh)) v over each slot's first ``lengths[b]``
+    positions of its table's blocks in layer ``layer``; [B, KH, group, Dh]
+    in the pool's dtype. Table entries past a slot's live blocks are never
+    read."""
+    B, KH, G, Dh = q.shape
+    block = pool_k.shape[3]
+    W = tables.shape[1]
+    pages = _pages(block)
+    pad = -G % _GROUP_TILE
+    if pad:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, pad), (0, 0)))
+    row = pl.BlockSpec((1, KH, G + pad, Dh), lambda b, *_: (b, 0, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(
+            _kernel, block=block, pages=pages, width=W, scale=Dh**-0.5
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[
+                row,
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=row,
+            scratch_shapes=[
+                pltpu.VMEM((2, KH, pages * block, Dh), pool_k.dtype),
+                pltpu.VMEM((2, KH, pages * block, Dh), pool_v.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(q.shape, pool_v.dtype),
+        # Slots run in order: each starts the next one's first copies.
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_decode_attention",
+    )(
+        jnp.reshape(layer, (1,)).astype(jnp.int32),
+        lengths.astype(jnp.int32),
+        tables.reshape(-1).astype(jnp.int32),
+        q.astype(pool_k.dtype),
+        pool_k,
+        pool_v,
+    )
+    return out[:, :, :G]

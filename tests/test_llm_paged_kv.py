@@ -99,21 +99,29 @@ def _programs(cfg):
 
 
 @pytest.mark.parametrize(
-    "phase", ["whole_prefill", "continued_prefill", "decode", "verify"]
+    "phase",
+    ["whole_prefill", "continued_prefill", "decode", "verify", "kernel-decode"],
 )
 @pytest.mark.parametrize("family", ["gpt2", "llama"])
 def test_paged_programs_match_the_training_forward(family, phase):
     """Every paged program reproduces the training path's logits: a whole
     prefill in a padded bucket, a prefill continued behind a block boundary
     (the prefix-cache and chunked-prefill path), teacher-forced decode of
-    two slots at unlike positions, and ``paged_verify`` of k + 1 tokens a
-    slot followed by a decode step over what it wrote."""
+    two slots at unlike positions — by the gather and, interpreted, by the
+    kernel that reads the live blocks in place — and ``paged_verify`` of
+    k + 1 tokens a slot followed by a decode step over what it wrote."""
     cfg, mod, params = _family_model(family)
     toks = np.asarray(
         jax.random.randint(jax.random.key(1), (2, 24), 0, cfg.vocab_size)
     )
     full = np.asarray(mod.forward(params, jnp.asarray(toks), cfg))  # [2, 24, V]
     fill, decode, verify = _programs(cfg)
+    if phase == "kernel-decode":
+        decode = jax.jit(
+            functools.partial(
+                paged.paged_decode, cfg=cfg, block_size=BLOCK, interpret=True
+            )
+        )
     pool = paged.init_block_pool(cfg, num_blocks=9, block_size=BLOCK)
     assert TABLES.shape[1] * BLOCK == WINDOW
 
@@ -146,7 +154,7 @@ def test_paged_programs_match_the_training_forward(family, phase):
         want = np.stack([full[b, positions[b] : positions[b] + k1] for b in rows])
         np.testing.assert_allclose(np.asarray(logits), want, **TOL_CHAINED)
         positions += k1
-    for _ in range(6 if phase == "decode" else 1):
+    for _ in range(1 if phase == "verify" else 6):
         pool, logits = decode(
             params, jnp.asarray(toks[rows, positions]), jnp.asarray(positions),
             tables, pool,
@@ -155,6 +163,86 @@ def test_paged_programs_match_the_training_forward(family, phase):
             np.asarray(logits), full[rows, positions], **TOL_CHAINED
         )
         positions += 1
+
+
+# -- the decode kernel against the gather --------------------------------------
+#
+# ``ops.paged_attention`` walks each slot's table and attends its live blocks;
+# ``paged._attend_gathered`` brings every table back whole and masks. Same
+# pool, same tables, same lengths: the same rows out, for every shape of
+# slot a step can hold. Three layers, four slots, tables of five blocks of 8
+# scattered over a pool of random (stale) values; interpreted, on the CPU.
+
+KERNEL_TABLES = np.array(
+    [[9, 4, 13, 2, 7], [3, 12, 6, 10, 1], [5, 8, 11, 14, 15], [0, 0, 0, 0, 0]],
+    np.int32,
+)
+KERNEL_CASES = {  # positions a slot, and the layer read
+    "scattered tables, unlike positions": ([29, 3, 18, 0], 0),
+    "a slot at its block's last row": ([7, 15, 39, 0], 0),  # p % 8 == 7
+    "a slot at a block's first row": ([8, 16, 32, 0], 1),  # p % 8 == 0
+    "free slots on the scratch block": ([21, 0, 0, 0], 1),  # tables 1, 2 unread
+    "every slot at length one": ([0, 0, 0, 0], 0),
+    "the last layer of the pool": ([29, 3, 18, 0], 2),
+}
+
+
+@functools.cache
+def _kernel_operands(family):
+    cfg, _, _ = _family_model(family)
+    cfg = dataclasses.replace(cfg, n_layer=3)
+    KH = getattr(cfg, "n_kv_head", None) or cfg.n_head
+    shape = paged.init_block_pool(cfg, num_blocks=16, block_size=BLOCK)["k"].shape
+    ks = jax.random.split(jax.random.key(5), 3)
+    q = jax.random.normal(ks[0], (4, KH, cfg.n_head // KH, cfg.head_dim), cfg.dtype)
+    return q, jax.random.normal(ks[1], shape), jax.random.normal(ks[2], shape)
+
+
+@pytest.mark.parametrize("case", KERNEL_CASES)
+@pytest.mark.parametrize("family", ["gpt2", "llama"])  # group 1, group 2
+def test_the_decode_kernel_attends_what_the_gather_attends(family, case):
+    from ray_tpu.ops.paged_attention import paged_decode_attention
+
+    q, pk, pv = _kernel_operands(family)
+    positions, layer = KERNEL_CASES[case]
+    tables = jnp.asarray(KERNEL_TABLES)
+    lengths = jnp.asarray(positions, jnp.int32) + 1
+    want = paged._attend_gathered(q, pk, pv, layer, tables, lengths)
+    got = paged_decode_attention(
+        q, pk, pv, jnp.int32(layer), tables, lengths, interpret=True
+    )
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
+    # The comparison can tell a layer from its neighbour and a table from
+    # another's: the gather of the wrong ones is far off.
+    for wrong in (
+        paged._attend_gathered(q, pk, pv, (layer + 1) % 3, tables, lengths),
+        paged._attend_gathered(q, pk, pv, layer, tables[::-1], lengths),
+    ):
+        assert np.abs(np.asarray(wrong) - np.asarray(got)).max() > 0.1
+
+
+def test_the_decode_kernel_reads_no_block_past_a_slots_live_ones():
+    """Work follows the live blocks, not the table's width: entries behind
+    them point at a block of NaN here, which the gather brings back (a
+    masked zero times a NaN is a NaN) and the kernel never copies."""
+    from ray_tpu.ops.paged_attention import paged_decode_attention
+
+    q, pk, pv = _kernel_operands("llama")
+    lengths = jnp.asarray([30, 4, 19, 1], jnp.int32)  # 4, 1, 3, 1 live blocks
+    clean = paged_decode_attention(
+        q, pk, pv, jnp.int32(1), jnp.asarray(KERNEL_TABLES), lengths,
+        interpret=True,
+    )
+    poisoned = KERNEL_TABLES.copy()
+    for b, live in enumerate([4, 1, 3, 1]):
+        poisoned[b, live:] = 15
+    pk, pv = pk.at[:, 15].set(jnp.nan), pv.at[:, 15].set(jnp.nan)
+    args = (q, pk, pv, jnp.int32(1), jnp.asarray(poisoned), lengths)
+    assert np.isnan(np.asarray(paged._attend_gathered(*args))).all()
+    np.testing.assert_array_equal(
+        np.asarray(paged_decode_attention(*args, interpret=True)),
+        np.asarray(clean),
+    )
 
 
 def _greedy_rollout(mod, cfg, params, prompt, max_tokens, stop, window=64):

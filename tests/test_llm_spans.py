@@ -87,6 +87,73 @@ def test_the_three_parts_of_a_decode_step_add_up_to_it(family):
         assert readback["extra"]["bytes"] == 2 * 512 * 4  # [max_slots, vocab] f32
 
 
+def test_a_decode_step_says_how_much_of_its_tables_is_live():
+    """``kv_blocks_live`` is the blocks the step's live rows attend, their
+    own key included, and ``kv_blocks_table`` what a gather of every table
+    brings back: two prompts of 15 and 30 tokens in blocks of 16, so the
+    first crosses into its second block at its second step, the other into
+    its third at the third; the steps are
+    counted under the arm their program was built with."""
+    eng = LLMEngine(llm_config("llama"))
+    prompts = [[7] * 15, [9] * 30]
+    for i, p in enumerate(prompts):
+        eng.add_request(str(i), p, SamplingParams(max_tokens=5, temperature=0.0))
+    while eng.has_unfinished():
+        eng.step()
+    steps = of("llm.decode_step")
+    assert len(steps) == 4  # the first token of each came from its prefill
+    for k, step in enumerate(steps):
+        positions = [len(p) + k for p in prompts]  # where step k writes
+        assert step["extra"]["kv_blocks_live"] == sum(
+            -(-(pos + 1) // 16) for pos in positions
+        )
+        assert step["extra"]["kv_blocks_table"] == 2 * (64 // 16)
+    assert [s["extra"]["kv_blocks_live"] for s in steps] == [3, 4, 5, 5]
+    assert eng.stats["decode_attn_gather_steps"] == 4  # a CPU gathers
+    assert eng.stats["decode_attn_kernel_steps"] == 0
+
+
+def test_the_decode_arm_follows_platform_and_shapes_and_nothing_a_user_sets(monkeypatch):
+    """The kernel on a TPU where the head and block sizes are whole tiles
+    and no mesh spans chips, the gather otherwise; no field of LLMConfig or
+    of the global configuration names it."""
+    import inspect
+
+    import jax
+
+    from ray_tpu.models import paged
+    from ray_tpu.models.kimi_linear import KimiLinearConfig
+
+    tiling = llama.LlamaConfig.tiny(n_layer=1, d_model=256, n_head=2, n_kv_head=1)
+    small_head = llama.LlamaConfig.tiny(n_layer=1, d_model=128, n_head=2, n_kv_head=1)
+    assert (tiling.head_dim, small_head.head_dim) == (128, 64)
+    two_chips = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("tp",))
+    one_chip = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("tp",))
+    cases = [
+        (tiling, 16, None, True), (tiling, 32, one_chip, True),
+        (small_head, 16, None, False),  # GPT-2's heads of 64 as well
+        (tiling, 8, None, False),  # half a bf16 sublane tile
+        (tiling, 16384, None, False),  # a chunk of one block past VMEM
+        (tiling, 16, two_chips, False),  # a Mosaic call is not partitioned
+        (KimiLinearConfig.tiny(), 16, None, False),  # programs of its own
+    ]
+    for backend in ("cpu", "tpu"):
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        for cfg, block, mesh, on_tpu in cases:
+            want = on_tpu and backend == "tpu"
+            assert paged.decode_attends_in_place(cfg, block, mesh=mesh) == want
+    eng = LLMEngine(llm_config(model_config=tiling, max_seq=64))
+    assert eng._decode_arm == "decode_attn_kernel_steps"  # "tpu" still patched
+    assert paged._decode_attention(small_head, 16, None, False) is paged._attend_gathered
+    assert paged._decode_attention(tiling, 16, two_chips, False) is paged._attend_gathered
+    # Nothing to set: the decision's only inputs are the model's shapes, the
+    # block size and the mesh, and no configuration names it.
+    assert list(inspect.signature(paged.decode_attends_in_place).parameters) == [
+        "cfg", "block_size", "mesh"]
+    named = [f.name for c in (LLMConfig, Config) for f in dataclasses.fields(c)]
+    assert not [n for n in named if "attn" in n.lower() or "kernel" in n.lower()]
+
+
 class SlowLogits:
     """Logits whose copy to the host takes a while, as a device's would:
     ``np.asarray`` calls ``__array__``, and notes when it was done."""

@@ -148,6 +148,33 @@ def test_gpt2_125m_train_step_compiles_on_four_chips(v5e_2x2, spec):
     ), "does not fit one v5e chip's 16 GB"
 
 
+@pytest.mark.parametrize(
+    "group,block,width", [(4, 16, 128), (1, 32, 64), (8, 128, 16)],
+    ids=["mistral-7b", "mha-blocks-of-32", "group-8-blocks-of-128"],
+)
+def test_the_decode_attention_kernel_compiles_at_served_widths(
+    v5e_2x2, group, block, width
+):
+    """The block-walking kernel at the widths the benchmark serves (sixteen
+    slots, 8 KV heads of 128, tables of 2,048 positions over a 16-layer
+    pool left in HBM) and at other groups and block sizes that
+    ``paged_attention.fits`` admits: what the chip's compiler would refuse
+    (a copy off the tiling, too much VMEM) it refuses here."""
+    from ray_tpu.ops import paged_attention
+
+    assert paged_attention.fits(8, 128, block, itemsize=2)
+    one = jax.sharding.SingleDeviceSharding(v5e_2x2[0])
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)  # noqa: E731
+    pool = sds((16, 2049 * 16 // block, 8, block, 128), jnp.bfloat16)
+    compiled = jax.jit(paged_attention.paged_decode_attention).lower(
+        sds((16, 8, group, 128), jnp.bfloat16), pool, pool,
+        sds((), jnp.int32), sds((16, width), jnp.int32), sds((16,), jnp.int32),
+    ).compile()
+    assert compiled.as_text().count(MOSAIC) == 1
+    # Nothing pool-sized beside the pool: the operands stay where they are.
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
 @pytest.mark.parametrize("program", ["paged_prefill", "paged_decode"])
 def test_engine_paged_programs_write_the_pool_in_place(v5e_2x2, program):
     """The engine's two paged programs, compiled for one chip: the pool is
@@ -185,6 +212,10 @@ def test_engine_paged_programs_write_the_pool_in_place(v5e_2x2, program):
             sds((4, W), i32), on_chip(eng.pool),
         )
     compiled = lowered.compile()
+    # Decode, lowered for the chip, attends the live blocks in place (head
+    # 128 and blocks of 16 are whole tiles): the kernel, handed the carried
+    # pool where it lies. Prefill gathers.
+    assert compiled.as_text().count(MOSAIC) == (program == "paged_decode")
     pool_k = eng.pool["k"]
     pool_bytes = 2 * pool_k.nbytes
     slab_bytes = pool_k.nbytes // cfg.n_layer
