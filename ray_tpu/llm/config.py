@@ -42,10 +42,12 @@ class LLMConfig:
     # oversubscription), floored at one max-length request + 1.
     # What a block holds is the family's business: keys and values per
     # head, or latent rows (one [kv_lora_rank + rope] row a token, no head
-    # axis) beside a state per slot that no block holds. A family with
-    # such a state is served without the prefix cache, speculative
-    # decoding, tensor parallelism and the disaggregated handoff: the
-    # engine says so by name at construction.
+    # axis), with or without a state per slot that no block holds. Latent
+    # rows alone are shared by prefix and prefilled in chunks like keys
+    # and values; a family with a state per slot is served without the
+    # prefix cache. Either brings programs of its own, and is served
+    # without speculative decoding, tensor parallelism and the
+    # disaggregated handoff: the engine says so by name at construction.
     kv_block_size: int = 16
     num_kv_blocks: Optional[int] = None
     # Parallelism: tensor-parallel degree (mesh `tp` axis over local devices)

@@ -113,24 +113,42 @@ def _validate_block_multiple(name: str, value: int, block_size: int) -> None:
         )
 
 
-def _has_recurrent_state(cfg, config: LLMConfig) -> bool:
-    """Whether the family keeps a recurrent state per slot beside its
-    blocks. What the engine cannot do for such a family is refused here, at
+def _why_not(cfg, what: str) -> Optional[str]:
+    """Why the engine cannot do ``what`` (``spec``: verify speculatively,
+    ``tp``: shard, ``handoff``: export or import the cache) for ``cfg``'s
+    family, or None for a family of keys and values per head served through
+    ``kv_hooks``."""
+    if paged.has_recurrent_state(cfg):
+        fact = f"the family {cfg.family!r} keeps a recurrent state per slot"
+        reason = {
+            "spec": "rejected tokens cannot be taken back out of it",
+            "tp": "neither it nor the family's experts have sharding rules yet",
+            "handoff": "a handoff of pool blocks does not carry it",
+        }
+    elif paged.brings_own_programs(cfg):
+        fact = (
+            f"the family {cfg.family!r} brings its own paged programs over a "
+            "cache that is not keys and values per head"
+        )
+        reason = {
+            "spec": "paged_verify scores through kv_hooks only",
+            "tp": "the family has no sharding rules yet",
+            "handoff": "the handoff exports and imports the pool's keys and values",
+        }
+    else:
+        return None
+    return f"{fact}, and {reason[what]}"
+
+
+def _refuse_at_construction(cfg, config: LLMConfig) -> None:
+    """What the engine cannot do for the family is refused here, at
     construction and by name, not as a shape error at the first request."""
-    if not paged.has_recurrent_state(cfg):
-        return False
-    why = f"the family {cfg.family!r} keeps a recurrent state per slot"
-    if config.spec_decode_tokens > 0:
+    if config.spec_decode_tokens > 0 and _why_not(cfg, "spec"):
         raise ValueError(
-            f"spec_decode_tokens > 0 (speculative verification): {why}, "
-            "and rejected tokens cannot be taken back out of it"
+            f"spec_decode_tokens > 0 (speculative verification): {_why_not(cfg, 'spec')}"
         )
-    if config.tensor_parallelism > 1:
-        raise ValueError(
-            f"tensor_parallelism > 1: {why}, and neither it nor the "
-            "family's experts have sharding rules yet"
-        )
-    return True
+    if config.tensor_parallelism > 1 and _why_not(cfg, "tp"):
+        raise ValueError(f"tensor_parallelism > 1: {_why_not(cfg, 'tp')}")
 
 
 @dataclasses.dataclass
@@ -189,8 +207,16 @@ class LLMEngine:
         if cfg.vocab_size < self.tokenizer.vocab_size:
             raise ValueError("model vocab smaller than tokenizer vocab")
         self.model_config = cfg
-        self._recurrent = _has_recurrent_state(cfg, config)
+        _refuse_at_construction(cfg, config)
         self._model = paged.family(cfg)
+        # Two facts about a family whose cache is not keys and values per
+        # head (models/paged.py, "What a pool is now"). It brings its own
+        # programs: packed ``meta`` operand, ``live`` mask, counters
+        # behind the logits, nothing that goes through ``kv_hooks``. It
+        # keeps a state per slot: resets at position 0, a scratch row, no
+        # prefix cache. The second implies the first.
+        self._own_programs = paged.brings_own_programs(cfg)
+        self._slot_state = paged.has_recurrent_state(cfg)
         devices = jax.devices()
         tp = config.tensor_parallelism
         if tp > 1:
@@ -253,9 +279,9 @@ class LLMEngine:
         # the output is the input's buffer and no step copies 2 x
         # [L, N, KH, block, Dh]. Every call rebinds self.pool; the
         # array passed in is deleted and nothing may keep it.
-        if self._recurrent:
-            # The same two names for a family with a state per slot.
-            # Its programs take the slot (prefill) and the live slots
+        if self._own_programs:
+            # The same two names for a family that brings its programs.
+            # They take the slot (prefill) and the live slots
             # (decode) as well, and every small operand of a call
             # rides in ONE int32 array (``meta``), handed over as
             # numpy: an upload costs the host 0.5-0.6 ms a piece, and
@@ -342,7 +368,7 @@ class LLMEngine:
         )
         for part, arr in self.pool.items():  # bytes of each cache part
             self.stats[f"cache_bytes_{part}"] = int(arr.nbytes)
-        if self._recurrent:
+        if self._slot_state:
             # Prefills that began a sequence and so began from zero state,
             # whatever the slot held; admissions that would have looked a
             # prefix up and could not (a hit needs the state at the
@@ -402,11 +428,10 @@ class LLMEngine:
         monotonic time the caller took the request in, where that was
         earlier than this call (the flight recorder's ``llm.queue`` span
         starts there)."""
-        if prefill_only and self._recurrent:
+        if prefill_only and self._own_programs:
             raise ValueError(
-                "prefill_only (the disaggregated KV export): the family "
-                f"{self.model_config.family!r} keeps a recurrent state per "
-                "slot, which a handoff of pool blocks does not carry"
+                "prefill_only (the disaggregated KV export): "
+                + _why_not(self.model_config, "handoff")
             )
         sampling = sampling or SamplingParams()
         ids = (
@@ -450,11 +475,10 @@ class LLMEngine:
         fails, in which case admission falls back to the local, chunked
         when configured, prefill path). Counts neither requests_total nor
         prompt_tokens: the prefill replica already did."""
-        if self._recurrent:
+        if self._own_programs:
             raise ValueError(
-                "a disaggregated handoff (KV import): the family "
-                f"{self.model_config.family!r} keeps a recurrent state per "
-                "slot, which a handoff of pool blocks does not carry"
+                "a disaggregated handoff (KV import): "
+                + _why_not(self.model_config, "handoff")
             )
         sampling = sampling or SamplingParams()
         stop = (
@@ -501,7 +525,7 @@ class LLMEngine:
         can never serve another prompt's KV. A family with a recurrent
         state is never served from the pool: a hit would need the state
         at the prefix's end."""
-        if not self.config.enable_prefix_caching or self._recurrent:
+        if not self.config.enable_prefix_caching or self._slot_state:
             return None
         self.stats["prefix_lookups"] += 1
         chain = self._chain_hashes(prompt)
@@ -516,7 +540,7 @@ class LLMEngine:
     def _insert_prefix(self, prompt: list, blocks: list) -> None:
         """Pool the prompt's longest aligned prefix: take a reference on
         the request's first P/block blocks — sharing, not copying."""
-        if not self.config.enable_prefix_caching or self._recurrent:
+        if not self.config.enable_prefix_caching or self._slot_state:
             return
         p = self._aligned_prefix_len(len(prompt))
         if p < self.config.prefix_chunk or p > self.config.max_prefix_cache_tokens:
@@ -690,9 +714,9 @@ class LLMEngine:
 
     def _take_counters(self, out: np.ndarray, req: _Request) -> np.ndarray:
         """The logits of a prefill whose read-back is ``out``. A family
-        with a state per slot packs its counters behind them (__init__):
-        they go onto the prefill span still open on ``req``."""
-        if not self._recurrent:
+        that brings its programs packs its counters behind them
+        (__init__): they go onto the prefill span still open on ``req``."""
+        if not self._own_programs:
             return out
         V = self.model_config.vocab_size
         if req.pf_open is not None:
@@ -710,8 +734,9 @@ class LLMEngine:
         """Dispatch one paged prefill of ``n`` tokens from position
         ``start`` into ``slot``; rebinds the donated pool and returns the
         program's second output, still on the device."""
-        if self._recurrent:
-            if start == 0:  # begins from zero state, whatever the slot held
+        if self._own_programs:
+            if self._slot_state and start == 0:
+                # begins from zero state, whatever the slot held
                 self.stats["state_resets"] += 1
             meta = np.concatenate([[n, start, slot], row]).astype(np.int32)
             args = (self.params, toks, meta)
@@ -908,7 +933,7 @@ class LLMEngine:
         if entry is not None:
             self.stats["prefix_hits"] += 1
             self.stats["prefix_tokens_reused"] += P
-        if self._recurrent and self.config.enable_prefix_caching:
+        if self._slot_state and self.config.enable_prefix_caching:
             self.stats["prefix_cache_bypassed"] += 1  # once an admission
         if self._chunks_feasible(P, T):
             self._begin_chunked_prefill(req, slot, P)
@@ -1139,9 +1164,9 @@ class LLMEngine:
                 bs = self._block_size
                 at = self.positions[[r.slot for r in active]]
                 kv_blocks_live = int(((at + bs) // bs).sum())
-            if self._recurrent:
-                # Slots that are free or still prefilling step on the
-                # scratch row of the state and are routed to no expert.
+            if self._own_programs:
+                # Slots that are free or still prefilling are routed to
+                # no expert, and step on the scratch row of a state.
                 live = np.zeros(len(self._slot_req), np.int32)
                 live[[r.slot for r in active]] = 1
                 meta = np.concatenate(
@@ -1164,10 +1189,11 @@ class LLMEngine:
             logits_np = np.asarray(logits)  # raylint: disable=RL101 -- the decode step's ONE intended sync: batched logits readback feeding host-side sampling
             t_read = _time.monotonic() if fr else 0.0
             moe = {}
-            if self._recurrent:  # the counters' row behind the logits
+            if self._own_programs:  # the counters' row behind the logits
                 if fr:
                     moe = self._model.span_fields(
-                        self.model_config, logits_np[-1], len(active), len(active)
+                        self.model_config, logits_np[-1], len(active), len(active),
+                        decode=(at, self.block_tables.size * bs),
                     )
                 logits_np = logits_np[:-1]
             now = _time.perf_counter()

@@ -14,16 +14,11 @@ What this file holds, top down:
   ``params["layers"]`` is a list with one dict a layer and the programs unroll
   it; nothing is stacked and nothing is sliced out of a stack.
 - the mixers, each in the two forms serving needs: :func:`kda_prefill` /
-  :func:`kda_decode` over :mod:`ray_tpu.ops.delta_rule`, :func:`mla_prefill`
-  (latent rows expanded to keys and values per head) / :func:`mla_decode`
-  (``kv_b`` absorbed into the query and the output, so that a step reads latent
-  rows only).
-- :func:`moe_ffn`: routing over all experts of the model, computing the part
-  of the result that the experts held here give (``experts_held`` of them from
-  ``expert_offset``). No capacity and no dropped token: the (token, pick)
-  pairs that land here are sorted by expert and run through grouped matrix
-  products. What absent experts would add is left out; on one chip the layer
-  runs without its exchange.
+  :func:`kda_decode` over :mod:`ray_tpu.ops.delta_rule`. The latent mixer
+  (``mla_latent``, ``mla_prefill``, ``mla_decode``) and the expert layer
+  (``route``, ``moe_ffn``) are :mod:`ray_tpu.models.latent_moe`'s, which this
+  family shares with ``mla_moe``: here without rotation, without a low-rank
+  query and with one expert group, by leaving those arguments off.
 - the paged programs :func:`paged_prefill` / :func:`paged_decode` and
   :func:`init_pool`, which :mod:`ray_tpu.models.paged` hands a
   ``KimiLinearConfig`` to. The cache is ``{"ckv": [L_mla, N, block, 576],
@@ -42,7 +37,18 @@ from typing import Any, ClassVar
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.llama import _mlp_sublayer, _rms_norm
+from ray_tpu.models import latent_moe
+from ray_tpu.models.latent_moe import (  # noqa: F401 -- moe_ffn and route: the family's surface
+    ffn,
+    final_logits,
+    mla_decode,
+    mla_latent,
+    mla_prefill,
+    moe_ffn,
+    outputs,
+    route,
+)
+from ray_tpu.models.llama import _rms_norm
 from ray_tpu.ops.delta_rule import kda_chunked, kda_step
 
 Params = dict
@@ -81,6 +87,8 @@ class KimiLinearConfig:
     expert_offset: int = 0  # ... starting from this one
     experts_per_token: int = 8
     n_shared_experts: int = 1
+    n_group: int = 1  # num_expert_group: the grouped top-k is a plain one
+    topk_group: int = 1
     routed_scaling: float = 2.446
     renormalize: bool = True
     # Serving
@@ -292,146 +300,14 @@ def kda_decode(h, p, cfg: KimiLinearConfig, S, tail):
 
 
 # ---------------------------------------------------------------------------
-# MLA mixer (no rotation: position enters through the KDA layers)
+# What the engine writes on a span (the latent mixer and the expert layer are
+# latent_moe's: module docstring)
 
 
-def mla_latent(h, p, cfg: KimiLinearConfig):
-    """The cache row of each token: ``[RMSNorm(c); k_pe]``, [..., 576]."""
-    ckv = h @ p["wkva"].astype(cfg.dtype)
-    c, k_pe = jnp.split(ckv, [cfg.kv_lora_rank], axis=-1)
-    return jnp.concatenate([_rms_norm(c, p["kv_norm"], cfg.rms_eps), k_pe], axis=-1)
-
-
-def _mla_scale(cfg) -> float:
-    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
-
-
-def mla_prefill(h, rows, mask, p, cfg: KimiLinearConfig):
-    """``h`` [T, D] normed queries; ``rows`` [S, 576] the latent rows they may
-    see under ``mask`` [T, S]. Expands keys and values per head."""
-    T, S = mask.shape
-    H, dn, dv = cfg.n_head, cfg.qk_nope_head_dim, cfg.v_head_dim
-    dt = cfg.dtype
-    q = (h @ p["wq"].astype(dt)).reshape(T, H, -1)
-    c, k_pe = jnp.split(rows, [cfg.kv_lora_rank], axis=-1)
-    kv = (c @ p["wkvb"].astype(dt)).reshape(S, H, dn + dv)
-    k = jnp.concatenate(
-        [kv[..., :dn], jnp.broadcast_to(k_pe[:, None, :], (S, H, k_pe.shape[-1]))], axis=-1
-    )
-    s = jnp.einsum("thd,shd->hts", q, k).astype(_F32) * _mla_scale(cfg)
-    pa = jax.nn.softmax(jnp.where(mask[None], s, -1e30), axis=-1).astype(dt)
-    o = jnp.einsum("hts,shd->thd", pa, kv[..., dn:])
-    return o.reshape(T, H * dv) @ p["wo"].astype(dt)
-
-
-def mla_decode(h, rows, mask, p, cfg: KimiLinearConfig):
-    """One query a row: ``h`` [B, D], ``rows`` [B, S, 576], ``mask`` [B, S].
-    ``kv_b`` is absorbed: its key half into the query, its value half into
-    the output, so attention runs over latent rows as they lie in the pool."""
-    B = h.shape[0]
-    H, dn, dv, R = cfg.n_head, cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
-    dt = cfg.dtype
-    q = (h @ p["wq"].astype(dt)).reshape(B, H, -1)
-    wkvb = p["wkvb"].astype(dt).reshape(R, H, dn + dv)
-    q_lat = jnp.einsum("bhd,rhd->bhr", q[..., :dn], wkvb[..., :dn])
-    ql = jnp.concatenate([q_lat, q[..., dn:]], axis=-1)  # [B, H, 576]
-    s = jnp.einsum("bhc,bsc->bhs", ql, rows).astype(_F32) * _mla_scale(cfg)
-    pa = jax.nn.softmax(jnp.where(mask[:, None], s, -1e30), axis=-1).astype(dt)
-    o_lat = jnp.einsum("bhs,bsr->bhr", pa, rows[..., :R])
-    o = jnp.einsum("bhr,rhd->bhd", o_lat, wkvb[..., dn:])
-    return o.reshape(B, H * dv) @ p["wo"].astype(dt)
-
-
-# ---------------------------------------------------------------------------
-# Expert feed-forward
-
-
-def route(h, p, cfg: KimiLinearConfig):
-    """``(experts [T, k] int32, weights [T, k] float32)`` of each token: the
-    router in float32 over all experts of the model, chosen by score plus
-    selection bias, weighted by score, renormalised over the chosen and
-    scaled. (``num_expert_group`` 1: the grouped top-k is a plain one.)"""
-    s = jax.nn.sigmoid(jnp.dot(
-        h.astype(_F32), p["router"].astype(_F32), precision=jax.lax.Precision.HIGHEST
-    ))
-    _, idx = jax.lax.top_k(s + p["router_bias"].astype(_F32), cfg.experts_per_token)
-    w = jnp.take_along_axis(s, idx, axis=-1)
-    if cfg.renormalize:
-        w = w / jnp.sum(w, axis=-1, keepdims=True)
-    return idx.astype(jnp.int32), w * cfg.routed_scaling
-
-
-def moe_ffn(h, p, cfg: KimiLinearConfig, valid=None):
-    """``h`` [T, D] normed -> ``(y [T, D], counts int32 [2], picks [T, k])``:
-    the experts held here on the picks that land on them, plus the shared
-    expert. ``valid`` [T] bool marks real tokens: the others are routed
-    nowhere, so they touch no expert. ``counts`` is (picks that landed on a
-    held expert, held experts with at least one pick)."""
-    T, D = h.shape
-    E, k = cfg.experts_held, cfg.experts_per_token
-    dt = cfg.dtype
-    idx, w = route(h, p, cfg)
-    local = idx - cfg.expert_offset
-    here = (local >= 0) & (local < E)
-    if valid is not None:
-        here &= valid[:, None]
-    # Sort the (token, pick) pairs by expert; those that land elsewhere get
-    # the number past the last expert and so sort behind every group.
-    expert = jnp.where(here, local, E).reshape(T * k)
-    order = jnp.argsort(expert, stable=True)
-    sizes = jnp.sum(
-        expert[:, None] == jnp.arange(E, dtype=jnp.int32)[None, :], axis=0, dtype=jnp.int32
-    )
-    xs = h[order // k]  # [T k, D], grouped by expert
-    mid = jax.nn.silu(jax.lax.ragged_dot(xs, p["e_gate"].astype(dt), sizes)) * (
-        jax.lax.ragged_dot(xs, p["e_up"].astype(dt), sizes)
-    )
-    ys = jax.lax.ragged_dot(mid, p["e_down"].astype(dt), sizes)
-    # Back to (token, pick) order; a row behind the groups holds nothing.
-    ys = ys[jnp.argsort(order)].reshape(T, k, D).astype(_F32)
-    y = jnp.sum(jnp.where(here[..., None], ys * w[..., None], 0.0), axis=1)
-    shared = (jax.nn.silu(h @ p["s_gate"].astype(dt)) * (h @ p["s_up"].astype(dt))) @ (
-        p["s_down"].astype(dt)
-    )
-    counts = jnp.stack([jnp.sum(here, dtype=jnp.int32), jnp.sum(sizes > 0, dtype=jnp.int32)])
-    return y.astype(dt) + shared, counts, idx
-
-
-def _ffn(x, p, cfg: KimiLinearConfig, layer: int, valid, seen: list):
-    """The feed-forward sublayer with its residual; an expert layer's
-    counts and picks are appended to ``seen``."""
-    if not cfg.is_moe(layer):
-        return _mlp_sublayer(x, p, cfg)
-    y, counts, picks = moe_ffn(_rms_norm(x, p["mlp_norm"], cfg.rms_eps), p, cfg, valid)
-    seen.append((counts, picks))
-    return x + y
-
-
-def _outputs(pool, logits, seen, with_picks: bool):
-    counts = jnp.stack([c for c, _ in seen])
-    if with_picks:
-        return pool, logits, counts, jnp.stack([p for _, p in seen])
-    return pool, logits, counts
-
-
-def span_fields(cfg: KimiLinearConfig, counts, tokens: int, slots: int) -> dict:
-    """What the engine writes on the span of one program run over ``tokens``
-    real tokens of ``slots`` sequences: the program's counters (flat, as read
-    back; two a layer, anything behind them is padding) summed over the
-    expert layers, and the rows of the state the run stepped."""
-    counts = counts[: 2 * cfg.n_moe_layers].reshape(-1, 2)
-    return {
-        "picks": tokens * cfg.experts_per_token * cfg.n_moe_layers,
-        "picks_here": int(counts[:, 0].sum()),
-        "experts_touched": int(counts[:, 1].sum()),
-        "experts_held": cfg.experts_held * cfg.n_moe_layers,
-        "state_slots": slots,
-    }
-
-
-def _final(params, last, cfg: KimiLinearConfig):
-    h = _rms_norm(last, params["final_norm"], cfg.rms_eps)
-    return (h @ params["lm_head"].astype(cfg.dtype)).astype(_F32)
+def span_fields(cfg: KimiLinearConfig, counts, tokens: int, slots: int, decode=None) -> dict:
+    """:func:`ray_tpu.models.latent_moe.span_fields`, and the rows of the
+    state that the run stepped (``slots`` sequences)."""
+    return {**latent_moe.span_fields(cfg, counts, tokens, decode), "state_slots": slots}
 
 
 # ---------------------------------------------------------------------------
@@ -475,7 +351,6 @@ def paged_prefill(
     and with ``with_picks`` the chosen experts [expert layers, T, k] (for the
     benchmark's comparison of routing)."""
     T = tokens.shape[1]
-    S = table.shape[0] * block_size
     ckv, state, conv = pool["ckv"], pool["state"], pool["conv"]
     slot = state.shape[1] - 1 if slot is None else slot
     fresh = start == 0
@@ -483,7 +358,6 @@ def paged_prefill(
     pos = start + jnp.arange(T, dtype=jnp.int32)
     valid = jnp.arange(T) < length
     bids, offs = table[pos // block_size], pos % block_size
-    mask = jnp.arange(S)[None, :] <= pos[:, None]  # [T, S]
     x = params["wte"].astype(cfg.dtype)[tokens[0]]
     seen: list = []
     for i, p, kind, l in _layers(params, cfg):
@@ -496,12 +370,13 @@ def paged_prefill(
             conv = conv.at[l, slot].set(tail.astype(conv.dtype))
         else:
             ckv = ckv.at[l, bids, offs].set(mla_latent(h, p, cfg))
-            rows = ckv[l, table].reshape(S, cfg.latent_dim)
-            out = mla_prefill(h, rows, mask, p, cfg)
-        x = _ffn(x + out, p, cfg, i, valid, seen)
+            out = mla_prefill(
+                h, ckv, l, table, pos, start + length, p, cfg, block_size=block_size
+            )
+        x = ffn(x + out, p, cfg, i, valid, seen)
     last = jax.lax.dynamic_index_in_dim(x, (length - 1).astype(jnp.int32), 0, keepdims=False)
-    logits = _final(params, last[None], cfg)[0]
-    return _outputs({"ckv": ckv, "state": state, "conv": conv}, logits, seen, with_picks)
+    logits = final_logits(params, last[None], cfg)[0]
+    return outputs({"ckv": ckv, "state": state, "conv": conv}, logits, seen, with_picks)
 
 
 def paged_decode(
@@ -534,9 +409,9 @@ def paged_decode(
             ckv = ckv.at[l, bids, offs].set(mla_latent(h, p, cfg))
             rows = ckv[l, tables].reshape(B, S, cfg.latent_dim)
             out = mla_decode(h, rows, mask, p, cfg)
-        x = _ffn(x + out, p, cfg, i, live, seen)
-    return _outputs(
-        {"ckv": ckv, "state": state, "conv": conv}, _final(params, x, cfg), seen, with_picks
+        x = ffn(x + out, p, cfg, i, live, seen)
+    return outputs(
+        {"ckv": ckv, "state": state, "conv": conv}, final_logits(params, x, cfg), seen, with_picks
     )
 
 

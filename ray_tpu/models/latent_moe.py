@@ -1,0 +1,383 @@
+"""What the families with latent attention and routed experts share: one
+implementation, which :mod:`ray_tpu.models.kimi_linear` and
+:mod:`ray_tpu.models.mla_moe` both import.
+
+- **The latent row.** One ``[RMSNorm(c); k_r]`` row of ``kv_lora_rank +
+  qk_rope_head_dim`` values a position, for all heads, written into the block
+  pool under the engine's block tables (:func:`mla_latent`). Where the family
+  rotates, ``k_r`` is rotated by its position *before* it is written, so a row
+  in the pool is good for whoever reads it later, a request that shares the
+  block by prefix included.
+- **Attention over latent rows**, in the two forms serving needs.
+  :func:`mla_prefill` expands keys and values per head, a stretch of the table
+  and a run of queries at a time with a running softmax, over the positions
+  that run may see: no ``[H, T, table]`` score tensor exists. :func:`mla_decode` absorbs the
+  expansion into the query and the output, so that a step reads latent rows as
+  they lie in the pool.
+- **What a family leaves off is an argument it does not give**: ``rope`` (the
+  cosines and sines of the tokens' positions, :func:`rope_tables`; None: no
+  rotation), ``scale`` (None: ``(d_nope + d_rope)^-1/2``), the query's low-rank
+  pair (``wq_a`` / ``q_norm`` / ``wq_b`` in the layer's parameters; absent: one
+  ``wq``), the router's selection bias (``router_bias``; absent: none), and the
+  expert groups (``cfg.n_group`` 1: a plain top-k).
+- **The expert layer** (:func:`route`, :func:`moe_ffn`): routing over all
+  experts of the model, computing the part of the result that the experts held
+  here give (``experts_held`` of them from ``expert_offset``). No capacity and
+  no dropped token: the (token, pick) pairs that land here are sorted by expert
+  and run through grouped matrix products, a long prompt's in passes that
+  stop where the landed pairs end. What absent experts would add is
+  left out; on one chip the layer runs without its exchange.
+
+A configuration handed to these functions has the fields ``n_head``,
+``kv_lora_rank``, ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``,
+``rms_eps``, ``dtype``, ``n_experts``, ``experts_held``, ``expert_offset``,
+``experts_per_token``, ``n_group``, ``topk_group``, ``routed_scaling``,
+``renormalize``, ``n_moe_layers`` and ``is_moe(layer)``.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models.llama import _mlp_sublayer, _rms_norm
+
+_F32 = jnp.float32
+
+# Positions of the table that one step of the prefill's running softmax
+# expands and scores, and queries that go through it together: [H, 512, 512]
+# float32 is 67 MB at 64 heads, where the 2,048 bucket against the whole table
+# of 4,096 would be 2.1 GB a layer.
+KEY_POSITIONS = 512
+
+# Sorted (token, pick) rows that one pass of the grouped products takes: a
+# decode step's rows whole, and a 2,048-token prompt's 16,384 in as many
+# passes as hold a pick that landed here (one, at a sixteenth of the experts).
+ROWS_A_PASS = 2048
+
+
+# ---------------------------------------------------------------------------
+# Rotation (decoupled RoPE on the shared key part, YaRN frequencies)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """``0.1 m ln s + 1`` (1 where nothing is stretched)."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_frequencies(
+    dim: int, theta: float, factor: float = 1.0, original_max: int = 4096,
+    beta_fast: float = 32.0, beta_slow: float = 1.0,
+):
+    """The ``dim / 2`` angular frequencies of a rotation over ``dim`` values:
+    ``theta^(-2i/dim)``, and under YaRN (``factor`` > 1) those divided by
+    ``factor`` where a pair turns fewer than ``beta_slow`` times over the
+    original context, left alone where it turns more than ``beta_fast`` times,
+    and a linear ramp between (the published correction range, floor and
+    ceiling of ``dim ln(original_max / (beta 2 pi)) / (2 ln theta)``)."""
+    i = jnp.arange(dim // 2, dtype=_F32)
+    plain = theta ** (-2.0 * i / dim)
+    if factor <= 1.0:
+        return plain
+
+    def turns_at(beta):
+        return dim * math.log(original_max / (beta * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(turns_at(beta_fast)), 0)
+    high = min(math.ceil(turns_at(beta_slow)), dim - 1)
+    keep = 1.0 - jnp.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return (1.0 - keep) * plain / factor + keep * plain
+
+
+def rope_tables(freqs, positions, mscale: float = 1.0):
+    """``(cos, sin)`` [..., dim / 2] float32 of ``positions`` [...] int."""
+    angles = positions.astype(_F32)[..., None] * freqs
+    return jnp.cos(angles) * mscale, jnp.sin(angles) * mscale
+
+
+def rotate(x, cos, sin):
+    """Rotate the pairs ``(2i, 2i + 1)`` of the last axis of ``x`` (the
+    family's interleaved convention) by the angles of ``cos`` / ``sin``
+    [..., dim / 2], which broadcast against ``x``'s leading axes."""
+    pairs = x.astype(_F32).reshape(*x.shape[:-1], -1, 2)
+    a, b = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Latent attention
+
+
+def whole_tiles(width: int) -> int:
+    """``width`` rounded up to whole 128-lane tiles. A pool whose rows are
+    576 wide is kept by a TPU with the *block* axis minor-most (less padding
+    than 576 -> 640 lanes), and a program that gathers blocks from it first
+    re-lays the whole pool and lays it back at the end: 2 x 1.06 GB a step at
+    32 slots of 4,096 (my chip run, PR 33). A family whose pool is large
+    gives its rows this width (zeros behind the latent row) and keeps the
+    layout it indexes."""
+    return -(-width // 128) * 128
+
+
+def mla_latent(h, p, cfg, rope=None, width=None):
+    """The cache row of each token: ``[RMSNorm(c); R_t k_r]``, [..., 576].
+    ``rope``: ``(cos, sin)`` of the tokens' positions, or None for a family
+    that does not rotate. ``width``: the pool's row width where it is more
+    than the latent row's (zeros fill the rest)."""
+    ckv = h @ p["wkva"].astype(cfg.dtype)
+    c, k_r = jnp.split(ckv, [cfg.kv_lora_rank], axis=-1)
+    if rope is not None:
+        k_r = rotate(k_r, *rope)
+    parts = [_rms_norm(c, p["kv_norm"], cfg.rms_eps), k_r]
+    fill = (width or 0) - cfg.kv_lora_rank - k_r.shape[-1]
+    if fill > 0:
+        parts.append(jnp.zeros((*k_r.shape[:-1], fill), k_r.dtype))
+    return jnp.concatenate(parts, axis=-1)
+
+
+def mla_query(h, p, cfg, rope=None):
+    """``[q_n; R_t q_r]`` per head, [..., H, d_n + d_r]: through the low-rank
+    pair and its norm where the layer has one, else through one matrix."""
+    dt = cfg.dtype
+    if "wq_a" in p:
+        c_q = _rms_norm(h @ p["wq_a"].astype(dt), p["q_norm"], cfg.rms_eps)
+        q = c_q @ p["wq_b"].astype(dt)
+    else:
+        q = h @ p["wq"].astype(dt)
+    q = q.reshape(*h.shape[:-1], cfg.n_head, -1)
+    if rope is None:
+        return q
+    dn = cfg.qk_nope_head_dim
+    cos, sin = (a[..., None, :] for a in rope)  # one angle for every head
+    return jnp.concatenate([q[..., :dn], rotate(q[..., dn:], cos, sin)], axis=-1)
+
+
+def _mla_scale(cfg, scale) -> float:
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 if scale is None else scale
+
+
+def mla_prefill(
+    h, ckv, l: int, table, pos, n_keys, p, cfg, *, block_size: int,
+    rope=None, scale=None, key_positions: int = KEY_POSITIONS,
+):
+    """``h`` [T, D] normed queries at consecutive positions ``pos`` [T];
+    ``ckv`` the latent pool [L, N, block, 576 or wider], which already holds
+    their own rows, read at layer ``l`` through ``table`` [W]; ``n_keys``
+    (traced) the positions that hold a row by now, so that nothing behind
+    them is read. Keys and values are expanded per head a stretch of
+    ``key_positions`` of the table at a time, scored in one product over
+    ``[k_n; k_r]`` under the mask ``column <= position``, and folded into a
+    running softmax (float32 maximum, sum and values): what the expanded
+    attention over the whole table gives, without its scores. The queries go
+    ``key_positions`` at a time too, each such run through the stretches up
+    to its own last position only: a prompt that starts at 0 scores 10 of
+    the 16 tiles of the 2,048 bucket."""
+    T = h.shape[0]
+    H, dn, dv = cfg.n_head, cfg.qk_nope_head_dim, cfg.v_head_dim
+    R, dr, dt = cfg.kv_lora_rank, cfg.qk_rope_head_dim, cfg.dtype
+    nb = math.gcd(table.shape[0], max(1, key_positions // block_size))  # blocks a step
+    Kb = nb * block_size
+    Qb = Kb if T % Kb == 0 else T  # queries a run
+    q = mla_query(h, p, cfg, rope)
+    wkvb = p["wkvb"].astype(dt)
+    scale = _mla_scale(cfg, scale)
+
+    def attend(q, pos):
+        def step(j, carry):
+            m, s_sum, acc = carry
+            blocks = jax.lax.dynamic_slice_in_dim(table, j * nb, nb)
+            rows = ckv[l, blocks].reshape(Kb, -1)
+            kv = (rows[:, :R] @ wkvb).reshape(Kb, H, dn + dv)
+            k_r = jnp.broadcast_to(rows[:, None, R : R + dr], (Kb, H, dr))  # one for all heads
+            k = jnp.concatenate([kv[..., :dn], k_r], axis=-1)
+            s = jnp.einsum("thd,shd->hts", q, k, preferred_element_type=_F32) * scale
+            cols = j * Kb + jnp.arange(Kb)
+            s = jnp.where((cols[None, :] <= pos[:, None])[None], s, -1e30)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            keep = jnp.exp(m - m_new)
+            e = jnp.exp(s - m_new[..., None])
+            acc = acc * keep[..., None] + jnp.einsum(
+                "hts,shd->htd", e.astype(dt), kv[..., dn:], preferred_element_type=_F32
+            )
+            return m_new, s_sum * keep + jnp.sum(e, axis=-1), acc
+
+        # Every query sees column 0, so the first stretch leaves no row without
+        # a key and a later stretch that is masked whole for a row adds
+        # exp(-1e30). The run's last query sees the most.
+        steps = (jnp.minimum(n_keys - 1, pos[-1]) // Kb + 1).astype(jnp.int32)
+        n = q.shape[0]
+        init = (jnp.full((H, n), -1e30, _F32), jnp.zeros((H, n), _F32), jnp.zeros((H, n, dv), _F32))
+        _, s_sum, acc = jax.lax.fori_loop(0, steps, step, init)
+        return (acc / s_sum[..., None]).astype(dt).transpose(1, 0, 2)
+
+    o = jnp.concatenate([attend(q[i : i + Qb], pos[i : i + Qb]) for i in range(0, T, Qb)])
+    return o.reshape(T, H * dv) @ p["wo"].astype(dt)
+
+
+def mla_decode(h, rows, mask, p, cfg, rope=None, scale=None):
+    """One query a row: ``h`` [B, D], ``rows`` [B, S, 576 or wider], ``mask``
+    [B, S]. ``wkvb`` is absorbed: its key half into the query, its value half
+    into the output, so attention runs over latent rows as they lie in the
+    pool (a wider row's zeros meet zeros in the query)."""
+    B = h.shape[0]
+    H, dn, dv, R = cfg.n_head, cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
+    dt = cfg.dtype
+    q = mla_query(h, p, cfg, rope)
+    wkvb = p["wkvb"].astype(dt).reshape(R, H, dn + dv)
+    q_lat = jnp.einsum("bhd,rhd->bhr", q[..., :dn], wkvb[..., :dn])
+    ql = jnp.concatenate([q_lat, q[..., dn:]], axis=-1)  # [B, H, 576]
+    ql = jnp.pad(ql, ((0, 0), (0, 0), (0, rows.shape[-1] - ql.shape[-1])))
+    s = jnp.einsum("bhc,bsc->bhs", ql, rows).astype(_F32) * _mla_scale(cfg, scale)
+    pa = jax.nn.softmax(jnp.where(mask[:, None], s, -1e30), axis=-1).astype(dt)
+    o_lat = jnp.einsum("bhs,bsr->bhr", pa, rows[..., :R])
+    o = jnp.einsum("bhr,rhd->bhd", o_lat, wkvb[..., dn:])
+    return o.reshape(B, H * dv) @ p["wo"].astype(dt)
+
+
+# ---------------------------------------------------------------------------
+# Expert feed-forward
+
+
+def route(h, p, cfg):
+    """``(experts [T, k] int32, weights [T, k] float32)`` of each token: the
+    router in float32 over all experts of the model; chosen by score (plus the
+    selection bias where the layer has one) among the experts of the
+    ``topk_group`` best of ``n_group`` groups of consecutive experts, a group
+    scored by the sum of its two largest; weighted by score, renormalised over
+    the chosen and scaled. One group: a plain top-k."""
+    s = jax.nn.sigmoid(jnp.dot(
+        h.astype(_F32), p["router"].astype(_F32), precision=jax.lax.Precision.HIGHEST
+    ))
+    choice = s + p["router_bias"].astype(_F32) if "router_bias" in p else s
+    G = cfg.n_group
+    if G > 1:
+        grouped = choice.reshape(*choice.shape[:-1], G, -1)
+        best = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)  # [T, G]
+        _, gid = jax.lax.top_k(best, cfg.topk_group)
+        kept = jnp.any(gid[..., None] == jnp.arange(G), axis=-2)  # [T, G]
+        choice = jnp.where(kept[..., None], grouped, -jnp.inf).reshape(choice.shape)
+    _, idx = jax.lax.top_k(choice, cfg.experts_per_token)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if cfg.renormalize:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), w * cfg.routed_scaling
+
+
+def moe_ffn(h, p, cfg, valid=None):
+    """``h`` [T, D] normed -> ``(y [T, D], counts int32 [2], picks [T, k])``:
+    the experts held here on the picks that land on them, plus the shared
+    expert. ``valid`` [T] bool marks real tokens: the others are routed
+    nowhere, so they touch no expert. ``counts`` is (picks that landed on a
+    held expert, held experts with at least one pick).
+
+    The (token, pick) pairs are sorted by expert, those that land elsewhere
+    behind every group. Up to ``ROWS_A_PASS`` pairs (a decode step, a short
+    prompt) go through the grouped products whole. More of them go
+    ``ROWS_A_PASS`` sorted rows a pass, for as many passes as hold a pair that
+    landed here: a chip that holds a sixteenth of the experts computes a
+    sixteenth of a long prompt's rows, and drops none."""
+    T, D = h.shape
+    E, k = cfg.experts_held, cfg.experts_per_token
+    dt = cfg.dtype
+    idx, w = route(h, p, cfg)
+    local = idx - cfg.expert_offset
+    here = (local >= 0) & (local < E)
+    if valid is not None:
+        here &= valid[:, None]
+    # Sort the (token, pick) pairs by expert; those that land elsewhere get
+    # the number past the last expert and so sort behind every group.
+    expert = jnp.where(here, local, E).reshape(T * k)
+    order = jnp.argsort(expert, stable=True)
+    sizes = jnp.sum(
+        expert[:, None] == jnp.arange(E, dtype=jnp.int32)[None, :], axis=0, dtype=jnp.int32
+    )
+    gate, up, down = (p[n].astype(dt) for n in ("e_gate", "e_up", "e_down"))
+
+    def experts(xs, sizes, weight=None):
+        mid = jax.nn.silu(jax.lax.ragged_dot(xs, gate, sizes)) * jax.lax.ragged_dot(xs, up, sizes)
+        if weight is not None:  # a pick's weight, put on its row before the down projection
+            mid = (mid.astype(_F32) * weight[:, None]).astype(dt)
+        return jax.lax.ragged_dot(mid, down, sizes)
+
+    if T * k <= ROWS_A_PASS:
+        ys = experts(h[order // k], sizes)  # [T k, D], grouped by expert
+        # Back to (token, pick) order; a row behind the groups holds nothing.
+        ys = ys[jnp.argsort(order)].reshape(T, k, D).astype(_F32)
+        y = jnp.sum(jnp.where(here[..., None], ys * w[..., None], 0.0), axis=1)
+    else:
+        rows = ROWS_A_PASS
+        order = jnp.pad(order, (0, -(T * k) % rows))
+        weight = jnp.pad(w.reshape(T * k), (0, order.shape[0] - T * k))
+        ends = jnp.cumsum(sizes)
+        landed = ends[-1]
+
+        def one_pass(j, y):
+            lo = j * rows
+            pairs = jax.lax.dynamic_slice_in_dim(order, lo, rows)
+            real = lo + jnp.arange(rows) < landed
+            part = jnp.clip(jnp.minimum(ends, lo + rows) - jnp.maximum(ends - sizes, lo), 0)
+            ys = jnp.where(real[:, None], experts(h[pairs // k], part, weight[pairs]), 0)
+            # Each row is added to its token through a 0/1 matrix (exact in
+            # bfloat16, summed in float32): no scatter.
+            to_token = real[:, None] & (pairs[:, None] // k == jnp.arange(T)[None, :])
+            return y + jnp.einsum("rt,rd->td", to_token.astype(dt), ys, preferred_element_type=_F32)
+
+        y = jax.lax.fori_loop(0, -(-landed // rows), one_pass, jnp.zeros((T, D), _F32))
+    shared = (jax.nn.silu(h @ p["s_gate"].astype(dt)) * (h @ p["s_up"].astype(dt))) @ (
+        p["s_down"].astype(dt)
+    )
+    counts = jnp.stack([jnp.sum(here, dtype=jnp.int32), jnp.sum(sizes > 0, dtype=jnp.int32)])
+    return y.astype(dt) + shared, counts, idx
+
+
+def ffn(x, p, cfg, layer: int, valid, seen: list):
+    """The feed-forward sublayer with its residual; an expert layer's
+    counts and picks are appended to ``seen``."""
+    if not cfg.is_moe(layer):
+        return _mlp_sublayer(x, p, cfg)
+    y, counts, picks = moe_ffn(_rms_norm(x, p["mlp_norm"], cfg.rms_eps), p, cfg, valid)
+    seen.append((counts, picks))
+    return x + y
+
+
+def outputs(pool, logits, seen, with_picks: bool):
+    """What a paged program of these families returns: the pool, the logits,
+    the expert layers' counters [expert layers, 2] and, asked for, the picks."""
+    counts = jnp.stack([c for c, _ in seen])
+    if with_picks:
+        return pool, logits, counts, jnp.stack([p for _, p in seen])
+    return pool, logits, counts
+
+
+def final_logits(params, last, cfg):
+    h = _rms_norm(last, params["final_norm"], cfg.rms_eps)
+    return (h @ params["lm_head"].astype(cfg.dtype)).astype(_F32)
+
+
+# ---------------------------------------------------------------------------
+# What the engine writes on a span
+
+
+def span_fields(cfg, counts, tokens: int, decode=None) -> dict:
+    """What the engine writes on the span of one program run over ``tokens``
+    real tokens: the program's counters (flat, as read back; two a layer,
+    anything behind them is padding) summed over the expert layers. For a
+    decode step, ``decode`` is ``(the live slots' positions, rows the tables
+    span)``: ``latent_rows_live`` is what the step's attention needs (each
+    live slot's ``position + 1`` rows), ``latent_rows_read`` what the program
+    reads a layer: every slot's whole table, while decode gathers it."""
+    counts = counts[: 2 * cfg.n_moe_layers].reshape(-1, 2)
+    out = {
+        "picks": tokens * cfg.experts_per_token * cfg.n_moe_layers,
+        "picks_here": int(counts[:, 0].sum()),
+        "experts_touched": int(counts[:, 1].sum()),
+        "experts_held": cfg.experts_held * cfg.n_moe_layers,
+    }
+    if decode is not None:
+        positions, table_rows = decode
+        out["latent_rows_read"] = int(table_rows)
+        out["latent_rows_live"] = int(positions.sum()) + len(positions)
+    return out

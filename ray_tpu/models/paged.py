@@ -46,17 +46,25 @@ supplies a small hook table (``kv_hooks``), because GQA with group=1 *is*
 MHA. A family whose cache is not keys and values per head supplies its cache
 and its layer bodies itself:
 
-- **What a pool is now.** Either blocks of keys and values, as above, or —
-  for ``kimi_linear`` — latent rows in blocks (``"ckv": [L_mla, N, block,
-  576]``, no head axis, under the same block tables and the same
-  ``BlockManager``) beside a recurrent state and a convolution tail *per
-  slot* (``"state": [L_kda, slots + 1, H, d_k, d_v]`` float32, ``"conv"``),
-  which no block table reaches. Stale keys are masked away by position; a
-  stale state is not, so a prefill from position 0 starts from zero state
-  and a later chunk continues from its slot's. Row ``slots`` is scratch:
-  slots that are free, or still prefilling, step there. Such a pool cannot
-  be shared by prefix, verified speculatively or exported by blocks alone,
-  and :func:`has_recurrent_state` says so to the engine.
+- **What a pool is now.** Blocks of keys and values, as above, or latent
+  rows in blocks (``"ckv": [L, N, block, 576]``: one row a position for all
+  heads, no head axis, under the same block tables and the same
+  ``BlockManager``), and for ``kimi_linear`` a recurrent state and a
+  convolution tail *per slot* beside them (``"state": [L_kda, slots + 1, H,
+  d_k, d_v]`` float32, ``"conv"``), which no block table reaches.
+  Two facts about such a family, which the engine asks one by one:
+  *it brings its own programs* (no ``kv_hooks``: its module supplies
+  ``init_pool``, ``paged_prefill``, ``paged_decode`` and ``span_fields``),
+  so nothing that reads ``pool["k"]`` or scores through the hooks serves it
+  (speculative verification, the disaggregated handoff, tensor
+  parallelism); and *it keeps a state per slot* (:func:`has_recurrent_state`).
+  Latent rows alone (``mla_moe``) are a cache like keys and values: stale
+  rows are masked away by position, a prefix is shared by block ids and a
+  prompt prefills in chunks. A state is not: a stale one is not masked, so a
+  prefill from position 0 starts from zero state and a later chunk continues
+  from its slot's; row ``slots`` is scratch, where slots that are free or
+  still prefilling step; and a prefix hit would need the state at the
+  prefix's end, so such a family is served without the prefix cache.
 """
 
 from __future__ import annotations
@@ -76,6 +84,7 @@ _FAMILIES = {
     "gpt2": "ray_tpu.models.gpt2",
     "llama": "ray_tpu.models.llama",
     "kimi_linear": "ray_tpu.models.kimi_linear",
+    "mla_moe": "ray_tpu.models.mla_moe",
 }
 
 
@@ -84,7 +93,7 @@ def family(cfg):
     ``param_logical_specs`` where the family has sharding rules, and either
     ``kv_hooks(cfg, S)`` (keys and values per head: the pool and the
     programs below serve it) or a cache and programs of its own
-    (``init_pool``, ``paged_prefill``, ``paged_decode``,
+    (``init_pool``, ``paged_prefill``, ``paged_decode``, ``span_fields``,
     ``has_recurrent_state``).
 
     ``kv_hooks`` returns ``(embed, qkv, finish, final, H, KH, Dh)``. The
@@ -98,6 +107,12 @@ def family(cfg):
             f"(known: {', '.join(_FAMILIES)})"
         )
     return importlib.import_module(name)
+
+
+def brings_own_programs(cfg) -> bool:
+    """Whether the family serves through a cache and programs of its own
+    and not through ``kv_hooks`` (module docstring)."""
+    return not hasattr(family(cfg), "kv_hooks")
 
 
 def has_recurrent_state(cfg) -> bool:
@@ -308,6 +323,11 @@ def paged_verify(
         raise ValueError(
             f"paged_verify cannot serve the family {cfg.family!r}: rejected "
             "tokens would have to be taken back out of its recurrent state"
+        )
+    if brings_own_programs(cfg):
+        raise ValueError(
+            f"paged_verify cannot serve the family {cfg.family!r}: it scores "
+            "through kv_hooks, and the family brings programs of its own"
         )
     B, T = tokens.shape
     W = tables.shape[1]

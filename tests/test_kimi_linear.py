@@ -137,8 +137,11 @@ def test_mla_absorbed_decode_is_the_expanded_attention(tiny):
     S, n = 32, 21
     h = jax.random.normal(jax.random.key(2), (S, cfg.d_model))
     rows = kl.mla_latent(h, p, cfg)
-    causal = jnp.arange(S)[None, :] <= jnp.arange(S)[:, None]
-    expanded = kl.mla_prefill(h, rows, causal, p, cfg)
+    # the rows as a one-layer pool under the table 1, 2 (the prefill reads them there)
+    ckv = jnp.zeros((1, 3, 16, rows.shape[-1])).at[0, 1:].set(rows.reshape(2, 16, -1))
+    expanded = kl.mla_prefill(
+        h, ckv, 0, jnp.asarray([1, 2]), jnp.arange(S), jnp.asarray(S), p, cfg, block_size=16
+    )
     absorbed = kl.mla_decode(
         h[n][None], rows[None], (jnp.arange(S) <= n)[None], p, cfg
     )

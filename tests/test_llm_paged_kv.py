@@ -294,10 +294,14 @@ def _tiny_model_of(family):
         from ray_tpu.models.kimi_linear import KimiLinearConfig
 
         return KimiLinearConfig.tiny(max_seq=128)
+    if family == "mla_moe":
+        from ray_tpu.models.mla_moe import MlaMoeConfig
+
+        return MlaMoeConfig.tiny(max_seq=128)
     return _family_model(family)[0]
 
 
-@pytest.mark.parametrize("family", ["gpt2", "llama", "kimi_linear"])
+@pytest.mark.parametrize("family", ["gpt2", "llama", "kimi_linear", "mla_moe"])
 def test_kv_block_size_is_a_block_size_not_a_switch(family):
     """The engine has one cache, the block pool: a ``kv_block_size`` that
     is no block size is refused by name at construction, for every family."""
@@ -316,13 +320,14 @@ def test_a_family_is_looked_up_once_and_an_unknown_one_is_refused():
     """``paged.family`` is the one lookup by name: every family's module
     brings ``init_params`` and either its hooks or its own programs, and a
     name it does not know is a ``ValueError`` that says the name."""
-    for name in ("gpt2", "llama", "kimi_linear"):
+    for name in ("gpt2", "llama", "kimi_linear", "mla_moe"):
         mod = paged.family(_tiny_model_of(name))
         assert callable(mod.init_params)
         own = all(
             hasattr(mod, f) for f in ("init_pool", "paged_prefill", "paged_decode")
         )
         assert hasattr(mod, "kv_hooks") != own
+        assert paged.brings_own_programs(_tiny_model_of(name)) == own
         assert paged.has_recurrent_state(_tiny_model_of(name)) == (
             name == "kimi_linear"
         )

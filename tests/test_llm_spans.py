@@ -123,6 +123,7 @@ def test_the_decode_arm_follows_platform_and_shapes_and_nothing_a_user_sets(monk
 
     from ray_tpu.models import paged
     from ray_tpu.models.kimi_linear import KimiLinearConfig
+    from ray_tpu.models.mla_moe import MlaMoeConfig
 
     tiling = llama.LlamaConfig.tiny(n_layer=1, d_model=256, n_head=2, n_kv_head=1)
     small_head = llama.LlamaConfig.tiny(n_layer=1, d_model=128, n_head=2, n_kv_head=1)
@@ -136,6 +137,7 @@ def test_the_decode_arm_follows_platform_and_shapes_and_nothing_a_user_sets(monk
         (tiling, 16384, None, False),  # a chunk of one block past VMEM
         (tiling, 16, two_chips, False),  # a Mosaic call is not partitioned
         (KimiLinearConfig.tiny(), 16, None, False),  # programs of its own
+        (MlaMoeConfig.tiny(), 16, None, False),  # likewise: it gathers its latent rows
     ]
     for backend in ("cpu", "tpu"):
         monkeypatch.setattr(jax, "default_backend", lambda: backend)
@@ -415,6 +417,10 @@ def engine_of():
             from ray_tpu.models.kimi_linear import KimiLinearConfig
 
             config = llm_config(model_config=KimiLinearConfig.tiny(max_seq=128))
+        elif family == "mla_moe":
+            from ray_tpu.models.mla_moe import MlaMoeConfig
+
+            config = llm_config(model_config=MlaMoeConfig.tiny(max_seq=128))
         else:
             config = llm_config(family)
         return built.setdefault(family, LLMEngine(config))
@@ -423,7 +429,7 @@ def engine_of():
 
 
 @pytest.mark.parametrize("program", ["paged_prefill", "paged_decode"])
-@pytest.mark.parametrize("family", ["gpt2", "llama", "kimi_linear"])
+@pytest.mark.parametrize("family", ["gpt2", "llama", "kimi_linear", "mla_moe"])
 def test_a_jitted_program_carries_its_name_into_the_trace(engine_of, family, program):
     """The profiler's ``XLA Modules`` line names a run after the module, and
     the module after the jitted function: ``jit_paged_decode`` for every
@@ -432,7 +438,7 @@ def test_a_jitted_program_carries_its_name_into_the_trace(engine_of, family, pro
     readers find the programs by these names."""
     eng = engine_of(family)
     toks = np.zeros((1, 16), np.int32)
-    if family == "kimi_linear":  # every small operand in one int32 array
+    if family in ("kimi_linear", "mla_moe"):  # every small operand in one int32 array
         width = 3 + eng.block_tables.shape[1]
         operands = {
             "paged_prefill": (toks, np.zeros(width, np.int32)),
