@@ -12,6 +12,16 @@ Reference parity: the role vLLM's engine plays under ray.llm
   until EOS/max_tokens; new requests prefill into freed slots between
   decode steps, so long generations never block short ones behind a
   static batch barrier.
+- **A decode step in flight.** Where every active row is greedy the
+  program chooses the tokens (argmax over the logits, which stay on the
+  device) and ``step()`` launches step N+1, fed by step N's tokens on the
+  device, before it reads step N's ``[B]`` int32: the host's round (read,
+  books, the pump, admission) runs while the device works. ``max_tokens``
+  and ``max_seq`` ends are counts the host knows in time; a stop token is
+  learnt a step late, and that row's one extra step is discarded. A
+  temperature, a speculative decoder, a prefill in chunks or a replaced
+  ``_sample`` make a turn synchronous (logits to the host, nothing in
+  flight when ``step()`` returns): read off the input, set by nobody.
 - **Tensor parallelism** = the standard rule table over a ``tp`` mesh axis;
   XLA shards the einsums and inserts ICI collectives — no per-layer manual
   split.
@@ -193,6 +203,21 @@ class _Request:
     pf_open: Optional[tuple] = None
 
 
+@dataclasses.dataclass
+class _DecodeStep:
+    """A decode program that has been launched and whose tokens the host
+    has not read. ``rows`` are the requests whose rows were live in it, in
+    the slots they hold; one of them that has finished since (on a stop
+    token, learnt from the step before) has had its row computed for
+    nothing: it is skipped when the step is read."""
+
+    rows: list
+    at: np.ndarray  # the rows' write positions, for the span's fields
+    logits: jax.Array  # [B, V]: copied to the host by a synchronous turn only
+    small: jax.Array  # int32 [B (+ counters)]: argmax tokens, the programs' counters
+    t_launch: float  # monotonic, before its operand was built
+
+
 class LLMEngine:
     def __init__(self, config: LLMConfig, tokenizer=None):
         self.config = config
@@ -287,8 +312,8 @@ class LLMEngine:
             # numpy: an upload costs the host 0.5-0.6 ms a piece, and
             # at a 13 ms step three more of them were a tenth of the
             # step and most of its run-to-run noise (PERF.md section
-            # 6, PR 29). The programs' counters are packed behind the
-            # logits, so that they ride the one read-back a step
+            # 6, PR 29). The prefill's counters are packed behind its
+            # logits, so that they ride the one read-back an admission
             # makes anyway (_take_counters unpacks them).
             def paged_prefill(params, tokens, meta, pool):
                 # meta [3 + W]: length, start, slot, the block table
@@ -300,20 +325,7 @@ class LLMEngine:
                     [logits, counts.reshape(-1).astype(logits.dtype)]
                 )
 
-            def paged_decode(params, meta, pool):
-                # meta [B, 3 + W]: last token, position, live, table
-                pool, logits, counts = paged.paged_decode(
-                    params, meta[:, 0], meta[:, 1], meta[:, 3:], pool,
-                    cfg=cfg, block_size=bs, live=meta[:, 2] > 0,
-                )
-                row = jnp.pad(
-                    counts.reshape(1, -1).astype(logits.dtype),
-                    ((0, 0), (0, logits.shape[1] - counts.size)),
-                )
-                return pool, jnp.concatenate([logits, row])
-
             self._pg_prefill = jax.jit(paged_prefill, donate_argnums=3)
-            self._pg_decode = jax.jit(paged_decode, donate_argnums=2)
         else:
             def paged_prefill(params, tokens, length, start, table, pool):
                 return paged.paged_prefill(
@@ -321,14 +333,41 @@ class LLMEngine:
                     cfg=cfg, block_size=bs,
                 )
 
-            def paged_decode(params, last_tokens, positions, tables, pool):
-                return paged.paged_decode(
-                    params, last_tokens, positions, tables, pool,
+            self._pg_prefill = jax.jit(paged_prefill, donate_argnums=5)
+
+        def paged_decode(params, prev, meta, pool):
+            # One layout for every family. meta [B, 4 + W], numpy: position,
+            # live, the host's token, whether to use it, the block table.
+            # ``prev`` is the third output of the step launched before this
+            # one, still on the device: a row whose last token the host has
+            # not seen (it is in that step) takes it from there, so a
+            # greedy token never visits the host on its way to the next
+            # step. The choice is the program's too: argmax over float32,
+            # the first index on ties, as np.argmax; a family's counters
+            # ride behind the tokens, in the one small array a turn reads.
+            tokens = jnp.where(meta[:, 3] > 0, meta[:, 2], prev[: meta.shape[0]])
+            if self._own_programs:
+                pool, logits, counts = paged.paged_decode(
+                    params, tokens, meta[:, 0], meta[:, 4:], pool,
+                    cfg=cfg, block_size=bs, live=meta[:, 1] > 0,
+                )
+                behind = counts.reshape(-1).astype(jnp.int32)
+            else:
+                pool, logits = paged.paged_decode(
+                    params, tokens, meta[:, 0], meta[:, 4:], pool,
                     cfg=cfg, block_size=bs, mesh=self.mesh,
                 )
+                behind = jnp.zeros(0, jnp.int32)
+            chosen = jnp.argmax(logits.astype(jnp.float32), axis=-1)
+            return pool, logits, jnp.concatenate(
+                [chosen.astype(jnp.int32), behind]
+            )
 
-            self._pg_prefill = jax.jit(paged_prefill, donate_argnums=5)
-            self._pg_decode = jax.jit(paged_decode, donate_argnums=4)
+        self._pg_decode = jax.jit(paged_decode, donate_argnums=3)
+        # The decode step that has been launched and not read (step()),
+        # and what a step with none before it is handed as ``prev``.
+        self._inflight: Optional[_DecodeStep] = None
+        self._no_prev = None
         # Prefix pool: key (chunk-aligned token tuple hash) ->
         # {"blocks": the prefix's block ids, "tokens", "len", "used"}.
         # LRU within max_prefix_cache_tokens.
@@ -360,6 +399,11 @@ class LLMEngine:
             # (paged.decode_attends_in_place: platform and shapes decide):
             "decode_attn_kernel_steps": 0,  # live blocks read in place
             "decode_attn_gather_steps": 0,  # whole tables gathered
+            # Of those, the programs launched before the step before them
+            # was read; and the rows computed for a request that had ended
+            # on a stop token by then (never appended, streamed or counted).
+            "decode_steps_ahead": 0,
+            "decode_rows_discarded": 0,
         }
         self._decode_arm = (
             "decode_attn_kernel_steps"
@@ -1108,6 +1152,13 @@ class LLMEngine:
         return [req] if req.finished else []
 
     def _sample(self, logits: np.ndarray, req: _Request) -> int:
+        """The next token of ``req`` from a row of logits on the host: every
+        first token (a prefill's logits are read at admission), and every
+        row of a synchronous turn. A turn that runs ahead does not come
+        here: its rows are greedy and the decode program's own argmax is
+        this function's. Replacing it on an engine (the benchmark's output
+        check notes the logits and forces the token here) therefore makes
+        every turn synchronous: ``_runs_ahead``."""
         if req.temperature <= 0.0:
             return int(np.argmax(logits))
         z = logits / req.temperature
@@ -1143,8 +1194,25 @@ class LLMEngine:
 
     # -- the engine loop ------------------------------------------------------
     def step(self) -> list:
-        """Admit + one decode step for all active slots. Returns the
-        requests that finished this step."""
+        """Admit, then one decode step for all active slots. Returns the
+        requests that finished this turn.
+
+        **What may be in flight when it returns.** Where every active row is
+        greedy, a turn launches the NEXT turn's decode program before it
+        reads this one's tokens (``_runs_ahead`` says when; ``_decode_turn``
+        how), so that the device goes from one program into the next while
+        the host keeps its books and comes back through the pump. Then
+        ``self.pool`` is the handle that program will fill, ``positions``,
+        ``last_tokens`` and ``generated`` hold what the host has READ, one
+        token behind the device, and ``block_tables`` has already been
+        handed over. Whatever is launched later (a prefill, a handoff's
+        scatter, an export's gather) takes the pool from that handle and so
+        runs after it on the device: reading or rebinding ``pool`` between
+        steps is always safe. Writing ``block_tables`` or ``positions``
+        between steps, or expecting the next step to see such a write, is
+        for callers whose engine is on the synchronous arm (a replaced
+        ``_sample``, a temperature, a speculative decoder, a prefill in
+        chunks): there nothing is in flight between steps."""
         instrument = _metrics.metrics_enabled()
         # Prefill chunks of already-admitted long prompts advance BEFORE
         # this step's admissions, so a request admitted this step runs
@@ -1157,90 +1225,200 @@ class LLMEngine:
         if active and self._spec is not None and self._spec_eligible(active):
             finished += self._spec.step(active)
         elif active:
-            fr = _flightrec.on()
-            t_dec = _time.monotonic()
-            self.stats[self._decode_arm] += 1
-            if fr:  # blocks this step's rows hold, before they advance
-                bs = self._block_size
-                at = self.positions[[r.slot for r in active]]
-                kv_blocks_live = int(((at + bs) // bs).sum())
-            if self._own_programs:
-                # Slots that are free or still prefilling are routed to
-                # no expert, and step on the scratch row of a state.
-                live = np.zeros(len(self._slot_req), np.int32)
-                live[[r.slot for r in active]] = 1
-                meta = np.concatenate(
-                    [
-                        self.last_tokens[:, None], self.positions[:, None],
-                        live[:, None], self.block_tables,
-                    ],
-                    axis=1,
-                )
-                self.pool, logits = self._pg_decode(self.params, meta, self.pool)
-            else:
-                self.pool, logits = self._pg_decode(
-                    self.params,
-                    jnp.asarray(self.last_tokens),
-                    jnp.asarray(self.positions),
-                    jnp.asarray(self.block_tables),
-                    self.pool,
-                )
-            t_disp = _time.monotonic() if fr else 0.0
-            logits_np = np.asarray(logits)  # raylint: disable=RL101 -- the decode step's ONE intended sync: batched logits readback feeding host-side sampling
-            t_read = _time.monotonic() if fr else 0.0
-            moe = {}
-            if self._own_programs:  # the counters' row behind the logits
-                if fr:
-                    moe = self._model.span_fields(
-                        self.model_config, logits_np[-1], len(active), len(active),
-                        decode=(at, self.block_tables.size * bs),
-                    )
-                logits_np = logits_np[:-1]
-            now = _time.perf_counter()
-            for req in active:
-                slot = req.slot
-                self.positions[slot] += 1
-                tok = self._sample(logits_np[slot], req)
-                req.generated.append(tok)
-                self.stats["tokens_generated"] += 1
-                if instrument and req.t_last_token:
-                    _ITL_SECONDS.observe(now - req.t_last_token)
-                req.t_last_token = now
-                self.last_tokens[slot] = tok
-                self._maybe_finish(req)
-                if req.finished:
-                    finished.append(req)
-            if fr:
-                # Batch-wide phases (no rid). The step, and its three
-                # parts end to end: the uploads and the launch, the wait
-                # for the device with the copy of the logits, and the
-                # sampling of every active slot on the host.
-                t_end = _time.monotonic()
-                batch = len(active)
-                _flightrec.record(
-                    "llm", "llm.decode_dispatch", t=t_dec,
-                    dur_s=t_disp - t_dec, batch=batch,
-                )
-                _flightrec.record(
-                    "llm", "llm.decode_readback", t=t_disp,
-                    dur_s=t_read - t_disp, bytes=logits_np.nbytes,
-                )
-                _flightrec.record(
-                    "llm", "llm.decode_sample", t=t_read,
-                    dur_s=t_end - t_read, batch=batch,
-                )
-                # How much of the tables the traffic fills: the blocks
-                # the live rows attend (ceil((position + 1) / block)
-                # each) over the B x W entries a gather would bring back.
-                _flightrec.record(
-                    "llm", "llm.decode_step", t=t_dec,
-                    dur_s=t_end - t_dec, batch=batch,
-                    kv_blocks_live=kv_blocks_live,
-                    kv_blocks_table=self.block_tables.size, **moe,
-                )
+            finished += self._decode_turn(active, instrument)
+        else:
+            # Ran dry: a step in flight has only rows of requests that
+            # ended on a stop token, and goes with them.
+            self._inflight = None
         self._steps += 1
         if instrument:
             self._publish_metrics()
+        return finished
+
+    def _runs_ahead(self, active: list) -> bool:
+        """Whether this turn may choose its tokens on the device and launch
+        the next step before it reads them; read off the engine's input, set
+        by nobody. Every active row is greedy (the program's argmax is then
+        the sample: a temperature draws from ``self._rng`` on the host); no
+        speculative decoder is built (it reads ``last_tokens`` on the host);
+        no slot is mid-way through a prefill in chunks (its cursor moves
+        between steps); and ``_sample`` is the engine's own: whoever
+        replaces it wants every row's logits, and may rewrite
+        ``block_tables`` and ``pool`` between steps."""
+        return (
+            self._spec is None
+            and getattr(self._sample, "__func__", None) is LLMEngine._sample
+            and all(r.temperature <= 0.0 for r in active)
+            and not any(r is not None and r.prefilling for r in self._slot_req)
+        )
+
+    def _ends_by_count(self, req: _Request) -> bool:
+        """Whether ``req``, a row of the step in flight, ends at that step's
+        token whatever it is: ``max_tokens`` and ``max_seq`` are counts (what
+        ``_maybe_finish`` will find once the token is appended)."""
+        return (
+            len(req.generated) + 1 >= req.max_tokens
+            or self.positions[req.slot] + 2 >= self.config.max_seq
+        )
+
+    def _launch_decode(self, rows: list, behind: Optional[_DecodeStep] = None):
+        """Build the one operand and launch a decode program in which
+        ``rows`` are live. ``behind`` is the step launched before it and not
+        yet read: its rows stand one position on and take their token from
+        its output on the device; those of its rows that are not among
+        ``rows`` end at it by count, and are left out as a free slot is (not
+        live, position 0, table on the scratch block)."""
+        t_launch = _time.monotonic()
+        if self._no_prev is None:
+            B, W = self.block_tables.shape
+            i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+            small = jax.eval_shape(
+                self._pg_decode, self.params, i32(B), i32(B, 4 + W), self.pool
+            )[2]
+            self._no_prev = jnp.zeros(small.shape, jnp.int32)
+            if self.mesh is not None:
+                self._no_prev = jax.device_put(self._no_prev, self._replicated)
+        meta = np.concatenate(
+            [
+                self.positions[:, None], np.zeros_like(self.positions)[:, None],
+                self.last_tokens[:, None], np.ones_like(self.positions)[:, None],
+                self.block_tables,
+            ],
+            axis=1,
+        )
+        slots = [r.slot for r in rows]
+        if behind is not None:
+            theirs = {r.slot for r in behind.rows if not r.finished}
+            on = sorted(theirs.intersection(slots))
+            meta[on, 0] += 1
+            meta[on, 3] = 0
+            ended = sorted(theirs.difference(slots))
+            meta[ended] = 0
+            meta[ended, 3] = 1
+        meta[slots, 1] = 1
+        self.stats[self._decode_arm] += 1
+        self.pool, logits, small = self._pg_decode(
+            self.params, self._no_prev if behind is None else behind.small,
+            meta, self.pool,
+        )
+        return _DecodeStep(rows, meta[slots, 0], logits, small, t_launch)
+
+    def _decode_turn(self, active: list, instrument: bool) -> list:
+        """One turn's decode step over ``active``; returns the requests
+        that finished at it. The step that is read is the one in flight, or
+        one launched now where there is none. A turn that runs ahead
+        (``_runs_ahead``) then launches the next turn's step, for the rows
+        that go on and those admitted since, BEFORE it waits for this
+        step's tokens, and reads ``[B]`` int32 where a synchronous turn
+        reads the logits and calls ``_sample`` a row. A stop token is the
+        one end learnt a step late: that row's next step is already
+        launched, and is discarded when its turn comes."""
+        fr = _flightrec.on()
+        finished: list = []
+        ahead = self._runs_ahead(active)
+        cur, self._inflight = self._inflight, None
+        if cur is not None and all(r.finished for r in cur.rows):
+            cur = None  # computed for requests that had all ended: dropped
+        # The span starts at the turn's first launch, so that the next
+        # program to start on the device after it is a decode program (the
+        # benchmark's readers pair a span with that run).
+        t_dec = _time.monotonic()
+        if cur is None:
+            cur = self._launch_decode(active)
+        nxt = None
+        if ahead:
+            reading = {id(r) for r in cur.rows}
+            on = [
+                r for r in active
+                if id(r) not in reading or not self._ends_by_count(r)
+            ]
+            if on:
+                nxt = self._launch_decode(on, behind=cur)
+                self.stats["decode_steps_ahead"] += 1
+        going = {id(r) for r in nxt.rows} if nxt is not None else ()
+        t_disp = _time.monotonic() if fr else 0.0
+        if nxt is None and cur.t_launch < t_dec:
+            # A turn that launches nothing (every row of the step in flight
+            # ends at it by count, or the turn is synchronous) has no
+            # program of its own to start at: its span starts where the
+            # step it reads was launched, in the turn before, with no
+            # dispatch, and its wait for the tokens runs from there.
+            t_dec = t_disp = cur.t_launch
+        B = len(self._slot_req)
+        logits_np = counters = None
+        if ahead:
+            small = np.asarray(cur.small)  # raylint: disable=RL101 -- the decode step's ONE intended sync: the tokens the program chose (and a family's counters), a few hundred bytes
+            copied = small.nbytes
+            chosen, counters = small[:B], small[B:]
+        else:
+            logits_np = np.asarray(cur.logits)  # raylint: disable=RL101 -- the synchronous arm's ONE intended sync: batched logits readback feeding host-side sampling
+            copied = logits_np.nbytes
+            if fr and self._own_programs:
+                counters = np.asarray(cur.small)[B:]  # raylint: disable=RL101 -- the programs' counters, ready with the logits
+                copied += cur.small.nbytes
+        t_read = _time.monotonic() if fr else 0.0
+        now = _time.perf_counter()
+        rows = [r for r in cur.rows if not r.finished]
+        for req in rows:
+            slot = req.slot
+            self.positions[slot] += 1
+            tok = (
+                int(chosen[slot]) if logits_np is None
+                else self._sample(logits_np[slot], req)
+            )
+            req.generated.append(tok)
+            self.stats["tokens_generated"] += 1
+            if instrument and req.t_last_token:
+                _ITL_SECONDS.observe(now - req.t_last_token)
+            req.t_last_token = now
+            self.last_tokens[slot] = tok
+            self._maybe_finish(req)
+            if req.finished:
+                finished.append(req)
+                if id(req) in going:  # a stop token: its next row is waste
+                    self.stats["decode_rows_discarded"] += 1
+        self._inflight = nxt
+        if fr:
+            # Batch-wide phases (no rid). The step, and its three parts
+            # end to end: building the operand and launching (the next
+            # step's, when running ahead), the wait for this step's
+            # tokens (or logits) with their copy, and the books of every
+            # row (sampling too on the synchronous arm). ``batch`` is the
+            # rows of the step that was read, ``discarded`` those of them
+            # computed for nothing, ``ahead`` whether this turn launched
+            # the next step before it read this one.
+            t_end = _time.monotonic()
+            batch = len(cur.rows)
+            moe = {}
+            if counters is not None and counters.size:
+                moe = self._model.span_fields(
+                    self.model_config, counters, batch, batch,
+                    decode=(cur.at, self.block_tables.size * self._block_size),
+                )
+            _flightrec.record(
+                "llm", "llm.decode_dispatch", t=t_dec,
+                dur_s=t_disp - t_dec, batch=batch,
+            )
+            _flightrec.record(
+                "llm", "llm.decode_readback", t=t_disp,
+                dur_s=t_read - t_disp, bytes=copied,
+            )
+            _flightrec.record(
+                "llm", "llm.decode_sample", t=t_read,
+                dur_s=t_end - t_read, batch=batch,
+            )
+            # How much of the tables the traffic fills: the blocks
+            # the live rows attend (ceil((position + 1) / block)
+            # each) over the B x W entries a gather would bring back.
+            bs = self._block_size
+            _flightrec.record(
+                "llm", "llm.decode_step", t=t_dec,
+                dur_s=t_end - t_dec, batch=batch,
+                kv_blocks_live=int(((cur.at + bs) // bs).sum()),
+                kv_blocks_table=self.block_tables.size,
+                ahead=int(nxt is not None), discarded=batch - len(rows),
+                **moe,
+            )
         return finished
 
     def _spec_eligible(self, active: list) -> bool:

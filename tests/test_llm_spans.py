@@ -69,9 +69,20 @@ def end(e):
 FAMILIES = pytest.mark.parametrize("family", ["gpt2", "llama"])
 
 
+@pytest.mark.parametrize("arm", ["ahead", "synchronous"])
 @FAMILIES
-def test_the_three_parts_of_a_decode_step_add_up_to_it(family):
+def test_the_three_parts_of_a_decode_step_add_up_to_it(family, arm):
+    """One ``llm.decode_step`` a turn, tiled by its three parts, on both
+    arms. Running ahead, a turn's dispatch is the NEXT step's launch and its
+    read-back the few bytes of tokens the program chose; the last turn
+    launches nothing (both rows end at the step in flight by count), so its
+    span starts where that step was launched, with no dispatch. A wrapped
+    ``_sample`` makes every turn synchronous: launch, the logits' copy,
+    sampling on the host."""
     eng = LLMEngine(llm_config(family))
+    if arm == "synchronous":
+        own = eng._sample
+        eng._sample = lambda logits, req: own(logits, req)
     eng.generate(["hello there", "abc"], SamplingParams(max_tokens=5))
     steps = of("llm.decode_step")
     parts = [of(p) for p in
@@ -83,8 +94,20 @@ def test_the_three_parts_of_a_decode_step_add_up_to_it(family):
         total = dispatch["dur_s"] + readback["dur_s"] + sample["dur_s"]
         assert abs(total - step["dur_s"]) < MS
         assert dispatch["extra"]["batch"] == sample["extra"]["batch"] == 2
-        assert step["extra"]["batch"] == 2
-        assert readback["extra"]["bytes"] == 2 * 512 * 4  # [max_slots, vocab] f32
+        assert step["extra"]["batch"] == 2 and step["extra"]["discarded"] == 0
+        assert readback["extra"]["bytes"] == {
+            "ahead": 2 * 4,  # [max_slots] int32
+            "synchronous": 2 * 512 * 4,  # [max_slots, vocab] f32
+        }[arm]
+    ahead = [s["extra"]["ahead"] for s in steps]
+    assert ahead == ([1, 1, 1, 0] if arm == "ahead" else [0, 0, 0, 0])
+    assert eng.stats["decode_steps_ahead"] == sum(ahead)
+    for k in range(3):  # a turn that launches starts its span there, after the one before
+        assert steps[k + 1]["t"] >= end(steps[k]) or (arm, k) == ("ahead", 2)
+    if arm == "ahead":  # the last turn: from the launch of the step it read
+        dispatches = parts[0]
+        assert dispatches[3]["dur_s"] == 0.0
+        assert steps[2]["t"] < steps[3]["t"] < end(steps[2])
 
 
 def test_a_decode_step_says_how_much_of_its_tables_is_live():
@@ -271,10 +294,10 @@ def test_pump_gap_lies_between_two_steps_and_not_after_a_dry_engine():
     assert (gaps1, len(gaps)) == (2, 3)
     assert len(pushes) == 5 and pushes[0]["extra"]["streams"] == 1
     dry = steps[3]["t"] - end(steps[2])
-    assert all(g["dur_s"] < dry for g in gaps)
+    assert not [g for g in gaps if g["t"] < steps[3]["t"] and end(g) > end(steps[2])]
     for gap in gaps:  # from one step's return to the next one's entry
-        before = max((s for s in steps if end(s) <= gap["t"] + MS), key=end)
-        after = min((s for s in steps if s["t"] >= end(gap) - MS), key=lambda s: s["t"])
+        before = max((s for s in steps if end(s) <= gap["t"]), key=end)
+        after = min((s for s in steps if end(s) >= end(gap)), key=end)
         assert steps.index(after) == steps.index(before) + 1
         assert gap["extra"]["pending"] == 0
         inside = [p for p in pushes if gap["t"] <= p["t"] and end(p) <= end(gap)]
@@ -289,8 +312,11 @@ def test_pump_gap_lies_between_two_steps_and_not_after_a_dry_engine():
     for gap in gaps:
         assert any(end(t) == gap["t"] for t in turns)
         assert any(t["t"] == end(gap) for t in turns)
-    for step in steps:
-        assert any(t["t"] <= step["t"] and end(step) <= end(t) for t in turns)
+    for step in steps:  # a step ends in its turn, and starts there if the turn launched one
+        (turn,) = [t for t in turns if t["t"] <= end(step) <= end(t)]
+        assert turn["t"] <= step["t"] or not step["extra"]["ahead"]
+    # a stream's last turn launches nothing: its span starts in the turn before
+    assert [s["extra"]["ahead"] for s in steps] == [1, 1, 0, 1, 0]
 
 
 # -- the hop into the replica -------------------------------------------------
@@ -438,20 +464,14 @@ def test_a_jitted_program_carries_its_name_into_the_trace(engine_of, family, pro
     readers find the programs by these names."""
     eng = engine_of(family)
     toks = np.zeros((1, 16), np.int32)
-    if family in ("kimi_linear", "mla_moe"):  # every small operand in one int32 array
-        width = 3 + eng.block_tables.shape[1]
-        operands = {
-            "paged_prefill": (toks, np.zeros(width, np.int32)),
-            "paged_decode": (np.zeros((len(eng.block_tables), width), np.int32),),
-        }[program]
-    elif program == "paged_prefill":
+    slots, width = eng.block_tables.shape
+    if program == "paged_decode":  # one layout for every family: prev, meta
+        operands = (jnp.zeros(slots, jnp.int32), np.zeros((slots, 4 + width), np.int32))
+    elif family in ("kimi_linear", "mla_moe"):  # every small operand in one int32 array
+        operands = (toks, np.zeros(3 + width, np.int32))
+    else:
         n, z = jnp.asarray(4, jnp.int32), jnp.asarray(0, jnp.int32)
         operands = (jnp.asarray(toks), n, z, jnp.asarray(eng.block_tables[0]))
-    else:
-        operands = (
-            jnp.asarray(eng.last_tokens), jnp.asarray(eng.positions),
-            jnp.asarray(eng.block_tables),
-        )
     jitted = {"paged_prefill": eng._pg_prefill, "paged_decode": eng._pg_decode}[program]
     assert _module_name(jitted, eng.params, *operands, eng.pool) == f"jit_{program}"
 

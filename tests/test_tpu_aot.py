@@ -207,9 +207,9 @@ def test_engine_paged_programs_write_the_pool_in_place(v5e_2x2, program):
             sds((), i32), sds((W,), i32), on_chip(eng.pool),
         )
     else:
-        lowered = eng._pg_decode.lower(
-            on_chip(eng.params), sds((4,), i32), sds((4,), i32),
-            sds((4, W), i32), on_chip(eng.pool),
+        lowered = eng._pg_decode.lower(  # the step before's tokens, the one operand
+            on_chip(eng.params), sds((4,), i32), sds((4, 4 + W), i32),
+            on_chip(eng.pool),
         )
     compiled = lowered.compile()
     # Decode, lowered for the chip, attends the live blocks in place (head
