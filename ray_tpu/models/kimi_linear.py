@@ -91,6 +91,7 @@ class KimiLinearConfig:
     topk_group: int = 1
     routed_scaling: float = 2.446
     renormalize: bool = True
+    hidden_act: str = "silu"  # of the experts, which have a gate (SwiGLU)
     # Serving
     max_seq: int = 2048
     state_slots: int = 16  # state rows where the caller names no count
@@ -421,36 +422,6 @@ def paged_decode(
 
 @functools.partial(jax.jit, static_argnames=("cfg", "rounds", "tokens"))
 def balance_routers(params, key, cfg: KimiLinearConfig, rounds: int, tokens: int):
-    """``params`` with each expert layer's ``router_bias`` set by the published
-    rule of balancing without an auxiliary loss: round after round over
-    seeded random tokens, the bias of an expert that got less than its share
-    of the picks goes up by a step and that of one that got more goes down.
-    A trained checkpoint is served with a bias that has balanced its experts;
-    random weights with a zero bias are not balanced at all (SiLU's positive
-    mean gives every hidden state a common part, so every token favours the
-    same few experts, and which chip's share they fall into changes with the
-    seed: PERF.md section 6, PR 29). The bias enters the selection only."""
-    bs = 16
-    table = jnp.arange(1, tokens // bs + 1, dtype=jnp.int32)
-    pool = init_pool(cfg, tokens // bs + 1, bs, 0)
-    at = [n for n, p in enumerate(params["layers"]) if "router_bias" in p]
-    length, start = jnp.asarray(tokens, jnp.int32), jnp.asarray(0, jnp.int32)
-
-    def with_biases(biases):
-        layers = list(params["layers"])
-        for n, b in zip(at, biases):
-            layers[n] = {**layers[n], "router_bias": b}
-        return {**params, "layers": layers}
-
-    def one_round(r, biases):
-        toks = jax.random.randint(jax.random.fold_in(key, r), (1, tokens), 0, cfg.vocab_size)
-        *_, picks = paged_prefill(
-            with_biases(biases), toks, length, start, table, pool, cfg,
-            block_size=bs, with_picks=True,
-        )
-        load = jnp.mean(jax.nn.one_hot(picks, cfg.n_experts, dtype=_F32), axis=(1, 2))
-        step = 0.02 * (1.0 - r / rounds)  # of a score in (0, 1); shrinking, so it settles
-        return biases + step * jnp.sign(1.0 / cfg.n_experts - load)
-
-    biases = jnp.stack([params["layers"][n]["router_bias"] for n in at])
-    return with_biases(jax.lax.fori_loop(0, rounds, one_round, biases))
+    """:func:`ray_tpu.models.latent_moe.balance_routers` over this family's
+    prefill."""
+    return latent_moe.balance_routers(params, key, cfg, rounds, tokens, init_pool, paged_prefill)

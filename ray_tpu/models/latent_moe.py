@@ -26,13 +26,27 @@ implementation, which :mod:`ray_tpu.models.kimi_linear` and
   no dropped token: the (token, pick) pairs that land here are sorted by expert
   and run through grouped matrix products, a long prompt's in passes that
   stop where the landed pairs end. What absent experts would add is
-  left out; on one chip the layer runs without its exchange.
+  left out; on one chip the layer runs without its exchange. Here too a
+  family says what it has by what its layer's parameters hold: experts with a
+  gate (``e_gate``, ``s_gate``: ``act(gate) * up``) or without one
+  (``act(up)``), ``act`` the configuration's ``hidden_act``; and the routed
+  part at the model's width, or in a latent (``latent_in`` / ``latent_out``:
+  the router and the shared expert read the layer's input, the routed experts
+  read ``latent_in`` of it, and their weighted sum goes back through
+  ``latent_out``).
+
+**The module's name** means latent *attention* and routed experts. NVIDIA's
+"LatentMoE" (``nemotron_h``) is another thing, the expert layer itself computed
+in a latent, which :func:`moe_ffn` now also does: that family has no latent
+attention and imports the expert layer, :func:`balance_routers` and
+:func:`span_fields` alone.
 
 A configuration handed to these functions has the fields ``n_head``,
 ``kv_lora_rank``, ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``,
 ``rms_eps``, ``dtype``, ``n_experts``, ``experts_held``, ``expert_offset``,
 ``experts_per_token``, ``n_group``, ``topk_group``, ``routed_scaling``,
-``renormalize``, ``n_moe_layers`` and ``is_moe(layer)``.
+``renormalize``, ``hidden_act``, ``n_moe_layers`` and ``is_moe(layer)`` (the
+expert layer reads only those from ``n_experts`` on).
 """
 
 from __future__ import annotations
@@ -51,6 +65,9 @@ _F32 = jnp.float32
 # float32 is 67 MB at 64 heads, where the 2,048 bucket against the whole table
 # of 4,096 would be 2.1 GB a layer.
 KEY_POSITIONS = 512
+
+# ``hidden_act`` -> the experts' activation. ``relu2`` is the squared ReLU.
+ACTIVATIONS = {"silu": jax.nn.silu, "relu2": lambda x: jnp.square(jax.nn.relu(x))}
 
 # Sorted (token, pick) rows that one pass of the grouped products takes: a
 # decode step's rows whole, and a 2,048-token prompt's 16,384 in as many
@@ -278,10 +295,16 @@ def moe_ffn(h, p, cfg, valid=None):
     prompt) go through the grouped products whole. More of them go
     ``ROWS_A_PASS`` sorted rows a pass, for as many passes as hold a pair that
     landed here: a chip that holds a sixteenth of the experts computes a
-    sixteenth of a long prompt's rows, and drops none."""
-    T, D = h.shape
+    sixteenth of a long prompt's rows, and drops none.
+
+    What the layer's parameters hold decides its form (module docstring): with
+    ``latent_in`` / ``latent_out`` the routed experts run on ``h @ latent_in``
+    and their weighted sum goes back through ``latent_out``; without ``e_gate``
+    (``s_gate``) the routed (shared) experts have no gate."""
+    T = h.shape[0]
     E, k = cfg.experts_held, cfg.experts_per_token
     dt = cfg.dtype
+    act = ACTIVATIONS[cfg.hidden_act]
     idx, w = route(h, p, cfg)
     local = idx - cfg.expert_offset
     here = (local >= 0) & (local < E)
@@ -294,10 +317,18 @@ def moe_ffn(h, p, cfg, valid=None):
     sizes = jnp.sum(
         expert[:, None] == jnp.arange(E, dtype=jnp.int32)[None, :], axis=0, dtype=jnp.int32
     )
-    gate, up, down = (p[n].astype(dt) for n in ("e_gate", "e_up", "e_down"))
+    up, down = p["e_up"].astype(dt), p["e_down"].astype(dt)
+    gate = p["e_gate"].astype(dt) if "e_gate" in p else None
+    shared_in = h
+    if "latent_in" in p:  # the routed experts' input; the router has read h itself
+        h = h @ p["latent_in"].astype(dt)
+    D = h.shape[1]
 
     def experts(xs, sizes, weight=None):
-        mid = jax.nn.silu(jax.lax.ragged_dot(xs, gate, sizes)) * jax.lax.ragged_dot(xs, up, sizes)
+        if gate is None:
+            mid = act(jax.lax.ragged_dot(xs, up, sizes))
+        else:
+            mid = act(jax.lax.ragged_dot(xs, gate, sizes)) * jax.lax.ragged_dot(xs, up, sizes)
         if weight is not None:  # a pick's weight, put on its row before the down projection
             mid = (mid.astype(_F32) * weight[:, None]).astype(dt)
         return jax.lax.ragged_dot(mid, down, sizes)
@@ -326,11 +357,13 @@ def moe_ffn(h, p, cfg, valid=None):
             return y + jnp.einsum("rt,rd->td", to_token.astype(dt), ys, preferred_element_type=_F32)
 
         y = jax.lax.fori_loop(0, -(-landed // rows), one_pass, jnp.zeros((T, D), _F32))
-    shared = (jax.nn.silu(h @ p["s_gate"].astype(dt)) * (h @ p["s_up"].astype(dt))) @ (
-        p["s_down"].astype(dt)
-    )
+    y = y.astype(dt)
+    if "latent_out" in p:
+        y = y @ p["latent_out"].astype(dt)
+    mid = shared_in @ p["s_up"].astype(dt)
+    mid = act(shared_in @ p["s_gate"].astype(dt)) * mid if "s_gate" in p else act(mid)
     counts = jnp.stack([jnp.sum(here, dtype=jnp.int32), jnp.sum(sizes > 0, dtype=jnp.int32)])
-    return y.astype(dt) + shared, counts, idx
+    return y + mid @ p["s_down"].astype(dt), counts, idx
 
 
 def ffn(x, p, cfg, layer: int, valid, seen: list):
@@ -355,6 +388,48 @@ def outputs(pool, logits, seen, with_picks: bool):
 def final_logits(params, last, cfg):
     h = _rms_norm(last, params["final_norm"], cfg.rms_eps)
     return (h @ params["lm_head"].astype(cfg.dtype)).astype(_F32)
+
+
+# ---------------------------------------------------------------------------
+# The selection bias of a served checkpoint
+
+
+def balance_routers(params, key, cfg, rounds: int, tokens: int, init_pool, paged_prefill):
+    """``params`` with each expert layer's ``router_bias`` set by the published
+    rule of balancing without an auxiliary loss: round after round over
+    seeded random tokens, the bias of an expert that got less than its share
+    of the picks goes up by a step and that of one that got more goes down.
+    A trained checkpoint is served with a bias that has balanced its experts;
+    random weights with a zero bias are not balanced at all (an activation's
+    positive mean gives every hidden state a common part, so every token
+    favours the same few experts, and which chip's share they fall into changes
+    with the seed: PERF.md section 6, PR 29). The bias enters the selection
+    only. ``init_pool`` and ``paged_prefill`` are the family's own (its
+    prefill gives the picks with ``with_picks``); to be called under ``jit``."""
+    bs = 16
+    table = jnp.arange(1, tokens // bs + 1, dtype=jnp.int32)
+    pool = init_pool(cfg, tokens // bs + 1, bs, 0)
+    at = [n for n, p in enumerate(params["layers"]) if "router_bias" in p]
+    length, start = jnp.asarray(tokens, jnp.int32), jnp.asarray(0, jnp.int32)
+
+    def with_biases(biases):
+        layers = list(params["layers"])
+        for n, b in zip(at, biases):
+            layers[n] = {**layers[n], "router_bias": b}
+        return {**params, "layers": layers}
+
+    def one_round(r, biases):
+        toks = jax.random.randint(jax.random.fold_in(key, r), (1, tokens), 0, cfg.vocab_size)
+        *_, picks = paged_prefill(
+            with_biases(biases), toks, length, start, table, pool, cfg,
+            block_size=bs, with_picks=True,
+        )
+        load = jnp.mean(jax.nn.one_hot(picks, cfg.n_experts, dtype=_F32), axis=(1, 2))
+        step = 0.02 * (1.0 - r / rounds)  # of a score in (0, 1); shrinking, so it settles
+        return biases + step * jnp.sign(1.0 / cfg.n_experts - load)
+
+    biases = jnp.stack([params["layers"][n]["router_bias"] for n in at])
+    return with_biases(jax.lax.fori_loop(0, rounds, one_round, biases))
 
 
 # ---------------------------------------------------------------------------

@@ -91,6 +91,7 @@ class MlaMoeConfig:
     topk_group: int = 4
     routed_scaling: float = 2.5
     renormalize: bool = True  # norm_topk_prob
+    hidden_act: str = "silu"  # of the experts, which have a gate (SwiGLU)
     # Serving
     max_seq: int = 4096
     rms_eps: float = 1e-6

@@ -51,7 +51,14 @@ and its layer bodies itself:
   heads, no head axis, under the same block tables and the same
   ``BlockManager``), and for ``kimi_linear`` a recurrent state and a
   convolution tail *per slot* beside them (``"state": [L_kda, slots + 1, H,
-  d_k, d_v]`` float32, ``"conv"``), which no block table reaches.
+  d_k, d_v]`` float32, ``"conv"``), which no block table reaches. The third
+  shape is ``nemotron_h``'s: keys and values per head in blocks, as above
+  (``"k"``, ``"v"``, one layer of the pool an attention block), *and* a state
+  and a tail per slot (``"state": [L_mamba, slots + 1, H, P, N]`` float32,
+  ``"conv"``). Its attention blocks write, gather and attend with this
+  module's functions (``_write_read``, :func:`decode_attention`: the kernel
+  over live blocks on a TPU); for everything else it is a family with a state
+  per slot.
   Two facts about such a family, which the engine asks one by one:
   *it brings its own programs* (no ``kv_hooks``: its module supplies
   ``init_pool``, ``paged_prefill``, ``paged_decode`` and ``span_fields``),
@@ -85,6 +92,7 @@ _FAMILIES = {
     "llama": "ray_tpu.models.llama",
     "kimi_linear": "ray_tpu.models.kimi_linear",
     "mla_moe": "ray_tpu.models.mla_moe",
+    "nemotron_h": "ray_tpu.models.nemotron_h",
 }
 
 
@@ -179,12 +187,16 @@ def decode_attends_in_place(cfg, block_size: int, *, mesh=None) -> bool:
     backend, attends the live blocks in place (the kernel) or gathers each
     table whole: the kernel on a TPU, for a family of keys and values per
     head whose head and block sizes are whole TPU tiles and fit VMEM, outside a mesh
-    (the compiler cannot partition a Mosaic call). Decided by what the
-    code can see, like ``ops.attention.uses_flash_kernel``; nothing a user
-    sets reaches it."""
+    (the compiler cannot partition a Mosaic call). Keys and values per head
+    are what ``kv_hooks`` serve, and what a family that brings its own
+    programs says it keeps (``kv_per_head``: its attention layers then call
+    :func:`decode_attention`, the same choice, for their part of the pool).
+    Decided by what the code can see, like
+    ``ops.attention.uses_flash_kernel``; nothing a user sets reaches it."""
+    mod = family(cfg)
     return (
         jax.default_backend() == "tpu"
-        and hasattr(family(cfg), "kv_hooks")
+        and (hasattr(mod, "kv_hooks") or getattr(mod, "kv_per_head", False))
         and _kernel_fits(cfg, block_size, mesh)
     )
 
@@ -199,7 +211,7 @@ def _kernel_fits(cfg, block_size, mesh) -> bool:
     )
 
 
-def _decode_attention(cfg, block_size, mesh, interpret):
+def decode_attention(cfg, block_size, mesh, interpret):
     """The decode step's attention over the scattered pool: the kernel
     where the shapes fit and the program is lowered for a TPU (decided at
     lowering, so a program compiled here for a described chip holds what
@@ -390,7 +402,7 @@ def paged_decode(
 
     Each layer scatters the step's key and value, then attends positions
     [0, position] of every slot: over the live blocks in place or over the
-    gathered table (:func:`_decode_attention`)."""
+    gathered table (:func:`decode_attention`)."""
     mod = family(cfg)
     if not hasattr(mod, "kv_hooks"):
         return mod.paged_decode(
@@ -402,7 +414,7 @@ def paged_decode(
     S = W * block_size
     embed, qkv, finish, final, H, KH, Dh = mod.kv_hooks(cfg, S)
     group = H // KH
-    attend = _decode_attention(cfg, block_size, mesh, interpret)
+    attend = decode_attention(cfg, block_size, mesh, interpret)
 
     x = embed(params, last_tokens[:, None], positions[:, None])  # [B,1,D]
     rows = jnp.arange(B)

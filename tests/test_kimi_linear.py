@@ -19,7 +19,7 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmarks.reference import kimi_linear_ref as ref  # noqa: E402
-from ray_tpu.models import kimi_linear as kl, paged  # noqa: E402
+from ray_tpu.models import kimi_linear as kl, latent_moe, paged  # noqa: E402
 from ray_tpu.models.llama import LlamaConfig  # noqa: E402
 from ray_tpu.ops import delta_rule  # noqa: E402
 
@@ -181,6 +181,22 @@ def test_tokens_marked_invalid_touch_no_expert(tiny):
     y3, counts3, _ = kl.moe_ffn(h[:3], p, cfg)
     assert counts.tolist() == counts3.tolist() == [3 * cfg.experts_per_token, int(counts3[1])]
     np.testing.assert_allclose(y[:3], y3, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("rows_a_pass", [None, 24], ids=["one_pass", "in_passes"])
+def test_the_expert_layer_gives_what_it_gave_before_it_learnt_a_latent(monkeypatch, rows_a_pass):
+    """``moe_ffn`` now also serves a family without gates and with a latent
+    around the routed part; a layer of this family's holds neither, and gets
+    the numbers the function gave before (tests/moe_ffn_golden.py: stored
+    outputs, bit for bit when this was written), a share of the experts held,
+    padding rows invalid, whole or in passes."""
+    import moe_ffn_golden as golden
+
+    if rows_a_pass:
+        monkeypatch.setattr(latent_moe, "ROWS_A_PASS", rows_a_pass)
+    h, p, share, valid = golden.case(kl.KimiLinearConfig.tiny(), offset=2, held=3, bias=True)
+    golden.assert_as_before(
+        "kimi_linear_in_passes" if rows_a_pass else "kimi_linear", kl.moe_ffn(h, p, share, valid))
 
 
 @pytest.mark.parametrize("skew", [False, True])

@@ -19,11 +19,12 @@ from ray_tpu.llm import LLMConfig, LLMEngine, SamplingParams
 from ray_tpu.models import gpt2, llama
 from ray_tpu.models.kimi_linear import KimiLinearConfig
 from ray_tpu.models.mla_moe import MlaMoeConfig
+from ray_tpu.models.nemotron_h import NemotronHConfig
 from ray_tpu.util import flightrec
 
 pytestmark = pytest.mark.timeout(600)
 
-FAMILIES = ["gpt2", "llama", "kimi_linear", "mla_moe"]
+FAMILIES = ["gpt2", "llama", "kimi_linear", "mla_moe", "nemotron_h"]
 NEVER = -1  # no token stops a request: it runs its max_tokens
 
 
@@ -37,6 +38,7 @@ def llm_config(family, **kw):
             dtype=jnp.float32, attn_impl="reference"),
         "kimi_linear": lambda: KimiLinearConfig.tiny(max_seq=128),
         "mla_moe": lambda: MlaMoeConfig.tiny(max_seq=128),
+        "nemotron_h": lambda: NemotronHConfig.tiny(max_seq=128),
     }[family]()
     return LLMConfig(**{
         "model_config": model, "max_slots": 3, "max_seq": 128,

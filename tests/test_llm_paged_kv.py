@@ -298,10 +298,14 @@ def _tiny_model_of(family):
         from ray_tpu.models.mla_moe import MlaMoeConfig
 
         return MlaMoeConfig.tiny(max_seq=128)
+    if family == "nemotron_h":
+        from ray_tpu.models.nemotron_h import NemotronHConfig
+
+        return NemotronHConfig.tiny(max_seq=128)
     return _family_model(family)[0]
 
 
-@pytest.mark.parametrize("family", ["gpt2", "llama", "kimi_linear", "mla_moe"])
+@pytest.mark.parametrize("family", ["gpt2", "llama", "kimi_linear", "mla_moe", "nemotron_h"])
 def test_kv_block_size_is_a_block_size_not_a_switch(family):
     """The engine has one cache, the block pool: a ``kv_block_size`` that
     is no block size is refused by name at construction, for every family."""
@@ -320,7 +324,7 @@ def test_a_family_is_looked_up_once_and_an_unknown_one_is_refused():
     """``paged.family`` is the one lookup by name: every family's module
     brings ``init_params`` and either its hooks or its own programs, and a
     name it does not know is a ``ValueError`` that says the name."""
-    for name in ("gpt2", "llama", "kimi_linear", "mla_moe"):
+    for name in ("gpt2", "llama", "kimi_linear", "mla_moe", "nemotron_h"):
         mod = paged.family(_tiny_model_of(name))
         assert callable(mod.init_params)
         own = all(
@@ -329,7 +333,7 @@ def test_a_family_is_looked_up_once_and_an_unknown_one_is_refused():
         assert hasattr(mod, "kv_hooks") != own
         assert paged.brings_own_programs(_tiny_model_of(name)) == own
         assert paged.has_recurrent_state(_tiny_model_of(name)) == (
-            name == "kimi_linear"
+            name in ("kimi_linear", "nemotron_h")
         )
 
     @dataclasses.dataclass(frozen=True)

@@ -147,6 +147,7 @@ def test_the_decode_arm_follows_platform_and_shapes_and_nothing_a_user_sets(monk
     from ray_tpu.models import paged
     from ray_tpu.models.kimi_linear import KimiLinearConfig
     from ray_tpu.models.mla_moe import MlaMoeConfig
+    from ray_tpu.models.nemotron_h import NemotronHConfig
 
     tiling = llama.LlamaConfig.tiny(n_layer=1, d_model=256, n_head=2, n_kv_head=1)
     small_head = llama.LlamaConfig.tiny(n_layer=1, d_model=128, n_head=2, n_kv_head=1)
@@ -161,6 +162,10 @@ def test_the_decode_arm_follows_platform_and_shapes_and_nothing_a_user_sets(monk
         (tiling, 16, two_chips, False),  # a Mosaic call is not partitioned
         (KimiLinearConfig.tiny(), 16, None, False),  # programs of its own
         (MlaMoeConfig.tiny(), 16, None, False),  # likewise: it gathers its latent rows
+        # programs of its own over keys and values per head: its attention
+        # blocks make the same choice (at the published head of 128; tiny: 16)
+        (NemotronHConfig.tiny(head_dim=128), 16, None, True),
+        (NemotronHConfig.tiny(), 16, None, False),
     ]
     for backend in ("cpu", "tpu"):
         monkeypatch.setattr(jax, "default_backend", lambda: backend)
@@ -169,8 +174,8 @@ def test_the_decode_arm_follows_platform_and_shapes_and_nothing_a_user_sets(monk
             assert paged.decode_attends_in_place(cfg, block, mesh=mesh) == want
     eng = LLMEngine(llm_config(model_config=tiling, max_seq=64))
     assert eng._decode_arm == "decode_attn_kernel_steps"  # "tpu" still patched
-    assert paged._decode_attention(small_head, 16, None, False) is paged._attend_gathered
-    assert paged._decode_attention(tiling, 16, two_chips, False) is paged._attend_gathered
+    assert paged.decode_attention(small_head, 16, None, False) is paged._attend_gathered
+    assert paged.decode_attention(tiling, 16, two_chips, False) is paged._attend_gathered
     # Nothing to set: the decision's only inputs are the model's shapes, the
     # block size and the mesh, and no configuration names it.
     assert list(inspect.signature(paged.decode_attends_in_place).parameters) == [
@@ -447,6 +452,10 @@ def engine_of():
             from ray_tpu.models.mla_moe import MlaMoeConfig
 
             config = llm_config(model_config=MlaMoeConfig.tiny(max_seq=128))
+        elif family == "nemotron_h":
+            from ray_tpu.models.nemotron_h import NemotronHConfig
+
+            config = llm_config(model_config=NemotronHConfig.tiny(max_seq=128))
         else:
             config = llm_config(family)
         return built.setdefault(family, LLMEngine(config))
@@ -455,7 +464,7 @@ def engine_of():
 
 
 @pytest.mark.parametrize("program", ["paged_prefill", "paged_decode"])
-@pytest.mark.parametrize("family", ["gpt2", "llama", "kimi_linear", "mla_moe"])
+@pytest.mark.parametrize("family", ["gpt2", "llama", "kimi_linear", "mla_moe", "nemotron_h"])
 def test_a_jitted_program_carries_its_name_into_the_trace(engine_of, family, program):
     """The profiler's ``XLA Modules`` line names a run after the module, and
     the module after the jitted function: ``jit_paged_decode`` for every
@@ -467,7 +476,7 @@ def test_a_jitted_program_carries_its_name_into_the_trace(engine_of, family, pro
     slots, width = eng.block_tables.shape
     if program == "paged_decode":  # one layout for every family: prev, meta
         operands = (jnp.zeros(slots, jnp.int32), np.zeros((slots, 4 + width), np.int32))
-    elif family in ("kimi_linear", "mla_moe"):  # every small operand in one int32 array
+    elif family in ("kimi_linear", "mla_moe", "nemotron_h"):  # every small operand in one int32 array
         operands = (toks, np.zeros(3 + width, np.int32))
     else:
         n, z = jnp.asarray(4, jnp.int32), jnp.asarray(0, jnp.int32)
