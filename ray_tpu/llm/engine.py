@@ -42,7 +42,7 @@ from ray_tpu.core.config import GLOBAL_CONFIG
 from ray_tpu.llm.block_manager import BlockManager
 from ray_tpu.llm.config import LLMConfig, SamplingParams
 from ray_tpu.llm.tokenizer import ByteTokenizer
-from ray_tpu.models import paged
+from ray_tpu.models import latent_moe, paged
 from ray_tpu.util import flightrec as _flightrec
 from ray_tpu.util import metrics as _metrics
 from ray_tpu.util.prefix_digest import BYTE_BOS_SCHEME, chain_digests
@@ -410,6 +410,15 @@ class LLMEngine:
             if paged.decode_attends_in_place(cfg, bs, mesh=self.mesh)
             else "decode_attn_gather_steps"
         )
+        # Programs (prefills and decode steps alike) of a model with routed
+        # experts, by the arm their grouped products were built with
+        # (ops.moe_gmm.fits: platform and the experts' widths decide).
+        self._moe_arm = None
+        in_kernel = latent_moe.grouped_products_in_kernel(params, cfg, self.mesh)
+        if in_kernel is not None:
+            self.stats["moe_gmm_kernel_steps"] = 0  # each touched expert streamed once
+            self.stats["moe_gmm_ragged_steps"] = 0  # jax.lax.ragged_dot
+            self._moe_arm = "moe_gmm_kernel_steps" if in_kernel else "moe_gmm_ragged_steps"
         for part, arr in self.pool.items():  # bytes of each cache part
             self.stats[f"cache_bytes_{part}"] = int(arr.nbytes)
         if self._slot_state:
@@ -789,6 +798,8 @@ class LLMEngine:
                 self.params, jnp.asarray(toks), jnp.asarray(n, jnp.int32),
                 jnp.asarray(start, jnp.int32), jnp.asarray(row),
             )
+        if self._moe_arm:
+            self.stats[self._moe_arm] += 1
         self.pool, out = self._pg_prefill(*args, self.pool)
         return out
 
@@ -1297,6 +1308,8 @@ class LLMEngine:
             meta[ended, 3] = 1
         meta[slots, 1] = 1
         self.stats[self._decode_arm] += 1
+        if self._moe_arm:
+            self.stats[self._moe_arm] += 1
         self.pool, logits, small = self._pg_decode(
             self.params, self._no_prev if behind is None else behind.small,
             meta, self.pool,

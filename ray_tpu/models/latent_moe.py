@@ -24,8 +24,9 @@ implementation, which :mod:`ray_tpu.models.kimi_linear` and
   experts of the model, computing the part of the result that the experts held
   here give (``experts_held`` of them from ``expert_offset``). No capacity and
   no dropped token: the (token, pick) pairs that land here are sorted by expert
-  and run through grouped matrix products, a long prompt's in passes that
-  stop where the landed pairs end. What absent experts would add is
+  and run through grouped matrix products (:mod:`ray_tpu.ops.moe_gmm`'s
+  kernel on a TPU, ``jax.lax.ragged_dot`` elsewhere), a long prompt's in
+  passes that stop where the landed pairs end. What absent experts would add is
   left out; on one chip the layer runs without its exchange. Here too a
   family says what it has by what its layer's parameters hold: experts with a
   gate (``e_gate``, ``s_gate``: ``act(gate) * up``) or without one
@@ -57,6 +58,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.llama import _mlp_sublayer, _rms_norm
+from ray_tpu.ops import moe_gmm
 
 _F32 = jnp.float32
 
@@ -300,7 +302,13 @@ def moe_ffn(h, p, cfg, valid=None):
     What the layer's parameters hold decides its form (module docstring): with
     ``latent_in`` / ``latent_out`` the routed experts run on ``h @ latent_in``
     and their weighted sum goes back through ``latent_out``; without ``e_gate``
-    (``s_gate``) the routed (shared) experts have no gate."""
+    (``s_gate``) the routed (shared) experts have no gate.
+
+    The grouped products are :func:`ray_tpu.ops.moe_gmm.gmm`, which reads each
+    touched expert's weights once, where :func:`experts_in_kernel` says so
+    (a TPU, the experts' two widths in whole lane tiles), and
+    ``jax.lax.ragged_dot`` elsewhere: the same contract, rows behind the last
+    group never read unmasked here either way."""
     T = h.shape[0]
     E, k = cfg.experts_held, cfg.experts_per_token
     dt = cfg.dtype
@@ -323,15 +331,16 @@ def moe_ffn(h, p, cfg, valid=None):
     if "latent_in" in p:  # the routed experts' input; the router has read h itself
         h = h @ p["latent_in"].astype(dt)
     D = h.shape[1]
+    product = moe_gmm.gmm if experts_in_kernel(p, dt) else jax.lax.ragged_dot
 
     def experts(xs, sizes, weight=None):
         if gate is None:
-            mid = act(jax.lax.ragged_dot(xs, up, sizes))
+            mid = act(product(xs, up, sizes))
         else:
-            mid = act(jax.lax.ragged_dot(xs, gate, sizes)) * jax.lax.ragged_dot(xs, up, sizes)
+            mid = act(product(xs, gate, sizes)) * product(xs, up, sizes)
         if weight is not None:  # a pick's weight, put on its row before the down projection
             mid = (mid.astype(_F32) * weight[:, None]).astype(dt)
-        return jax.lax.ragged_dot(mid, down, sizes)
+        return product(mid, down, sizes)
 
     if T * k <= ROWS_A_PASS:
         ys = experts(h[order // k], sizes)  # [T k, D], grouped by expert
@@ -364,6 +373,24 @@ def moe_ffn(h, p, cfg, valid=None):
     mid = act(shared_in @ p["s_gate"].astype(dt)) * mid if "s_gate" in p else act(mid)
     counts = jnp.stack([jnp.sum(here, dtype=jnp.int32), jnp.sum(sizes > 0, dtype=jnp.int32)])
     return y + mid @ p["s_down"].astype(dt), counts, idx
+
+
+def experts_in_kernel(p, dtype, mesh=None) -> bool:
+    """Whether :func:`moe_ffn` runs the grouped products of the expert layer
+    ``p`` (its ``e_up`` [E, K, N] and ``e_down`` [E, N, K]) in ``dtype``
+    through the kernel: :func:`ray_tpu.ops.moe_gmm.fits` of both directions."""
+    _, K, N = p["e_up"].shape
+    return moe_gmm.fits(K, N, dtype, mesh) and moe_gmm.fits(N, K, dtype, mesh)
+
+
+def grouped_products_in_kernel(params, cfg, mesh=None):
+    """:func:`experts_in_kernel` of a model with these parameters (every
+    expert layer of a model has one shape): the arm its programs are built
+    with. None for a model without an expert layer."""
+    for p in params.get("layers", ()):
+        if "e_up" in p:
+            return experts_in_kernel(p, cfg.dtype, mesh)
+    return None
 
 
 def ffn(x, p, cfg, layer: int, valid, seen: list):

@@ -9,13 +9,26 @@ tolerance is what a float32 sum in another order keeps, so a kernel in the
 grouped products' place can stay inside it."""
 
 import dataclasses
+import functools
 import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu.ops import moe_gmm
+
 ROWS, REAL = 40, 37  # rows of input, of which the first REAL are tokens
+
+
+def take_arm(monkeypatch, arm: str):
+    """Send ``moe_ffn``'s grouped products through ``arm``: ``ragged_dot`` is
+    what a CPU takes by itself; ``kernel`` patches the predicate and runs
+    ``ops.moe_gmm.gmm`` in the Pallas interpreter, at whatever widths the
+    layer has (no whole lane tiles: one column tile)."""
+    if arm == "kernel":
+        monkeypatch.setattr(moe_gmm, "fits", lambda *a, **kw: True)
+        monkeypatch.setattr(moe_gmm, "gmm", functools.partial(moe_gmm.gmm, interpret=True))
 
 
 def case(cfg, offset: int, held: int, bias: bool):

@@ -183,15 +183,18 @@ def test_tokens_marked_invalid_touch_no_expert(tiny):
     np.testing.assert_allclose(y[:3], y3, rtol=1e-5, atol=1e-6)
 
 
+@pytest.mark.parametrize("arm", ["ragged_dot", "kernel"])
 @pytest.mark.parametrize("rows_a_pass", [None, 24], ids=["one_pass", "in_passes"])
-def test_the_expert_layer_gives_what_it_gave_before_it_learnt_a_latent(monkeypatch, rows_a_pass):
+def test_the_expert_layer_gives_what_it_gave_before_it_learnt_a_latent(monkeypatch, rows_a_pass, arm):
     """``moe_ffn`` now also serves a family without gates and with a latent
     around the routed part; a layer of this family's holds neither, and gets
     the numbers the function gave before (tests/moe_ffn_golden.py: stored
     outputs, bit for bit when this was written), a share of the experts held,
-    padding rows invalid, whole or in passes."""
+    padding rows invalid, whole or in passes, and whichever arm computes the
+    grouped products."""
     import moe_ffn_golden as golden
 
+    golden.take_arm(monkeypatch, arm)
     if rows_a_pass:
         monkeypatch.setattr(latent_moe, "ROWS_A_PASS", rows_a_pass)
     h, p, share, valid = golden.case(kl.KimiLinearConfig.tiny(), offset=2, held=3, bias=True)

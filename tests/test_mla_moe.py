@@ -251,12 +251,15 @@ def test_one_group_and_a_bias_is_exactly_kimi_linears_router():
         assert np.array_equal(a[0], b[0]) and np.array_equal(a[1], b[1])
 
 
-def test_the_expert_layer_gives_what_it_gave_before_it_learnt_a_latent():
+@pytest.mark.parametrize("arm", ["ragged_dot", "kernel"])
+def test_the_expert_layer_gives_what_it_gave_before_it_learnt_a_latent(monkeypatch, arm):
     """As in test_kimi_linear.py, for this family's grouped selection without
     a bias: a layer with gates and no latent pair gets from ``moe_ffn`` the
-    numbers it gave before (tests/moe_ffn_golden.py)."""
+    numbers it gave before (tests/moe_ffn_golden.py), whichever arm computes
+    the grouped products."""
     import moe_ffn_golden as golden
 
+    golden.take_arm(monkeypatch, arm)
     h, p, share, valid = golden.case(mm.MlaMoeConfig.tiny(), offset=2, held=4, bias=False)
     golden.assert_as_before("mla_moe", latent_moe.moe_ffn(h, p, share, valid))
 

@@ -156,12 +156,21 @@ def test_attention_prefill_and_decode_are_the_reference(tiny):
     )
 
 
-def test_the_expert_layer_is_the_reference_in_its_latent(tiny):
+@pytest.mark.parametrize("arm", ["ragged_dot", "kernel"])
+def test_the_expert_layer_is_the_reference_in_its_latent(tiny, monkeypatch, arm):
+    """Ungated and in its latent, whichever arm computes the grouped products
+    (the kernel in the interpreter: tests/moe_ffn_golden.py:take_arm)."""
+    import moe_ffn_golden as golden
+
     cfg, params = tiny
     p = _block(params, cfg, "E")
     assert "e_gate" not in p and "s_gate" not in p and p["latent_in"].shape == (cfg.d_model, cfg.moe_latent)
     u = jax.random.normal(jax.random.key(9), (40, cfg.d_model))
+    plain = latent_moe.moe_ffn(u, p, cfg)
+    golden.take_arm(monkeypatch, arm)
     y, counts, picks = latent_moe.moe_ffn(u, p, cfg)
+    assert np.array_equal(picks, plain[2]) and np.array_equal(counts, plain[1])
+    np.testing.assert_allclose(y, plain[0], rtol=1e-4, atol=1e-7)  # the stored cases' tolerance
     want, idx = ref.experts(u, p, ref_config(cfg), MM)
     np.testing.assert_allclose(y, want, rtol=2e-4, atol=2e-6)
     assert (np.sort(picks, -1) == np.sort(idx, -1)).all()
@@ -180,11 +189,15 @@ def test_tokens_marked_invalid_touch_no_expert(tiny):
     assert none.tolist() == [0, 0]
 
 
+@pytest.mark.parametrize("arm", ["ragged_dot", "kernel"])
 @pytest.mark.parametrize("rows", [8, 24, 64])
-def test_the_grouped_products_in_passes_give_what_one_pass_gives(tiny, monkeypatch, rows):
+def test_the_grouped_products_in_passes_give_what_one_pass_gives(tiny, monkeypatch, rows, arm):
     """40 tokens x 3 picks = 120 sorted rows in the latent, a chip holding
-    experts 2-4: in passes of 8, 24 or 64 rows against all in one pass and
-    against the reference's masked loop."""
+    experts 2-4: in passes of 8, 24 or 64 rows, through either arm of the
+    grouped products, against all in one pass of ``ragged_dot`` and against
+    the reference's masked loop."""
+    import moe_ffn_golden as golden
+
     cfg, params = tiny
     p = _block(params, cfg, "E")
     share = dataclasses.replace(cfg, experts_held=3, expert_offset=2)
@@ -192,6 +205,7 @@ def test_the_grouped_products_in_passes_give_what_one_pass_gives(tiny, monkeypat
     u = jax.random.normal(jax.random.key(11), (40, cfg.d_model))
     valid = jnp.arange(40) < 37
     whole, counts, picks = latent_moe.moe_ffn(u, held, share, valid)
+    golden.take_arm(monkeypatch, arm)
     monkeypatch.setattr(latent_moe, "ROWS_A_PASS", rows)
     y, c, i = jax.jit(lambda u, held, valid: latent_moe.moe_ffn(u, held, share, valid))(u, held, valid)
     assert 0 < int(counts[0]) < 111 and np.array_equal(c, counts) and np.array_equal(i, picks)

@@ -9,6 +9,7 @@ partition a Mosaic call.
 """
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +31,16 @@ from ray_tpu.train.spmd import default_optimizer, make_train_step
 pytestmark = pytest.mark.timeout(600)
 
 MOSAIC = "tpu_custom_call"
+
+
+def mosaic_calls(text: str) -> list:
+    """The names of a compiled program's Mosaic calls, as a device trace
+    lists them, without their numbers."""
+    calls = re.findall(
+        r"^\s*(?:ROOT )?%?([\w.\-]+) = [^\n]*custom_call_target=\"" + MOSAIC + '"',
+        text, re.M,
+    )
+    return [re.sub(r"\.\d+$", "", c) for c in calls]
 
 
 @pytest.fixture(scope="module")
@@ -66,8 +77,6 @@ def test_flash_kernels_keep_their_names_in_the_compiled_program(v5e_2x2, meshed)
     """A device trace names an operation after its HLO instruction. The
     Mosaic calls are ``flash_fwd.<n>`` and ``flash_bwd.<n>`` there, under a
     mesh too, where they used to take the ``shard_map``'s name and number."""
-    import re
-
     devices = v5e_2x2 if meshed else v5e_2x2[:1]
     mesh = make_mesh(MeshSpec(fsdp=len(devices)), devices)
     sharding = NamedSharding(mesh, P(("dp", "fsdp")))
@@ -80,11 +89,7 @@ def test_flash_kernels_keep_their_names_in_the_compiled_program(v5e_2x2, meshed)
         ).astype(jnp.float32).sum()
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q).compile().as_text()
-    calls = re.findall(
-        r"^\s*(?:ROOT )?%?([\w.\-]+) = [^\n]*custom_call_target=\"" + MOSAIC + '"',
-        text, re.M,
-    )
-    assert sorted(re.sub(r"\.\d+$", "", c) for c in calls) == ["flash_bwd", "flash_fwd"]
+    assert sorted(mosaic_calls(text)) == ["flash_bwd", "flash_fwd"]
 
 
 @pytest.mark.parametrize(
@@ -172,6 +177,32 @@ def test_the_decode_attention_kernel_compiles_at_served_widths(
     ).compile()
     assert compiled.as_text().count(MOSAIC) == 1
     # Nothing pool-sized beside the pool: the operands stay where they are.
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+@pytest.mark.parametrize(
+    "m,experts,K,N",
+    [
+        (64 * 22, 128, 1024, 2688), (64 * 22, 128, 2688, 1024),
+        (16 * 8, 64, 2304, 1024), (2048, 64, 1024, 2304),
+        (32 * 8, 12, 7168, 2048), (2048, 12, 2048, 7168),
+    ],
+    ids=["nemotron-up", "nemotron-down", "kimi-up", "kimi-down-pass", "axk1-up", "axk1-down-pass"],
+)
+def test_the_grouped_product_kernel_compiles_at_served_widths(v5e_2x2, m, experts, K, N):
+    """``ops.moe_gmm.gmm`` at the three expert cells' widths, both
+    directions, at a decode step's rows or a prefill pass's: its weight
+    tiles, two of them in flight, fit the VMEM it asks for, the call keeps
+    its name (a device trace lists it as ``moe_gmm.<n>``), and nothing of
+    the weights' size is made beside them."""
+    from ray_tpu.ops import moe_gmm
+
+    one = jax.sharding.SingleDeviceSharding(v5e_2x2[0])
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)  # noqa: E731
+    compiled = jax.jit(moe_gmm.gmm).lower(
+        sds((m, K), jnp.bfloat16), sds((experts, K, N), jnp.bfloat16), sds((experts,), jnp.int32)
+    ).compile()
+    assert mosaic_calls(compiled.as_text()) == ["moe_gmm"]
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
 
 
