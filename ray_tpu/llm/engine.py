@@ -22,6 +22,16 @@ Reference parity: the role vLLM's engine plays under ray.llm
   temperature, a speculative decoder, a prefill in chunks or a replaced
   ``_sample`` make a turn synchronous (logits to the host, nothing in
   flight when ``step()`` returns): read off the input, set by nobody.
+- **Every launch numbered.** The prefill and decode programs take the pool
+  from the launch before them, so the device runs them in the order the
+  host launched them: ``stats["programs_launched"]`` counts them, and the
+  flight recorder's ``llm.prefill`` / ``llm.prefill_chunk`` (``seq``) and
+  ``llm.decode_step`` (``seq``: the step read, ``next_seq``: the step
+  launched ahead, 0 where none) carry their program's number, so that a
+  span finds its run in a device trace by counting, whatever the clocks
+  say. A step launched and dropped unread keeps its number and has no
+  span. The speculative decoder's programs (``llm/spec_decode.py``) are
+  not numbered: no traced deployment runs it.
 - **Tensor parallelism** = the standard rule table over a ``tp`` mesh axis;
   XLA shards the einsums and inserts ICI collectives — no per-layer manual
   split.
@@ -216,6 +226,7 @@ class _DecodeStep:
     logits: jax.Array  # [B, V]: copied to the host by a synchronous turn only
     small: jax.Array  # int32 [B (+ counters)]: argmax tokens, the programs' counters
     t_launch: float  # monotonic, before its operand was built
+    seq: int  # its launch's number: stats["programs_launched"] then
 
 
 class LLMEngine:
@@ -368,6 +379,9 @@ class LLMEngine:
         # and what a step with none before it is handed as ``prev``.
         self._inflight: Optional[_DecodeStep] = None
         self._no_prev = None
+        # The counts of the admitting turn under way (_in_wave), None in
+        # a turn that has launched no prefill and given no slot.
+        self._wave: Optional[dict] = None
         # Prefix pool: key (chunk-aligned token tuple hash) ->
         # {"blocks": the prefix's block ids, "tokens", "len", "used"}.
         # LRU within max_prefix_cache_tokens.
@@ -382,6 +396,10 @@ class LLMEngine:
         self._digest_version = 0
         self.stats = {
             "prefill_tokens": 0,  # tokens that PAID prefill compute
+            # The rows those programs computed (the sum of their buckets),
+            # and the turns that launched one or gave a request a slot.
+            "prefill_tokens_padded": 0,
+            "admit_waves": 0,
             "prefill_chunks": 0,  # chunked-prefill pieces executed
             "prefix_hits": 0,
             "prefix_lookups": 0,
@@ -404,6 +422,9 @@ class LLMEngine:
             # on a stop token by then (never appended, streamed or counted).
             "decode_steps_ahead": 0,
             "decode_rows_discarded": 0,
+            # Prefill and decode programs launched: the device runs them in
+            # this order, so a launch's count is its run's place in a trace.
+            "programs_launched": 0,
         }
         self._decode_arm = (
             "decode_attn_kernel_steps"
@@ -639,7 +660,7 @@ class LLMEngine:
             try:
                 slot = self.slot_free.index(True)
             except ValueError:
-                return admit_finished
+                break
             if fr:  # where this attempt starts, and the counters then
                 mark = (
                     _time.monotonic(), self.stats["prefill_tokens"],
@@ -648,7 +669,7 @@ class LLMEngine:
             if req.handoff is not None:
                 verdict = self._admit_handoff(req, slot)
                 if verdict == "wait":
-                    return admit_finished
+                    break
                 if verdict == "done":
                     if fr and req.error is None:
                         self._rec_admitted(req, *mark)
@@ -672,16 +693,14 @@ class LLMEngine:
                     self._rec_admitted(req, *mark)
                 continue
             if logits is None:
-                return admit_finished
+                break
             T = len(req.prompt)
             logits_np = self._take_counters(np.asarray(logits), req)  # raylint: disable=RL101 -- admission sampling: first token sampled host-side from the last-logits readback
             self._close_prefill_span(req)
             tok = self._sample(logits_np, req)
             if fr:
                 self._rec_admitted(req, *mark)
-            req.slot = slot
-            self.slot_free[slot] = False
-            self._slot_req[slot] = req
+            self._take_slot(req, slot)
             if req.prefill_only:
                 # Disaggregated prefill leg: export the prompt KV and
                 # finish here — the decode tier takes it from the handoff.
@@ -704,7 +723,37 @@ class LLMEngine:
             self._maybe_finish(req)
             if req.finished:
                 admit_finished.append(req)
+        if self._wave is not None:
+            self._wave["waiting"] = len(waiting)
+            self._wave["left"] = sum(
+                r.slot < 0 and not r.finished for r in waiting
+            )
         return admit_finished
+
+    def _in_wave(self) -> dict:
+        """The counts of the admitting turn under way, which ``step()``
+        records as ``llm.admit_wave``; begun by the turn's first prefill
+        launch or slot taken. Until then no row has joined or left the
+        decoding ones, so ``rows_stalled`` is counted here as it stood at
+        the turn's entry."""
+        if self._wave is None:
+            self.stats["admit_waves"] += 1
+            self._wave = {
+                "wave": self.stats["admit_waves"], "waiting": 0, "left": 0,
+                "admitted": 0, "prefills": 0, "tokens": 0, "padded": 0,
+                "reused": 0,
+                "rows_stalled": sum(
+                    r is not None and not r.prefilling for r in self._slot_req
+                ),
+            }
+        return self._wave
+
+    def _take_slot(self, req: _Request, slot: int) -> None:
+        """``req`` holds ``slot`` from here on: one admission of the wave."""
+        self._in_wave()["admitted"] += 1
+        req.slot = slot
+        self.slot_free[slot] = False
+        self._slot_req[slot] = req
 
     @staticmethod
     def _rec_first_token(req: _Request) -> None:
@@ -731,7 +780,9 @@ class LLMEngine:
         lookup, prefill, read-back, the first sample), or, for a chunked
         prefill or a handoff, its slot taken. ``tokens`` and ``reused``
         are what this admission added to the engine's counters of prompt
-        tokens prefilled and taken from the prefix pool."""
+        tokens prefilled and taken from the prefix pool, ``wave`` the
+        ``llm.admit_wave`` it belongs to (0: a handoff that had ended at
+        its prefill takes no slot and makes no wave)."""
         _flightrec.record(
             "llm", "llm.queue", t=req.t_queued,
             dur_s=t_adm - req.t_queued, rid=req.request_id,
@@ -741,13 +792,16 @@ class LLMEngine:
             dur_s=_time.monotonic() - t_adm, rid=req.request_id,
             tokens=self.stats["prefill_tokens"] - paid,
             reused=self.stats["prefix_tokens_reused"] - reused,
+            wave=self._wave["wave"] if self._wave is not None else 0,
         )
 
-    @staticmethod
-    def _open_prefill_span(req: _Request, phase: str, t_pf: float, **extra):
-        """Note on ``req`` the prefill that was just dispatched; its span
-        is recorded by ``_close_prefill_span``."""
+    def _open_prefill_span(self, req: _Request, phase: str, t_pf: float, **extra):
+        """Note on ``req`` the prefill that ``_run_prefill`` has just
+        dispatched, with its launch's number and its wave's; its span is
+        recorded by ``_close_prefill_span``."""
         if _flightrec.on():
+            extra["wave"] = self._wave["wave"]
+            extra["seq"] = self.stats["programs_launched"]
             req.pf_open = (phase, t_pf, extra)
 
     @staticmethod
@@ -786,7 +840,10 @@ class LLMEngine:
     def _run_prefill(self, toks, n: int, start: int, row, slot: int):
         """Dispatch one paged prefill of ``n`` tokens from position
         ``start`` into ``slot``; rebinds the donated pool and returns the
-        program's second output, still on the device."""
+        program's second output, still on the device. Every prefill
+        program goes through here, so here they are counted: the tokens
+        given and the rows computed (``toks``' width, the bucket), for
+        the engine and for the turn's wave, and the launch's number."""
         if self._own_programs:
             if self._slot_state and start == 0:
                 # begins from zero state, whatever the slot held
@@ -800,6 +857,20 @@ class LLMEngine:
             )
         if self._moe_arm:
             self.stats[self._moe_arm] += 1
+        bucket = toks.shape[1]
+        wave = self._in_wave()
+        if not wave["prefills"] and self._inflight is not None:
+            # The wave's first launch queues behind what is left of the
+            # decode step in flight.
+            wave["inflight_age_ms"] = (
+                _time.monotonic() - self._inflight.t_launch
+            ) * 1e3
+        wave["prefills"] += 1
+        wave["tokens"] += n
+        wave["padded"] += bucket
+        self.stats["prefill_tokens"] += n
+        self.stats["prefill_tokens_padded"] += bucket
+        self.stats["programs_launched"] += 1
         self.pool, out = self._pg_prefill(*args, self.pool)
         return out
 
@@ -871,9 +942,7 @@ class LLMEngine:
         row = np.zeros(self._table_width, np.int32)
         row[: len(table)] = table
         self.block_tables[slot] = row
-        req.slot = slot
-        self.slot_free[slot] = False
-        self._slot_req[slot] = req
+        self._take_slot(req, slot)
         tok = int(h["first_token"])
         req.handoff = None
         req.generated.append(tok)
@@ -988,6 +1057,7 @@ class LLMEngine:
         if entry is not None:
             self.stats["prefix_hits"] += 1
             self.stats["prefix_tokens_reused"] += P
+            self._in_wave()["reused"] += P  # a slot or a launch follows
         if self._slot_state and self.config.enable_prefix_caching:
             self.stats["prefix_cache_bypassed"] += 1  # once an admission
         if self._chunks_feasible(P, T):
@@ -997,7 +1067,6 @@ class LLMEngine:
         toks[0, :rem] = req.prompt[P:]
         t_pf = _time.monotonic()
         logits = self._run_prefill(toks, rem, P, row, slot)
-        self.stats["prefill_tokens"] += rem
         self._open_prefill_span(
             req, "llm.prefill", t_pf, tokens=rem, reused=P, bucket=bucket
         )
@@ -1080,11 +1149,9 @@ class LLMEngine:
         happens in _advance_prefills under its per-step budget — an
         admission wave of long prompts must not burst N first-chunks
         into one step."""
-        req.slot = slot
+        self._take_slot(req, slot)
         req.prefilling = True
         req.pf_next = start
-        self.slot_free[slot] = False
-        self._slot_req[slot] = req
         self.positions[slot] = start
         self.last_tokens[slot] = 0
 
@@ -1101,7 +1168,6 @@ class LLMEngine:
         logits = self._run_prefill(
             toks, clen, start, self.block_tables[req.slot], req.slot
         )
-        self.stats["prefill_tokens"] += clen
         self.stats["prefill_chunks"] += 1
         if _metrics.metrics_enabled():
             _PREFILL_CHUNKS.inc(1.0)
@@ -1225,11 +1291,23 @@ class LLMEngine:
         ``_sample``, a temperature, a speculative decoder, a prefill in
         chunks): there nothing is in flight between steps."""
         instrument = _metrics.metrics_enabled()
+        fr = _flightrec.on()
+        t_wave = _time.monotonic() if fr else 0.0
         # Prefill chunks of already-admitted long prompts advance BEFORE
         # this step's admissions, so a request admitted this step runs
         # exactly its first chunk — one chunk per request per step.
         finished = self._advance_prefills()
         finished += self._admit_waiting()
+        if self._wave is not None:
+            # An admitting turn: this stretch launched a prefill program
+            # or gave a request a slot, and no decode ran meanwhile. One
+            # span a wave, with what _in_wave's callers counted.
+            wave, self._wave = self._wave, None
+            if fr:
+                _flightrec.record(
+                    "llm", "llm.admit_wave", t=t_wave,
+                    dur_s=_time.monotonic() - t_wave, **wave,
+                )
         active = [
             r for r in self._slot_req if r is not None and not r.prefilling
         ]
@@ -1310,11 +1388,15 @@ class LLMEngine:
         self.stats[self._decode_arm] += 1
         if self._moe_arm:
             self.stats[self._moe_arm] += 1
+        self.stats["programs_launched"] += 1
         self.pool, logits, small = self._pg_decode(
             self.params, self._no_prev if behind is None else behind.small,
             meta, self.pool,
         )
-        return _DecodeStep(rows, meta[slots, 0], logits, small, t_launch)
+        return _DecodeStep(
+            rows, meta[slots, 0], logits, small, t_launch,
+            self.stats["programs_launched"],
+        )
 
     def _decode_turn(self, active: list, instrument: bool) -> list:
         """One turn's decode step over ``active``; returns the requests
@@ -1399,7 +1481,9 @@ class LLMEngine:
             # row (sampling too on the synchronous arm). ``batch`` is the
             # rows of the step that was read, ``discarded`` those of them
             # computed for nothing, ``ahead`` whether this turn launched
-            # the next step before it read this one.
+            # the next step before it read this one, ``seq`` and
+            # ``next_seq`` the launch numbers of the step read and of the
+            # step launched ahead (0: none).
             t_end = _time.monotonic()
             batch = len(cur.rows)
             moe = {}
@@ -1430,6 +1514,7 @@ class LLMEngine:
                 kv_blocks_live=int(((cur.at + bs) // bs).sum()),
                 kv_blocks_table=self.block_tables.size,
                 ahead=int(nxt is not None), discarded=batch - len(rows),
+                seq=cur.seq, next_seq=nxt.seq if nxt is not None else 0,
                 **moe,
             )
         return finished

@@ -130,7 +130,13 @@ def capture_jax_trace(trace_dir: str, duration_s: float = 3.0) -> dict:
     difference at that event), and closes by saving
     ``flightrec.snapshot()`` as ``FLIGHTREC_SNAPSHOT`` in ``trace_dir``:
     every ring of this process, with the wall anchor its monotonic times
-    are read against."""
+    are read against. The anchor is only the first guess: the profiler's
+    laying of the device's plane against the host's errs by up to 2 ms.
+    The snapshot's ``llm.prefill`` / ``llm.decode_step`` events carry
+    ``seq`` (and ``next_seq``), the number of the launch, and the engine's
+    programs run in launch order, so the trace's ``jit_paged_prefill`` /
+    ``jit_paged_decode`` runs in start order are consecutive numbers: an
+    event finds its run by counting, whatever the clocks say."""
     import json
     import os
 

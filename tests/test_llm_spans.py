@@ -6,6 +6,7 @@ jitted programs carry into a device trace. Toy engine, on the CPU.
 
 import asyncio
 import dataclasses
+import functools
 import http.client
 import json
 import time
@@ -258,11 +259,192 @@ def test_queue_and_admit_lie_inside_the_first_token_interval(family):
         assert end(admit) <= end(first) + MS
         assert admit["t"] <= prefill["t"] and end(prefill) <= end(admit)
         assert admit["extra"] == {
-            "tokens": prefill["extra"]["tokens"], "reused": 0}
+            "tokens": prefill["extra"]["tokens"], "reused": 0,
+            "wave": prefill["extra"]["wave"]}
     # "b" waited for "a"'s prefill: its queue span covers a's admission
     (qb,) = [e for e in of("llm.queue") if e["rid"] == "b"]
     (aa,) = [e for e in of("llm.admit") if e["rid"] == "a"]
     assert qb["t"] <= aa["t"] and end(qb) >= end(aa)
+
+
+# -- the admitting turn, and every launch numbered ------------------------------
+
+# Llama's arm of the engine, and a family that brings its own programs.
+ARMS = pytest.mark.parametrize("arm", ["ahead", "synchronous"])
+WAVE_FAMILIES = pytest.mark.parametrize("family", ["llama", "kimi_linear"])
+
+
+def wave_config(family, **kw):
+    if family == "kimi_linear":
+        from ray_tpu.models.kimi_linear import KimiLinearConfig
+
+        kw["model_config"] = KimiLinearConfig.tiny(max_seq=128)
+    return llm_config(family if family == "llama" else "gpt2", **kw)
+
+
+def admitted_ids(eng):
+    """Requests that have taken a slot, whether or not they still hold it."""
+    return {
+        r.request_id for r in eng.requests.values()
+        if r.generated or r.prefilling or r.slot >= 0
+    }
+
+
+@functools.lru_cache(maxsize=None)
+def drive(family, arm, recorder=True, **kw):
+    """Five requests through two slots, turn by turn: four handed over at
+    once and a fifth while both slots are held. Of every turn: what stood
+    at its entry (counted here, from the engine's books), the waves it
+    recorded and the requests it admitted. One run a case, kept."""
+    GLOBAL_CONFIG.flightrec = recorder
+    eng = LLMEngine(wave_config(family, **kw))
+    if arm == "synchronous":
+        own = eng._sample
+        eng._sample = lambda logits, req: own(logits, req)
+    work = [("a", 12, 3), ("b", 20, 6), ("c", 5, 4), ("d", 30, 2), ("e", 9, 3)]
+    for rid, n, out in work[:4]:
+        eng.add_request(rid, list(range(3, 3 + n)), SamplingParams(max_tokens=out))
+    turns = []
+    while eng.has_unfinished():
+        if len(turns) == 2:
+            rid, n, out = work[4]
+            eng.add_request(rid, list(range(3, 3 + n)), SamplingParams(max_tokens=out))
+        turn = {
+            "stalled": sum(r is not None and not r.prefilling for r in eng._slot_req),
+            "waiting": sum(r.slot < 0 and not r.finished for r in eng.requests.values()),
+            "before": dict(eng.stats), "admitted": admitted_ids(eng),
+            "waves": len(of("llm.admit_wave")),
+        }
+        eng.step()
+        turn["waves"] = of("llm.admit_wave")[turn["waves"]:]
+        turn["admitted"] = admitted_ids(eng) - turn["admitted"]
+        turn["after"] = dict(eng.stats)
+        turns.append(turn)
+    tokens = {r.request_id: list(r.generated) for r in eng.requests.values()}
+    return dict(eng.stats), turns, events(), tokens
+
+
+@ARMS
+@WAVE_FAMILIES
+def test_one_admit_wave_a_turn_that_admits_and_none_otherwise(family, arm):
+    """``llm.admit_wave`` is recorded by the turns that launched a prefill
+    or gave a request a slot, once, and by no other; its fields are what
+    stood at the turn's entry and what the turn did; the spans of the
+    admissions inside it carry its ordinal."""
+    stats, turns, evs, _ = drive(family, arm)
+    admitting = [t for t in turns if t["waves"]]
+    assert [len(t["waves"]) for t in turns] == [
+        int(bool(t["admitted"]) or t["after"]["prefill_tokens"] > t["before"]["prefill_tokens"])
+        for t in turns
+    ]
+    assert 3 <= len(admitting) < len(turns) and stats["admit_waves"] == len(admitting)
+    for k, turn in enumerate(admitting):
+        (wave,) = turn["waves"]
+        x = wave["extra"]
+        assert x["wave"] == k + 1
+        assert x["rows_stalled"] == turn["stalled"]
+        assert x["waiting"] == turn["waiting"]
+        assert x["admitted"] == len(turn["admitted"]) == x["prefills"]
+        assert x["left"] == x["waiting"] - x["admitted"]
+        assert x["reused"] == 0
+        inside = [e for e in evs if e["phase"] in ("llm.prefill", "llm.admit")
+                  and e["extra"]["wave"] == x["wave"]]
+        assert sorted(e["rid"] for e in inside) == sorted(2 * list(turn["admitted"]))
+        for e in inside:
+            assert wave["t"] <= e["t"] and end(e) <= end(wave)
+        # the prefill queued behind a decode step in flight, or behind none
+        running_ahead = arm == "ahead" and turn["stalled"] > 0
+        assert ("inflight_age_ms" in x) == running_ahead
+        if running_ahead:
+            assert 0.0 < x["inflight_age_ms"] < 60e3
+    first = admitting[0]["waves"][0]["extra"]
+    assert (first["waiting"], first["left"], first["admitted"], first["rows_stalled"]) == (4, 2, 2, 0)
+    # the fifth request waited a turn or more without a slot: no wave for that
+    assert any(t["waiting"] and not t["waves"] for t in turns)
+
+
+@ARMS
+@WAVE_FAMILIES
+def test_the_waves_add_up_to_the_engines_counters(family, arm):
+    stats, turns, evs, _ = drive(family, arm)
+    waves = [w["extra"] for t in turns for w in t["waves"]]
+    assert sum(w["admitted"] for w in waves) == 5
+    assert sum(w["prefills"] for w in waves) == 5
+    assert sum(w["tokens"] for w in waves) == stats["prefill_tokens"] == 12 + 20 + 5 + 30 + 9
+    assert sum(w["padded"] for w in waves) == stats["prefill_tokens_padded"] == 16 + 32 + 16 + 32 + 16
+    assert sum(w["reused"] for w in waves) == stats["prefix_tokens_reused"] == 0
+    fills = [e["extra"] for e in evs if e["phase"] == "llm.prefill"]
+    assert sum(f["bucket"] for f in fills) == stats["prefill_tokens_padded"]
+
+
+@ARMS
+@WAVE_FAMILIES
+def test_seq_rises_by_one_a_launch_and_ends_at_programs_launched(family, arm):
+    """Prefills and decode steps share one count, in the order they were
+    launched: a prefill span carries its program's number, a decode span
+    that of the step it read and of the step it launched ahead."""
+    stats, _turns, evs, _ = drive(family, arm)
+    launches, seen = [], set()
+    for e in evs:  # in the order recorded: a turn's prefills, then its step
+        x = e["extra"] if "extra" in e else {}
+        if e["phase"] == "llm.prefill":
+            launches.append((e["t"], x["seq"]))
+        elif e["phase"] == "llm.decode_step":
+            for n in (x["seq"], x["next_seq"]):
+                if n and n not in seen:  # launched in this turn, read in this or the next
+                    launches.append((e["t"], n))
+                    seen.add(n)
+            assert x["ahead"] == int(x["next_seq"] > 0)
+            assert x["next_seq"] in (0, x["seq"] + 1) or arm == "ahead"
+    assert [n for _t, n in launches] == list(range(1, stats["programs_launched"] + 1))
+    assert [t for t, _n in launches] == sorted(t for t, _n in launches)
+    steps = [e["extra"] for e in evs if e["phase"] == "llm.decode_step"]
+    if arm == "ahead":  # the step launched ahead is the step the next turn reads
+        for this, after in zip(steps, steps[1:]):
+            assert not this["next_seq"] or after["seq"] == this["next_seq"]
+    assert stats["programs_launched"] == 5 + stats["decode_attn_gather_steps"]
+
+
+@WAVE_FAMILIES
+def test_a_wave_holds_the_chunk_it_advanced(family):
+    """With ``prefill_chunk_tokens`` set a prompt's slot is taken in one
+    wave and each of its chunks launched in a later one, under the numbers
+    of that wave and of its launch."""
+    stats, turns, evs, _ = drive(family, "ahead", prefill_chunk_tokens=16)
+    waves = [w for t in turns for w in t["waves"]]
+    chunks = [e for e in evs if e["phase"] == "llm.prefill_chunk"]
+    assert len(chunks) == stats["prefill_chunks"] == 4  # "b" and "d": 16 + 4, 16 + 14
+    for chunk in chunks:
+        (wave,) = [w for w in waves if w["extra"]["wave"] == chunk["extra"]["wave"]]
+        assert wave["t"] <= chunk["t"] and end(chunk) <= end(wave)
+        # one chunk a turn, and the chunk's slot was taken in an earlier wave
+        assert wave["extra"]["prefills"] - wave["extra"]["admitted"] in (0, 1)
+        (slot_taken,) = [e for e in evs if e["phase"] == "llm.admit" and e["rid"] == chunk["rid"]]
+        assert slot_taken["extra"]["wave"] < chunk["extra"]["wave"]
+    by_wave = {w["extra"]["wave"]: w["extra"] for w in waves}
+    fills = [e for e in evs if e["phase"] in ("llm.prefill", "llm.prefill_chunk")]
+    for n, x in by_wave.items():
+        mine = [f["extra"] for f in fills if f["extra"]["wave"] == n]
+        assert x["prefills"] == len(mine)
+        assert x["tokens"] == sum(f["tokens"] for f in mine)
+        assert x["padded"] == sum(f["bucket"] for f in mine)
+    assert sum(x["tokens"] for x in by_wave.values()) == stats["prefill_tokens"] == 76
+    assert sum(x["admitted"] for x in by_wave.values()) == 5
+    seqs = sorted(f["extra"]["seq"] for f in fills)
+    assert len(set(seqs)) == len(seqs) == 3 + 4 and seqs[-1] <= stats["programs_launched"]
+
+
+@ARMS
+@WAVE_FAMILIES
+def test_with_the_recorder_off_the_tokens_are_the_same_and_nothing_is_recorded(family, arm):
+    on_stats, _turns, _evs, on_tokens = drive(family, arm)
+    flightrec.reset()
+    off_stats, turns, evs, off_tokens = drive(family, arm, recorder=False)
+    assert off_tokens == on_tokens and len(off_tokens) == 5
+    assert evs == [] and not any(t["waves"] for t in turns)
+    assert flightrec.snapshot()["rings"] == {}
+    for counter in ("admit_waves", "prefill_tokens_padded", "programs_launched"):
+        assert off_stats[counter] == on_stats[counter] > 0
 
 
 def test_queue_starts_at_the_hand_over_a_caller_names():
