@@ -1486,11 +1486,19 @@ class LLMEngine:
             # step launched ahead (0: none).
             t_end = _time.monotonic()
             batch = len(cur.rows)
+            # How much of the tables the traffic fills: the blocks
+            # the live rows attend (ceil((position + 1) / block)
+            # each) over the B x W entries a gather would bring back.
+            bs = self._block_size
+            blocks_live = int(((cur.at + bs) // bs).sum())
             moe = {}
             if counters is not None and counters.size:
+                # The rows a layer's attention reads, by the program's arm.
+                in_place = self._decode_arm == "decode_attn_kernel_steps"
+                blocks_read = blocks_live if in_place else self.block_tables.size
                 moe = self._model.span_fields(
                     self.model_config, counters, batch, batch,
-                    decode=(cur.at, self.block_tables.size * self._block_size),
+                    decode=(cur.at, blocks_read * bs),
                 )
             _flightrec.record(
                 "llm", "llm.decode_dispatch", t=t_dec,
@@ -1504,14 +1512,10 @@ class LLMEngine:
                 "llm", "llm.decode_sample", t=t_read,
                 dur_s=t_end - t_read, batch=batch,
             )
-            # How much of the tables the traffic fills: the blocks
-            # the live rows attend (ceil((position + 1) / block)
-            # each) over the B x W entries a gather would bring back.
-            bs = self._block_size
             _flightrec.record(
                 "llm", "llm.decode_step", t=t_dec,
                 dur_s=t_end - t_dec, batch=batch,
-                kv_blocks_live=int(((cur.at + bs) // bs).sum()),
+                kv_blocks_live=blocks_live,
                 kv_blocks_table=self.block_tables.size,
                 ahead=int(nxt is not None), discarded=batch - len(rows),
                 seq=cur.seq, next_seq=nxt.seq if nxt is not None else 0,

@@ -37,7 +37,7 @@ from typing import Any, ClassVar
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import latent_moe
+from ray_tpu.models import latent_moe, paged
 from ray_tpu.models.latent_moe import (  # noqa: F401 -- moe_ffn and route: the family's surface
     ffn,
     final_logits,
@@ -108,6 +108,12 @@ class KimiLinearConfig:
     @property
     def latent_dim(self) -> int:
         return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def pool_row_dim(self) -> int:
+        """A pool row's width: the latent row as it is, 4.5 lane tiles (the
+        accepted check reads the rows whole at this width)."""
+        return self.latent_dim
 
     @property
     def conv_dim(self) -> int:
@@ -324,7 +330,7 @@ def init_pool(cfg: KimiLinearConfig, num_blocks: int, block_size: int, slots=Non
     H, d = cfg.kda_heads, cfg.kda_head_dim
     Lk, Lm = len(cfg.kda_layers), len(cfg.mla_layers)
     return {
-        "ckv": jnp.zeros((Lm, num_blocks, block_size, cfg.latent_dim), cfg.dtype),
+        "ckv": jnp.zeros((Lm, num_blocks, block_size, cfg.pool_row_dim), cfg.dtype),
         "state": jnp.zeros((Lk, slots + 1, H, d, d), _F32),
         "conv": jnp.zeros((Lk, slots + 1, cfg.conv_kernel - 1, cfg.conv_dim), cfg.dtype),
     }
@@ -382,22 +388,27 @@ def paged_prefill(
 
 def paged_decode(
     params, last_tokens, positions, tables, pool, cfg: KimiLinearConfig, *,
-    block_size: int, live=None, with_picks: bool = False,
+    block_size: int, live=None, with_picks: bool = False, interpret: bool = False,
 ):
     """One token a slot; operands as :func:`ray_tpu.models.paged.paged_decode`,
     plus ``live`` [B] bool: a slot that is not live (free, or still prefilling
     in chunks) steps on the scratch row of the state and is routed to no
-    expert; its logits mean nothing. None: every slot is live. Returns
-    ``(pool, logits [B, vocab] float32, counts int32 [expert layers, 2])``."""
+    expert; its logits mean nothing. None: every slot is live. The latent
+    layers attend as :func:`ray_tpu.models.paged.latent_decode_attention`
+    chooses (``interpret``: its kernel in the Pallas interpreter, the tests).
+    Returns ``(pool, logits [B, vocab] float32, counts int32 [expert layers,
+    2])``."""
     B = last_tokens.shape[0]
-    S = tables.shape[1] * block_size
     ckv, state, conv = pool["ckv"], pool["state"], pool["conv"]
     rows_of = jnp.arange(B)
     if live is not None:
         rows_of = jnp.where(live, rows_of, state.shape[1] - 1)
     bids = tables[jnp.arange(B), positions // block_size]
     offs = positions % block_size
-    mask = jnp.arange(S)[None, :] <= positions[:, None]  # [B, S]
+    lengths = positions + 1  # the step's own row is attended
+    attend = paged.latent_decode_attention(
+        cfg, block_size, None, interpret, latent_moe.mla_scale(cfg)
+    )
     x = params["wte"].astype(cfg.dtype)[last_tokens]
     seen: list = []
     for i, p, kind, l in _layers(params, cfg):
@@ -408,8 +419,7 @@ def paged_decode(
             conv = conv.at[l, rows_of].set(tail.astype(conv.dtype))
         else:
             ckv = ckv.at[l, bids, offs].set(mla_latent(h, p, cfg))
-            rows = ckv[l, tables].reshape(B, S, cfg.latent_dim)
-            out = mla_decode(h, rows, mask, p, cfg)
+            out = mla_decode(h, ckv, l, tables, lengths, p, cfg, attend)
         x = ffn(x + out, p, cfg, i, live, seen)
     return outputs(
         {"ckv": ckv, "state": state, "conv": conv}, final_logits(params, x, cfg), seen, with_picks

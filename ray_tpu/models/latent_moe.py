@@ -174,7 +174,8 @@ def mla_query(h, p, cfg, rope=None):
     return jnp.concatenate([q[..., :dn], rotate(q[..., dn:], cos, sin)], axis=-1)
 
 
-def _mla_scale(cfg, scale) -> float:
+def mla_scale(cfg, scale=None) -> float:
+    """The softmax's scale: a family's own, or ``(d_n + d_r)^-1/2``."""
     return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 if scale is None else scale
 
 
@@ -202,7 +203,7 @@ def mla_prefill(
     Qb = Kb if T % Kb == 0 else T  # queries a run
     q = mla_query(h, p, cfg, rope)
     wkvb = p["wkvb"].astype(dt)
-    scale = _mla_scale(cfg, scale)
+    scale = mla_scale(cfg, scale)
 
     def attend(q, pos):
         def step(j, carry):
@@ -236,11 +237,15 @@ def mla_prefill(
     return o.reshape(T, H * dv) @ p["wo"].astype(dt)
 
 
-def mla_decode(h, rows, mask, p, cfg, rope=None, scale=None):
-    """One query a row: ``h`` [B, D], ``rows`` [B, S, 576 or wider], ``mask``
-    [B, S]. ``wkvb`` is absorbed: its key half into the query, its value half
-    into the output, so attention runs over latent rows as they lie in the
-    pool (a wider row's zeros meet zeros in the query)."""
+def mla_decode(h, ckv, l: int, tables, lengths, p, cfg, attend, rope=None):
+    """One query a row: ``h`` [B, D] against the first ``lengths`` [B] rows
+    that ``tables`` [B, W] give each slot in layer ``l`` of the latent pool
+    ``ckv`` [L, N, block, 576 or wider]. ``wkvb`` is absorbed: its key half
+    into the query, its value half into the output, so attention runs over
+    latent rows as they lie in the pool (a wider row's zeros meet zeros in
+    the query): ``attend`` is :func:`ray_tpu.models.paged.latent_decode_attention`'s,
+    over the live blocks in place or over the gathered table, and carries
+    the softmax's scale."""
     B = h.shape[0]
     H, dn, dv, R = cfg.n_head, cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
     dt = cfg.dtype
@@ -248,10 +253,8 @@ def mla_decode(h, rows, mask, p, cfg, rope=None, scale=None):
     wkvb = p["wkvb"].astype(dt).reshape(R, H, dn + dv)
     q_lat = jnp.einsum("bhd,rhd->bhr", q[..., :dn], wkvb[..., :dn])
     ql = jnp.concatenate([q_lat, q[..., dn:]], axis=-1)  # [B, H, 576]
-    ql = jnp.pad(ql, ((0, 0), (0, 0), (0, rows.shape[-1] - ql.shape[-1])))
-    s = jnp.einsum("bhc,bsc->bhs", ql, rows).astype(_F32) * _mla_scale(cfg, scale)
-    pa = jax.nn.softmax(jnp.where(mask[:, None], s, -1e30), axis=-1).astype(dt)
-    o_lat = jnp.einsum("bhs,bsr->bhr", pa, rows[..., :R])
+    ql = jnp.pad(ql, ((0, 0), (0, 0), (0, ckv.shape[-1] - ql.shape[-1])))
+    o_lat = attend(ql, ckv, l, tables, lengths)  # [B, H, R]
     o = jnp.einsum("bhr,rhd->bhd", o_lat, wkvb[..., dn:])
     return o.reshape(B, H * dv) @ p["wo"].astype(dt)
 
@@ -467,10 +470,12 @@ def span_fields(cfg, counts, tokens: int, decode=None) -> dict:
     """What the engine writes on the span of one program run over ``tokens``
     real tokens: the program's counters (flat, as read back; two a layer,
     anything behind them is padding) summed over the expert layers. For a
-    decode step, ``decode`` is ``(the live slots' positions, rows the tables
-    span)``: ``latent_rows_live`` is what the step's attention needs (each
-    live slot's ``position + 1`` rows), ``latent_rows_read`` what the program
-    reads a layer: every slot's whole table, while decode gathers it."""
+    decode step, ``decode`` is ``(the live slots' positions, the rows the
+    program reads a layer)``: ``latent_rows_live`` is what the step's
+    attention needs (each live slot's ``position + 1`` rows),
+    ``latent_rows_read`` what the arm the program was built with reads: each
+    live slot's live blocks under the kernel, every slot's whole table under
+    the gather."""
     counts = counts[: 2 * cfg.n_moe_layers].reshape(-1, 2)
     out = {
         "picks": tokens * cfg.experts_per_token * cfg.n_moe_layers,
@@ -479,7 +484,7 @@ def span_fields(cfg, counts, tokens: int, decode=None) -> dict:
         "experts_held": cfg.experts_held * cfg.n_moe_layers,
     }
     if decode is not None:
-        positions, table_rows = decode
-        out["latent_rows_read"] = int(table_rows)
+        positions, rows_read = decode
+        out["latent_rows_read"] = int(rows_read)
         out["latent_rows_live"] = int(positions.sum()) + len(positions)
     return out

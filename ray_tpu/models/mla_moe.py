@@ -43,7 +43,7 @@ from typing import Any, ClassVar
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import latent_moe
+from ray_tpu.models import latent_moe, paged
 from ray_tpu.models.latent_moe import ffn, final_logits, mla_decode, mla_latent, mla_prefill
 from ray_tpu.models.llama import _rms_norm
 
@@ -302,29 +302,32 @@ def paged_prefill(
 
 def paged_decode(
     params, last_tokens, positions, tables, pool, cfg: MlaMoeConfig, *,
-    block_size: int, live=None, with_picks: bool = False,
+    block_size: int, live=None, with_picks: bool = False, interpret: bool = False,
 ):
     """One token a slot; operands as :func:`ray_tpu.models.paged.paged_decode`,
     plus ``live`` [B] bool: a slot that is not live (free, or still prefilling
     in chunks) is routed to no expert; its logits mean nothing and its row goes
     where its table points (the scratch block, or the next chunk's first
-    position). None: every slot is live. Each layer gathers ``ckv[l, tables]``
-    whole: ``latent_rows_read`` on the step's span says how much of that was
-    live. Returns ``(pool, logits [B, vocab] float32, counts)``."""
+    position). None: every slot is live. Each layer writes the step's row,
+    then attends rows [0, position] of every slot: over the live blocks in
+    place or over the gathered table
+    (:func:`ray_tpu.models.paged.latent_decode_attention`; ``interpret`` runs
+    its kernel in the Pallas interpreter: the tests), and ``latent_rows_read``
+    on the step's span says which. Returns ``(pool, logits [B, vocab]
+    float32, counts)``."""
     B = last_tokens.shape[0]
-    S = tables.shape[1] * block_size
     ckv = pool["ckv"]
     bids = tables[jnp.arange(B), positions // block_size]
     offs = positions % block_size
-    mask = jnp.arange(S)[None, :] <= positions[:, None]  # [B, S]
+    lengths = positions + 1  # the step's own row is attended
+    attend = paged.latent_decode_attention(cfg, block_size, None, interpret, cfg.softmax_scale)
     rope = _rope(cfg, positions)
     x = params["wte"].astype(cfg.dtype)[last_tokens]
     seen: list = []
     for l, p in enumerate(params["layers"]):
         h = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
         ckv = ckv.at[l, bids, offs].set(mla_latent(h, p, cfg, rope, cfg.pool_row_dim))
-        rows = ckv[l, tables].reshape(B, S, cfg.pool_row_dim)
-        x = x + mla_decode(h, rows, mask, p, cfg, rope, cfg.softmax_scale)
+        x = x + mla_decode(h, ckv, l, tables, lengths, p, cfg, attend, rope)
         x = ffn(x, p, cfg, l + 1, live, seen)
     return latent_moe.outputs({"ckv": ckv}, final_logits(params, x, cfg), seen, with_picks)
 

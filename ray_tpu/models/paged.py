@@ -66,8 +66,11 @@ and its layer bodies itself:
   (speculative verification, the disaggregated handoff, tensor
   parallelism); and *it keeps a state per slot* (:func:`has_recurrent_state`).
   Latent rows alone (``mla_moe``) are a cache like keys and values: stale
-  rows are masked away by position, a prefix is shared by block ids and a
-  prompt prefills in chunks. A state is not: a stale one is not masked, so a
+  rows are masked away by position, a prefix is shared by block ids, a
+  prompt prefills in chunks, and decode attends each slot's live blocks in
+  place where the rows are whole lane tiles
+  (:func:`latent_decode_attention`: the kernel's latent arm, one copy of a
+  block serving keys and values alike). A state is not: a stale one is not masked, so a
   prefill from position 0 starts from zero state and a later chunk continues
   from its slot's; row ``slots`` is scratch, where slots that are free or
   still prefilling step; and a prefix hit would need the state at the
@@ -182,23 +185,41 @@ def _attend_gathered(qg, pk, pv, l, tables, lengths):
     return jnp.einsum("bkgs,bksd->bkgd", pa, vd)
 
 
+def _attend_latent_gathered(ql, ckv, l, tables, lengths, *, value_width, scale):
+    """Latent decode attention by gather: each slot's whole table brought
+    back as dense rows [B, S, C] and masked to its first ``lengths[b]``
+    positions. ``ql`` [B, H, C], the absorbed query; returns [B, H,
+    value_width]. What
+    :func:`ops.paged_attention.paged_latent_decode_attention` computes from
+    the live blocks alone."""
+    B = ql.shape[0]
+    S = tables.shape[1] * ckv.shape[2]
+    rows = ckv[l, tables].reshape(B, S, ckv.shape[3])
+    s = jnp.einsum("bhc,bsc->bhs", ql, rows).astype(jnp.float32) * scale
+    mask = jnp.arange(S)[None, :] < lengths[:, None]  # [B, S]
+    pa = jax.nn.softmax(jnp.where(mask[:, None], s, -1e30), axis=-1).astype(rows.dtype)
+    return jnp.einsum("bhs,bsr->bhr", pa, rows[..., :value_width])
+
+
 def decode_attends_in_place(cfg, block_size: int, *, mesh=None) -> bool:
-    """Whether :func:`paged_decode`, lowered for this process's default
-    backend, attends the live blocks in place (the kernel) or gathers each
-    table whole: the kernel on a TPU, for a family of keys and values per
-    head whose head and block sizes are whole TPU tiles and fit VMEM, outside a mesh
-    (the compiler cannot partition a Mosaic call). Keys and values per head
-    are what ``kv_hooks`` serve, and what a family that brings its own
-    programs says it keeps (``kv_per_head``: its attention layers then call
-    :func:`decode_attention`, the same choice, for their part of the pool).
+    """Whether the family's decode program, lowered for this process's
+    default backend, attends the live blocks in place (the kernel) or gathers
+    each table whole: the kernel on a TPU, for a cache whose shapes are whole
+    TPU tiles and fit VMEM, outside a mesh (the compiler cannot partition a
+    Mosaic call). The cache is keys and values per head, which ``kv_hooks``
+    serve and which a family that brings its own programs says it keeps
+    (``kv_per_head``: its attention layers then call
+    :func:`decode_attention`, the same choice, for their part of the pool),
+    or latent rows, whose width the configuration gives (``pool_row_dim``:
+    the family's programs call :func:`latent_decode_attention`).
     Decided by what the code can see, like
     ``ops.attention.uses_flash_kernel``; nothing a user sets reaches it."""
     mod = family(cfg)
-    return (
-        jax.default_backend() == "tpu"
-        and (hasattr(mod, "kv_hooks") or getattr(mod, "kv_per_head", False))
-        and _kernel_fits(cfg, block_size, mesh)
-    )
+    if hasattr(mod, "kv_hooks") or getattr(mod, "kv_per_head", False):
+        fits = _kernel_fits(cfg, block_size, mesh)
+    else:
+        fits = hasattr(cfg, "pool_row_dim") and _latent_kernel_fits(cfg, block_size, mesh)
+    return jax.default_backend() == "tpu" and fits
 
 
 def _kv_heads(cfg) -> int:
@@ -211,20 +232,45 @@ def _kernel_fits(cfg, block_size, mesh) -> bool:
     )
 
 
-def decode_attention(cfg, block_size, mesh, interpret):
-    """The decode step's attention over the scattered pool: the kernel
-    where the shapes fit and the program is lowered for a TPU (decided at
-    lowering, so a program compiled here for a described chip holds what
-    the chip will run), the gather elsewhere. ``interpret`` runs the
-    kernel in the Pallas interpreter whatever the platform and the shapes
-    (the tests)."""
-    kernel = paged_attention.paged_decode_attention
+def _latent_kernel_fits(cfg, block_size, mesh) -> bool:
+    return (mesh is None or mesh.size == 1) and paged_attention.fits_latent(
+        cfg.n_head, cfg.pool_row_dim, cfg.kv_lora_rank, block_size,
+        jnp.dtype(cfg.dtype).itemsize,
+    )
+
+
+def _choose(kernel, gather, fits: bool, interpret: bool):
+    """``kernel`` where the shapes fit and the program is lowered for a TPU
+    (decided at lowering, so a program compiled here for a described chip
+    holds what the chip will run), ``gather`` elsewhere. ``interpret`` runs
+    the kernel in the Pallas interpreter whatever the platform and the
+    shapes (the tests)."""
     if interpret:
         return functools.partial(kernel, interpret=True)
-    if not _kernel_fits(cfg, block_size, mesh):
-        return _attend_gathered
-    return functools.partial(
-        jax.lax.platform_dependent, tpu=kernel, default=_attend_gathered
+    if not fits:
+        return gather
+    return functools.partial(jax.lax.platform_dependent, tpu=kernel, default=gather)
+
+
+def decode_attention(cfg, block_size, mesh, interpret):
+    """The decode step's attention over the scattered pool of keys and
+    values per head, ``attend(qg, pk, pv, l, tables, lengths)``: the kernel
+    or the gather, as :func:`_choose` says."""
+    return _choose(
+        paged_attention.paged_decode_attention, _attend_gathered,
+        _kernel_fits(cfg, block_size, mesh), interpret,
+    )
+
+
+def latent_decode_attention(cfg, block_size, mesh, interpret, scale: float):
+    """The same over a pool of latent rows ``[L, N, block, pool_row_dim]``,
+    ``attend(ql, ckv, l, tables, lengths)`` -> [B, H, kv_lora_rank]: ``ql``
+    [B, H, pool_row_dim] the absorbed query, ``scale`` the softmax's."""
+    static = dict(value_width=cfg.kv_lora_rank, scale=scale)
+    return _choose(
+        functools.partial(paged_attention.paged_latent_decode_attention, **static),
+        functools.partial(_attend_latent_gathered, **static),
+        _latent_kernel_fits(cfg, block_size, mesh), interpret,
     )
 
 
@@ -407,7 +453,7 @@ def paged_decode(
     if not hasattr(mod, "kv_hooks"):
         return mod.paged_decode(
             params, last_tokens, positions, tables, pool, cfg,
-            block_size=block_size, live=live,
+            block_size=block_size, live=live, interpret=interpret,
         )
     B = last_tokens.shape[0]
     W = tables.shape[1]

@@ -16,6 +16,13 @@ The pool ``[L, N, KH, block, Dh]`` never becomes a value of the kernel's
 caller: it is handed over whole in ``memory_space=ANY`` with the layer as a
 prefetched scalar, so no layer's slab is sliced out as a temporary.
 
+Two entries over one walk and one fold. :func:`paged_decode_attention`: keys
+and values per head, two pools, two copies a live block.
+:func:`paged_latent_decode_attention`: a pool of latent rows ``[L, N, block,
+C]``, one row a position for all heads, which the absorbed query scores whole
+and whose first ``value_width`` lanes are the values, so one copy of a live
+block serves both: a single "KV head" whose group is every query head.
+
 Matmuls run on the MXU in the pool's dtype with float32 accumulation;
 scores, softmax statistics and the accumulator are float32; the mask is the
 length (``col < length``, the gather path's ``col <= position``).
@@ -37,19 +44,24 @@ from ray_tpu.ops.attention import _LOG2E, _NEG_INF
 # on the dead end of a slot's last chunk: on a v5e, sixteen slots of 128-768
 # positions take 49 us a layer at 128, 43 at 256 and 43 at 512 (PERF.md).
 _CHUNK = 256
+# The same for latent rows, whose slots hold five times the positions: on a
+# v5e, thirty-two slots of 540-3,900 rows of 640 take 322 us a layer at 256,
+# 279 at 512 and 261 at 1,024 (PERF.md section 6, PR 39).
+_LATENT_CHUNK = 1024
 # Query heads of one KV head are padded to the bf16 sublane tile, so that
 # each head's [group, Dh] operand and [group, chunk] scores are whole tiles.
 _GROUP_TILE = 16
 
 
-# The four chunk buffers (keys and values, two each) may take this much of
-# a core's VMEM (16 MiB by default on a v5e), the rest left to the scores.
+# The chunk buffers (two a pool: four for keys and values, two for latent
+# rows) may take this much of a core's VMEM (16 MiB by default on a v5e), the
+# rest left to the scores.
 _VMEM_BUFFER_BYTES = 8 * 2**20
 
 
-def _pages(block_size: int) -> int:
+def _pages(block_size: int, chunk: int) -> int:
     """Blocks a chunk: a block at least, however long."""
-    return max(1, _CHUNK // block_size)
+    return max(1, chunk // block_size)
 
 
 def fits(kv_heads: int, head_dim: int, block_size: int, itemsize: int) -> bool:
@@ -57,7 +69,7 @@ def fits(kv_heads: int, head_dim: int, block_size: int, itemsize: int) -> bool:
     shapes (the lane width in ``head_dim``, the bf16 sublane tile in the
     block) and its chunk buffers, of ``itemsize`` bytes an element, fit
     VMEM."""
-    chunk = _pages(block_size) * block_size
+    chunk = _pages(block_size, _CHUNK) * block_size
     return (
         head_dim % 128 == 0
         and block_size % 16 == 0
@@ -65,16 +77,37 @@ def fits(kv_heads: int, head_dim: int, block_size: int, itemsize: int) -> bool:
     )
 
 
+def fits_latent(
+    heads: int, row_dim: int, value_width: int, block_size: int, itemsize: int
+) -> bool:
+    """:func:`fits` for a pool of latent rows: the row and its value part in
+    whole lane tiles, the block and the query heads (the one group) in whole
+    bf16 sublane tiles, the two chunk buffers within VMEM."""
+    chunk = _pages(block_size, _LATENT_CHUNK) * block_size
+    return (
+        row_dim % 128 == 0
+        and value_width % 128 == 0
+        and block_size % 16 == 0
+        and heads % _GROUP_TILE == 0
+        and 2 * chunk * row_dim * itemsize <= _VMEM_BUFFER_BYTES
+    )
+
+
 def _kernel(
     layer_ref, lengths_ref, tables_ref,  # scalar prefetch (SMEM)
     q_ref,  # [1, KH, G, Dh] VMEM
-    k_hbm, v_hbm,  # [L, N, KH, block, Dh], left in HBM
-    o_ref,  # [1, KH, G, Dh] VMEM
-    kbuf, vbuf,  # [2, KH, pages * block, Dh] VMEM
-    sems,  # DMA semaphores [2 (k, v), 2 (buffer)]
-    parity,  # SMEM [1]: the buffer the slot's first chunk was copied to
-    *, block, pages, width, scale,
+    *refs,  # the pools, o_ref, a chunk buffer for each pool, sems, parity
+    block, pages, width, scale,
 ):
+    """``refs``: the pools ``[L, N, KH, block, Dh]`` left in HBM, keys then
+    values, or one whose rows are the keys and, in their first lanes, the
+    values; ``o_ref`` [1, KH, G, Dv] VMEM; for each pool its chunk buffer
+    [2, KH, pages * block, Dh] VMEM; DMA semaphores [pools, 2 (buffer)];
+    SMEM [1]: the buffer the slot's first chunk was copied to."""
+    n = (len(refs) - 3) // 2
+    pools, o_ref, bufs = refs[:n], refs[n], refs[n + 1 : 2 * n + 1]
+    sems, parity = refs[2 * n + 1 :]
+    kbuf, vbuf = bufs[0], bufs[-1]
     b = pl.program_id(0)
     slots = pl.num_programs(0)
     layer = layer_ref[0]
@@ -84,19 +117,17 @@ def _kernel(
         return (lengths_ref[slot] + block - 1) // block
 
     def copies(slot, i, buf, j):
-        """The two copies of live block ``j`` of the slot's chunk ``i``:
-        every KV head of one block is contiguous in the pool, and lands
-        strided, at its positions of each head's row of the buffer."""
+        """The copies of live block ``j`` of the slot's chunk ``i``, one a
+        pool: every KV head of one block is contiguous in the pool, and
+        lands strided, at its positions of each head's row of the buffer."""
         page = tables_ref[slot * width + i * pages + j]
         rows = pl.ds(pl.multiple_of(j * block, block), block)
-        return (
+        return [
             pltpu.make_async_copy(
-                k_hbm.at[layer, page], kbuf.at[buf, :, rows, :], sems.at[0, buf]
-            ),
-            pltpu.make_async_copy(
-                v_hbm.at[layer, page], vbuf.at[buf, :, rows, :], sems.at[1, buf]
-            ),
-        )
+                hbm.at[layer, page], dst.at[buf, :, rows, :], sems.at[t, buf]
+            )
+            for t, (hbm, dst) in enumerate(zip(pools, bufs))
+        ]
 
     def for_each_copy(slot, i, buf, act):
         n = jnp.minimum(pages, live_pages(slot) - i * pages)
@@ -122,6 +153,7 @@ def _kernel(
     n_chunks = (live_pages(b) + pages - 1) // pages
     q = q_ref[0]  # [KH, G, Dh]
     KH, G, Dh = q.shape
+    Dv = o_ref.shape[-1]
     cols = jax.lax.broadcasted_iota(jnp.int32, (KH, G, chunk), 2)
 
     def body(i, carry):
@@ -139,7 +171,8 @@ def _kernel(
 
         for_each_copy(b, i, buf, lambda c: c.wait())
         k = kbuf[buf]  # [KH, chunk, Dh]
-        v = vbuf[buf]
+        # [KH, chunk, Dv]: all of a value buffer, the first lanes of a row's
+        v = vbuf[buf, :, :, :Dv]
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
@@ -157,10 +190,51 @@ def _kernel(
 
     m0 = jnp.full((KH, G, 1), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((KH, G, 1), jnp.float32)
-    acc0 = jnp.zeros((KH, G, Dh), jnp.float32)
+    acc0 = jnp.zeros((KH, G, Dv), jnp.float32)
     _, l, acc = jax.lax.fori_loop(0, n_chunks, body, (m0, l0, acc0))
     o_ref[0] = (acc / l).astype(o_ref.dtype)
     parity[0] = (first + n_chunks) % 2
+
+
+def _attend(q, pools, layer, tables, lengths, *, value_width, scale, chunk, interpret, name):
+    """The call both entries make: ``q`` [B, KH, G, Dh] against ``pools``
+    (each [L, N, KH, block, Dh]; the last one's first ``value_width`` lanes
+    are the values), ``chunk`` positions a fold; [B, KH, G, value_width]."""
+    B, KH, G, Dh = q.shape
+    block = pools[0].shape[3]
+    pages = _pages(block, chunk)
+    dtype = pools[0].dtype
+    anywhere = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        functools.partial(
+            _kernel, block=block, pages=pages, width=tables.shape[1], scale=scale
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[
+                pl.BlockSpec((1, KH, G, Dh), lambda b, *_: (b, 0, 0, 0)),
+                *[anywhere for _ in pools],
+            ],
+            out_specs=pl.BlockSpec((1, KH, G, value_width), lambda b, *_: (b, 0, 0, 0)),
+            scratch_shapes=[
+                *[pltpu.VMEM((2, KH, pages * block, Dh), dtype) for _ in pools],
+                pltpu.SemaphoreType.DMA((len(pools), 2)),
+                pltpu.SMEM((1,), jnp.int32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, KH, G, value_width), dtype),
+        # Slots run in order: each starts the next one's first copies.
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name=name,
+    )(
+        jnp.reshape(layer, (1,)).astype(jnp.int32),
+        lengths.astype(jnp.int32),
+        tables.reshape(-1).astype(jnp.int32),
+        q.astype(dtype),
+        *pools,
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -178,45 +252,37 @@ def paged_decode_attention(
     positions of its table's blocks in layer ``layer``; [B, KH, group, Dh]
     in the pool's dtype. Table entries past a slot's live blocks are never
     read."""
-    B, KH, G, Dh = q.shape
-    block = pool_k.shape[3]
-    W = tables.shape[1]
-    pages = _pages(block)
+    G, Dh = q.shape[2:]
     pad = -G % _GROUP_TILE
     if pad:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    row = pl.BlockSpec((1, KH, G + pad, Dh), lambda b, *_: (b, 0, 0, 0))
-    out = pl.pallas_call(
-        functools.partial(
-            _kernel, block=block, pages=pages, width=W, scale=Dh**-0.5
-        ),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(B,),
-            in_specs=[
-                row,
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=row,
-            scratch_shapes=[
-                pltpu.VMEM((2, KH, pages * block, Dh), pool_k.dtype),
-                pltpu.VMEM((2, KH, pages * block, Dh), pool_v.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
-                pltpu.SMEM((1,), jnp.int32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct(q.shape, pool_v.dtype),
-        # Slots run in order: each starts the next one's first copies.
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
-        interpret=interpret,
+    out = _attend(
+        q, (pool_k, pool_v), layer, tables, lengths, value_width=Dh,
+        scale=Dh**-0.5, chunk=_CHUNK, interpret=interpret,
         name="paged_decode_attention",
-    )(
-        jnp.reshape(layer, (1,)).astype(jnp.int32),
-        lengths.astype(jnp.int32),
-        tables.reshape(-1).astype(jnp.int32),
-        q.astype(pool_k.dtype),
-        pool_k,
-        pool_v,
     )
     return out[:, :, :G]
+
+
+@functools.partial(jax.jit, static_argnames=("value_width", "scale", "interpret"))
+def paged_latent_decode_attention(
+    ql: jax.Array,  # [B, H, C] — the absorbed query of one position a slot
+    pool: jax.Array,  # [L, N, block, C] latent rows
+    layer: jax.Array,  # scalar int32 — the layer of the pool to read
+    tables: jax.Array,  # [B, W] int32 block tables
+    lengths: jax.Array,  # [B] int32, >= 1 — positions attended, a slot
+    *,
+    value_width: int,  # a row's first lanes that are its value
+    scale: float,
+    interpret: bool = False,
+) -> jax.Array:
+    """softmax(ql rows^T scale) rows[:, :value_width] over each slot's first
+    ``lengths[b]`` rows of its table's blocks in layer ``layer``; [B, H,
+    value_width] in the pool's dtype. Table entries past a slot's live
+    blocks are never read."""
+    out = _attend(
+        ql[:, None], (pool[:, :, None],), layer, tables, lengths,
+        value_width=value_width, scale=scale, chunk=_LATENT_CHUNK,
+        interpret=interpret, name="paged_latent_decode_attention",
+    )
+    return out[:, 0]

@@ -142,8 +142,9 @@ def test_mla_absorbed_decode_is_the_expanded_attention(tiny):
     expanded = kl.mla_prefill(
         h, ckv, 0, jnp.asarray([1, 2]), jnp.arange(S), jnp.asarray(S), p, cfg, block_size=16
     )
+    attend = paged.latent_decode_attention(cfg, 16, None, False, latent_moe.mla_scale(cfg))
     absorbed = kl.mla_decode(
-        h[n][None], rows[None], (jnp.arange(S) <= n)[None], p, cfg
+        h[n][None], ckv, 0, jnp.asarray([[1, 2]]), jnp.asarray([n + 1]), p, cfg, attend
     )
     np.testing.assert_allclose(absorbed[0], expanded[n], rtol=2e-4, atol=2e-6)
 
@@ -274,10 +275,13 @@ def test_pool_parts_and_what_the_paged_programs_refuse(tiny):
         paged.paged_verify(None, jnp.zeros((1, 2), jnp.int32), None, None, pool, cfg, block_size=16)
 
 
-def test_paged_prefill_and_decode_are_the_reference_forward(tiny):
+@pytest.mark.parametrize("interpret", [False, True], ids=["gather", "kernel_interpreted"])
+def test_paged_prefill_and_decode_are_the_reference_forward(tiny, interpret):
     """Two prompts in two buckets into two slots and scattered tables, three
     decode steps with a free and a prefilling-like (not live) slot beside
-    them: logits against the reference's full forward."""
+    them: logits against the reference's full forward, the latent layers'
+    decode by the gather and by the kernel over live blocks (interpreted:
+    on a TPU these rows of 576 would gather)."""
     cfg, params = tiny
     c = ref_config(cfg)
     bs, W, B, K = 16, 8, 4, 3
@@ -287,7 +291,7 @@ def test_paged_prefill_and_decode_are_the_reference_forward(tiny):
     want, inner = ref.forward(params, jnp.asarray(toks), c, inner=True)
     want_picks = inner["picks"]
     prefill = jax.jit(functools.partial(paged.paged_prefill, cfg=cfg, block_size=bs))
-    decode = jax.jit(functools.partial(paged.paged_decode, cfg=cfg, block_size=bs))
+    decode = jax.jit(functools.partial(paged.paged_decode, cfg=cfg, block_size=bs, interpret=interpret))
     pool = paged.init_block_pool(cfg, 20, bs, B)
     # whatever was in the slots before must not matter
     pool["state"] = pool["state"] + 3.0

@@ -5,9 +5,11 @@ prefill over latent rows (which nothing in the repo ran before this family);
 what the engine refuses for it, by name and for its own reason; its spans.
 """
 
+import functools
 import os
 import sys
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -18,7 +20,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmarks.reference import mla_moe_ref as ref  # noqa: E402
 from ray_tpu.core.config import GLOBAL_CONFIG  # noqa: E402
 from ray_tpu.llm import LLMConfig, LLMEngine, SamplingParams  # noqa: E402
-from ray_tpu.models import mla_moe as mm  # noqa: E402
+from ray_tpu.models import mla_moe as mm, paged  # noqa: E402
 from ray_tpu.util import flightrec  # noqa: E402
 from test_mla_moe import ref_config  # noqa: E402
 
@@ -173,3 +175,75 @@ def test_spans_carry_the_expert_counters_and_the_latent_rows(engine):
         assert x["picks_here"] == x["picks"] == x["tokens"] * cfg.experts_per_token * cfg.n_moe_layers
         assert "latent_rows_read" not in x
     assert engine.stats["cache_bytes_ckv"] == engine.pool["ckv"].nbytes
+
+
+# -- decode over the live blocks in place (the kernel, interpreted here) ----------
+
+
+def test_decode_through_the_kernel_is_decode_through_the_gather(engine):
+    """``paged_decode(..., interpret=True)`` attends each slot's live blocks
+    with the latent kernel in the Pallas interpreter; without it, here on the
+    CPU, it gathers every table whole. Three slots at unlike positions (one on
+    a block's last row, one a free slot on the scratch block) over a pool of
+    stale values: the same logits and the same pool, to a float32 sum's
+    tolerance."""
+    cfg, bs = engine.model_config, 16
+    pool = mm.init_pool(cfg, 25, bs)
+    pool = {"ckv": jax.random.normal(jax.random.key(4), pool["ckv"].shape).at[..., cfg.latent_dim:].set(0)}
+    tables = jnp.asarray(
+        np.stack([np.random.default_rng(5).permutation(np.arange(1, 25))[:8] for _ in range(2)] + [np.zeros(8)]),
+        jnp.int32,
+    )
+    positions = jnp.asarray([77, 31, 0], jnp.int32)
+    tokens = jnp.asarray([5, 9, 0], jnp.int32)
+    live = jnp.asarray([True, True, False])
+    out = {
+        interpret: jax.jit(functools.partial(mm.paged_decode, cfg=cfg, block_size=bs, interpret=interpret))(
+            engine.params, tokens, positions, tables, pool, live=live
+        )
+        for interpret in (False, True)
+    }
+    (pool_g, logits_g, counts_g), (pool_k, logits_k, counts_k) = out[False], out[True]
+    np.testing.assert_allclose(logits_k[:2], logits_g[:2], rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(pool_k["ckv"], pool_g["ckv"], rtol=2e-4, atol=2e-5)
+    np.testing.assert_array_equal(counts_k, counts_g)
+    # the step's own rows were written where the tables point, and are attended
+    assert not np.allclose(pool_g["ckv"][:, tables[0, 4], 13], pool["ckv"][:, tables[0, 4], 13])
+
+
+def test_an_engine_built_with_the_kernel_streams_the_gathers_tokens_and_says_so(monkeypatch):
+    """The engine as a TPU replica has it (the arm named by
+    ``paged.decode_attends_in_place``, the kernel run by the interpreter here):
+    the same greedy tokens as the engine that gathers, every decode program
+    counted under ``decode_attn_kernel_steps``, and ``latent_rows_read`` on a
+    step's span the live slots' live blocks, not the tables."""
+    ps = prompts(4, np.random.default_rng(8))
+    gathered = LLMEngine(llm_config())
+    want = gathered.generate(ps, SamplingParams(max_tokens=6))
+    assert gathered.stats["decode_attn_kernel_steps"] == 0 < gathered.stats["decode_attn_gather_steps"]
+
+    choose = paged.latent_decode_attention
+    monkeypatch.setattr(
+        paged, "latent_decode_attention",
+        lambda cfg, bs, mesh, interpret, scale: choose(cfg, bs, mesh, True, scale),
+    )
+    monkeypatch.setattr(paged, "decode_attends_in_place", lambda cfg, bs, mesh=None: True)
+    eng = LLMEngine(llm_config())
+    saved = GLOBAL_CONFIG.flightrec
+    GLOBAL_CONFIG.flightrec = True
+    flightrec.reset()
+    try:
+        got = eng.generate(ps, SamplingParams(max_tokens=6))
+        events = [e for r in flightrec.snapshot(planes=("llm",))["rings"].values()
+                  for e in r["events"]]
+    finally:
+        GLOBAL_CONFIG.flightrec = saved
+        flightrec.reset()
+    assert [o["token_ids"] for o in got] == [o["token_ids"] for o in want]
+    assert eng.stats["decode_attn_gather_steps"] == 0
+    assert eng.stats["decode_attn_kernel_steps"] == gathered.stats["decode_attn_gather_steps"]
+    steps = [e["extra"] for e in events if e["phase"] == "llm.decode_step"]
+    assert steps
+    for x in steps:
+        assert x["latent_rows_read"] == x["kv_blocks_live"] * 16
+        assert x["latent_rows_live"] <= x["latent_rows_read"] < x["latent_rows_live"] + 16 * x["batch"]

@@ -245,6 +245,121 @@ def test_the_decode_kernel_reads_no_block_past_a_slots_live_ones():
     )
 
 
+# -- the latent arm of the kernel against its gather ------------------------------
+#
+# A pool of latent rows [L, N, block, C], one row a position for all heads:
+# ``paged_latent_decode_attention`` copies each live block once and takes keys
+# (the whole row) and values (its first lanes) from that one copy;
+# ``paged._attend_latent_gathered`` is what ``mla_decode`` did on a gathered
+# row. Two layers, four slots of four heads, blocks of 16, tables of 72 blocks
+# (1,152 positions: more than a chunk) scattered over a pool of random (stale)
+# values, rows of 640 with zeros behind 576 as A.X-K1's are.
+
+LATENT = dict(value_width=512, scale=0.1147)
+LATENT_BLOCK, LATENT_WIDTH = 16, 72
+LATENT_CASES = {  # lengths a slot
+    "slots of unlike lengths": [300, 5, 77, 1100],
+    "a length of one": [1, 1, 1, 1],
+    "on and one past a block edge": [16, 17, 32, 33],
+    "on and one past a chunk edge": [1024, 1025, 1023, 1152],
+    "a table wider than any slot uses": [20, 3, 40, 9],
+}
+
+
+@functools.cache
+def _latent_operands(padded=True):
+    from ray_tpu.ops import paged_attention
+
+    assert LATENT_BLOCK * LATENT_WIDTH > paged_attention._LATENT_CHUNK  # the cases name its edge
+    assert paged_attention._LATENT_CHUNK == 1024
+    ks = jax.random.split(jax.random.key(11), 2)
+    pool = jax.random.normal(ks[0], (2, 289, LATENT_BLOCK, 640))
+    if padded:
+        pool = pool.at[..., 576:].set(0)
+    ql = jax.random.normal(ks[1], (4, 4, 640))
+    tables = np.random.default_rng(3).permutation(np.arange(1, 289)).reshape(4, LATENT_WIDTH)
+    return ql, pool, jnp.asarray(tables, jnp.int32)
+
+
+@pytest.mark.parametrize("case", [*LATENT_CASES, "every lane of a row random"])
+def test_the_latent_kernel_attends_what_the_gather_attends(case):
+    from ray_tpu.ops.paged_attention import paged_latent_decode_attention
+
+    ql, pool, tables = _latent_operands(padded=case in LATENT_CASES)
+    lengths = jnp.asarray(LATENT_CASES.get(case, [300, 5, 77, 1100]), jnp.int32)
+    want = paged._attend_latent_gathered(ql, pool, 1, tables, lengths, **LATENT)
+    got = paged_latent_decode_attention(
+        ql, pool, jnp.int32(1), tables, lengths, **LATENT, interpret=True
+    )
+    assert got.shape == (4, 4, 512)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
+    # The comparison can tell a layer from its neighbour and a table from
+    # another's: the gather of the wrong ones is far off.
+    for wrong in (
+        paged._attend_latent_gathered(ql, pool, 0, tables, lengths, **LATENT),
+        paged._attend_latent_gathered(ql, pool, 1, tables[::-1], lengths, **LATENT),
+    ):
+        assert np.abs(np.asarray(wrong) - np.asarray(got)).max() > 0.1
+
+
+def test_the_latent_kernel_lets_no_dead_end_reach_the_output():
+    """Entries behind a slot's live blocks point at a block of NaN here,
+    which the gather brings back (a masked zero times a NaN is a NaN) and the
+    kernel never copies; and the rows of the chunk buffer behind the live
+    blocks, which are values too here, hold nothing that reaches the output
+    (the interpreter fills a fresh buffer with NaN)."""
+    from ray_tpu.ops.paged_attention import paged_latent_decode_attention
+
+    ql, pool, tables = _latent_operands()
+    lengths = jnp.asarray([300, 5, 77, 1100], jnp.int32)  # 19, 1, 5, 69 live blocks
+    clean = paged_latent_decode_attention(
+        ql, pool, jnp.int32(0), tables, lengths, **LATENT, interpret=True
+    )
+    assert np.isfinite(np.asarray(clean)).all()
+    poisoned = np.asarray(tables).copy()
+    for b, live in enumerate([19, 1, 5, 69]):
+        poisoned[b, live:] = 0
+    pool = pool.at[:, 0].set(jnp.nan)
+    args = (ql, pool, jnp.int32(0), jnp.asarray(poisoned), lengths)
+    assert np.isnan(np.asarray(paged._attend_latent_gathered(*args, **LATENT))).all()
+    np.testing.assert_array_equal(
+        np.asarray(paged_latent_decode_attention(*args, **LATENT, interpret=True)),
+        np.asarray(clean),
+    )
+
+
+@pytest.mark.parametrize("family, fits", [("mla_moe", True), ("kimi_linear", False)])
+def test_the_latent_arm_is_chosen_by_the_pools_shapes(family, fits, monkeypatch):
+    """At the published widths: A.X-K1's rows of 640 (five lane tiles), blocks
+    of 16 and 64 heads are whole tiles; Kimi Linear's rows of 576 are four and
+    a half, so its program holds the gather whatever the platform. Off a TPU
+    and under a mesh both gather."""
+    from ray_tpu.models.kimi_linear import KimiLinearConfig
+    from ray_tpu.models.mla_moe import MlaMoeConfig
+    from ray_tpu.ops import paged_attention
+
+    cfg = {"mla_moe": MlaMoeConfig, "kimi_linear": KimiLinearConfig}[family]()
+    assert cfg.pool_row_dim == (640 if fits else 576)
+    assert paged_attention.fits_latent(
+        cfg.n_head, cfg.pool_row_dim, cfg.kv_lora_rank, 16, itemsize=2
+    ) == fits
+    assert paged._latent_kernel_fits(cfg, 16, None) == fits
+    assert not paged_attention.fits_latent(64, 640, 512, 8, itemsize=2)  # half a bf16 tile of rows
+    assert not paged_attention.fits_latent(8, 640, 512, 16, itemsize=2)  # half a tile of heads
+    attend = paged.latent_decode_attention(cfg, 16, None, False, 0.1)
+    assert (attend.func is jax.lax.platform_dependent) == fits
+    assert fits or attend.func is paged._attend_latent_gathered
+    assert not paged.decode_attends_in_place(cfg, 16)  # lowered here for a CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert paged.decode_attends_in_place(cfg, 16) == fits
+
+    class FourChips:
+        size = 4
+
+    assert not paged.decode_attends_in_place(cfg, 16, mesh=FourChips())
+    assert not paged.decode_attends_in_place(cfg, 8)
+
+
 def _greedy_rollout(mod, cfg, params, prompt, max_tokens, stop, window=64):
     """Greedy tokens from the training forward alone: the whole sequence
     through ``forward`` for every token, no cache of any kind. Causal, so

@@ -160,7 +160,10 @@ def test_prefill_by_stretches_of_the_table_is_the_whole_expanded_attention(tiny,
     np.testing.assert_allclose(got[:n], want[:n], rtol=2e-4, atol=2e-6)
 
 
-def test_absorbed_decode_is_the_expanding_form(tiny):
+@pytest.mark.parametrize("interpret", [False, True], ids=["gather", "kernel_interpreted"])
+def test_absorbed_decode_is_the_expanding_form(tiny, interpret):
+    """By either arm of ``paged.latent_decode_attention``: over the gathered
+    table, and over the live blocks with the kernel in the interpreter."""
     cfg, params = tiny
     p = params["layers"][0]
     S, n = 32, 21
@@ -169,16 +172,20 @@ def test_absorbed_decode_is_the_expanding_form(tiny):
     rope = latent_moe.rope_tables(cfg.rope_freqs, pos)
     rows = latent_moe.mla_latent(h, p, cfg, rope)
     expanded = _expanded(h, rows, pos, p, cfg, rope, cfg.softmax_scale)
-    one = tuple(a[n][None] for a in rope)
-    absorbed = latent_moe.mla_decode(
-        h[n][None], rows[None], (pos <= n)[None], p, cfg, one, cfg.softmax_scale
-    )
+    # the rows as a one-layer pool under the table 2, 1
+    ckv = jnp.zeros((1, 3, 16, rows.shape[-1])).at[0, jnp.asarray([2, 1])].set(rows.reshape(2, 16, -1))
+    attend = paged.latent_decode_attention(cfg, 16, None, interpret, cfg.softmax_scale)
+
+    def decode(rope_at):
+        one = tuple(a[rope_at][None] for a in rope)
+        return latent_moe.mla_decode(
+            h[n][None], ckv, 0, jnp.asarray([[2, 1]]), jnp.asarray([n + 1]), p, cfg, attend, one
+        )
+
+    absorbed = decode(n)
     np.testing.assert_allclose(absorbed[0], expanded[n], rtol=1e-4, atol=2e-6)
     # the rotation matters: the same query as if it stood elsewhere reads another mixture
-    moved = tuple(a[3][None] for a in rope)
-    elsewhere = latent_moe.mla_decode(
-        h[n][None], rows[None], (pos <= n)[None], p, cfg, moved, cfg.softmax_scale
-    )
+    elsewhere = decode(3)
     assert float(jnp.abs(elsewhere - absorbed).max()) > 1e-3 * float(jnp.abs(absorbed).max())
 
 

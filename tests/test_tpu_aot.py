@@ -9,6 +9,7 @@ partition a Mosaic call.
 """
 
 import dataclasses
+import functools
 import re
 
 import jax
@@ -178,6 +179,65 @@ def test_the_decode_attention_kernel_compiles_at_served_widths(
     assert compiled.as_text().count(MOSAIC) == 1
     # Nothing pool-sized beside the pool: the operands stay where they are.
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+def test_the_latent_attention_kernel_compiles_at_served_widths(v5e_2x2):
+    """The latent arm of the block-walking kernel at the widths A.X-K1 is
+    served at (32 slots, a pool of 7 layers of 8,193 blocks of 16 rows of 640
+    left in HBM, tables of 4,096 positions, 64 heads, values 512 wide): one
+    Mosaic call under its own name, the lane slice that takes the values out
+    of the rows' buffer and its chunk buffers accepted, and nothing of the
+    pool's size beside the pool (the unit axis that makes the rows one "KV
+    head" is a bitcast, not a copy)."""
+    from ray_tpu.ops import paged_attention
+
+    assert paged_attention.fits_latent(64, 640, 512, 16, itemsize=2)
+    one = jax.sharding.SingleDeviceSharding(v5e_2x2[0])
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)  # noqa: E731
+    compiled = jax.jit(
+        functools.partial(
+            paged_attention.paged_latent_decode_attention, value_width=512, scale=0.1147
+        )
+    ).lower(
+        sds((32, 64, 640), jnp.bfloat16), sds((7, 8193, 16, 640), jnp.bfloat16),
+        sds((), jnp.int32), sds((32, 256), jnp.int32), sds((32,), jnp.int32),
+    ).compile()
+    assert mosaic_calls(compiled.as_text()) == ["paged_latent_decode_attention"]
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+def test_axk1s_decode_program_attends_its_latent_pool_in_place(v5e_2x2):
+    """``mla_moe.paged_decode`` at A.X-K1's served shapes (the benchmark's
+    configuration: 7 layers of the published widths, 12 experts held), lowered
+    for the chip: seven latent kernels, one a layer, the pool donated and
+    aliased, and no temporary the size of a gathered table (32 slots x 4,096
+    rows x 640 lanes in bf16 is 168 MB a layer; the gathering program held
+    257 MB of temporaries)."""
+    from ray_tpu.models import mla_moe, paged
+
+    cfg = mla_moe.MlaMoeConfig(
+        vocab_size=20480, n_layer=7, experts_held=12, max_seq=4096
+    )
+    B, bs, N = 32, 16, 8193
+    assert paged._latent_kernel_fits(cfg, bs, None)
+    one = jax.sharding.SingleDeviceSharding(v5e_2x2[0])
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)  # noqa: E731
+    on_chip = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
+    params = on_chip(jax.eval_shape(lambda k: mla_moe.draw_params(k, cfg), jax.random.key(0)))
+    pool = on_chip(jax.eval_shape(lambda: mla_moe.init_pool(cfg, N, bs)))
+    compiled = jax.jit(
+        functools.partial(paged.paged_decode, cfg=cfg, block_size=bs), donate_argnums=4
+    ).lower(
+        params, sds((B,), jnp.int32), sds((B,), jnp.int32),
+        sds((B, cfg.max_seq // bs), jnp.int32), pool, live=sds((B,), jnp.bool_),
+    ).compile()
+    assert mosaic_calls(compiled.as_text()).count("paged_latent_decode_attention") == cfg.n_layer
+    mem = compiled.memory_analysis()
+    table_bytes = B * cfg.max_seq * cfg.pool_row_dim * 2
+    assert mem.alias_size_in_bytes >= N * bs * cfg.pool_row_dim * 2 * cfg.n_layer
+    assert mem.temp_size_in_bytes < table_bytes // 2
+    gathered = f"bf16[{B},{cfg.max_seq // bs},{bs},{cfg.pool_row_dim}]"
+    assert gathered not in compiled.as_text()
 
 
 @pytest.mark.parametrize(

@@ -1,6 +1,6 @@
 """The decode attention kernel alone, on the chip, against the gather.
 
-    python tools/paged_attention_chip.py [--chunks 128,256,512]
+    python tools/paged_attention_chip.py [--latent] [--chunks 128,256,512]
 
 Sixteen slots over a pool of Mistral-7B's shapes (16 layers, 2,049 blocks of
 16, 8 KV heads of 128, tables of 2,048 positions): for each mix of live
@@ -8,15 +8,21 @@ lengths, how far ``ops.paged_attention.paged_decode_attention`` lies from
 ``paged._attend_gathered`` on the same operands (the largest absolute
 difference of the outputs summed over the layers, a layer), and the
 microseconds a layer each takes (a scan over the sixteen layers, timed to
-``block_until_ready``). ``--chunks`` sweeps the
-kernel's chunk length. Needs a TPU: the kernel does not lower elsewhere, and
-a time from another backend says nothing (PERF.md section 6, PR 32, holds
-the v5e's readings). The last line of standard output is one JSON list.
+``block_until_ready``). ``--latent`` does the same for the latent arm at
+A.X-K1's shapes (32 slots over a pool of 7 layers, 8,193 blocks of 16 rows of
+640, tables of 4,096 positions, 64 heads, values 512 wide):
+``paged_latent_decode_attention`` against ``paged._attend_latent_gathered``,
+the first mix's lengths drawn from ``reasoning-backlog``'s tables, and each
+row also says what share of the live blocks' bytes' speed (819 GB/s) the
+kernel reached. ``--chunks`` sweeps the kernel's chunk length. Needs a TPU:
+the kernel does not lower elsewhere, and a time from another backend says
+nothing (PERF.md section 6, PR 32 and PR 39, holds the v5e's readings). The last line of standard output is one JSON list.
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -32,48 +38,51 @@ from ray_tpu.models import paged  # noqa: E402
 from ray_tpu.ops import paged_attention  # noqa: E402
 
 B, KH, G, DH, BLOCK, W, L, N = 16, 8, 4, 128, 16, 128, 16, 2049
+# The latent arm's: slots, heads, row and value widths, table, layers, blocks.
+LB, LH, LC, LR, LW, LL, LN = 32, 64, 640, 512, 256, 7, 8193
+HBM_BYTES_A_US = 819e3  # a v5e's, as benchmarks/peaks.json has it
 
 
-def per_layer(attend):
-    """``attend`` over every layer of the pool in one program."""
+def per_layer(attend, layers):
+    """``attend`` over every layer of the pools in one program."""
 
     @jax.jit
-    def run(q, pk, pv, tables, lengths):
-        def body(acc, layer):
-            out = attend(q, pk, pv, layer, tables, lengths)
-            return acc + out.astype(jnp.float32), None
+    def run(q, pools, tables, lengths):
+        out = jax.eval_shape(attend, q, *pools, jnp.int32(0), tables, lengths)
 
-        layers = jnp.arange(L, dtype=jnp.int32)
-        return jax.lax.scan(body, jnp.zeros(q.shape, jnp.float32), layers)[0]
+        def body(acc, layer):
+            return acc + attend(q, *pools, layer, tables, lengths).astype(jnp.float32), None
+
+        return jax.lax.scan(
+            body, jnp.zeros(out.shape, jnp.float32), jnp.arange(layers, dtype=jnp.int32)
+        )[0]
 
     return run
 
 
-def us_a_layer(run, *operands, iters=20) -> float:
+def us_a_layer(run, layers, *operands, iters=20) -> float:
     jax.block_until_ready(run(*operands))  # compiled, outside the timing
     t = time.perf_counter()
     for _ in range(iters):
         out = run(*operands)
     jax.block_until_ready(out)
-    return (time.perf_counter() - t) / iters / L * 1e6
+    return (time.perf_counter() - t) / iters / layers * 1e6
 
 
-def main() -> int:
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--chunks", default=str(paged_attention._CHUNK),
-                    help="comma-separated chunk lengths to sweep")
-    args = ap.parse_args()
-    if jax.default_backend() != "tpu":
-        raise SystemExit("needs a TPU: the kernel's time is a device time")
+def tables_of(rng, slots, width, blocks):
+    return jnp.asarray(
+        np.stack([rng.permutation(np.arange(1, blocks))[:width] for _ in range(slots)]),
+        jnp.int32,
+    )
+
+
+def per_head(rng):
+    """``(operands, mixes, gather, kernel, the name of its chunk, layers,
+    bytes a live block)`` of the arm for keys and values per head."""
     ks = jax.random.split(jax.random.key(0), 3)
     pk = jax.random.normal(ks[0], (L, N, KH, BLOCK, DH), jnp.bfloat16)
     pv = jax.random.normal(ks[1], (L, N, KH, BLOCK, DH), jnp.bfloat16)
     q = jax.random.normal(ks[2], (B, KH, G, DH), jnp.bfloat16)
-    rng = np.random.default_rng(0)
-    tables = jnp.asarray(
-        np.stack([rng.permutation(np.arange(1, N))[:W] for _ in range(B)]),
-        jnp.int32,
-    )
     mixes = {
         "batch-backlog": rng.integers(128, 769, B),  # the mix's live lengths
         "sixteen of 400": np.full(B, 400),
@@ -82,20 +91,75 @@ def main() -> int:
                                  257, 15, 31, 33, 1000, 2047]),
         "tables full": np.full(B, W * BLOCK),
     }
-    gather = per_layer(paged._attend_gathered)
+    return (
+        (q, (pk, pv), tables_of(rng, B, W, N)), mixes, paged._attend_gathered,
+        paged_attention.paged_decode_attention, "_CHUNK", L, 2 * KH * BLOCK * DH * 2,
+    )
+
+
+def latent(rng):
+    """The same of the latent arm. A slot of ``reasoning-backlog`` holds its
+    prompt and as much of its answer as it has got to."""
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "traffic", "reasoning-backlog.json")) as f:
+        mix = json.load(f)
+    ks = jax.random.split(jax.random.key(0), 2)
+    # rows of 640 with zeros behind the 576 of the latent row, as the engine writes them
+    pool = jax.random.normal(ks[0], (LL, LN, BLOCK, LC), jnp.bfloat16).at[..., 576:].set(0)
+    ql = jax.random.normal(ks[1], (LB, LH, LC), jnp.bfloat16) * 0.3
+    drawn = rng.choice(mix["prompt_tokens"], LB) + (
+        rng.random(LB) * rng.choice(mix["output_tokens"], LB)
+    ).astype(int)
+    mixes = {
+        "reasoning-backlog": drawn,
+        "thirty-two of 1930": np.full(LB, 1930),
+        "free slots": np.ones(LB),
+        "block and chunk edges": np.array(
+            [16, 17, 32, 1, 128, 129, 127, 2048, 255, 256, 257, 15, 31, 33, 1000, 2047,
+             511, 512, 513, 1023, 1024, 1025, 4095, 4096, 3000, 3001, 2, 100, 500, 1500,
+             2500, 3500]),
+        "tables full": np.full(LB, LW * BLOCK),
+    }
+    static = dict(value_width=LR, scale=0.1147)
+    return (
+        (ql, (pool,), tables_of(rng, LB, LW, LN)), mixes,
+        functools.partial(paged._attend_latent_gathered, **static),
+        functools.partial(paged_attention.paged_latent_decode_attention, **static),
+        "_LATENT_CHUNK", LL, BLOCK * LC * 2,
+    )
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--latent", action="store_true",
+                    help="the latent arm at A.X-K1's shapes")
+    ap.add_argument("--chunks", default=None,
+                    help="comma-separated chunk lengths to sweep (default: the arm's own)")
+    args = ap.parse_args()
+    if jax.default_backend() != "tpu":
+        raise SystemExit("needs a TPU: the kernel's time is a device time")
+    rng = np.random.default_rng(0)
+    (q, pools, tables), mixes, attend, kernel_of, chunk_name, layers, block_bytes = (
+        latent if args.latent else per_head
+    )(rng)
+    chunks = args.chunks or str(getattr(paged_attention, chunk_name))
+    gather = per_layer(attend, layers)
     rows = []
-    for chunk in map(int, args.chunks.split(",")):
-        paged_attention._CHUNK = chunk
+    for chunk in map(int, chunks.split(",")):
+        setattr(paged_attention, chunk_name, chunk)
         jax.clear_caches()
-        kernel = per_layer(paged_attention.paged_decode_attention)
+        kernel = per_layer(kernel_of, layers)
         for name, lens in mixes.items():
-            operands = (q, pk, pv, tables, jnp.asarray(lens, jnp.int32))
-            diff = jnp.max(jnp.abs(kernel(*operands) - gather(*operands))) / L
+            operands = (q, pools, tables, jnp.asarray(lens, jnp.int32))
+            diff = jnp.max(jnp.abs(kernel(*operands) - gather(*operands))) / layers
+            kernel_us = us_a_layer(kernel, layers, *operands)
+            live_bytes = int((-(-lens // BLOCK)).sum()) * block_bytes
             rows.append({
                 "chunk": chunk, "mix": name, "live_positions": int(lens.sum()),
                 "max_abs_diff": round(float(diff), 5),
-                "kernel_us_a_layer": round(us_a_layer(kernel, *operands), 2),
-                "gather_us_a_layer": round(us_a_layer(gather, *operands), 2),
+                "kernel_us_a_layer": round(kernel_us, 2),
+                "gather_us_a_layer": round(us_a_layer(gather, layers, *operands), 2),
+                "kernel_pct_of_bytes_speed": round(100 * live_bytes / HBM_BYTES_A_US / kernel_us, 1),
             })
             print(json.dumps(rows[-1]), file=sys.stderr, flush=True)
     print(json.dumps(rows))
