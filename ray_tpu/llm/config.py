@@ -48,6 +48,21 @@ class LLMConfig:
     # prefix cache. Either brings programs of its own, and is served
     # without speculative decoding, tensor parallelism and the
     # disaggregated handoff: the engine says so by name at construction.
+    # A family whose layers are of two kinds (some attend a sliding window
+    # only) has a second part of the pool for the window layers and a second
+    # block table a slot. num_kv_blocks keeps meaning the blocks of the
+    # layers that keep every position, and admission reserves those as
+    # above. A block of a window layer is kv_block_size positions of those
+    # layers' keys and values; it is taken just before the program that
+    # writes its first position and given back to the free list, while the
+    # request runs, once no query can see it (a chunk's blocks at a time
+    # during prefill, one every kv_block_size decoded tokens). No setting
+    # sizes that part: a slot holds at most ceil((window + the longest
+    # prefill program's tokens: prefill_chunk_tokens, or the largest
+    # bucket without chunks) / kv_block_size) + 1 of them, and the engine
+    # makes max_slots times that and the scratch block. Such a family is
+    # served without the prefix cache too (the blocks behind the window at
+    # a prefix's end are gone).
     kv_block_size: int = 16
     num_kv_blocks: Optional[int] = None
     # Parallelism: tensor-parallel degree (mesh `tp` axis over local devices)

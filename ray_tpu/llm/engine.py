@@ -40,6 +40,7 @@ Reference parity: the role vLLM's engine plays under ray.llm
 from __future__ import annotations
 
 import dataclasses
+import logging
 import pickle
 import time as _time
 from typing import Optional
@@ -49,7 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.core.config import GLOBAL_CONFIG
-from ray_tpu.llm.block_manager import BlockManager
+from ray_tpu.llm.block_manager import BlockManager, WindowBlocks
 from ray_tpu.llm.config import LLMConfig, SamplingParams
 from ray_tpu.llm.tokenizer import ByteTokenizer
 from ray_tpu.models import latent_moe, paged
@@ -102,6 +103,7 @@ _PREFIX_HIT_RATE = _metrics.Gauge(
     tag_keys=("replica",),
 )
 
+_log = logging.getLogger(__name__)
 _replica_tags_cache: dict | None = None
 
 
@@ -138,7 +140,17 @@ def _why_not(cfg, what: str) -> Optional[str]:
     ``tp``: shard, ``handoff``: export or import the cache) for ``cfg``'s
     family, or None for a family of keys and values per head served through
     ``kv_hooks``."""
-    if paged.has_recurrent_state(cfg):
+    if len(paged.retention(cfg)) > 1:
+        fact = (
+            f"the family {cfg.family!r} keeps a block table per layer kind and "
+            "gives window blocks back while a request runs"
+        )
+        reason = {
+            "spec": "paged_verify reads one table a slot through kv_hooks",
+            "tp": "the family has no sharding rules yet",
+            "handoff": "the handoff exports and imports one pool under one table",
+        }
+    elif paged.has_recurrent_state(cfg):
         fact = f"the family {cfg.family!r} keeps a recurrent state per slot"
         reason = {
             "spec": "rejected tokens cannot be taken back out of it",
@@ -211,6 +223,11 @@ class _Request:
     # the read-back of those logits, where the host waits anyway.
     t_queued: float = 0.0
     pf_open: Optional[tuple] = None
+    # A chunk that was not the prompt's last, of a family whose prefill
+    # packs counters behind its logits: (its output, still on the device,
+    # when its launch returned). Its span is recorded, with the counters,
+    # when the request's next chunk is due: the chunk has run by then.
+    pf_late: Optional[tuple] = None
 
 
 @dataclasses.dataclass
@@ -250,9 +267,14 @@ class LLMEngine:
         # programs: packed ``meta`` operand, ``live`` mask, counters
         # behind the logits, nothing that goes through ``kv_hooks``. It
         # keeps a state per slot: resets at position 0, a scratch row, no
-        # prefix cache. The second implies the first.
+        # prefix cache. The second implies the first. A third fact, asked
+        # the same way: how long each of its layer kinds keeps a position.
+        # Every family but one has one kind, which keeps everything; a second
+        # kind keeps a window, and the slot a second table (below).
         self._own_programs = paged.brings_own_programs(cfg)
         self._slot_state = paged.has_recurrent_state(cfg)
+        kinds = paged.retention(cfg)
+        assert kinds[0] is None and len(kinds) <= 2, kinds
         devices = jax.devices()
         tp = config.tensor_parallelism
         if tp > 1:
@@ -303,10 +325,29 @@ class LLMEngine:
             (B * self._table_width) // 2, self._table_width + 1
         ) + 1  # +1: block 0 is scratch
         self.block_mgr = BlockManager(n)
+        # A slot's tables, a layer kind after another in one row. The blocks
+        # of a kind that keeps a window are not reserved from num_kv_blocks:
+        # WindowBlocks counts what the slots can hold at most (the window and
+        # the longest prefill program looking back on it) and hands them out
+        # and takes them back launch by launch.
+        W = self._table_width
+        self.block_tables = np.zeros((B, len(kinds) * W), np.int32)
+        self._window: Optional[WindowBlocks] = None
+        if len(kinds) > 1:
+            self._window_span = config.prefill_chunk_tokens or max(config.prefill_buckets)
+            self._window = WindowBlocks(
+                kinds[1], paged.window_blocks_a_slot(kinds[1], self._window_span, bs), bs,
+                self.block_tables[:, W:],
+            )
+        # Neither is served from the prefix pool: a hit would need the state,
+        # or the window blocks, at the prefix's end.
+        self._no_prefix = self._slot_state or self._window is not None
         # A family with a recurrent state keeps one row of it a slot
         # (and a scratch row) beside the blocks: max_slots sizes it.
-        self.pool = paged.init_block_pool(cfg, n, bs, B)
-        self.block_tables = np.zeros((B, self._table_width), np.int32)
+        self.pool = paged.init_block_pool(
+            cfg, n, bs, B,
+            window_blocks=self._window.mgr.num_blocks if self._window else None,
+        )
 
         # Functions with names of their own, not functools.partial: a
         # device trace then lists the programs as jit_paged_prefill /
@@ -327,9 +368,10 @@ class LLMEngine:
             # logits, so that they ride the one read-back an admission
             # makes anyway (_take_counters unpacks them).
             def paged_prefill(params, tokens, meta, pool):
-                # meta [3 + W]: length, start, slot, the block table
+                # meta [3 + W a kind]: length, start, slot, the block table(s)
+                table = meta[3:] if len(kinds) == 1 else meta[3:].reshape(len(kinds), W)
                 pool, logits, counts = paged.paged_prefill(
-                    params, tokens, meta[0], meta[1], meta[3:], pool,
+                    params, tokens, meta[0], meta[1], table, pool,
                     cfg=cfg, block_size=bs, slot=meta[2],
                 )
                 return pool, jnp.concatenate(
@@ -358,8 +400,9 @@ class LLMEngine:
             # ride behind the tokens, in the one small array a turn reads.
             tokens = jnp.where(meta[:, 3] > 0, meta[:, 2], prev[: meta.shape[0]])
             if self._own_programs:
+                tables = meta[:, 4:] if len(kinds) == 1 else meta[:, 4:].reshape(-1, len(kinds), W)
                 pool, logits, counts = paged.paged_decode(
-                    params, tokens, meta[:, 0], meta[:, 4:], pool,
+                    params, tokens, meta[:, 0], tables, pool,
                     cfg=cfg, block_size=bs, live=meta[:, 1] > 0,
                 )
                 behind = counts.reshape(-1).astype(jnp.int32)
@@ -425,6 +468,9 @@ class LLMEngine:
             # Prefill and decode programs launched: the device runs them in
             # this order, so a launch's count is its run's place in a trace.
             "programs_launched": 0,
+            # Prompts longer than the largest prefill bucket, cut to their
+            # last tokens at add_request.
+            "prompts_truncated": 0,
         }
         self._decode_arm = (
             "decode_attn_kernel_steps"
@@ -440,15 +486,22 @@ class LLMEngine:
             self.stats["moe_gmm_kernel_steps"] = 0  # each touched expert streamed once
             self.stats["moe_gmm_ragged_steps"] = 0  # jax.lax.ragged_dot
             self._moe_arm = "moe_gmm_kernel_steps" if in_kernel else "moe_gmm_ragged_steps"
-        for part, arr in self.pool.items():  # bytes of each cache part
+        for path, arr in jax.tree_util.tree_flatten_with_path(self.pool)[0]:
+            part = "_".join(str(k.key) for k in path)  # bytes of each cache part
             self.stats[f"cache_bytes_{part}"] = int(arr.nbytes)
         if self._slot_state:
             # Prefills that began a sequence and so began from zero state,
-            # whatever the slot held; admissions that would have looked a
-            # prefix up and could not (a hit needs the state at the
-            # prefix's end: snapshots are not kept).
+            # whatever the slot held.
             self.stats["state_resets"] = 0
+        if self._no_prefix:
+            # Admissions that would have looked a prefix up and could not (a
+            # hit needs the state, or the window layers' blocks, at the
+            # prefix's end: neither is kept).
             self.stats["prefix_cache_bypassed"] = 0
+        if self._window is not None:
+            # Window blocks given back to the free list while their request
+            # still ran.
+            self.stats["window_blocks_released"] = 0
         # Host-side slot state (numpy: mutated per step)
         self.positions = np.zeros(B, np.int32)  # next write position
         self.last_tokens = np.zeros(B, np.int32)
@@ -515,6 +568,13 @@ class LLMEngine:
         )
         max_prompt = max(self.config.prefill_buckets)
         if len(ids) > max_prompt:
+            if not self.stats["prompts_truncated"]:
+                _log.warning(
+                    "prompt of %d tokens cut to its last %d, the largest prefill "
+                    "bucket (counted from here on in stats['prompts_truncated'])",
+                    len(ids), max_prompt,
+                )
+            self.stats["prompts_truncated"] += 1
             ids = ids[-max_prompt:]
         stop = (
             sampling.stop_token
@@ -597,9 +657,10 @@ class LLMEngine:
         """Longest pooled prefix of ``prompt``; returns (entry | None).
         Hits are verified against the stored tokens, so a hash collision
         can never serve another prompt's KV. A family with a recurrent
-        state is never served from the pool: a hit would need the state
+        state, or with layers that keep a window, is never served from the
+        pool: a hit would need the state, or the blocks behind the window,
         at the prefix's end."""
-        if not self.config.enable_prefix_caching or self._slot_state:
+        if not self.config.enable_prefix_caching or self._no_prefix:
             return None
         self.stats["prefix_lookups"] += 1
         chain = self._chain_hashes(prompt)
@@ -614,7 +675,7 @@ class LLMEngine:
     def _insert_prefix(self, prompt: list, blocks: list) -> None:
         """Pool the prompt's longest aligned prefix: take a reference on
         the request's first P/block blocks — sharing, not copying."""
-        if not self.config.enable_prefix_caching or self._slot_state:
+        if not self.config.enable_prefix_caching or self._no_prefix:
             return
         p = self._aligned_prefix_len(len(prompt))
         if p < self.config.prefix_chunk or p > self.config.max_prefix_cache_tokens:
@@ -819,6 +880,21 @@ class LLMEngine:
             rid=req.request_id, **extra,
         )
 
+    def _close_late_span(self, req: _Request) -> None:
+        """Record the span of ``req``'s chunk before this one (``pf_late``):
+        the launch, as for any chunk whose logits nobody samples from, with
+        the counters its program packed behind them."""
+        if req.pf_late is None:
+            return
+        out, t_end = req.pf_late
+        req.pf_late = None
+        self._take_counters(np.asarray(out), req)  # raylint: disable=RL101 -- a chunk launched a turn ago: its counters, for its span
+        phase, t_pf, extra = req.pf_open
+        req.pf_open = None
+        _flightrec.record(
+            "llm", phase, t=t_pf, dur_s=t_end - t_pf, rid=req.request_id, **extra,
+        )
+
     def _take_counters(self, out: np.ndarray, req: _Request) -> np.ndarray:
         """The logits of a prefill whose read-back is ``out``. A family
         that brings its programs packs its counters behind them
@@ -844,6 +920,9 @@ class LLMEngine:
         program goes through here, so here they are counted: the tokens
         given and the rows computed (``toks``' width, the bucket), for
         the engine and for the turn's wave, and the launch's number."""
+        if self._window is not None:
+            self._advance_window(slot, start, start + n)
+            row = self.block_tables[slot]  # with the window kind's entries
         if self._own_programs:
             if self._slot_state and start == 0:
                 # begins from zero state, whatever the slot held
@@ -873,6 +952,14 @@ class LLMEngine:
         self.stats["programs_launched"] += 1
         self.pool, out = self._pg_prefill(*args, self.pool)
         return out
+
+    def _advance_window(self, slot: int, first_query: int, upto: int) -> None:
+        """Before a launch that writes ``slot``'s positions up to ``upto`` and
+        whose first query stands at ``first_query``: the window kind's blocks
+        behind the window go back, those the writes need are taken
+        (``WindowBlocks.advance``), and the slot's table follows."""
+        self._window.advance(slot, first_query, upto)
+        self.stats["window_blocks_released"] = self._window.released
 
     def _admit_handoff(self, req: _Request, slot: int) -> str:
         """Admit a disaggregated handoff: reserve blocks, pull the shipped
@@ -939,7 +1026,7 @@ class LLMEngine:
             return "fallback"
         self.pool = disagg.scatter_into_pool(self, kv, table[:nb_kv])
         req.blocks = table
-        row = np.zeros(self._table_width, np.int32)
+        row = np.zeros(self.block_tables.shape[1], np.int32)
         row[: len(table)] = table
         self.block_tables[slot] = row
         self._take_slot(req, slot)
@@ -1034,6 +1121,19 @@ class LLMEngine:
             )
             req.finished = True
             return None
+        if (
+            self._window is not None and rem > self._window_span
+            and not self._chunks_feasible(P, T)
+        ):
+            # One program over the whole prompt would look back on more
+            # window blocks than a slot is counted to hold.
+            req.error = (
+                f"request {req.request_id}: a prompt of {T} tokens must prefill in "
+                f"chunks of at most {self._window_span} over window layers, and no "
+                f"ladder of prefill_buckets under max_seq holds its chunks"
+            )
+            req.finished = True
+            return None
         if not self.block_mgr.can_alloc(need):
             # Under allocation pressure the prefix pool must give way:
             # its pinned refs can otherwise hold enough blocks that a
@@ -1051,14 +1151,14 @@ class LLMEngine:
             self.block_mgr.incref(shared)
         table = shared + self.block_mgr.alloc(need)
         req.blocks = table
-        row = np.zeros(self._table_width, np.int32)
+        row = np.zeros(self.block_tables.shape[1], np.int32)
         row[: len(table)] = table
         self.block_tables[slot] = row
         if entry is not None:
             self.stats["prefix_hits"] += 1
             self.stats["prefix_tokens_reused"] += P
             self._in_wave()["reused"] += P  # a slot or a launch follows
-        if self._slot_state and self.config.enable_prefix_caching:
+        if self._no_prefix and self.config.enable_prefix_caching:
             self.stats["prefix_cache_bypassed"] += 1  # once an admission
         if self._chunks_feasible(P, T):
             self._begin_chunked_prefill(req, slot, P)
@@ -1158,6 +1258,7 @@ class LLMEngine:
     def _prefill_one_chunk(self, req: _Request):
         """Prefill the next chunk of ``req``'s prompt; returns the chunk's
         last-logits (only the final chunk's are ever sampled)."""
+        self._close_late_span(req)
         T = len(req.prompt)
         start = req.pf_next
         clen = min(self.config.prefill_chunk_tokens, T - start)
@@ -1201,7 +1302,10 @@ class LLMEngine:
         logits = self._prefill_one_chunk(req)
         T = len(req.prompt)
         if req.pf_next < T:
-            self._close_prefill_span(req)  # logits never read: the launch
+            if self._own_programs and req.pf_open is not None:
+                req.pf_late = (logits, _time.monotonic())  # its counters are read later
+            else:
+                self._close_prefill_span(req)  # logits never read: the launch
             return []
         req.prefilling = False
         logits_np = self._take_counters(np.asarray(logits), req)  # raylint: disable=RL101 -- final-chunk sampling: first token sampled host-side from the chunk's last-logits
@@ -1262,6 +1366,8 @@ class LLMEngine:
         if req.slot >= 0:
             self.block_mgr.decref(req.blocks)
             req.blocks = []
+            if self._window is not None:
+                self._window.release(req.slot)
             self.block_tables[req.slot] = 0
             self.positions[req.slot] = 0
             self.last_tokens[req.slot] = 0
@@ -1367,23 +1473,33 @@ class LLMEngine:
             self._no_prev = jnp.zeros(small.shape, jnp.int32)
             if self.mesh is not None:
                 self._no_prev = jax.device_put(self._no_prev, self._replicated)
+        slots = [r.slot for r in rows]
+        at = self.positions.copy()
+        on, ended = [], []
+        if behind is not None:
+            theirs = {r.slot for r in behind.rows if not r.finished}
+            on = sorted(theirs.intersection(slots))
+            at[on] += 1
+            ended = sorted(theirs.difference(slots))
+        if self._window is not None:
+            # The live rows' window tables, as of the position each writes:
+            # a new block every block_size steps, one given back as often.
+            w, bs = self._window, self._block_size
+            here = at[slots]
+            due = (here // bs >= w.hi[slots]) | (np.maximum(here - w.window + 1, 0) // bs > w.lo[slots])
+            for slot in (s for s, d in zip(slots, due) if d):
+                self._advance_window(slot, int(at[slot]), int(at[slot]) + 1)
         meta = np.concatenate(
             [
-                self.positions[:, None], np.zeros_like(self.positions)[:, None],
-                self.last_tokens[:, None], np.ones_like(self.positions)[:, None],
+                at[:, None], np.zeros_like(at)[:, None],
+                self.last_tokens[:, None], np.ones_like(at)[:, None],
                 self.block_tables,
             ],
             axis=1,
         )
-        slots = [r.slot for r in rows]
-        if behind is not None:
-            theirs = {r.slot for r in behind.rows if not r.finished}
-            on = sorted(theirs.intersection(slots))
-            meta[on, 0] += 1
-            meta[on, 3] = 0
-            ended = sorted(theirs.difference(slots))
-            meta[ended] = 0
-            meta[ended, 3] = 1
+        meta[on, 3] = 0
+        meta[ended] = 0
+        meta[ended, 3] = 1
         meta[slots, 1] = 1
         self.stats[self._decode_arm] += 1
         if self._moe_arm:
@@ -1499,6 +1615,27 @@ class LLMEngine:
                 moe = self._model.span_fields(
                     self.model_config, counters, batch, batch,
                     decode=(cur.at, blocks_read * bs),
+                )
+            if self._window is not None:
+                # The rows of keys and values the step's attention needs in
+                # a layer of each kind, those a window layer's program reads
+                # (the blocks from the one that holds the window's first
+                # position; every table whole under the gather), and the
+                # window blocks held against what the slots would hold had
+                # none been given back.
+                w = self._window
+                length = cur.at.astype(np.int64) + 1
+                first = np.maximum(length - w.window, 0) // bs
+                in_place = self._decode_arm == "decode_attn_kernel_steps"
+                moe.update(
+                    kv_rows_full=int(length.sum()),
+                    kv_rows_window=int(np.minimum(length, w.window).sum()),
+                    kv_rows_window_read=(
+                        int(((cur.at // bs) - first + 1).sum()) if in_place
+                        else len(self._slot_req) * self._table_width
+                    ) * bs,
+                    window_blocks_held=w.held_blocks,
+                    blocks_full_retention=w.full_retention_blocks,
                 )
             _flightrec.record(
                 "llm", "llm.decode_dispatch", t=t_dec,

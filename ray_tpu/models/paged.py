@@ -75,12 +75,30 @@ and its layer bodies itself:
   from its slot's; row ``slots`` is scratch, where slots that are free or
   still prefilling step; and a prefix hit would need the state at the
   prefix's end, so such a family is served without the prefix cache.
+  The fourth shape is ``afmoe``'s: keys and values per head in blocks, in
+  *two parts* (``{"full": {"k", "v"}, "window": {"k", "v"}}``, each ``[layers
+  of the kind, blocks of the part, KH, block, Dh]``), because its layers are
+  of two kinds: a full layer keeps every position, a window layer only the
+  last ``sliding_window``. Each part has its own blocks and each slot a table
+  for each (``tables [..., kinds, W]``: entry ``i`` of either is the block of
+  positions ``[i block, (i + 1) block)``; behind the window a window table
+  points at the scratch block, its blocks given back while the request runs).
+  That is the third fact the engine asks (:func:`retention`): *how long each
+  of its layer kinds keeps a position*. A table of one kind serves both where
+  nothing was given back (``tables [..., W]``: a rehearsal, the routers'
+  balance). Decode attends a window layer through the same
+  :func:`decode_attention` with ``window`` given (the kernel's walk from the
+  block that holds ``length - window``, the gather under the same mask), and
+  prefill reads either kind a stretch of the table at a time
+  (:func:`prefill_attention`). A prefix hit would need the window blocks
+  behind the prefix's end, which are gone: served without the prefix cache.
 """
 
 from __future__ import annotations
 
 import functools
 import importlib
+import math
 
 import jax
 import jax.numpy as jnp
@@ -96,6 +114,7 @@ _FAMILIES = {
     "kimi_linear": "ray_tpu.models.kimi_linear",
     "mla_moe": "ray_tpu.models.mla_moe",
     "nemotron_h": "ray_tpu.models.nemotron_h",
+    "afmoe": "ray_tpu.models.afmoe",
 }
 
 
@@ -132,13 +151,34 @@ def has_recurrent_state(cfg) -> bool:
     return getattr(family(cfg), "has_recurrent_state", False)
 
 
-def init_block_pool(cfg, num_blocks: int, block_size: int, slots=None):
+def retention(cfg) -> tuple:
+    """How many positions each of the family's layer kinds keeps, a kind an
+    entry: None for a kind that keeps every position (the one kind of every
+    family but one), a count for a kind that attends the last so many only.
+    The first kind keeps everything. A slot holds a block table a kind
+    (module docstring, the fourth shape)."""
+    kinds = getattr(family(cfg), "retention", None)
+    return (None,) if kinds is None else kinds(cfg)
+
+
+def window_blocks_a_slot(window: int, span: int, block_size: int) -> int:
+    """The most blocks of a window kind that one slot holds at a time: the
+    window and the ``span`` positions of the longest prefill program looking
+    back on it, in blocks, and one for a window that starts inside a block."""
+    return -(-(window + span) // block_size) + 1
+
+
+def init_block_pool(cfg, num_blocks: int, block_size: int, slots=None, window_blocks=None):
     """Zeroed pool pytree {"k","v"}: [L, N, KH, block, Dh] in activation
     dtype. KH is the KV-head count (unexpanded GQA for Llama). A family with
     a state per slot sizes it for ``slots`` sequences (the engine's
-    ``max_slots``) and a scratch row."""
+    ``max_slots``) and a scratch row. ``window_blocks``: the blocks of each
+    window kind's part, from the engine that counted them (None: the family
+    counts them from what its configuration says of the deployment)."""
     mod = family(cfg)
     if not hasattr(mod, "kv_hooks"):
+        if window_blocks is not None:
+            return mod.init_pool(cfg, num_blocks, block_size, slots, window_blocks=window_blocks)
         return mod.init_pool(cfg, num_blocks, block_size, slots)
     shape = (cfg.n_layer, num_blocks, _kv_heads(cfg), block_size, cfg.head_dim)
     return {
@@ -167,10 +207,11 @@ def _write_read(pool_kv, l, bids, offs, new, tables):
     return pool_kv, pool_kv[l, tables]
 
 
-def _attend_gathered(qg, pk, pv, l, tables, lengths):
+def _attend_gathered(qg, pk, pv, l, tables, lengths, window=None):
     """Decode attention by gather: each slot's whole table brought back as
     a dense row [B, KH, S, Dh] and masked to its first ``lengths[b]``
-    positions. ``qg`` [B, KH, group, Dh]; returns the same shape. What
+    positions (with ``window``, the last ``window`` of them). ``qg`` [B, KH,
+    group, Dh]; returns the same shape. What
     :func:`ops.paged_attention.paged_decode_attention` computes from the
     live blocks alone."""
     B, KH, _, Dh = qg.shape
@@ -180,6 +221,8 @@ def _attend_gathered(qg, pk, pv, l, tables, lengths):
     s = jnp.einsum("bkgd,bksd->bkgs", qg, kd).astype(jnp.float32)
     s = s * (1.0 / (Dh**0.5))
     mask = jnp.arange(S)[None, :] < lengths[:, None]  # [B, S]
+    if window is not None:
+        mask &= jnp.arange(S)[None, :] >= lengths[:, None] - window
     s = jnp.where(mask[:, None, None, :], s, -1e30)
     pa = jax.nn.softmax(s, axis=-1).astype(vd.dtype)
     return jnp.einsum("bkgs,bksd->bkgd", pa, vd)
@@ -252,14 +295,87 @@ def _choose(kernel, gather, fits: bool, interpret: bool):
     return functools.partial(jax.lax.platform_dependent, tpu=kernel, default=gather)
 
 
-def decode_attention(cfg, block_size, mesh, interpret):
+def decode_attention(cfg, block_size, mesh, interpret, window=None):
     """The decode step's attention over the scattered pool of keys and
     values per head, ``attend(qg, pk, pv, l, tables, lengths)``: the kernel
-    or the gather, as :func:`_choose` says."""
-    return _choose(
-        paged_attention.paged_decode_attention, _attend_gathered,
-        _kernel_fits(cfg, block_size, mesh), interpret,
-    )
+    or the gather, as :func:`_choose` says. ``window``: for a layer that
+    attends the last ``window`` positions only, either arm under that mask;
+    None leaves both as they were."""
+    kernel, gather = paged_attention.paged_decode_attention, _attend_gathered
+    if window is not None:
+        kernel = functools.partial(kernel, window=window)
+        gather = functools.partial(gather, window=window)
+    return _choose(kernel, gather, _kernel_fits(cfg, block_size, mesh), interpret)
+
+
+# Positions of the table that one step of prefill's running softmax scores,
+# and queries that go through it together: [KH, group, 512, 512] float32 is
+# 50 MB at 48 heads, where a 2,048-token chunk against a table of 18,432 is
+# 7.2 GB a layer.
+KEY_POSITIONS = 512
+
+
+def prefill_attention(
+    q, pk, pv, l, table, pos, n_keys, *, block_size: int, window=None,
+    key_positions: int = KEY_POSITIONS,
+):
+    """Prefill's attention over keys and values per head, a stretch of the
+    table at a time: ``q`` [T, KH, group, Dh] at consecutive positions ``pos``
+    [T] against layer ``l`` of ``pk`` / ``pv`` [L, N, KH, block, Dh], which
+    already hold the queries' own keys and values, read through ``table``
+    [W]; ``n_keys`` (traced) the positions that hold a row by now. The mask is
+    ``column <= position`` and, with ``window``, ``position - column <
+    window``. Each run of ``key_positions`` queries folds the stretches from
+    the one that holds the first column its first query sees (column 0
+    without a window) to the one that holds its last query's own position
+    into a running softmax (float32 maximum, sum and values), as
+    :func:`ray_tpu.models.latent_moe.mla_prefill` does for latent rows: no
+    ``[heads, T, table]`` scores exist, and a window layer reads nothing
+    behind its window, where the table points at the scratch block. Returns
+    [T, KH, group, Dh] in the pool's dtype."""
+    T, KH, G, Dh = q.shape
+    dt = pk.dtype
+    nb = math.gcd(table.shape[0], max(1, key_positions // block_size))  # blocks a step
+    Kb = nb * block_size
+    Qb = Kb if T % Kb == 0 else T  # queries a run
+    scale = Dh**-0.5
+    f32 = jnp.float32
+
+    def attend(q, pos):
+        def step(j, carry):
+            m, s_sum, acc = carry
+            blocks = jax.lax.dynamic_slice_in_dim(table, j * nb, nb)
+            k = pk[l, blocks].transpose(1, 0, 2, 3).reshape(KH, Kb, Dh)
+            v = pv[l, blocks].transpose(1, 0, 2, 3).reshape(KH, Kb, Dh)
+            s = jnp.einsum("tkgd,ksd->kgts", q, k, preferred_element_type=f32) * scale
+            cols = j * Kb + jnp.arange(Kb)
+            seen = cols[None, :] <= pos[:, None]
+            if window is not None:
+                seen &= cols[None, :] > pos[:, None] - window
+            s = jnp.where(seen[None, None], s, -1e30)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            keep = jnp.exp(m - m_new)
+            e = jnp.exp(s - m_new[..., None])
+            acc = acc * keep[..., None] + jnp.einsum(
+                "kgts,ksd->kgtd", e.astype(dt), v, preferred_element_type=f32
+            )
+            return m_new, s_sum * keep + jnp.sum(e, axis=-1), acc
+
+        # A stretch that is masked whole for a row adds exp(0) a column to it
+        # while the row's maximum is still -1e30; the first stretch that
+        # holds a column it sees multiplies that away (exp(-1e30 - m) = 0),
+        # and every row sees its own position, in the run's last stretches.
+        last = jnp.minimum(n_keys - 1, pos[-1]) // Kb
+        first = 0 if window is None else jnp.minimum(jnp.maximum(pos[0] - window + 1, 0) // Kb, last)
+        n = q.shape[0]
+        init = (
+            jnp.full((KH, G, n), -1e30, f32), jnp.zeros((KH, G, n), f32),
+            jnp.zeros((KH, G, n, Dh), f32),
+        )
+        _, s_sum, acc = jax.lax.fori_loop(first, last + 1, step, init)
+        return (acc / s_sum[..., None]).astype(dt).transpose(2, 0, 1, 3)
+
+    return jnp.concatenate([attend(q[i : i + Qb], pos[i : i + Qb]) for i in range(0, T, Qb)])
 
 
 def latent_decode_attention(cfg, block_size, mesh, interpret, scale: float):

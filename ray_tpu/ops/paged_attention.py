@@ -26,6 +26,13 @@ block serves both: a single "KV head" whose group is every query head.
 Matmuls run on the MXU in the pool's dtype with float32 accumulation;
 scores, softmax statistics and the accumulator are float32; the mask is the
 length (``col < length``, the gather path's ``col <= position``).
+
+**A walk with a lower bound.** A layer that keeps only the last ``window``
+positions (``window`` static, given to :func:`paged_decode_attention`) attends
+columns ``length - window <= col < length``: a slot's walk then starts at the
+block that holds ``length - window`` and the table's entries before it are
+never read, so the engine may have given those blocks back. With no window the
+kernel is traced as it was: the bound exists in the program only where asked.
 """
 
 from __future__ import annotations
@@ -97,13 +104,14 @@ def _kernel(
     layer_ref, lengths_ref, tables_ref,  # scalar prefetch (SMEM)
     q_ref,  # [1, KH, G, Dh] VMEM
     *refs,  # the pools, o_ref, a chunk buffer for each pool, sems, parity
-    block, pages, width, scale,
+    block, pages, width, scale, window=None,
 ):
     """``refs``: the pools ``[L, N, KH, block, Dh]`` left in HBM, keys then
     values, or one whose rows are the keys and, in their first lanes, the
     values; ``o_ref`` [1, KH, G, Dv] VMEM; for each pool its chunk buffer
     [2, KH, pages * block, Dh] VMEM; DMA semaphores [pools, 2 (buffer)];
-    SMEM [1]: the buffer the slot's first chunk was copied to."""
+    SMEM [1]: the buffer the slot's first chunk was copied to. ``window``: the
+    positions a slot's query sees, its own included (None: all of them)."""
     n = (len(refs) - 3) // 2
     pools, o_ref, bufs = refs[:n], refs[n], refs[n + 1 : 2 * n + 1]
     sems, parity = refs[2 * n + 1 :]
@@ -113,14 +121,22 @@ def _kernel(
     layer = layer_ref[0]
     chunk = pages * block
 
+    def first_page(slot):
+        """The table entry a slot's walk starts at: the block that holds the
+        first position its window keeps."""
+        return jnp.maximum(lengths_ref[slot] - window, 0) // block
+
     def live_pages(slot):
-        return (lengths_ref[slot] + block - 1) // block
+        """Blocks the slot's walk covers, from its first page on."""
+        end = (lengths_ref[slot] + block - 1) // block
+        return end if window is None else end - first_page(slot)
 
     def copies(slot, i, buf, j):
         """The copies of live block ``j`` of the slot's chunk ``i``, one a
         pool: every KV head of one block is contiguous in the pool, and
         lands strided, at its positions of each head's row of the buffer."""
-        page = tables_ref[slot * width + i * pages + j]
+        entry = i * pages + j if window is None else first_page(slot) + i * pages + j
+        page = tables_ref[slot * width + entry]
         rows = pl.ds(pl.multiple_of(j * block, block), block)
         return [
             pltpu.make_async_copy(
@@ -177,7 +193,12 @@ def _kernel(
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         ) * (scale * _LOG2E)  # [KH, G, chunk] f32, base-2
-        s = jnp.where(i * chunk + cols < length, s, _NEG_INF)
+        if window is None:
+            seen = i * chunk + cols < length
+        else:
+            col = first_page(b) * block + i * chunk + cols
+            seen = (col < length) & (col >= length - window)
+        s = jnp.where(seen, s, _NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp2(s - m_new)
         alpha = jnp.exp2(m - m_new)
@@ -196,7 +217,9 @@ def _kernel(
     parity[0] = (first + n_chunks) % 2
 
 
-def _attend(q, pools, layer, tables, lengths, *, value_width, scale, chunk, interpret, name):
+def _attend(
+    q, pools, layer, tables, lengths, *, value_width, scale, chunk, interpret, name, window=None
+):
     """The call both entries make: ``q`` [B, KH, G, Dh] against ``pools``
     (each [L, N, KH, block, Dh]; the last one's first ``value_width`` lanes
     are the values), ``chunk`` positions a fold; [B, KH, G, value_width]."""
@@ -205,10 +228,11 @@ def _attend(q, pools, layer, tables, lengths, *, value_width, scale, chunk, inte
     pages = _pages(block, chunk)
     dtype = pools[0].dtype
     anywhere = pl.BlockSpec(memory_space=pl.ANY)
+    static = dict(block=block, pages=pages, width=tables.shape[1], scale=scale)
+    if window is not None:
+        static["window"] = window
     return pl.pallas_call(
-        functools.partial(
-            _kernel, block=block, pages=pages, width=tables.shape[1], scale=scale
-        ),
+        functools.partial(_kernel, **static),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(B,),
@@ -237,7 +261,7 @@ def _attend(q, pools, layer, tables, lengths, *, value_width, scale, chunk, inte
     )
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "window"))
 def paged_decode_attention(
     q: jax.Array,  # [B, KH, group, Dh] — one query position a slot
     pool_k: jax.Array,  # [L, N, KH, block, Dh]
@@ -247,11 +271,13 @@ def paged_decode_attention(
     lengths: jax.Array,  # [B] int32, >= 1 — positions attended, a slot
     *,
     interpret: bool = False,
+    window: int | None = None,  # of them, only the last ``window``
 ) -> jax.Array:
     """softmax(q k^T / sqrt(Dh)) v over each slot's first ``lengths[b]``
-    positions of its table's blocks in layer ``layer``; [B, KH, group, Dh]
-    in the pool's dtype. Table entries past a slot's live blocks are never
-    read."""
+    positions of its table's blocks in layer ``layer`` (with ``window``, the
+    last ``window`` of them); [B, KH, group, Dh] in the pool's dtype. Table
+    entries past a slot's live blocks, and before the block that holds the
+    window's first position, are never read."""
     G, Dh = q.shape[2:]
     pad = -G % _GROUP_TILE
     if pad:
@@ -259,7 +285,7 @@ def paged_decode_attention(
     out = _attend(
         q, (pool_k, pool_v), layer, tables, lengths, value_width=Dh,
         scale=Dh**-0.5, chunk=_CHUNK, interpret=interpret,
-        name="paged_decode_attention",
+        name="paged_decode_attention", window=window,
     )
     return out[:, :, :G]
 

@@ -1,0 +1,224 @@
+"""The ``afmoe`` family through ``LLMEngine``: two kinds of layer under a block
+table each, the window kind's blocks given back while requests run, at a tiny
+size on the CPU (a window of 8 over blocks of 4, chunks of 8, contexts of 40
+and more). Logits against the plain reference's full forward; the bound on a
+slot's window blocks and the conservation of the free list; what the engine
+refuses for the family, by name and for its own reason; the prefix bypass; its
+spans and counters.
+"""
+
+import os
+import sys
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmarks.reference import afmoe_ref as ref  # noqa: E402
+from ray_tpu.core.config import GLOBAL_CONFIG  # noqa: E402
+from ray_tpu.llm import LLMConfig, LLMEngine, SamplingParams  # noqa: E402
+from ray_tpu.models import afmoe, paged  # noqa: E402
+from ray_tpu.util import flightrec  # noqa: E402
+from test_afmoe import ref_config  # noqa: E402
+
+pytestmark = pytest.mark.timeout(300)
+BLOCK, CHUNK = 4, 8
+
+
+def llm_config(**kw):
+    return LLMConfig(**{
+        "model_config": afmoe.AfmoeConfig.tiny(max_seq=128), "max_slots": 3, "max_seq": 128,
+        "prefill_buckets": (8, 16, 64, 128), "prefill_chunk_tokens": CHUNK, "kv_block_size": BLOCK,
+        "num_kv_blocks": 3 * 32 + 1, "prefix_chunk": 16, "seed": 0, **kw,
+    })
+
+
+def prompts(lengths, seed=1):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(3, 500, size=n).tolist() for n in lengths]
+
+
+@pytest.fixture(scope="module")
+def engine():
+    return LLMEngine(llm_config())
+
+
+def run(engine, ps, answers, after_step=None):
+    """The requests to their ends, the logits the engine samples from noted
+    by request; ``after_step`` is called after every step."""
+    seen: dict = {}
+    sample = engine._sample
+
+    def recording(logits, req):
+        seen.setdefault(req.request_id, []).append(np.array(logits))
+        return sample(logits, req)
+
+    engine._sample = recording
+    try:
+        ids = [f"t{engine._steps}-{i}" for i in range(len(ps))]
+        for rid, p, n in zip(ids, ps, answers):
+            engine.add_request(rid, p, SamplingParams(max_tokens=n, stop_token=-1))
+        while engine.has_unfinished():
+            engine.step()
+            if after_step is not None:
+                after_step()
+        done = {r.request_id: r for r in engine.pop_finished()}
+    finally:
+        engine._sample = sample
+    return [done[rid] for rid in ids], [np.stack(seen[rid]) for rid in ids]
+
+
+def test_short_and_long_requests_in_chunks_are_the_reference_and_the_window_stays_bounded(engine):
+    """Five requests of 7 to 60 tokens over three slots, the long ones
+    prefilled in chunks of 8 between the others' decode steps: every logits
+    row the engine samples from is the reference's (2e-4, float32 on both
+    sides), though blocks one slot gave back were written by another meanwhile;
+    no slot ever holds more than ceil((8 + 8) / 4) + 1 window blocks; and the
+    window part's free list is conserved at every step."""
+    w = engine._window
+    assert w.per_slot == 5 and w.mgr.num_blocks == 3 * 5 + 1
+    assert engine.pool["window"]["k"].shape[1] == w.mgr.num_blocks
+    assert engine.pool["full"]["k"].shape[1] == 3 * 32 + 1
+    held_most, owners = [0], {}
+
+    def check():
+        held = [len(h) for h in w._held]
+        held_most[0] = max(held_most[0], *held)
+        assert w.mgr.free_blocks + sum(held) == w.mgr.num_blocks - 1
+        assert sorted(b for h in w._held for b in h) == sorted(set(b for h in w._held for b in h))
+        for slot, h in enumerate(w._held):
+            for b in h:
+                owners.setdefault(b, set()).add(engine._slot_req[slot].request_id)
+            lo, hi = int(w.lo[slot]), int(w.hi[slot])
+            assert list(w.tables[slot, lo:hi]) == h and not w.tables[slot, :lo].any()
+
+    lens, answers = [50, 7, 33, 60, 9], [20, 30, 10, 5, 9]
+    ps = prompts(lens)
+    released = engine.stats["window_blocks_released"]
+    done, logits = run(engine, ps, answers, check)
+    assert 3 <= held_most[0] <= w.per_slot
+    assert engine.stats["window_blocks_released"] - released >= 40
+    assert max(len(o) for o in owners.values()) >= 2  # a block one request gave back, another held
+    assert w.mgr.free_blocks == w.mgr.num_blocks - 1 and not w.tables.any()
+    c = ref_config(engine.model_config)
+    for p, r, got in zip(ps, done, logits):
+        assert r.error is None and len(r.generated) == len(got)
+        toks = jnp.asarray(p + r.generated, jnp.int32)
+        want = ref.forward(engine.params, toks, c)
+        np.testing.assert_allclose(got, want[len(p) - 1 : len(p) - 1 + len(got)], rtol=2e-4, atol=2e-6)
+    assert engine.stats["prompts_truncated"] == 0 and engine.stats["prefill_chunks"] > 0
+
+
+def test_greedy_tokens_run_ahead_and_do_not_depend_on_company(engine):
+    """Without a replaced ``_sample`` the engine runs ahead (a decode step in
+    flight while the window tables move): the tokens of a request alone are
+    its tokens among others."""
+    ps = prompts([41, 12, 30], seed=3)
+    sampling = SamplingParams(max_tokens=24, stop_token=-1)
+    alone = [engine.generate([p], sampling)[0]["token_ids"] for p in ps]
+    ahead = engine.stats["decode_steps_ahead"]
+    together = [o["token_ids"] for o in engine.generate(ps, sampling)]
+    assert together == alone
+    assert engine.stats["decode_steps_ahead"] > ahead
+
+
+@pytest.mark.parametrize("what, kw, match", [
+    ("speculative verification", {"spec_decode_tokens": 2}, "spec_decode_tokens"),
+    ("tensor parallelism", {"tensor_parallelism": 2}, "tensor_parallelism"),
+    ("the disaggregated export", "prefill_only", "prefill_only"),
+    ("the disaggregated import", "handoff", "handoff"),
+])
+def test_what_the_engine_cannot_do_for_this_family_is_said_with_its_own_reason(engine, what, kw, match):
+    if isinstance(kw, dict):
+        with pytest.raises(ValueError, match=match) as e:
+            LLMEngine(llm_config(**kw))
+    elif kw == "prefill_only":
+        with pytest.raises(ValueError, match=match) as e:
+            engine.add_request("x", [1, 2, 3], prefill_only=True)
+    else:
+        with pytest.raises(ValueError, match=match) as e:
+            engine.add_handoff_request("x", {"prompt": [1, 2, 3]})
+    assert "'afmoe' keeps a block table per layer kind" in str(e.value)
+    assert "recurrent state" not in str(e.value)
+
+
+def test_a_repeated_prompt_bypasses_the_prefix_cache_and_is_counted():
+    """A prefix hit would need the window blocks behind the prefix's end,
+    which are gone: the lookup is bypassed, counted, and the second request
+    prefills whole and answers as the first."""
+    eng = LLMEngine(llm_config())
+    assert eng.config.enable_prefix_caching
+    p = prompts([40], seed=4)[0]
+    first, second = (eng.generate([p], SamplingParams(max_tokens=4))[0]["token_ids"] for _ in range(2))
+    assert first == second
+    assert eng.stats["prefix_cache_bypassed"] == 2 and eng.stats["prefix_lookups"] == 0
+    assert eng.stats["prefix_tokens_reused"] == 0 and eng.stats["prefill_tokens"] == 80
+    assert not eng._prefix_pool
+
+
+def test_a_prompt_longer_than_the_largest_bucket_is_cut_counted_and_logged_once(caplog):
+    eng = LLMEngine(llm_config(prefill_buckets=(8, 16, 32), max_seq=64, num_kv_blocks=49))
+    with caplog.at_level("WARNING", logger="ray_tpu.llm.engine"):
+        for i, p in enumerate(prompts([40, 33, 20], seed=5)):
+            eng.add_request(f"r{i}", p, SamplingParams(max_tokens=2))
+    assert eng.stats["prompts_truncated"] == 2
+    assert [len(r.prompt) for r in eng.requests.values()] == [32, 32, 20]
+    assert sum("cut to its last 32" in r.message for r in caplog.records) == 1
+
+
+def test_a_prompt_that_cannot_prefill_in_chunks_is_refused_not_run_whole():
+    """With chunking off the longest program is the largest bucket and the
+    window part is sized for it; with a ladder that cannot hold a chunk
+    under ``max_seq`` the request ends with an error and the engine goes on."""
+    eng = LLMEngine(llm_config(prefill_chunk_tokens=0))
+    assert eng._window.per_slot == -(-(8 + 128) // BLOCK) + 1
+    eng = LLMEngine(llm_config(prefill_buckets=(8, 128), max_seq=128))
+    ps = prompts([100, 12], seed=6)  # 100 = 12 chunks of 8 and one of 4, all in the 8 bucket
+    outs = eng.generate(ps, SamplingParams(max_tokens=3))
+    assert [o["error"] for o in outs] == [None, None]
+    eng = LLMEngine(llm_config(prefill_buckets=(16, 128), max_seq=128))
+    eng.add_request("long", prompts([125], seed=7)[0], SamplingParams(max_tokens=2))
+    eng.add_request("short", prompts([10], seed=8)[0], SamplingParams(max_tokens=2))
+    while eng.has_unfinished():
+        eng.step()
+    done = {r.request_id: r for r in eng.pop_finished()}
+    assert "must prefill in chunks" in done["long"].error and done["short"].error is None
+
+
+def test_spans_carry_the_rows_by_kind_the_window_blocks_and_the_chunks_counters(engine):
+    saved = GLOBAL_CONFIG.flightrec
+    GLOBAL_CONFIG.flightrec = True
+    flightrec.reset()
+    try:
+        ps = prompts([45, 10, 6], seed=9)  # two in chunks, one whole
+        engine.generate(ps, SamplingParams(max_tokens=12, stop_token=-1))
+        events = sorted(  # a chunk's span is recorded a turn late, with its counters: by start
+            (e for r in flightrec.snapshot(planes=("llm",))["rings"].values() for e in r["events"]),
+            key=lambda e: e["t"],
+        )
+    finally:
+        GLOBAL_CONFIG.flightrec = saved
+        flightrec.reset()
+    steps = [e["extra"] for e in events if e["phase"] == "llm.decode_step"]
+    assert steps and all(
+        {"kv_rows_full", "kv_rows_window", "kv_rows_window_read", "window_blocks_held",
+         "blocks_full_retention", "experts_touched", "picks_here"} <= set(s) for s in steps
+    )
+    both = [s for s in steps if s["batch"] == 2]
+    assert both
+    for s in both:
+        assert s["kv_rows_window"] <= s["kv_rows_full"] and s["kv_rows_window"] <= 2 * 8
+        # the gather reads every table whole on the CPU
+        assert s["kv_rows_window_read"] == 3 * 32 * BLOCK
+        assert 0 < s["window_blocks_held"] <= s["blocks_full_retention"]
+    assert any(s["window_blocks_held"] < s["blocks_full_retention"] for s in steps)  # the long one gave blocks back
+    chunks = [e["extra"] for e in events if e["phase"] == "llm.prefill_chunk"]
+    assert sorted({c["start"] for c in chunks if c["tokens"] == CHUNK}) == [0, 8, 16, 24, 32]
+    assert [(c["start"], c["tokens"]) for c in chunks][-1] == (40, 5)
+    assert all("experts_touched" in c and "picks_here" in c for c in chunks)
+    whole = [e["extra"] for e in events if e["phase"] == "llm.prefill"]
+    assert whole and all("experts_touched" in x for x in whole)
+    assert paged.retention(engine.model_config) == (None, 8)

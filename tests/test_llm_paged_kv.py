@@ -245,6 +245,68 @@ def test_the_decode_kernel_reads_no_block_past_a_slots_live_ones():
     )
 
 
+# -- the walk with a lower bound (a layer that keeps a window) -------------------
+#
+# The same kernel given ``window``: a slot attends columns ``length - window <=
+# col < length`` and its walk starts at the block that holds the first of them.
+# Blocks of 8, a window of 16: lengths under, at and over the window, on block
+# edges and off them; the table's entries behind the window point at the
+# scratch block, as the engine leaves them, which holds NaN here and which the
+# windowed kernel must never copy (the free slot of every case sits on it).
+
+KEPT = 16
+WINDOW_CASES = {  # positions a slot
+    "under the window": [5, 14, 0, 0],
+    "at the window": [15, 16, 15, 0],  # lengths 16 and 17: the first to drop a column
+    "over the window, off block edges": [29, 21, 38, 0],
+    "the window's first column on a block's first row": [23, 31, 39, 0],  # (p + 1 - 16) % 8 == 0
+    "the window's first column on a block's last row": [22, 30, 38, 0],  # (p + 1 - 16) % 8 == 7
+    "free slots on the scratch block": [33, 0, 0, 0],
+}
+
+
+@pytest.mark.parametrize("case", WINDOW_CASES)
+@pytest.mark.parametrize("family", ["gpt2", "llama"])  # group 1, group 2
+def test_the_windowed_kernel_attends_what_the_windowed_gather_attends(family, case):
+    from ray_tpu.ops.paged_attention import paged_decode_attention
+
+    q, pk, pv = _kernel_operands(family)
+    positions = np.asarray(WINDOW_CASES[case])
+    lengths = jnp.asarray(positions, jnp.int32) + 1
+    tables = jnp.asarray(KERNEL_TABLES)
+    want = paged._attend_gathered(q, pk, pv, 1, tables, lengths, window=KEPT)
+    # behind the window: given back, so the table points at the scratch block
+    behind = KERNEL_TABLES.copy()
+    for b, p in enumerate(positions):
+        behind[b, : max(p + 1 - KEPT, 0) // BLOCK] = 0
+    poisoned = pk.at[:, 0].set(jnp.nan), pv.at[:, 0].set(jnp.nan)
+    got = paged_decode_attention(
+        q, *poisoned, jnp.int32(1), jnp.asarray(behind), lengths, interpret=True, window=KEPT
+    )
+    live = positions > 0  # the others are free slots, on the scratch block
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live], **TOL)
+    over = positions + 1 > KEPT
+    unwindowed = np.asarray(paged._attend_gathered(q, pk, pv, 1, tables, lengths))
+    # the mask is the window's: slots past it differ from the unwindowed gather, the others do not
+    assert all(
+        (np.abs(unwindowed[b] - np.asarray(got)[b]).max() > 1e-3) == over[b] for b in np.flatnonzero(live)
+    )
+
+
+def test_with_no_window_the_kernel_is_traced_as_it_was():
+    """The lower bound exists in the program only where asked: without a
+    window the kernel's jaxpr has no trace of it."""
+    from ray_tpu.ops import paged_attention
+
+    q, pk, pv = _kernel_operands("llama")
+    args = (q, pk, pv, jnp.int32(0), jnp.asarray(KERNEL_TABLES), jnp.asarray([30, 4, 19, 1], jnp.int32))
+    plain = str(jax.make_jaxpr(functools.partial(paged_attention.paged_decode_attention, interpret=True))(*args))
+    windowed = str(jax.make_jaxpr(
+        functools.partial(paged_attention.paged_decode_attention, interpret=True, window=KEPT)
+    )(*args))
+    assert plain != windowed and "window" not in plain
+
+
 # -- the latent arm of the kernel against its gather ------------------------------
 #
 # A pool of latent rows [L, N, block, C], one row a position for all heads:

@@ -181,6 +181,28 @@ def test_the_decode_attention_kernel_compiles_at_served_widths(
     assert compiled.memory_analysis().temp_size_in_bytes < 2**20
 
 
+def test_the_windowed_decode_attention_kernel_compiles_at_served_widths(v5e_2x2):
+    """The same kernel with its walk's lower bound, as Trinity's sliding
+    layers call it: twenty-four slots, six query heads a key/value head,
+    tables of 18,432 positions over a four-layer part of the pool, a window
+    of 4,096."""
+    import functools
+
+    from ray_tpu.ops import paged_attention
+
+    one = jax.sharding.SingleDeviceSharding(v5e_2x2[0])
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)  # noqa: E731
+    pool = sds((4, 24 * 385 + 1, 8, 16, 128), jnp.bfloat16)
+    compiled = jax.jit(
+        functools.partial(paged_attention.paged_decode_attention, window=4096)
+    ).lower(
+        sds((24, 8, 6, 128), jnp.bfloat16), pool, pool,
+        sds((), jnp.int32), sds((24, 1152), jnp.int32), sds((24,), jnp.int32),
+    ).compile()
+    assert compiled.as_text().count(MOSAIC) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
 def test_the_latent_attention_kernel_compiles_at_served_widths(v5e_2x2):
     """The latent arm of the block-walking kernel at the widths A.X-K1 is
     served at (32 slots, a pool of 7 layers of 8,193 blocks of 16 rows of 640
