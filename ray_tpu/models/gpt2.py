@@ -50,11 +50,13 @@ class GPT2Config:
     dtype: Any = jnp.bfloat16  # activation dtype
     param_dtype: Any = jnp.float32
     attn_impl: str = "auto"  # "auto" | "pallas" | "reference"
-    # Flash kernel block sizes. Bigger blocks amortize per-program switch
-    # cost (measured best at S=1024 on v5e: 512x512); clamped to S at
-    # dispatch.
-    attn_block_q: int = 512
-    attn_block_k: int = 512
+    # Flash kernel block sizes, fitted to S at dispatch
+    # (ops/attention.py:_fit_block). Equal blocks have their diagonal tile
+    # cut into row groups; on a v5e at S=1024, D=64 one block of 1024 a head
+    # takes 417 us a call forward+backward where two of 512 take 554 and two
+    # of 512 scored whole 671 (PERF.md section 6, PR 41).
+    attn_block_q: int = 1024
+    attn_block_k: int = 1024
     # Rematerialization policy for the per-layer scan:
     #   "full"  — recompute the whole block in backward (min memory, +FLOPs)
     #   "dots"  — save weight-matmul outputs, recompute attention/gelu/norms

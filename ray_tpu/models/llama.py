@@ -49,8 +49,8 @@ class LlamaConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     attn_impl: str = "auto"
-    attn_block_q: int = 512
-    attn_block_k: int = 512
+    attn_block_q: int = 1024  # as GPT2Config's (a head of 128: 259 us against 424)
+    attn_block_k: int = 1024
     remat: str = "mlp"  # same policy ladder as GPT2Config.remat
     loss_chunk: int = 128
     pipeline_microbatches: int = 0

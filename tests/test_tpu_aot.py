@@ -56,13 +56,24 @@ def v5e_2x2():
     return devices
 
 
-def test_flash_kernels_compile_on_one_chip(v5e_2x2):
+@pytest.mark.parametrize(
+    "shape, block",
+    [
+        ((8, 12, 1024, 64), 512),  # two blocks a head, each diagonal tile in two groups
+        ((2, 25, 1024, 64), 1024),  # the shard of GPT-2 XL under fsdp=4: one block a head
+        ((2, 8, 4096, 64), 1024),  # the backward's full-length operands past the default VMEM scope
+        ((2, 8, 1024, 128), 1024),  # Llama's head
+    ],
+    ids=["S1024-block512", "gpt2xl-shard", "S4096", "head128"],
+)
+def test_flash_kernels_compile_on_one_chip(v5e_2x2, shape, block):
     one = NamedSharding(Mesh(np.array(v5e_2x2[:1]), ("x",)), P())
-    q = jax.ShapeDtypeStruct((8, 12, 1024, 64), jnp.bfloat16, sharding=one)
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one)
+    assert attention.diag_group(block, block) == block // 2
 
     def fwd(q, k, v):
         return attention.causal_attention(
-            q, k, v, impl="pallas", block_q=512, block_k=512
+            q, k, v, impl="pallas", block_q=block, block_k=block
         )
 
     def loss(q, k, v):

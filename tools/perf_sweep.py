@@ -1,7 +1,9 @@
 """Perf sweep for the GPT-2 train step on the local chip.
 
-Measures ms/step and tokens/s/chip for combinations of batch size, remat
-policy, and flash-attention block sizes, plus standalone kernel timings.
+Measures ms/step and tokens/s/chip for combinations of batch size and remat
+policy, and the two flash-attention kernels alone (device microseconds a
+call from the profiler's trace, by block sizes and by the rows of a diagonal
+tile's groups, at the shard shape of `train-gpt2xl-fsdp4` and four others).
 Usage:
     python tools/perf_sweep.py            # full sweep
     python tools/perf_sweep.py step       # train-step sweep only
@@ -22,7 +24,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.models import gpt2
-from ray_tpu.ops.attention import causal_attention
 from ray_tpu.parallel import (
     DEFAULT_RULES,
     MeshSpec,
@@ -36,68 +37,119 @@ from ray_tpu.train.spmd import (
 )
 
 
-def _time_chained(fn, carry, *args, iters_a=8, iters_b=40):
-    """Time fn(carry, *args) -> carry with a serial data dependency.
+# (B, H, S, D): the shard of `train-gpt2xl-fsdp4` (fsdp=4: 2 of 8 sequences,
+# 25 heads), GPT-2-125M at 16 a chip, two longer sequences, a head of 128
+# (Llama).
+ATTN_SHAPES = (
+    (2, 25, 1024, 64), (16, 12, 1024, 64), (2, 12, 2048, 64), (2, 12, 4096, 64),
+    (2, 16, 1024, 128),
+)
+ATTN_BLOCKS = (256, 512, 1024)
+ATTN_GROUPS = (None, 512, 256, 128)  # None: a straddling tile scored whole
+PEAK_FLOPS, PEAK_BYTES = 197e12, 819e9  # a v5e's, as benchmarks/peaks.json has it
+ATTN_CALLS = 16
 
-    Every iteration consumes the previous output, and timing runs at two
-    iteration counts and reports the slope, which cancels whatever constant
-    cost the dispatch and the final wait add.
-    """
-    c = carry
-    for _ in range(3):
-        c = fn(c, *args)
-    jax.block_until_ready(c)
 
-    def run(n):
-        nonlocal c
-        t0 = time.perf_counter()
-        for _ in range(n):
-            c = fn(c, *args)
-        jax.block_until_ready(c)
-        return time.perf_counter() - t0
+def attn_least_us(B, H, S, D):
+    """The least a v5e could take for the forward and for the backward
+    kernel, as benchmarks/layer_metrics/flash_roofline_pct.py reckons it."""
+    fwd_ops = B * H * 2 * 2 * D * S * (S + 1) / 2
+    tensor, row = B * H * S * D * 2, B * H * S * 4
+    return tuple(
+        1e6 * max(ops / PEAK_FLOPS, nbytes / PEAK_BYTES)
+        for ops, nbytes in (
+            (fwd_ops, 4 * tensor + row), (2 * fwd_ops, 7 * tensor + 2 * row)
+        )
+    )
 
-    t_a = run(iters_a)
-    t_b = run(iters_b)
-    return (t_b - t_a) / (iters_b - iters_a)
+
+def kernel_us(step, args, log_dir):
+    """Mean device microseconds of one flash_fwd and one flash_bwd call over
+    ATTN_CALLS runs of ``step``, from the profiler's trace (what
+    flash_roofline_pct reads), and the host's clock over the same runs."""
+    from benchmarks import trace_reduce
+
+    jax.block_until_ready(step(*args))
+    jax.profiler.start_trace(log_dir)
+    t0 = time.perf_counter()
+    q = args[0]
+    for _ in range(ATTN_CALLS):
+        q = step(q, *args[1:])
+    jax.block_until_ready(q)
+    host_us = (time.perf_counter() - t0) / ATTN_CALLS * 1e6
+    jax.profiler.stop_trace()
+    trace = trace_reduce.plain_from_xplane(trace_reduce.find_xplane(log_dir))
+    took = {"flash_fwd": [], "flash_bwd": []}
+    for plane in trace["planes"]:
+        if plane["name"] != "/device:TPU:0":
+            continue
+        for line in plane["lines"]:
+            if line["name"] != trace_reduce.OPS_LINE:
+                continue
+            for name, _, dur_ns in line["events"]:
+                for kernel in took:
+                    if name.startswith(kernel):
+                        took[kernel].append(dur_ns / 1e3)
+    if not (took["flash_fwd"] and took["flash_bwd"]):
+        raise SystemExit("no flash_fwd / flash_bwd operation on /device:TPU:0: needs a TPU")
+    return (
+        sum(took["flash_fwd"]) / len(took["flash_fwd"]),
+        sum(took["flash_bwd"]) / len(took["flash_bwd"]),
+        host_us,
+    )
 
 
 def sweep_attention():
-    print("== flash attention kernel sweep (B=16, H=12, S=1024, D=64) ==")
-    B, H, S, D = 16, 12, 1024, 64
-    ks = jax.random.split(jax.random.key(0), 4)
-    q, k, v = (
-        jax.random.normal(kk, (B, H, S, D), jnp.bfloat16) for kk in ks[:3]
-    )
+    """The two flash kernels alone: microseconds a call by the device trace,
+    the share of the roofline time, and score pairs computed over needed,
+    by block sizes and by the rows of a diagonal tile's groups."""
+    import shutil
+    import tempfile
 
-    def fwd_chain(impl, bq, bk):
-        # Chain the output back into q: a serial dependency.
-        return jax.jit(
-            lambda q, k, v: causal_attention(
-                q, k, v, impl=impl, block_q=bq, block_k=bk
-            )
+    from ray_tpu.ops import attention
+
+    for B, H, S, D in ATTN_SHAPES:
+        least_f, least_b = attn_least_us(B, H, S, D)
+        print(
+            f"== flash kernels B={B} H={H} S={S} D={D}: least fwd {least_f:.1f} us, "
+            f"bwd {least_b:.1f} us (* the group the kernels choose) =="
         )
+        ks = jax.random.split(jax.random.key(0), 3)
+        q, k, v = (jax.random.normal(kk, (B, H, S, D), jnp.bfloat16) for kk in ks)
+        for bq in ATTN_BLOCKS:
+            for bk in ATTN_BLOCKS:
+                for group in ATTN_GROUPS:
+                    if group is not None and (bq != bk or bq % group or bq == group):
+                        continue
 
-    def bwd_chain(impl, bq, bk):
-        def f(q, k, v):
-            return jnp.sum(
-                causal_attention(
-                    q, k, v, impl=impl, block_q=bq, block_k=bk
-                ).astype(jnp.float32)
-                ** 2
-            )
+                    def loss(q, k, v):
+                        o = attention._flash_attention(
+                            q, k, v, D**-0.5, bq, bk, group, False, None
+                        )
+                        return jnp.sum(o.astype(jnp.float32) ** 2)
 
-        g = jax.grad(f, argnums=(0, 1, 2))
-        # dq chains into q (tanh keeps values bounded across iterations).
-        return jax.jit(lambda q, k, v: jnp.tanh(g(q, k, v)[0]))
-
-    for bq in (256, 512, 1024):
-        for bk in (256, 512, 1024):
-            t_f = _time_chained(fwd_chain("pallas", bq, bk), q, k, v) * 1e3
-            t_b = _time_chained(bwd_chain("pallas", bq, bk), q, k, v) * 1e3
-            print(f"  bq={bq:4d} bk={bk:4d}: fwd {t_f:6.2f} ms  fwd+bwd {t_b:6.2f} ms")
-    t_f = _time_chained(fwd_chain("reference", 256, 256), q, k, v) * 1e3
-    t_b = _time_chained(bwd_chain("reference", 256, 256), q, k, v) * 1e3
-    print(f"  reference (jnp): fwd {t_f:6.2f} ms  fwd+bwd {t_b:6.2f} ms")
+                    # dq chains into q (tanh keeps values bounded).
+                    step = jax.jit(
+                        lambda q, k, v: jnp.tanh(jax.grad(loss, argnums=(0, 1, 2))(q, k, v)[0])
+                    )
+                    log_dir = tempfile.mkdtemp(prefix="attn_sweep_")
+                    try:
+                        f_us, b_us, host_us = kernel_us(step, (q, k, v), log_dir)
+                    except Exception as e:  # a shape the compiler refuses: report, go on
+                        print(f"  bq={bq:4d} bk={bk:4d} group={group}: FAIL {str(e)[:200]!r}")
+                        continue
+                    finally:
+                        shutil.rmtree(log_dir, ignore_errors=True)
+                    computed, needed = attention.causal_pairs(S, bq, bk, group)
+                    chosen = "*" if group == attention.diag_group(bq, bk) else " "
+                    print(
+                        f" {chosen}bq={bq:4d} bk={bk:4d} group={str(group):>4s}: fwd {f_us:7.1f} us "
+                        f"({100 * least_f / f_us:4.1f}%)  bwd {b_us:7.1f} us "
+                        f"({100 * least_b / b_us:4.1f}%)  fwd+bwd {f_us + b_us:7.1f} us "
+                        f"({100 * (least_f + least_b) / (f_us + b_us):4.1f}% of roofline)  "
+                        f"host {host_us:7.1f} us a step  pairs {computed / needed:.3f}",
+                        flush=True,
+                    )
 
 
 def sweep_step():
