@@ -19,7 +19,7 @@ Reference parity: the role vLLM's engine plays under ray.llm
   books, the pump, admission) runs while the device works. ``max_tokens``
   and ``max_seq`` ends are counts the host knows in time; a stop token is
   learnt a step late, and that row's one extra step is discarded. A
-  temperature, a speculative decoder, a prefill in chunks or a replaced
+  temperature, a speculative decoder or a replaced
   ``_sample`` make a turn synchronous (logits to the host, nothing in
   flight when ``step()`` returns): read off the input, set by nobody.
 - **Every launch numbered.** The prefill and decode programs take the pool
@@ -104,6 +104,13 @@ _PREFIX_HIT_RATE = _metrics.Gauge(
 )
 
 _log = logging.getLogger(__name__)
+# While at least half the slots decode, the decode turns between two prefill
+# chunks (LLMEngine._advance_prefills): the chunks' share of the device is
+# then at most chunk / (chunk + 3 steps).
+_DECODE_TURNS_A_CHUNK = 3
+# A prompt that waits while this many chunks go to others counts as one chunk
+# shorter when the next chunk is given: no prompt is passed over for ever.
+_CHUNKS_PASSED_A_CHUNK = 32
 _replica_tags_cache: dict | None = None
 
 
@@ -200,6 +207,7 @@ class _Request:
     # prompt length.
     prefilling: bool = False
     pf_next: int = 0
+    pf_passed: int = 0  # chunks that went to other prompts while this one waited
     # Admission failure surfaced via pop_finished (an impossible
     # reservation must fail the REQUEST, not wedge the engine loop).
     error: Optional[str] = None
@@ -468,8 +476,9 @@ class LLMEngine:
             # Prefill and decode programs launched: the device runs them in
             # this order, so a launch's count is its run's place in a trace.
             "programs_launched": 0,
-            # Prompts longer than the largest prefill bucket, cut to their
-            # last tokens at add_request.
+            # Prompts cut to their last tokens at add_request: those longer
+            # than the largest prefill bucket, or, where the engine prefills
+            # in chunks, than max_seq holds beside the answer.
             "prompts_truncated": 0,
         }
         self._decode_arm = (
@@ -509,7 +518,7 @@ class LLMEngine:
         self.requests: dict[str, _Request] = {}
         self._slot_req: list = [None] * B
         self._rng = np.random.default_rng(config.seed)
-        self._pf_rr = 0  # round-robin cursor over prefilling slots
+        self._turns_since_chunk = 0  # decode turns since a prefill chunk ran
         self._steps = 0
         self._published_tokens = 0  # tokens already inc'd into the counter
         # Rolling TTFT window ((monotonic, seconds) pairs): a ROUTING/
@@ -567,11 +576,20 @@ class LLMEngine:
             else list(prompt)
         )
         max_prompt = max(self.config.prefill_buckets)
+        # In chunks a prompt may be as long as the cache holds beside its
+        # answer, where the ladder has a bucket for every chunk of it.
+        longest = self.config.max_seq - sampling.max_tokens
+        if (
+            len(ids) > max_prompt and longest > max_prompt
+            and self._chunks_feasible(0, min(len(ids), longest))
+        ):
+            max_prompt = longest
         if len(ids) > max_prompt:
             if not self.stats["prompts_truncated"]:
                 _log.warning(
-                    "prompt of %d tokens cut to its last %d, the largest prefill "
-                    "bucket (counted from here on in stats['prompts_truncated'])",
+                    "prompt of %d tokens cut to its last %d: the largest prefill "
+                    "bucket, or with prefill_chunk_tokens what max_seq holds beside "
+                    "the answer (counted from here on in stats['prompts_truncated'])",
                     len(ids), max_prompt,
                 )
             self.stats["prompts_truncated"] += 1
@@ -863,6 +881,8 @@ class LLMEngine:
         if _flightrec.on():
             extra["wave"] = self._wave["wave"]
             extra["seq"] = self.stats["programs_launched"]
+            if self._slot_state:  # began from the slot's state, or from zero
+                extra["state_carried"] = int(extra.get("start", 0) > 0)
             req.pf_open = (phase, t_pf, extra)
 
     @staticmethod
@@ -1281,24 +1301,50 @@ class LLMEngine:
         return logits
 
     def _advance_prefills(self) -> list:
-        """ONE chunk, for ONE prefilling slot (round-robin), per step:
-        the per-step prefill budget is prefill_chunk_tokens TOTAL, so a
+        """ONE chunk, for ONE prefilling slot, per step at most: the
+        per-step prefill budget is prefill_chunk_tokens TOTAL, so a
         wave of long prompts serializes its prefill across steps instead
         of collectively stalling the decode batch (the token-budget rule
-        of Sarathi-style chunked prefill). A slot whose final chunk lands
-        samples its first token and joins the decode batch. Returns
-        requests that finished here (max_tokens=1 / stop at prefill)."""
-        B = len(self._slot_req)
-        req = None
-        for off in range(B):
-            slot = (self._pf_rr + off) % B
-            cand = self._slot_req[slot]
-            if cand is not None and cand.prefilling:
-                req = cand
-                self._pf_rr = (slot + 1) % B
-                break
-        if req is None:
+        of Sarathi-style chunked prefill).
+
+        **Whose chunk**: the prompt with the fewest tokens left (the one
+        that came first among equals), so a prompt that has begun is
+        finished before another begins and a short prompt's first token
+        does not wait behind a long one's: with k prompts waiting
+        together the slots decode after 1, 2, ... k prompts' worth of
+        chunks and not all after k. Every chunk that goes to another
+        prompt takes 1 / ``_CHUNKS_PASSED_A_CHUNK`` of a chunk off a
+        waiting prompt's count, so a long prompt is passed over by a
+        bounded number of chunks however many short ones keep arriving.
+
+        **How often**: while at least half the slots are decoding, a
+        chunk follows ``_DECODE_TURNS_A_CHUNK`` turns that ran none, so
+        the streams that are decoding keep most of their rate through a
+        wave of long prompts (a chunk of 2,048 lasts several decode
+        steps) and the chunks take a bounded share of the device; with
+        fewer rows decoding there is little to protect and every turn
+        runs a chunk, which fills the batch soonest.
+
+        A slot whose final chunk lands samples its first token and joins
+        the decode batch. Returns requests that finished here
+        (max_tokens=1 / stop at prefill)."""
+        pending = [r for r in self._slot_req if r is not None and r.prefilling]
+        if not pending:
             return []
+        decoding = sum(r is not None for r in self._slot_req) - len(pending)
+        if (
+            2 * decoding >= len(self._slot_req)
+            and self._turns_since_chunk < _DECODE_TURNS_A_CHUNK
+        ):
+            return []
+        credit = self.config.prefill_chunk_tokens / _CHUNKS_PASSED_A_CHUNK
+        req = min(
+            pending,
+            key=lambda r: (len(r.prompt) - r.pf_next - credit * r.pf_passed, r.t_admit),
+        )
+        for r in pending:
+            r.pf_passed += r is not req
+        self._turns_since_chunk = 0
         logits = self._prefill_one_chunk(req)
         T = len(req.prompt)
         if req.pf_next < T:
@@ -1394,14 +1440,15 @@ class LLMEngine:
         steps is always safe. Writing ``block_tables`` or ``positions``
         between steps, or expecting the next step to see such a write, is
         for callers whose engine is on the synchronous arm (a replaced
-        ``_sample``, a temperature, a speculative decoder, a prefill in
-        chunks): there nothing is in flight between steps."""
+        ``_sample``, a temperature, a speculative decoder): there nothing
+        is in flight between steps."""
         instrument = _metrics.metrics_enabled()
         fr = _flightrec.on()
         t_wave = _time.monotonic() if fr else 0.0
         # Prefill chunks of already-admitted long prompts advance BEFORE
         # this step's admissions, so a request admitted this step runs
         # exactly its first chunk — one chunk per request per step.
+        chunks = self.stats["prefill_chunks"]
         finished = self._advance_prefills()
         finished += self._admit_waiting()
         if self._wave is not None:
@@ -1425,6 +1472,8 @@ class LLMEngine:
             # Ran dry: a step in flight has only rows of requests that
             # ended on a stop token, and goes with them.
             self._inflight = None
+        if active and self.stats["prefill_chunks"] == chunks:
+            self._turns_since_chunk += 1
         self._steps += 1
         if instrument:
             self._publish_metrics()
@@ -1436,15 +1485,17 @@ class LLMEngine:
         by nobody. Every active row is greedy (the program's argmax is then
         the sample: a temperature draws from ``self._rng`` on the host); no
         speculative decoder is built (it reads ``last_tokens`` on the host);
-        no slot is mid-way through a prefill in chunks (its cursor moves
-        between steps); and ``_sample`` is the engine's own: whoever
-        replaces it wants every row's logits, and may rewrite
-        ``block_tables`` and ``pool`` between steps."""
+        and ``_sample`` is the engine's own: whoever replaces it wants every
+        row's logits, and may rewrite ``block_tables`` and ``pool`` between
+        steps. A slot mid-way through a prefill in chunks does not stand in
+        the way: it is not live in the step launched ahead, which writes its
+        row of garbage at the slot's cursor as every step does, and the
+        slot's next chunk is launched after that step and so runs after it
+        on the device and overwrites the row."""
         return (
             self._spec is None
             and getattr(self._sample, "__func__", None) is LLMEngine._sample
             and all(r.temperature <= 0.0 for r in active)
-            and not any(r is not None and r.prefilling for r in self._slot_req)
         )
 
     def _ends_by_count(self, req: _Request) -> bool:
@@ -1636,6 +1687,12 @@ class LLMEngine:
                     ) * bs,
                     window_blocks_held=w.held_blocks,
                     blocks_full_retention=w.full_retention_blocks,
+                )
+            if self.config.prefill_chunk_tokens:
+                # Slots mid-way through a prefill in chunks at this turn:
+                # their chunks wait their turn (_advance_prefills).
+                moe["chunks_pending"] = sum(
+                    r is not None and r.prefilling for r in self._slot_req
                 )
             _flightrec.record(
                 "llm", "llm.decode_dispatch", t=t_dec,

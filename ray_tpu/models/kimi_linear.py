@@ -72,6 +72,7 @@ class KimiLinearConfig:
     kda_head_dim: int = 128  # d_k = d_v
     conv_kernel: int = 4
     kda_gate_rank: int = 128  # of the decay's and the output gate's low-rank pairs
+    kda_neg_eigval: bool = False  # beta in (0, 2) and not (0, 1): this model's is not
     # MLA
     n_head: int = 32
     kv_lora_rank: int = 512
@@ -181,29 +182,14 @@ def draw_params(key: jax.Array, cfg: KimiLinearConfig) -> Params:
     the published modelling code draws them (A in [1, 16], a time step in
     [0.001, 0.1])."""
     pd = cfg.param_dtype
-    D, H, dk = cfg.d_model, cfg.kda_heads, cfg.kda_head_dim
-    r, Fm, E = cfg.kda_gate_rank, cfg.moe_d_ff, cfg.experts_held
+    D = cfg.d_model
+    Fm, E = cfg.moe_d_ff, cfg.experts_held
     std = 0.02
     resid = std / (2 * cfg.n_layer) ** 0.5
     keys = iter(jax.random.split(key, 32 * cfg.n_layer + 8))
 
     def w(shape, s=std, dtype=pd):
         return jax.random.normal(next(keys), shape, dtype) * jnp.asarray(s, dtype)
-
-    def kda():
-        dt = jnp.exp(jax.random.uniform(
-            next(keys), (H * dk,), _F32, jnp.log(0.001), jnp.log(0.1)))
-        return {
-            "wqkv": w((D, cfg.conv_dim)),
-            "conv": w((cfg.conv_kernel, cfg.conv_dim), cfg.conv_kernel**-0.5),
-            "f_down": w((D, r)), "f_up": w((r, H * dk)),
-            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1(dt)
-            "A_log": jnp.log(jax.random.uniform(next(keys), (H,), _F32, 1.0, 16.0)),
-            "wb": w((D, H)),
-            "g_down": w((D, r)), "g_up": w((r, H * dk)),
-            "o_norm": jnp.ones((dk,), pd),
-            "wo": w((H * dk, D), resid),
-        }
 
     def mla():
         Hm = cfg.n_head
@@ -233,7 +219,7 @@ def draw_params(key: jax.Array, cfg: KimiLinearConfig) -> Params:
     for i in range(1, cfg.n_layer + 1):
         layers.append({
             "attn_norm": jnp.ones((D,), pd),
-            **(mla() if cfg.mixer(i) == "mla" else kda()),
+            **(mla() if cfg.mixer(i) == "mla" else draw_kda(w, keys, cfg, resid)),
             "mlp_norm": jnp.ones((D,), pd),
             **(moe() if cfg.is_moe(i) else dense()),
         })
@@ -246,13 +232,38 @@ def draw_params(key: jax.Array, cfg: KimiLinearConfig) -> Params:
 
 
 # ---------------------------------------------------------------------------
-# KDA mixer
+# KDA mixer (this family's and ``solar_open2``'s: one implementation)
 
 
-def _kda_inputs(h, mixed, p, cfg: KimiLinearConfig):
+def draw_kda(w, keys, cfg, resid: float) -> Params:
+    """A KDA layer's random weights: ``w(shape, std)`` draws a matrix in the
+    parameter dtype and ``keys`` yields a key a draw, both the caller's, so
+    that a family's weights come off one stream in one order; ``resid`` is the
+    deviation of the projection back to the residual stream. ``A_log`` and
+    ``dt_bias`` as :func:`draw_params` says."""
+    D, H, dk, r = cfg.d_model, cfg.kda_heads, cfg.kda_head_dim, cfg.kda_gate_rank
+    dt = jnp.exp(jax.random.uniform(
+        next(keys), (H * dk,), _F32, jnp.log(0.001), jnp.log(0.1)))
+    return {
+        "wqkv": w((D, cfg.conv_dim)),
+        "conv": w((cfg.conv_kernel, cfg.conv_dim), cfg.conv_kernel**-0.5),
+        "f_down": w((D, r)), "f_up": w((r, H * dk)),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1(dt)
+        "A_log": jnp.log(jax.random.uniform(next(keys), (H,), _F32, 1.0, 16.0)),
+        "wb": w((D, H)),
+        "g_down": w((D, r)), "g_up": w((r, H * dk)),
+        "o_norm": jnp.ones((dk,), cfg.param_dtype),
+        "wo": w((H * dk, D), resid),
+    }
+
+
+def _kda_inputs(h, mixed, p, cfg):
     """From the normed input ``h`` [..., D] and the convolved, SiLU'd
     projections ``mixed`` [..., 3 H d]: ``(q, k, v, g, beta)`` with heads
-    split out, ``q`` and ``k`` normalised, ``g`` the log decay."""
+    split out, ``q`` and ``k`` normalised, ``g`` the log decay. ``beta`` is a
+    sigmoid, in (0, 1), and twice that, in (0, 2), where the configuration
+    allows the transition ``I - beta k k^T`` a negative eigenvalue along ``k``
+    (``kda_neg_eigval``: the published ``kda_allow_neg_eigval``)."""
     H, d = cfg.kda_heads, cfg.kda_head_dim
     dt = cfg.dtype
     q, k, v = (
@@ -265,10 +276,12 @@ def _kda_inputs(h, mixed, p, cfg: KimiLinearConfig):
         (f.astype(_F32) + p["dt_bias"].astype(_F32)).reshape(*f.shape[:-1], H, d)
     )
     beta = jax.nn.sigmoid((h @ p["wb"].astype(dt)).astype(_F32))
+    if cfg.kda_neg_eigval:
+        beta = 2.0 * beta
     return l2(q) * d**-0.5, l2(k), v, g, beta
 
 
-def _kda_output(h, o, p, cfg: KimiLinearConfig):
+def _kda_output(h, o, p, cfg):
     """RMSNorm per head, the sigmoid output gate, ``W_o``."""
     dt = cfg.dtype
     gate = (h @ p["g_down"].astype(dt)) @ p["g_up"].astype(dt)
@@ -277,7 +290,7 @@ def _kda_output(h, o, p, cfg: KimiLinearConfig):
     return o.astype(dt) @ p["wo"].astype(dt)
 
 
-def kda_prefill(h, p, cfg: KimiLinearConfig, S0, tail, length):
+def kda_prefill(h, p, cfg, S0, tail, length):
     """``h`` [T, D] normed, of which the first ``length`` rows are tokens;
     ``S0`` [H, d_k, d_v] and ``tail`` [K-1, 3 H d] are the state and the last
     pre-convolution rows before row 0 (zeros at the start of a sequence).
@@ -295,7 +308,7 @@ def kda_prefill(h, p, cfg: KimiLinearConfig, S0, tail, length):
     return _kda_output(h, o, p, cfg), S, tail
 
 
-def kda_decode(h, p, cfg: KimiLinearConfig, S, tail):
+def kda_decode(h, p, cfg, S, tail):
     """One token a row: ``h`` [B, D], ``S`` [B, H, d_k, d_v], ``tail`` [B,
     K-1, 3 H d]. Returns ``(out [B, D], S, tail)``."""
     dt = cfg.dtype

@@ -58,7 +58,9 @@ and its layer bodies itself:
   ``"conv"``). Its attention blocks write, gather and attend with this
   module's functions (``_write_read``, :func:`decode_attention`: the kernel
   over live blocks on a TPU); for everything else it is a family with a state
-  per slot.
+  per slot. ``solar_open2`` keeps the same three things, its state the delta
+  rule's (``"state": [L_kda, slots + 1, H, d, d]``) and its attention layers
+  read by prefill a stretch of the table at a time (:func:`prefill_attention`).
   Two facts about such a family, which the engine asks one by one:
   *it brings its own programs* (no ``kv_hooks``: its module supplies
   ``init_pool``, ``paged_prefill``, ``paged_decode`` and ``span_fields``),
@@ -115,6 +117,7 @@ _FAMILIES = {
     "mla_moe": "ray_tpu.models.mla_moe",
     "nemotron_h": "ray_tpu.models.nemotron_h",
     "afmoe": "ray_tpu.models.afmoe",
+    "solar_open2": "ray_tpu.models.solar_open2",
 }
 
 

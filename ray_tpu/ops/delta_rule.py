@@ -6,7 +6,13 @@ a state ``S`` of ``[d_k, d_v]``:
     S_t = S' + beta_t k_t (v_t - S'^T k_t)^T
     o_t = S_t^T q_t
 
-``alpha_t = exp(g_t)`` with ``g_t <= 0`` per key channel, ``beta_t`` in (0, 1).
+``alpha_t = exp(g_t)`` with ``g_t <= 0`` per key channel; ``beta_t`` in (0, 1)
+(Kimi Linear) or in (0, 2) (Solar Open 2, ``kda_allow_neg_eigval``), the
+caller's choice. With ``|k_t| = 1``, as both callers normalise it, the step's
+transition ``(I - beta_t k_t k_t^T) Diag(alpha_t)`` has the eigenvalue ``1 -
+beta_t`` along ``k_t``, in (-1, 1) for ``beta_t`` in (0, 2), and no singular
+value above one: past 1 the state's part along ``k_t`` changes sign and is
+still not grown.
 
 Two forms of the same mathematics:
 
@@ -15,12 +21,15 @@ Two forms of the same mathematics:
 - :func:`kda_chunked`, chunks of :data:`CHUNK` tokens under ``lax.scan``, for
   prefill. Inside a chunk that starts from ``S_0`` the writes ``u_t = beta_t
   (v_t - S'^T_t k_t)`` satisfy a unit lower-triangular system (the WY / UT
-  form), which is solved exactly; outputs and the next state are then matrix
-  products. With ``G_t`` the running sum of ``g`` inside the chunk, every
-  decay that appears is ``exp(G_t - G_i)`` with ``i <= t``, ``exp(G_t)`` or
-  ``exp(G_C - G_i)``: exponents at or below zero, so nothing is ever divided
-  by a product of ``alpha`` and nothing overflows; a product that underflows
-  is one the recurrence would have lost too.
+  form), which is solved exactly, by substitution, whatever ``beta`` is: the
+  solve assumes a unit diagonal and nothing of the entries under it, which
+  are ``beta_t (k_t . k_i)`` decayed, under 2 in size here. Outputs and the
+  next state are then matrix products. With ``G_t`` the running sum of ``g``
+  inside the chunk, every decay that appears is ``exp(G_t - G_i)`` with ``i
+  <= t``, ``exp(G_t)`` or ``exp(G_C - G_i)``: exponents at or below zero, so
+  nothing is ever divided by a product of ``alpha`` and nothing overflows (the
+  writes ``u_t`` are the recurrence's own, and it grows nothing: above); a
+  product that underflows is one the recurrence would have lost too.
 
 A position with ``beta = 0`` and ``g = 0`` leaves the state as it was (the
 padded tail of a prefill bucket). State and accumulation are float32; plain

@@ -46,7 +46,7 @@ def ref_config(cfg: kl.KimiLinearConfig) -> dict:
     )
 
 
-def _kda_inputs(key, T, H=3, d=8, strong_decay=False):
+def _kda_inputs(key, T, H=3, d=8, strong_decay=False, beta_max=1.0):
     ks = jax.random.split(key, 6)
     l2 = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa: E731
     q = l2(jax.random.normal(ks[0], (T, H, d))) * d**-0.5
@@ -56,14 +56,18 @@ def _kda_inputs(key, T, H=3, d=8, strong_decay=False):
     # gives them; "strong" is past what exp(-sum) could be divided by.
     lo, hi = (2.0, 6.0) if strong_decay else (0.001, 1.6)
     g = -jax.random.uniform(ks[3], (T, H, d), minval=lo, maxval=hi)
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (T, H)))
+    beta = beta_max * jax.nn.sigmoid(jax.random.normal(ks[4], (T, H)))
     S0 = jax.random.normal(ks[5], (H, d, d))
     return q, k, v, g, beta, S0
 
 
+@pytest.mark.parametrize("beta_max", [1.0, 2.0], ids=["beta_under_1", "beta_under_2"])
 @pytest.mark.parametrize("T", [1, 63, 64, 130])
-def test_kda_chunked_is_the_step_is_the_recurrence(T):
-    q, k, v, g, beta, S0 = _kda_inputs(jax.random.key(T), T)
+def test_kda_chunked_is_the_step_is_the_recurrence(T, beta_max):
+    """``beta`` in (0, 1), Kimi Linear's, and in (0, 2), Solar Open 2's: past
+    1 the transition has a negative eigenvalue along ``k``."""
+    q, k, v, g, beta, S0 = _kda_inputs(jax.random.key(T), T, beta_max=beta_max)
+    assert float(beta.max()) < beta_max and (T == 1 or float(beta.max()) > beta_max / 2)
     o_c, S_c = delta_rule.kda_chunked(q, k, v, g, beta, S0)
     S, o_s = S0, []
     for t in range(T):

@@ -159,14 +159,20 @@ def test_a_repeated_prompt_bypasses_the_prefix_cache_and_is_counted():
     assert not eng._prefix_pool
 
 
-def test_a_prompt_longer_than_the_largest_bucket_is_cut_counted_and_logged_once(caplog):
-    eng = LLMEngine(llm_config(prefill_buckets=(8, 16, 32), max_seq=64, num_kv_blocks=49))
+@pytest.mark.parametrize("chunk, lengths, kept", [
+    (0, [40, 33, 20], [32, 32, 20]),  # whole prefills: the largest bucket
+    (CHUNK, [70, 63, 40], [62, 62, 40]),  # in chunks (PR 43): what max_seq holds beside the answer
+], ids=["whole", "in_chunks"])
+def test_a_prompt_longer_than_the_engine_can_prefill_is_cut_counted_and_logged_once(caplog, chunk, lengths, kept):
+    eng = LLMEngine(llm_config(
+        prefill_buckets=(8, 16, 32), max_seq=64, num_kv_blocks=49, prefill_chunk_tokens=chunk,
+    ))
     with caplog.at_level("WARNING", logger="ray_tpu.llm.engine"):
-        for i, p in enumerate(prompts([40, 33, 20], seed=5)):
+        for i, p in enumerate(prompts(lengths, seed=5)):
             eng.add_request(f"r{i}", p, SamplingParams(max_tokens=2))
     assert eng.stats["prompts_truncated"] == 2
-    assert [len(r.prompt) for r in eng.requests.values()] == [32, 32, 20]
-    assert sum("cut to its last 32" in r.message for r in caplog.records) == 1
+    assert [len(r.prompt) for r in eng.requests.values()] == kept
+    assert sum(f"cut to its last {kept[0]}" in r.message for r in caplog.records) == 1
 
 
 def test_a_prompt_that_cannot_prefill_in_chunks_is_refused_not_run_whole():

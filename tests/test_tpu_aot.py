@@ -279,8 +279,10 @@ def test_axk1s_decode_program_attends_its_latent_pool_in_place(v5e_2x2):
         (64 * 22, 128, 1024, 2688), (64 * 22, 128, 2688, 1024),
         (16 * 8, 64, 2304, 1024), (2048, 64, 1024, 2304),
         (32 * 8, 12, 7168, 2048), (2048, 12, 2048, 7168),
+        (32 * 8, 40, 4096, 1280), (2048, 40, 1280, 4096),
     ],
-    ids=["nemotron-up", "nemotron-down", "kimi-up", "kimi-down-pass", "axk1-up", "axk1-down-pass"],
+    ids=["nemotron-up", "nemotron-down", "kimi-up", "kimi-down-pass", "axk1-up", "axk1-down-pass",
+         "solar-up", "solar-down-pass"],
 )
 def test_the_grouped_product_kernel_compiles_at_served_widths(v5e_2x2, m, experts, K, N):
     """``ops.moe_gmm.gmm`` at the three expert cells' widths, both
@@ -353,3 +355,58 @@ def test_engine_paged_programs_write_the_pool_in_place(v5e_2x2, program):
     assert not [
         r for r in results if "dynamic-update-slice" in r and pool_shape in r
     ]
+
+
+@pytest.mark.parametrize("program", ["chunk_of_2048", "decode_of_32_slots"])
+def test_solar_open2s_served_programs_compile_for_one_chip(v5e_2x2, program):
+    """``solar_open2``'s two programs at the benchmark's shapes (one period of
+    the published widths, 40 of 320 experts held, 32 slots of 18,432
+    positions), lowered for the chip: the 2,048-token chunk that continues a
+    prompt (three chunked delta-rule scans at 64 heads, the GQA layer's
+    attention a stretch of the table at a time) and the decode step (the
+    attention kernel over the live blocks, once: one GQA layer; the state read
+    and written by slot), the pool donated and aliased, and weights, cache
+    and temporaries within the chip's 16 GB. (The experts' grouped products
+    are ``ragged_dot`` in a program built in a process whose backend is the
+    CPU: their kernel compiles at this family's widths in the test above.)"""
+    from ray_tpu.models import paged, solar_open2 as so
+
+    cfg = so.SolarOpen2Config(
+        vocab_size=24576, layer_kinds=so.PUBLISHED_LAYER_KINDS[:4], experts_held=40,
+        max_seq=18432, state_slots=32,
+    )
+    B, bs, N = 32, 16, 36865
+    W = cfg.max_seq // bs
+    assert paged._kernel_fits(cfg, bs, None)
+    one = jax.sharding.SingleDeviceSharding(v5e_2x2[0])
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)  # noqa: E731
+    on_chip = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
+    params = on_chip(jax.eval_shape(lambda k: so.draw_params(k, cfg), jax.random.key(0)))
+    pool = on_chip(jax.eval_shape(lambda: so.init_pool(cfg, N, bs)))
+    i32 = jnp.int32
+    if program == "chunk_of_2048":
+        compiled = jax.jit(
+            functools.partial(paged.paged_prefill, cfg=cfg, block_size=bs), donate_argnums=5
+        ).lower(
+            params, sds((1, 2048), i32), sds((), i32), sds((), i32), sds((W,), i32), pool,
+            slot=sds((), i32),
+        ).compile()
+        calls = mosaic_calls(compiled.as_text())
+        assert "paged_decode_attention" not in calls
+    else:
+        compiled = jax.jit(
+            functools.partial(paged.paged_decode, cfg=cfg, block_size=bs), donate_argnums=4
+        ).lower(
+            params, sds((B,), i32), sds((B,), i32), sds((B, W), i32), pool, live=sds((B,), jnp.bool_),
+        ).compile()
+        calls = mosaic_calls(compiled.as_text())
+        assert calls.count("paged_decode_attention") == cfg.layers_of(so.GQA) == 1
+        gathered = f"bf16[{B},{W},{cfg.n_kv_head},{bs},{cfg.head_dim}]"
+        assert gathered not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    nbytes = lambda t: sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(t))  # noqa: E731
+    assert 6.6e9 < nbytes(params) < 6.7e9 and 2.8e9 < nbytes(pool) < 2.9e9
+    assert mem.alias_size_in_bytes >= nbytes(pool)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
+    print(program, {k: getattr(mem, k + "_size_in_bytes") for k in ("temp", "argument", "output", "alias")},
+          nbytes(params), nbytes(pool))
