@@ -31,6 +31,31 @@ Two forms of the same mathematics:
   writes ``u_t`` are the recurrence's own, and it grows nothing: above); a
   product that underflows is one the recurrence would have lost too.
 
+The pair terms ``kk[t, i] = k_t . (k_i exp(G_t - G_i))`` and ``qk[t, i]``
+(``q_t`` for ``k_t``), ``i <= t``, carry a decay per key channel, so they are
+not one product of ``k`` with itself. They are formed by row blocks of
+:data:`BLOCK` positions (:func:`_pair_terms`); for the block that starts at
+position ``r``:
+
+- columns before it (``i < r <= t``): ``exp(G_t - G_i) = exp(G_t - G_r)
+  exp(G_r - G_i)``, so ``kk[t, i] = (k_t e^(G_t - G_r)) . (k_i e^(G_r - G_i))``:
+  one ``[BLOCK, d_k] x [d_k, r]`` product a head, its operands the size of
+  ``k``. ``G`` decreases, so both exponents are at or below zero, as every
+  exponent above; columns at or past ``r`` are masked out of the product and
+  their exponent clamped at zero before the ``exp``. A factor that underflows
+  stands under a product ``exp(G_t - G_i)`` no larger than itself: one the
+  elementwise form, and the recurrence, would have lost too.
+- its own columns (``r <= i <= t``): elementwise, ``exp(G_t - G_i)`` a pair
+  and channel over ``[H, BLOCK, BLOCK, d_k]``, then summed over the channel.
+  (A product here would need ``exp(G_r - G_i)`` with ``i > r``, an exponent
+  above zero: up to 15 positions' decay, past float32 at 6 a position.)
+
+So no value has the ``[H, CHUNK, CHUNK, d_k]`` elements of a decay for every
+pair (``BLOCK / CHUNK`` of them), and the rest runs on the matrix unit.
+``BLOCK = 16`` is what flash-linear-attention's ``chunk_kda`` / ``chunk_gla``
+intra-chunk kernels use; on a v5e at ``[2048, 64, 128]`` it was timed against
+8, 32 and 64 (``tools/delta_rule_chip.py``; PERF.md section 6, PR 44).
+
 A position with ``beta = 0`` and ``g = 0`` leaves the state as it was (the
 padded tail of a prefill bucket). State and accumulation are float32; plain
 ``jax.numpy``, no kernel.
@@ -42,6 +67,7 @@ import jax
 import jax.numpy as jnp
 
 CHUNK = 64
+BLOCK = 16  # rows of a block of the pair terms; divides CHUNK
 _F32 = jnp.float32
 # The state is float32 and feeds back into itself: its products are taken at
 # full precision (on a TPU the default rounds float32 operands to bfloat16).
@@ -61,17 +87,46 @@ def kda_step(q, k, v, g, beta, S):
     return jnp.einsum("...kv,...k->...v", S, q, precision=_PREC), S
 
 
+def _pair_terms(q, k, G):
+    """``kk[t, i] = k_t . (k_i exp(G_t - G_i))`` and ``qk[t, i]`` with ``q_t``
+    for ``k_t``, both ``[H, C, C]`` and meant for ``i <= t`` (the caller's
+    ``tril``), by row blocks of :data:`BLOCK` positions: the module docstring
+    says how."""
+    H, C, d = k.shape
+    n = C // BLOCK
+    blocks = lambda a: a.reshape(H, n, BLOCK, d)  # noqa: E731
+    Gb, kb, qb = blocks(G), blocks(k), blocks(q)
+    Gr = Gb[:, :, :1]  # G at each block's first position r
+    # Columns before the block: (x_t e^(G_t - G_r)) . (k_i e^(G_r - G_i)), one
+    # product for the rows of k and of q.
+    before = jnp.arange(C)[None, :] < jnp.arange(0, C, BLOCK)[:, None]  # [n, C(i)]
+    to_r = jnp.where(before[..., None], jnp.exp(jnp.minimum(Gr - G[:, None], 0.0)), 0.0)
+    from_r = jnp.exp(Gb - Gr)
+    pair = jnp.einsum(
+        "hbtc,hbic->hbti",
+        jnp.concatenate([kb * from_r, qb * from_r], axis=2), k[:, None] * to_r,
+        precision=_PREC,
+    )  # [H, n, 2 B(t), C(i)]
+    # The block's own columns, elementwise: exp(G_t - G_i) a channel, for i <= t.
+    decay = jnp.exp(jnp.minimum(Gb[:, :, :, None] - Gb[:, :, None], 0.0))
+    kd = decay * kb[:, :, None]  # [H, n, B(t), B(i), d]: k_i as position t sees it
+    # Two sums over one kd, not one over stacked rows: XLA then fuses both with
+    # the exp into one pass, and writes kd out between them otherwise.
+    own = jnp.concatenate(
+        [jnp.sum(x[:, :, :, None] * kd, axis=-1) for x in (kb, qb)], axis=2
+    )  # [H, n, 2 B(t), B(i)]
+    at_own = jnp.eye(n, dtype=_F32)[:, None, :, None]  # [n, 1, n, 1]: block b's columns
+    pair = pair + (own[..., None, :] * at_own).reshape(pair.shape)
+    return pair[:, :, :BLOCK].reshape(H, C, C), pair[:, :, BLOCK:].reshape(H, C, C)
+
+
 def _chunk(S, inputs):
     """One chunk of every head: ``q, k, g`` [H, C, d_k], ``v`` [H, C, d_v],
     ``beta`` [H, C], ``S`` [H, d_k, d_v]."""
     q, k, v, g, beta = inputs
     C = q.shape[1]
     G = jnp.cumsum(g, axis=1)  # [H, C, d_k], decreasing
-    # decay[t, i] = exp(G_t - G_i) per channel, wanted for i <= t only.
-    decay = jnp.exp(jnp.minimum(G[:, :, None, :] - G[:, None, :, :], 0.0))
-    kd = decay * k[:, None, :, :]  # k_i as position t sees it
-    kk = jnp.sum(k[:, :, None, :] * kd, axis=-1)  # [H, C(t), C(i)]
-    qk = jnp.sum(q[:, :, None, :] * kd, axis=-1)
+    kk, qk = _pair_terms(q, k, G)
     eG = jnp.exp(G)
     system = jnp.eye(C, dtype=_F32) + jnp.tril(kk, -1) * beta[..., None]
     rhs = beta[..., None] * (

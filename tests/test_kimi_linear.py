@@ -46,27 +46,31 @@ def ref_config(cfg: kl.KimiLinearConfig) -> dict:
     )
 
 
-def _kda_inputs(key, T, H=3, d=8, strong_decay=False, beta_max=1.0):
+def _kda_inputs(key, T, H=3, d=8, decay=(0.001, 1.6), beta_max=1.0):
     ks = jax.random.split(key, 6)
     l2 = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa: E731
     q = l2(jax.random.normal(ks[0], (T, H, d))) * d**-0.5
     k = l2(jax.random.normal(ks[1], (T, H, d)))
     v = jax.random.normal(ks[2], (T, H, d))
     # log decays from -0.001 to -1.6 a token, as the model's initialiser
-    # gives them; "strong" is past what exp(-sum) could be divided by.
-    lo, hi = (2.0, 6.0) if strong_decay else (0.001, 1.6)
-    g = -jax.random.uniform(ks[3], (T, H, d), minval=lo, maxval=hi)
+    # gives them; the strong ones are past what exp(-sum) could be divided by.
+    g = -jax.random.uniform(ks[3], (T, H, d), minval=decay[0], maxval=decay[1])
     beta = beta_max * jax.nn.sigmoid(jax.random.normal(ks[4], (T, H)))
     S0 = jax.random.normal(ks[5], (H, d, d))
     return q, k, v, g, beta, S0
 
 
 @pytest.mark.parametrize("beta_max", [1.0, 2.0], ids=["beta_under_1", "beta_under_2"])
-@pytest.mark.parametrize("T", [1, 63, 64, 130])
-def test_kda_chunked_is_the_step_is_the_recurrence(T, beta_max):
+@pytest.mark.parametrize(
+    "T, H, d",
+    # 15-17 and 79 end inside a sub-block of delta_rule.BLOCK rows and on its
+    # edges; the last two are the two cells' heads cut down at their width.
+    [(T, 3, 8) for T in (1, 15, 16, 17, 63, 64, 79, 130)] + [(70, 2, 128), (40, 4, 128)],
+)
+def test_kda_chunked_is_the_step_is_the_recurrence(T, H, d, beta_max):
     """``beta`` in (0, 1), Kimi Linear's, and in (0, 2), Solar Open 2's: past
     1 the transition has a negative eigenvalue along ``k``."""
-    q, k, v, g, beta, S0 = _kda_inputs(jax.random.key(T), T, beta_max=beta_max)
+    q, k, v, g, beta, S0 = _kda_inputs(jax.random.key(T), T, H, d, beta_max=beta_max)
     assert float(beta.max()) < beta_max and (T == 1 or float(beta.max()) > beta_max / 2)
     o_c, S_c = delta_rule.kda_chunked(q, k, v, g, beta, S0)
     S, o_s = S0, []
@@ -80,16 +84,48 @@ def test_kda_chunked_is_the_step_is_the_recurrence(T, beta_max):
     np.testing.assert_allclose(S_c, S_r[0], rtol=2e-4, atol=2e-5)
 
 
-def test_kda_chunked_survives_decays_no_product_could_be_divided_by():
-    """exp(-sum of g) over a chunk is far past float32 here: the chunked
-    form must never form it."""
-    q, k, v, g, beta, S0 = _kda_inputs(jax.random.key(7), 100, strong_decay=True)
-    assert float(jnp.sum(g[:64, 0, 0])) < -120
+@pytest.mark.parametrize(
+    "over, decay, beta_max",
+    [("chunk", (2.0, 6.0), 1.0), ("chunk", (2.0, 6.0), 2.0), ("sub_block", (6.0, 9.0), 2.0)],
+)
+def test_kda_chunked_survives_decays_no_product_could_be_divided_by(over, decay, beta_max):
+    """exp(-sum of g) over a chunk, or over one sub-block of it, is far past
+    float32 here: the chunked form must never form it. Past a sub-block the
+    factor ``exp(G_r - G_i)`` of an earlier column underflows while a row's
+    ``exp(G_t - G_r)`` does not: the term it loses is one the recurrence
+    lost too."""
+    q, k, v, g, beta, S0 = _kda_inputs(jax.random.key(7), 100, decay=decay, beta_max=beta_max)
+    span = {"chunk": delta_rule.CHUNK, "sub_block": delta_rule.BLOCK}[over]
+    assert float(jnp.sum(g[:span], axis=0).max()) < -89  # exp(89) is past float32
+    if over == "sub_block":
+        G = jnp.cumsum(g[: delta_rule.CHUNK], axis=0)
+        assert float(jnp.exp(G[delta_rule.BLOCK] - G[0]).max()) == 0.0
+        assert float(jnp.exp(G[delta_rule.BLOCK + 1] - G[delta_rule.BLOCK]).min()) > 0.0
     o_c, S_c = delta_rule.kda_chunked(q, k, v, g, beta, S0)
     o_r, S_r = ref.kda_recurrence(*(a[None] for a in (q, k, v, g, beta, S0)))
     assert np.isfinite(np.asarray(o_c)).all() and np.isfinite(np.asarray(S_c)).all()
     np.testing.assert_allclose(o_c, o_r[0], rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(S_c, S_r[0], rtol=2e-4, atol=2e-5)
+
+
+def test_kda_chunked_never_forms_a_decay_for_every_pair_of_a_chunk():
+    """The pair terms go by sub-blocks: no value of the traced program, the
+    scan's body included, is as large as ``[H, CHUNK, CHUNK, d_k]``."""
+    T, H, d = 128, 4, 128
+    spec = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
+    jaxpr = jax.make_jaxpr(delta_rule.kda_chunked)(
+        spec(T, H, d), spec(T, H, d), spec(T, H, d), spec(T, H, d), spec(T, H), spec(H, d, d)
+    )
+
+    def sizes(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield from (v.aval.size for v in eqn.outvars)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from sizes(sub)
+
+    seen = list(sizes(jaxpr.jaxpr))
+    assert len(seen) > 50 and max(seen) >= T * H * d  # the walk reached the scan's body
+    assert max(seen) < H * delta_rule.CHUNK * delta_rule.CHUNK * d
 
 
 def test_kda_positions_with_beta_0_and_g_0_leave_the_state_alone():

@@ -370,6 +370,7 @@ def test_solar_open2s_served_programs_compile_for_one_chip(v5e_2x2, program):
     are ``ragged_dot`` in a program built in a process whose backend is the
     CPU: their kernel compiles at this family's widths in the test above.)"""
     from ray_tpu.models import paged, solar_open2 as so
+    from ray_tpu.ops import delta_rule
 
     cfg = so.SolarOpen2Config(
         vocab_size=24576, layer_kinds=so.PUBLISHED_LAYER_KINDS[:4], experts_held=40,
@@ -391,8 +392,14 @@ def test_solar_open2s_served_programs_compile_for_one_chip(v5e_2x2, program):
             params, sds((1, 2048), i32), sds((), i32), sds((), i32), sds((W,), i32), pool,
             slot=sds((), i32),
         ).compile()
-        calls = mosaic_calls(compiled.as_text())
+        text = compiled.as_text()
+        calls = mosaic_calls(text)
         assert "paged_decode_attention" not in calls
+        # The scans' pair terms go by sub-blocks (ops/delta_rule.py): the
+        # compiler re-forms no decay for every pair of a chunk's positions.
+        H, C, d = cfg.kda_heads, delta_rule.CHUNK, cfg.kda_head_dim
+        assert (H, d) == (64, 128) and f"f32[{H},{C},{d}]" in text  # the pattern can match
+        assert f"f32[{H},{C},{C},{d}]" not in text
     else:
         compiled = jax.jit(
             functools.partial(paged.paged_decode, cfg=cfg, block_size=bs), donate_argnums=4

@@ -1,0 +1,111 @@
+"""The delta rule's chunked prefill alone, on the chip, by the rows of a block.
+
+    python tools/delta_rule_chip.py [--blocks 8,16,32,64] [--heads 64,32] [--tokens 2048]
+
+``ops.delta_rule.kda_chunked`` at the shapes of the two cells that run it
+(`serve-longdoc-solaropen2`: 64 heads a layer; `serve-batch-kimilinear`: 32;
+keys and values 128 wide, a bucket of 2,048 tokens = 32 chunks), traced anew
+under each setting of :data:`ops.delta_rule.BLOCK`. A block of
+:data:`CHUNK` rows forms every pair term elementwise over ``[H, C, C, d_k]``,
+as before PR 44, beside a product with no column left to take (10.6 ms at 64
+heads where that PR's parent read 9.17). A line a setting: the milliseconds a call
+takes by the host's clock (to ``block_until_ready``) and by the device trace,
+how far its result lies from the first setting's, and the trace's operations
+a call, longest first (milliseconds). Needs a TPU: a time from another
+backend says nothing (PERF.md section 6, PR 44, holds the v5e's readings).
+The last line of standard output is one JSON list.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import shutil
+import sys
+import tempfile
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+from benchmarks import trace_reduce  # noqa: E402
+from ray_tpu.ops import delta_rule  # noqa: E402
+
+CALLS = 8
+WIDTH = 128
+
+
+def inputs(key, T, H, d=WIDTH):
+    """As `kimi_linear._kda_inputs` hands them over: unit keys, queries
+    scaled, log decays from the initialiser's range, ``beta`` in (0, 2)."""
+    ks = jax.random.split(key, 6)
+    l2 = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa: E731
+    return (
+        l2(jax.random.normal(ks[0], (T, H, d))) * d**-0.5,
+        l2(jax.random.normal(ks[1], (T, H, d))),
+        jax.random.normal(ks[2], (T, H, d)),
+        -jax.random.uniform(ks[3], (T, H, d), minval=0.001, maxval=1.6),
+        2.0 * jax.nn.sigmoid(jax.random.normal(ks[4], (T, H))),
+        jax.random.normal(ks[5], (H, d, d)),
+    )
+
+
+def time_calls(run, args):
+    """Host and device milliseconds a call over ``CALLS`` calls, and the
+    device's operations a call."""
+    jax.block_until_ready(run(*args))  # compiled, outside the timing
+    log_dir = tempfile.mkdtemp(prefix="delta_rule_chip_")
+    try:
+        jax.profiler.start_trace(log_dir)
+        t = time.perf_counter()
+        for _ in range(CALLS):
+            out = run(*args)
+        jax.block_until_ready(out)
+        host_ms = (time.perf_counter() - t) / CALLS * 1e3
+        jax.profiler.stop_trace()
+        reduced = trace_reduce.reduce(
+            trace_reduce.plain_from_xplane(trace_reduce.find_xplane(log_dir))
+        )
+    finally:
+        shutil.rmtree(log_dir, ignore_errors=True)
+    ops = [[name, round(s / CALLS * 1e3, 3)] for name, s in reduced["ops"][:12]]
+    return host_ms, reduced["busy_s"] / CALLS * 1e3, ops
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--blocks", default=f"8,16,32,{delta_rule.CHUNK}", help="comma-separated rows a block")
+    ap.add_argument("--heads", default="64,32", help="comma-separated heads a layer")
+    ap.add_argument("--tokens", type=int, default=2048)
+    args = ap.parse_args()
+    if jax.default_backend() != "tpu":
+        raise SystemExit("needs a TPU: the scan's time is a device time")
+    out = []
+    for H in map(int, args.heads.split(",")):
+        operands = inputs(jax.random.key(H), args.tokens, H)
+        first = None
+        for block in map(int, args.blocks.split(",")):
+            delta_rule.BLOCK = block
+            jax.clear_caches()  # the scan keeps its body's trace by the function
+            run = jax.jit(delta_rule.kda_chunked)
+            o, S = run(*operands)
+            first = first or (o, S)
+            host_ms, device_ms, ops = time_calls(run, operands)
+            row = {
+                "heads": H, "tokens": args.tokens, "block": block,
+                "host_ms": round(host_ms, 3), "device_ms": round(device_ms, 3),
+                "o_diff": float(jnp.max(jnp.abs(o - first[0]))),
+                "S_diff": float(jnp.max(jnp.abs(S - first[1]))),
+                "ops_ms": ops,
+            }
+            print(json.dumps(row), flush=True)
+            out.append(row)
+    print(json.dumps(out))
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())
