@@ -20,16 +20,37 @@ Two forms of the same mathematics:
   state once.
 - :func:`kda_chunked`, chunks of :data:`CHUNK` tokens under ``lax.scan``, for
   prefill. Inside a chunk that starts from ``S_0`` the writes ``u_t = beta_t
-  (v_t - S'^T_t k_t)`` satisfy a unit lower-triangular system (the WY / UT
-  form), which is solved exactly, by substitution, whatever ``beta`` is: the
-  solve assumes a unit diagonal and nothing of the entries under it, which
-  are ``beta_t (k_t . k_i)`` decayed, under 2 in size here. Outputs and the
-  next state are then matrix products. With ``G_t`` the running sum of ``g``
-  inside the chunk, every decay that appears is ``exp(G_t - G_i)`` with ``i
-  <= t``, ``exp(G_t)`` or ``exp(G_C - G_i)``: exponents at or below zero, so
-  nothing is ever divided by a product of ``alpha`` and nothing overflows (the
-  writes ``u_t`` are the recurrence's own, and it grows nothing: above); a
-  product that underflows is one the recurrence would have lost too.
+  (v_t - S'^T_t k_t)`` satisfy a unit lower-triangular system ``(I + A) u =
+  rhs`` (the WY / UT form), which is solved exactly, whatever ``beta`` is:
+  below. Outputs and the next state are then matrix products. With ``G_t`` the
+  running sum of ``g`` inside the chunk, every decay that appears is
+  ``exp(G_t - G_i)`` with ``i <= t``, ``exp(G_t)`` or ``exp(G_C - G_i)``:
+  exponents at or below zero, so nothing is ever divided by a product of
+  ``alpha`` and nothing overflows (the writes ``u_t`` are the recurrence's
+  own, and it grows nothing: above); a product that underflows is one the
+  recurrence would have lost too.
+
+The system's entries under the diagonal are ``A[t, i] = beta_t (k_t . k_i)``
+decayed, under 2 in size here. ``I + A`` is inverted and the inverse applied
+as a product (:func:`_unit_lower_inverse`); no solver is called and no series
+is cut short. The four diagonal blocks of :data:`BLOCK` rows are inverted by
+elimination a column at a time, the arithmetic of substitution with every
+block of every head side by side: fifteen steps of one multiply and subtract
+over ``[H, 4, 16, 16]``. Then ``[[P, 0], [R, Q]]^-1 = [[P^-1, 0], [-Q^-1 R
+P^-1, Q^-1]]`` twice, 16 -> 32 -> 64, with products at full precision. Both
+are identities of any unit lower-triangular matrix: nothing is assumed of the
+entries. (On random entries up to 2, whose inverse reaches 1e10, the merging
+products lose a few times what substitution loses, of the same three digits
+of the largest entry; on this recurrence's systems, whose inverses are
+bounded because the recurrence grows nothing, the result is as near the
+token-by-token form as substitution's was at every decay the initialiser
+gives, ``beta`` at 1.99 on keys a few degrees apart included:
+``tests/test_kimi_linear.py``.) The system needs only ``k, g, beta``, so it
+could be inverted for every chunk of a call ahead of the scan and the loop
+left with the state's products; on a v5e that was slower than the parent's
+solve inside the loop, because every intermediate the size of ``k`` then goes
+out to the device's memory and comes back (PERF.md section 6, PR 45). So all
+of it runs inside the scan, a chunk a step.
 
 The pair terms ``kk[t, i] = k_t . (k_i exp(G_t - G_i))`` and ``qk[t, i]``
 (``q_t`` for ``k_t``), ``i <= t``, carry a decay per key channel, so they are
@@ -120,19 +141,68 @@ def _pair_terms(q, k, G):
     return pair[:, :, :BLOCK].reshape(H, C, C), pair[:, :, BLOCK:].reshape(H, C, C)
 
 
+def _mm(a, b):
+    return jnp.matmul(a, b, precision=_PREC)
+
+
+def _diag_block_inverses(A):
+    """``A``: [..., C, C]. The inverse of ``I + N`` for each of the ``C /
+    BLOCK`` diagonal blocks ``N`` of ``A``, of which only the entries under
+    the diagonal are read: [..., C / BLOCK, BLOCK, BLOCK]. By elimination, a
+    column at a time: ``I + N`` is the product over ``j`` of ``I + N[:, j]
+    e_j^T``, whose inverses are ``I - N[:, j] e_j^T``, so ``X <- X - N[:, j]
+    X[j, :]`` from ``X = I``; row ``j`` is final by then, since only columns
+    before ``j`` reach it. The arithmetic of substitution, every block of
+    every head side by side."""
+    C = A.shape[-1]
+    N = jnp.tril(
+        jnp.stack([A[..., r:r + BLOCK, r:r + BLOCK] for r in range(0, C, BLOCK)], axis=-3), -1
+    )
+
+    def eliminate(j, X):
+        column = jax.lax.dynamic_slice_in_dim(N, j, 1, axis=-1)
+        return X - column * jax.lax.dynamic_slice_in_dim(X, j, 1, axis=-2)
+
+    return jax.lax.fori_loop(
+        0, BLOCK - 1, eliminate, jnp.broadcast_to(jnp.eye(BLOCK, dtype=_F32), N.shape)
+    )
+
+
+def _unit_lower_inverse(A):
+    """``(I + A)^-1`` for ``A`` [..., C, C] strictly lower triangular, exactly
+    (no series is cut short, nothing is assumed of the entries): the diagonal
+    blocks of :data:`BLOCK` rows by elimination, then ``[[P, 0], [R, Q]]^-1 =
+    [[P^-1, 0], [-Q^-1 R P^-1, Q^-1]]`` with products, the block doubling
+    until it is the chunk."""
+    C = A.shape[-1]
+    inv, b = _diag_block_inverses(A), BLOCK
+    while b < C:
+        R = jnp.stack([A[..., r + b:r + 2 * b, r:r + b] for r in range(0, C, 2 * b)], axis=-3)
+        pairs = inv.reshape(*inv.shape[:-3], -1, 2, b, b)
+        P, Q = pairs[..., 0, :, :], pairs[..., 1, :, :]
+        inv = jnp.concatenate(
+            [
+                jnp.concatenate([P, jnp.zeros_like(P)], axis=-1),
+                jnp.concatenate([-_mm(_mm(Q, R), P), Q], axis=-1),
+            ],
+            axis=-2,
+        )
+        b *= 2
+    return inv[..., 0, :, :]
+
+
 def _chunk(S, inputs):
     """One chunk of every head: ``q, k, g`` [H, C, d_k], ``v`` [H, C, d_v],
     ``beta`` [H, C], ``S`` [H, d_k, d_v]."""
     q, k, v, g, beta = inputs
-    C = q.shape[1]
     G = jnp.cumsum(g, axis=1)  # [H, C, d_k], decreasing
     kk, qk = _pair_terms(q, k, G)
     eG = jnp.exp(G)
-    system = jnp.eye(C, dtype=_F32) + jnp.tril(kk, -1) * beta[..., None]
+    inverse = _unit_lower_inverse(jnp.tril(kk, -1) * beta[..., None])
     rhs = beta[..., None] * (
         v - jnp.einsum("htc,hcv->htv", k * eG, S, precision=_PREC)
     )
-    u = jax.scipy.linalg.solve_triangular(system, rhs, lower=True, unit_diagonal=True)
+    u = _mm(inverse, rhs)
     o = jnp.einsum("htc,hcv->htv", q * eG, S, precision=_PREC) + jnp.einsum(
         "hti,hiv->htv", jnp.tril(qk), u, precision=_PREC,
     )

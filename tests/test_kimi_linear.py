@@ -108,24 +108,98 @@ def test_kda_chunked_survives_decays_no_product_could_be_divided_by(over, decay,
     np.testing.assert_allclose(S_c, S_r[0], rtol=2e-4, atol=2e-5)
 
 
+def _walk(jaxpr):
+    """Every equation of a traced program, those of its loops' bodies too."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _walk(sub)
+
+
+def _kda_chunked_jaxpr(T, H, d):
+    spec = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
+    return jax.make_jaxpr(delta_rule.kda_chunked)(
+        spec(T, H, d), spec(T, H, d), spec(T, H, d), spec(T, H, d), spec(T, H), spec(H, d, d)
+    ).jaxpr
+
+
 def test_kda_chunked_never_forms_a_decay_for_every_pair_of_a_chunk():
     """The pair terms go by sub-blocks: no value of the traced program, the
-    scan's body included, is as large as ``[H, CHUNK, CHUNK, d_k]``."""
-    T, H, d = 128, 4, 128
-    spec = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
-    jaxpr = jax.make_jaxpr(delta_rule.kda_chunked)(
-        spec(T, H, d), spec(T, H, d), spec(T, H, d), spec(T, H, d), spec(T, H), spec(H, d, d)
-    )
+    scan's body included, is as large as ``[H, CHUNK, CHUNK, d_k]``, so none
+    is that for every chunk of the call either."""
+    n, H, d = 4, 4, 128
+    C = delta_rule.CHUNK
+    shapes = [v.aval.shape for eqn in _walk(_kda_chunked_jaxpr(n * C, H, d)) for v in eqn.outvars]
+    sizes = [int(np.prod(shape)) for shape in shapes]
+    assert len(sizes) > 50 and max(sizes) >= n * C * H * d  # the walk reached the scan's body
+    assert max(sizes) < H * C * C * d
+    assert not [shape for shape in shapes if shape[-3:] == (C, C, d)]
 
-    def sizes(jaxpr):
-        for eqn in jaxpr.eqns:
-            yield from (v.aval.size for v in eqn.outvars)
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                yield from sizes(sub)
 
-    seen = list(sizes(jaxpr.jaxpr))
-    assert len(seen) > 50 and max(seen) >= T * H * d  # the walk reached the scan's body
-    assert max(seen) < H * delta_rule.CHUNK * delta_rule.CHUNK * d
+def test_kda_chunked_calls_no_solver():
+    """The chunk's system is inverted by blocks: no ``triangular_solve`` is
+    left in the traced program. One scan over the chunks carries the state,
+    and the only loop inside it is the diagonal blocks' elimination."""
+    n, H, d = 4, 4, 128
+    jaxpr = _kda_chunked_jaxpr(n * delta_rule.CHUNK, H, d)
+    names = [eqn.primitive.name for eqn in _walk(jaxpr)]
+    assert not [name for name in names if "solve" in name or name == "custom_call"]
+    loops = lambda jaxpr: [e for e in _walk(jaxpr) if e.primitive.name in ("scan", "while")]  # noqa: E731
+    over_chunks = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+    assert len(over_chunks) == 1 and over_chunks[0].params["length"] == n
+    inner = loops(over_chunks[0].params["jaxpr"].jaxpr)
+    assert len(inner) == 1 and len(loops(jaxpr)) == 2
+    blocks = (H, delta_rule.CHUNK // delta_rule.BLOCK, delta_rule.BLOCK, delta_rule.BLOCK)
+    assert [v.aval.shape for v in inner[0].outvars if v.aval.ndim] == [blocks]
+
+
+@pytest.mark.parametrize("size", [0.2, 1.0, 2.0], ids=["entries_to_0.2", "entries_to_1", "entries_to_2"])
+@pytest.mark.parametrize("H", [3, 64])
+def test_the_inverse_by_blocks_is_the_triangular_solve(H, size):
+    """``(I + A)^-1`` by blocks against ``solve_triangular(I + A, I)`` on
+    random strictly lower ``A``: nothing is assumed of the entries. At 2 the
+    inverse's own reach 1e10 and float32 holds neither result to more than
+    three digits of the largest, so each matrix is read by its distance from
+    the inverse taken in float64, over its largest entry: the blocks' may be
+    a few times the solver's (their merging products are not a substitution)
+    and no more."""
+    C = delta_rule.CHUNK
+    A = jnp.tril(jax.random.uniform(jax.random.key(H), (H, C, C), minval=-size, maxval=size), -1)
+    eye = jnp.eye(C)
+    inv = np.asarray(delta_rule._unit_lower_inverse(A))
+    solved = np.asarray(jax.scipy.linalg.solve_triangular(
+        eye + A, jnp.broadcast_to(eye, A.shape), lower=True, unit_diagonal=True
+    ))
+    exact = np.linalg.inv(np.eye(C) + np.asarray(A, np.float64))
+    far = lambda X: np.abs(X - exact).max(axis=(-2, -1)) / np.abs(exact).max(axis=(-2, -1))  # noqa: E731
+    assert (far(inv) <= np.maximum(10 * far(solved), 2e-6)).all()
+    assert far(inv).max() < (3e-6 if size <= 1 else 1e-3)
+    assert (np.triu(inv, 1) == 0).all() and (np.diagonal(inv, axis1=-2, axis2=-1) == 1).all()
+    if size < 1:  # an inverse of entries near one: every entry to its own size too
+        np.testing.assert_allclose(inv, exact, rtol=2e-4, atol=2e-6)
+
+
+@pytest.mark.parametrize("decay", [(0.01, 0.1), (0.001, 1.6)], ids=["slow_decay", "initialiser_decay"])
+@pytest.mark.parametrize("T, H, d", [(200, 3, 8), (130, 2, 128)])
+def test_kda_chunked_at_beta_2_on_keys_nearly_parallel(T, H, d, decay):
+    """The system's worst case here: ``beta`` 1.99 at every position and keys
+    a few degrees apart, so every entry under the diagonal is near 2 (where
+    the decay leaves it: a channel keeps half of itself over a chunk at the
+    slow end) and the writes alternate in sign: a series in the system's
+    powers would have terms past 2^15 before they cancel. (With next to no
+    decay at all, 0.0001-0.01 a token, the chunked form leaves these
+    tolerances whoever solves it: substitution by 3.5 times at 128 wide, the
+    blocks by 5.)"""
+    q, _, v, g, _, S0 = _kda_inputs(jax.random.key(T), T, H, d, decay=decay)
+    ks = jax.random.split(jax.random.key(d), 2)
+    k = jax.random.normal(ks[0], (1, H, d)) + 0.05 * jax.random.normal(ks[1], (T, H, d))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    assert float(jnp.einsum("thd,shd->hts", k, k).min()) > 0.7
+    beta = jnp.full((T, H), 1.99)
+    o_c, S_c = delta_rule.kda_chunked(q, k, v, g, beta, S0)
+    o_r, S_r = ref.kda_recurrence(*(a[None] for a in (q, k, v, g, beta, S0)))
+    np.testing.assert_allclose(o_c, o_r[0], rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(S_c, S_r[0], rtol=2e-4, atol=2e-5)
 
 
 def test_kda_positions_with_beta_0_and_g_0_leave_the_state_alone():

@@ -10,6 +10,7 @@ partition a Mosaic call.
 
 import dataclasses
 import functools
+import math
 import re
 
 import jax
@@ -396,10 +397,17 @@ def test_solar_open2s_served_programs_compile_for_one_chip(v5e_2x2, program):
         calls = mosaic_calls(text)
         assert "paged_decode_attention" not in calls
         # The scans' pair terms go by sub-blocks (ops/delta_rule.py): the
-        # compiler re-forms no decay for every pair of a chunk's positions.
+        # compiler re-forms no decay for every pair of a chunk's positions,
+        # for one chunk or with the call's 32 in front.
         H, C, d = cfg.kda_heads, delta_rule.CHUNK, cfg.kda_head_dim
-        assert (H, d) == (64, 128) and f"f32[{H},{C},{d}]" in text  # the pattern can match
-        assert f"f32[{H},{C},{C},{d}]" not in text
+        n = 2048 // C
+        shapes = {tuple(map(int, s.split(","))) for s in re.findall(r"f32\[([\d,]+)\]", text)}
+        assert (H, d) == (64, 128) and (H, C, d) in shapes  # the pattern can match
+        # (The chunked inputs, [n, H, C, d], end the same way here: H == C.)
+        assert (n, H, C, d) in shapes and n * H * C * d < H * C * C * d
+        assert not [s for s in shapes if s[-3:] == (C, C, d) and math.prod(s) >= H * C * C * d]
+        # The chunk's system is inverted by products: no solver is called.
+        assert not re.search("triangular-solve|TriangularSolve|InvertDiagBlocks", text)
     else:
         compiled = jax.jit(
             functools.partial(paged.paged_decode, cfg=cfg, block_size=bs), donate_argnums=4

@@ -1,6 +1,8 @@
-"""The delta rule's chunked prefill alone, on the chip, by the rows of a block.
+"""The delta rule's chunked prefill alone, on the chip, by the rows of a block
+or against another copy of the module.
 
     python tools/delta_rule_chip.py [--blocks 8,16,32,64] [--heads 64,32] [--tokens 2048]
+    python tools/delta_rule_chip.py --against _parent/ray_tpu/ops/delta_rule.py
 
 ``ops.delta_rule.kda_chunked`` at the shapes of the two cells that run it
 (`serve-longdoc-solaropen2`: 64 heads a layer; `serve-batch-kimilinear`: 32;
@@ -11,14 +13,20 @@ as before PR 44, beside a product with no column left to take (10.6 ms at 64
 heads where that PR's parent read 9.17). A line a setting: the milliseconds a call
 takes by the host's clock (to ``block_until_ready``) and by the device trace,
 how far its result lies from the first setting's, and the trace's operations
-a call, longest first (milliseconds). Needs a TPU: a time from another
-backend says nothing (PERF.md section 6, PR 44, holds the v5e's readings).
-The last line of standard output is one JSON list.
+a call, longest first (milliseconds). With ``--against <path to a
+delta_rule.py>`` (another commit's, from ``git archive``; more than once for
+more than one), this checkout's module and each of those are timed beside one
+another at their own ``BLOCK`` (or at each of ``--blocks``, where given): the
+same line a module, ``module`` naming it, its differences those from this
+checkout's result. Needs a TPU: a time from another backend says nothing
+(PERF.md section 6, PR 44 and PR 45, holds the v5e's readings). The last line
+of standard output is one JSON list.
 """
 
 from __future__ import annotations
 
 import argparse
+import importlib.util
 import json
 import os
 import shutil
@@ -53,7 +61,7 @@ def inputs(key, T, H, d=WIDTH):
     )
 
 
-def time_calls(run, args):
+def time_calls(run, args, top):
     """Host and device milliseconds a call over ``CALLS`` calls, and the
     device's operations a call."""
     jax.block_until_ready(run(*args))  # compiled, outside the timing
@@ -71,31 +79,54 @@ def time_calls(run, args):
         )
     finally:
         shutil.rmtree(log_dir, ignore_errors=True)
-    ops = [[name, round(s / CALLS * 1e3, 3)] for name, s in reduced["ops"][:12]]
+    ops = [[name, round(s / CALLS * 1e3, 3)] for name, s in reduced["ops"][:top]]
     return host_ms, reduced["busy_s"] / CALLS * 1e3, ops
+
+
+def load(path):
+    """Another copy of ``ops/delta_rule.py``, as a module of its own."""
+    spec = importlib.util.spec_from_file_location("delta_rule_at_" + str(abs(hash(path))), path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
 
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--blocks", default=f"8,16,32,{delta_rule.CHUNK}", help="comma-separated rows a block")
+    ap.add_argument(
+        "--blocks", default=None,
+        help=f"comma-separated rows a block (8,16,32,{delta_rule.CHUNK}; with --against each module's own)",
+    )
+    ap.add_argument(
+        "--against", action="append", default=[], metavar="PATH",
+        help="another delta_rule.py to time beside this checkout's; may be given again",
+    )
     ap.add_argument("--heads", default="64,32", help="comma-separated heads a layer")
     ap.add_argument("--tokens", type=int, default=2048)
+    ap.add_argument("--ops", type=int, default=12, help="operations printed a line, longest first")
     args = ap.parse_args()
     if jax.default_backend() != "tpu":
         raise SystemExit("needs a TPU: the scan's time is a device time")
+    modules = [("this checkout", delta_rule)] + [(path, load(path)) for path in args.against]
+    if args.blocks:
+        blocks = lambda module: map(int, args.blocks.split(","))  # noqa: E731
+    elif args.against:
+        blocks = lambda module: [module.BLOCK]  # noqa: E731
+    else:
+        blocks = lambda module: [8, 16, 32, delta_rule.CHUNK]  # noqa: E731
     out = []
     for H in map(int, args.heads.split(",")):
         operands = inputs(jax.random.key(H), args.tokens, H)
         first = None
-        for block in map(int, args.blocks.split(",")):
-            delta_rule.BLOCK = block
+        for (name, module), block in ((m, b) for m in modules for b in blocks(m[1])):
+            module.BLOCK = block
             jax.clear_caches()  # the scan keeps its body's trace by the function
-            run = jax.jit(delta_rule.kda_chunked)
+            run = jax.jit(module.kda_chunked)
             o, S = run(*operands)
             first = first or (o, S)
-            host_ms, device_ms, ops = time_calls(run, operands)
+            host_ms, device_ms, ops = time_calls(run, operands, args.ops)
             row = {
-                "heads": H, "tokens": args.tokens, "block": block,
+                "module": name, "heads": H, "tokens": args.tokens, "block": block,
                 "host_ms": round(host_ms, 3), "device_ms": round(device_ms, 3),
                 "o_diff": float(jnp.max(jnp.abs(o - first[0]))),
                 "S_diff": float(jnp.max(jnp.abs(S - first[1]))),
