@@ -15,8 +15,8 @@ by slot beside the pool and is none of this class's business: it is sized by
 ``max_slots``, reset by the prefill that starts a sequence, and so neither
 allocated nor freed.
 
-A family whose layers are of two kinds (``models/paged.py``, the fourth shape
-of pool) has a second part of the pool, for the layers that attend only the
+A family whose layers are of two kinds (``models/paged.py``, a second table
+kind that keeps a window) has a second part of the pool, for the layers that attend only the
 last ``window`` positions, and :class:`WindowBlocks` keeps its books: a
 ``BlockManager`` of its own and a table a slot beside the slot's other one.
 A block of a window layer is ``block_size`` positions of keys and values of

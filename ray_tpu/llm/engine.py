@@ -142,54 +142,6 @@ def _validate_block_multiple(name: str, value: int, block_size: int) -> None:
         )
 
 
-def _why_not(cfg, what: str) -> Optional[str]:
-    """Why the engine cannot do ``what`` (``spec``: verify speculatively,
-    ``tp``: shard, ``handoff``: export or import the cache) for ``cfg``'s
-    family, or None for a family of keys and values per head served through
-    ``kv_hooks``."""
-    if len(paged.retention(cfg)) > 1:
-        fact = (
-            f"the family {cfg.family!r} keeps a block table per layer kind and "
-            "gives window blocks back while a request runs"
-        )
-        reason = {
-            "spec": "paged_verify reads one table a slot through kv_hooks",
-            "tp": "the family has no sharding rules yet",
-            "handoff": "the handoff exports and imports one pool under one table",
-        }
-    elif paged.has_recurrent_state(cfg):
-        fact = f"the family {cfg.family!r} keeps a recurrent state per slot"
-        reason = {
-            "spec": "rejected tokens cannot be taken back out of it",
-            "tp": "neither it nor the family's experts have sharding rules yet",
-            "handoff": "a handoff of pool blocks does not carry it",
-        }
-    elif paged.brings_own_programs(cfg):
-        fact = (
-            f"the family {cfg.family!r} brings its own paged programs over a "
-            "cache that is not keys and values per head"
-        )
-        reason = {
-            "spec": "paged_verify scores through kv_hooks only",
-            "tp": "the family has no sharding rules yet",
-            "handoff": "the handoff exports and imports the pool's keys and values",
-        }
-    else:
-        return None
-    return f"{fact}, and {reason[what]}"
-
-
-def _refuse_at_construction(cfg, config: LLMConfig) -> None:
-    """What the engine cannot do for the family is refused here, at
-    construction and by name, not as a shape error at the first request."""
-    if config.spec_decode_tokens > 0 and _why_not(cfg, "spec"):
-        raise ValueError(
-            f"spec_decode_tokens > 0 (speculative verification): {_why_not(cfg, 'spec')}"
-        )
-    if config.tensor_parallelism > 1 and _why_not(cfg, "tp"):
-        raise ValueError(f"tensor_parallelism > 1: {_why_not(cfg, 'tp')}")
-
-
 @dataclasses.dataclass
 class _Request:
     request_id: str
@@ -268,20 +220,18 @@ class LLMEngine:
         if cfg.vocab_size < self.tokenizer.vocab_size:
             raise ValueError("model vocab smaller than tokenizer vocab")
         self.model_config = cfg
-        _refuse_at_construction(cfg, config)
         self._model = paged.family(cfg)
-        # Two facts about a family whose cache is not keys and values per
-        # head (models/paged.py, "What a pool is now"). It brings its own
-        # programs: packed ``meta`` operand, ``live`` mask, counters
-        # behind the logits, nothing that goes through ``kv_hooks``. It
-        # keeps a state per slot: resets at position 0, a scratch row, no
-        # prefix cache. The second implies the first. A third fact, asked
-        # the same way: how long each of its layer kinds keeps a position.
-        # Every family but one has one kind, which keeps everything; a second
-        # kind keeps a window, and the slot a second table (below).
-        self._own_programs = paged.brings_own_programs(cfg)
-        self._slot_state = paged.has_recurrent_state(cfg)
-        kinds = paged.retention(cfg)
+        # What the family keeps for a request (models/paged.py, "What a pool
+        # is made of"), read once: a state per slot (resets at position 0,
+        # counted), and the table kinds. Every family but one has one kind,
+        # which keeps everything; a second kind keeps a window, and the slot
+        # a second table (below).
+        self._cache = paged.cache(cfg)
+        if config.spec_decode_tokens > 0:
+            self._refuse("spec_decode_tokens > 0", "speculative verification")
+        if config.tensor_parallelism > 1:
+            self._refuse("tensor_parallelism > 1", "tensor parallelism")
+        kinds = self._cache.retention
         assert kinds[0] is None and len(kinds) <= 2, kinds
         devices = jax.devices()
         tp = config.tensor_parallelism
@@ -347,9 +297,6 @@ class LLMEngine:
                 kinds[1], paged.window_blocks_a_slot(kinds[1], self._window_span, bs), bs,
                 self.block_tables[:, W:],
             )
-        # Neither is served from the prefix pool: a hit would need the state,
-        # or the window blocks, at the prefix's end.
-        self._no_prefix = self._slot_state or self._window is not None
         # A family with a recurrent state keeps one row of it a slot
         # (and a scratch row) beside the blocks: max_slots sizes it.
         self.pool = paged.init_block_pool(
@@ -364,41 +311,32 @@ class LLMEngine:
         # the output is the input's buffer and no step copies 2 x
         # [L, N, KH, block, Dh]. Every call rebinds self.pool; the
         # array passed in is deleted and nothing may keep it.
-        if self._own_programs:
-            # The same two names for a family that brings its programs.
-            # They take the slot (prefill) and the live slots
-            # (decode) as well, and every small operand of a call
-            # rides in ONE int32 array (``meta``), handed over as
-            # numpy: an upload costs the host 0.5-0.6 ms a piece, and
-            # at a 13 ms step three more of them were a tenth of the
-            # step and most of its run-to-run noise (PERF.md section
-            # 6, PR 29). The prefill's counters are packed behind its
-            # logits, so that they ride the one read-back an admission
-            # makes anyway (_take_counters unpacks them).
-            def paged_prefill(params, tokens, meta, pool):
-                # meta [3 + W a kind]: length, start, slot, the block table(s)
-                table = meta[3:] if len(kinds) == 1 else meta[3:].reshape(len(kinds), W)
-                pool, logits, counts = paged.paged_prefill(
-                    params, tokens, meta[0], meta[1], table, pool,
-                    cfg=cfg, block_size=bs, slot=meta[2],
-                )
-                return pool, jnp.concatenate(
-                    [logits, counts.reshape(-1).astype(logits.dtype)]
-                )
+        # One layout a program for every family: every small operand of a
+        # call rides in ONE int32 array (``meta``), handed over as numpy
+        # (an upload costs the host 0.5-0.6 ms a piece, and at a 13 ms step
+        # three more of them were a tenth of the step and most of its
+        # run-to-run noise: PERF.md section 6, PR 29), and a family's
+        # counters ride behind what the host reads anyway. paged.paged_prefill
+        # and paged.paged_decode return ``(pool, logits)`` and, from a family
+        # that has counters, a third value (benchmarks/ unpacks two from the
+        # hook families'), so both wrappers take ``pool, logits, *counts``.
+        def paged_prefill(params, tokens, meta, pool):
+            # meta [3 + W a kind]: length, start, slot, the block table(s);
+            # the counters behind the logits are unpacked by _take_counters.
+            table = meta[3:] if len(kinds) == 1 else meta[3:].reshape(len(kinds), W)
+            pool, logits, *counts = paged.paged_prefill(
+                params, tokens, meta[0], meta[1], table, pool,
+                cfg=cfg, block_size=bs, slot=meta[2],
+            )
+            return pool, jnp.concatenate(
+                [logits, *(c.reshape(-1).astype(logits.dtype) for c in counts)]
+            )
 
-            self._pg_prefill = jax.jit(paged_prefill, donate_argnums=3)
-        else:
-            def paged_prefill(params, tokens, length, start, table, pool):
-                return paged.paged_prefill(
-                    params, tokens, length, start, table, pool,
-                    cfg=cfg, block_size=bs,
-                )
-
-            self._pg_prefill = jax.jit(paged_prefill, donate_argnums=5)
+        self._pg_prefill = jax.jit(paged_prefill, donate_argnums=3)
 
         def paged_decode(params, prev, meta, pool):
-            # One layout for every family. meta [B, 4 + W], numpy: position,
-            # live, the host's token, whether to use it, the block table.
+            # meta [B, 4 + W a kind], numpy: position, live, the host's
+            # token, whether to use it, the block table(s).
             # ``prev`` is the third output of the step launched before this
             # one, still on the device: a row whose last token the host has
             # not seen (it is in that step) takes it from there, so a
@@ -407,23 +345,14 @@ class LLMEngine:
             # the first index on ties, as np.argmax; a family's counters
             # ride behind the tokens, in the one small array a turn reads.
             tokens = jnp.where(meta[:, 3] > 0, meta[:, 2], prev[: meta.shape[0]])
-            if self._own_programs:
-                tables = meta[:, 4:] if len(kinds) == 1 else meta[:, 4:].reshape(-1, len(kinds), W)
-                pool, logits, counts = paged.paged_decode(
-                    params, tokens, meta[:, 0], tables, pool,
-                    cfg=cfg, block_size=bs, live=meta[:, 1] > 0,
-                )
-                behind = counts.reshape(-1).astype(jnp.int32)
-            else:
-                pool, logits = paged.paged_decode(
-                    params, tokens, meta[:, 0], meta[:, 4:], pool,
-                    cfg=cfg, block_size=bs, mesh=self.mesh,
-                )
-                behind = jnp.zeros(0, jnp.int32)
-            chosen = jnp.argmax(logits.astype(jnp.float32), axis=-1)
-            return pool, logits, jnp.concatenate(
-                [chosen.astype(jnp.int32), behind]
+            tables = meta[:, 4:] if len(kinds) == 1 else meta[:, 4:].reshape(-1, len(kinds), W)
+            pool, logits, *counts = paged.paged_decode(
+                params, tokens, meta[:, 0], tables, pool,
+                cfg=cfg, block_size=bs, live=meta[:, 1] > 0, mesh=self.mesh,
             )
+            behind = [c.reshape(-1).astype(jnp.int32) for c in counts]
+            chosen = jnp.argmax(logits.astype(jnp.float32), axis=-1)
+            return pool, logits, jnp.concatenate([chosen.astype(jnp.int32), *behind])
 
         self._pg_decode = jax.jit(paged_decode, donate_argnums=3)
         # The decode step that has been launched and not read (step()),
@@ -498,11 +427,11 @@ class LLMEngine:
         for path, arr in jax.tree_util.tree_flatten_with_path(self.pool)[0]:
             part = "_".join(str(k.key) for k in path)  # bytes of each cache part
             self.stats[f"cache_bytes_{part}"] = int(arr.nbytes)
-        if self._slot_state:
+        if self._cache.slot_state:
             # Prefills that began a sequence and so began from zero state,
             # whatever the slot held.
             self.stats["state_resets"] = 0
-        if self._no_prefix:
+        if not self._cache.shares_prefixes:
             # Admissions that would have looked a prefix up and could not (a
             # hit needs the state, or the window layers' blocks, at the
             # prefix's end: neither is kept).
@@ -548,6 +477,16 @@ class LLMEngine:
                 self, config.draft_model_config, config.spec_decode_tokens
             )
 
+    def _refuse(self, option: str, what: str) -> None:
+        """What the engine cannot do for the family (``what``: speculative
+        verification, tensor parallelism, the disaggregated handoff) is
+        refused by name where ``option`` asks for it, at construction or at
+        the request, not as a shape error later: the family's record says
+        why."""
+        why = self._cache.why_not(self.model_config.family, what)
+        if why:
+            raise ValueError(f"{option}: {why}")
+
     # -- admission -----------------------------------------------------------
     def add_request(
         self,
@@ -564,11 +503,8 @@ class LLMEngine:
         monotonic time the caller took the request in, where that was
         earlier than this call (the flight recorder's ``llm.queue`` span
         starts there)."""
-        if prefill_only and self._own_programs:
-            raise ValueError(
-                "prefill_only (the disaggregated KV export): "
-                + _why_not(self.model_config, "handoff")
-            )
+        if prefill_only:
+            self._refuse("prefill_only (the KV export)", "the disaggregated handoff")
         sampling = sampling or SamplingParams()
         ids = (
             self.tokenizer.encode(prompt)
@@ -627,11 +563,7 @@ class LLMEngine:
         fails, in which case admission falls back to the local, chunked
         when configured, prefill path). Counts neither requests_total nor
         prompt_tokens: the prefill replica already did."""
-        if self._own_programs:
-            raise ValueError(
-                "a disaggregated handoff (KV import): "
-                + _why_not(self.model_config, "handoff")
-            )
+        self._refuse("a handoff request (the KV import)", "the disaggregated handoff")
         sampling = sampling or SamplingParams()
         stop = (
             sampling.stop_token
@@ -678,7 +610,7 @@ class LLMEngine:
         state, or with layers that keep a window, is never served from the
         pool: a hit would need the state, or the blocks behind the window,
         at the prefix's end."""
-        if not self.config.enable_prefix_caching or self._no_prefix:
+        if not (self.config.enable_prefix_caching and self._cache.shares_prefixes):
             return None
         self.stats["prefix_lookups"] += 1
         chain = self._chain_hashes(prompt)
@@ -693,7 +625,7 @@ class LLMEngine:
     def _insert_prefix(self, prompt: list, blocks: list) -> None:
         """Pool the prompt's longest aligned prefix: take a reference on
         the request's first P/block blocks — sharing, not copying."""
-        if not self.config.enable_prefix_caching or self._no_prefix:
+        if not (self.config.enable_prefix_caching and self._cache.shares_prefixes):
             return
         p = self._aligned_prefix_len(len(prompt))
         if p < self.config.prefix_chunk or p > self.config.max_prefix_cache_tokens:
@@ -881,7 +813,7 @@ class LLMEngine:
         if _flightrec.on():
             extra["wave"] = self._wave["wave"]
             extra["seq"] = self.stats["programs_launched"]
-            if self._slot_state:  # began from the slot's state, or from zero
+            if self._cache.slot_state:  # began from the slot's state, or from zero
                 extra["state_carried"] = int(extra.get("start", 0) > 0)
             req.pf_open = (phase, t_pf, extra)
 
@@ -916,13 +848,11 @@ class LLMEngine:
         )
 
     def _take_counters(self, out: np.ndarray, req: _Request) -> np.ndarray:
-        """The logits of a prefill whose read-back is ``out``. A family
-        that brings its programs packs its counters behind them
-        (__init__): they go onto the prefill span still open on ``req``."""
-        if not self._own_programs:
-            return out
+        """The logits of a prefill whose read-back is ``out``. What a
+        family's program packed behind them (__init__) is its counters: they
+        go onto the prefill span still open on ``req``."""
         V = self.model_config.vocab_size
-        if req.pf_open is not None:
+        if out.size > V and req.pf_open is not None:
             phase, t_pf, extra = req.pf_open
             extra = {
                 **extra,
@@ -943,17 +873,10 @@ class LLMEngine:
         if self._window is not None:
             self._advance_window(slot, start, start + n)
             row = self.block_tables[slot]  # with the window kind's entries
-        if self._own_programs:
-            if self._slot_state and start == 0:
-                # begins from zero state, whatever the slot held
-                self.stats["state_resets"] += 1
-            meta = np.concatenate([[n, start, slot], row]).astype(np.int32)
-            args = (self.params, toks, meta)
-        else:
-            args = (
-                self.params, jnp.asarray(toks), jnp.asarray(n, jnp.int32),
-                jnp.asarray(start, jnp.int32), jnp.asarray(row),
-            )
+        if self._cache.slot_state and start == 0:
+            # begins from zero state, whatever the slot held
+            self.stats["state_resets"] += 1
+        meta = np.concatenate([[n, start, slot], row]).astype(np.int32)
         if self._moe_arm:
             self.stats[self._moe_arm] += 1
         bucket = toks.shape[1]
@@ -970,7 +893,7 @@ class LLMEngine:
         self.stats["prefill_tokens"] += n
         self.stats["prefill_tokens_padded"] += bucket
         self.stats["programs_launched"] += 1
-        self.pool, out = self._pg_prefill(*args, self.pool)
+        self.pool, out = self._pg_prefill(self.params, toks, meta, self.pool)
         return out
 
     def _advance_window(self, slot: int, first_query: int, upto: int) -> None:
@@ -1178,7 +1101,7 @@ class LLMEngine:
             self.stats["prefix_hits"] += 1
             self.stats["prefix_tokens_reused"] += P
             self._in_wave()["reused"] += P  # a slot or a launch follows
-        if self._no_prefix and self.config.enable_prefix_caching:
+        if self.config.enable_prefix_caching and not self._cache.shares_prefixes:
             self.stats["prefix_cache_bypassed"] += 1  # once an admission
         if self._chunks_feasible(P, T):
             self._begin_chunked_prefill(req, slot, P)
@@ -1348,7 +1271,7 @@ class LLMEngine:
         logits = self._prefill_one_chunk(req)
         T = len(req.prompt)
         if req.pf_next < T:
-            if self._own_programs and req.pf_open is not None:
+            if logits.shape[0] > self.model_config.vocab_size and req.pf_open is not None:
                 req.pf_late = (logits, _time.monotonic())  # its counters are read later
             else:
                 self._close_prefill_span(req)  # logits never read: the launch
@@ -1615,7 +1538,7 @@ class LLMEngine:
         else:
             logits_np = np.asarray(cur.logits)  # raylint: disable=RL101 -- the synchronous arm's ONE intended sync: batched logits readback feeding host-side sampling
             copied = logits_np.nbytes
-            if fr and self._own_programs:
+            if fr and cur.small.shape[0] > B:
                 counters = np.asarray(cur.small)[B:]  # raylint: disable=RL101 -- the programs' counters, ready with the logits
                 copied += cur.small.nbytes
         t_read = _time.monotonic() if fr else 0.0

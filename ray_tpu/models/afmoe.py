@@ -23,11 +23,12 @@ The layer (four norms, ``x += Norm(Sublayer(Norm(x)))`` twice):
   RMSNorm(f)``.
 - Final RMSNorm; untied head.
 
-The cache is the fourth shape of pool (:mod:`ray_tpu.models.paged`, "What a
-pool is now"): ``{"full": {"k", "v"}, "window": {"k", "v"}}``, each ``[layers of
-the kind, blocks of the part, KH, block, Dh]``, under a block table a kind
+The cache has a second table kind that keeps a window
+(:mod:`ray_tpu.models.paged`, "What a pool is made of"): ``{"full": {"k", "v"},
+"window": {"k", "v"}}``, each ``[layers of the kind, blocks of the part, KH,
+block, Dh]``, under a block table a kind
 (``tables [..., 2, W]``; one table ``[..., W]`` serves both where nothing was
-given back). :func:`retention` tells the engine that the second kind keeps
+given back). :func:`cache` tells the engine that the second kind keeps
 ``sliding_window`` positions only: its blocks behind the window go back to the
 free list while the request runs, and its table points at the scratch block
 there. Decode attends through :func:`paged.decode_attention` (the kernel's
@@ -48,7 +49,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models import latent_moe, paged
 from ray_tpu.models.latent_moe import final_logits, moe_ffn, outputs
-from ray_tpu.models.llama import _rms_norm
+from ray_tpu.models.common import _rms_norm
 
 Params = dict
 _F32 = jnp.float32
@@ -56,14 +57,11 @@ _F32 = jnp.float32
 SLIDING, FULL = "sliding_attention", "full_attention"
 PUBLISHED_LAYER_TYPES = (SLIDING, SLIDING, SLIDING, FULL) * 15
 
-has_recurrent_state = False
-kv_per_head = True  # both parts of the cache: paged.decode_attends_in_place asks
 
-
-def retention(cfg) -> tuple:
-    """Positions each layer kind keeps (:func:`paged.retention`): a full
-    layer all, a sliding layer the window."""
-    return (None, cfg.sliding_window)
+def cache(cfg) -> paged.Cache:
+    """Keys and values per head in blocks under a table a layer kind: a full
+    layer keeps every position, a sliding layer the window."""
+    return paged.Cache(retention=(None, cfg.sliding_window))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -327,7 +325,7 @@ def span_fields(cfg: AfmoeConfig, counts, tokens: int, slots: int, decode=None) 
     """:func:`ray_tpu.models.latent_moe.span_fields` of the expert layers
     (``slots`` and ``decode`` name nothing here: no state is stepped, and the
     rows of keys and values a step needs and reads, by kind, are the engine's
-    own count off the positions and :func:`retention`)."""
+    own count off the positions and the window)."""
     return latent_moe.span_fields(cfg, counts, tokens)
 
 

@@ -19,6 +19,13 @@ import jax
 import jax.numpy as jnp
 
 
+def _rms_norm(x, scale, eps):
+    """RMSNorm in float32, back in ``x``'s dtype: every family's but GPT-2's."""
+    x32 = x.astype(jnp.float32)
+    rms = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (x32 * rms * scale).astype(x.dtype)
+
+
 def chunked_lm_loss(
     x: jax.Array, wte: jax.Array, targets: jax.Array, chunk: int
 ) -> jax.Array:

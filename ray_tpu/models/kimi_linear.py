@@ -13,19 +13,20 @@ What this file holds, top down:
 - :class:`KimiLinearConfig` and :func:`init_params`. Layers differ in kind, so
   ``params["layers"]`` is a list with one dict a layer and the programs unroll
   it; nothing is stacked and nothing is sliced out of a stack.
-- the mixers, each in the two forms serving needs: :func:`kda_prefill` /
-  :func:`kda_decode` over :mod:`ray_tpu.ops.delta_rule`. The latent mixer
+- the mixers are other modules': KDA (``kda_prefill`` / ``kda_decode``) is
+  :mod:`ray_tpu.models.kda`'s, shared with ``solar_open2``; the latent mixer
   (``mla_latent``, ``mla_prefill``, ``mla_decode``) and the expert layer
-  (``route``, ``moe_ffn``) are :mod:`ray_tpu.models.latent_moe`'s, which this
-  family shares with ``mla_moe``: here without rotation, without a low-rank
-  query and with one expert group, by leaving those arguments off.
+  (``route``, ``moe_ffn``) are :mod:`ray_tpu.models.latent_moe`'s, shared with
+  ``mla_moe``: here without rotation, without a low-rank query and with one
+  expert group, by leaving those arguments off.
 - the paged programs :func:`paged_prefill` / :func:`paged_decode` and
   :func:`init_pool`, which :mod:`ray_tpu.models.paged` hands a
   ``KimiLinearConfig`` to. The cache is ``{"ckv": [L_mla, N, block, 576],
   "state": [L_kda, slots + 1, H, d_k, d_v] float32, "conv": [L_kda, slots + 1,
   3, 3 H d]}``: latent rows in blocks under the engine's block tables, and a
-  recurrent state and a convolution tail per slot. Row ``slots`` of the last
-  two is scratch: slots that are free, or still prefilling, step there.
+  recurrent state and a convolution tail per slot, handled as
+  :func:`paged.state_prefill` and :func:`paged.state_decode` say. Row ``slots``
+  of the last two is scratch: a prefill that names no slot runs there.
 """
 
 from __future__ import annotations
@@ -38,6 +39,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import latent_moe, paged
+from ray_tpu.models.kda import draw_kda, kda_decode, kda_prefill
 from ray_tpu.models.latent_moe import (  # noqa: F401 -- moe_ffn and route: the family's surface
     ffn,
     final_logits,
@@ -48,8 +50,7 @@ from ray_tpu.models.latent_moe import (  # noqa: F401 -- moe_ffn and route: the 
     outputs,
     route,
 )
-from ray_tpu.models.llama import _rms_norm
-from ray_tpu.ops.delta_rule import kda_chunked, kda_step
+from ray_tpu.models.common import _rms_norm
 
 Params = dict
 _F32 = jnp.float32
@@ -232,94 +233,6 @@ def draw_params(key: jax.Array, cfg: KimiLinearConfig) -> Params:
 
 
 # ---------------------------------------------------------------------------
-# KDA mixer (this family's and ``solar_open2``'s: one implementation)
-
-
-def draw_kda(w, keys, cfg, resid: float) -> Params:
-    """A KDA layer's random weights: ``w(shape, std)`` draws a matrix in the
-    parameter dtype and ``keys`` yields a key a draw, both the caller's, so
-    that a family's weights come off one stream in one order; ``resid`` is the
-    deviation of the projection back to the residual stream. ``A_log`` and
-    ``dt_bias`` as :func:`draw_params` says."""
-    D, H, dk, r = cfg.d_model, cfg.kda_heads, cfg.kda_head_dim, cfg.kda_gate_rank
-    dt = jnp.exp(jax.random.uniform(
-        next(keys), (H * dk,), _F32, jnp.log(0.001), jnp.log(0.1)))
-    return {
-        "wqkv": w((D, cfg.conv_dim)),
-        "conv": w((cfg.conv_kernel, cfg.conv_dim), cfg.conv_kernel**-0.5),
-        "f_down": w((D, r)), "f_up": w((r, H * dk)),
-        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1(dt)
-        "A_log": jnp.log(jax.random.uniform(next(keys), (H,), _F32, 1.0, 16.0)),
-        "wb": w((D, H)),
-        "g_down": w((D, r)), "g_up": w((r, H * dk)),
-        "o_norm": jnp.ones((dk,), cfg.param_dtype),
-        "wo": w((H * dk, D), resid),
-    }
-
-
-def _kda_inputs(h, mixed, p, cfg):
-    """From the normed input ``h`` [..., D] and the convolved, SiLU'd
-    projections ``mixed`` [..., 3 H d]: ``(q, k, v, g, beta)`` with heads
-    split out, ``q`` and ``k`` normalised, ``g`` the log decay. ``beta`` is a
-    sigmoid, in (0, 1), and twice that, in (0, 2), where the configuration
-    allows the transition ``I - beta k k^T`` a negative eigenvalue along ``k``
-    (``kda_neg_eigval``: the published ``kda_allow_neg_eigval``)."""
-    H, d = cfg.kda_heads, cfg.kda_head_dim
-    dt = cfg.dtype
-    q, k, v = (
-        a.reshape(*a.shape[:-1], H, d).astype(_F32)
-        for a in jnp.split(mixed, 3, axis=-1)
-    )
-    l2 = lambda a: a * jax.lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)  # noqa: E731
-    f = (h @ p["f_down"].astype(dt)) @ p["f_up"].astype(dt)
-    g = -jnp.exp(p["A_log"].astype(_F32))[:, None] * jax.nn.softplus(
-        (f.astype(_F32) + p["dt_bias"].astype(_F32)).reshape(*f.shape[:-1], H, d)
-    )
-    beta = jax.nn.sigmoid((h @ p["wb"].astype(dt)).astype(_F32))
-    if cfg.kda_neg_eigval:
-        beta = 2.0 * beta
-    return l2(q) * d**-0.5, l2(k), v, g, beta
-
-
-def _kda_output(h, o, p, cfg):
-    """RMSNorm per head, the sigmoid output gate, ``W_o``."""
-    dt = cfg.dtype
-    gate = (h @ p["g_down"].astype(dt)) @ p["g_up"].astype(dt)
-    o = _rms_norm(o, p["o_norm"].astype(_F32), cfg.rms_eps)  # o is float32
-    o = o.reshape(*o.shape[:-2], -1) * jax.nn.sigmoid(gate.astype(_F32))
-    return o.astype(dt) @ p["wo"].astype(dt)
-
-
-def kda_prefill(h, p, cfg, S0, tail, length):
-    """``h`` [T, D] normed, of which the first ``length`` rows are tokens;
-    ``S0`` [H, d_k, d_v] and ``tail`` [K-1, 3 H d] are the state and the last
-    pre-convolution rows before row 0 (zeros at the start of a sequence).
-    Returns ``(out [T, D], S, tail)`` as of row ``length``: padded rows do not
-    touch the state."""
-    T, K = h.shape[0], cfg.conv_kernel
-    dt = cfg.dtype
-    x = jnp.concatenate([tail.astype(dt), h @ p["wqkv"].astype(dt)])  # [K-1+T, C]
-    conv = p["conv"].astype(dt)
-    mixed = sum(conv[j] * x[j : j + T] for j in range(K))
-    q, k, v, g, beta = _kda_inputs(h, jax.nn.silu(mixed), p, cfg)
-    live = (jnp.arange(T) < length)[:, None]
-    o, S = kda_chunked(q, k, v, g * live[..., None], beta * live, S0)
-    tail = jax.lax.dynamic_slice_in_dim(x, length, K - 1, axis=0)
-    return _kda_output(h, o, p, cfg), S, tail
-
-
-def kda_decode(h, p, cfg, S, tail):
-    """One token a row: ``h`` [B, D], ``S`` [B, H, d_k, d_v], ``tail`` [B,
-    K-1, 3 H d]. Returns ``(out [B, D], S, tail)``."""
-    dt = cfg.dtype
-    x = jnp.concatenate([tail.astype(dt), (h @ p["wqkv"].astype(dt))[:, None]], axis=1)
-    mixed = jnp.einsum("kc,bkc->bc", p["conv"].astype(dt), x)
-    q, k, v, g, beta = _kda_inputs(h, jax.nn.silu(mixed), p, cfg)
-    o, S = kda_step(q, k, v, g, beta, S)
-    return _kda_output(h, o, p, cfg), S, x[:, 1:]
-
-
-# ---------------------------------------------------------------------------
 # What the engine writes on a span (the latent mixer and the expert layer are
 # latent_moe's: module docstring)
 
@@ -333,7 +246,11 @@ def span_fields(cfg: KimiLinearConfig, counts, tokens: int, slots: int, decode=N
 # ---------------------------------------------------------------------------
 # The paged programs (models/paged.py dispatches here by cfg.family)
 
-has_recurrent_state = True
+
+
+def cache(cfg: KimiLinearConfig) -> paged.Cache:
+    """Latent rows in blocks, a delta-rule state and a tail per slot."""
+    return paged.Cache(slot_state=True, per_head=False)
 
 
 def init_pool(cfg: KimiLinearConfig, num_blocks: int, block_size: int, slots=None):
@@ -372,7 +289,6 @@ def paged_prefill(
     benchmark's comparison of routing)."""
     T = tokens.shape[1]
     ckv, state, conv = pool["ckv"], pool["state"], pool["conv"]
-    slot = state.shape[1] - 1 if slot is None else slot
     fresh = start == 0
 
     pos = start + jnp.arange(T, dtype=jnp.int32)
@@ -383,11 +299,10 @@ def paged_prefill(
     for i, p, kind, l in _layers(params, cfg):
         h = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
         if kind == "kda":
-            S0 = jnp.where(fresh, 0.0, state[l, slot])
-            tail = jnp.where(fresh, 0, conv[l, slot])
-            out, S1, tail = kda_prefill(h, p, cfg, S0, tail, length)
-            state = state.at[l, slot].set(S1)
-            conv = conv.at[l, slot].set(tail.astype(conv.dtype))
+            out, state, conv = paged.state_prefill(
+                lambda S, tail: kda_prefill(h, p, cfg, S, tail, length),
+                state, conv, l, slot, fresh,
+            )
         else:
             ckv = ckv.at[l, bids, offs].set(mla_latent(h, p, cfg))
             out = mla_prefill(
@@ -405,7 +320,7 @@ def paged_decode(
 ):
     """One token a slot; operands as :func:`ray_tpu.models.paged.paged_decode`,
     plus ``live`` [B] bool: a slot that is not live (free, or still prefilling
-    in chunks) steps on the scratch row of the state and is routed to no
+    in chunks) leaves its state and tail as they were and is routed to no
     expert; its logits mean nothing. None: every slot is live. The latent
     layers attend as :func:`ray_tpu.models.paged.latent_decode_attention`
     chooses (``interpret``: its kernel in the Pallas interpreter, the tests).
@@ -413,9 +328,7 @@ def paged_decode(
     2])``."""
     B = last_tokens.shape[0]
     ckv, state, conv = pool["ckv"], pool["state"], pool["conv"]
-    rows_of = jnp.arange(B)
-    if live is not None:
-        rows_of = jnp.where(live, rows_of, state.shape[1] - 1)
+    keep = None if live is None else ~live
     bids = tables[jnp.arange(B), positions // block_size]
     offs = positions % block_size
     lengths = positions + 1  # the step's own row is attended
@@ -427,9 +340,9 @@ def paged_decode(
     for i, p, kind, l in _layers(params, cfg):
         h = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
         if kind == "kda":
-            out, S1, tail = kda_decode(h, p, cfg, state[l, rows_of], conv[l, rows_of])
-            state = state.at[l, rows_of].set(S1)
-            conv = conv.at[l, rows_of].set(tail.astype(conv.dtype))
+            out, state, conv = paged.state_decode(
+                lambda S, tail: kda_decode(h, p, cfg, S, tail), state, conv, l, B, keep
+            )
         else:
             ckv = ckv.at[l, bids, offs].set(mla_latent(h, p, cfg))
             out = mla_decode(h, ckv, l, tables, lengths, p, cfg, attend)

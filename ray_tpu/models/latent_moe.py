@@ -57,7 +57,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.llama import _mlp_sublayer, _rms_norm
+from ray_tpu.models.common import _rms_norm
+from ray_tpu.models.llama import _mlp_sublayer
 from ray_tpu.ops import moe_gmm
 
 _F32 = jnp.float32

@@ -28,7 +28,7 @@ from typing import Any, ClassVar
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.common import chunked_lm_loss, pipelined_blocks
+from ray_tpu.models.common import _rms_norm, chunked_lm_loss, pipelined_blocks
 from ray_tpu.ops.attention import causal_attention, uses_flash_kernel
 
 Params = dict
@@ -137,12 +137,6 @@ def init_params(key: jax.Array, cfg: LlamaConfig) -> Params:
         "final_norm": jnp.ones((D,), pd),
         "lm_head": normal(next(k), (D, V)),
     }
-
-
-def _rms_norm(x, scale, eps):
-    x32 = x.astype(jnp.float32)
-    rms = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (x32 * rms * scale).astype(x.dtype)
 
 
 def rope_tables(cfg: LlamaConfig, seq: int):

@@ -45,12 +45,11 @@ import jax.numpy as jnp
 
 from ray_tpu.models import latent_moe, paged
 from ray_tpu.models.latent_moe import ffn, final_logits, mla_decode, mla_latent, mla_prefill
-from ray_tpu.models.llama import _rms_norm
+from ray_tpu.models.common import _rms_norm
 
 Params = dict
 _F32 = jnp.float32
 
-has_recurrent_state = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -245,6 +244,11 @@ def draw_params(key: jax.Array, cfg: MlaMoeConfig) -> Params:
 
 # ---------------------------------------------------------------------------
 # The paged programs (models/paged.py dispatches here by cfg.family)
+
+
+def cache(cfg: MlaMoeConfig) -> paged.Cache:
+    """Latent rows in blocks and nothing else."""
+    return paged.Cache(per_head=False)
 
 
 def init_pool(cfg: MlaMoeConfig, num_blocks: int, block_size: int, slots=None):

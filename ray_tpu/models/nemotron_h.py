@@ -26,10 +26,10 @@ in blocks *and* a state per slot.
 
 The cache is ``{"k", "v": [blocks *, N, KH, block, Dh], "state": [blocks M,
 slots + 1, H, P, N] float32, "conv": [blocks M, slots + 1, 3, H P + 2 G N]}``:
-the third shape of pool (:mod:`ray_tpu.models.paged`, "What a pool is now").
-Row ``slots`` of the last two is scratch: a prefill that names no slot runs
-there. The multi-token-prediction head of the published model is a drafter
-beside it and is not here.
+blocks of rows per head and a state per slot (:mod:`ray_tpu.models.paged`, "What
+a pool is made of"). Row ``slots`` of the last two is scratch: a prefill that
+names no slot runs there. The multi-token-prediction head of the published
+model is a drafter beside it and is not here.
 """
 
 from __future__ import annotations
@@ -43,7 +43,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models import latent_moe, paged
 from ray_tpu.models.latent_moe import final_logits, moe_ffn, outputs
-from ray_tpu.models.llama import _rms_norm
+from ray_tpu.models.common import _rms_norm
 from ray_tpu.ops.ssd import ssd_chunked, ssd_step
 
 Params = dict
@@ -53,8 +53,6 @@ PUBLISHED_PATTERN = (
     "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEM*EMEMEMEME"
 )
 
-has_recurrent_state = True
-kv_per_head = True  # the attention blocks' cache: paged.decode_attends_in_place asks
 
 
 @dataclasses.dataclass(frozen=True)
@@ -374,6 +372,12 @@ def span_fields(cfg: NemotronHConfig, counts, tokens: int, slots: int, decode=No
 # The paged programs (models/paged.py dispatches here by cfg.family)
 
 
+def cache(cfg: NemotronHConfig) -> paged.Cache:
+    """Keys and values per head in blocks (the attention blocks'), a Mamba-2
+    state and a tail per slot."""
+    return paged.Cache(slot_state=True)
+
+
 def init_pool(cfg: NemotronHConfig, num_blocks: int, block_size: int, slots=None):
     """The zeroed cache: keys and values per head in blocks, state and
     convolution tail by slot with one scratch row more (docstring of this
@@ -418,7 +422,6 @@ def paged_prefill(
     (for the balance and the benchmark's comparison of routing)."""
     T = tokens.shape[1]
     pk, pv, state, conv = pool["k"], pool["v"], pool["state"], pool["conv"]
-    slot = state.shape[1] - 1 if slot is None else slot
     fresh = start == 0
 
     pos = start + jnp.arange(T, dtype=jnp.int32)
@@ -428,11 +431,10 @@ def paged_prefill(
     for kind, p, l in _layers(params, cfg):
         u = _rms_norm(x, p["norm"], cfg.rms_eps)
         if kind == "M":
-            h0 = jnp.where(fresh, 0.0, state[l, slot])
-            tail = jnp.where(fresh, 0, conv[l, slot])
-            out, h, tail = mamba_prefill(u, p, cfg, h0, tail, length)
-            state = state.at[l, slot].set(h)
-            conv = conv.at[l, slot].set(tail.astype(conv.dtype))
+            out, state, conv = paged.state_prefill(
+                lambda h, tail: mamba_prefill(u, p, cfg, h, tail, length),
+                state, conv, l, slot, fresh,
+            )
             x = x + out
         elif kind == "*":
             out, pk, pv = attention_prefill(u, p, cfg, pk, pv, l, table, pos, block_size)
@@ -466,13 +468,9 @@ def paged_decode(
     for kind, p, l in _layers(params, cfg):
         u = _rms_norm(x, p["norm"], cfg.rms_eps)
         if kind == "M":
-            h0, tail0 = state[l, :B], conv[l, :B]
-            out, h, tail = mamba_decode(u, p, cfg, h0, tail0)
-            if keep is not None:
-                h = jnp.where(keep[:, None, None, None], h0, h)
-                tail = jnp.where(keep[:, None, None], tail0, tail)
-            state = state.at[l, :B].set(h)
-            conv = conv.at[l, :B].set(tail.astype(conv.dtype))
+            out, state, conv = paged.state_decode(
+                lambda h, tail: mamba_decode(u, p, cfg, h, tail), state, conv, l, B, keep
+            )
             x = x + out
         elif kind == "*":
             out, pk, pv = attention_decode(

@@ -1,106 +1,75 @@
 """Paged (block-table) KV cache: serving memory management, TPU-native.
 
-Reference parity: the capability vLLM supplies under ray.llm — paged
-attention over a shared block pool (engine knobs at
-python/ray/llm/_internal/serve/engines/vllm/vllm_models.py:89). Redesigned
-for XLA's static-shape compilation model instead of CUDA paged-attention
-kernels:
+Reference parity: the capability vLLM supplies under ray.llm (engine knobs at
+python/ray/llm/_internal/serve/engines/vllm/vllm_models.py:89), redesigned
+for XLA's static shapes instead of CUDA paged-attention kernels:
 
-- **The pool** is a pytree ``{"k","v": [L, N_blocks, KH, block, Dh]}``.
-  A request owns a *block table* — ``[W]`` int32 physical block ids with
-  ``W = max_seq // block`` — so HBM is allocated per ~block tokens
-  actually used, not per ``max_seq`` slot row. Block 0 is a reserved
-  scratch block: padded/garbage writes land there and are never read.
-- **Scatter, then attend.** New K/V are scattered straight into their
-  (layer, block, offset) homes before anything reads them. *Prefill and
-  verify* then gather the request's blocks back into a dense
-  ``[KH, S, Dh]`` row (a *transient* — XLA frees it after the layer) and
-  run the same masked grouped-head einsums as the training forward
-  (``gpt2.forward``, ``llama.forward``). *Decode* reads the live blocks
-  where they lie: on a TPU, at shapes that tile, one kernel a layer walks
-  each slot's table and attends its ``ceil((position + 1) / block)`` live
-  blocks (``ops/paged_attention.py``), so a step costs what is cached and
-  not ``max_seq`` a slot; elsewhere decode gathers too, and that gather is
-  what the tests hold the kernel to. Identical math (bf16 operands,
-  float32 scores and softmax, the mask ``col <= position``) ⇒ logit
-  parity with the training forward position by position, which the tests
-  assert of both.
-- **The pool is written in place.** The layer scan carries the whole pool
-  and scans over the layer index, so each layer's scatter writes a few
-  rows into the buffer it was handed; the only slab-sized work left is
-  prefill's gather. A caller that donates the pool (the engine, the speculative
-  decoder) gets its own buffer back as the output and must rebind it; one
-  that does not (``benchmarks/check.py``) keeps its input and pays one
-  copy of the pool at entry, which the compiler inserts.
-- **Static shapes everywhere**: W, block, and the prefill bucket are
-  compile-time constants; positions/tables are traced operands. Two
-  compiled programs (prefill-per-bucket + decode).
-- **Prefix sharing is free**: a pooled prefix is a list of block ids; a
-  hit points the new request's first P/block table entries at the shared
-  blocks (host-side refcount) — no device copy at all.
+- **Block tables.** A request owns ``[W]`` int32 physical block ids, ``W =
+  max_seq // block``, so HBM goes by blocks actually used and not by
+  ``max_seq`` a slot. Block 0 is scratch: padded and garbage writes land there
+  and are never read. W, block and the prefill bucket are compile-time
+  constants, positions and tables traced operands: two compiled programs
+  (prefill per bucket, decode). A pooled prefix is a list of block ids, shared
+  by host-side refcount with no device copy.
+- **Scatter, then attend.** New rows are scattered into their (layer, block,
+  offset) homes before anything reads them. *Prefill and verify* gather the
+  request's blocks back into a dense row (a transient) and run the training
+  forward's masked grouped-head einsums. *Decode* reads the live blocks where
+  they lie: on a TPU, at shapes that tile, one kernel a layer walks each
+  slot's table over its ``ceil((position + 1) / block)`` live blocks
+  (``ops/paged_attention.py``); elsewhere decode gathers too, and that gather
+  is what the tests hold the kernel to. Identical math (bf16 operands, float32
+  scores and softmax, the mask ``col <= position``): logit parity with the
+  training forward position by position.
+- **The pool is written in place.** The layer scan carries the whole pool and
+  scans over the layer index. A caller that donates the pool (the engine, the
+  speculative decoder) gets its buffer back as the output and must rebind it;
+  one that does not (``benchmarks/check.py``) pays one copy of it at entry.
 
-Family dispatch is by the configuration's ``family`` name, looked up once
-(:func:`family`). GPT-2 (learned-position MHA) and Llama (RoPE GQA) share
-everything here — scatter, gather, masking, grouped attention — and each
-supplies a small hook table (``kv_hooks``), because GQA with group=1 *is*
-MHA. A family whose cache is not keys and values per head supplies its cache
-and its layer bodies itself:
+**What a pool is made of.** Four parts; which of them a family has is its
+record's business (:class:`Cache`, :func:`cache`), and the engine reads that
+and nothing else about a family's cache.
 
-- **What a pool is now.** Blocks of keys and values, as above, or latent
-  rows in blocks (``"ckv": [L, N, block, 576]``: one row a position for all
-  heads, no head axis, under the same block tables and the same
-  ``BlockManager``), and for ``kimi_linear`` a recurrent state and a
-  convolution tail *per slot* beside them (``"state": [L_kda, slots + 1, H,
-  d_k, d_v]`` float32, ``"conv"``), which no block table reaches. The third
-  shape is ``nemotron_h``'s: keys and values per head in blocks, as above
-  (``"k"``, ``"v"``, one layer of the pool an attention block), *and* a state
-  and a tail per slot (``"state": [L_mamba, slots + 1, H, P, N]`` float32,
-  ``"conv"``). Its attention blocks write, gather and attend with this
-  module's functions (``_write_read``, :func:`decode_attention`: the kernel
-  over live blocks on a TPU); for everything else it is a family with a state
-  per slot. ``solar_open2`` keeps the same three things, its state the delta
-  rule's (``"state": [L_kda, slots + 1, H, d, d]``) and its attention layers
-  read by prefill a stretch of the table at a time (:func:`prefill_attention`).
-  Two facts about such a family, which the engine asks one by one:
-  *it brings its own programs* (no ``kv_hooks``: its module supplies
-  ``init_pool``, ``paged_prefill``, ``paged_decode`` and ``span_fields``),
-  so nothing that reads ``pool["k"]`` or scores through the hooks serves it
-  (speculative verification, the disaggregated handoff, tensor
-  parallelism); and *it keeps a state per slot* (:func:`has_recurrent_state`).
-  Latent rows alone (``mla_moe``) are a cache like keys and values: stale
-  rows are masked away by position, a prefix is shared by block ids, a
-  prompt prefills in chunks, and decode attends each slot's live blocks in
-  place where the rows are whole lane tiles
-  (:func:`latent_decode_attention`: the kernel's latent arm, one copy of a
-  block serving keys and values alike). A state is not: a stale one is not masked, so a
-  prefill from position 0 starts from zero state and a later chunk continues
-  from its slot's; row ``slots`` is scratch, where slots that are free or
-  still prefilling step; and a prefix hit would need the state at the
-  prefix's end, so such a family is served without the prefix cache.
-  The fourth shape is ``afmoe``'s: keys and values per head in blocks, in
-  *two parts* (``{"full": {"k", "v"}, "window": {"k", "v"}}``, each ``[layers
-  of the kind, blocks of the part, KH, block, Dh]``), because its layers are
-  of two kinds: a full layer keeps every position, a window layer only the
-  last ``sliding_window``. Each part has its own blocks and each slot a table
-  for each (``tables [..., kinds, W]``: entry ``i`` of either is the block of
-  positions ``[i block, (i + 1) block)``; behind the window a window table
-  points at the scratch block, its blocks given back while the request runs).
-  That is the third fact the engine asks (:func:`retention`): *how long each
-  of its layer kinds keeps a position*. A table of one kind serves both where
-  nothing was given back (``tables [..., W]``: a rehearsal, the routers'
-  balance). Decode attends a window layer through the same
-  :func:`decode_attention` with ``window`` given (the kernel's walk from the
-  block that holds ``length - window``, the gather under the same mask), and
-  prefill reads either kind a stretch of the table at a time
-  (:func:`prefill_attention`). A prefix hit would need the window blocks
-  behind the prefix's end, which are gone: served without the prefix cache.
+- *Blocks of rows per head*, ``{"k", "v": [L, N, KH, block, Dh]}``: written
+  with :func:`_write`, read by prefill as a gathered table or a stretch of the
+  table at a time (:func:`prefill_attention`), by decode through
+  :func:`decode_attention`.
+- *Blocks of latent rows*, ``"ckv": [L, N, block, pool_row_dim]``: one row a
+  position for all heads, under the same tables and ``BlockManager``. A cache
+  like keys and values (stale rows masked by position, prefixes shared,
+  prompts in chunks); decode attends in place where the rows are whole lane
+  tiles (:func:`latent_decode_attention`).
+- *A state and a tail per slot*, ``"state": [L', slots + 1, ...]`` float32 and
+  ``"conv": [L', slots + 1, K - 1, C]``, which no block table reaches: slot
+  ``b``'s are row ``b``, row ``slots`` is scratch. A stale state is not masked,
+  so one policy holds (:func:`state_prefill`, :func:`state_decode`): a
+  sequence begins from zero whatever the slot held, a later chunk continues
+  from the slot's, a decode step leaves a slot that is not live as it was.
+- *A second table kind that keeps a window*: the blocks in two parts
+  (``{"full": {"k", "v"}, "window": {"k", "v"}}``), a slot a table for each
+  (``tables [..., kinds, W]``; behind the window a window table points at the
+  scratch block, its blocks given back while the request runs). One table
+  ``[..., W]`` serves both where nothing was given back (a rehearsal).
+
+Neither a state nor the blocks behind a window are there at a prefix's end:
+such a family is served without the prefix cache.
+
+Family dispatch is by the configuration's ``family`` name (:func:`family`).
+GPT-2 (learned-position MHA) and Llama (RoPE GQA) share everything here and
+each supplies a small hook table (``kv_hooks``), because GQA with group=1 *is*
+MHA; speculative verification, the disaggregated handoff and tensor
+parallelism reach these two only (:meth:`Cache.why_not`). Every other family
+brings ``init_pool``, ``paged_prefill``, ``paged_decode``, ``span_fields`` and
+its record, ``cache(cfg)``, in its module.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import importlib
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -127,7 +96,7 @@ def family(cfg):
     ``kv_hooks(cfg, S)`` (keys and values per head: the pool and the
     programs below serve it) or a cache and programs of its own
     (``init_pool``, ``paged_prefill``, ``paged_decode``, ``span_fields``,
-    ``has_recurrent_state``).
+    ``cache``).
 
     ``kv_hooks`` returns ``(embed, qkv, finish, final, H, KH, Dh)``. The
     hooks take ``pos2d``, always [B, T] absolute positions — prefill passes
@@ -142,26 +111,54 @@ def family(cfg):
     return importlib.import_module(name)
 
 
-def brings_own_programs(cfg) -> bool:
-    """Whether the family serves through a cache and programs of its own
-    and not through ``kv_hooks`` (module docstring)."""
-    return not hasattr(family(cfg), "kv_hooks")
+@dataclasses.dataclass(frozen=True)
+class Cache:
+    """What a family keeps for a request (module docstring, "What a pool is
+    made of")."""
+
+    # Positions each table kind keeps, a kind an entry: None keeps every
+    # position (the first kind always), a count the last so many only. A slot
+    # holds a block table a kind.
+    retention: tuple = (None,)
+    slot_state: bool = False  # a state and a tail per slot beside the blocks
+    per_head: bool = True  # rows in blocks: keys and values per head, or latent rows
+    hooks: bool = False  # served through kv_hooks by this module's programs
+
+    @property
+    def shares_prefixes(self) -> bool:
+        """Whether a pooled prefix can serve a later request: a hit needs
+        what the cache held at the prefix's end, and neither a state nor the
+        blocks behind a window are kept."""
+        return not self.slot_state and len(self.retention) == 1
+
+    def why_not(self, family: str, what: str) -> Optional[str]:
+        """Why ``what`` (speculative verification, tensor parallelism, the
+        disaggregated handoff: each written for one pool of keys and values
+        under one table, the cache that ``kv_hooks`` serve) cannot serve the
+        family of this record, said field by field; None where it can."""
+        if self.hooks:
+            return None
+        keeps = []
+        if len(self.retention) > 1:
+            keeps.append(
+                "keeps a block table per layer kind and gives window blocks back "
+                "while a request runs"
+            )
+        if self.slot_state:
+            keeps.append("keeps a recurrent state per slot, which no block table reaches")
+        rows = "keys and values per head" if self.per_head else "latent rows"
+        keeps.append(f"brings its own paged programs over {rows}")
+        return (
+            f"the family {family!r} {', '.join(keeps)}, and {what} is served "
+            "through kv_hooks only"
+        )
 
 
-def has_recurrent_state(cfg) -> bool:
-    """Whether part of the family's cache is a state per slot that block
-    tables do not reach (module docstring)."""
-    return getattr(family(cfg), "has_recurrent_state", False)
-
-
-def retention(cfg) -> tuple:
-    """How many positions each of the family's layer kinds keeps, a kind an
-    entry: None for a kind that keeps every position (the one kind of every
-    family but one), a count for a kind that attends the last so many only.
-    The first kind keeps everything. A slot holds a block table a kind
-    (module docstring, the fourth shape)."""
-    kinds = getattr(family(cfg), "retention", None)
-    return (None,) if kinds is None else kinds(cfg)
+def cache(cfg) -> Cache:
+    """The record of the configuration's family: this module's for a family
+    of ``kv_hooks``, else the one its module states."""
+    mod = family(cfg)
+    return Cache(hooks=True) if hasattr(mod, "kv_hooks") else mod.cache(cfg)
 
 
 def window_blocks_a_slot(window: int, span: int, block_size: int) -> int:
@@ -178,8 +175,8 @@ def init_block_pool(cfg, num_blocks: int, block_size: int, slots=None, window_bl
     ``max_slots``) and a scratch row. ``window_blocks``: the blocks of each
     window kind's part, from the engine that counted them (None: the family
     counts them from what its configuration says of the deployment)."""
-    mod = family(cfg)
-    if not hasattr(mod, "kv_hooks"):
+    if not cache(cfg).hooks:
+        mod = family(cfg)
         if window_blocks is not None:
             return mod.init_pool(cfg, num_blocks, block_size, slots, window_blocks=window_blocks)
         return mod.init_pool(cfg, num_blocks, block_size, slots)
@@ -252,20 +249,14 @@ def decode_attends_in_place(cfg, block_size: int, *, mesh=None) -> bool:
     default backend, attends the live blocks in place (the kernel) or gathers
     each table whole: the kernel on a TPU, for a cache whose shapes are whole
     TPU tiles and fit VMEM, outside a mesh (the compiler cannot partition a
-    Mosaic call). The cache is keys and values per head, which ``kv_hooks``
-    serve and which a family that brings its own programs says it keeps
-    (``kv_per_head``: its attention layers then call
-    :func:`decode_attention`, the same choice, for their part of the pool),
-    or latent rows, whose width the configuration gives (``pool_row_dim``:
-    the family's programs call :func:`latent_decode_attention`).
+    Mosaic call). By the family's record the rows in blocks are keys and
+    values per head (its attention layers call :func:`decode_attention`, the
+    same choice) or latent rows, whose width the configuration gives
+    (``pool_row_dim``: its programs call :func:`latent_decode_attention`).
     Decided by what the code can see, like
     ``ops.attention.uses_flash_kernel``; nothing a user sets reaches it."""
-    mod = family(cfg)
-    if hasattr(mod, "kv_hooks") or getattr(mod, "kv_per_head", False):
-        fits = _kernel_fits(cfg, block_size, mesh)
-    else:
-        fits = hasattr(cfg, "pool_row_dim") and _latent_kernel_fits(cfg, block_size, mesh)
-    return jax.default_backend() == "tpu" and fits
+    fits = _kernel_fits if cache(cfg).per_head else _latent_kernel_fits
+    return jax.default_backend() == "tpu" and fits(cfg, block_size, mesh)
 
 
 def _kv_heads(cfg) -> int:
@@ -393,6 +384,38 @@ def latent_decode_attention(cfg, block_size, mesh, interpret, scale: float):
     )
 
 
+def state_prefill(step, state, conv, l, slot, fresh):
+    """The prefill side of a state and a tail per slot: layer ``l`` of
+    ``state`` [L', slots + 1, ...] and ``conv`` [L', slots + 1, K - 1, C]
+    through the family's mixer, ``step(state0, tail0) -> (out, state1,
+    tail1)``. ``slot``: the sequence's row (None: the scratch row, the
+    last). ``fresh``: whether the sequence begins with this call (``start ==
+    0``, worked out once a program): it then starts from zero and an empty
+    tail whatever the slot held, and else from the slot's (a later chunk).
+    Returns ``(out, state, conv)`` with the row written back."""
+    row = state.shape[1] - 1 if slot is None else slot
+    state0 = jnp.where(fresh, 0.0, state[l, row])
+    tail0 = jnp.where(fresh, 0, conv[l, row])
+    out, state1, tail1 = step(state0, tail0)
+    return out, state.at[l, row].set(state1), conv.at[l, row].set(tail1.astype(conv.dtype))
+
+
+def state_decode(step, state, conv, l, rows: int, keep=None):
+    """The decode side: slot ``b``'s state and tail are row ``b``, so rows
+    ``[:rows]`` of layer ``l`` go through ``step(state0 [rows, ...], tail0)
+    -> (out, state1, tail1)`` and back where they lie, with no gather by
+    slot. ``keep`` [rows] bool (``~live``, worked out once a program; None:
+    every slot is live): a slot that is not live, free or still prefilling
+    in chunks, keeps its state and tail as they were; its ``out`` means
+    nothing. Returns ``(out, state, conv)``."""
+    state0, tail0 = state[l, :rows], conv[l, :rows]
+    out, state1, tail1 = step(state0, tail0)
+    if keep is not None:
+        state1 = jnp.where(keep[:, None, None, None], state0, state1)
+        tail1 = jnp.where(keep[:, None, None], tail0, tail1)
+    return out, state.at[l, :rows].set(state1), conv.at[l, :rows].set(tail1.astype(conv.dtype))
+
+
 def _scan_layers(body, x, params, pool):
     """Run ``body`` over the layers with the pool in the carry, so that
     layer l's scatter writes into the buffer layer l+1 reads: a scanned
@@ -431,7 +454,7 @@ def paged_prefill(
     prefix-continue path — attention always spans the full gathered row
     under the mask ``col <= start + row`` (the static-shape trade)."""
     mod = family(cfg)
-    if not hasattr(mod, "kv_hooks"):
+    if not cache(cfg).hooks:
         return mod.paged_prefill(
             params, tokens, length, start, table, pool, cfg,
             block_size=block_size, slot=slot,
@@ -496,16 +519,9 @@ def paged_verify(
     Callers must keep positions + T <= max_seq (the engine falls back to
     plain decode near the boundary): out-of-range scatter indices would
     clamp into the slot's last real block and corrupt it."""
-    if has_recurrent_state(cfg):
-        raise ValueError(
-            f"paged_verify cannot serve the family {cfg.family!r}: rejected "
-            "tokens would have to be taken back out of its recurrent state"
-        )
-    if brings_own_programs(cfg):
-        raise ValueError(
-            f"paged_verify cannot serve the family {cfg.family!r}: it scores "
-            "through kv_hooks, and the family brings programs of its own"
-        )
+    refused = cache(cfg).why_not(cfg.family, "paged_verify")
+    if refused:
+        raise ValueError(refused)
     B, T = tokens.shape
     W = tables.shape[1]
     S = W * block_size
@@ -569,7 +585,7 @@ def paged_decode(
     [0, position] of every slot: over the live blocks in place or over the
     gathered table (:func:`decode_attention`)."""
     mod = family(cfg)
-    if not hasattr(mod, "kv_hooks"):
+    if not cache(cfg).hooks:
         return mod.paged_decode(
             params, last_tokens, positions, tables, pool, cfg,
             block_size=block_size, live=live, interpret=interpret,

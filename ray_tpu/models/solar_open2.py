@@ -11,8 +11,8 @@ Block: ``x += Mixer(RMSNorm(x)); x += MoE(RMSNorm(x))``; final RMSNorm; untied
 head. Layers are numbered from 0 as in the published ``gqa_layers``; the
 expert layer counts them from 1, as :mod:`latent_moe` does.
 
-- **KDA layer.** :func:`ray_tpu.models.kimi_linear.kda_prefill` /
-  ``kda_decode``, the one implementation, which reads the range of ``beta``
+- **KDA layer.** :func:`ray_tpu.models.kda.kda_prefill` / ``kda_decode``, the
+  one implementation, which reads the range of ``beta``
   off the configuration (``kda_neg_eigval``: the transition ``I - beta k k^T``
   then has the eigenvalue ``1 - beta`` in (-1, 1) along ``k``). 64 heads of 128
   here: a state of ``[64, 128, 128]`` float32 a layer and sequence.
@@ -46,9 +46,9 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import latent_moe, paged
-from ray_tpu.models.kimi_linear import draw_kda, kda_decode, kda_prefill
+from ray_tpu.models.kda import draw_kda, kda_decode, kda_prefill
 from ray_tpu.models.latent_moe import ffn, final_logits, outputs
-from ray_tpu.models.llama import _rms_norm
+from ray_tpu.models.common import _rms_norm
 
 Params = dict
 _F32 = jnp.float32
@@ -56,8 +56,6 @@ _F32 = jnp.float32
 GQA, KDA = "gqa", "kda"
 PUBLISHED_LAYER_KINDS = (GQA, KDA, KDA, KDA) * 12  # gqa_layers 0, 4, ..., 44 of 48
 
-has_recurrent_state = True
-kv_per_head = True  # the GQA layers' cache: paged.decode_attends_in_place asks
 
 
 @dataclasses.dataclass(frozen=True)
@@ -174,7 +172,7 @@ def draw_params(key: jax.Array, cfg: SolarOpen2Config) -> Params:
     """Random weights, drawn tensor by tensor in the parameter dtype: no
     float32 copy of an expert stack is ever live. N(0, 0.02), the projections
     back to the residual stream scaled by 1/sqrt(2 L) of the layers held; the
-    KDA layers' by :func:`kimi_linear.draw_kda`; the router in float32 with
+    KDA layers' by :func:`kda.draw_kda`; the router in float32 with
     unit-variance logits and a zero selection bias; norms one. ``silent_ids``:
     those columns of the head are zero."""
     pd = cfg.param_dtype
@@ -264,6 +262,12 @@ def span_fields(cfg: SolarOpen2Config, counts, tokens: int, slots: int, decode=N
 # The paged programs (models/paged.py dispatches here by cfg.family)
 
 
+def cache(cfg: SolarOpen2Config) -> paged.Cache:
+    """Keys and values per head in blocks (the GQA layers'), a delta-rule
+    state and a tail per slot (the KDA layers')."""
+    return paged.Cache(slot_state=True)
+
+
 def init_pool(cfg: SolarOpen2Config, num_blocks: int, block_size: int, slots=None):
     """The zeroed cache: keys and values per head in blocks, state and
     convolution tail by slot with one scratch row more (docstring of this
@@ -304,7 +308,6 @@ def paged_prefill(
     (for the balance and the benchmark's comparison of routing)."""
     T = tokens.shape[1]
     pk, pv, state, conv = pool["k"], pool["v"], pool["state"], pool["conv"]
-    slot = state.shape[1] - 1 if slot is None else slot
     fresh = start == 0
 
     pos = start + jnp.arange(T, dtype=jnp.int32)
@@ -315,11 +318,10 @@ def paged_prefill(
     for i, kind, p, l in _layers(params, cfg):
         a = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
         if kind == KDA:
-            S0 = jnp.where(fresh, 0.0, state[l, slot])
-            tail = jnp.where(fresh, 0, conv[l, slot])
-            out, S1, tail = kda_prefill(a, p, cfg, S0, tail, length)
-            state = state.at[l, slot].set(S1)
-            conv = conv.at[l, slot].set(tail.astype(conv.dtype))
+            out, state, conv = paged.state_prefill(
+                lambda S, tail: kda_prefill(a, p, cfg, S, tail, length),
+                state, conv, l, slot, fresh,
+            )
         else:
             q, k, v, g = _qkvg(a, p, cfg)
             pk = paged._write(pk, l, bids, offs, k)
@@ -359,13 +361,9 @@ def paged_decode(
     for i, kind, p, l in _layers(params, cfg):
         a = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
         if kind == KDA:
-            S0, tail0 = state[l, :B], conv[l, :B]
-            out, S1, tail = kda_decode(a, p, cfg, S0, tail0)
-            if keep is not None:
-                S1 = jnp.where(keep[:, None, None, None], S0, S1)
-                tail = jnp.where(keep[:, None, None], tail0, tail)
-            state = state.at[l, :B].set(S1)
-            conv = conv.at[l, :B].set(tail.astype(conv.dtype))
+            out, state, conv = paged.state_decode(
+                lambda S, tail: kda_decode(a, p, cfg, S, tail), state, conv, l, B, keep
+            )
         else:
             q, k, v, g = _qkvg(a, p, cfg)
             pk = paged._write(pk, l, bids, offs, k)

@@ -15,7 +15,7 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmarks.reference import afmoe_ref as ref  # noqa: E402
-from ray_tpu.models import afmoe, latent_moe  # noqa: E402
+from ray_tpu.models import afmoe, latent_moe, paged  # noqa: E402
 
 pytestmark = pytest.mark.timeout(300)
 BLOCK, CHUNK, WIDTH = 4, 8, 16  # a table of 16 blocks: 64 positions
@@ -195,7 +195,7 @@ def test_the_pool_has_a_part_a_kind_and_the_window_part_is_counted_not_set():
     assert pool["full"]["k"].shape == (1, 33, 2, BLOCK, 16)
     # ceil((8 + 8) / 4) + 1 = 5 blocks a slot, and the scratch block
     assert pool["window"]["k"].shape == (3, 3 * 5 + 1, 2, BLOCK, 16)
-    assert afmoe.retention(cfg) == (None, 8)
+    assert afmoe.cache(cfg) == paged.Cache(retention=(None, 8), per_head=True, hooks=False)
 
 
 def _loads(cfg, params, texts):

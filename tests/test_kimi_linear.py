@@ -19,7 +19,7 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmarks.reference import kimi_linear_ref as ref  # noqa: E402
-from ray_tpu.models import kimi_linear as kl, latent_moe, paged  # noqa: E402
+from ray_tpu.models import kda, kimi_linear as kl, latent_moe, paged  # noqa: E402
 from ray_tpu.models.llama import LlamaConfig  # noqa: E402
 from ray_tpu.ops import delta_rule  # noqa: E402
 
@@ -225,20 +225,20 @@ def test_kda_prefill_padded_tail_and_continuation(tiny):
     h = jax.random.normal(jax.random.key(1), (48, cfg.d_model))
     H, d = cfg.kda_heads, cfg.kda_head_dim
     zeros = jnp.zeros((H, d, d)), jnp.zeros((cfg.conv_kernel - 1, cfg.conv_dim))
-    whole, S_w, tail_w = kl.kda_prefill(h[:37], p, cfg, *zeros, 37)
-    padded, S_p, tail_p = kl.kda_prefill(h, p, cfg, *zeros, 37)
+    whole, S_w, tail_w = kda.kda_prefill(h[:37], p, cfg, *zeros, 37)
+    padded, S_p, tail_p = kda.kda_prefill(h, p, cfg, *zeros, 37)
     np.testing.assert_allclose(S_p, S_w, rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(tail_p, tail_w, rtol=1e-6)
     np.testing.assert_allclose(padded[:37], whole, rtol=1e-5, atol=1e-6)
-    first, S_1, tail_1 = kl.kda_prefill(h[:16], p, cfg, *zeros, 16)
-    second, S_2, tail_2 = kl.kda_prefill(h[16:37], p, cfg, S_1, tail_1, 21)
+    first, S_1, tail_1 = kda.kda_prefill(h[:16], p, cfg, *zeros, 16)
+    second, S_2, tail_2 = kda.kda_prefill(h[16:37], p, cfg, S_1, tail_1, 21)
     np.testing.assert_allclose(jnp.concatenate([first, second]), whole, rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(S_2, S_w, rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(tail_2, tail_w, rtol=1e-6)
     # ... and one token at a time, as decode steps
     S, tail, outs = S_1[None], tail_1[None], []
     for t in range(16, 37):
-        o, S, tail = kl.kda_decode(h[t][None], p, cfg, S, tail)
+        o, S, tail = kda.kda_decode(h[t][None], p, cfg, S, tail)
         outs.append(o[0])
     np.testing.assert_allclose(jnp.stack(outs), whole[16:], rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(S[0], S_w, rtol=2e-4, atol=2e-5)
@@ -384,7 +384,7 @@ def test_pool_parts_and_what_the_paged_programs_refuse(tiny):
     assert pool["state"].shape == (Lk, 7, H, d, d) and pool["state"].dtype == jnp.float32
     assert pool["conv"].shape == (Lk, 7, cfg.conv_kernel - 1, cfg.conv_dim)
     assert paged.init_block_pool(cfg, 9, 16)["state"].shape[1] == cfg.state_slots + 1
-    assert paged.has_recurrent_state(cfg) and not paged.has_recurrent_state(LlamaConfig.tiny())
+    assert paged.cache(cfg).slot_state and not paged.cache(LlamaConfig.tiny()).slot_state
     with pytest.raises(ValueError, match="recurrent state"):
         paged.paged_verify(None, jnp.zeros((1, 2), jnp.int32), None, None, pool, cfg, block_size=16)
 

@@ -227,4 +227,4 @@ def test_spans_carry_the_rows_by_kind_the_window_blocks_and_the_chunks_counters(
     assert all("experts_touched" in c and "picks_here" in c for c in chunks)
     whole = [e["extra"] for e in events if e["phase"] == "llm.prefill"]
     assert whole and all("experts_touched" in x for x in whole)
-    assert paged.retention(engine.model_config) == (None, 8)
+    assert paged.cache(engine.model_config).retention == (None, 8)

@@ -479,6 +479,14 @@ def _tiny_model_of(family):
         from ray_tpu.models.nemotron_h import NemotronHConfig
 
         return NemotronHConfig.tiny(max_seq=128)
+    if family == "afmoe":
+        from ray_tpu.models.afmoe import AfmoeConfig
+
+        return AfmoeConfig.tiny(max_seq=128)
+    if family == "solar_open2":
+        from ray_tpu.models.solar_open2 import SolarOpen2Config
+
+        return SolarOpen2Config.tiny(max_seq=128)
     return _family_model(family)[0]
 
 
@@ -497,21 +505,45 @@ def test_kv_block_size_is_a_block_size_not_a_switch(family):
         assert "one cache" in str(e.value)
 
 
-def test_a_family_is_looked_up_once_and_an_unknown_one_is_refused():
+@pytest.mark.parametrize("name", list(paged._FAMILIES))
+def test_a_family_is_looked_up_once_and_its_record_is_what_its_pool_holds(name):
     """``paged.family`` is the one lookup by name: every family's module
-    brings ``init_params`` and either its hooks or its own programs, and a
-    name it does not know is a ``ValueError`` that says the name."""
-    for name in ("gpt2", "llama", "kimi_linear", "mla_moe", "nemotron_h"):
-        mod = paged.family(_tiny_model_of(name))
-        assert callable(mod.init_params)
-        own = all(
-            hasattr(mod, f) for f in ("init_pool", "paged_prefill", "paged_decode")
-        )
-        assert hasattr(mod, "kv_hooks") != own
-        assert paged.brings_own_programs(_tiny_model_of(name)) == own
-        assert paged.has_recurrent_state(_tiny_model_of(name)) == (
-            name in ("kimi_linear", "nemotron_h")
-        )
+    brings ``init_params`` and either its hooks or its own programs, and
+    ``paged.cache`` says of it what its ``init_pool`` really builds: a state
+    and a tail beside the blocks exactly where the record says so, a window
+    part exactly where it names a second table kind, rows per head or latent
+    rows as it says."""
+    cfg = _tiny_model_of(name)
+    mod = paged.family(cfg)
+    assert callable(mod.init_params)
+    own = all(hasattr(mod, f) for f in ("init_pool", "paged_prefill", "paged_decode", "cache"))
+    assert hasattr(mod, "kv_hooks") != own
+    record = paged.cache(cfg)
+    assert record.hooks == (not own) and record.retention[0] is None
+    pool = paged.init_block_pool(cfg, 9, 16, 3)
+    assert (("state" in pool), ("conv" in pool)) == (record.slot_state, record.slot_state)
+    if record.slot_state:  # a row a slot and the scratch row, float32
+        assert pool["state"].shape[1] == pool["conv"].shape[1] == 4
+        assert pool["state"].dtype == jnp.float32
+    assert (set(pool) == {"full", "window"}) == (len(record.retention) == 2)
+    if len(record.retention) == 2:
+        assert record.retention[1] == cfg.sliding_window
+        blocks = [pool["full"], pool["window"]]
+    else:
+        blocks = [pool]
+    for part in blocks:
+        assert ("k" in part and "v" in part) == record.per_head
+        assert ("ckv" in part) == (not record.per_head)
+        rows = part["k"] if record.per_head else part["ckv"]
+        # [layers, blocks, KH, block, Dh], or [layers, blocks, block, row width]: no head axis
+        assert rows.ndim == (5 if record.per_head else 4) and rows.shape[-2] == 16
+    assert record.shares_prefixes == (name in ("gpt2", "llama", "mla_moe"))
+    assert (record.why_not(name, "x") is None) == record.hooks
+
+
+def test_an_unknown_family_is_refused_by_name():
+    """A name ``paged.family`` does not know is a ``ValueError`` that says
+    the name, whichever question is asked first."""
 
     @dataclasses.dataclass(frozen=True)
     class Other:
@@ -520,10 +552,98 @@ def test_a_family_is_looked_up_once_and_an_unknown_one_is_refused():
     for call in (
         lambda: paged.family(Other()),
         lambda: paged.init_block_pool(Other(), 4, 16),
-        lambda: paged.has_recurrent_state(Other()),
+        lambda: paged.cache(Other()),
     ):
         with pytest.raises(ValueError, match="mamba"):
             call()
+
+
+def test_every_family_is_launched_one_way_and_counters_are_read_off_the_size():
+    """A Llama engine and a Kimi Linear engine launch a prefill through the
+    same ``_run_prefill`` and the same operands (the tokens and ONE int32
+    ``meta`` [3 + W], both numpy: nothing is uploaded piece by piece), and
+    what came back says by its size whether the program brought counters: a
+    Llama prefill's read-back is its logits and nothing else."""
+    launched, back = {}, {}
+    for name in ("llama", "kimi_linear"):
+        eng = LLMEngine(
+            LLMConfig(
+                model_config=_tiny_model_of(name), max_slots=2, max_seq=64,
+                prefill_buckets=(16, 32), kv_block_size=16,
+            )
+        )
+        program = eng._pg_prefill
+
+        def spy(*args, name=name, program=program):
+            launched.setdefault(name, []).append(args[1:3])  # between params and the pool
+            pool, out = program(*args)
+            back.setdefault(name, []).append(out.shape)
+            return pool, out
+
+        eng._pg_prefill = spy
+        out = eng.generate([list(range(3, 15))], SamplingParams(max_tokens=3))
+        assert len(out[0]["token_ids"]) == 3 and eng.stats["programs_launched"] >= 3
+    described = {
+        name: [[(type(a), a.dtype, a.shape) for a in args] for args in calls]
+        for name, calls in launched.items()
+    }
+    W = 64 // 16
+    assert described["llama"] == described["kimi_linear"] == [
+        [(np.ndarray, np.dtype("int32"), (1, 16)), (np.ndarray, np.dtype("int32"), (3 + W,))]
+    ]
+    (llama,), (kimi,) = back["llama"], back["kimi_linear"]
+    assert llama == (_tiny_model_of("llama").vocab_size,)
+    assert kimi[0] > _tiny_model_of("kimi_linear").vocab_size
+
+
+def _toy_mixer(x):
+    """A mixer step of no family: the state takes ``x`` in, the tail shifts."""
+
+    def step(state, tail):
+        return state.sum(axis=(-1, -2)), state + x, jnp.roll(tail, 1, axis=-2) + 1
+
+    return step
+
+
+@pytest.mark.parametrize("case", ["fresh", "continued", "no_slot", "not_live"])
+def test_a_slots_state_is_handled_under_one_policy(case):
+    """``paged.state_prefill`` / ``state_decode`` with a toy mixer: a fresh
+    start ignores what the slot held, a later chunk continues from it, no
+    slot lands on the scratch row, and a decode step leaves a slot that is
+    not live as it was, state and tail bit for bit."""
+    L, slots = 2, 3
+    key = jax.random.key(0)
+    state = jax.random.normal(key, (L, slots + 1, 2, 4, 4), jnp.float32)
+    conv = jax.random.normal(key, (L, slots + 1, 3, 6)).astype(jnp.bfloat16)
+    l = 1
+    if case in ("fresh", "continued"):
+        fresh = jnp.asarray(case == "fresh")
+        out, state1, conv1 = paged.state_prefill(_toy_mixer(2.0), state, conv, l, 2, fresh)
+        began = jnp.zeros_like(state[l, 2]) if case == "fresh" else state[l, 2]
+        tail0 = jnp.zeros_like(conv[l, 2]) if case == "fresh" else conv[l, 2]
+        np.testing.assert_array_equal(out, began.sum(axis=(-1, -2)))
+        np.testing.assert_array_equal(state1[l, 2], began + 2.0)
+        np.testing.assert_array_equal(conv1[l, 2], (jnp.roll(tail0, 1, axis=-2) + 1).astype(conv.dtype))
+        touched = (l, 2)
+    elif case == "no_slot":
+        _, state1, conv1 = paged.state_prefill(_toy_mixer(2.0), state, conv, l, None, jnp.asarray(False))
+        np.testing.assert_array_equal(state1[l, slots], state[l, slots] + 2.0)
+        touched = (l, slots)
+    else:
+        live = jnp.asarray([True, False, True])
+        out, state1, conv1 = paged.state_decode(_toy_mixer(3.0), state, conv, l, slots, ~live)
+        assert out.shape == (slots, 2)
+        for b in (0, 2):
+            np.testing.assert_array_equal(state1[l, b], state[l, b] + 3.0)
+            np.testing.assert_array_equal(conv1[l, b], (jnp.roll(conv[l, b], 1, axis=-2) + 1).astype(conv.dtype))
+        all_live = paged.state_decode(_toy_mixer(3.0), state, conv, l, slots)[1]
+        np.testing.assert_array_equal(all_live[l, :slots], state[l, :slots] + 3.0)
+        touched = (l, slice(0, slots, 2))
+    # every other row of every layer, the scratch row too, is as it was bit for bit
+    for before, after in ((state, state1), (conv, conv1)):
+        mask = np.ones(before.shape[:2], bool)
+        mask[touched] = False
+        np.testing.assert_array_equal(np.asarray(after.astype(jnp.float32))[mask], np.asarray(before.astype(jnp.float32))[mask])
 
 
 def test_engine_paged_prefix_shares_blocks_without_copy():

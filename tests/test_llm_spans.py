@@ -192,6 +192,10 @@ class SlowLogits:
     def __init__(self, logits, done: list):
         self.logits, self.done = logits, done
 
+    @property
+    def shape(self):
+        return self.logits.shape
+
     def __array__(self, dtype=None, copy=None):
         time.sleep(0.03)
         out = np.asarray(self.logits)
@@ -656,13 +660,10 @@ def test_a_jitted_program_carries_its_name_into_the_trace(engine_of, family, pro
     eng = engine_of(family)
     toks = np.zeros((1, 16), np.int32)
     slots, width = eng.block_tables.shape
-    if program == "paged_decode":  # one layout for every family: prev, meta
+    if program == "paged_decode":  # one layout a program for every family: prev, meta
         operands = (jnp.zeros(slots, jnp.int32), np.zeros((slots, 4 + width), np.int32))
-    elif family in ("kimi_linear", "mla_moe", "nemotron_h"):  # every small operand in one int32 array
+    else:  # every small operand in one int32 array
         operands = (toks, np.zeros(3 + width, np.int32))
-    else:
-        n, z = jnp.asarray(4, jnp.int32), jnp.asarray(0, jnp.int32)
-        operands = (jnp.asarray(toks), n, z, jnp.asarray(eng.block_tables[0]))
     jitted = {"paged_prefill": eng._pg_prefill, "paged_decode": eng._pg_decode}[program]
     assert _module_name(jitted, eng.params, *operands, eng.pool) == f"jit_{program}"
 

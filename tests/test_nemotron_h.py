@@ -371,7 +371,7 @@ def test_pool_is_blocks_of_keys_and_values_and_a_state_per_slot(tiny):
     assert pool["state"].dtype == jnp.float32
     assert pool["conv"].shape == (n_m, 6, cfg.conv_kernel - 1, cfg.conv_dim)
     assert paged.init_block_pool(cfg, 9, 16)["state"].shape[1] == cfg.state_slots + 1
-    assert paged.brings_own_programs(cfg) and paged.has_recurrent_state(cfg)
+    assert paged.cache(cfg) == paged.Cache(slot_state=True, per_head=True, hooks=False)
     assert not paged.decode_attends_in_place(cfg, 16)  # no TPU here: the gather
     with pytest.raises(ValueError, match="recurrent state"):
         paged.paged_verify(None, jnp.zeros((1, 2), jnp.int32), None, None, pool, cfg, block_size=16)

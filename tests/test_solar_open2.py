@@ -21,7 +21,7 @@ import pytest
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmarks.reference import solar_open2_ref as ref  # noqa: E402
-from ray_tpu.models import kimi_linear as kl, paged, solar_open2 as so  # noqa: E402
+from ray_tpu.models import kda, kimi_linear as kl, paged, solar_open2 as so  # noqa: E402
 from ray_tpu.models.llama import LlamaConfig  # noqa: E402
 
 pytestmark = pytest.mark.timeout(300)
@@ -86,23 +86,25 @@ def test_pool_parts_and_what_the_paged_programs_refuse(tiny):
     assert pool["state"].shape == (3, 7, H, d, d) and pool["state"].dtype == jnp.float32
     assert pool["conv"].shape == (3, 7, cfg.conv_kernel - 1, cfg.conv_dim)
     assert paged.init_block_pool(cfg, 9, 16)["state"].shape[1] == cfg.state_slots + 1
-    assert paged.has_recurrent_state(cfg) and paged.brings_own_programs(cfg)
-    assert paged.retention(cfg) == (None,)  # one layer kind: everything is kept
-    assert not paged.has_recurrent_state(LlamaConfig.tiny())
+    assert paged.cache(cfg).slot_state and not paged.cache(cfg).hooks
+    assert paged.cache(cfg).retention == (None,)  # one layer kind: everything is kept
+    assert not paged.cache(LlamaConfig.tiny()).slot_state
     with pytest.raises(ValueError, match="recurrent state"):
         paged.paged_verify(None, jnp.zeros((1, 2), jnp.int32), None, None, pool, cfg, block_size=16)
 
 
 def test_one_kda_implementation_serves_both_families_and_reads_betas_range(tiny):
-    """``solar_open2`` calls ``kimi_linear``'s mixer; ``kda_neg_eigval`` doubles
-    ``beta`` and changes nothing else, and Kimi Linear's stays a sigmoid."""
-    assert so.kda_prefill is kl.kda_prefill and so.kda_decode is kl.kda_decode
+    """``solar_open2`` and ``kimi_linear`` call ``models/kda.py``'s mixer;
+    ``kda_neg_eigval`` doubles ``beta`` and changes nothing else, and Kimi
+    Linear's stays a sigmoid."""
+    assert so.kda_prefill is kl.kda_prefill is kda.kda_prefill
+    assert so.kda_decode is kl.kda_decode is kda.kda_decode
     cfg, params = tiny
     p = params["layers"][1]
     h = jax.random.normal(jax.random.key(1), (8, cfg.d_model))
     mixed = jax.random.normal(jax.random.key(2), (8, cfg.conv_dim))
-    wide = kl._kda_inputs(h, mixed, p, cfg)
-    unit = kl._kda_inputs(h, mixed, p, dataclasses.replace(cfg, kda_neg_eigval=False))
+    wide = kda._kda_inputs(h, mixed, p, cfg)
+    unit = kda._kda_inputs(h, mixed, p, dataclasses.replace(cfg, kda_neg_eigval=False))
     for a, b in zip(wide[:4], unit[:4]):
         np.testing.assert_array_equal(a, b)
     np.testing.assert_allclose(wide[4], 2.0 * unit[4], rtol=1e-6)

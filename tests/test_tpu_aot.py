@@ -329,9 +329,8 @@ def test_engine_paged_programs_write_the_pool_in_place(v5e_2x2, program):
     )
     i32, W = jnp.int32, eng.block_tables.shape[1]
     if program == "paged_prefill":
-        lowered = eng._pg_prefill.lower(
-            on_chip(eng.params), sds((1, 32), i32), sds((), i32),
-            sds((), i32), sds((W,), i32), on_chip(eng.pool),
+        lowered = eng._pg_prefill.lower(  # the tokens and the one small operand
+            on_chip(eng.params), sds((1, 32), i32), sds((3 + W,), i32), on_chip(eng.pool),
         )
     else:
         lowered = eng._pg_decode.lower(  # the step before's tokens, the one operand

@@ -47,7 +47,7 @@ WIDTH = 128
 
 
 def inputs(key, T, H, d=WIDTH):
-    """As `kimi_linear._kda_inputs` hands them over: unit keys, queries
+    """As `models/kda.py:_kda_inputs` hands them over: unit keys, queries
     scaled, log decays from the initialiser's range, ``beta`` in (0, 2)."""
     ks = jax.random.split(key, 6)
     l2 = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa: E731
