@@ -427,6 +427,14 @@ class LLMEngine:
         for path, arr in jax.tree_util.tree_flatten_with_path(self.pool)[0]:
             part = "_".join(str(k.key) for k in path)  # bytes of each cache part
             self.stats[f"cache_bytes_{part}"] = int(arr.nbytes)
+            if self._cache.kinds:  # ... and as the device lays it out (a minor dimension in whole tiles)
+                self.stats[f"cache_bytes_laid_{part}"] = int(arr.on_device_size_in_bytes())
+        # What the mathematics needs of the same blocks, a table kind, where
+        # the family's record states its kinds: a pool that pads shows as laid
+        # bytes over these.
+        blocks = (n, *([self._window.mgr.num_blocks] if self._window else []))
+        for i, (kind, count) in enumerate(zip(self._cache.kinds, blocks)):
+            self.stats[f"cache_bytes_needed_kind{i}"] = kind.row_bytes * bs * count
         if self._cache.slot_state:
             # Prefills that began a sequence and so began from zero state,
             # whatever the slot held.

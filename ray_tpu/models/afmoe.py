@@ -410,8 +410,10 @@ def paged_decode(
     B = last_tokens.shape[0]
     tables = _by_kind(tables if tables.ndim == 3 else jnp.stack([tables, tables], axis=1))
     attend = {
-        "full": paged.decode_attention(cfg, block_size, None, interpret),
-        "window": paged.decode_attention(cfg, block_size, None, interpret, window=cfg.sliding_window),
+        "full": paged.decode_attention(paged.attention_kind(cfg), block_size, None, interpret),
+        "window": paged.decode_attention(
+            paged.attention_kind(cfg, cfg.sliding_window), block_size, None, interpret
+        ),
     }
     rows = jnp.arange(B)
     offs = positions % block_size

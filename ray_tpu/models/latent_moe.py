@@ -28,7 +28,8 @@ implementation, which :mod:`ray_tpu.models.kimi_linear` and
   kernel on a TPU, ``jax.lax.ragged_dot`` elsewhere), a long prompt's in
   passes that stop where the landed pairs end. What absent experts would add is
   left out; on one chip the layer runs without its exchange. Here too a
-  family says what it has by what its layer's parameters hold: experts with a
+  family says what it has by what its layer's parameters hold: a shared expert
+  (``s_up``; absent: the routed sum alone), experts with a
   gate (``e_gate``, ``s_gate``: ``act(gate) * up``) or without one
   (``act(up)``), ``act`` the configuration's ``hidden_act``; and the routed
   part at the model's width, or in a latent (``latent_in`` / ``latent_out``:
@@ -292,7 +293,7 @@ def route(h, p, cfg):
 def moe_ffn(h, p, cfg, valid=None):
     """``h`` [T, D] normed -> ``(y [T, D], counts int32 [2], picks [T, k])``:
     the experts held here on the picks that land on them, plus the shared
-    expert. ``valid`` [T] bool marks real tokens: the others are routed
+    expert where the layer has one. ``valid`` [T] bool marks real tokens: the others are routed
     nowhere, so they touch no expert. ``counts`` is (picks that landed on a
     held expert, held experts with at least one pick).
 
@@ -373,10 +374,12 @@ def moe_ffn(h, p, cfg, valid=None):
     y = y.astype(dt)
     if "latent_out" in p:
         y = y @ p["latent_out"].astype(dt)
-    mid = shared_in @ p["s_up"].astype(dt)
-    mid = act(shared_in @ p["s_gate"].astype(dt)) * mid if "s_gate" in p else act(mid)
+    mid = None
+    if "s_up" in p:  # the shared expert, where the layer has one
+        mid = shared_in @ p["s_up"].astype(dt)
+        mid = act(shared_in @ p["s_gate"].astype(dt)) * mid if "s_gate" in p else act(mid)
     counts = jnp.stack([jnp.sum(here, dtype=jnp.int32), jnp.sum(sizes > 0, dtype=jnp.int32)])
-    return y + mid @ p["s_down"].astype(dt), counts, idx
+    return (y if mid is None else y + mid @ p["s_down"].astype(dt)), counts, idx
 
 
 def experts_in_kernel(p, dtype, mesh=None) -> bool:

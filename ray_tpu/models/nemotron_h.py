@@ -461,7 +461,7 @@ def paged_decode(
     logits [B, vocab] float32, counts int32 [E blocks, 2])``."""
     B = last_tokens.shape[0]
     pk, pv, state, conv = pool["k"], pool["v"], pool["state"], pool["conv"]
-    attend = paged.decode_attention(cfg, block_size, None, interpret)
+    attend = paged.decode_attention(paged.attention_kind(cfg), block_size, None, interpret)
     keep = None if live is None else ~live
     x = params["wte"].astype(cfg.dtype)[last_tokens]
     seen: list = []

@@ -33,7 +33,11 @@ and nothing else about a family's cache.
 - *Blocks of rows per head*, ``{"k", "v": [L, N, KH, block, Dh]}``: written
   with :func:`_write`, read by prefill as a gathered table or a stretch of the
   table at a time (:func:`prefill_attention`), by decode through
-  :func:`decode_attention`.
+  :func:`decode_attention`. What those two need of an attention layer is its
+  *kind* (:class:`AttentionKind`: KV heads, the key's and the value's width,
+  a window, a learned sink), which a family with one shape of head takes
+  from its configuration (:func:`attention_kind`) and one whose kinds of
+  layer differ in shape states on its record (``Cache.kinds``).
 - *Blocks of latent rows*, ``"ckv": [L, N, block, pool_row_dim]``: one row a
   position for all heads, under the same tables and ``BlockManager``. A cache
   like keys and values (stale rows masked by position, prefixes shared,
@@ -87,6 +91,7 @@ _FAMILIES = {
     "nemotron_h": "ray_tpu.models.nemotron_h",
     "afmoe": "ray_tpu.models.afmoe",
     "solar_open2": "ray_tpu.models.solar_open2",
+    "mimo_v2": "ray_tpu.models.mimo_v2",
 }
 
 
@@ -123,6 +128,11 @@ class Cache:
     slot_state: bool = False  # a state and a tail per slot beside the blocks
     per_head: bool = True  # rows in blocks: keys and values per head, or latent rows
     hooks: bool = False  # served through kv_hooks by this module's programs
+    # The kinds of attention layer over keys and values per head, a table
+    # kind an entry (:class:`AttentionKind`, each with its count of layers),
+    # where the family's kinds differ in shape (empty: one shape of head,
+    # which the configuration gives: :func:`attention_kind`).
+    kinds: tuple = ()
 
     @property
     def shares_prefixes(self) -> bool:
@@ -207,25 +217,33 @@ def _write_read(pool_kv, l, bids, offs, new, tables):
     return pool_kv, pool_kv[l, tables]
 
 
-def _attend_gathered(qg, pk, pv, l, tables, lengths, window=None):
+def _attend_gathered(qg, pk, pv, l, tables, lengths, sink=None, *, window=None):
     """Decode attention by gather: each slot's whole table brought back as
-    a dense row [B, KH, S, Dh] and masked to its first ``lengths[b]``
-    positions (with ``window``, the last ``window`` of them). ``qg`` [B, KH,
-    group, Dh]; returns the same shape. What
+    dense rows [B, KH, S, Dk] and [B, KH, S, Dv] and masked to its first
+    ``lengths[b]`` positions (with ``window``, the last ``window`` of them).
+    ``qg`` [B, KH, group, Dk] (a key pool with wider rows holds zeros behind a
+    key: the query is padded to meet them); ``sink`` [KH, group]: a column of
+    the softmax that carries no value. Returns [B, KH, group, Dv]. What
     :func:`ops.paged_attention.paged_decode_attention` computes from the
     live blocks alone."""
     B, KH, _, Dh = qg.shape
     S = tables.shape[1] * pk.shape[3]
-    kd = pk[l, tables].transpose(0, 2, 1, 3, 4).reshape(B, KH, S, Dh)
-    vd = pv[l, tables].transpose(0, 2, 1, 3, 4).reshape(B, KH, S, Dh)
+    if pk.shape[-1] > Dh:
+        qg = jnp.pad(qg, ((0, 0),) * 3 + ((0, pk.shape[-1] - Dh),))
+    kd = pk[l, tables].transpose(0, 2, 1, 3, 4).reshape(B, KH, S, pk.shape[-1])
+    vd = pv[l, tables].transpose(0, 2, 1, 3, 4).reshape(B, KH, S, pv.shape[-1])
     s = jnp.einsum("bkgd,bksd->bkgs", qg, kd).astype(jnp.float32)
     s = s * (1.0 / (Dh**0.5))
     mask = jnp.arange(S)[None, :] < lengths[:, None]  # [B, S]
     if window is not None:
         mask &= jnp.arange(S)[None, :] >= lengths[:, None] - window
     s = jnp.where(mask[:, None, None, :], s, -1e30)
-    pa = jax.nn.softmax(s, axis=-1).astype(vd.dtype)
-    return jnp.einsum("bkgs,bksd->bkgd", pa, vd)
+    if sink is None:
+        pa = jax.nn.softmax(s, axis=-1)
+    else:
+        column = jnp.broadcast_to(sink.astype(jnp.float32)[None, :, :, None], (*s.shape[:3], 1))
+        pa = jax.nn.softmax(jnp.concatenate([s, column], axis=-1), axis=-1)[..., :S]
+    return jnp.einsum("bkgs,bksd->bkgd", pa.astype(vd.dtype), vd)
 
 
 def _attend_latent_gathered(ql, ckv, l, tables, lengths, *, value_width, scale):
@@ -244,6 +262,42 @@ def _attend_latent_gathered(ql, ckv, l, tables, lengths, *, value_width, scale):
     return jnp.einsum("bhs,bsr->bhr", pa, rows[..., :value_width])
 
 
+@dataclasses.dataclass(frozen=True)
+class AttentionKind:
+    """What :func:`decode_attention`, :func:`prefill_attention` and the choice
+    between the kernel and the gather need of one kind of attention layer
+    over keys and values per head."""
+
+    kv_heads: int
+    key_width: int  # of a query's and a key's head: the scores' scale is its ^-1/2
+    value_width: int
+    itemsize: int  # of the pool's dtype
+    window: Optional[int] = None  # the last positions a query sees, its own included
+    sink: bool = False  # a learned scalar a query head in the softmax's sum
+    # The key pool's row width where it is more than a key's (zeros behind
+    # the key, up to whole lane tiles; None: the key's own).
+    key_lanes: Optional[int] = None
+    # What the kernel's call is named after in a device trace, for a family
+    # whose kinds a reader tells apart ("": the kernel's own name).
+    name: str = ""
+    layers: int = 0  # of the kind, held here (a family's record says it: ``Cache.kinds``)
+
+    @property
+    def row_bytes(self) -> int:
+        """Bytes the mathematics needs for one position in all layers of the
+        kind, whatever the pool pads."""
+        return self.layers * self.kv_heads * (self.key_width + self.value_width) * self.itemsize
+
+
+def attention_kind(cfg, window=None) -> AttentionKind:
+    """The kind of a family whose attention layers have one shape of head:
+    ``n_kv_head`` (or ``n_head``) heads of ``head_dim`` for keys and values
+    alike, no sink; ``window`` for its layers that keep one."""
+    return AttentionKind(
+        _kv_heads(cfg), cfg.head_dim, cfg.head_dim, jnp.dtype(cfg.dtype).itemsize, window
+    )
+
+
 def decode_attends_in_place(cfg, block_size: int, *, mesh=None) -> bool:
     """Whether the family's decode program, lowered for this process's
     default backend, attends the live blocks in place (the kernel) or gathers
@@ -251,21 +305,27 @@ def decode_attends_in_place(cfg, block_size: int, *, mesh=None) -> bool:
     TPU tiles and fit VMEM, outside a mesh (the compiler cannot partition a
     Mosaic call). By the family's record the rows in blocks are keys and
     values per head (its attention layers call :func:`decode_attention`, the
-    same choice) or latent rows, whose width the configuration gives
+    same choice, a kind of layer at a time: in place only if every kind's
+    shapes fit) or latent rows, whose width the configuration gives
     (``pool_row_dim``: its programs call :func:`latent_decode_attention`).
     Decided by what the code can see, like
     ``ops.attention.uses_flash_kernel``; nothing a user sets reaches it."""
-    fits = _kernel_fits if cache(cfg).per_head else _latent_kernel_fits
-    return jax.default_backend() == "tpu" and fits(cfg, block_size, mesh)
+    if jax.default_backend() != "tpu":
+        return False
+    record = cache(cfg)
+    if not record.per_head:
+        return _latent_kernel_fits(cfg, block_size, mesh)
+    return all(_kernel_fits(kind, block_size, mesh) for kind in record.kinds or (attention_kind(cfg),))
 
 
 def _kv_heads(cfg) -> int:
     return getattr(cfg, "n_kv_head", None) or cfg.n_head
 
 
-def _kernel_fits(cfg, block_size, mesh) -> bool:
+def _kernel_fits(kind: AttentionKind, block_size, mesh) -> bool:
     return (mesh is None or mesh.size == 1) and paged_attention.fits(
-        _kv_heads(cfg), cfg.head_dim, block_size, jnp.dtype(cfg.dtype).itemsize
+        kind.kv_heads, kind.key_lanes or kind.key_width, block_size, kind.itemsize,
+        kind.value_width,
     )
 
 
@@ -289,17 +349,20 @@ def _choose(kernel, gather, fits: bool, interpret: bool):
     return functools.partial(jax.lax.platform_dependent, tpu=kernel, default=gather)
 
 
-def decode_attention(cfg, block_size, mesh, interpret, window=None):
+def decode_attention(kind: AttentionKind, block_size, mesh, interpret):
     """The decode step's attention over the scattered pool of keys and
-    values per head, ``attend(qg, pk, pv, l, tables, lengths)``: the kernel
-    or the gather, as :func:`_choose` says. ``window``: for a layer that
+    values per head, ``attend(qg, pk, pv, l, tables, lengths)`` (and, for a
+    kind with a sink, the layer's ``sink`` [KH, group] behind them): the
+    kernel or the gather, as :func:`_choose` says. A kind with a window
     attends the last ``window`` positions only, either arm under that mask;
-    None leaves both as they were."""
+    one without a window, a sink or a name leaves both as they were."""
     kernel, gather = paged_attention.paged_decode_attention, _attend_gathered
-    if window is not None:
-        kernel = functools.partial(kernel, window=window)
-        gather = functools.partial(gather, window=window)
-    return _choose(kernel, gather, _kernel_fits(cfg, block_size, mesh), interpret)
+    if kind.window is not None:
+        kernel = functools.partial(kernel, window=kind.window)
+        gather = functools.partial(gather, window=kind.window)
+    if kind.name:  # a suffix: a reader that matches the kernel's own name as a prefix still does
+        kernel = functools.partial(kernel, name=f"paged_decode_attention_{kind.name}")
+    return _choose(kernel, gather, _kernel_fits(kind, block_size, mesh), interpret)
 
 
 # Positions of the table that one step of prefill's running softmax scores,
@@ -310,37 +373,42 @@ KEY_POSITIONS = 512
 
 
 def prefill_attention(
-    q, pk, pv, l, table, pos, n_keys, *, block_size: int, window=None,
+    q, pk, pv, l, table, pos, n_keys, *, block_size: int, window=None, sink=None,
     key_positions: int = KEY_POSITIONS,
 ):
     """Prefill's attention over keys and values per head, a stretch of the
-    table at a time: ``q`` [T, KH, group, Dh] at consecutive positions ``pos``
-    [T] against layer ``l`` of ``pk`` / ``pv`` [L, N, KH, block, Dh], which
-    already hold the queries' own keys and values, read through ``table``
-    [W]; ``n_keys`` (traced) the positions that hold a row by now. The mask is
-    ``column <= position`` and, with ``window``, ``position - column <
-    window``. Each run of ``key_positions`` queries folds the stretches from
-    the one that holds the first column its first query sees (column 0
-    without a window) to the one that holds its last query's own position
-    into a running softmax (float32 maximum, sum and values), as
-    :func:`ray_tpu.models.latent_moe.mla_prefill` does for latent rows: no
-    ``[heads, T, table]`` scores exist, and a window layer reads nothing
-    behind its window, where the table points at the scratch block. Returns
-    [T, KH, group, Dh] in the pool's dtype."""
+    table at a time: ``q`` [T, KH, group, Dk] at consecutive positions ``pos``
+    [T] against layer ``l`` of ``pk`` [L, N, KH, block, Dk or wider] and
+    ``pv`` [L, N, KH, block, Dv], which already hold the queries' own keys
+    and values, read through ``table`` [W]; ``n_keys`` (traced) the positions
+    that hold a row by now. The mask is ``column <= position`` and, with
+    ``window``, ``position - column < window``. Each run of ``key_positions``
+    queries folds the stretches from the one that holds the first column its
+    first query sees (column 0 without a window) to the one that holds its
+    last query's own position into a running softmax (float32 maximum, sum
+    and values), as :func:`ray_tpu.models.latent_moe.mla_prefill` does for
+    latent rows: no ``[heads, T, table]`` scores exist, and a window layer
+    reads nothing behind its window, where the table points at the scratch
+    block. ``sink`` [KH, group]: the layer's learned sink, with which the
+    running softmax starts from ``(sink, 1, 0)`` and not from ``(-1e30, 0,
+    0)``. Returns [T, KH, group, Dv] in the pool's dtype."""
     T, KH, G, Dh = q.shape
+    Dk, Dv = pk.shape[-1], pv.shape[-1]
     dt = pk.dtype
     nb = math.gcd(table.shape[0], max(1, key_positions // block_size))  # blocks a step
     Kb = nb * block_size
     Qb = Kb if T % Kb == 0 else T  # queries a run
     scale = Dh**-0.5
     f32 = jnp.float32
+    if Dk > Dh:  # zeros behind a key in the pool's rows meet zeros in the query
+        q = jnp.pad(q, ((0, 0),) * 3 + ((0, Dk - Dh),))
 
     def attend(q, pos):
         def step(j, carry):
             m, s_sum, acc = carry
             blocks = jax.lax.dynamic_slice_in_dim(table, j * nb, nb)
-            k = pk[l, blocks].transpose(1, 0, 2, 3).reshape(KH, Kb, Dh)
-            v = pv[l, blocks].transpose(1, 0, 2, 3).reshape(KH, Kb, Dh)
+            k = pk[l, blocks].transpose(1, 0, 2, 3).reshape(KH, Kb, Dk)
+            v = pv[l, blocks].transpose(1, 0, 2, 3).reshape(KH, Kb, Dv)
             s = jnp.einsum("tkgd,ksd->kgts", q, k, preferred_element_type=f32) * scale
             cols = j * Kb + jnp.arange(Kb)
             seen = cols[None, :] <= pos[:, None]
@@ -359,13 +427,17 @@ def prefill_attention(
         # while the row's maximum is still -1e30; the first stretch that
         # holds a column it sees multiplies that away (exp(-1e30 - m) = 0),
         # and every row sees its own position, in the run's last stretches.
+        # (From a sink the maximum is never -1e30: such a stretch adds
+        # exp(-1e30 - sink) = 0 from the start.)
         last = jnp.minimum(n_keys - 1, pos[-1]) // Kb
         first = 0 if window is None else jnp.minimum(jnp.maximum(pos[0] - window + 1, 0) // Kb, last)
         n = q.shape[0]
-        init = (
-            jnp.full((KH, G, n), -1e30, f32), jnp.zeros((KH, G, n), f32),
-            jnp.zeros((KH, G, n, Dh), f32),
-        )
+        if sink is None:
+            m0, s0 = jnp.full((KH, G, n), -1e30, f32), jnp.zeros((KH, G, n), f32)
+        else:
+            m0 = jnp.broadcast_to(sink.astype(f32)[:, :, None], (KH, G, n))
+            s0 = jnp.ones((KH, G, n), f32)
+        init = (m0, s0, jnp.zeros((KH, G, n, Dv), f32))
         _, s_sum, acc = jax.lax.fori_loop(first, last + 1, step, init)
         return (acc / s_sum[..., None]).astype(dt).transpose(2, 0, 1, 3)
 
@@ -595,7 +667,7 @@ def paged_decode(
     S = W * block_size
     embed, qkv, finish, final, H, KH, Dh = mod.kv_hooks(cfg, S)
     group = H // KH
-    attend = decode_attention(cfg, block_size, mesh, interpret)
+    attend = decode_attention(attention_kind(cfg), block_size, mesh, interpret)
 
     x = embed(params, last_tokens[:, None], positions[:, None])  # [B,1,D]
     rows = jnp.arange(B)

@@ -33,6 +33,17 @@ columns ``length - window <= col < length``: a slot's walk then starts at the
 block that holds ``length - window`` and the table's entries before it are
 never read, so the engine may have given those blocks back. With no window the
 kernel is traced as it was: the bound exists in the program only where asked.
+
+**Keys wider than values, and a sink.** Each pool has a chunk buffer of its own
+width, so keys of another width than the values' lie beside them, and a key
+pool's rows may be wider than a key (zeros up to whole lane tiles: Mosaic copies
+no slice of a row that is not whole tiles, so keys of 192 are stored in 256);
+the scale of the scores is the key's own width's. A layer
+with a learned *sink* (a scalar a query head that takes probability and adds no
+value: ``p_j = exp(s_j) / (sum exp(s) + exp(sink))``) hands it over as a
+float32 operand, and a slot's fold then starts from ``(sink, 1, 0)`` and not
+from ``(-inf, 0, 0)``. Like the window, both exist in the program only where
+asked: with one width and no sink the kernel is traced as it was.
 """
 
 from __future__ import annotations
@@ -71,16 +82,21 @@ def _pages(block_size: int, chunk: int) -> int:
     return max(1, chunk // block_size)
 
 
-def fits(kv_heads: int, head_dim: int, block_size: int, itemsize: int) -> bool:
+def fits(
+    kv_heads: int, head_dim: int, block_size: int, itemsize: int, value_dim: int | None = None
+) -> bool:
     """Whether the kernel's copies and matmuls are whole TPU tiles at these
-    shapes (the lane width in ``head_dim``, the bf16 sublane tile in the
-    block) and its chunk buffers, of ``itemsize`` bytes an element, fit
+    shapes (the lane width in ``head_dim``, a key's stored width, and in
+    ``value_dim``, a value's, where it is another; the bf16 sublane tile in
+    the block) and its chunk buffers, of ``itemsize`` bytes an element, fit
     VMEM."""
     chunk = _pages(block_size, _CHUNK) * block_size
+    value_dim = head_dim if value_dim is None else value_dim
     return (
         head_dim % 128 == 0
+        and value_dim % 128 == 0
         and block_size % 16 == 0
-        and 4 * kv_heads * chunk * head_dim * itemsize <= _VMEM_BUFFER_BYTES
+        and 2 * kv_heads * chunk * (head_dim + value_dim) * itemsize <= _VMEM_BUFFER_BYTES
     )
 
 
@@ -103,15 +119,18 @@ def fits_latent(
 def _kernel(
     layer_ref, lengths_ref, tables_ref,  # scalar prefetch (SMEM)
     q_ref,  # [1, KH, G, Dh] VMEM
-    *refs,  # the pools, o_ref, a chunk buffer for each pool, sems, parity
-    block, pages, width, scale, window=None,
+    *refs,  # (the sink,) the pools, o_ref, a chunk buffer for each pool, sems, parity
+    block, pages, width, scale, window=None, sink=False,
 ):
-    """``refs``: the pools ``[L, N, KH, block, Dh]`` left in HBM, keys then
-    values, or one whose rows are the keys and, in their first lanes, the
+    """``refs``: with ``sink``, first the sinks [KH, G, 1] float32 VMEM; the
+    pools ``[L, N, KH, block, D]`` left in HBM, keys then values (each of its
+    own width), or one whose rows are the keys and, in their first lanes, the
     values; ``o_ref`` [1, KH, G, Dv] VMEM; for each pool its chunk buffer
-    [2, KH, pages * block, Dh] VMEM; DMA semaphores [pools, 2 (buffer)];
+    [2, KH, pages * block, D] VMEM; DMA semaphores [pools, 2 (buffer)];
     SMEM [1]: the buffer the slot's first chunk was copied to. ``window``: the
     positions a slot's query sees, its own included (None: all of them)."""
+    if sink:
+        sink_ref, *refs = refs
     n = (len(refs) - 3) // 2
     pools, o_ref, bufs = refs[:n], refs[n], refs[n + 1 : 2 * n + 1]
     sems, parity = refs[2 * n + 1 :]
@@ -209,8 +228,12 @@ def _kernel(
         )
         return m_new, l_new, acc_new
 
-    m0 = jnp.full((KH, G, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((KH, G, 1), jnp.float32)
+    if sink:  # the sink's term is in the sum before any key: exp2(m0 - m0) = 1
+        m0 = sink_ref[...] * _LOG2E
+        l0 = jnp.ones((KH, G, 1), jnp.float32)
+    else:
+        m0 = jnp.full((KH, G, 1), _NEG_INF, jnp.float32)
+        l0 = jnp.zeros((KH, G, 1), jnp.float32)
     acc0 = jnp.zeros((KH, G, Dv), jnp.float32)
     _, l, acc = jax.lax.fori_loop(0, n_chunks, body, (m0, l0, acc0))
     o_ref[0] = (acc / l).astype(o_ref.dtype)
@@ -218,11 +241,14 @@ def _kernel(
 
 
 def _attend(
-    q, pools, layer, tables, lengths, *, value_width, scale, chunk, interpret, name, window=None
+    q, pools, layer, tables, lengths, *, value_width, scale, chunk, interpret, name, window=None,
+    sink=None,
 ):
     """The call both entries make: ``q`` [B, KH, G, Dh] against ``pools``
-    (each [L, N, KH, block, Dh]; the last one's first ``value_width`` lanes
-    are the values), ``chunk`` positions a fold; [B, KH, G, value_width]."""
+    (each [L, N, KH, block, its own width]; the first one's rows are the keys,
+    the last one's first ``value_width`` lanes the values), ``chunk``
+    positions a fold, ``sink`` [KH, G] float32 or None; [B, KH, G,
+    value_width]."""
     B, KH, G, Dh = q.shape
     block = pools[0].shape[3]
     pages = _pages(block, chunk)
@@ -231,6 +257,11 @@ def _attend(
     static = dict(block=block, pages=pages, width=tables.shape[1], scale=scale)
     if window is not None:
         static["window"] = window
+    sinks, sink_specs = (), ()
+    if sink is not None:
+        static["sink"] = True
+        sinks = (sink.astype(jnp.float32)[:, :, None],)
+        sink_specs = (pl.BlockSpec((KH, G, 1), lambda b, *_: (0, 0, 0)),)
     return pl.pallas_call(
         functools.partial(_kernel, **static),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -238,11 +269,12 @@ def _attend(
             grid=(B,),
             in_specs=[
                 pl.BlockSpec((1, KH, G, Dh), lambda b, *_: (b, 0, 0, 0)),
+                *sink_specs,
                 *[anywhere for _ in pools],
             ],
             out_specs=pl.BlockSpec((1, KH, G, value_width), lambda b, *_: (b, 0, 0, 0)),
             scratch_shapes=[
-                *[pltpu.VMEM((2, KH, pages * block, Dh), dtype) for _ in pools],
+                *[pltpu.VMEM((2, KH, pages * block, pool.shape[-1]), dtype) for pool in pools],
                 pltpu.SemaphoreType.DMA((len(pools), 2)),
                 pltpu.SMEM((1,), jnp.int32),
             ],
@@ -257,35 +289,42 @@ def _attend(
         lengths.astype(jnp.int32),
         tables.reshape(-1).astype(jnp.int32),
         q.astype(dtype),
+        *sinks,
         *pools,
     )
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "window"))
+@functools.partial(jax.jit, static_argnames=("interpret", "window", "name"))
 def paged_decode_attention(
-    q: jax.Array,  # [B, KH, group, Dh] — one query position a slot
-    pool_k: jax.Array,  # [L, N, KH, block, Dh]
-    pool_v: jax.Array,
+    q: jax.Array,  # [B, KH, group, Dk] — one query position a slot
+    pool_k: jax.Array,  # [L, N, KH, block, Dk or wider]
+    pool_v: jax.Array,  # [L, N, KH, block, Dv]
     layer: jax.Array,  # scalar int32 — the layer of the pool to read
     tables: jax.Array,  # [B, W] int32 block tables
     lengths: jax.Array,  # [B] int32, >= 1 — positions attended, a slot
+    sink: jax.Array | None = None,  # [KH, group] — the layer's learned sink a query head
     *,
     interpret: bool = False,
     window: int | None = None,  # of them, only the last ``window``
+    name: str = "paged_decode_attention",  # the call's, in a device trace
 ) -> jax.Array:
-    """softmax(q k^T / sqrt(Dh)) v over each slot's first ``lengths[b]``
+    """softmax(q k^T / sqrt(Dk)) v over each slot's first ``lengths[b]``
     positions of its table's blocks in layer ``layer`` (with ``window``, the
-    last ``window`` of them); [B, KH, group, Dh] in the pool's dtype. Table
-    entries past a slot's live blocks, and before the block that holds the
-    window's first position, are never read."""
-    G, Dh = q.shape[2:]
-    pad = -G % _GROUP_TILE
-    if pad:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, pad), (0, 0)))
+    last ``window`` of them; with ``sink``, its ``exp`` in the softmax's sum
+    beside the keys'); [B, KH, group, Dv] in the pool's dtype. Table entries
+    past a slot's live blocks, and before the block that holds the window's
+    first position, are never read. A key pool whose rows are wider than
+    ``Dk`` (zeros behind a key, up to whole lane tiles) meets zeros in the
+    query; the scale is the key's own width's."""
+    G, Dk = q.shape[2:]
+    pad, lanes = -G % _GROUP_TILE, pool_k.shape[-1] - Dk
+    if pad or lanes:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, pad), (0, lanes)))
+    if pad and sink is not None:
+        sink = jnp.pad(sink, ((0, 0), (0, pad)))
     out = _attend(
-        q, (pool_k, pool_v), layer, tables, lengths, value_width=Dh,
-        scale=Dh**-0.5, chunk=_CHUNK, interpret=interpret,
-        name="paged_decode_attention", window=window,
+        q, (pool_k, pool_v), layer, tables, lengths, value_width=pool_v.shape[-1],
+        scale=Dk**-0.5, chunk=_CHUNK, interpret=interpret, name=name, window=window, sink=sink,
     )
     return out[:, :, :G]
 

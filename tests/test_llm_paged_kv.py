@@ -487,6 +487,10 @@ def _tiny_model_of(family):
         from ray_tpu.models.solar_open2 import SolarOpen2Config
 
         return SolarOpen2Config.tiny(max_seq=128)
+    if family == "mimo_v2":
+        from ray_tpu.models.mimo_v2 import MimoV2Config
+
+        return MimoV2Config.tiny(max_seq=128)
     return _family_model(family)[0]
 
 

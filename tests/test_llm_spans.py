@@ -175,8 +175,8 @@ def test_the_decode_arm_follows_platform_and_shapes_and_nothing_a_user_sets(monk
             assert paged.decode_attends_in_place(cfg, block, mesh=mesh) == want
     eng = LLMEngine(llm_config(model_config=tiling, max_seq=64))
     assert eng._decode_arm == "decode_attn_kernel_steps"  # "tpu" still patched
-    assert paged.decode_attention(small_head, 16, None, False) is paged._attend_gathered
-    assert paged.decode_attention(tiling, 16, two_chips, False) is paged._attend_gathered
+    assert paged.decode_attention(paged.attention_kind(small_head), 16, None, False) is paged._attend_gathered
+    assert paged.decode_attention(paged.attention_kind(tiling), 16, two_chips, False) is paged._attend_gathered
     # Nothing to set: the decision's only inputs are the model's shapes, the
     # block size and the mesh, and no configuration names it.
     assert list(inspect.signature(paged.decode_attends_in_place).parameters) == [

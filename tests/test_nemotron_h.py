@@ -145,7 +145,7 @@ def test_attention_prefill_and_decode_are_the_reference(tiny):
     tables = jnp.stack([table, jnp.zeros(4, jnp.int32)])
     positions = jnp.asarray([S, 0])
     for interpret in (False, True):
-        attend = paged.decode_attention(cfg, bs, None, interpret)
+        attend = paged.decode_attention(paged.attention_kind(cfg), bs, None, interpret)
         o, k1, v1 = nh.attention_decode(
             jnp.stack([u[S], u[0]]), p, cfg, pk, pv, 0, tables, positions, bs, attend
         )

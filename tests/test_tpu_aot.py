@@ -378,7 +378,7 @@ def test_solar_open2s_served_programs_compile_for_one_chip(v5e_2x2, program):
     )
     B, bs, N = 32, 16, 36865
     W = cfg.max_seq // bs
-    assert paged._kernel_fits(cfg, bs, None)
+    assert paged._kernel_fits(paged.attention_kind(cfg), bs, None)
     one = jax.sharding.SingleDeviceSharding(v5e_2x2[0])
     sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)  # noqa: E731
     on_chip = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
@@ -424,3 +424,194 @@ def test_solar_open2s_served_programs_compile_for_one_chip(v5e_2x2, program):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
     print(program, {k: getattr(mem, k + "_size_in_bytes") for k in ("temp", "argument", "output", "alias")},
           nbytes(params), nbytes(pool))
+
+
+@pytest.mark.parametrize("kind", ["full", "window"])
+def test_the_decode_attention_kernel_compiles_at_mimo_v25s_two_shapes(v5e_2x2, kind):
+    """The block-walking kernel as MiMo-V2.5's two kinds of layer call it (32
+    slots, tables of 18,432 positions): 4 key/value heads of 16 queries over a
+    two-layer part, no window; 8 key/value heads of 8 queries (padded to 16)
+    over a five-layer part, a window of 128 and a sink a head. Keys of 192 lie
+    in rows of 256 lanes beside values of 128, each pool with a chunk buffer
+    of its own width. **This is where Mosaic's refusal of 192 lanes shows**: a
+    key pool with rows of 192 (which the compiler lays out in 256 lanes
+    anyway) is refused for a copy that is not whole lane tiles, at no chip
+    time."""
+    from ray_tpu.ops import paged_attention
+
+    layers, blocks, KH, G, window, sink = {
+        "full": (2, 36865, 4, 16, None, False), "window": (5, 32 * 137 + 1, 8, 8, 128, True),
+    }[kind]
+    assert paged_attention.fits(KH, 256, 16, itemsize=2, value_dim=128)
+    assert not paged_attention.fits(KH, 192, 16, itemsize=2, value_dim=128)
+    one = jax.sharding.SingleDeviceSharding(v5e_2x2[0])
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)  # noqa: E731
+    name = f"paged_decode_attention_{kind}"
+    call = jax.jit(functools.partial(paged_attention.paged_decode_attention, window=window, name=name))
+
+    def operands(lanes):
+        args = [
+            sds((32, KH, G, 192), jnp.bfloat16), sds((layers, blocks, KH, 16, lanes), jnp.bfloat16),
+            sds((layers, blocks, KH, 16, 128), jnp.bfloat16), sds((), jnp.int32),
+            sds((32, 1152), jnp.int32), sds((32,), jnp.int32),
+        ]
+        return args + [sds((KH, G), jnp.float32)] * sink
+
+    compiled = call.lower(*operands(256)).compile()
+    assert mosaic_calls(compiled.as_text()) == [name]
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+    with pytest.raises(Exception, match="aligned to tiling"):
+        call.lower(*operands(192)).compile()
+
+
+@pytest.mark.parametrize("program", ["chunk_of_2048", "decode_of_32_slots"])
+def test_mimo_v25s_served_programs_compile_for_one_chip(v5e_2x2, program):
+    """``mimo_v2``'s two programs at the benchmark's shapes (the dense layer
+    and one period of the published widths, 16 of 256 experts held, 32 slots of
+    18,432 positions), lowered for the chip: the 2,048-token chunk that
+    continues a prompt (attention a stretch of the table at a time, the window
+    layers from their sink) and the decode step, whose attention is the kernel
+    in every layer, under a name a kind: twice over the full part's 4 heads,
+    five times over the window part's 8, and no table gathered whole. The pool
+    is donated and aliased; weights, cache and temporaries are within the
+    chip's 16 GB."""
+    from ray_tpu.models import mimo_v2 as mm, paged
+
+    cfg = mm.MimoV2Config(
+        vocab_size=19072, layer_pattern=(0, 1, 1, 1, 1, 1, 0), moe_layers=(0, 1, 1, 1, 1, 1, 1),
+        experts_held=16, max_seq=18432, window_slots=32,
+    )
+    B, bs, N = 32, 16, 36865
+    W = cfg.max_seq // bs
+    assert all(paged._kernel_fits(kind, bs, None) for kind in paged.cache(cfg).kinds)
+    one = jax.sharding.SingleDeviceSharding(v5e_2x2[0])
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)  # noqa: E731
+    on_chip = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
+    params = on_chip(jax.eval_shape(lambda k: mm.draw_params(k, cfg), jax.random.key(0)))
+    pool = on_chip(jax.eval_shape(lambda: mm.init_pool(cfg, N, bs)))
+    i32 = jnp.int32
+    if program == "chunk_of_2048":
+        compiled = jax.jit(
+            functools.partial(paged.paged_prefill, cfg=cfg, block_size=bs), donate_argnums=5
+        ).lower(
+            params, sds((1, 2048), i32), sds((), i32), sds((), i32), sds((2, W), i32), pool,
+        ).compile()
+        assert not [c for c in mosaic_calls(compiled.as_text()) if c.startswith("paged_decode_attention")]
+    else:
+        compiled = jax.jit(
+            functools.partial(paged.paged_decode, cfg=cfg, block_size=bs), donate_argnums=4
+        ).lower(
+            params, sds((B,), i32), sds((B,), i32), sds((B, 2, W), i32), pool, live=sds((B,), jnp.bool_),
+        ).compile()
+        calls = mosaic_calls(compiled.as_text())
+        assert calls.count("paged_decode_attention_full") == 2
+        assert calls.count("paged_decode_attention_window") == 5
+        for heads in (4, 8):  # no table brought back whole
+            assert f"bf16[{B},{W},{heads},{bs}," not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    nbytes = lambda t: sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(t))  # noqa: E731
+    assert 6.8e9 < nbytes(params) < 6.9e9 and 5.7e9 < nbytes(pool) < 5.8e9
+    assert mem.alias_size_in_bytes >= nbytes(pool)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
+    print(program, {k: getattr(mem, k + "_size_in_bytes") for k in ("temp", "argument", "output", "alias")},
+          nbytes(params), nbytes(pool))
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _pallas_calls(inner)
+
+
+def _plain_decode_kernel_calls(jaxpr, head_dim) -> int:
+    """How many calls of the decode kernel ``jaxpr`` makes, each held to what
+    the kernel was before a sink, a width a pool and a kind's name: its own
+    name, six operands (three scalars ahead, the queries, two pools: no
+    sink), both chunk buffers and the output of the one width."""
+    calls = list(_pallas_calls(jaxpr))
+    for eqn in calls:
+        assert eqn.params["name"] == "paged_decode_attention"
+        shapes = [v.aval.shape for v in eqn.invars]
+        assert len(shapes) == 6 and shapes[4] == shapes[5] and shapes[4][-1] == head_dim
+        buffers = [v.aval.shape for v in eqn.params["jaxpr"].invars if "vmem" in str(v.aval)]
+        assert len(buffers) == 2 and buffers[0] == buffers[1] and buffers[0][-1] == head_dim
+        assert eqn.params["out_avals"][0].shape[-1] == head_dim
+    return len(calls)
+
+
+@pytest.mark.parametrize(
+    "slots,group,blocks,layers,width,window",
+    [(16, 4, 2049, 16, 128, None), (24, 6, 9241, 4, 1152, 4096)], ids=["mistral-7b", "trinity-window"],
+)
+def test_a_kind_with_one_width_and_no_sink_is_the_plain_kernel_and_the_plain_gather(
+    slots, group, blocks, layers, width, window
+):
+    """What is asked of the attention functions only where a family asks for
+    it stays out of every other family's program: at Mistral's and Trinity's
+    served shapes, the kind a family of one head shape states
+    (``paged.attention_kind``: no sink, one width, no name) is served by the
+    kernel and the gather called as before kinds existed (the window and
+    nothing else bound to them); that kernel has no sink operand, one buffer
+    width and its own name; that gather appends no column and pads no
+    query."""
+    from ray_tpu.models import llama, paged
+    from ray_tpu.ops import paged_attention as pa
+
+    def unbound(f):
+        bound = {}
+        while isinstance(f, functools.partial):
+            assert not f.args
+            bound.update(f.keywords)
+            f = f.func
+        return f, bound
+
+    sds, i32, bf16 = jax.ShapeDtypeStruct, jnp.int32, jnp.bfloat16
+    pool = sds((layers, blocks, 8, 16, 128), bf16)
+    args = (sds((slots, 8, group, 128), bf16), pool, pool, sds((), i32), sds((slots, width), i32),
+            sds((slots,), i32))
+    cfg = llama.LlamaConfig.tiny(n_head=8 * group, n_kv_head=8, d_model=8 * group * 128)  # bf16 activations
+    kind = paged.attention_kind(cfg, window)
+    assert (kind.kv_heads, kind.key_width, kind.value_width, kind.sink, kind.key_lanes, kind.name) == (
+        8, 128, 128, False, None, "")
+    windowed = {} if window is None else {"window": window}
+    assert unbound(paged.decode_attention(kind, 16, None, True)) == (
+        pa.paged_decode_attention, {"interpret": True, **windowed})
+    two_chips = Mesh(np.array(jax.devices()[:2]), ("tp",))  # no kernel under a mesh: the gather itself
+    assert unbound(paged.decode_attention(kind, 16, two_chips, False)) == (paged._attend_gathered, windowed)
+    dispatch, arms = unbound(paged.decode_attention(kind, 16, None, False))
+    assert dispatch is jax.lax.platform_dependent and sorted(arms) == ["default", "tpu"]
+    assert unbound(arms["tpu"]) == (pa.paged_decode_attention, windowed)
+    assert unbound(arms["default"]) == (paged._attend_gathered, windowed)
+    kernel = functools.partial(pa.paged_decode_attention, interpret=True, **windowed)
+    assert _plain_decode_kernel_calls(jax.make_jaxpr(kernel)(*args).jaxpr, 128) == 1
+    text = str(jax.make_jaxpr(functools.partial(paged._attend_gathered, **windowed))(*args))
+    assert f",{width * 16 + 1}]" not in text and " pad[" not in text  # no column beside the keys', no padded query
+
+
+def test_the_other_families_decode_programs_call_the_plain_kernel():
+    """Llama's, Trinity's, Nemotron's and Solar's decode programs whole, at
+    tiny sizes under the interpreted kernel: every attention layer's call is
+    the plain kernel's (its own name, no sink operand, one width)."""
+    from ray_tpu.models import afmoe, llama, nemotron_h, paged, solar_open2
+
+    sds, i32 = jax.ShapeDtypeStruct, jnp.int32
+    cfgs = [
+        llama.LlamaConfig.tiny(n_layer=2, d_model=64, n_head=4, n_kv_head=2, max_seq=64),
+        afmoe.AfmoeConfig.tiny(max_seq=64),
+        nemotron_h.NemotronHConfig.tiny(max_seq=64),
+        solar_open2.SolarOpen2Config.tiny(max_seq=64),
+    ]
+    B, bs, W, N = 2, 4, 16, 33
+    for cfg in cfgs:
+        mod = paged.family(cfg)
+        params = jax.eval_shape(lambda k, mod=mod, cfg=cfg: mod.init_params(k, cfg), jax.random.key(0))
+        pool = jax.eval_shape(lambda cfg=cfg: paged.init_block_pool(cfg, N, bs, B))
+        jaxpr = jax.make_jaxpr(
+            functools.partial(paged.paged_decode, cfg=cfg, block_size=bs, interpret=True))(
+            params, sds((B,), i32), sds((B,), i32), sds((B, W), i32), pool)
+        assert _plain_decode_kernel_calls(jaxpr.jaxpr, cfg.head_dim) >= 1, cfg.family

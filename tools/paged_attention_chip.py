@@ -1,6 +1,6 @@
 """The decode attention kernel alone, on the chip, against the gather.
 
-    python tools/paged_attention_chip.py [--latent] [--chunks 128,256,512]
+    python tools/paged_attention_chip.py [--latent | --kind full|window] [--chunks 128,256,512]
 
 Sixteen slots over a pool of Mistral-7B's shapes (16 layers, 2,049 blocks of
 16, 8 KV heads of 128, tables of 2,048 positions): for each mix of live
@@ -14,7 +14,15 @@ A.X-K1's shapes (32 slots over a pool of 7 layers, 8,193 blocks of 16 rows of
 ``paged_latent_decode_attention`` against ``paged._attend_latent_gathered``,
 the first mix's lengths drawn from ``reasoning-backlog``'s tables, and each
 row also says what share of the live blocks' bytes' speed (819 GB/s) the
-kernel reached. ``--chunks`` sweeps the kernel's chunk length. Needs a TPU:
+kernel reached. ``--kind full`` and ``--kind window`` do it for the two
+kinds of attention layer of MiMo-V2.5's cell (32 slots, tables of 18,432
+positions, keys of 192 in rows of 256 lanes beside values of 128): 4
+key/value heads of 16 queries over 6k-17k live rows in a two-layer part; 8
+key/value heads of 8 queries (padded to 16) with a window of 128 and a sink
+over a five-layer part, where the bytes are those of the blocks the walk
+covers. (A key buffer of 192 lanes cannot be timed beside the rows of 256:
+Mosaic refuses a copy that is not whole lane tiles, PERF.md section 6, PR
+48.) ``--chunks`` sweeps the kernel's chunk length. Needs a TPU:
 the kernel does not lower elsewhere, and a time from another backend says
 nothing (PERF.md section 6, PR 32 and PR 39, holds the v5e's readings). The last line of standard output is one JSON list.
 """
@@ -129,19 +137,68 @@ def latent(rng):
     )
 
 
+# MiMo-V2.5's cell: slots, table width, key and value widths, the key pool's lanes.
+MB, MW, MDK, MDV, MLANES, MWINDOW = 32, 1152, 192, 128, 256, 128
+KINDS = {  # layers of the part, its blocks, key/value heads, queries a head, window, sink
+    "full": (2, 36865, 4, 16, None, False),
+    "window": (5, 32 * 137 + 1, 8, 8, MWINDOW, True),
+}
+
+
+def per_kind(kind: str, rng):
+    """The same of one kind of MiMo-V2.5's attention layers; and the blocks a
+    slot's walk covers, for the bytes."""
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "traffic", "longdoc-backlog.json")) as f:
+        mix = json.load(f)
+    layers, blocks, kh, g, window, has_sink = KINDS[kind]
+    ks = jax.random.split(jax.random.key(0), 4)
+    pk = jax.random.normal(ks[0], (layers, blocks, kh, BLOCK, MLANES), jnp.bfloat16).at[..., MDK:].set(0)
+    pv = jax.random.normal(ks[1], (layers, blocks, kh, BLOCK, MDV), jnp.bfloat16)
+    q = jax.random.normal(ks[2], (MB, kh, g, MDK), jnp.bfloat16)
+    sink = (5.0 + 0.5 * jax.random.normal(ks[3], (kh, g)),) if has_sink else ()
+    drawn = rng.choice(mix["prompt_tokens"], MB) + (
+        rng.random(MB) * rng.choice(mix["output_tokens"], MB)
+    ).astype(int)
+    mixes = {
+        "longdoc-backlog": drawn,
+        "thirty-two of 10880": np.full(MB, 10880),
+        "free slots": np.ones(MB),
+        "block and window edges": np.array(
+            [16, 17, 32, 1, 128, 129, 127, 2048, 255, 256, 257, 15, 31, 33, 1000, 2047,
+             143, 144, 145, 1023, 1024, 1025, 4095, 4096, 3000, 3001, 2, 100, 500, 16384,
+             17000, 18432]),
+        "tables full": np.full(MB, MW * BLOCK),
+    }
+    static = {} if window is None else {"window": window}
+
+    def covered(lens):
+        first = 0 if window is None else np.maximum(lens - window, 0) // BLOCK
+        return -(-lens // BLOCK) - first
+
+    gather = lambda q, pk, pv, l, t, n: paged._attend_gathered(q, pk, pv, l, t, n, *sink, **static)  # noqa: E731
+    kernel = lambda q, pk, pv, l, t, n: paged_attention.paged_decode_attention(  # noqa: E731
+        q, pk, pv, l, t, n, *sink, **static)
+    return (
+        (q, (pk, pv), tables_of(rng, MB, MW, blocks)), mixes, gather, kernel, "_CHUNK", layers,
+        kh * BLOCK * (MLANES + MDV) * 2, covered,
+    )
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--latent", action="store_true",
                     help="the latent arm at A.X-K1's shapes")
+    ap.add_argument("--kind", choices=sorted(KINDS), help="a kind of MiMo-V2.5's attention layers")
     ap.add_argument("--chunks", default=None,
                     help="comma-separated chunk lengths to sweep (default: the arm's own)")
     args = ap.parse_args()
     if jax.default_backend() != "tpu":
         raise SystemExit("needs a TPU: the kernel's time is a device time")
     rng = np.random.default_rng(0)
-    (q, pools, tables), mixes, attend, kernel_of, chunk_name, layers, block_bytes = (
-        latent if args.latent else per_head
-    )(rng)
+    arm = functools.partial(per_kind, args.kind) if args.kind else latent if args.latent else per_head
+    (q, pools, tables), mixes, attend, kernel_of, chunk_name, layers, block_bytes, *covered = arm(rng)
+    covered = covered[0] if covered else lambda lens: -(-lens // BLOCK)  # a slot's live blocks
     chunks = args.chunks or str(getattr(paged_attention, chunk_name))
     gather = per_layer(attend, layers)
     rows = []
@@ -153,7 +210,7 @@ def main() -> int:
             operands = (q, pools, tables, jnp.asarray(lens, jnp.int32))
             diff = jnp.max(jnp.abs(kernel(*operands) - gather(*operands))) / layers
             kernel_us = us_a_layer(kernel, layers, *operands)
-            live_bytes = int((-(-lens // BLOCK)).sum()) * block_bytes
+            live_bytes = int(covered(lens).sum()) * block_bytes
             rows.append({
                 "chunk": chunk, "mix": name, "live_positions": int(lens.sum()),
                 "max_abs_diff": round(float(diff), 5),
