@@ -418,7 +418,7 @@ class LLMEngine:
         # Programs (prefills and decode steps alike) of a model with routed
         # experts, by the arm their grouped products were built with
         # (ops.moe_gmm.fits: platform and the experts' widths decide).
-        self._moe_arm = None
+        self._moe_arm = self._state_arm = None
         in_kernel = latent_moe.grouped_products_in_kernel(params, cfg, self.mesh)
         if in_kernel is not None:
             self.stats["moe_gmm_kernel_steps"] = 0  # each touched expert streamed once
@@ -439,6 +439,16 @@ class LLMEngine:
             # Prefills that began a sequence and so began from zero state,
             # whatever the slot held.
             self.stats["state_resets"] = 0
+            # Decode programs launched, by the arm their state steps were
+            # built with (paged.state_steps_in_kernel: platform and the
+            # state's tiles decide).
+            self.stats["state_kernel_steps"] = 0  # a tile held on the chip: one read, one write
+            self.stats["state_plain_steps"] = 0  # plain jax.numpy on the rows
+            self._state_arm = (
+                "state_kernel_steps"
+                if paged.state_steps_in_kernel(self.pool["state"], self.mesh)
+                else "state_plain_steps"
+            )
         if not self._cache.shares_prefixes:
             # Admissions that would have looked a prefix up and could not (a
             # hit needs the state, or the window layers' blocks, at the
@@ -1486,6 +1496,8 @@ class LLMEngine:
         self.stats[self._decode_arm] += 1
         if self._moe_arm:
             self.stats[self._moe_arm] += 1
+        if self._state_arm:
+            self.stats[self._state_arm] += 1
         self.stats["programs_launched"] += 1
         self.pool, logits, small = self._pg_decode(
             self.params, self._no_prev if behind is None else behind.small,

@@ -14,7 +14,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.common import _rms_norm
-from ray_tpu.ops.delta_rule import kda_chunked, kda_step
+from ray_tpu.ops import state_step
+from ray_tpu.ops.delta_rule import kda_chunked
 
 Params = dict
 _F32 = jnp.float32
@@ -95,11 +96,12 @@ def kda_prefill(h, p, cfg, S0, tail, length):
 
 
 def kda_decode(h, p, cfg, S, tail):
-    """One token a row: ``h`` [B, D], ``S`` [B, H, d_k, d_v], ``tail`` [B,
+    """One token a row: ``h`` [B, D], ``S`` [B, H, d_k, d_v] (or the rows
+    where they lie, a :class:`ray_tpu.ops.state_step.Rows`), ``tail`` [B,
     K-1, 3 H d]. Returns ``(out [B, D], S, tail)``."""
     dt = cfg.dtype
     x = jnp.concatenate([tail.astype(dt), (h @ p["wqkv"].astype(dt))[:, None]], axis=1)
     mixed = jnp.einsum("kc,bkc->bc", p["conv"].astype(dt), x)
     q, k, v, g, beta = _kda_inputs(h, jax.nn.silu(mixed), p, cfg)
-    o, S = kda_step(q, k, v, g, beta, S)
+    o, S = state_step.kda(q, k, v, g, beta, S)
     return _kda_output(h, o, p, cfg), S, x[:, 1:]

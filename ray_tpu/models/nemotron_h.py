@@ -44,7 +44,8 @@ import jax.numpy as jnp
 from ray_tpu.models import latent_moe, paged
 from ray_tpu.models.latent_moe import final_logits, moe_ffn, outputs
 from ray_tpu.models.common import _rms_norm
-from ray_tpu.ops.ssd import ssd_chunked, ssd_step
+from ray_tpu.ops import state_step
+from ray_tpu.ops.ssd import ssd_chunked
 
 Params = dict
 _F32 = jnp.float32
@@ -293,14 +294,15 @@ def mamba_prefill(u, p, cfg: NemotronHConfig, h0, tail, length):
 
 
 def mamba_decode(u, p, cfg: NemotronHConfig, h, tail):
-    """One token a row: ``u`` [B, D], ``h`` [B, H, P, N], ``tail`` [B, K-1,
+    """One token a row: ``u`` [B, D], ``h`` [B, H, P, N] (or the rows where
+    they lie, a :class:`ray_tpu.ops.state_step.Rows`), ``tail`` [B, K-1,
     conv_dim]. Returns ``(out [B, D], h, tail)``."""
     dt = cfg.dtype
     z, xBC, step = _mamba_inputs(u, p, cfg)
     rows = jnp.concatenate([tail.astype(dt), xBC[:, None]], axis=1)  # [B, K, conv_dim]
     mixed = jnp.einsum("kc,bkc->bc", p["conv_w"].astype(dt), rows) + p["conv_b"].astype(dt)
     x, B, C, A, D = _ssm_operands(jax.nn.silu(mixed), p, cfg)
-    y, h = ssd_step(x, step, A, B, C, D, h)
+    y, h = state_step.ssd(x, step, A, B, C, D, h)
     out = gated_norm(y.reshape(y.shape[0], -1), z, p["gate_norm"], cfg) @ p["w_out"].astype(dt)
     return out, h, rows[:, 1:]
 

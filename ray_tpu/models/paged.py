@@ -49,6 +49,8 @@ and nothing else about a family's cache.
   so one policy holds (:func:`state_prefill`, :func:`state_decode`): a
   sequence begins from zero whatever the slot held, a later chunk continues
   from the slot's, a decode step leaves a slot that is not live as it was.
+  On a TPU, at tiles that are whole, a decode step holds a slot's tile in
+  VMEM while it is stepped (``ops/state_step.py``): read once, written once.
 - *A second table kind that keeps a window*: the blocks in two parts
   (``{"full": {"k", "v"}, "window": {"k", "v"}}``), a slot a table for each
   (``tables [..., kinds, W]``; behind the window a window table points at the
@@ -78,7 +80,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops import paged_attention
+from ray_tpu.ops import paged_attention, state_step
 
 Params = dict
 
@@ -472,20 +474,49 @@ def state_prefill(step, state, conv, l, slot, fresh):
     return out, state.at[l, row].set(state1), conv.at[l, row].set(tail1.astype(conv.dtype))
 
 
-def state_decode(step, state, conv, l, rows: int, keep=None):
+def state_decode(step, state, conv, l, rows: int, keep=None, *, interpret: bool = False):
     """The decode side: slot ``b``'s state and tail are row ``b``, so rows
-    ``[:rows]`` of layer ``l`` go through ``step(state0 [rows, ...], tail0)
-    -> (out, state1, tail1)`` and back where they lie, with no gather by
-    slot. ``keep`` [rows] bool (``~live``, worked out once a program; None:
-    every slot is live): a slot that is not live, free or still prefilling
-    in chunks, keeps its state and tail as they were; its ``out`` means
-    nothing. Returns ``(out, state, conv)``."""
-    state0, tail0 = state[l, :rows], conv[l, :rows]
-    out, state1, tail1 = step(state0, tail0)
+    ``[:rows]`` of layer ``l`` go through ``step(state0, tail0) -> (out,
+    state1, tail1)`` and back where they lie, with no gather by slot. ``keep``
+    [rows] bool (``~live``, worked out once a program; None: every slot is
+    live): a slot that is not live, free or still prefilling in chunks, keeps
+    its state and tail as they were; its ``out`` means nothing.
+
+    ``state0`` is the rows' values ``[rows, ...]``, or, where the program is
+    lowered for a TPU and the state's tiles are the kernel's
+    (:func:`state_steps_in_kernel`), the rows where they lie, a
+    :class:`ray_tpu.ops.state_step.Rows`: a family's step takes either
+    through :func:`ray_tpu.ops.state_step.kda` / ``ssd``, which step an array
+    in plain ``jax.numpy`` and a ``Rows`` in VMEM, a tile read once and
+    written once, and hand back what they were given. ``interpret`` runs the
+    kernel in the Pallas interpreter whatever the platform and the shapes (the
+    tests). Returns ``(out, state, conv)``."""
+
+    def plain(state, tail0):
+        state0 = state[l, :rows]
+        out, state1, tail1 = step(state0, tail0)
+        if keep is not None:
+            state1 = jnp.where(keep[:, None, None, None], state0, state1)
+        return out, state.at[l, :rows].set(state1), tail1
+
+    def kernel(state, tail0, interpret=False):
+        out, held, tail1 = step(state_step.Rows(state, l, rows, keep, interpret), tail0)
+        return out, held.state, tail1
+
+    tail0 = conv[l, :rows]
+    fits = state_step.tiles(*state.shape[2:])
+    out, state, tail1 = _choose(kernel, plain, fits, interpret)(state, tail0)
     if keep is not None:
-        state1 = jnp.where(keep[:, None, None, None], state0, state1)
         tail1 = jnp.where(keep[:, None, None], tail0, tail1)
-    return out, state.at[l, :rows].set(state1), conv.at[l, :rows].set(tail1.astype(conv.dtype))
+    return out, state, conv.at[l, :rows].set(tail1.astype(conv.dtype))
+
+
+def state_steps_in_kernel(state, mesh=None) -> bool:
+    """Whether a decode program built in this process steps a pool's
+    ``state`` ``[L', slots + 1, H, a, b]`` through the kernel of
+    :mod:`ray_tpu.ops.state_step` (:func:`state_decode`'s choice, which
+    platform and shapes make: :func:`ray_tpu.ops.state_step.fits`)."""
+    return state_step.fits(*state.shape[2:], mesh)
 
 
 def _scan_layers(body, x, params, pool):

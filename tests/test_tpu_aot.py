@@ -615,3 +615,120 @@ def test_the_other_families_decode_programs_call_the_plain_kernel():
             functools.partial(paged.paged_decode, cfg=cfg, block_size=bs, interpret=True))(
             params, sds((B,), i32), sds((B,), i32), sds((B, W), i32), pool)
         assert _plain_decode_kernel_calls(jaxpr.jaxpr, cfg.head_dim) >= 1, cfg.family
+
+
+# Layers, rows, heads, tile and the groups of B and C (None: KDA) of the three
+# cells that keep a state a slot.
+_STATE_SHAPES = {
+    "kimi-linear": (7, 16, 32, (128, 128), None),
+    "solar-open2": (3, 32, 64, (128, 128), None),
+    "nemotron-3-super": (5, 64, 128, (64, 128), 8),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_STATE_SHAPES))
+def test_the_state_step_kernel_compiles_at_served_shapes(v5e_2x2, cell):
+    """``ops.state_step`` at the three served states (32 and 64 heads of 128 x
+    128 over 16 and 32 rows; 128 heads of 64 x 128 over 64 rows with ``B``,
+    ``C`` by group), lowered for the chip: its blocks and its transposition
+    are whole tiles and fit the VMEM it asks for, the call keeps its name (a
+    device trace lists it as ``state_step_kda.<n>`` / ``state_step_ssd.<n>``),
+    the state donated is the state returned, and nothing of a row's size is
+    made beside it."""
+    from ray_tpu.ops import state_step
+
+    layers, rows, H, (a, b), groups = _STATE_SHAPES[cell]
+    assert state_step.tiles(H, a, b)
+    one = jax.sharding.SingleDeviceSharding(v5e_2x2[0])
+    sds = lambda s, dt=jnp.float32: jax.ShapeDtypeStruct(s, dt, sharding=one)  # noqa: E731
+    state = sds((layers, rows + 1, H, a, b))
+    if groups is None:
+        step, name = state_step.kda, "state_step_kda"
+        operands = (*[sds((rows, H, a))] * 2, sds((rows, H, b)), sds((rows, H, a)), sds((rows, H)))
+    else:
+        step, name = state_step.ssd, "state_step_ssd"
+        operands = (sds((rows, H, a)), sds((rows, H)), sds((H,)), *[sds((rows, groups, b))] * 2, sds((H,)))
+
+    def run(state, keep, *operands):
+        out, held = step(*operands, state_step.Rows(state, layers - 1, rows, keep))
+        return out, held.state
+
+    compiled = jax.jit(run, donate_argnums=0).lower(state, sds((rows,), jnp.bool_), *operands).compile()
+    assert mosaic_calls(compiled.as_text()) == [name]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= math.prod(state.shape) * 4
+    assert mem.temp_size_in_bytes < 2**20
+
+
+@pytest.mark.parametrize(
+    "cell", ["serve-batch-kimilinear", "serve-longdoc-solaropen2", "serve-chat-nemotron3super"]
+)
+def test_a_state_familys_decode_program_steps_its_state_where_it_lies(v5e_2x2, cell):
+    """The decode program of each cell whose family keeps a state a slot, its
+    model built as the benchmark builds it, lowered for the chip: the state
+    kernel once a state layer, the pool donated and aliased, and no value of
+    the rows' shape ``[rows, H, a, b]`` or of the state's anywhere: no slice
+    of the rows is brought out, no copy of the state made, and the rows are
+    not set back by a dynamic-update-slice."""
+    from benchmarks import harness
+    from ray_tpu.models import paged
+
+    found = harness.cell(cell)
+    c, mix = harness.config_of(found), harness.traffic_of(found)
+    e = mix["engine"]
+    cfg = harness.family(c).model_config(c, mix)
+    bs, N, B = e["kv_block_size"], e["num_kv_blocks"], e["max_slots"]
+    W = e["max_seq"] // bs
+    one = jax.sharding.SingleDeviceSharding(v5e_2x2[0])
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)  # noqa: E731
+    on_chip = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
+    mod = paged.family(cfg)
+    params = on_chip(jax.eval_shape(lambda k: mod.init_params(k, cfg), jax.random.key(0)))
+    pool = on_chip(jax.eval_shape(lambda: paged.init_block_pool(cfg, N, bs, B)))
+    state = pool["state"]
+    assert state.shape[1] == B + 1 and paged.cache(cfg).slot_state
+    i32 = jnp.int32
+    compiled = jax.jit(
+        functools.partial(paged.paged_decode, cfg=cfg, block_size=bs), donate_argnums=4
+    ).lower(
+        params, sds((B,), i32), sds((B,), i32), sds((B, W), i32), pool, live=sds((B,), jnp.bool_)
+    ).compile()
+    text = compiled.as_text()
+    calls = mosaic_calls(text)
+    steps = [name for name in calls if name.startswith("state_step_")]
+    assert len(steps) == state.shape[0] and len(set(steps)) == 1
+    shape = lambda dims: "f32[" + ",".join(map(str, dims)) + "]"  # noqa: E731
+    # "%name = type[shape]{layout} op(": an instruction's name and result.
+    results = [ln.split("(", 1)[0] for ln in text.splitlines() if " = " in ln]
+    whole = [r for r in results if shape(state.shape) in r]
+    assert whole  # the pattern can match: the kernel's own result is the state
+    assert not [r for r in whole if any(op in r for op in (" copy", "fusion", "dynamic-update-slice"))]
+    assert not [r for r in results if shape((B, *state.shape[2:])) in r]
+    mem = compiled.memory_analysis()
+    nbytes = lambda t: sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(t))  # noqa: E731
+    assert mem.alias_size_in_bytes >= nbytes(pool)
+    assert mem.temp_size_in_bytes < math.prod(state.shape) * 4  # (Kimi Linear's holds two copies of its latent pool)
+
+
+@pytest.mark.parametrize("family", ["llama", "gpt2", "mla_moe", "afmoe", "mimo_v2"])
+def test_a_family_with_no_state_a_slot_never_meets_the_state_step(family):
+    """The five families that keep no state a slot: their record says so,
+    their pool has no such part, and their decode program, traced whole at a
+    tiny size, calls no kernel but the attention's and chooses by platform
+    nowhere but there."""
+    from ray_tpu.models import paged
+
+    mod = paged.family(type("Named", (), {"family": family})())
+    config = next(v for k, v in vars(mod).items() if k.endswith("Config") and hasattr(v, "tiny"))
+    cfg = config.tiny(max_seq=64)
+    assert not paged.cache(cfg).slot_state
+    B, bs, W, N = 2, 4, 16, 33
+    sds, i32 = jax.ShapeDtypeStruct, jnp.int32
+    params = jax.eval_shape(lambda k: mod.init_params(k, cfg), jax.random.key(0))
+    pool = jax.eval_shape(lambda: paged.init_block_pool(cfg, N, bs, B))
+    assert "state" not in pool and "conv" not in pool
+    jaxpr = jax.make_jaxpr(functools.partial(paged.paged_decode, cfg=cfg, block_size=bs))(
+        params, sds((B,), i32), sds((B,), i32), sds((B, W), i32), pool)
+    names = [eqn.params["name"] for eqn in _pallas_calls(jaxpr.jaxpr)]
+    assert not [n for n in names if not n.startswith("paged_")]
+    assert "state_step" not in str(jaxpr)
