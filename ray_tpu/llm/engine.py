@@ -449,6 +449,12 @@ class LLMEngine:
                 if paged.state_steps_in_kernel(self.pool["state"], self.mesh)
                 else "state_plain_steps"
             )
+        if self._cache.prefill_in_place:
+            # Prefill programs launched, by the arm their attention was built
+            # with (paged.prefill_attends_in_kernel: platform, the kinds'
+            # shapes and the program's bucket decide).
+            self.stats["prefill_attn_kernel_chunks"] = 0  # one kernel call a layer over the table
+            self.stats["prefill_attn_fold_chunks"] = 0  # runs of einsums, a stretch at a time
         if not self._cache.shares_prefixes:
             # Admissions that would have looked a prefix up and could not (a
             # hit needs the state, or the window layers' blocks, at the
@@ -898,6 +904,11 @@ class LLMEngine:
         if self._moe_arm:
             self.stats[self._moe_arm] += 1
         bucket = toks.shape[1]
+        if self._cache.prefill_in_place:
+            in_kernel = paged.prefill_attends_in_kernel(
+                self.model_config, self._block_size, bucket, mesh=self.mesh
+            )
+            self.stats[f"prefill_attn_{'kernel' if in_kernel else 'fold'}_chunks"] += 1
         wave = self._in_wave()
         if not wave["prefills"] and self._inflight is not None:
             # The wave's first launch queues behind what is left of the

@@ -61,7 +61,7 @@ PUBLISHED_LAYER_TYPES = (SLIDING, SLIDING, SLIDING, FULL) * 15
 def cache(cfg) -> paged.Cache:
     """Keys and values per head in blocks under a table a layer kind: a full
     layer keeps every position, a sliding layer the window."""
-    return paged.Cache(retention=(None, cfg.sliding_window))
+    return paged.Cache(retention=(None, cfg.sliding_window), prefill_in_place=True)
 
 
 @dataclasses.dataclass(frozen=True)

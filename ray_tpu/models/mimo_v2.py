@@ -182,7 +182,9 @@ def cache(cfg: MimoV2Config) -> paged.Cache:
     """Keys and values per head in blocks under a table a layer kind: a full
     layer keeps every position, a window layer the window; each kind with its
     own shapes."""
-    return paged.Cache(retention=(None, cfg.sliding_window), kinds=attention_kinds(cfg))
+    return paged.Cache(
+        retention=(None, cfg.sliding_window), kinds=attention_kinds(cfg), prefill_in_place=True
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -306,15 +308,20 @@ def _rotate(t, rope):
     return jnp.concatenate([t1 * cos - t2 * sin, t1 * sin + t2 * cos, rest], axis=-1).astype(t.dtype)
 
 
-def _qkv(a, p, cfg: MimoV2Config, kind: paged.AttentionKind, rope):
+def _qkv(a, p, cfg: MimoV2Config, kind: paged.AttentionKind, rope, *, held: bool = False):
     """``a`` [..., D] normed -> ``(q [..., KH, group, Dk], k [..., KH,
     key_lanes], v [..., KH, Dv])`` of a layer of ``kind``: ``q`` and ``k``
     rotated in their first lanes, ``k`` with zeros up to the pool's row, ``v``
-    scaled."""
+    scaled. ``held`` (a chunk's rows): the query projection is one value
+    behind an optimization barrier. Without it a v5e's compiler forms a
+    window layer's ``[2048, 4096] x [4096, 12288]`` product twice, once for
+    the lanes the rope rotates and once for the others (206 GFLOP each time,
+    five layers of the benchmark's seven: PERF.md section 6, PR 50)."""
     dt = cfg.dtype
     H, KH, Dk, Dv = cfg.n_head, kind.kv_heads, cfg.head_dim, cfg.v_head_dim
     lead = a.shape[:-1]
-    q = _rotate((a @ p["wq"].astype(dt)).reshape(*lead, H, Dk), rope)
+    q = a @ p["wq"].astype(dt)
+    q = _rotate((jax.lax.optimization_barrier(q) if held else q).reshape(*lead, H, Dk), rope)
     k = _rotate((a @ p["wk"].astype(dt)).reshape(*lead, KH, Dk), rope)
     v = (a @ p["wv"].astype(dt)).reshape(*lead, KH, Dv)
     v = (v.astype(_F32) * cfg.value_scale).astype(dt)
@@ -412,14 +419,14 @@ def paged_prefill(
     seen: list = []
     for layer, p, kind, l in _layers(params, cfg):
         a = _rms_norm(x, p["in_norm"], cfg.rms_eps)
-        q, k, v = _qkv(a, p, cfg, kinds[kind], ropes[kind])
+        q, k, v = _qkv(a, p, cfg, kinds[kind], ropes[kind], held=True)
         tab, kv = tables[kind], pool[PARTS[kind]]
         bids, offs = tab[pos // block_size], pos % block_size
         kv["k"] = paged._write(kv["k"], l, bids, offs, k)
         kv["v"] = paged._write(kv["v"], l, bids, offs, v)
         o = paged.prefill_attention(
             q, kv["k"], kv["v"], l, tab, pos, start + length, block_size=block_size,
-            window=kinds[kind].window, sink=_sink(p, kinds[kind]),
+            window=kinds[kind].window, sink=_sink(p, kinds[kind]), name=kinds[kind].name,
         )
         x = ffn(_out(x, o, p, cfg), p, cfg, layer, valid, seen)
     last = jax.lax.dynamic_index_in_dim(x, (length - 1).astype(jnp.int32), 0, keepdims=False)

@@ -31,13 +31,15 @@ record's business (:class:`Cache`, :func:`cache`), and the engine reads that
 and nothing else about a family's cache.
 
 - *Blocks of rows per head*, ``{"k", "v": [L, N, KH, block, Dh]}``: written
-  with :func:`_write`, read by prefill as a gathered table or a stretch of the
-  table at a time (:func:`prefill_attention`), by decode through
-  :func:`decode_attention`. What those two need of an attention layer is its
-  *kind* (:class:`AttentionKind`: KV heads, the key's and the value's width,
-  a window, a learned sink), which a family with one shape of head takes
-  from its configuration (:func:`attention_kind`) and one whose kinds of
-  layer differ in shape states on its record (``Cache.kinds``).
+  with :func:`_write`, read by prefill as a gathered table or where they lie
+  (:func:`prefill_attention`: on a TPU one kernel a layer that walks the
+  table, ``ops/paged_prefill_attention.py``; elsewhere a stretch of the
+  table at a time), by decode through :func:`decode_attention`. What those
+  two need of an attention layer is its *kind* (:class:`AttentionKind`: KV
+  heads, the key's and the value's width, a window, a learned sink), which
+  a family with one shape of head takes from its configuration
+  (:func:`attention_kind`) and one whose kinds of layer differ in shape
+  states on its record (``Cache.kinds``).
 - *Blocks of latent rows*, ``"ckv": [L, N, block, pool_row_dim]``: one row a
   position for all heads, under the same tables and ``BlockManager``. A cache
   like keys and values (stale rows masked by position, prefixes shared,
@@ -80,7 +82,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops import paged_attention, state_step
+from ray_tpu.ops import paged_attention, paged_prefill_attention, state_step
 
 Params = dict
 
@@ -135,6 +137,9 @@ class Cache:
     # where the family's kinds differ in shape (empty: one shape of head,
     # which the configuration gives: :func:`attention_kind`).
     kinds: tuple = ()
+    # Prefill attends those layers through :func:`prefill_attention`, the
+    # pool read where it lies (a family without gathers its table whole).
+    prefill_in_place: bool = False
 
     @property
     def shares_prefixes(self) -> bool:
@@ -367,7 +372,7 @@ def decode_attention(kind: AttentionKind, block_size, mesh, interpret):
     return _choose(kernel, gather, _kernel_fits(kind, block_size, mesh), interpret)
 
 
-# Positions of the table that one step of prefill's running softmax scores,
+# Positions of the table that one step of the fold's running softmax scores,
 # and queries that go through it together: [KH, group, 512, 512] float32 is
 # 50 MB at 48 heads, where a 2,048-token chunk against a table of 18,432 is
 # 7.2 GB a layer.
@@ -376,24 +381,86 @@ KEY_POSITIONS = 512
 
 def prefill_attention(
     q, pk, pv, l, table, pos, n_keys, *, block_size: int, window=None, sink=None,
+    key_positions: int = KEY_POSITIONS, name: str = "", interpret: bool = False,
+):
+    """Prefill's attention over keys and values per head, the pool read where
+    it lies: ``q`` [T, KH, group, Dk] at consecutive positions ``pos`` [T]
+    against layer ``l`` of ``pk`` [L, N, KH, block, Dk or wider] and ``pv``
+    [L, N, KH, block, Dv], which already hold the queries' own keys and
+    values, read through ``table`` [W]; ``n_keys`` (traced) the positions
+    that hold a row by now. The mask is ``column <= position`` and, with
+    ``window``, ``position - column < window``; ``sink`` [KH, group] is the
+    layer's learned sink, with which a row's running softmax starts from
+    ``(sink, 1, 0)`` and not from ``(-1e30, 0, 0)``. No ``[heads, T, table]``
+    scores exist, and a window layer reads nothing behind its window, where
+    the table points at the scratch block. Returns [T, KH, group, Dv] in the
+    pool's dtype (a row at or past ``n_keys``, the padding behind a last
+    chunk: finite numbers that mean nothing).
+
+    The choice between two arms, as :func:`decode_attention` is (``name``:
+    the kind's, behind the kernel's own in a device trace; ``interpret``: the
+    kernel in the Pallas interpreter, the tests): one kernel call over a grid
+    of (KV head, tile of queries) that walks the table
+    (:func:`ops.paged_prefill_attention.paged_prefill_attention`) where the
+    program is lowered for a TPU and the kernel takes the kind's shapes
+    (:func:`ops.paged_prefill_attention.fits`: a chunk's length in whole
+    tiles, and no window longer than a tile of queries), and
+    :func:`_prefill_fold`'s runs of XLA
+    einsums elsewhere. The families that call this are served on one chip:
+    there is no mesh to ask about."""
+    fold = functools.partial(
+        _prefill_fold, block_size=block_size, window=window, sink=sink, key_positions=key_positions
+    )
+
+    def kernel(q, pk, pv, l, table, pos, n_keys, interpret=False):
+        return paged_prefill_attention.paged_prefill_attention(
+            q, pk, pv, l, table, pos[0], n_keys, sink, window=window, interpret=interpret,
+            name=f"paged_prefill_attention_{name}" if name else "paged_prefill_attention",
+        )
+
+    fits = paged_prefill_attention.fits(
+        q.shape[0], q.shape[2], pk.shape[-1], pv.shape[-1], block_size, window
+    )
+    l, n_keys = jnp.asarray(l, jnp.int32), jnp.asarray(n_keys, jnp.int32)
+    return _choose(kernel, fold, fits, interpret)(q, pk, pv, l, table, pos, n_keys)
+
+
+def prefill_attends_in_kernel(cfg, block_size: int, tokens: int, *, mesh=None) -> bool:
+    """Whether the family's prefill program of ``tokens`` positions, lowered
+    for this process's default backend, attends through the kernel
+    (:func:`prefill_attention`'s choice, a kind of layer at a time by its
+    shapes: :func:`ops.paged_prefill_attention.fits`) in any of its kinds of
+    layer. (Trinity's full layers do, and its layers with a window of two
+    chunks keep the fold.) False for a family whose prefill does not call
+    :func:`prefill_attention` at all (``Cache.prefill_in_place``), and
+    under a mesh over chips, which no such family is served on."""
+    record = cache(cfg)
+    if jax.default_backend() != "tpu" or not record.prefill_in_place:
+        return False
+    if mesh is not None and mesh.size > 1:
+        return False
+    kinds = record.kinds or (
+        attention_kind(cfg), *(attention_kind(cfg, w) for w in record.retention if w is not None)
+    )
+    return any(
+        paged_prefill_attention.fits(
+            tokens, cfg.n_head // kind.kv_heads, kind.key_lanes or kind.key_width, kind.value_width,
+            block_size, kind.window,
+        )
+        for kind in kinds
+    )
+
+
+def _prefill_fold(
+    q, pk, pv, l, table, pos, n_keys, *, block_size: int, window=None, sink=None,
     key_positions: int = KEY_POSITIONS,
 ):
-    """Prefill's attention over keys and values per head, a stretch of the
-    table at a time: ``q`` [T, KH, group, Dk] at consecutive positions ``pos``
-    [T] against layer ``l`` of ``pk`` [L, N, KH, block, Dk or wider] and
-    ``pv`` [L, N, KH, block, Dv], which already hold the queries' own keys
-    and values, read through ``table`` [W]; ``n_keys`` (traced) the positions
-    that hold a row by now. The mask is ``column <= position`` and, with
-    ``window``, ``position - column < window``. Each run of ``key_positions``
-    queries folds the stretches from the one that holds the first column its
-    first query sees (column 0 without a window) to the one that holds its
-    last query's own position into a running softmax (float32 maximum, sum
-    and values), as :func:`ray_tpu.models.latent_moe.mla_prefill` does for
-    latent rows: no ``[heads, T, table]`` scores exist, and a window layer
-    reads nothing behind its window, where the table points at the scratch
-    block. ``sink`` [KH, group]: the layer's learned sink, with which the
-    running softmax starts from ``(sink, 1, 0)`` and not from ``(-1e30, 0,
-    0)``. Returns [T, KH, group, Dv] in the pool's dtype."""
+    """:func:`prefill_attention` in XLA's own operations, a stretch of the
+    table at a time. Each run of ``key_positions`` queries folds the
+    stretches from the one that holds the first column its first query sees
+    (column 0 without a window) to the one that holds its last query's own
+    position into a running softmax (float32 maximum, sum and values), as
+    :func:`ray_tpu.models.latent_moe.mla_prefill` does for latent rows."""
     T, KH, G, Dh = q.shape
     Dk, Dv = pk.shape[-1], pv.shape[-1]
     dt = pk.dtype

@@ -265,7 +265,7 @@ def span_fields(cfg: SolarOpen2Config, counts, tokens: int, slots: int, decode=N
 def cache(cfg: SolarOpen2Config) -> paged.Cache:
     """Keys and values per head in blocks (the GQA layers'), a delta-rule
     state and a tail per slot (the KDA layers')."""
-    return paged.Cache(slot_state=True)
+    return paged.Cache(slot_state=True, prefill_in_place=True)
 
 
 def init_pool(cfg: SolarOpen2Config, num_blocks: int, block_size: int, slots=None):

@@ -195,7 +195,8 @@ def test_the_pool_has_a_part_a_kind_and_the_window_part_is_counted_not_set():
     assert pool["full"]["k"].shape == (1, 33, 2, BLOCK, 16)
     # ceil((8 + 8) / 4) + 1 = 5 blocks a slot, and the scratch block
     assert pool["window"]["k"].shape == (3, 3 * 5 + 1, 2, BLOCK, 16)
-    assert afmoe.cache(cfg) == paged.Cache(retention=(None, 8), per_head=True, hooks=False)
+    assert afmoe.cache(cfg) == paged.Cache(
+        retention=(None, 8), per_head=True, hooks=False, prefill_in_place=True)
 
 
 def _loads(cfg, params, texts):

@@ -330,7 +330,8 @@ def test_the_pool_has_a_part_a_kind_each_with_its_kinds_heads_and_the_record_pri
     assert pool["window"]["v"].shape == (3, 3 * 5 + 1, 2, BLOCK, 16)
     # the record states both kinds; (24 + 16) float32 a head: two full layers of one head, three window layers of two
     full, window = mimo_v2.attention_kinds(cfg)
-    assert mimo_v2.cache(cfg) == paged.Cache(retention=(None, 6), kinds=(full, window))
+    assert mimo_v2.cache(cfg) == paged.Cache(
+        retention=(None, 6), kinds=(full, window), prefill_in_place=True)
     assert (full.layers, window.layers) == (2, 3) and (full.row_bytes, window.row_bytes) == (2 * 160, 3 * 2 * 160)
     assert (full.kv_heads, full.window, full.sink, full.name) == (1, None, False, "full")
     assert (window.kv_heads, window.window, window.sink, window.name) == (2, 6, True, "window")

@@ -363,7 +363,7 @@ def test_solar_open2s_served_programs_compile_for_one_chip(v5e_2x2, program):
     the published widths, 40 of 320 experts held, 32 slots of 18,432
     positions), lowered for the chip: the 2,048-token chunk that continues a
     prompt (three chunked delta-rule scans at 64 heads, the GQA layer's
-    attention a stretch of the table at a time) and the decode step (the
+    attention one call of the prefill kernel over the table) and the decode step (the
     attention kernel over the live blocks, once: one GQA layer; the state read
     and written by slot), the pool donated and aliased, and weights, cache
     and temporaries within the chip's 16 GB. (The experts' grouped products
@@ -395,6 +395,10 @@ def test_solar_open2s_served_programs_compile_for_one_chip(v5e_2x2, program):
         text = compiled.as_text()
         calls = mosaic_calls(text)
         assert "paged_decode_attention" not in calls
+        # The GQA layer's attention is the prefill kernel, once, and no
+        # stretch's scores are a value of the program.
+        assert calls.count("paged_prefill_attention") == cfg.layers_of(so.GQA) == 1
+        assert f"f32[{cfg.n_kv_head},{cfg.n_head // cfg.n_kv_head},512,512]" not in text
         # The scans' pair terms go by sub-blocks (ops/delta_rule.py): the
         # compiler re-forms no decay for every pair of a chunk's positions,
         # for one chunk or with the call's 32 in front.
@@ -469,8 +473,8 @@ def test_mimo_v25s_served_programs_compile_for_one_chip(v5e_2x2, program):
     """``mimo_v2``'s two programs at the benchmark's shapes (the dense layer
     and one period of the published widths, 16 of 256 experts held, 32 slots of
     18,432 positions), lowered for the chip: the 2,048-token chunk that
-    continues a prompt (attention a stretch of the table at a time, the window
-    layers from their sink) and the decode step, whose attention is the kernel
+    continues a prompt (attention one call of the prefill kernel a layer, the
+    window layers from their sink) and the decode step, whose attention is the kernel
     in every layer, under a name a kind: twice over the full part's 4 heads,
     five times over the window part's 8, and no table gathered whole. The pool
     is donated and aliased; weights, cache and temporaries are within the
@@ -496,7 +500,18 @@ def test_mimo_v25s_served_programs_compile_for_one_chip(v5e_2x2, program):
         ).lower(
             params, sds((1, 2048), i32), sds((), i32), sds((), i32), sds((2, W), i32), pool,
         ).compile()
-        assert not [c for c in mosaic_calls(compiled.as_text()) if c.startswith("paged_decode_attention")]
+        text = compiled.as_text()
+        calls = mosaic_calls(text)
+        assert not [c for c in calls if c.startswith("paged_decode_attention")]
+        # A layer's attention is one call of the prefill kernel under its
+        # kind's name: no stretch's scores are a value of the program ...
+        assert calls.count("paged_prefill_attention_full") == 2
+        assert calls.count("paged_prefill_attention_window") == 5
+        assert "f32[4,16,512,512]" not in text and "f32[8,8,512,512]" not in text
+        # ... and each layer's query projection is formed once (the parent's
+        # compiler formed six of the seven twice: PERF.md section 6, PR 50).
+        products = re.findall(r"%([\w.\-]+) = bf16\[2048,12288\]\S* fusion\(", text)
+        assert len(products) == 7 and not [name for name in products if "remat" in name]
     else:
         compiled = jax.jit(
             functools.partial(paged.paged_decode, cfg=cfg, block_size=bs), donate_argnums=4
@@ -515,6 +530,82 @@ def test_mimo_v25s_served_programs_compile_for_one_chip(v5e_2x2, program):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
     print(program, {k: getattr(mem, k + "_size_in_bytes") for k in ("temp", "argument", "output", "alias")},
           nbytes(params), nbytes(pool))
+
+
+def test_trinitys_chunk_program_attends_through_the_prefill_kernel(v5e_2x2):
+    """``afmoe``'s 2,048-token chunk at the benchmark's shapes (the last
+    dense layer and one period of the published widths, 32 of 256 experts
+    held, 24 slots of 18,432 positions), lowered for the chip: the full
+    layer's attention is one call of the prefill kernel, 6 queries a
+    key/value head; the four layers with a window of 4,096, two chunks long,
+    keep the fold by the kernel's rule over a kind's shapes
+    (``ops.paged_prefill_attention.fits``), their stretches' scores values of
+    the program as they were."""
+    from ray_tpu.models import afmoe, paged
+
+    S, F = afmoe.SLIDING, afmoe.FULL
+    cfg = afmoe.AfmoeConfig(
+        vocab_size=25024, layer_types=(S, S, F, S, S), n_dense=1, experts_held=32, max_seq=18432,
+        window_slots=24,
+    )
+    bs, N = 16, 24577
+    W = cfg.max_seq // bs
+    one = jax.sharding.SingleDeviceSharding(v5e_2x2[0])
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)  # noqa: E731
+    on_chip = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
+    params = on_chip(jax.eval_shape(lambda k: afmoe.draw_params(k, cfg), jax.random.key(0)))
+    pool = on_chip(jax.eval_shape(lambda: afmoe.init_pool(cfg, N, bs)))
+    i32 = jnp.int32
+    compiled = jax.jit(
+        functools.partial(paged.paged_prefill, cfg=cfg, block_size=bs), donate_argnums=5
+    ).lower(
+        params, sds((1, 2048), i32), sds((), i32), sds((), i32), sds((2, W), i32), pool,
+    ).compile()
+    text = compiled.as_text()
+    assert mosaic_calls(text).count("paged_prefill_attention") == cfg.layers_of(F) == 1
+    assert "f32[8,6,512,512]" in text and cfg.layers_of(S) == 4
+    mem = compiled.memory_analysis()
+    nbytes = lambda t: sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(t))  # noqa: E731
+    assert mem.alias_size_in_bytes >= nbytes(pool)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
+
+
+# The kinds of attention layer of the three cells that prefill in chunks:
+# layers of the part, its blocks, key/value heads, queries a head, a key's
+# width and its row's, window, sink, table.
+_PREFILL_KINDS = {
+    "mimo-v2.5 full": (2, 36865, 4, 16, 192, 256, None, False, 1152),
+    "mimo-v2.5 window": (5, 32 * 137 + 1, 8, 8, 192, 256, 128, True, 1152),
+    "solar-open2": (1, 36865, 8, 8, 128, 128, None, False, 1152),
+    "trinity full": (1, 24577, 8, 6, 128, 128, None, False, 1152),
+    "trinity window": (4, 24 * 385 + 1, 8, 6, 128, 128, 4096, False, 1152),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(_PREFILL_KINDS))
+def test_the_prefill_attention_kernel_compiles_at_the_chunked_cells_shapes(v5e_2x2, kind):
+    """The kernel alone, a chunk of 2,048 queries against each kind's part
+    of the pool: one Mosaic call under the name it was given, and nothing
+    beside it but the queries scaled and re-laid (a KV head's query heads as
+    rows) and, for keys of 192, padded to their rows' 256 lanes: 64 MB each
+    at 64 heads."""
+    from ray_tpu.ops import paged_prefill_attention as ppa
+
+    layers, blocks, KH, G, Dk, lanes, window, sink, W = _PREFILL_KINDS[kind]
+    # (Trinity's window kind keeps the fold in its program, by the rule over a window; the kernel takes its shapes all the same.)
+    assert ppa.fits(2048, G, lanes, 128, 16, window) == (kind != "trinity window")
+    one = jax.sharding.SingleDeviceSharding(v5e_2x2[0])
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)  # noqa: E731
+    name = "paged_prefill_attention_" + kind.split()[-1]
+    call = jax.jit(functools.partial(ppa.paged_prefill_attention, window=window, name=name))
+    args = [
+        sds((2048, KH, G, Dk), jnp.bfloat16), sds((layers, blocks, KH, 16, lanes), jnp.bfloat16),
+        sds((layers, blocks, KH, 16, 128), jnp.bfloat16), sds((), jnp.int32), sds((W,), jnp.int32),
+        sds((), jnp.int32), sds((), jnp.int32),
+    ] + [sds((KH, G), jnp.float32)] * sink
+    compiled = call.lower(*args).compile()
+    assert mosaic_calls(compiled.as_text()) == [name]
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2 * 2048 * KH * G * lanes * 2 + 2**20
 
 
 def _pallas_calls(jaxpr):

@@ -1,6 +1,7 @@
 """The decode attention kernel alone, on the chip, against the gather.
 
     python tools/paged_attention_chip.py [--latent | --kind full|window] [--chunks 128,256,512]
+    python tools/paged_attention_chip.py --prefill [--tile-rows 2048,4096] [--score-elements 1048576]
 
 Sixteen slots over a pool of Mistral-7B's shapes (16 layers, 2,049 blocks of
 16, 8 KV heads of 128, tables of 2,048 positions): for each mix of live
@@ -22,7 +23,15 @@ key/value heads of 8 queries (padded to 16) with a window of 128 and a sink
 over a five-layer part, where the bytes are those of the blocks the walk
 covers. (A key buffer of 192 lanes cannot be timed beside the rows of 256:
 Mosaic refuses a copy that is not whole lane tiles, PERF.md section 6, PR
-48.) ``--chunks`` sweeps the kernel's chunk length. Needs a TPU:
+48.) ``--chunks`` sweeps the kernel's chunk length. ``--prefill`` times the
+prefill kernel (``ops.paged_prefill_attention``) against the fold it stands in
+for (``paged._prefill_fold``) at the shapes of the three cells that prefill
+in chunks: a chunk of 2,048 at starts 0, 4k, 8k and 14k under each kind of
+layer of MiMo-V2.5, Solar-Open2 and Trinity, microseconds a layer, the
+largest difference of the outputs, and the share of the array's peak (197
+TFLOP/s) by the pairs the mathematics needs at the widths it needs (a key of
+192, not the 256 lanes of its row); ``--tile-rows`` and ``--score-elements`` sweep
+the rows a tile of queries may hold and the scores of a (tile, stretch). Needs a TPU:
 the kernel does not lower elsewhere, and a time from another backend says
 nothing (PERF.md section 6, PR 32 and PR 39, holds the v5e's readings). The last line of standard output is one JSON list.
 """
@@ -43,7 +52,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from ray_tpu.models import paged  # noqa: E402
-from ray_tpu.ops import paged_attention  # noqa: E402
+from ray_tpu.ops import paged_attention, paged_prefill_attention  # noqa: E402
 
 B, KH, G, DH, BLOCK, W, L, N = 16, 8, 4, 128, 16, 128, 16, 2049
 # The latent arm's: slots, heads, row and value widths, table, layers, blocks.
@@ -185,8 +194,91 @@ def per_kind(kind: str, rng):
     )
 
 
+# The chunked cells' kinds of layer: layers of the part, key/value heads,
+# queries a head, a key's width and its row's, a value's, window, sink, table.
+CHUNK, PEAK_FLOPS_A_US = 2048, 197e6
+PREFILL_KINDS = {
+    "mimov25 full": (2, 4, 16, MDK, MLANES, MDV, None, False, MW),
+    "mimov25 window": (5, 8, 8, MDK, MLANES, MDV, MWINDOW, True, MW),
+    "solaropen2": (1, 8, 8, 128, 128, 128, None, False, 1152),
+    "trinity full": (1, 8, 6, 128, 128, 128, None, False, 1024),
+    "trinity window": (4, 8, 6, 128, 128, 128, 4096, False, 1024),
+}
+STARTS = (0, 4096, 8192, 14336)
+
+
+def prefill(args, rng) -> list:
+    """A chunk's attention a layer, the kernel against the fold, by kind of
+    layer and by where in its document the chunk starts."""
+    ppa = paged_prefill_attention
+    rows, fold_us = [], {}  # the fold is timed once: no variant of the kernel's changes it
+    for tile_rows in map(int, args.tile_rows.split(",")):
+        for elements in map(int, args.score_elements.split(",")):
+            ppa._TILE_ROWS, ppa._SCORE_ELEMENTS = tile_rows, elements
+            jax.clear_caches()
+            for name, (layers, kh, g, dk, lanes, dv, window, has_sink, width) in PREFILL_KINDS.items():
+                blocks = width + 1
+                ks = jax.random.split(jax.random.key(0), 4)
+                pk = jax.random.normal(ks[0], (layers, blocks, kh, BLOCK, lanes), jnp.bfloat16)
+                pk = pk.at[..., dk:].set(0)
+                pv = jax.random.normal(ks[1], (layers, blocks, kh, BLOCK, dv), jnp.bfloat16)
+                q = jax.random.normal(ks[2], (CHUNK, kh, g, dk), jnp.bfloat16)
+                sink = 5.0 + 0.5 * jax.random.normal(ks[3], (kh, g)) if has_sink else None
+                table = jnp.asarray(rng.permutation(np.arange(1, blocks))[:width], jnp.int32)
+
+                def kernel(q, pk, pv, l, table, start):
+                    return ppa.paged_prefill_attention(
+                        q, pk, pv, l, table, start, start + CHUNK, sink, window=window)
+
+                def fold(q, pk, pv, l, table, start):
+                    return paged._prefill_fold(
+                        q, pk, pv, l, table, start + jnp.arange(CHUNK, dtype=jnp.int32),
+                        start + CHUNK, block_size=BLOCK, window=window, sink=sink)
+
+                runs = {}
+                for arm, attend in (("kernel", kernel), ("fold", fold)):
+                    @jax.jit
+                    def run(q, pk, pv, table, start, attend=attend):
+                        def body(acc, layer):
+                            return acc + attend(q, pk, pv, layer, table, start).astype(jnp.float32), None
+                        return jax.lax.scan(
+                            body, jnp.zeros((CHUNK, kh, g, dv), jnp.float32),
+                            jnp.arange(layers, dtype=jnp.int32))[0]
+                    runs[arm] = run
+                for start in STARTS:
+                    if start + CHUNK > width * BLOCK:
+                        continue
+                    operands = (q, pk, pv, table, jnp.int32(start))
+                    diff = jnp.max(jnp.abs(runs["kernel"](*operands) - runs["fold"](*operands))) / layers
+                    at = start + np.arange(CHUNK) + 1  # the keys a query sees
+                    pairs = int((at if window is None else np.minimum(at, window)).sum())
+                    flops = 2 * pairs * kh * g * (dk + dv)
+                    if (name, start) not in fold_us:
+                        fold_us[name, start] = us_a_layer(runs["fold"], layers, *operands, iters=10)
+                    us = {"kernel": us_a_layer(runs["kernel"], layers, *operands, iters=10),
+                          "fold": fold_us[name, start]}
+                    rows.append({
+                        "tile_rows": tile_rows, "score_elements": elements,
+                        "tile": (tile := ppa.tile(CHUNK, g, window)),
+                        "stretch": ppa.stretch(tile, g, BLOCK, window), "kind": name, "start": start,
+                        "max_abs_diff": round(float(diff), 5),
+                        "kernel_us_a_layer": round(us["kernel"], 1),
+                        "fold_us_a_layer": round(us["fold"], 1),
+                        "kernel_pct_of_peak": round(100 * flops / PEAK_FLOPS_A_US / us["kernel"], 1),
+                        "fold_pct_of_peak": round(100 * flops / PEAK_FLOPS_A_US / us["fold"], 1),
+                    })
+                    print(json.dumps(rows[-1]), file=sys.stderr, flush=True)
+    return rows
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--prefill", action="store_true",
+                    help="the prefill kernel against the fold at the three chunked cells' shapes")
+    ap.add_argument("--tile-rows", default=str(paged_prefill_attention._TILE_ROWS),
+                    help="with --prefill: comma-separated bounds on a tile's rows to sweep")
+    ap.add_argument("--score-elements", default=str(paged_prefill_attention._SCORE_ELEMENTS),
+                    help="with --prefill: comma-separated sizes of a (tile, stretch)'s scores to sweep")
     ap.add_argument("--latent", action="store_true",
                     help="the latent arm at A.X-K1's shapes")
     ap.add_argument("--kind", choices=sorted(KINDS), help="a kind of MiMo-V2.5's attention layers")
@@ -196,6 +288,9 @@ def main() -> int:
     if jax.default_backend() != "tpu":
         raise SystemExit("needs a TPU: the kernel's time is a device time")
     rng = np.random.default_rng(0)
+    if args.prefill:
+        print(json.dumps(prefill(args, rng)))
+        return 0
     arm = functools.partial(per_kind, args.kind) if args.kind else latent if args.latent else per_head
     (q, pools, tables), mixes, attend, kernel_of, chunk_name, layers, block_bytes, *covered = arm(rng)
     covered = covered[0] if covered else lambda lens: -(-lens // BLOCK)  # a slot's live blocks
