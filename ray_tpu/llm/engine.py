@@ -433,8 +433,11 @@ class LLMEngine:
         # the family's record states its kinds: a pool that pads shows as laid
         # bytes over these.
         blocks = (n, *([self._window.mgr.num_blocks] if self._window else []))
-        for i, (kind, count) in enumerate(zip(self._cache.kinds, blocks)):
-            self.stats[f"cache_bytes_needed_kind{i}"] = kind.row_bytes * bs * count
+        needed = [kind.row_bytes * bs * count for kind, count in zip(self._cache.kinds, blocks)]
+        for i, nbytes in enumerate(needed):
+            self.stats[f"cache_bytes_needed_kind{i}"] = nbytes
+        if needed:  # ... and of all kinds together, whatever else the pool holds beside them
+            self.stats["cache_bytes_needed_kv"] = sum(needed)
         if self._cache.slot_state:
             # Prefills that began a sequence and so began from zero state,
             # whatever the slot held.
@@ -876,7 +879,9 @@ class LLMEngine:
         family's program packed behind them (__init__) is its counters: they
         go onto the prefill span still open on ``req``."""
         V = self.model_config.vocab_size
-        if out.size > V and req.pf_open is not None:
+        # (A family of kv_hooks has no fields of its own; one without
+        # counters still says what its run stepped.)
+        if not self._cache.hooks and req.pf_open is not None:
             phase, t_pf, extra = req.pf_open
             extra = {
                 **extra,
@@ -1569,7 +1574,7 @@ class LLMEngine:
         else:
             logits_np = np.asarray(cur.logits)  # raylint: disable=RL101 -- the synchronous arm's ONE intended sync: batched logits readback feeding host-side sampling
             copied = logits_np.nbytes
-            if fr and cur.small.shape[0] > B:
+            if fr and not self._cache.hooks:
                 counters = np.asarray(cur.small)[B:]  # raylint: disable=RL101 -- the programs' counters, ready with the logits
                 copied += cur.small.nbytes
         t_read = _time.monotonic() if fr else 0.0
@@ -1613,7 +1618,7 @@ class LLMEngine:
             bs = self._block_size
             blocks_live = int(((cur.at + bs) // bs).sum())
             moe = {}
-            if counters is not None and counters.size:
+            if counters is not None and not self._cache.hooks:
                 # The rows a layer's attention reads, by the program's arm.
                 in_place = self._decode_arm == "decode_attn_kernel_steps"
                 blocks_read = blocks_live if in_place else self.block_tables.size

@@ -412,7 +412,10 @@ def ffn(x, p, cfg, layer: int, valid, seen: list):
 
 def outputs(pool, logits, seen, with_picks: bool):
     """What a paged program of these families returns: the pool, the logits,
-    the expert layers' counters [expert layers, 2] and, asked for, the picks."""
+    the expert layers' counters [expert layers, 2] and, asked for, the picks;
+    from a model with no expert layer, the pool and the logits alone."""
+    if not seen:
+        return pool, logits
     counts = jnp.stack([c for c, _ in seen])
     if with_picks:
         return pool, logits, counts, jnp.stack([p for _, p in seen])

@@ -322,12 +322,23 @@ def _qkv(u, p, cfg: NemotronHConfig):
     return q, k, v
 
 
+def causal_attention(q, kd, vd, pos, cfg, scale=None):
+    """``q`` [T, KH, group, Dh] at positions ``pos`` [T] against a table's
+    gathered keys and values ``kd``, ``vd`` [KH, S, Dh] under the mask ``column
+    <= position``, scores in float32 times ``scale`` (None: ``head_dim^-1/2``);
+    [T, n_head Dh]."""
+    T, S = q.shape[0], kd.shape[1]
+    s = jnp.einsum("tkgd,ksd->kgts", q, kd).astype(_F32) * (cfg.head_dim**-0.5 if scale is None else scale)
+    s = jnp.where((jnp.arange(S)[None, :] <= pos[:, None])[None, None], s, -1e30)
+    pa = jax.nn.softmax(s, axis=-1).astype(vd.dtype)
+    return jnp.einsum("kgts,ksd->tkgd", pa, vd).reshape(T, -1)
+
+
 def attention_prefill(u, p, cfg: NemotronHConfig, pk, pv, l: int, table, pos, block_size: int):
     """``u`` [T, D] normed queries at consecutive positions ``pos`` [T]: their
     keys and values written under ``table`` [W], the table's row gathered back
     and attended under the mask ``column <= position``. Returns ``(out [T, D],
     pk, pv)``."""
-    T = u.shape[0]
     KH, Dh = cfg.n_kv_head, cfg.head_dim
     S = table.shape[0] * block_size
     q, k, v = _qkv(u, p, cfg)
@@ -336,11 +347,7 @@ def attention_prefill(u, p, cfg: NemotronHConfig, pk, pv, l: int, table, pos, bl
     pv, vd = paged._write_read(pv, l, bids, offs, v, table)
     kd = kd.transpose(1, 0, 2, 3).reshape(KH, S, Dh)
     vd = vd.transpose(1, 0, 2, 3).reshape(KH, S, Dh)
-    s = jnp.einsum("tkgd,ksd->kgts", q, kd).astype(_F32) * Dh**-0.5
-    s = jnp.where((jnp.arange(S)[None, :] <= pos[:, None])[None, None], s, -1e30)
-    pa = jax.nn.softmax(s, axis=-1).astype(vd.dtype)
-    o = jnp.einsum("kgts,ksd->tkgd", pa, vd).reshape(T, -1)
-    return o @ p["wo"].astype(cfg.dtype), pk, pv
+    return causal_attention(q, kd, vd, pos, cfg) @ p["wo"].astype(cfg.dtype), pk, pv
 
 
 def attention_decode(u, p, cfg: NemotronHConfig, pk, pv, l: int, tables, positions, block_size, attend):

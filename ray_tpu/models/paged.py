@@ -96,6 +96,7 @@ _FAMILIES = {
     "afmoe": "ray_tpu.models.afmoe",
     "solar_open2": "ray_tpu.models.solar_open2",
     "mimo_v2": "ray_tpu.models.mimo_v2",
+    "granitemoehybrid": "ray_tpu.models.granite_hybrid",
 }
 
 
@@ -224,7 +225,7 @@ def _write_read(pool_kv, l, bids, offs, new, tables):
     return pool_kv, pool_kv[l, tables]
 
 
-def _attend_gathered(qg, pk, pv, l, tables, lengths, sink=None, *, window=None):
+def _attend_gathered(qg, pk, pv, l, tables, lengths, sink=None, *, window=None, scale=None):
     """Decode attention by gather: each slot's whole table brought back as
     dense rows [B, KH, S, Dk] and [B, KH, S, Dv] and masked to its first
     ``lengths[b]`` positions (with ``window``, the last ``window`` of them).
@@ -239,8 +240,17 @@ def _attend_gathered(qg, pk, pv, l, tables, lengths, sink=None, *, window=None):
         qg = jnp.pad(qg, ((0, 0),) * 3 + ((0, pk.shape[-1] - Dh),))
     kd = pk[l, tables].transpose(0, 2, 1, 3, 4).reshape(B, KH, S, pk.shape[-1])
     vd = pv[l, tables].transpose(0, 2, 1, 3, 4).reshape(B, KH, S, pv.shape[-1])
+    scale = 1.0 / (Dh**0.5) if scale is None else scale
+    return _attend_rows(qg, kd, vd, lengths, sink, window, scale)
+
+
+def _attend_rows(qg, kd, vd, lengths, sink, window, scale):
+    """A slot's gathered rows ``kd`` [B, KH, S, Dk] and ``vd`` [B, KH, S, Dv]
+    attended by its query under the mask of its length (and window), with a
+    sink's column where there is one; [B, KH, group, Dv]."""
+    S = kd.shape[2]
     s = jnp.einsum("bkgd,bksd->bkgs", qg, kd).astype(jnp.float32)
-    s = s * (1.0 / (Dh**0.5))
+    s = s * scale
     mask = jnp.arange(S)[None, :] < lengths[:, None]  # [B, S]
     if window is not None:
         mask &= jnp.arange(S)[None, :] >= lengths[:, None] - window
@@ -276,7 +286,7 @@ class AttentionKind:
     over keys and values per head."""
 
     kv_heads: int
-    key_width: int  # of a query's and a key's head: the scores' scale is its ^-1/2
+    key_width: int  # of a query's and a key's head: the scores' scale is its ^-1/2 unless stated
     value_width: int
     itemsize: int  # of the pool's dtype
     window: Optional[int] = None  # the last positions a query sees, its own included
@@ -288,6 +298,14 @@ class AttentionKind:
     # whose kinds a reader tells apart ("": the kernel's own name).
     name: str = ""
     layers: int = 0  # of the kind, held here (a family's record says it: ``Cache.kinds``)
+    # The scores' scale where the family states one (a published multiplier
+    # that replaces ``key_width^-1/2``; None: that).
+    scale: Optional[float] = None
+    # A head's value and key side by side in ONE pool row, ``{"kv": [L, N, KH,
+    # block, value | key]}`` (``ops/paged_attention.py``, "Heads of half a
+    # lane tile"): heads of 64, where a pool a side would pad every row to
+    # twice its bytes or leave decode to the gather.
+    packed: bool = False
 
     @property
     def row_bytes(self) -> int:
@@ -330,7 +348,12 @@ def _kv_heads(cfg) -> int:
 
 
 def _kernel_fits(kind: AttentionKind, block_size, mesh) -> bool:
-    return (mesh is None or mesh.size == 1) and paged_attention.fits(
+    one_chip = mesh is None or mesh.size == 1
+    if kind.packed:
+        return one_chip and paged_attention.fits_packed(
+            kind.kv_heads, kind.key_width, block_size, kind.itemsize
+        )
+    return one_chip and paged_attention.fits(
         kind.kv_heads, kind.key_lanes or kind.key_width, block_size, kind.itemsize,
         kind.value_width,
     )
@@ -369,7 +392,34 @@ def decode_attention(kind: AttentionKind, block_size, mesh, interpret):
         gather = functools.partial(gather, window=kind.window)
     if kind.name:  # a suffix: a reader that matches the kernel's own name as a prefix still does
         kernel = functools.partial(kernel, name=f"paged_decode_attention_{kind.name}")
+    if kind.scale is not None:
+        kernel = functools.partial(kernel, scale=kind.scale)
+        gather = functools.partial(gather, scale=kind.scale)
     return _choose(kernel, gather, _kernel_fits(kind, block_size, mesh), interpret)
+
+
+def _attend_packed_gathered(qg, pool, l, tables, lengths, *, scale=None):
+    """:func:`_attend_gathered` over one pool of ``[value | key]`` rows ``[L,
+    N, KH, block, 2 Dh]``: what
+    :func:`ops.paged_attention.paged_packed_decode_attention` computes from
+    the live blocks alone."""
+    B, KH, _, Dh = qg.shape
+    S = tables.shape[1] * pool.shape[3]
+    rows = pool[l, tables].transpose(0, 2, 1, 3, 4).reshape(B, KH, S, 2 * Dh)
+    scale = Dh**-0.5 if scale is None else scale
+    return _attend_rows(qg, rows[..., Dh:], rows[..., :Dh], lengths, None, None, scale)
+
+
+def packed_decode_attention(kind: AttentionKind, block_size, mesh, interpret):
+    """:func:`decode_attention` for a kind whose pool is ``packed``:
+    ``attend(qg, pool, l, tables, lengths)`` over the one pool."""
+    assert kind.packed and kind.window is None and not kind.sink, kind
+    static = {} if kind.scale is None else {"scale": kind.scale}
+    return _choose(
+        functools.partial(paged_attention.paged_packed_decode_attention, **static),
+        functools.partial(_attend_packed_gathered, **static),
+        _kernel_fits(kind, block_size, mesh), interpret,
+    )
 
 
 # Positions of the table that one step of the fold's running softmax scores,
@@ -574,7 +624,7 @@ def state_decode(step, state, conv, l, rows: int, keep=None, *, interpret: bool 
     fits = state_step.tiles(*state.shape[2:])
     out, state, tail1 = _choose(kernel, plain, fits, interpret)(state, tail0)
     if keep is not None:
-        tail1 = jnp.where(keep[:, None, None], tail0, tail1)
+        tail1 = jnp.where(keep[(slice(None), *[None] * (tail0.ndim - 1))], tail0, tail1)
     return out, state, conv.at[l, :rows].set(tail1.astype(conv.dtype))
 
 

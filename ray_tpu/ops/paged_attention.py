@@ -44,6 +44,17 @@ value: ``p_j = exp(s_j) / (sum exp(s) + exp(sink))``) hands it over as a
 float32 operand, and a slot's fold then starts from ``(sink, 1, 0)`` and not
 from ``(-inf, 0, 0)``. Like the window, both exist in the program only where
 asked: with one width and no sink the kernel is traced as it was.
+
+**Heads of half a lane tile.** A head of 64 fills half the lanes of a tile, and
+Mosaic copies no slice of a row that is not whole tiles. Such a head's value
+and key lie side by side in ONE pool row of 128 lanes, ``[value | key]``
+(:func:`paged_packed_decode_attention`): the walk is the latent rows' ("one
+pool whose rows are the keys and, in their first lanes, the values") with a
+row a KV head, the query laid against the key's lanes with zeros against the
+value's, one copy a live block for both, and no lane of the pool is padding.
+
+**A scale that is stated.** The scores' scale is ``Dk^-1/2`` unless the caller
+states another (``scale``: a family whose published multiplier replaces it).
 """
 
 from __future__ import annotations
@@ -294,7 +305,7 @@ def _attend(
     )
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "window", "name"))
+@functools.partial(jax.jit, static_argnames=("interpret", "window", "name", "scale"))
 def paged_decode_attention(
     q: jax.Array,  # [B, KH, group, Dk] — one query position a slot
     pool_k: jax.Array,  # [L, N, KH, block, Dk or wider]
@@ -307,6 +318,7 @@ def paged_decode_attention(
     interpret: bool = False,
     window: int | None = None,  # of them, only the last ``window``
     name: str = "paged_decode_attention",  # the call's, in a device trace
+    scale: float | None = None,  # of the scores (None: Dk^-1/2)
 ) -> jax.Array:
     """softmax(q k^T / sqrt(Dk)) v over each slot's first ``lengths[b]``
     positions of its table's blocks in layer ``layer`` (with ``window``, the
@@ -324,9 +336,49 @@ def paged_decode_attention(
         sink = jnp.pad(sink, ((0, 0), (0, pad)))
     out = _attend(
         q, (pool_k, pool_v), layer, tables, lengths, value_width=pool_v.shape[-1],
-        scale=Dk**-0.5, chunk=_CHUNK, interpret=interpret, name=name, window=window, sink=sink,
+        scale=Dk**-0.5 if scale is None else scale, chunk=_CHUNK, interpret=interpret, name=name,
+        window=window, sink=sink,
     )
     return out[:, :, :G]
+
+
+def fits_packed(kv_heads: int, head_dim: int, block_size: int, itemsize: int) -> bool:
+    """:func:`fits` for a pool whose row is a head's ``[value | key]``: the
+    two halves make one whole lane tile, the block whole bf16 sublane tiles,
+    the two chunk buffers within VMEM."""
+    chunk = _pages(block_size, _CHUNK) * block_size
+    return (
+        (2 * head_dim) % 128 == 0
+        and block_size % 16 == 0
+        and 2 * kv_heads * chunk * 2 * head_dim * itemsize <= _VMEM_BUFFER_BYTES
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def paged_packed_decode_attention(
+    q: jax.Array,  # [B, KH, group, Dh] — one query position a slot
+    pool: jax.Array,  # [L, N, KH, block, 2 Dh]: a position's [value | key] a KV head
+    layer: jax.Array,  # scalar int32 — the layer of the pool to read
+    tables: jax.Array,  # [B, W] int32 block tables
+    lengths: jax.Array,  # [B] int32, >= 1 — positions attended, a slot
+    *,
+    scale: float | None = None,  # of the scores (None: Dh^-1/2)
+    interpret: bool = False,
+) -> jax.Array:
+    """softmax(q k^T scale) v over each slot's first ``lengths[b]`` positions
+    of its table's blocks in layer ``layer``, keys and values read from the one
+    pool; [B, KH, group, Dh] in the pool's dtype. The query meets zeros where
+    a row holds its value, and the fold's product with the whole row is cut to
+    the value's lanes behind the call: every operand of the kernel is whole
+    lane tiles."""
+    B, KH, G, Dh = q.shape
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, -G % _GROUP_TILE), (pool.shape[-1] - Dh, 0)))
+    out = _attend(
+        q, (pool,), layer, tables, lengths, value_width=pool.shape[-1],
+        scale=Dh**-0.5 if scale is None else scale, chunk=_CHUNK, interpret=interpret,
+        name="paged_decode_attention_packed",
+    )
+    return out[:, :, :G, :Dh]
 
 
 @functools.partial(jax.jit, static_argnames=("value_width", "scale", "interpret"))

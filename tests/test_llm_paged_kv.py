@@ -491,6 +491,10 @@ def _tiny_model_of(family):
         from ray_tpu.models.mimo_v2 import MimoV2Config
 
         return MimoV2Config.tiny(max_seq=128)
+    if family == "granitemoehybrid":
+        from ray_tpu.models.granite_hybrid import GraniteHybridConfig
+
+        return GraniteHybridConfig.tiny(max_seq=128)
     return _family_model(family)[0]
 
 
@@ -535,10 +539,11 @@ def test_a_family_is_looked_up_once_and_its_record_is_what_its_pool_holds(name):
         blocks = [pool["full"], pool["window"]]
     else:
         blocks = [pool]
+    packed = any(kind.packed for kind in record.kinds)  # a head's value and key in one pool row
     for part in blocks:
-        assert ("k" in part and "v" in part) == record.per_head
+        assert ("kv" in part if packed else "k" in part and "v" in part) == record.per_head
         assert ("ckv" in part) == (not record.per_head)
-        rows = part["k"] if record.per_head else part["ckv"]
+        rows = part["kv" if packed else "k"] if record.per_head else part["ckv"]
         # [layers, blocks, KH, block, Dh], or [layers, blocks, block, row width]: no head axis
         assert rows.ndim == (5 if record.per_head else 4) and rows.shape[-2] == 16
     assert record.shares_prefixes == (name in ("gpt2", "llama", "mla_moe"))

@@ -823,3 +823,146 @@ def test_a_family_with_no_state_a_slot_never_meets_the_state_step(family):
     names = [eqn.params["name"] for eqn in _pallas_calls(jaxpr.jaxpr)]
     assert not [n for n in names if not n.startswith("paged_")]
     assert "state_step" not in str(jaxpr)
+
+
+# ---------------------------------------------------------------------------
+# Granite-4.0-H-Micro (PR 51): heads of 64 attended in place, a period scanned
+
+
+def test_the_packed_decode_kernel_compiles_at_granites_shape(v5e_2x2):
+    """``paged_packed_decode_attention`` at the cell's shape (64 slots, 8 KV
+    heads of 4 queries, heads of 64 whose value and key share a pool row of 128
+    lanes, a four-layer part of 8,193 blocks, the published multiplier for a
+    scale), lowered for the chip: Mosaic takes the layout, the call keeps a
+    name the readers' prefix matches, and nothing of the pool's size is made
+    beside it."""
+    from ray_tpu.ops import paged_attention as pa
+
+    B, KH, G, Dh, bs, W, L, N = 64, 8, 4, 64, 16, 128, 4, 8193
+    assert pa.fits_packed(KH, Dh, bs, 2) and not pa.fits(KH, Dh, bs, 2)
+    one = jax.sharding.SingleDeviceSharding(v5e_2x2[0])
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)  # noqa: E731
+    call = jax.jit(functools.partial(pa.paged_packed_decode_attention, scale=1 / 64))
+    compiled = call.lower(
+        sds((B, KH, G, Dh), jnp.bfloat16), sds((L, N, KH, bs, 2 * Dh), jnp.bfloat16), sds((), jnp.int32),
+        sds((B, W), jnp.int32), sds((B,), jnp.int32),
+    ).compile()
+    assert mosaic_calls(compiled.as_text()) == ["paged_decode_attention_packed"]
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+@pytest.mark.parametrize("program", ["decode_of_64_slots", "prefill_of_512"])
+def test_granite_hybrids_served_programs_compile_for_one_chip(v5e_2x2, program):
+    """``granite_hybrid``'s two programs with the model built as the benchmark
+    builds it (all 40 layers at the published widths, 64 slots of 2,048
+    positions), compiled for the chip: the decode program holds ONE period's
+    Mosaic calls (nine state steps and one attention call inside the scan's
+    body, whatever the depth) and no gather of a table; the pool donated is
+    the pool returned; and neither makes a temporary the size of a cache part
+    (a prefill of 128 tokens or more used to want the whole ``conv`` part with
+    its three rows along lanes, 2.6 GB: the tails lie flat for that)."""
+    from benchmarks import harness
+    from ray_tpu.models import paged
+
+    found = harness.cell("serve-chat-granite4hmicro")
+    c, mix = harness.config_of(found), harness.traffic_of(found)
+    e = mix["engine"]
+    cfg = harness.family(c).model_config(c, mix)
+    assert cfg.n_layer == 40 and len(cfg.period) == 10 and cfg.periods == 4
+    bs, N, B = e["kv_block_size"], e["num_kv_blocks"], e["max_slots"]
+    W = e["max_seq"] // bs
+    one = jax.sharding.SingleDeviceSharding(v5e_2x2[0])
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)  # noqa: E731
+    on_chip = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
+    mod = paged.family(cfg)
+    params = on_chip(jax.eval_shape(lambda k: mod.init_params(k, cfg), jax.random.key(0)))
+    pool = on_chip(jax.eval_shape(lambda: paged.init_block_pool(cfg, N, bs, B)))
+    nbytes = lambda t: sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(t))  # noqa: E731
+    assert 6.3e9 < nbytes(params) < 6.45e9 and 6.0e9 < nbytes(pool) < 6.1e9
+    assert pool["kv"].shape == (4, N, 8, bs, 128) and pool["state"].shape == (36, B + 1, 64, 64, 128)
+    i32 = jnp.int32
+    if program == "decode_of_64_slots":
+        compiled = jax.jit(
+            functools.partial(paged.paged_decode, cfg=cfg, block_size=bs), donate_argnums=4
+        ).lower(
+            params, sds((B,), i32), sds((B,), i32), sds((B, W), i32), pool, live=sds((B,), jnp.bool_)
+        ).compile()
+        calls = mosaic_calls(compiled.as_text())
+        assert sorted(calls) == ["paged_decode_attention_packed"] + ["state_step_ssd"] * 9
+        assert f"bf16[{B},{W},8,{bs},128]" not in compiled.as_text()  # no table gathered whole
+        limit = 64 * 2**20
+    else:
+        compiled = jax.jit(
+            functools.partial(paged.paged_prefill, cfg=cfg, block_size=bs), donate_argnums=5
+        ).lower(
+            params, sds((1, 512), i32), sds((), i32), sds((), i32), sds((W,), i32), pool, slot=sds((), i32)
+        ).compile()
+        assert mosaic_calls(compiled.as_text()) == []
+        limit = 512 * 2**20
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= nbytes(pool)
+    assert mem.temp_size_in_bytes < limit, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.5e9  # of the chip's 16 GB
+
+
+# sha256 (16 hex digits) of each accepted cell's programs at the commit before
+# PR 51 (11518c6): the decode program's and the largest prefill's lowered text
+# with the Mosaic calls' serialized bodies cut out (a body carries its source's
+# path and lines), and the decode program's jaxpr with source locations cut.
+_LOWERED_AT_THE_PARENT = {
+    "serve-batch-mistral7b": ("7a69735a4fd87361", "8aeffec5bc4a2fc4", "5279d828fbfe3f1a"),
+    "serve-chat-nemotron3super": ("cfd666b203792e2c", "9e2112067440a7fd", "cc31f47ee931a05b"),
+    "serve-longdoc-solaropen2": ("71beb01763a3e6ea", "3cecb92187c72542", "7aac897d5b9e2c4c"),
+    "serve-mixed-trinity": ("c6f68ddfe10caf2d", "550842580db6067c", "b0ee041c9bd3b01f"),
+    "serve-longdoc-mimov25": ("e97122a8ecb4d59b", "38627766ccf14454", "f23ad7ee61bc330e"),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_LOWERED_AT_THE_PARENT))
+def test_an_accepted_cells_programs_lower_as_before_scales_and_packed_heads(v5e_2x2, cell):
+    """Mistral's, Nemotron's, Solar's, Trinity's and MiMo's served programs,
+    built as the benchmark builds them and lowered for the chip, are what they
+    were before a kind could state its scale or pack its heads: the same
+    lowered text outside the kernels' bodies, and the same jaxpr, kernels'
+    bodies included. (An edit to ``ops/paged_attention.py`` re-keys the Mosaic
+    calls made from it all the same, by the lines a body carries: PERF.md
+    section 6, PR 39.)"""
+    import hashlib
+
+    from benchmarks import harness
+    from ray_tpu.models import paged
+
+    found = harness.cell(cell)
+    c, mix = harness.config_of(found), harness.traffic_of(found)
+    e = mix["engine"]
+    cfg = harness.family(c).model_config(c, mix)
+    bs, N, B = e["kv_block_size"], e["num_kv_blocks"], e["max_slots"]
+    W = e["max_seq"] // bs
+    one = jax.sharding.SingleDeviceSharding(v5e_2x2[0])
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)  # noqa: E731
+    on_chip = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
+    mod, record = paged.family(cfg), paged.cache(cfg)
+    params = on_chip(jax.eval_shape(lambda k: mod.init_params(k, cfg), jax.random.key(0)))
+    pool = on_chip(jax.eval_shape(lambda: paged.init_block_pool(cfg, N, bs, B)))
+    kinds, i32 = len(record.retention), jnp.int32
+    tables = sds((B, W), i32) if kinds == 1 else sds((B, kinds, W), i32)
+    table = sds((W,), i32) if kinds == 1 else sds((kinds, W), i32)
+    live = {"live": sds((B,), jnp.bool_)} if record.slot_state else {}
+    T = e.get("prefill_chunk_tokens") or max(e["prefill_buckets"])
+    decode = functools.partial(paged.paged_decode, cfg=cfg, block_size=bs)
+    d_args = (params, sds((B,), i32), sds((B,), i32), tables, pool)
+    prefill = functools.partial(paged.paged_prefill, cfg=cfg, block_size=bs)
+    p_args = (params, sds((1, T), i32), sds((), i32), sds((), i32), table, pool)
+
+    def digest(text):
+        return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+    def outside_the_bodies(lowered):
+        return digest(re.sub(r'(body\\22: \\22)[A-Za-z0-9+/=]+', r"\1", lowered.as_text()))
+
+    got = (
+        outside_the_bodies(jax.jit(decode, donate_argnums=4).lower(*d_args, **live)),
+        outside_the_bodies(jax.jit(prefill, donate_argnums=5).lower(*p_args, slot=sds((), i32))),
+        digest(re.sub(r" at [^ \n]*:\d+", "", str(jax.make_jaxpr(decode)(*d_args, **live)))),
+    )
+    assert got == _LOWERED_AT_THE_PARENT[cell]

@@ -31,7 +31,17 @@ layer of MiMo-V2.5, Solar-Open2 and Trinity, microseconds a layer, the
 largest difference of the outputs, and the share of the array's peak (197
 TFLOP/s) by the pairs the mathematics needs at the widths it needs (a key of
 192, not the 256 lanes of its row); ``--tile-rows`` and ``--score-elements`` sweep
-the rows a tile of queries may hold and the scores of a (tile, stretch). Needs a TPU:
+the rows a tile of queries may hold and the scores of a (tile, stretch).
+``--head64`` times the three layouts in which heads of 64 can be attended in
+place (PR 51), at Granite-4.0-H-Micro's cell (64 slots of 100-1,150 live rows
+drawn from ``chat-backlog``, 8 key/value heads of 4 queries, a four-layer
+part of 8,193 blocks) and at one long shape (8 slots of 16,384 rows): (a)
+``packed``, a head's value and key side by side in one pool row of 128 lanes
+(``paged_packed_decode_attention``); (b) ``pairs``, two heads a lane tile in
+each of two pools, the pair's queries laid against their own half; (c)
+``padded``, keys and values each padded to 128 lanes, twice the cache; each
+against the gather over the packed pool, with the share of the bytes' speed by
+the bytes the mathematics needs (2,048 B a position and layer). Needs a TPU:
 the kernel does not lower elsewhere, and a time from another backend says
 nothing (PERF.md section 6, PR 32 and PR 39, holds the v5e's readings). The last line of standard output is one JSON list.
 """
@@ -194,6 +204,69 @@ def per_kind(kind: str, rng):
     )
 
 
+def head64(rng) -> list:
+    """Heads of 64 in place, by layout (module docstring)."""
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "traffic", "chat-backlog.json")) as f:
+        mix = json.load(f)
+    kh, g, dh, layers, blocks, scale = 8, 4, 64, 4, 8193, 1 / 64
+    ks = jax.random.split(jax.random.key(0), 3)
+    k = jax.random.normal(ks[0], (layers, blocks, kh, BLOCK, dh), jnp.bfloat16)
+    v = jax.random.normal(ks[1], (layers, blocks, kh, BLOCK, dh), jnp.bfloat16)
+    zeros = jnp.zeros_like(k)
+    pools = {
+        "packed": (jnp.concatenate([v, k], axis=-1),),
+        "pairs": tuple(
+            x.reshape(layers, blocks, kh // 2, 2, BLOCK, dh).transpose(0, 1, 2, 4, 3, 5)
+            .reshape(layers, blocks, kh // 2, BLOCK, 2 * dh) for x in (k, v)),
+        "padded": (jnp.concatenate([k, zeros], axis=-1), jnp.concatenate([v, zeros], axis=-1)),
+    }
+    del k, v, zeros
+
+    def pairs(q, pk, pv, l, t, n):
+        b = q.shape[0]
+        q2 = q.reshape(b, kh // 2, 2, g, dh)
+        z = jnp.zeros_like(q2[:, :, 0])
+        laid = jnp.concatenate([  # the pair's first head against lanes 0-63, its second against 64-127
+            jnp.concatenate([q2[:, :, 0], z], axis=-1), jnp.concatenate([z, q2[:, :, 1]], axis=-1)], axis=2)
+        out = paged_attention.paged_decode_attention(laid, pk, pv, l, t, n, scale=scale)  # [b, kh/2, 2g, 2dh]
+        return jnp.stack([out[:, :, :g, :dh], out[:, :, g:, dh:]], axis=2).reshape(b, kh, g, dh)
+
+    kernels = {
+        "packed": functools.partial(paged_attention.paged_packed_decode_attention, scale=scale),
+        "pairs": pairs,
+        "padded": lambda q, pk, pv, l, t, n: paged_attention.paged_decode_attention(
+            q, pk, pv, l, t, n, scale=scale)[..., :dh],
+    }
+    gather = per_layer(functools.partial(paged._attend_packed_gathered, scale=scale), layers)
+    drawn = rng.choice(mix["prompt_tokens"], 64) + (
+        rng.random(64) * rng.choice(mix["output_tokens"], 64)).astype(int)
+    shapes = {"chat-backlog (64 slots)": (drawn, 128), "long (8 slots of 16384)": (np.full(8, 16384), 1024)}
+    rows = []
+    for name, (lens, width) in shapes.items():
+        q = jax.random.normal(ks[2], (len(lens), kh, g, dh), jnp.bfloat16) * 4
+        tables = jnp.asarray(
+            rng.permutation(np.arange(1, blocks))[: len(lens) * width].reshape(len(lens), width), jnp.int32)
+        lengths = jnp.asarray(lens, jnp.int32)
+        want = gather(q, pools["packed"], tables, lengths)
+        needed = int((-(-lens // BLOCK)).sum()) * kh * BLOCK * 2 * dh * 2  # the live blocks, a layer
+        gather_us = us_a_layer(gather, layers, q, pools["packed"], tables, lengths)
+        for layout, kernel in kernels.items():
+            run = per_layer(kernel, layers)
+            operands = (q, pools[layout], tables, lengths)
+            diff = jnp.max(jnp.abs(run(*operands) - want)) / layers
+            us = us_a_layer(run, layers, *operands)
+            rows.append({
+                "shape": name, "layout": layout, "live_positions": int(lens.sum()),
+                "pool_bytes_a_position_and_layer": sum(p.shape[-1] * p.shape[2] * 2 for p in pools[layout]),
+                "max_abs_diff": round(float(diff), 5), "kernel_us_a_layer": round(us, 2),
+                "gather_us_a_layer": round(gather_us, 2),
+                "kernel_pct_of_bytes_speed": round(100 * needed / HBM_BYTES_A_US / us, 1),
+            })
+            print(json.dumps(rows[-1]), file=sys.stderr, flush=True)
+    return rows
+
+
 # The chunked cells' kinds of layer: layers of the part, key/value heads,
 # queries a head, a key's width and its row's, a value's, window, sink, table.
 CHUNK, PEAK_FLOPS_A_US = 2048, 197e6
@@ -279,6 +352,8 @@ def main() -> int:
                     help="with --prefill: comma-separated bounds on a tile's rows to sweep")
     ap.add_argument("--score-elements", default=str(paged_prefill_attention._SCORE_ELEMENTS),
                     help="with --prefill: comma-separated sizes of a (tile, stretch)'s scores to sweep")
+    ap.add_argument("--head64", action="store_true",
+                    help="the layouts that attend heads of 64 in place, at Granite-4.0-H-Micro's shapes")
     ap.add_argument("--latent", action="store_true",
                     help="the latent arm at A.X-K1's shapes")
     ap.add_argument("--kind", choices=sorted(KINDS), help="a kind of MiMo-V2.5's attention layers")
@@ -288,8 +363,8 @@ def main() -> int:
     if jax.default_backend() != "tpu":
         raise SystemExit("needs a TPU: the kernel's time is a device time")
     rng = np.random.default_rng(0)
-    if args.prefill:
-        print(json.dumps(prefill(args, rng)))
+    if args.prefill or args.head64:
+        print(json.dumps(prefill(args, rng) if args.prefill else head64(rng)))
         return 0
     arm = functools.partial(per_kind, args.kind) if args.kind else latent if args.latent else per_head
     (q, pools, tables), mixes, attend, kernel_of, chunk_name, layers, block_bytes, *covered = arm(rng)
