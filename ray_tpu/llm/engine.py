@@ -277,6 +277,10 @@ class LLMEngine:
             _validate_block_multiple(
                 "prefill_chunk_tokens", config.prefill_chunk_tokens, bs
             )
+        # A prefill program's rows are whole blocks from a block's start, and
+        # it writes them a block at a time (paged._write_blocks).
+        for bucket in config.prefill_buckets:
+            _validate_block_multiple("prefill_buckets", bucket, bs)
         self._block_size = bs
         self._table_width = S // bs
         n = config.num_kv_blocks or max(
@@ -379,6 +383,11 @@ class LLMEngine:
             # The rows those programs computed (the sum of their buckets),
             # and the turns that launched one or gave a request a slot.
             "prefill_tokens_padded": 0,
+            # Of those rows, the blocks a tensor a layer that the programs
+            # wrote whole (bucket // kv_block_size a launch): a family whose
+            # rows in blocks are keys and values per head; no decode step
+            # adds to it.
+            "prefill_blocks_written": 0,
             "admit_waves": 0,
             "prefill_chunks": 0,  # chunked-prefill pieces executed
             "prefix_hits": 0,
@@ -926,6 +935,8 @@ class LLMEngine:
         wave["padded"] += bucket
         self.stats["prefill_tokens"] += n
         self.stats["prefill_tokens_padded"] += bucket
+        if self._cache.per_head:
+            self.stats["prefill_blocks_written"] += bucket // self._block_size
         self.stats["programs_launched"] += 1
         self.pool, out = self._pg_prefill(self.params, toks, meta, self.pool)
         return out

@@ -379,9 +379,8 @@ def paged_prefill(
         a = _rms_norm(x, p["in_norm"], cfg.rms_eps)
         q, k, v, g = _qkvg(a, p, cfg, rope if window else None)
         tab, kv = tables[part], pool[part]
-        bids, offs = tab[pos // block_size], pos % block_size
-        kv["k"] = paged._write(kv["k"], l, bids, offs, k)
-        kv["v"] = paged._write(kv["v"], l, bids, offs, v)
+        kv["k"] = paged._write_blocks(kv["k"], l, tab, start, k, block_size)
+        kv["v"] = paged._write_blocks(kv["v"], l, tab, start, v, block_size)
         o = paged.prefill_attention(
             q, kv["k"], kv["v"], l, tab, pos, start + length, block_size=block_size, window=window,
         )

@@ -234,7 +234,7 @@ def attention_prefill(u, p, cfg: GraniteHybridConfig, kv, l, table, pos, block_s
     S = table.shape[0] * block_size
     q, k, v = nemotron_h._qkv(u, p, cfg)
     row = jnp.concatenate([v, k], axis=-1)
-    kv, rows = paged._write_read(kv, l, table[pos // block_size], pos % block_size, row, table)
+    kv, rows = paged._write_blocks_read(kv, l, table, pos[0], row, block_size)
     rows = rows.transpose(1, 0, 2, 3).reshape(KH, S, 2 * Dh)
     o = nemotron_h.causal_attention(q, rows[..., Dh:], rows[..., :Dh], pos, cfg, cfg.attention_multiplier)
     return o @ p["wo"].astype(cfg.dtype), kv

@@ -421,9 +421,8 @@ def paged_prefill(
         a = _rms_norm(x, p["in_norm"], cfg.rms_eps)
         q, k, v = _qkv(a, p, cfg, kinds[kind], ropes[kind], held=True)
         tab, kv = tables[kind], pool[PARTS[kind]]
-        bids, offs = tab[pos // block_size], pos % block_size
-        kv["k"] = paged._write(kv["k"], l, bids, offs, k)
-        kv["v"] = paged._write(kv["v"], l, bids, offs, v)
+        kv["k"] = paged._write_blocks(kv["k"], l, tab, start, k, block_size)
+        kv["v"] = paged._write_blocks(kv["v"], l, tab, start, v, block_size)
         o = paged.prefill_attention(
             q, kv["k"], kv["v"], l, tab, pos, start + length, block_size=block_size,
             window=kinds[kind].window, sink=_sink(p, kinds[kind]), name=kinds[kind].name,

@@ -16,9 +16,10 @@ in blocks *and* a state per slot.
 - **\\*.** ``n_head`` query heads over ``n_kv_head`` key/value heads, no biases,
   no rotation, scale ``head_dim^-1/2``. Keys and values lie in the block pool
   under the engine's block tables, as :mod:`ray_tpu.models.paged` keeps them:
-  prefill writes and gathers with its ``_write_read``; decode attends through
-  its ``decode_attention`` (the kernel of ``ops/paged_attention.py`` over the
-  live blocks on a TPU, the gather elsewhere).
+  prefill writes whole blocks and gathers with its ``_write_blocks_read``;
+  decode writes a row with its ``_write`` and attends through its
+  ``decode_attention`` (the kernel of ``ops/paged_attention.py`` over the live
+  blocks on a TPU, the gather elsewhere).
 - **E.** :func:`ray_tpu.models.latent_moe.moe_ffn` with what this family's
   parameters hold: no gates, ``latent_in`` / ``latent_out`` around the routed
   part, a selection bias that :func:`init_params` balances
@@ -342,9 +343,8 @@ def attention_prefill(u, p, cfg: NemotronHConfig, pk, pv, l: int, table, pos, bl
     KH, Dh = cfg.n_kv_head, cfg.head_dim
     S = table.shape[0] * block_size
     q, k, v = _qkv(u, p, cfg)
-    bids, offs = table[pos // block_size], pos % block_size
-    pk, kd = paged._write_read(pk, l, bids, offs, k, table)  # [W, KH, block, Dh]
-    pv, vd = paged._write_read(pv, l, bids, offs, v, table)
+    pk, kd = paged._write_blocks_read(pk, l, table, pos[0], k, block_size)  # [W, KH, block, Dh]
+    pv, vd = paged._write_blocks_read(pv, l, table, pos[0], v, block_size)
     kd = kd.transpose(1, 0, 2, 3).reshape(KH, S, Dh)
     vd = vd.transpose(1, 0, 2, 3).reshape(KH, S, Dh)
     return causal_attention(q, kd, vd, pos, cfg) @ p["wo"].astype(cfg.dtype), pk, pv

@@ -31,7 +31,9 @@ record's business (:class:`Cache`, :func:`cache`), and the engine reads that
 and nothing else about a family's cache.
 
 - *Blocks of rows per head*, ``{"k", "v": [L, N, KH, block, Dh]}``: written
-  with :func:`_write`, read by prefill as a gathered table or where they lie
+  with :func:`_write` (a row of a head an update: decode, verify) or
+  :func:`_write_blocks` (a block an update: a prefill's rows are consecutive
+  whole blocks), read by prefill as a gathered table or where they lie
   (:func:`prefill_attention`: on a TPU one kernel a layer that walks the
   table, ``ops/paged_prefill_attention.py``; elsewhere a stretch of the
   table at a time), by decode through :func:`decode_attention`. What those
@@ -223,6 +225,33 @@ def _write_read(pool_kv, l, bids, offs, new, tables):
     [..., W, KH, block, Dh], by (layer, block) at once as well."""
     pool_kv = _write(pool_kv, l, bids, offs, new)
     return pool_kv, pool_kv[l, tables]
+
+
+def _write_blocks(pool_kv, l, table, start, new, block_size: int):
+    """:func:`_write` at a prefill's grain: ``new`` [T, KH, Dh] are the rows
+    of ``T`` consecutive positions from ``start``, whole blocks of them
+    (``start`` and ``T`` multiples of ``block_size``: the engine holds every
+    prefill program to that), so each block of ``table`` [W] they fill is one
+    update of ``KH x block x Dh`` contiguous elements and not ``block x KH``
+    of a row each. The same values at the same homes as ``_write(pool_kv, l,
+    table[pos // block_size], pos % block_size, new)``, the padding behind a
+    last chunk included. A block's id is the table indexed at its number: a
+    number past the table's end clamps entry by entry as a position's does
+    there (``llm/engine.py:_chunk_bucket``), and several padded blocks may
+    name the scratch block at once, so the ids are not unique."""
+    T, KH, Dh = new.shape
+    assert T % block_size == 0, (T, block_size)
+    n = T // block_size
+    blocks = table[start // block_size + jnp.arange(n, dtype=jnp.int32)]
+    tiles = new.reshape(n, block_size, KH, Dh).transpose(0, 2, 1, 3)
+    return pool_kv.at[l, blocks].set(tiles)
+
+
+def _write_blocks_read(pool_kv, l, table, start, new, block_size: int):
+    """:func:`_write_blocks`, then the rows of ``table`` [W] gathered back as
+    [W, KH, block, Dh], as :func:`_write_read` does."""
+    pool_kv = _write_blocks(pool_kv, l, table, start, new, block_size)
+    return pool_kv, pool_kv[l, table]
 
 
 def _attend_gathered(qg, pk, pv, l, tables, lengths, sink=None, *, window=None, scale=None):
@@ -687,8 +716,6 @@ def paged_prefill(
 
     pos = start + jnp.arange(T, dtype=jnp.int32)  # [T]
     x = embed(params, tokens, pos[None])
-    bids = table[pos // block_size]  # [T] physical blocks to write
-    offs = pos % block_size
     cols = jnp.arange(S)
     mask = cols[None, :] <= pos[:, None]  # [T, S]
     scale = 1.0 / (Dh**0.5)
@@ -700,8 +727,8 @@ def paged_prefill(
         kt = k[0].transpose(1, 0, 2)  # [T, KH, Dh]
         vt = v[0].transpose(1, 0, 2)
         # This request's row (transient): [W,KH,block,Dh] -> [KH,S,Dh]
-        pk, kd = _write_read(pk, l, bids, offs, kt, table)
-        pv, vd = _write_read(pv, l, bids, offs, vt, table)
+        pk, kd = _write_blocks_read(pk, l, table, start, kt, block_size)
+        pv, vd = _write_blocks_read(pv, l, table, start, vt, block_size)
         kd = kd.transpose(1, 0, 2, 3).reshape(KH, S, Dh)
         vd = vd.transpose(1, 0, 2, 3).reshape(KH, S, Dh)
         qg = q[0].reshape(KH, group, T, Dh)

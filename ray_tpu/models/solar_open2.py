@@ -20,11 +20,11 @@ expert layer counts them from 1, as :mod:`latent_moe` does.
   ``g = W_g a`` [H Dh]; no rotation and no other position signal, no norm over
   a head; scores ``q.k Dh^-1/2``, causal, float32 softmax, ``H / KH`` query
   heads a key/value head; ``W_o (o sigmoid(g))``. Keys and values lie in the
-  block pool under the engine's block tables: written with ``paged._write``,
-  read by prefill a stretch of the table at a time
-  (:func:`paged.prefill_attention`) and by decode through
-  :func:`paged.decode_attention` (the kernel over the live blocks on a TPU,
-  the gather elsewhere).
+  block pool under the engine's block tables: written with ``paged._write``
+  (a prefill's whole blocks with ``paged._write_blocks``), read by prefill a
+  stretch of the table at a time (:func:`paged.prefill_attention`) and by
+  decode through :func:`paged.decode_attention` (the kernel over the live
+  blocks on a TPU, the gather elsewhere).
 - **Experts, in every layer.** :func:`ray_tpu.models.latent_moe.moe_ffn`: a
   float32 sigmoid router over all experts of the model, the
   ``experts_per_token`` largest of ``s + b``, weights ``s / sum(s)`` times
@@ -312,7 +312,6 @@ def paged_prefill(
 
     pos = start + jnp.arange(T, dtype=jnp.int32)
     valid = jnp.arange(T) < length
-    bids, offs = table[pos // block_size], pos % block_size
     x = params["wte"].astype(cfg.dtype)[tokens[0]]
     seen: list = []
     for i, kind, p, l in _layers(params, cfg):
@@ -324,8 +323,8 @@ def paged_prefill(
             )
         else:
             q, k, v, g = _qkvg(a, p, cfg)
-            pk = paged._write(pk, l, bids, offs, k)
-            pv = paged._write(pv, l, bids, offs, v)
+            pk = paged._write_blocks(pk, l, table, start, k, block_size)
+            pv = paged._write_blocks(pv, l, table, start, v, block_size)
             o = paged.prefill_attention(
                 q, pk, pv, l, table, pos, start + length, block_size=block_size
             )

@@ -111,6 +111,24 @@ def test_short_and_long_requests_in_chunks_are_the_reference_and_the_window_stay
     assert engine.stats["prompts_truncated"] == 0 and engine.stats["prefill_chunks"] > 0
 
 
+@pytest.mark.parametrize("chunk, blocks", [(0, 16), (CHUNK, 6)], ids=["whole", "in_chunks"])
+def test_a_prefill_counts_the_blocks_it_writes_whole_and_a_decode_step_none(chunk, blocks):
+    """A prompt of 20 tokens in blocks of 4: one program of the 64 bucket
+    writes sixteen blocks a tensor a layer of its kind, three chunks in the 8
+    bucket two each (``paged._write_blocks``, both pool parts under their own
+    tables); the decode steps behind them add nothing, and every logits row
+    the engine samples from is the reference's either way."""
+    eng = LLMEngine(llm_config(prefill_chunk_tokens=chunk))
+    ps = prompts([20], seed=9)
+    done, logits = run(eng, ps, [5])
+    assert eng.stats["prefill_blocks_written"] == eng.stats["prefill_tokens_padded"] // BLOCK == blocks
+    assert eng.stats["prefill_chunks"] == (3 if chunk else 0) and eng.stats["tokens_generated"] == 5
+    toks = jnp.asarray(ps[0] + done[0].generated, jnp.int32)
+    want = ref.forward(eng.params, toks, ref_config(eng.model_config))
+    np.testing.assert_allclose(logits[0], want[19:24], rtol=2e-4, atol=2e-5)
+    assert done[0].generated == np.argmax(want[19:24], axis=-1).tolist()
+
+
 def test_greedy_tokens_run_ahead_and_do_not_depend_on_company(engine):
     ps = prompts([41, 12, 30], seed=3)
     sampling = SamplingParams(max_tokens=24, stop_token=-1)

@@ -463,6 +463,39 @@ def test_engine_greedy_tokens_match_the_forward_rollout(family):
         )
 
 
+@pytest.mark.parametrize("chunk, blocks", [(0, 4), (16, 3)], ids=["whole", "in_chunks"])
+@pytest.mark.parametrize("family", ["gpt2", "llama"])
+def test_a_prefill_counts_the_blocks_it_writes_whole_and_a_decode_step_none(family, chunk, blocks):
+    """A prompt of 40 tokens in blocks of 16: one program of the 64 bucket
+    writes four blocks a tensor a layer, three chunks in the 16 bucket write
+    one each (``paged._write_blocks``); the six decode steps behind them add
+    nothing, and the greedy tokens are the forward rollout's either way."""
+    cfg, mod, _ = _family_model(family)
+    eng = LLMEngine(
+        LLMConfig(
+            model_config=cfg, max_slots=2, max_seq=64, prefill_buckets=(16, 32, 64),
+            kv_block_size=16, prefix_chunk=16, prefill_chunk_tokens=chunk, seed=0,
+        )
+    )
+    prompt = list(range(5, 45))
+    out = eng.generate([prompt], SamplingParams(max_tokens=6, temperature=0.0))[0]
+    assert eng.stats["prefill_blocks_written"] == eng.stats["prefill_tokens_padded"] // 16 == blocks
+    assert eng.stats["prefill_chunks"] == (3 if chunk else 0)
+    assert eng.stats["decode_attn_gather_steps"] >= 5
+    assert out["token_ids"] == _greedy_rollout(mod, cfg, eng.params, prompt, 6, eng.tokenizer.eos_id)
+
+
+def test_prefill_buckets_are_held_to_whole_blocks():
+    """A prefill program writes whole blocks: a bucket that is no multiple
+    of ``kv_block_size`` is refused at construction, by name, where
+    ``prefix_chunk`` and ``prefill_chunk_tokens`` are."""
+    cfg, _, _ = _family_model("gpt2")
+    with pytest.raises(ValueError, match=r"prefill_buckets \(24\) must be a multiple of kv_block_size \(16\)"):
+        LLMEngine(
+            LLMConfig(model_config=cfg, max_slots=2, max_seq=64, prefill_buckets=(16, 24, 64), kv_block_size=16)
+        )
+
+
 # -- one cache ------------------------------------------------------------------
 
 

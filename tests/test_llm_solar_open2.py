@@ -217,6 +217,21 @@ def test_chunked_prefill_between_decode_turns_is_whole_prefill():
     assert sum(x["state_carried"] == 0 for x in chunks) == 4 == eng.stats["state_resets"]
 
 
+@pytest.mark.parametrize("chunk, blocks", [(0, 8), (16, 10)], ids=["whole", "in_chunks"])
+def test_a_prefill_counts_the_blocks_it_writes_whole_and_a_decode_step_none(chunk, blocks):
+    """A prompt of 70 tokens in blocks of 16: one program of the 128 bucket
+    writes eight blocks a tensor of the GQA layer, five chunks in the 32
+    bucket two each (``paged._write_blocks``); the decode steps behind them
+    add nothing, and the greedy tokens are the reference's either way."""
+    eng = LLMEngine(llm_config(prefill_chunk_tokens=chunk, num_kv_blocks=3 * 8 + 1))
+    p = prompts(1, np.random.default_rng(5), lo=70, hi=71)[0]
+    tokens = generate(eng, p)
+    assert eng.stats["prefill_blocks_written"] == eng.stats["prefill_tokens_padded"] // 16 == blocks
+    assert eng.stats["prefill_chunks"] == (5 if chunk else 0) and eng.stats["tokens_generated"] == len(tokens)
+    want = ref.forward(eng.params, jnp.asarray(p + tokens, jnp.int32), ref_config(eng.model_config))
+    assert tokens == np.argmax(want[69 : 69 + len(tokens)], axis=-1).tolist()
+
+
 def test_padded_bucket_tails_leave_the_state_alone():
     """The same prompts through one wide bucket (every prompt padded to 128)
     and through the ladder."""

@@ -129,8 +129,9 @@ def test_the_gated_norm_is_per_group(tiny):
 
 
 def test_attention_prefill_and_decode_are_the_reference(tiny):
-    """20 positions written under a scattered table and attended, then one
-    more by the decode form (the gather, and the kernel in the interpreter):
+    """20 positions (and the padding behind them to two whole blocks, as a
+    prefill program's rows are) written under a scattered table and attended,
+    then one more by the decode form (the gather, and the kernel in the interpreter):
     the reference's causal attention, which knows no position."""
     cfg, params = tiny
     p = _block(params, cfg, "*")
@@ -140,8 +141,9 @@ def test_attention_prefill_and_decode_are_the_reference(tiny):
     pool = nh.init_pool(cfg, 6, bs, 2)
     pk, pv = pool["k"] + 3.0, pool["v"] - 2.0  # whatever lay there before must not matter
     table = jnp.asarray([4, 2, 0, 0], jnp.int32)
-    out, pk, pv = nh.attention_prefill(u[:S], p, cfg, pk, pv, 0, table, jnp.arange(S), bs)
-    np.testing.assert_allclose(out, want[0, :S], rtol=2e-4, atol=2e-6)
+    padded = jnp.concatenate([u[:S], jnp.ones((2 * bs - S, cfg.d_model))])
+    out, pk, pv = nh.attention_prefill(padded, p, cfg, pk, pv, 0, table, jnp.arange(2 * bs), bs)
+    np.testing.assert_allclose(out[:S], want[0, :S], rtol=2e-4, atol=2e-6)
     tables = jnp.stack([table, jnp.zeros(4, jnp.int32)])
     positions = jnp.asarray([S, 0])
     for interpret in (False, True):

@@ -211,7 +211,7 @@ def test_a_chunk_sees_the_rows_and_the_state_the_chunks_before_it_left(tiny):
     first, _ = _run_prefill(prefill, params, toks, 32, table, paged.init_block_pool(cfg, 6, bs, 2), 1, chunk=32)
 
     def second(pool, table=table):
-        t = jnp.asarray(toks[None, 32:40])
+        t = jnp.zeros((1, bs), jnp.int32).at[0, :8].set(toks[32:40])  # a bucket of one whole block
         return prefill(params, t, jnp.asarray(8), jnp.asarray(32), jnp.asarray(table), pool, slot=jnp.asarray(1))[1]
 
     right = second(first)

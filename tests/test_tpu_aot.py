@@ -512,6 +512,15 @@ def test_mimo_v25s_served_programs_compile_for_one_chip(v5e_2x2, program):
         # compiler formed six of the seven twice: PERF.md section 6, PR 50).
         products = re.findall(r"%([\w.\-]+) = bf16\[2048,12288\]\S* fusion\(", text)
         assert len(products) == 7 and not [name for name in products if "remat" in name]
+        # The chunk's keys and values go into the pool a block an update (PR
+        # 52): two scatters a layer whose window is a block's [KH, 16, lanes],
+        # into the donated pool where it lies; no part of the pool is copied.
+        # (Fifteen: the compiler forms the first window layer's values' write
+        # twice over the same input, as it did the parent's row scatter.)
+        part = r"= bf16\[[25],\d+,[48],16,(?:128|256)\]\S* "
+        writes = re.findall(part + r"scatter\(.*update_window_dims=\{1,2,3\}, inserted_window_dims=\{0,1\}", text)
+        assert len(writes) in (14, 15) and " scatter(" not in re.sub(part + r"scatter\(", "", text)
+        assert not re.findall(part + r"copy\(", text)
     else:
         compiled = jax.jit(
             functools.partial(paged.paged_decode, cfg=cfg, block_size=bs), donate_argnums=4
@@ -905,28 +914,32 @@ def test_granite_hybrids_served_programs_compile_for_one_chip(v5e_2x2, program):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.5e9  # of the chip's 16 GB
 
 
-# sha256 (16 hex digits) of each accepted cell's programs at the commit before
-# PR 51 (11518c6): the decode program's and the largest prefill's lowered text
-# with the Mosaic calls' serialized bodies cut out (a body carries its source's
-# path and lines), and the decode program's jaxpr with source locations cut.
+# sha256 (16 hex digits) of each accepted cell's decode program at the commit
+# before PR 51 (11518c6), which PR 52 (a prefill's block writes) left as it
+# was: its lowered text with the Mosaic calls' serialized bodies cut out (a
+# body carries its source's path and lines), and its jaxpr with source
+# locations cut. Behind them the cell's attention layers: the largest prefill
+# writes two pool tensors a layer, a block an update.
 _LOWERED_AT_THE_PARENT = {
-    "serve-batch-mistral7b": ("7a69735a4fd87361", "8aeffec5bc4a2fc4", "5279d828fbfe3f1a"),
-    "serve-chat-nemotron3super": ("cfd666b203792e2c", "9e2112067440a7fd", "cc31f47ee931a05b"),
-    "serve-longdoc-solaropen2": ("71beb01763a3e6ea", "3cecb92187c72542", "7aac897d5b9e2c4c"),
-    "serve-mixed-trinity": ("c6f68ddfe10caf2d", "550842580db6067c", "b0ee041c9bd3b01f"),
-    "serve-longdoc-mimov25": ("e97122a8ecb4d59b", "38627766ccf14454", "f23ad7ee61bc330e"),
+    "serve-batch-mistral7b": ("7a69735a4fd87361", "5279d828fbfe3f1a", 1),  # one body, scanned
+    "serve-chat-nemotron3super": ("cfd666b203792e2c", "cc31f47ee931a05b", 1),
+    "serve-longdoc-solaropen2": ("71beb01763a3e6ea", "7aac897d5b9e2c4c", 1),
+    "serve-mixed-trinity": ("c6f68ddfe10caf2d", "b0ee041c9bd3b01f", 5),
+    "serve-longdoc-mimov25": ("e97122a8ecb4d59b", "f23ad7ee61bc330e", 7),
 }
 
 
 @pytest.mark.parametrize("cell", sorted(_LOWERED_AT_THE_PARENT))
 def test_an_accepted_cells_programs_lower_as_before_scales_and_packed_heads(v5e_2x2, cell):
-    """Mistral's, Nemotron's, Solar's, Trinity's and MiMo's served programs,
+    """Mistral's, Nemotron's, Solar's, Trinity's and MiMo's decode programs,
     built as the benchmark builds them and lowered for the chip, are what they
-    were before a kind could state its scale or pack its heads: the same
-    lowered text outside the kernels' bodies, and the same jaxpr, kernels'
-    bodies included. (An edit to ``ops/paged_attention.py`` re-keys the Mosaic
-    calls made from it all the same, by the lines a body carries: PERF.md
-    section 6, PR 39.)"""
+    were before a kind could state its scale or pack its heads and before a
+    prefill wrote whole blocks: the same lowered text outside the kernels'
+    bodies, and the same jaxpr, kernels' bodies included. (An edit to
+    ``ops/paged_attention.py`` re-keys the Mosaic calls made from it all the
+    same, by the lines a body carries: PERF.md section 6, PR 39.) The cell's
+    largest prefill holds no scatter of a row of a head any more: two block
+    scatters an attention layer, none told that its block ids are unique."""
     import hashlib
 
     from benchmarks import harness
@@ -960,9 +973,14 @@ def test_an_accepted_cells_programs_lower_as_before_scales_and_packed_heads(v5e_
     def outside_the_bodies(lowered):
         return digest(re.sub(r'(body\\22: \\22)[A-Za-z0-9+/=]+', r"\1", lowered.as_text()))
 
-    got = (
+    *decode_at_the_parent, attention_layers = _LOWERED_AT_THE_PARENT[cell]
+    got = [
         outside_the_bodies(jax.jit(decode, donate_argnums=4).lower(*d_args, **live)),
-        outside_the_bodies(jax.jit(prefill, donate_argnums=5).lower(*p_args, slot=sds((), i32))),
         digest(re.sub(r" at [^ \n]*:\d+", "", str(jax.make_jaxpr(decode)(*d_args, **live)))),
-    )
-    assert got == _LOWERED_AT_THE_PARENT[cell]
+    ]
+    assert got == decode_at_the_parent
+    text = jax.jit(prefill, donate_argnums=5).lower(*p_args, slot=sds((), i32)).as_text()
+    by_block = "update_window_dims = [1, 2, 3], inserted_window_dims = [0, 1], scatter_dims_to_operand_dims = [0, 1]"
+    writes = [line for line in text.splitlines() if by_block in line]
+    assert len(writes) == 2 * attention_layers and all("unique_indices = false" in w for w in writes)
+    assert "inserted_window_dims = [0, 1, 2, 3]" not in text  # a row of a head an update
