@@ -54,6 +54,7 @@ from ray_tpu.llm.block_manager import BlockManager, WindowBlocks
 from ray_tpu.llm.config import LLMConfig, SamplingParams
 from ray_tpu.llm.tokenizer import ByteTokenizer
 from ray_tpu.models import latent_moe, paged
+from ray_tpu.models.common import stage
 from ray_tpu.util import flightrec as _flightrec
 from ray_tpu.util import metrics as _metrics
 from ray_tpu.util.prefix_digest import BYTE_BOS_SCHEME, chain_digests
@@ -332,9 +333,10 @@ class LLMEngine:
                 params, tokens, meta[0], meta[1], table, pool,
                 cfg=cfg, block_size=bs, slot=meta[2],
             )
-            return pool, jnp.concatenate(
-                [logits, *(c.reshape(-1).astype(logits.dtype) for c in counts)]
-            )
+            with stage("embed_head"):
+                return pool, jnp.concatenate(
+                    [logits, *(c.reshape(-1).astype(logits.dtype) for c in counts)]
+                )
 
         self._pg_prefill = jax.jit(paged_prefill, donate_argnums=3)
 
@@ -348,15 +350,17 @@ class LLMEngine:
             # step. The choice is the program's too: argmax over float32,
             # the first index on ties, as np.argmax; a family's counters
             # ride behind the tokens, in the one small array a turn reads.
-            tokens = jnp.where(meta[:, 3] > 0, meta[:, 2], prev[: meta.shape[0]])
+            with stage("embed_head"):
+                tokens = jnp.where(meta[:, 3] > 0, meta[:, 2], prev[: meta.shape[0]])
             tables = meta[:, 4:] if len(kinds) == 1 else meta[:, 4:].reshape(-1, len(kinds), W)
             pool, logits, *counts = paged.paged_decode(
                 params, tokens, meta[:, 0], tables, pool,
                 cfg=cfg, block_size=bs, live=meta[:, 1] > 0, mesh=self.mesh,
             )
-            behind = [c.reshape(-1).astype(jnp.int32) for c in counts]
-            chosen = jnp.argmax(logits.astype(jnp.float32), axis=-1)
-            return pool, logits, jnp.concatenate([chosen.astype(jnp.int32), *behind])
+            with stage("embed_head"):
+                behind = [c.reshape(-1).astype(jnp.int32) for c in counts]
+                chosen = jnp.argmax(logits.astype(jnp.float32), axis=-1)
+                return pool, logits, jnp.concatenate([chosen.astype(jnp.int32), *behind])
 
         self._pg_decode = jax.jit(paged_decode, donate_argnums=3)
         # The decode step that has been launched and not read (step()),

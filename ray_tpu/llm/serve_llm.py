@@ -67,9 +67,23 @@ class LLMServer:
         # still had work (None once it ran dry): the next step's entry
         # closes the flight recorder's llm.pump_gap span from it.
         self._t_step_returned: float | None = None
+        # Monotonic time the last step of a pump that ran dry returned (None
+        # while a pump runs): the start of the flight recorder's llm.idle
+        # span, which the start of the next pump closes.
+        self._t_ran_dry: float | None = None
 
     def _ensure_pump(self) -> None:
         if self._pump_task is None or self._pump_task.done():
+            if self._t_ran_dry is not None and _flightrec.on():
+                # The dry spell: nothing to serve from the last step's return
+                # until now. With llm.step and llm.pump_gap it tiles the
+                # engine's life, so a device that idles under it idles for
+                # want of a request and not for a late host.
+                _flightrec.record(
+                    "llm", "llm.idle", t=self._t_ran_dry,
+                    dur_s=time.monotonic() - self._t_ran_dry,
+                )
+            self._t_ran_dry = None
             self._pump_task = spawn(self._pump(), name="llm engine pump")
 
     def _step_with_admissions(self) -> list:
@@ -160,7 +174,8 @@ class LLMServer:
                 )
             with self._pending_lock:
                 if not more and not self._pending:
-                    self._t_step_returned = None  # ran dry: no gap to name
+                    # ran dry: no gap to name, and llm.idle starts here
+                    self._t_ran_dry, self._t_step_returned = self._t_step_returned, None
                     return
 
     def _admit(

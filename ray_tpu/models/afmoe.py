@@ -49,7 +49,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models import latent_moe, paged
 from ray_tpu.models.latent_moe import final_logits, moe_ffn, outputs
-from ray_tpu.models.common import _rms_norm
+from ray_tpu.models.common import _rms_norm, stage
 
 Params = dict
 _F32 = jnp.float32
@@ -270,6 +270,7 @@ def _rotate(t, rope):
     return jnp.concatenate([t1 * cos - t2 * sin, t1 * sin + t2 * cos], axis=-1).astype(t.dtype)
 
 
+@stage("attn_proj")
 def _qkvg(a, p, cfg: AfmoeConfig, rope):
     """``a`` [..., D] normed -> ``(q [..., KH, group, Dh], k, v [..., KH, Dh], g
     [..., H Dh])``: ``q`` and ``k`` normed a head, and rotated where ``rope``
@@ -285,6 +286,7 @@ def _qkvg(a, p, cfg: AfmoeConfig, rope):
     return q.reshape(*lead, KH, H // KH, Dh), k, v, a @ p["wg"].astype(dt)
 
 
+@stage("attn_proj")
 def _gated_out(x, o, g, p, cfg: AfmoeConfig):
     """``x + RMSNorm(W_o (o sigmoid(g)))``: ``o`` [..., KH, group, Dh]."""
     o = o.reshape(g.shape)
@@ -296,13 +298,17 @@ def _ffn(x, p, cfg: AfmoeConfig, layer: int, valid, seen: list):
     """The feed-forward sublayer between its two norms, with its residual;
     an expert layer's counts and picks are appended to ``seen``."""
     dt = cfg.dtype
-    m = _rms_norm(x, p["pre_mlp_norm"], cfg.rms_eps)
+    around = "experts" if cfg.is_moe(layer) else "mlp"  # the sandwich norms and the residual
+    with stage(around):
+        m = _rms_norm(x, p["pre_mlp_norm"], cfg.rms_eps)
     if cfg.is_moe(layer):
         f, counts, picks = moe_ffn(m, p, cfg, valid)
         seen.append((counts, picks))
     else:
-        f = (jax.nn.silu(m @ p["w_gate"].astype(dt)) * (m @ p["w_up"].astype(dt))) @ p["w_down"].astype(dt)
-    return x + _rms_norm(f, p["post_mlp_norm"], cfg.rms_eps)
+        with stage("mlp"):
+            f = (jax.nn.silu(m @ p["w_gate"].astype(dt)) * (m @ p["w_up"].astype(dt))) @ p["w_down"].astype(dt)
+    with stage(around):
+        return x + _rms_norm(f, p["post_mlp_norm"], cfg.rms_eps)
 
 
 def _layers(params, cfg: AfmoeConfig):
@@ -369,14 +375,17 @@ def paged_prefill(
     tables = _by_kind(table if table.ndim == 2 else jnp.stack([table, table]))
     pos = start + jnp.arange(T, dtype=jnp.int32)
     valid = jnp.arange(T) < length
-    rope = _rope(cfg, pos)
-    x = params["wte"].astype(cfg.dtype)[tokens[0]]
-    if cfg.mup:
-        x = x * jnp.asarray(cfg.d_model**0.5, cfg.dtype)
+    with stage("attn_proj"):
+        rope = _rope(cfg, pos)
+    with stage("embed_head"):
+        x = params["wte"].astype(cfg.dtype)[tokens[0]]
+        if cfg.mup:
+            x = x * jnp.asarray(cfg.d_model**0.5, cfg.dtype)
     pool = {part: dict(kv) for part, kv in pool.items()}
     seen: list = []
     for layer, p, part, l, window in _layers(params, cfg):
-        a = _rms_norm(x, p["in_norm"], cfg.rms_eps)
+        with stage("attn_proj"):
+            a = _rms_norm(x, p["in_norm"], cfg.rms_eps)
         q, k, v, g = _qkvg(a, p, cfg, rope if window else None)
         tab, kv = tables[part], pool[part]
         kv["k"] = paged._write_blocks(kv["k"], l, tab, start, k, block_size)
@@ -386,7 +395,8 @@ def paged_prefill(
         )
         x = _gated_out(x, o, g, p, cfg)
         x = _ffn(x, p, cfg, layer, valid, seen)
-    last = jax.lax.dynamic_index_in_dim(x, (length - 1).astype(jnp.int32), 0, keepdims=False)
+    with stage("embed_head"):
+        last = jax.lax.dynamic_index_in_dim(x, (length - 1).astype(jnp.int32), 0, keepdims=False)
     logits = final_logits(params, last[None], cfg)[0]
     return outputs(pool, logits, seen, with_picks)
 
@@ -414,23 +424,30 @@ def paged_decode(
             paged.attention_kind(cfg, cfg.sliding_window), block_size, None, interpret
         ),
     }
-    rows = jnp.arange(B)
-    offs = positions % block_size
-    lengths = positions + 1  # the step's own key is attended
-    rope = _rope(cfg, positions)
-    x = params["wte"].astype(cfg.dtype)[last_tokens]
-    if cfg.mup:
-        x = x * jnp.asarray(cfg.d_model**0.5, cfg.dtype)
+    with stage("pool_write"):
+        rows = jnp.arange(B)
+        offs = positions % block_size
+    with stage("attn_core"):
+        lengths = positions + 1  # the step's own key is attended
+    with stage("attn_proj"):
+        rope = _rope(cfg, positions)
+    with stage("embed_head"):
+        x = params["wte"].astype(cfg.dtype)[last_tokens]
+        if cfg.mup:
+            x = x * jnp.asarray(cfg.d_model**0.5, cfg.dtype)
     pool = {part: dict(kv) for part, kv in pool.items()}
     seen: list = []
     for layer, p, part, l, window in _layers(params, cfg):
-        a = _rms_norm(x, p["in_norm"], cfg.rms_eps)
+        with stage("attn_proj"):
+            a = _rms_norm(x, p["in_norm"], cfg.rms_eps)
         q, k, v, g = _qkvg(a, p, cfg, rope if window else None)
         tab, kv = tables[part], pool[part]
-        bids = tab[rows, positions // block_size]
+        with stage("pool_write"):
+            bids = tab[rows, positions // block_size]
         kv["k"] = paged._write(kv["k"], l, bids, offs, k)
         kv["v"] = paged._write(kv["v"], l, bids, offs, v)
-        o = attend[part](q, kv["k"], kv["v"], jnp.asarray(l, jnp.int32), tab, lengths)
+        with stage("attn_core"):
+            o = attend[part](q, kv["k"], kv["v"], jnp.asarray(l, jnp.int32), tab, lengths)
         x = _gated_out(x, o, g, p, cfg)
         x = _ffn(x, p, cfg, layer, live, seen)
     return outputs(pool, final_logits(params, x, cfg), seen, with_picks)

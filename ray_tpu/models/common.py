@@ -15,8 +15,58 @@ Contracts are family-neutral:
 
 from __future__ import annotations
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
+
+# The stages of a device program, one vocabulary for every family's prefill
+# and decode programs and for the train step: what a reader of a device trace
+# (benchmarks/stage_time.py) files an operation's time under. PERF.md section
+# 3 says which functions open which.
+STAGES = (
+    "attn_proj",  # q/k/v/gate projections, head norms, rotation, MLA's low-rank pairs and absorbs, W_o
+    "attn_core",  # scores and the weighted sum over the table: kernel, fold or gather, the sink
+    "pool_write",  # rows or blocks of keys, values or latent rows into the pool
+    "state_in",  # a recurrent mixer up to its scan: in-projection, convolution, forming q,k,v,g,beta / B,C,dt
+    "state_scan",  # the chunked scan or the state step, the slot's state and tail in and out
+    "state_out",  # norm, gate and out-projection behind the scan
+    "router",  # of an expert layer, all that is no grouped product: scores, top-k, sort, un-sort, combine
+    "experts",  # grouped products, activation, latent in/out, the shared expert
+    "mlp",  # a dense feed-forward
+    "embed_head",  # embedding gather, final norm, logits, argmax, the packed counters, the loss
+    "optimizer",  # the train step's update
+)
+STAGE_PREFIX = "st."
+
+
+class stage(contextlib.ContextDecorator):
+    """``jax.named_scope`` of one of :data:`STAGES`, as a context manager or
+    a decorator: every operation traced under it carries ``st.<name>`` in the
+    ``op_name`` of its HLO metadata (``jit(paged_prefill)/.../st.mlp/dot_general``,
+    and ``transpose(jvp(st.mlp))`` in a backward pass), which reaches the
+    device trace. Metadata only: no instruction of a compiled program changes.
+    A layer norm goes with the stage it feeds, a residual add with the stage
+    that made the branch. Stages are not nested: an operation carries one.
+
+    A decorated function opens a scope of its own a call (jax's scope object
+    keeps the name stack it replaced on itself, so one object shared by every
+    call would be neither re-entrant nor safe under two tracing threads)."""
+
+    def __init__(self, name: str):
+        if name not in STAGES:
+            raise ValueError(f"{name!r} is no stage of the vocabulary {STAGES}")
+        self.name = name
+
+    def _recreate_cm(self):
+        return jax.named_scope(STAGE_PREFIX + self.name)
+
+    def __enter__(self):
+        self._scope = self._recreate_cm()
+        return self._scope.__enter__()
+
+    def __exit__(self, *exc):
+        return self._scope.__exit__(*exc)
 
 
 def _rms_norm(x, scale, eps):
@@ -91,7 +141,7 @@ def pipelined_blocks(blocks, x, block_fn, mesh, *, n_micro):
             f"pipeline stages (pp mesh axis)"
         )
 
-    def stage(blocks_local, x_mb):
+    def run_stage(blocks_local, x_mb):
         out, aux_layers = jax.lax.scan(block_fn, x_mb, blocks_local)
         return out, jnp.sum(aux_layers)
 
@@ -119,7 +169,7 @@ def pipelined_blocks(blocks, x, block_fn, mesh, *, n_micro):
             # Stage 0 feeds microbatch t (clamped; late steps are bubble).
             feed = xs[jnp.minimum(t, n_micro - 1)]
             inp = jnp.where(idx == 0, feed, recv)
-            out, aux_mb = stage(blocks_local, inp)
+            out, aux_mb = run_stage(blocks_local, inp)
             # Aux counts only GENUINE microbatch steps for this stage
             # (stage s holds microbatch t-s at step t); bubble steps
             # process clamped duplicates and must not contribute.

@@ -27,7 +27,7 @@ from typing import Any, ClassVar
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.common import chunked_lm_loss, pipelined_blocks
+from ray_tpu.models.common import chunked_lm_loss, pipelined_blocks, stage
 from ray_tpu.ops.attention import causal_attention, uses_flash_kernel
 
 # Back-compat aliases (pre-round-4 private names)
@@ -208,6 +208,7 @@ def _layer_norm(x, scale, bias, eps=1e-5):
     return (y * scale + bias).astype(x.dtype)
 
 
+@stage("attn_proj")
 def _qkv(x, p, cfg: GPT2Config):
     """x [B, T, D] -> q, k, v, each [B, H, T, Dh]."""
     B, T, D = x.shape
@@ -221,6 +222,7 @@ def _qkv(x, p, cfg: GPT2Config):
     return heads(q), heads(k), heads(v)
 
 
+@stage("attn_proj")
 def _attn_out(x, attn, p, cfg: GPT2Config):
     """attn [B, H, T, Dh] through the output projection, onto x."""
     B, H, T, Dh = attn.shape
@@ -230,26 +232,28 @@ def _attn_out(x, attn, p, cfg: GPT2Config):
 
 def _attn_sublayer(x, p, cfg: GPT2Config, mesh=None, ring=False):
     q, k_, v = _qkv(x, p, cfg)
-    if ring:
-        # Sequence sharded over sp: ring attention keeps K/V distributed
-        # and rotates chunks over ICI instead of letting XLA re-gather the
-        # full sequence per chip (SURVEY §5.7 — must-build).
-        from ray_tpu.ops.ring_attention import ring_attention
+    with stage("attn_core"):
+        if ring:
+            # Sequence sharded over sp: ring attention keeps K/V distributed
+            # and rotates chunks over ICI instead of letting XLA re-gather the
+            # full sequence per chip (SURVEY §5.7 — must-build).
+            from ray_tpu.ops.ring_attention import ring_attention
 
-        attn = ring_attention(q, k_, v, mesh=mesh)
-    else:
-        attn = causal_attention(
-            q,
-            k_,
-            v,
-            impl=cfg.attn_impl,
-            block_q=cfg.attn_block_q,
-            block_k=cfg.attn_block_k,
-            mesh=mesh,
-        )
+            attn = ring_attention(q, k_, v, mesh=mesh)
+        else:
+            attn = causal_attention(
+                q,
+                k_,
+                v,
+                impl=cfg.attn_impl,
+                block_q=cfg.attn_block_q,
+                block_k=cfg.attn_block_k,
+                mesh=mesh,
+            )
     return _attn_out(x, attn, p, cfg)
 
 
+@stage("mlp")
 def _mlp_sublayer(x, p, cfg: GPT2Config):
     h = _layer_norm(x, p["ln2_scale"], p["ln2_bias"])
     h = h @ p["fc_w"].astype(cfg.dtype) + p["fc_b"].astype(cfg.dtype)
@@ -263,6 +267,7 @@ def kv_hooks(cfg: GPT2Config, S: int):
     query head, the dense MLP."""
     H, Dh = cfg.n_head, cfg.head_dim
 
+    @stage("embed_head")
     def embed(params, tokens, pos2d):
         return (
             params["wte"].astype(cfg.dtype)[tokens]
@@ -275,6 +280,7 @@ def kv_hooks(cfg: GPT2Config, S: int):
     def finish(x, attn, p):
         return _mlp_sublayer(_attn_out(x, attn, p, cfg), p, cfg)
 
+    @stage("embed_head")
     def final(params, last):
         h = _layer_norm(last, params["lnf_scale"], params["lnf_bias"])
         return (h @ params["wte"].astype(cfg.dtype).T).astype(jnp.float32)
@@ -298,41 +304,43 @@ def _moe_sublayer(x, p, cfg: GPT2Config):
     # ep-sharded einsums (and their backward) produce; compute the expert
     # path in f32 on CPU (virtual-mesh tests/dryrun). Real TPUs keep bf16.
     cdt = jnp.float32 if jax.default_backend() == "cpu" else cfg.dtype
-    h = _layer_norm(x, p["ln2_scale"], p["ln2_bias"])
-    hf = h.reshape(B * S, D).astype(cdt)
     N = B * S
     cap = max(int(cfg.expert_capacity_factor * N / E), 1)
 
-    logits = (hf @ p["gate_w"].astype(cdt)).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)  # [N, E]
-    gate = jnp.max(probs, axis=-1)
-    expert = jnp.argmax(probs, axis=-1)
-    onehot = jax.nn.one_hot(expert, E, dtype=jnp.float32)  # [N, E]
-    # Switch load-balancing auxiliary loss: E * sum_e f_e * P_e, where f is
-    # the (pre-capacity) routed fraction and P the mean router probability.
-    # Minimized at uniform routing; without it top-1 collapses.
-    aux = E * jnp.sum(
-        jnp.mean(onehot, axis=0) * jnp.mean(probs, axis=0)
-    )
-    pos = (jnp.cumsum(onehot, axis=0) - 1.0) * onehot
-    onehot = onehot * (pos < cap)  # over-capacity tokens dropped
-    dispatch = onehot[..., None] * jax.nn.one_hot(
-        pos.astype(jnp.int32), cap, dtype=jnp.float32
-    )  # [N, E, C]
-    combine = dispatch * gate[:, None, None]
-
-    xe = jnp.einsum("nd,nec->ecd", hf, dispatch.astype(cdt))
-    he = jnp.einsum("ecd,edf->ecf", xe, p["exp_w1"].astype(cdt))
-    he = jax.nn.gelu(
-        he + p["exp_b1"].astype(cdt)[:, None, :], approximate=True
-    )
-    ye = jnp.einsum("ecf,efd->ecd", he, p["exp_w2"].astype(cdt))
-    y = jnp.einsum("ecd,nec->nd", ye, combine.astype(cdt))
-    # Output bias only for tokens an expert actually served — dropped
-    # (over-capacity) tokens pass through the residual truly unchanged.
-    routed = jnp.sum(onehot, axis=-1, keepdims=True).astype(cdt)  # [N, 1]
-    y = y + p["exp_b2"].astype(cdt) * routed
-    return x + y.reshape(B, S, D).astype(x.dtype), aux
+    with stage("router"):
+        h = _layer_norm(x, p["ln2_scale"], p["ln2_bias"])
+        hf = h.reshape(B * S, D).astype(cdt)
+        logits = (hf @ p["gate_w"].astype(cdt)).astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)  # [N, E]
+        gate = jnp.max(probs, axis=-1)
+        expert = jnp.argmax(probs, axis=-1)
+        onehot = jax.nn.one_hot(expert, E, dtype=jnp.float32)  # [N, E]
+        # Switch load-balancing auxiliary loss: E * sum_e f_e * P_e, where f is
+        # the (pre-capacity) routed fraction and P the mean router probability.
+        # Minimized at uniform routing; without it top-1 collapses.
+        aux = E * jnp.sum(
+            jnp.mean(onehot, axis=0) * jnp.mean(probs, axis=0)
+        )
+        pos = (jnp.cumsum(onehot, axis=0) - 1.0) * onehot
+        onehot = onehot * (pos < cap)  # over-capacity tokens dropped
+        dispatch = onehot[..., None] * jax.nn.one_hot(
+            pos.astype(jnp.int32), cap, dtype=jnp.float32
+        )  # [N, E, C]
+        combine = dispatch * gate[:, None, None]
+        xe = jnp.einsum("nd,nec->ecd", hf, dispatch.astype(cdt))
+    with stage("experts"):
+        he = jnp.einsum("ecd,edf->ecf", xe, p["exp_w1"].astype(cdt))
+        he = jax.nn.gelu(
+            he + p["exp_b1"].astype(cdt)[:, None, :], approximate=True
+        )
+        ye = jnp.einsum("ecf,efd->ecd", he, p["exp_w2"].astype(cdt))
+    with stage("router"):
+        y = jnp.einsum("ecd,nec->nd", ye, combine.astype(cdt))
+        # Output bias only for tokens an expert actually served — dropped
+        # (over-capacity) tokens pass through the residual truly unchanged.
+        routed = jnp.sum(onehot, axis=-1, keepdims=True).astype(cdt)  # [N, 1]
+        y = y + p["exp_b2"].astype(cdt) * routed
+        return x + y.reshape(B, S, D).astype(x.dtype), aux
 
 
 def _block(x, p, cfg: GPT2Config, mesh=None, ring=False):
@@ -367,8 +375,9 @@ def hidden(
         import dataclasses as _dc
 
         cfg = _dc.replace(cfg, dtype=jnp.float32)
-    x = params["wte"].astype(cfg.dtype)[tokens]
-    x = x + params["wpe"].astype(cfg.dtype)[:S][None]
+    with stage("embed_head"):
+        x = params["wte"].astype(cfg.dtype)[tokens]
+        x = x + params["wpe"].astype(cfg.dtype)[:S][None]
 
     remat = {True: "full", False: "none"}.get(cfg.remat, cfg.remat)
     if remat == "mlp" and cfg.n_experts > 0:
@@ -436,7 +445,8 @@ def hidden(
     else:
         x, aux_layers = jax.lax.scan(scan_body, x, params["blocks"])
         aux = jnp.sum(aux_layers)
-    return _layer_norm(x, params["lnf_scale"], params["lnf_bias"]), aux
+    with stage("embed_head"):
+        return _layer_norm(x, params["lnf_scale"], params["lnf_bias"]), aux
 
 
 
@@ -446,7 +456,8 @@ def forward(
     """tokens [B, S] int32 -> logits [B, S, vocab] (activation dtype).
     Tied embeddings: logits = x @ wte^T (vocab-parallel under tp rules)."""
     x, _aux = hidden(params, tokens, cfg, mesh=mesh)
-    return x @ params["wte"].astype(cfg.dtype).T
+    with stage("embed_head"):
+        return x @ params["wte"].astype(cfg.dtype).T
 
 
 
@@ -461,27 +472,28 @@ def loss_fn(
     else:
         inputs, targets = tokens[:, :-1], tokens[:, 1:]
     x, moe_aux = hidden(params, inputs, cfg, mesh=mesh)
-    if cfg.loss_chunk and inputs.shape[1] > cfg.loss_chunk:
-        total = chunked_lm_loss(
-            x,
-            params["wte"].astype(cfg.dtype),
-            targets,
-            cfg.loss_chunk,
-        )
-        ce = total / targets.size
-    else:
-        logits = (x @ params["wte"].astype(cfg.dtype).T).astype(jnp.float32)
-        # Cross-entropy as logsumexp - target_logit: both reduce over
-        # vocab, so XLA fuses the f32 upcast into the reductions and never
-        # materializes an f32 [B, S, vocab] log-prob tensor.
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-        ce = jnp.mean(lse - tgt)
-    loss = ce
-    metrics = {"loss": ce, "tokens": jnp.array(targets.size, jnp.int32)}
-    if cfg.n_experts > 0:
-        loss = ce + cfg.moe_aux_weight * moe_aux
-        metrics["moe_aux"] = moe_aux
+    with stage("embed_head"):
+        if cfg.loss_chunk and inputs.shape[1] > cfg.loss_chunk:
+            total = chunked_lm_loss(
+                x,
+                params["wte"].astype(cfg.dtype),
+                targets,
+                cfg.loss_chunk,
+            )
+            ce = total / targets.size
+        else:
+            logits = (x @ params["wte"].astype(cfg.dtype).T).astype(jnp.float32)
+            # Cross-entropy as logsumexp - target_logit: both reduce over
+            # vocab, so XLA fuses the f32 upcast into the reductions and never
+            # materializes an f32 [B, S, vocab] log-prob tensor.
+            lse = jax.scipy.special.logsumexp(logits, axis=-1)
+            tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+            ce = jnp.mean(lse - tgt)
+        loss = ce
+        metrics = {"loss": ce, "tokens": jnp.array(targets.size, jnp.int32)}
+        if cfg.n_experts > 0:
+            loss = ce + cfg.moe_aux_weight * moe_aux
+            metrics["moe_aux"] = moe_aux
     return loss, metrics
 
 

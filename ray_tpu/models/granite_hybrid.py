@@ -46,7 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import nemotron_h, paged
-from ray_tpu.models.common import _rms_norm
+from ray_tpu.models.common import _rms_norm, stage
 
 Params = dict
 _F32 = jnp.float32
@@ -233,11 +233,15 @@ def attention_prefill(u, p, cfg: GraniteHybridConfig, kv, l, table, pos, block_s
     KH, Dh = cfg.n_kv_head, cfg.head_dim
     S = table.shape[0] * block_size
     q, k, v = nemotron_h._qkv(u, p, cfg)
-    row = jnp.concatenate([v, k], axis=-1)
+    with stage("attn_proj"):
+        row = jnp.concatenate([v, k], axis=-1)
     kv, rows = paged._write_blocks_read(kv, l, table, pos[0], row, block_size)
-    rows = rows.transpose(1, 0, 2, 3).reshape(KH, S, 2 * Dh)
-    o = nemotron_h.causal_attention(q, rows[..., Dh:], rows[..., :Dh], pos, cfg, cfg.attention_multiplier)
-    return o @ p["wo"].astype(cfg.dtype), kv
+    with stage("attn_core"):
+        rows = rows.transpose(1, 0, 2, 3).reshape(KH, S, 2 * Dh)
+        keys, values = rows[..., Dh:], rows[..., :Dh]
+    o = nemotron_h.causal_attention(q, keys, values, pos, cfg, cfg.attention_multiplier)
+    with stage("attn_proj"):
+        return o @ p["wo"].astype(cfg.dtype), kv
 
 
 def attention_decode(u, p, cfg: GraniteHybridConfig, kv, l, tables, positions, block_size, attend):
@@ -247,12 +251,18 @@ def attention_decode(u, p, cfg: GraniteHybridConfig, kv, l, tables, positions, b
     ``(out [B, D], kv)``."""
     B = u.shape[0]
     q, k, v = nemotron_h._qkv(u, p, cfg)
-    bids = tables[jnp.arange(B), positions // block_size]
-    kv = paged._write(kv, l, bids, positions % block_size, jnp.concatenate([v, k], axis=-1))
-    o = attend(q, kv, jnp.asarray(l, jnp.int32), tables, positions + 1).reshape(B, -1)
-    return o @ p["wo"].astype(cfg.dtype), kv
+    with stage("pool_write"):
+        bids, offs = tables[jnp.arange(B), positions // block_size], positions % block_size
+    with stage("attn_proj"):
+        row = jnp.concatenate([v, k], axis=-1)
+    kv = paged._write(kv, l, bids, offs, row)
+    with stage("attn_core"):
+        o = attend(q, kv, jnp.asarray(l, jnp.int32), tables, positions + 1)
+    with stage("attn_proj"):
+        return o.reshape(B, -1) @ p["wo"].astype(cfg.dtype), kv
 
 
+@stage("mlp")
 def mlp(m, p, cfg: GraniteHybridConfig):
     """``W_down (silu(W_gate m) * W_up m)``, gate and up one matrix."""
     dt = cfg.dtype
@@ -260,6 +270,7 @@ def mlp(m, p, cfg: GraniteHybridConfig):
     return (jax.nn.silu(gate) * up) @ p["w_down"].astype(dt)
 
 
+@stage("embed_head")
 def final_logits(params, last, cfg: GraniteHybridConfig):
     """The tied head: ``RMSNorm(x) E^T / logits_scaling``, float32."""
     h = _rms_norm(last, params["final_norm"], cfg.rms_eps)
@@ -321,24 +332,29 @@ def _period(cfg: GraniteHybridConfig, mamba, attention):
     """The body of a period: each place's mixer (``mamba(u, p, pool, l)`` or
     ``attention(u, p, pool, l)`` -> ``(out, pool)``, ``l`` the layer's index
     among those of its kind) and its SwiGLU, each branch times the residual
-    multiplier, each part under its name in a device trace."""
+    multiplier. The mixers name their own stages; a norm goes with the stage
+    it feeds and a residual with the stage that made the branch."""
     n_m, n_a = cfg.period.count("mamba"), cfg.period.count("attention")
     r = cfg.residual_multiplier
 
     def body(x, pool, places, i):
         seen = {"mamba": 0, "attention": 0}
         for kind, p in zip(cfg.period, places):
-            u = _rms_norm(x, p["norm"], cfg.rms_eps)
-            with jax.named_scope(f"granite_{kind}"):
-                if kind == "mamba":
-                    out, pool = mamba(u, p, pool, i * n_m + seen[kind])
-                else:
-                    out, pool = attention(u, p, pool, i * n_a + seen[kind])
+            mixer_in, mixer_out = ("state_in", "state_out") if kind == "mamba" else ("attn_proj", "attn_proj")
+            with stage(mixer_in):
+                u = _rms_norm(x, p["norm"], cfg.rms_eps)
+            if kind == "mamba":
+                out, pool = mamba(u, p, pool, i * n_m + seen[kind])
+            else:
+                out, pool = attention(u, p, pool, i * n_a + seen[kind])
             seen[kind] += 1
-            x = x + (r * out).astype(x.dtype)
-            with jax.named_scope("granite_mlp"):
-                out = mlp(_rms_norm(x, p["mlp_norm"], cfg.rms_eps), p, cfg)
-            x = x + (r * out).astype(x.dtype)
+            with stage(mixer_out):
+                x = x + (r * out).astype(x.dtype)
+            with stage("mlp"):
+                m = _rms_norm(x, p["mlp_norm"], cfg.rms_eps)
+            out = mlp(m, p, cfg)
+            with stage("mlp"):
+                x = x + (r * out).astype(x.dtype)
         return x, pool
 
     return body
@@ -359,8 +375,11 @@ def paged_prefill(
 
     def mamba(u, p, pool, l):
         def step(h, tail):  # the slot's tail is one flat row (docstring of this module)
-            out, h, tail = nemotron_h.mamba_prefill(u, p, cfg, h, tail.reshape(-1, cfg.conv_dim), length)
-            return out, h, tail.reshape(-1)
+            with stage("state_scan"):
+                tail = tail.reshape(-1, cfg.conv_dim)
+            out, h, tail = nemotron_h.mamba_prefill(u, p, cfg, h, tail, length)
+            with stage("state_scan"):
+                return out, h, tail.reshape(-1)
 
         out, state, conv = paged.state_prefill(step, pool["state"], pool["conv"], l, slot, fresh)
         return out, {**pool, "state": state, "conv": conv}
@@ -369,9 +388,11 @@ def paged_prefill(
         out, kv = attention_prefill(u, p, cfg, pool["kv"], l, table, pos, block_size)
         return out, {**pool, "kv": kv}
 
-    x = params["wte"].astype(cfg.dtype)[tokens[0]] * jnp.asarray(cfg.embedding_multiplier, cfg.dtype)
+    with stage("embed_head"):
+        x = params["wte"].astype(cfg.dtype)[tokens[0]] * jnp.asarray(cfg.embedding_multiplier, cfg.dtype)
     x, pool = _walk(_period(cfg, mamba, attention), x, params, pool, cfg, unrolled)
-    last = jax.lax.dynamic_index_in_dim(x, (length - 1).astype(jnp.int32), 0, keepdims=False)
+    with stage("embed_head"):
+        last = jax.lax.dynamic_index_in_dim(x, (length - 1).astype(jnp.int32), 0, keepdims=False)
     return pool, final_logits(params, last[None], cfg)[0]
 
 
@@ -386,12 +407,16 @@ def paged_decode(
     float32)``."""
     B = last_tokens.shape[0]
     attend = paged.packed_decode_attention(attention_kind(cfg), block_size, None, interpret)
-    keep = None if live is None else ~live
+    with stage("state_scan"):
+        keep = None if live is None else ~live
 
     def mamba(u, p, pool, l):
         def step(h, tail):
-            out, h, tail = nemotron_h.mamba_decode(u, p, cfg, h, tail.reshape(B, -1, cfg.conv_dim))
-            return out, h, tail.reshape(B, -1)
+            with stage("state_scan"):
+                tail = tail.reshape(B, -1, cfg.conv_dim)
+            out, h, tail = nemotron_h.mamba_decode(u, p, cfg, h, tail)
+            with stage("state_scan"):
+                return out, h, tail.reshape(B, -1)
 
         out, state, conv = paged.state_decode(
             step, pool["state"], pool["conv"], l, B, keep, interpret=interpret
@@ -402,6 +427,7 @@ def paged_decode(
         out, kv = attention_decode(u, p, cfg, pool["kv"], l, tables, positions, block_size, attend)
         return out, {**pool, "kv": kv}
 
-    x = params["wte"].astype(cfg.dtype)[last_tokens] * jnp.asarray(cfg.embedding_multiplier, cfg.dtype)
+    with stage("embed_head"):
+        x = params["wte"].astype(cfg.dtype)[last_tokens] * jnp.asarray(cfg.embedding_multiplier, cfg.dtype)
     x, pool = _walk(_period(cfg, mamba, attention), x, params, pool, cfg, unrolled)
     return pool, final_logits(params, x, cfg)

@@ -13,7 +13,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.common import _rms_norm
+from ray_tpu.models.common import _rms_norm, stage
 from ray_tpu.ops import state_step
 from ray_tpu.ops.delta_rule import kda_chunked
 
@@ -68,6 +68,7 @@ def _kda_inputs(h, mixed, p, cfg):
     return l2(q) * d**-0.5, l2(k), v, g, beta
 
 
+@stage("state_out")
 def _kda_output(h, o, p, cfg):
     """RMSNorm per head, the sigmoid output gate, ``W_o``."""
     dt = cfg.dtype
@@ -85,13 +86,16 @@ def kda_prefill(h, p, cfg, S0, tail, length):
     touch the state."""
     T, K = h.shape[0], cfg.conv_kernel
     dt = cfg.dtype
-    x = jnp.concatenate([tail.astype(dt), h @ p["wqkv"].astype(dt)])  # [K-1+T, C]
-    conv = p["conv"].astype(dt)
-    mixed = sum(conv[j] * x[j : j + T] for j in range(K))
-    q, k, v, g, beta = _kda_inputs(h, jax.nn.silu(mixed), p, cfg)
-    live = (jnp.arange(T) < length)[:, None]
-    o, S = kda_chunked(q, k, v, g * live[..., None], beta * live, S0)
-    tail = jax.lax.dynamic_slice_in_dim(x, length, K - 1, axis=0)
+    with stage("state_in"):
+        x = jnp.concatenate([tail.astype(dt), h @ p["wqkv"].astype(dt)])  # [K-1+T, C]
+        conv = p["conv"].astype(dt)
+        mixed = sum(conv[j] * x[j : j + T] for j in range(K))
+        q, k, v, g, beta = _kda_inputs(h, jax.nn.silu(mixed), p, cfg)
+        live = (jnp.arange(T) < length)[:, None]
+        g, beta = g * live[..., None], beta * live
+    with stage("state_scan"):
+        o, S = kda_chunked(q, k, v, g, beta, S0)
+        tail = jax.lax.dynamic_slice_in_dim(x, length, K - 1, axis=0)
     return _kda_output(h, o, p, cfg), S, tail
 
 
@@ -100,8 +104,12 @@ def kda_decode(h, p, cfg, S, tail):
     where they lie, a :class:`ray_tpu.ops.state_step.Rows`), ``tail`` [B,
     K-1, 3 H d]. Returns ``(out [B, D], S, tail)``."""
     dt = cfg.dtype
-    x = jnp.concatenate([tail.astype(dt), (h @ p["wqkv"].astype(dt))[:, None]], axis=1)
-    mixed = jnp.einsum("kc,bkc->bc", p["conv"].astype(dt), x)
-    q, k, v, g, beta = _kda_inputs(h, jax.nn.silu(mixed), p, cfg)
-    o, S = state_step.kda(q, k, v, g, beta, S)
-    return _kda_output(h, o, p, cfg), S, x[:, 1:]
+    with stage("state_in"):
+        x = jnp.concatenate([tail.astype(dt), (h @ p["wqkv"].astype(dt))[:, None]], axis=1)
+        mixed = jnp.einsum("kc,bkc->bc", p["conv"].astype(dt), x)
+        q, k, v, g, beta = _kda_inputs(h, jax.nn.silu(mixed), p, cfg)
+    with stage("state_scan"):
+        o, S = state_step.kda(q, k, v, g, beta, S)
+    out = _kda_output(h, o, p, cfg)
+    with stage("state_scan"):
+        return out, S, x[:, 1:]

@@ -50,7 +50,7 @@ from ray_tpu.models.latent_moe import (  # noqa: F401 -- moe_ffn and route: the 
     outputs,
     route,
 )
-from ray_tpu.models.common import _rms_norm
+from ray_tpu.models.common import _rms_norm, stage
 
 Params = dict
 _F32 = jnp.float32
@@ -293,23 +293,32 @@ def paged_prefill(
 
     pos = start + jnp.arange(T, dtype=jnp.int32)
     valid = jnp.arange(T) < length
-    bids, offs = table[pos // block_size], pos % block_size
-    x = params["wte"].astype(cfg.dtype)[tokens[0]]
+    with stage("pool_write"):
+        bids, offs = table[pos // block_size], pos % block_size
+    with stage("embed_head"):
+        x = params["wte"].astype(cfg.dtype)[tokens[0]]
     seen: list = []
     for i, p, kind, l in _layers(params, cfg):
-        h = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
+        mixer_in, mixer_out = ("state_in", "state_out") if kind == "kda" else ("attn_proj", "attn_proj")
+        with stage(mixer_in):
+            h = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
         if kind == "kda":
             out, state, conv = paged.state_prefill(
                 lambda S, tail: kda_prefill(h, p, cfg, S, tail, length),
                 state, conv, l, slot, fresh,
             )
         else:
-            ckv = ckv.at[l, bids, offs].set(mla_latent(h, p, cfg))
+            row = mla_latent(h, p, cfg)
+            with stage("pool_write"):
+                ckv = ckv.at[l, bids, offs].set(row)
             out = mla_prefill(
                 h, ckv, l, table, pos, start + length, p, cfg, block_size=block_size
             )
-        x = ffn(x + out, p, cfg, i, valid, seen)
-    last = jax.lax.dynamic_index_in_dim(x, (length - 1).astype(jnp.int32), 0, keepdims=False)
+        with stage(mixer_out):
+            x = x + out
+        x = ffn(x, p, cfg, i, valid, seen)
+    with stage("embed_head"):
+        last = jax.lax.dynamic_index_in_dim(x, (length - 1).astype(jnp.int32), 0, keepdims=False)
     logits = final_logits(params, last[None], cfg)[0]
     return outputs({"ckv": ckv, "state": state, "conv": conv}, logits, seen, with_picks)
 
@@ -328,25 +337,35 @@ def paged_decode(
     2])``."""
     B = last_tokens.shape[0]
     ckv, state, conv = pool["ckv"], pool["state"], pool["conv"]
-    keep = None if live is None else ~live
-    bids = tables[jnp.arange(B), positions // block_size]
-    offs = positions % block_size
-    lengths = positions + 1  # the step's own row is attended
+    with stage("state_scan"):
+        keep = None if live is None else ~live
+    with stage("pool_write"):
+        bids = tables[jnp.arange(B), positions // block_size]
+        offs = positions % block_size
+    with stage("attn_core"):
+        lengths = positions + 1  # the step's own row is attended
     attend = paged.latent_decode_attention(
         cfg, block_size, None, interpret, latent_moe.mla_scale(cfg)
     )
-    x = params["wte"].astype(cfg.dtype)[last_tokens]
+    with stage("embed_head"):
+        x = params["wte"].astype(cfg.dtype)[last_tokens]
     seen: list = []
     for i, p, kind, l in _layers(params, cfg):
-        h = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
+        mixer_in, mixer_out = ("state_in", "state_out") if kind == "kda" else ("attn_proj", "attn_proj")
+        with stage(mixer_in):
+            h = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
         if kind == "kda":
             out, state, conv = paged.state_decode(
                 lambda S, tail: kda_decode(h, p, cfg, S, tail), state, conv, l, B, keep
             )
         else:
-            ckv = ckv.at[l, bids, offs].set(mla_latent(h, p, cfg))
+            row = mla_latent(h, p, cfg)
+            with stage("pool_write"):
+                ckv = ckv.at[l, bids, offs].set(row)
             out = mla_decode(h, ckv, l, tables, lengths, p, cfg, attend)
-        x = ffn(x + out, p, cfg, i, live, seen)
+        with stage(mixer_out):
+            x = x + out
+        x = ffn(x, p, cfg, i, live, seen)
     return outputs(
         {"ckv": ckv, "state": state, "conv": conv}, final_logits(params, x, cfg), seen, with_picks
     )

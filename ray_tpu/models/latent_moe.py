@@ -58,7 +58,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.common import _rms_norm
+from ray_tpu.models.common import _rms_norm, stage
 from ray_tpu.models.llama import _mlp_sublayer
 from ray_tpu.ops import moe_gmm
 
@@ -143,6 +143,7 @@ def whole_tiles(width: int) -> int:
     return -(-width // 128) * 128
 
 
+@stage("attn_proj")
 def mla_latent(h, p, cfg, rope=None, width=None):
     """The cache row of each token: ``[RMSNorm(c); R_t k_r]``, [..., 576].
     ``rope``: ``(cos, sin)`` of the tokens' positions, or None for a family
@@ -159,6 +160,7 @@ def mla_latent(h, p, cfg, rope=None, width=None):
     return jnp.concatenate(parts, axis=-1)
 
 
+@stage("attn_proj")
 def mla_query(h, p, cfg, rope=None):
     """``[q_n; R_t q_r]`` per head, [..., H, d_n + d_r]: through the low-rank
     pair and its norm where the layer has one, else through one matrix."""
@@ -204,9 +206,11 @@ def mla_prefill(
     Kb = nb * block_size
     Qb = Kb if T % Kb == 0 else T  # queries a run
     q = mla_query(h, p, cfg, rope)
-    wkvb = p["wkvb"].astype(dt)
     scale = mla_scale(cfg, scale)
+    with stage("attn_core"):
+        wkvb = p["wkvb"].astype(dt)
 
+    @stage("attn_core")  # the expansion of a stretch's keys and values per head is the fold's
     def attend(q, pos):
         def step(j, carry):
             m, s_sum, acc = carry
@@ -235,8 +239,9 @@ def mla_prefill(
         _, s_sum, acc = jax.lax.fori_loop(0, steps, step, init)
         return (acc / s_sum[..., None]).astype(dt).transpose(1, 0, 2)
 
-    o = jnp.concatenate([attend(q[i : i + Qb], pos[i : i + Qb]) for i in range(0, T, Qb)])
-    return o.reshape(T, H * dv) @ p["wo"].astype(dt)
+    o = [attend(q[i : i + Qb], pos[i : i + Qb]) for i in range(0, T, Qb)]
+    with stage("attn_proj"):
+        return jnp.concatenate(o).reshape(T, H * dv) @ p["wo"].astype(dt)
 
 
 def mla_decode(h, ckv, l: int, tables, lengths, p, cfg, attend, rope=None):
@@ -252,19 +257,23 @@ def mla_decode(h, ckv, l: int, tables, lengths, p, cfg, attend, rope=None):
     H, dn, dv, R = cfg.n_head, cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
     dt = cfg.dtype
     q = mla_query(h, p, cfg, rope)
-    wkvb = p["wkvb"].astype(dt).reshape(R, H, dn + dv)
-    q_lat = jnp.einsum("bhd,rhd->bhr", q[..., :dn], wkvb[..., :dn])
-    ql = jnp.concatenate([q_lat, q[..., dn:]], axis=-1)  # [B, H, 576]
-    ql = jnp.pad(ql, ((0, 0), (0, 0), (0, ckv.shape[-1] - ql.shape[-1])))
-    o_lat = attend(ql, ckv, l, tables, lengths)  # [B, H, R]
-    o = jnp.einsum("bhr,rhd->bhd", o_lat, wkvb[..., dn:])
-    return o.reshape(B, H * dv) @ p["wo"].astype(dt)
+    with stage("attn_proj"):
+        wkvb = p["wkvb"].astype(dt).reshape(R, H, dn + dv)
+        q_lat = jnp.einsum("bhd,rhd->bhr", q[..., :dn], wkvb[..., :dn])
+        ql = jnp.concatenate([q_lat, q[..., dn:]], axis=-1)  # [B, H, 576]
+        ql = jnp.pad(ql, ((0, 0), (0, 0), (0, ckv.shape[-1] - ql.shape[-1])))
+    with stage("attn_core"):
+        o_lat = attend(ql, ckv, l, tables, lengths)  # [B, H, R]
+    with stage("attn_proj"):
+        o = jnp.einsum("bhr,rhd->bhd", o_lat, wkvb[..., dn:])
+        return o.reshape(B, H * dv) @ p["wo"].astype(dt)
 
 
 # ---------------------------------------------------------------------------
 # Expert feed-forward
 
 
+@stage("router")
 def route(h, p, cfg):
     """``(experts [T, k] int32, weights [T, k] float32)`` of each token: the
     router in float32 over all experts of the model; chosen by score (plus the
@@ -319,25 +328,28 @@ def moe_ffn(h, p, cfg, valid=None):
     dt = cfg.dtype
     act = ACTIVATIONS[cfg.hidden_act]
     idx, w = route(h, p, cfg)
-    local = idx - cfg.expert_offset
-    here = (local >= 0) & (local < E)
-    if valid is not None:
-        here &= valid[:, None]
-    # Sort the (token, pick) pairs by expert; those that land elsewhere get
-    # the number past the last expert and so sort behind every group.
-    expert = jnp.where(here, local, E).reshape(T * k)
-    order = jnp.argsort(expert, stable=True)
-    sizes = jnp.sum(
-        expert[:, None] == jnp.arange(E, dtype=jnp.int32)[None, :], axis=0, dtype=jnp.int32
-    )
-    up, down = p["e_up"].astype(dt), p["e_down"].astype(dt)
-    gate = p["e_gate"].astype(dt) if "e_gate" in p else None
+    with stage("router"):
+        local = idx - cfg.expert_offset
+        here = (local >= 0) & (local < E)
+        if valid is not None:
+            here &= valid[:, None]
+        # Sort the (token, pick) pairs by expert; those that land elsewhere get
+        # the number past the last expert and so sort behind every group.
+        expert = jnp.where(here, local, E).reshape(T * k)
+        order = jnp.argsort(expert, stable=True)
+        sizes = jnp.sum(
+            expert[:, None] == jnp.arange(E, dtype=jnp.int32)[None, :], axis=0, dtype=jnp.int32
+        )
     shared_in = h
-    if "latent_in" in p:  # the routed experts' input; the router has read h itself
-        h = h @ p["latent_in"].astype(dt)
+    with stage("experts"):
+        up, down = p["e_up"].astype(dt), p["e_down"].astype(dt)
+        gate = p["e_gate"].astype(dt) if "e_gate" in p else None
+        if "latent_in" in p:  # the routed experts' input; the router has read h itself
+            h = h @ p["latent_in"].astype(dt)
     D = h.shape[1]
     product = moe_gmm.gmm if experts_in_kernel(p, dt) else jax.lax.ragged_dot
 
+    @stage("experts")
     def experts(xs, sizes, weight=None):
         if gate is None:
             mid = act(product(xs, up, sizes))
@@ -348,38 +360,51 @@ def moe_ffn(h, p, cfg, valid=None):
         return product(mid, down, sizes)
 
     if T * k <= ROWS_A_PASS:
-        ys = experts(h[order // k], sizes)  # [T k, D], grouped by expert
-        # Back to (token, pick) order; a row behind the groups holds nothing.
-        ys = ys[jnp.argsort(order)].reshape(T, k, D).astype(_F32)
-        y = jnp.sum(jnp.where(here[..., None], ys * w[..., None], 0.0), axis=1)
+        with stage("router"):
+            xs = h[order // k]
+        ys = experts(xs, sizes)  # [T k, D], grouped by expert
+        with stage("router"):
+            # Back to (token, pick) order; a row behind the groups holds nothing.
+            ys = ys[jnp.argsort(order)].reshape(T, k, D).astype(_F32)
+            y = jnp.sum(jnp.where(here[..., None], ys * w[..., None], 0.0), axis=1)
     else:
         rows = ROWS_A_PASS
-        order = jnp.pad(order, (0, -(T * k) % rows))
-        weight = jnp.pad(w.reshape(T * k), (0, order.shape[0] - T * k))
-        ends = jnp.cumsum(sizes)
-        landed = ends[-1]
+        with stage("router"):
+            order = jnp.pad(order, (0, -(T * k) % rows))
+            weight = jnp.pad(w.reshape(T * k), (0, order.shape[0] - T * k))
+            ends = jnp.cumsum(sizes)
+            landed = ends[-1]
 
         def one_pass(j, y):
-            lo = j * rows
-            pairs = jax.lax.dynamic_slice_in_dim(order, lo, rows)
-            real = lo + jnp.arange(rows) < landed
-            part = jnp.clip(jnp.minimum(ends, lo + rows) - jnp.maximum(ends - sizes, lo), 0)
-            ys = jnp.where(real[:, None], experts(h[pairs // k], part, weight[pairs]), 0)
-            # Each row is added to its token through a 0/1 matrix (exact in
-            # bfloat16, summed in float32): no scatter.
-            to_token = real[:, None] & (pairs[:, None] // k == jnp.arange(T)[None, :])
-            return y + jnp.einsum("rt,rd->td", to_token.astype(dt), ys, preferred_element_type=_F32)
+            with stage("router"):
+                lo = j * rows
+                pairs = jax.lax.dynamic_slice_in_dim(order, lo, rows)
+                real = lo + jnp.arange(rows) < landed
+                part = jnp.clip(jnp.minimum(ends, lo + rows) - jnp.maximum(ends - sizes, lo), 0)
+                keep, xs, weights = real[:, None], h[pairs // k], weight[pairs]
+            ys = experts(xs, part, weights)
+            with stage("router"):
+                ys = jnp.where(keep, ys, 0)
+                # Each row is added to its token through a 0/1 matrix (exact in
+                # bfloat16, summed in float32): no scatter.
+                to_token = real[:, None] & (pairs[:, None] // k == jnp.arange(T)[None, :])
+                return y + jnp.einsum("rt,rd->td", to_token.astype(dt), ys, preferred_element_type=_F32)
 
-        y = jax.lax.fori_loop(0, -(-landed // rows), one_pass, jnp.zeros((T, D), _F32))
-    y = y.astype(dt)
-    if "latent_out" in p:
-        y = y @ p["latent_out"].astype(dt)
-    mid = None
-    if "s_up" in p:  # the shared expert, where the layer has one
-        mid = shared_in @ p["s_up"].astype(dt)
-        mid = act(shared_in @ p["s_gate"].astype(dt)) * mid if "s_gate" in p else act(mid)
-    counts = jnp.stack([jnp.sum(here, dtype=jnp.int32), jnp.sum(sizes > 0, dtype=jnp.int32)])
-    return (y if mid is None else y + mid @ p["s_down"].astype(dt)), counts, idx
+        with stage("router"):
+            passes, y0 = -(-landed // rows), jnp.zeros((T, D), _F32)
+        y = jax.lax.fori_loop(0, passes, one_pass, y0)
+    with stage("experts"):
+        y = y.astype(dt)
+        if "latent_out" in p:
+            y = y @ p["latent_out"].astype(dt)
+        mid = None
+        if "s_up" in p:  # the shared expert, where the layer has one
+            mid = shared_in @ p["s_up"].astype(dt)
+            mid = act(shared_in @ p["s_gate"].astype(dt)) * mid if "s_gate" in p else act(mid)
+    with stage("router"):
+        counts = jnp.stack([jnp.sum(here, dtype=jnp.int32), jnp.sum(sizes > 0, dtype=jnp.int32)])
+    with stage("experts"):
+        return (y if mid is None else y + mid @ p["s_down"].astype(dt)), counts, idx
 
 
 def experts_in_kernel(p, dtype, mesh=None) -> bool:
@@ -405,9 +430,12 @@ def ffn(x, p, cfg, layer: int, valid, seen: list):
     counts and picks are appended to ``seen``."""
     if not cfg.is_moe(layer):
         return _mlp_sublayer(x, p, cfg)
-    y, counts, picks = moe_ffn(_rms_norm(x, p["mlp_norm"], cfg.rms_eps), p, cfg, valid)
+    with stage("experts"):  # the norm feeds the router and the experts alike
+        h = _rms_norm(x, p["mlp_norm"], cfg.rms_eps)
+    y, counts, picks = moe_ffn(h, p, cfg, valid)
     seen.append((counts, picks))
-    return x + y
+    with stage("experts"):
+        return x + y
 
 
 def outputs(pool, logits, seen, with_picks: bool):
@@ -416,12 +444,14 @@ def outputs(pool, logits, seen, with_picks: bool):
     from a model with no expert layer, the pool and the logits alone."""
     if not seen:
         return pool, logits
-    counts = jnp.stack([c for c, _ in seen])
-    if with_picks:
-        return pool, logits, counts, jnp.stack([p for _, p in seen])
+    with stage("embed_head"):  # the packed counters
+        counts = jnp.stack([c for c, _ in seen])
+        if with_picks:
+            return pool, logits, counts, jnp.stack([p for _, p in seen])
     return pool, logits, counts
 
 
+@stage("embed_head")
 def final_logits(params, last, cfg):
     h = _rms_norm(last, params["final_norm"], cfg.rms_eps)
     return (h @ params["lm_head"].astype(cfg.dtype)).astype(_F32)

@@ -28,7 +28,7 @@ from typing import Any, ClassVar
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.common import _rms_norm, chunked_lm_loss, pipelined_blocks
+from ray_tpu.models.common import _rms_norm, chunked_lm_loss, pipelined_blocks, stage
 from ray_tpu.ops.attention import causal_attention, uses_flash_kernel
 
 Params = dict
@@ -163,39 +163,43 @@ def _apply_rope(t, cos, sin):
 def _attn_sublayer(x, p, cfg: LlamaConfig, cos, sin, mesh=None, ring=False):
     B, S, D = x.shape
     H, KH, Dh = cfg.n_head, cfg.n_kv_head, cfg.head_dim
-    h = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
-    q = h @ p["wq"].astype(cfg.dtype)
-    kk = h @ p["wk"].astype(cfg.dtype)
-    v = h @ p["wv"].astype(cfg.dtype)
 
     def heads(t, n):
         return t.reshape(B, S, n, Dh).transpose(0, 2, 1, 3)
 
-    q = _apply_rope(heads(q, H), cos, sin)
-    kk = _apply_rope(heads(kk, KH), cos, sin)
-    v = heads(v, KH)
-    # GQA: broadcast each KV head to its query-head group for the kernel.
-    group = H // KH
-    kk = jnp.repeat(kk, group, axis=1)
-    v = jnp.repeat(v, group, axis=1)
-    if ring:
-        # Sequence sharded over sp: ring attention keeps K/V distributed,
-        # rotating chunks over ICI (same dispatch as gpt2._attn_sublayer).
-        from ray_tpu.ops.ring_attention import ring_attention
+    with stage("attn_proj"):
+        h = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
+        q = h @ p["wq"].astype(cfg.dtype)
+        kk = h @ p["wk"].astype(cfg.dtype)
+        v = h @ p["wv"].astype(cfg.dtype)
+        q = _apply_rope(heads(q, H), cos, sin)
+        kk = _apply_rope(heads(kk, KH), cos, sin)
+        v = heads(v, KH)
+        # GQA: broadcast each KV head to its query-head group for the kernel.
+        group = H // KH
+        kk = jnp.repeat(kk, group, axis=1)
+        v = jnp.repeat(v, group, axis=1)
+    with stage("attn_core"):
+        if ring:
+            # Sequence sharded over sp: ring attention keeps K/V distributed,
+            # rotating chunks over ICI (same dispatch as gpt2._attn_sublayer).
+            from ray_tpu.ops.ring_attention import ring_attention
 
-        attn = ring_attention(q, kk, v, mesh=mesh)
-    else:
-        attn = causal_attention(
-            q, kk, v,
-            impl=cfg.attn_impl,
-            block_q=cfg.attn_block_q,
-            block_k=cfg.attn_block_k,
-            mesh=mesh,
-        )
-    attn = attn.transpose(0, 2, 1, 3).reshape(B, S, D)
-    return x + attn @ p["wo"].astype(cfg.dtype)
+            attn = ring_attention(q, kk, v, mesh=mesh)
+        else:
+            attn = causal_attention(
+                q, kk, v,
+                impl=cfg.attn_impl,
+                block_q=cfg.attn_block_q,
+                block_k=cfg.attn_block_k,
+                mesh=mesh,
+            )
+    with stage("attn_proj"):
+        attn = attn.transpose(0, 2, 1, 3).reshape(B, S, D)
+        return x + attn @ p["wo"].astype(cfg.dtype)
 
 
+@stage("mlp")
 def _mlp_sublayer(x, p, cfg: LlamaConfig):
     h = _rms_norm(x, p["mlp_norm"], cfg.rms_eps)
     gate = h @ p["w_gate"].astype(cfg.dtype)
@@ -208,11 +212,14 @@ def kv_hooks(cfg: LlamaConfig, S: int):
     through (``paged.family``): RoPE by gathered absolute position, keys and
     values for the ``n_kv_head`` heads unexpanded."""
     H, KH, Dh = cfg.n_head, cfg.n_kv_head, cfg.head_dim
-    cos_full, sin_full = rope_tables(cfg, S)
+    with stage("attn_proj"):
+        cos_full, sin_full = rope_tables(cfg, S)
 
+    @stage("embed_head")
     def embed(params, tokens, pos2d):
         return params["wte"].astype(cfg.dtype)[tokens]
 
+    @stage("attn_proj")
     def qkv(x, p, pos2d):
         B, T, _ = x.shape
         h = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
@@ -235,10 +242,12 @@ def kv_hooks(cfg: LlamaConfig, S: int):
 
     def finish(x, attn, p):  # attn [B, H, T, Dh]
         B, Hh, T, _ = attn.shape
-        a = attn.transpose(0, 2, 1, 3).reshape(B, T, cfg.d_model)
-        x = x + a @ p["wo"].astype(cfg.dtype)
+        with stage("attn_proj"):
+            a = attn.transpose(0, 2, 1, 3).reshape(B, T, cfg.d_model)
+            x = x + a @ p["wo"].astype(cfg.dtype)
         return _mlp_sublayer(x, p, cfg)
 
+    @stage("embed_head")
     def final(params, last):  # last [B, D] -> [B, vocab] f32
         h = _rms_norm(last, params["final_norm"], cfg.rms_eps)
         return (h @ params["lm_head"].astype(cfg.dtype)).astype(
@@ -259,8 +268,10 @@ def hidden(
         # Same XLA:CPU bf16-allreduce workaround as the GPT-2 pipeline.
         cfg = dataclasses.replace(cfg, dtype=jnp.float32)
     sp_size = mesh.shape.get("sp", 1) if mesh is not None else 1
-    x = params["wte"].astype(cfg.dtype)[tokens]
-    cos, sin = rope_tables(cfg, S)
+    with stage("embed_head"):
+        x = params["wte"].astype(cfg.dtype)[tokens]
+    with stage("attn_proj"):
+        cos, sin = rope_tables(cfg, S)
     remat = cfg.remat
     # No mesh for attention inside the pp pipeline (same as gpt2.hidden).
     attn_mesh = None if pipelined else mesh
@@ -314,7 +325,8 @@ def hidden(
         )
     else:
         x, _aux = jax.lax.scan(block_fn, x, params["blocks"])
-    return _rms_norm(x, params["final_norm"], cfg.rms_eps)
+    with stage("embed_head"):
+        return _rms_norm(x, params["final_norm"], cfg.rms_eps)
 
 
 def forward(
@@ -322,7 +334,8 @@ def forward(
 ) -> jax.Array:
     """tokens [B, S] -> logits [B, S, vocab]."""
     x = hidden(params, tokens, cfg, mesh=mesh)
-    return x @ params["lm_head"].astype(cfg.dtype)
+    with stage("embed_head"):
+        return x @ params["lm_head"].astype(cfg.dtype)
 
 
 def loss_fn(
@@ -335,17 +348,18 @@ def loss_fn(
     else:
         inputs, targets = tokens[:, :-1], tokens[:, 1:]
     x = hidden(params, inputs, cfg, mesh=mesh)
-    head = params["lm_head"].astype(cfg.dtype)
-    if cfg.loss_chunk and inputs.shape[1] > cfg.loss_chunk:
-        # chunked_lm_loss expects the head oriented [V, D]; lm_head is
-        # [D, V] — hand it transposed (fuses into the matmul under jit).
-        total = chunked_lm_loss(x, head.T, targets, cfg.loss_chunk)
-        ce = total / targets.size
-    else:
-        logits = (x @ head).astype(jnp.float32)
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-        ce = jnp.mean(lse - tgt)
+    with stage("embed_head"):
+        head = params["lm_head"].astype(cfg.dtype)
+        if cfg.loss_chunk and inputs.shape[1] > cfg.loss_chunk:
+            # chunked_lm_loss expects the head oriented [V, D]; lm_head is
+            # [D, V] — hand it transposed (fuses into the matmul under jit).
+            total = chunked_lm_loss(x, head.T, targets, cfg.loss_chunk)
+            ce = total / targets.size
+        else:
+            logits = (x @ head).astype(jnp.float32)
+            lse = jax.scipy.special.logsumexp(logits, axis=-1)
+            tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+            ce = jnp.mean(lse - tgt)
     return ce, {"loss": ce, "tokens": jnp.array(targets.size, jnp.int32)}
 
 

@@ -50,7 +50,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import latent_moe, paged
-from ray_tpu.models.common import _rms_norm
+from ray_tpu.models.common import _rms_norm, stage
 from ray_tpu.models.latent_moe import ffn, final_logits, outputs
 
 Params = dict
@@ -308,6 +308,7 @@ def _rotate(t, rope):
     return jnp.concatenate([t1 * cos - t2 * sin, t1 * sin + t2 * cos, rest], axis=-1).astype(t.dtype)
 
 
+@stage("attn_proj")
 def _qkv(a, p, cfg: MimoV2Config, kind: paged.AttentionKind, rope, *, held: bool = False):
     """``a`` [..., D] normed -> ``(q [..., KH, group, Dk], k [..., KH,
     key_lanes], v [..., KH, Dv])`` of a layer of ``kind``: ``q`` and ``k``
@@ -336,6 +337,7 @@ def _sink(p, kind: paged.AttentionKind):
     return p["sink"].reshape(kind.kv_heads, -1) if kind.sink else None
 
 
+@stage("attn_proj")
 def _out(x, o, p, cfg: MimoV2Config):
     """``x + W_o o``: ``o`` [..., KH, group, Dv]."""
     o = o.reshape(*o.shape[:-3], cfg.n_head * cfg.v_head_dim)
@@ -413,12 +415,15 @@ def paged_prefill(
     kinds = attention_kinds(cfg)
     pos = start + jnp.arange(T, dtype=jnp.int32)
     valid = jnp.arange(T) < length
-    ropes = _ropes(cfg, pos)
-    x = params["wte"].astype(cfg.dtype)[tokens[0]]
+    with stage("attn_proj"):
+        ropes = _ropes(cfg, pos)
+    with stage("embed_head"):
+        x = params["wte"].astype(cfg.dtype)[tokens[0]]
     pool = {part: dict(kv) for part, kv in pool.items()}
     seen: list = []
     for layer, p, kind, l in _layers(params, cfg):
-        a = _rms_norm(x, p["in_norm"], cfg.rms_eps)
+        with stage("attn_proj"):
+            a = _rms_norm(x, p["in_norm"], cfg.rms_eps)
         q, k, v = _qkv(a, p, cfg, kinds[kind], ropes[kind], held=True)
         tab, kv = tables[kind], pool[PARTS[kind]]
         kv["k"] = paged._write_blocks(kv["k"], l, tab, start, k, block_size)
@@ -428,7 +433,8 @@ def paged_prefill(
             window=kinds[kind].window, sink=_sink(p, kinds[kind]), name=kinds[kind].name,
         )
         x = ffn(_out(x, o, p, cfg), p, cfg, layer, valid, seen)
-    last = jax.lax.dynamic_index_in_dim(x, (length - 1).astype(jnp.int32), 0, keepdims=False)
+    with stage("embed_head"):
+        last = jax.lax.dynamic_index_in_dim(x, (length - 1).astype(jnp.int32), 0, keepdims=False)
     logits = final_logits(params, last[None], cfg)[0]
     return outputs(pool, logits, seen, with_picks)
 
@@ -453,22 +459,29 @@ def paged_decode(
     tables = _by_kind(tables if tables.ndim == 3 else jnp.stack([tables, tables], axis=1))
     kinds = attention_kinds(cfg)
     attend = [paged.decode_attention(kind, block_size, None, interpret) for kind in kinds]
-    rows = jnp.arange(B)
-    offs = positions % block_size
-    lengths = positions + 1  # the step's own key is attended
-    ropes = _ropes(cfg, positions)
-    x = params["wte"].astype(cfg.dtype)[last_tokens]
+    with stage("pool_write"):
+        rows = jnp.arange(B)
+        offs = positions % block_size
+    with stage("attn_core"):
+        lengths = positions + 1  # the step's own key is attended
+    with stage("attn_proj"):
+        ropes = _ropes(cfg, positions)
+    with stage("embed_head"):
+        x = params["wte"].astype(cfg.dtype)[last_tokens]
     pool = {part: dict(kv) for part, kv in pool.items()}
     seen: list = []
     for layer, p, kind, l in _layers(params, cfg):
-        a = _rms_norm(x, p["in_norm"], cfg.rms_eps)
+        with stage("attn_proj"):
+            a = _rms_norm(x, p["in_norm"], cfg.rms_eps)
         q, k, v = _qkv(a, p, cfg, kinds[kind], ropes[kind])
         tab, kv = tables[kind], pool[PARTS[kind]]
-        bids = tab[rows, positions // block_size]
+        with stage("pool_write"):
+            bids = tab[rows, positions // block_size]
         kv["k"] = paged._write(kv["k"], l, bids, offs, k)
         kv["v"] = paged._write(kv["v"], l, bids, offs, v)
-        o = attend[kind](
-            q, kv["k"], kv["v"], jnp.asarray(l, jnp.int32), tab, lengths, _sink(p, kinds[kind])
-        )
+        with stage("attn_core"):
+            o = attend[kind](
+                q, kv["k"], kv["v"], jnp.asarray(l, jnp.int32), tab, lengths, _sink(p, kinds[kind])
+            )
         x = ffn(_out(x, o, p, cfg), p, cfg, layer, live, seen)
     return outputs(pool, final_logits(params, x, cfg), seen, with_picks)

@@ -45,7 +45,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models import latent_moe, paged
 from ray_tpu.models.latent_moe import ffn, final_logits, mla_decode, mla_latent, mla_prefill
-from ray_tpu.models.common import _rms_norm
+from ray_tpu.models.common import _rms_norm, stage
 
 Params = dict
 _F32 = jnp.float32
@@ -264,14 +264,19 @@ def _rope(cfg: MlaMoeConfig, positions):
 def _attention(x, p, l: int, ckv, table, pos, n_keys, rope, cfg, block_size):
     """Layer ``l``'s attention sublayer of a prefill, with its residual: the
     latent rows of positions ``pos`` written under ``table``, then read."""
-    h = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
-    ckv = ckv.at[l, table[pos // block_size], pos % block_size].set(
-        mla_latent(h, p, cfg, rope, cfg.pool_row_dim)
-    )
-    return x + mla_prefill(
+    with stage("attn_proj"):
+        h = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
+    with stage("pool_write"):
+        bids, offs = table[pos // block_size], pos % block_size
+    row = mla_latent(h, p, cfg, rope, cfg.pool_row_dim)
+    with stage("pool_write"):
+        ckv = ckv.at[l, bids, offs].set(row)
+    out = mla_prefill(
         h, ckv, l, table, pos, n_keys, p, cfg, block_size=block_size,
         rope=rope, scale=cfg.softmax_scale,
-    ), ckv
+    )
+    with stage("attn_proj"):
+        return x + out, ckv
 
 
 def _prefill_layers(params, tokens, length, start, table, ckv, cfg, block_size):
@@ -279,8 +284,10 @@ def _prefill_layers(params, tokens, length, start, table, ckv, cfg, block_size):
     T = tokens.shape[1]
     pos = start + jnp.arange(T, dtype=jnp.int32)
     valid = jnp.arange(T) < length
-    rope = _rope(cfg, pos)
-    x = params["wte"].astype(cfg.dtype)[tokens[0]]
+    with stage("attn_proj"):
+        rope = _rope(cfg, pos)
+    with stage("embed_head"):
+        x = params["wte"].astype(cfg.dtype)[tokens[0]]
     seen: list = []
     for l, p in enumerate(params["layers"]):
         x, ckv = _attention(x, p, l, ckv, table, pos, start + length, rope, cfg, block_size)
@@ -299,7 +306,8 @@ def paged_prefill(
     ``(pool, last_logits [vocab] float32, counts int32 [expert layers, 2])``,
     and with ``with_picks`` the chosen experts [expert layers, T, k]."""
     x, ckv, seen = _prefill_layers(params, tokens, length, start, table, pool["ckv"], cfg, block_size)
-    last = jax.lax.dynamic_index_in_dim(x, (length - 1).astype(jnp.int32), 0, keepdims=False)
+    with stage("embed_head"):
+        last = jax.lax.dynamic_index_in_dim(x, (length - 1).astype(jnp.int32), 0, keepdims=False)
     logits = final_logits(params, last[None], cfg)[0]
     return latent_moe.outputs({"ckv": ckv}, logits, seen, with_picks)
 
@@ -321,17 +329,26 @@ def paged_decode(
     float32, counts)``."""
     B = last_tokens.shape[0]
     ckv = pool["ckv"]
-    bids = tables[jnp.arange(B), positions // block_size]
-    offs = positions % block_size
-    lengths = positions + 1  # the step's own row is attended
+    with stage("pool_write"):
+        bids = tables[jnp.arange(B), positions // block_size]
+        offs = positions % block_size
+    with stage("attn_core"):
+        lengths = positions + 1  # the step's own row is attended
     attend = paged.latent_decode_attention(cfg, block_size, None, interpret, cfg.softmax_scale)
-    rope = _rope(cfg, positions)
-    x = params["wte"].astype(cfg.dtype)[last_tokens]
+    with stage("attn_proj"):
+        rope = _rope(cfg, positions)
+    with stage("embed_head"):
+        x = params["wte"].astype(cfg.dtype)[last_tokens]
     seen: list = []
     for l, p in enumerate(params["layers"]):
-        h = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
-        ckv = ckv.at[l, bids, offs].set(mla_latent(h, p, cfg, rope, cfg.pool_row_dim))
-        x = x + mla_decode(h, ckv, l, tables, lengths, p, cfg, attend, rope)
+        with stage("attn_proj"):
+            h = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
+        row = mla_latent(h, p, cfg, rope, cfg.pool_row_dim)
+        with stage("pool_write"):
+            ckv = ckv.at[l, bids, offs].set(row)
+        out = mla_decode(h, ckv, l, tables, lengths, p, cfg, attend, rope)
+        with stage("attn_proj"):
+            x = x + out
         x = ffn(x, p, cfg, l + 1, live, seen)
     return latent_moe.outputs({"ckv": ckv}, final_logits(params, x, cfg), seen, with_picks)
 

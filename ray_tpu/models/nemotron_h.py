@@ -44,7 +44,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models import latent_moe, paged
 from ray_tpu.models.latent_moe import final_logits, moe_ffn, outputs
-from ray_tpu.models.common import _rms_norm
+from ray_tpu.models.common import _rms_norm, stage
 from ray_tpu.ops import state_step
 from ray_tpu.ops.ssd import ssd_chunked
 
@@ -282,15 +282,19 @@ def mamba_prefill(u, p, cfg: NemotronHConfig, h0, tail, length):
     touch the state."""
     T, K = u.shape[0], cfg.conv_kernel
     dt = cfg.dtype
-    z, xBC, step = _mamba_inputs(u, p, cfg)
-    rows = jnp.concatenate([tail.astype(dt), xBC])  # [K-1+T, conv_dim]
-    conv = p["conv_w"].astype(dt)
-    mixed = sum(conv[j] * rows[j : j + T] for j in range(K)) + p["conv_b"].astype(dt)
-    x, B, C, A, D = _ssm_operands(jax.nn.silu(mixed), p, cfg)
-    live = (jnp.arange(T) < length)[:, None]
-    y, h = ssd_chunked(x, step * live, A, B, C, D, h0)
-    tail = jax.lax.dynamic_slice_in_dim(rows, length, K - 1, axis=0)
-    out = gated_norm(y.reshape(T, -1), z, p["gate_norm"], cfg) @ p["w_out"].astype(dt)
+    with stage("state_in"):
+        z, xBC, step = _mamba_inputs(u, p, cfg)
+        rows = jnp.concatenate([tail.astype(dt), xBC])  # [K-1+T, conv_dim]
+        conv = p["conv_w"].astype(dt)
+        mixed = sum(conv[j] * rows[j : j + T] for j in range(K)) + p["conv_b"].astype(dt)
+        x, B, C, A, D = _ssm_operands(jax.nn.silu(mixed), p, cfg)
+        live = (jnp.arange(T) < length)[:, None]
+        step = step * live
+    with stage("state_scan"):
+        y, h = ssd_chunked(x, step, A, B, C, D, h0)
+        tail = jax.lax.dynamic_slice_in_dim(rows, length, K - 1, axis=0)
+    with stage("state_out"):
+        out = gated_norm(y.reshape(T, -1), z, p["gate_norm"], cfg) @ p["w_out"].astype(dt)
     return out, h, tail
 
 
@@ -299,19 +303,24 @@ def mamba_decode(u, p, cfg: NemotronHConfig, h, tail):
     they lie, a :class:`ray_tpu.ops.state_step.Rows`), ``tail`` [B, K-1,
     conv_dim]. Returns ``(out [B, D], h, tail)``."""
     dt = cfg.dtype
-    z, xBC, step = _mamba_inputs(u, p, cfg)
-    rows = jnp.concatenate([tail.astype(dt), xBC[:, None]], axis=1)  # [B, K, conv_dim]
-    mixed = jnp.einsum("kc,bkc->bc", p["conv_w"].astype(dt), rows) + p["conv_b"].astype(dt)
-    x, B, C, A, D = _ssm_operands(jax.nn.silu(mixed), p, cfg)
-    y, h = state_step.ssd(x, step, A, B, C, D, h)
-    out = gated_norm(y.reshape(y.shape[0], -1), z, p["gate_norm"], cfg) @ p["w_out"].astype(dt)
-    return out, h, rows[:, 1:]
+    with stage("state_in"):
+        z, xBC, step = _mamba_inputs(u, p, cfg)
+        rows = jnp.concatenate([tail.astype(dt), xBC[:, None]], axis=1)  # [B, K, conv_dim]
+        mixed = jnp.einsum("kc,bkc->bc", p["conv_w"].astype(dt), rows) + p["conv_b"].astype(dt)
+        x, B, C, A, D = _ssm_operands(jax.nn.silu(mixed), p, cfg)
+    with stage("state_scan"):
+        y, h = state_step.ssd(x, step, A, B, C, D, h)
+    with stage("state_out"):
+        out = gated_norm(y.reshape(y.shape[0], -1), z, p["gate_norm"], cfg) @ p["w_out"].astype(dt)
+    with stage("state_scan"):
+        return out, h, rows[:, 1:]
 
 
 # ---------------------------------------------------------------------------
 # Attention mixer (keys and values in the block pool, models/paged.py's way)
 
 
+@stage("attn_proj")
 def _qkv(u, p, cfg: NemotronHConfig):
     """``(q [..., KH, group, Dh], k [..., KH, Dh], v [..., KH, Dh])``."""
     dt = cfg.dtype
@@ -323,6 +332,7 @@ def _qkv(u, p, cfg: NemotronHConfig):
     return q, k, v
 
 
+@stage("attn_core")
 def causal_attention(q, kd, vd, pos, cfg, scale=None):
     """``q`` [T, KH, group, Dh] at positions ``pos`` [T] against a table's
     gathered keys and values ``kd``, ``vd`` [KH, S, Dh] under the mask ``column
@@ -345,9 +355,12 @@ def attention_prefill(u, p, cfg: NemotronHConfig, pk, pv, l: int, table, pos, bl
     q, k, v = _qkv(u, p, cfg)
     pk, kd = paged._write_blocks_read(pk, l, table, pos[0], k, block_size)  # [W, KH, block, Dh]
     pv, vd = paged._write_blocks_read(pv, l, table, pos[0], v, block_size)
-    kd = kd.transpose(1, 0, 2, 3).reshape(KH, S, Dh)
-    vd = vd.transpose(1, 0, 2, 3).reshape(KH, S, Dh)
-    return causal_attention(q, kd, vd, pos, cfg) @ p["wo"].astype(cfg.dtype), pk, pv
+    with stage("attn_core"):
+        kd = kd.transpose(1, 0, 2, 3).reshape(KH, S, Dh)
+        vd = vd.transpose(1, 0, 2, 3).reshape(KH, S, Dh)
+    o = causal_attention(q, kd, vd, pos, cfg)
+    with stage("attn_proj"):
+        return o @ p["wo"].astype(cfg.dtype), pk, pv
 
 
 def attention_decode(u, p, cfg: NemotronHConfig, pk, pv, l: int, tables, positions, block_size, attend):
@@ -357,12 +370,15 @@ def attention_decode(u, p, cfg: NemotronHConfig, pk, pv, l: int, tables, positio
     pv)``."""
     B = u.shape[0]
     q, k, v = _qkv(u, p, cfg)
-    bids = tables[jnp.arange(B), positions // block_size]
-    offs = positions % block_size
+    with stage("pool_write"):
+        bids = tables[jnp.arange(B), positions // block_size]
+        offs = positions % block_size
     pk = paged._write(pk, l, bids, offs, k)
     pv = paged._write(pv, l, bids, offs, v)
-    o = attend(q, pk, pv, jnp.asarray(l, jnp.int32), tables, positions + 1).reshape(B, -1)
-    return o @ p["wo"].astype(cfg.dtype), pk, pv
+    with stage("attn_core"):
+        o = attend(q, pk, pv, jnp.asarray(l, jnp.int32), tables, positions + 1)
+    with stage("attn_proj"):
+        return o.reshape(B, -1) @ p["wo"].astype(cfg.dtype), pk, pv
 
 
 # ---------------------------------------------------------------------------
@@ -402,6 +418,10 @@ def init_pool(cfg: NemotronHConfig, num_blocks: int, block_size: int, slots=None
     }
 
 
+# The stage a block's norm feeds, by the block's letter.
+_NORM_FEEDS = {"M": "state_in", "*": "attn_proj", "E": "experts"}
+
+
 def _layers(params, cfg):
     """(the block's letter, its parameters, its index among blocks of its kind)."""
     seen = dict.fromkeys("ME*", 0)
@@ -414,7 +434,8 @@ def _experts(x, u, p, cfg, valid, seen: list):
     """An ``E`` block with its residual; its counts and picks go to ``seen``."""
     y, counts, picks = moe_ffn(u, p, cfg, valid)
     seen.append((counts, picks))
-    return x + y
+    with stage("experts"):
+        return x + y
 
 
 def paged_prefill(
@@ -435,22 +456,27 @@ def paged_prefill(
 
     pos = start + jnp.arange(T, dtype=jnp.int32)
     valid = jnp.arange(T) < length
-    x = params["wte"].astype(cfg.dtype)[tokens[0]]
+    with stage("embed_head"):
+        x = params["wte"].astype(cfg.dtype)[tokens[0]]
     seen: list = []
     for kind, p, l in _layers(params, cfg):
-        u = _rms_norm(x, p["norm"], cfg.rms_eps)
+        with stage(_NORM_FEEDS[kind]):
+            u = _rms_norm(x, p["norm"], cfg.rms_eps)
         if kind == "M":
             out, state, conv = paged.state_prefill(
                 lambda h, tail: mamba_prefill(u, p, cfg, h, tail, length),
                 state, conv, l, slot, fresh,
             )
-            x = x + out
+            with stage("state_out"):
+                x = x + out
         elif kind == "*":
             out, pk, pv = attention_prefill(u, p, cfg, pk, pv, l, table, pos, block_size)
-            x = x + out
+            with stage("attn_proj"):
+                x = x + out
         else:
             x = _experts(x, u, p, cfg, valid, seen)
-    last = jax.lax.dynamic_index_in_dim(x, (length - 1).astype(jnp.int32), 0, keepdims=False)
+    with stage("embed_head"):
+        last = jax.lax.dynamic_index_in_dim(x, (length - 1).astype(jnp.int32), 0, keepdims=False)
     logits = final_logits(params, last[None], cfg)[0]
     return outputs({"k": pk, "v": pv, "state": state, "conv": conv}, logits, seen, with_picks)
 
@@ -471,21 +497,26 @@ def paged_decode(
     B = last_tokens.shape[0]
     pk, pv, state, conv = pool["k"], pool["v"], pool["state"], pool["conv"]
     attend = paged.decode_attention(paged.attention_kind(cfg), block_size, None, interpret)
-    keep = None if live is None else ~live
-    x = params["wte"].astype(cfg.dtype)[last_tokens]
+    with stage("state_scan"):
+        keep = None if live is None else ~live
+    with stage("embed_head"):
+        x = params["wte"].astype(cfg.dtype)[last_tokens]
     seen: list = []
     for kind, p, l in _layers(params, cfg):
-        u = _rms_norm(x, p["norm"], cfg.rms_eps)
+        with stage(_NORM_FEEDS[kind]):
+            u = _rms_norm(x, p["norm"], cfg.rms_eps)
         if kind == "M":
             out, state, conv = paged.state_decode(
                 lambda h, tail: mamba_decode(u, p, cfg, h, tail), state, conv, l, B, keep
             )
-            x = x + out
+            with stage("state_out"):
+                x = x + out
         elif kind == "*":
             out, pk, pv = attention_decode(
                 u, p, cfg, pk, pv, l, tables, positions, block_size, attend
             )
-            x = x + out
+            with stage("attn_proj"):
+                x = x + out
         else:
             x = _experts(x, u, p, cfg, live, seen)
     return outputs(

@@ -84,6 +84,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models.common import stage
 from ray_tpu.ops import paged_attention, paged_prefill_attention, state_step
 
 Params = dict
@@ -211,6 +212,7 @@ def init_block_pool(cfg, num_blocks: int, block_size: int, slots=None, window_bl
 # Paged ops
 
 
+@stage("pool_write")
 def _write(pool_kv, l, bids, offs, new):
     """Layer ``l`` of one pool tensor [L, N, KH, block, Dh]: scatter
     ``new`` [..., KH, Dh] to the (block, offset) homes ``bids`` / ``offs``
@@ -224,9 +226,11 @@ def _write_read(pool_kv, l, bids, offs, new, tables):
     """:func:`_write`, then gather the rows of ``tables`` [..., W] back as
     [..., W, KH, block, Dh], by (layer, block) at once as well."""
     pool_kv = _write(pool_kv, l, bids, offs, new)
-    return pool_kv, pool_kv[l, tables]
+    with stage("attn_core"):
+        return pool_kv, pool_kv[l, tables]
 
 
+@stage("pool_write")
 def _write_blocks(pool_kv, l, table, start, new, block_size: int):
     """:func:`_write` at a prefill's grain: ``new`` [T, KH, Dh] are the rows
     of ``T`` consecutive positions from ``start``, whole blocks of them
@@ -251,7 +255,8 @@ def _write_blocks_read(pool_kv, l, table, start, new, block_size: int):
     """:func:`_write_blocks`, then the rows of ``table`` [W] gathered back as
     [W, KH, block, Dh], as :func:`_write_read` does."""
     pool_kv = _write_blocks(pool_kv, l, table, start, new, block_size)
-    return pool_kv, pool_kv[l, table]
+    with stage("attn_core"):
+        return pool_kv, pool_kv[l, table]
 
 
 def _attend_gathered(qg, pk, pv, l, tables, lengths, sink=None, *, window=None, scale=None):
@@ -412,7 +417,8 @@ def decode_attention(kind: AttentionKind, block_size, mesh, interpret):
     """The decode step's attention over the scattered pool of keys and
     values per head, ``attend(qg, pk, pv, l, tables, lengths)`` (and, for a
     kind with a sink, the layer's ``sink`` [KH, group] behind them): the
-    kernel or the gather, as :func:`_choose` says. A kind with a window
+    kernel or the gather, as :func:`_choose` says; its caller opens the
+    stage ``attn_core`` around it. A kind with a window
     attends the last ``window`` positions only, either arm under that mask;
     one without a window, a sink or a name leaves both as they were."""
     kernel, gather = paged_attention.paged_decode_attention, _attend_gathered
@@ -458,6 +464,7 @@ def packed_decode_attention(kind: AttentionKind, block_size, mesh, interpret):
 KEY_POSITIONS = 512
 
 
+@stage("attn_core")
 def prefill_attention(
     q, pk, pv, l, table, pos, n_keys, *, block_size: int, window=None, sink=None,
     key_positions: int = KEY_POSITIONS, name: str = "", interpret: bool = False,
@@ -614,10 +621,12 @@ def state_prefill(step, state, conv, l, slot, fresh):
     tail whatever the slot held, and else from the slot's (a later chunk).
     Returns ``(out, state, conv)`` with the row written back."""
     row = state.shape[1] - 1 if slot is None else slot
-    state0 = jnp.where(fresh, 0.0, state[l, row])
-    tail0 = jnp.where(fresh, 0, conv[l, row])
-    out, state1, tail1 = step(state0, tail0)
-    return out, state.at[l, row].set(state1), conv.at[l, row].set(tail1.astype(conv.dtype))
+    with stage("state_scan"):
+        state0 = jnp.where(fresh, 0.0, state[l, row])
+        tail0 = jnp.where(fresh, 0, conv[l, row])
+    out, state1, tail1 = step(state0, tail0)  # the family's mixer names its own stages
+    with stage("state_scan"):
+        return out, state.at[l, row].set(state1), conv.at[l, row].set(tail1.astype(conv.dtype))
 
 
 def state_decode(step, state, conv, l, rows: int, keep=None, *, interpret: bool = False):
@@ -639,22 +648,26 @@ def state_decode(step, state, conv, l, rows: int, keep=None, *, interpret: bool 
     tests). Returns ``(out, state, conv)``."""
 
     def plain(state, tail0):
-        state0 = state[l, :rows]
-        out, state1, tail1 = step(state0, tail0)
-        if keep is not None:
-            state1 = jnp.where(keep[:, None, None, None], state0, state1)
-        return out, state.at[l, :rows].set(state1), tail1
+        with stage("state_scan"):
+            state0 = state[l, :rows]
+        out, state1, tail1 = step(state0, tail0)  # the family's mixer names its own stages
+        with stage("state_scan"):
+            if keep is not None:
+                state1 = jnp.where(keep[:, None, None, None], state0, state1)
+            return out, state.at[l, :rows].set(state1), tail1
 
     def kernel(state, tail0, interpret=False):
         out, held, tail1 = step(state_step.Rows(state, l, rows, keep, interpret), tail0)
         return out, held.state, tail1
 
-    tail0 = conv[l, :rows]
+    with stage("state_scan"):
+        tail0 = conv[l, :rows]
     fits = state_step.tiles(*state.shape[2:])
     out, state, tail1 = _choose(kernel, plain, fits, interpret)(state, tail0)
-    if keep is not None:
-        tail1 = jnp.where(keep[(slice(None), *[None] * (tail0.ndim - 1))], tail0, tail1)
-    return out, state, conv.at[l, :rows].set(tail1.astype(conv.dtype))
+    with stage("state_scan"):
+        if keep is not None:
+            tail1 = jnp.where(keep[(slice(None), *[None] * (tail0.ndim - 1))], tail0, tail1)
+        return out, state, conv.at[l, :rows].set(tail1.astype(conv.dtype))
 
 
 def state_steps_in_kernel(state, mesh=None) -> bool:
@@ -716,32 +729,36 @@ def paged_prefill(
 
     pos = start + jnp.arange(T, dtype=jnp.int32)  # [T]
     x = embed(params, tokens, pos[None])
-    cols = jnp.arange(S)
-    mask = cols[None, :] <= pos[:, None]  # [T, S]
+    with stage("attn_core"):
+        cols = jnp.arange(S)
+        mask = cols[None, :] <= pos[:, None]  # [T, S]
     scale = 1.0 / (Dh**0.5)
 
     def body(carry, layer):
         x, pk, pv = carry  # pk/pv: the whole pool, [L, N, KH, block, Dh]
         p, l = layer
         q, k, v = qkv(x, p, pos[None])  # q [1,H,T,Dh], k/v [1,KH,T,Dh]
-        kt = k[0].transpose(1, 0, 2)  # [T, KH, Dh]
-        vt = v[0].transpose(1, 0, 2)
+        with stage("attn_proj"):
+            kt = k[0].transpose(1, 0, 2)  # [T, KH, Dh]
+            vt = v[0].transpose(1, 0, 2)
         # This request's row (transient): [W,KH,block,Dh] -> [KH,S,Dh]
         pk, kd = _write_blocks_read(pk, l, table, start, kt, block_size)
         pv, vd = _write_blocks_read(pv, l, table, start, vt, block_size)
-        kd = kd.transpose(1, 0, 2, 3).reshape(KH, S, Dh)
-        vd = vd.transpose(1, 0, 2, 3).reshape(KH, S, Dh)
-        qg = q[0].reshape(KH, group, T, Dh)
-        s = jnp.einsum("kgtd,ksd->kgts", qg, kd).astype(jnp.float32) * scale
-        s = jnp.where(mask[None, None], s, -1e30)
-        pa = jax.nn.softmax(s, axis=-1).astype(vd.dtype)
-        attn = jnp.einsum("kgts,ksd->kgtd", pa, vd).reshape(1, H, T, Dh)
+        with stage("attn_core"):
+            kd = kd.transpose(1, 0, 2, 3).reshape(KH, S, Dh)
+            vd = vd.transpose(1, 0, 2, 3).reshape(KH, S, Dh)
+            qg = q[0].reshape(KH, group, T, Dh)
+            s = jnp.einsum("kgtd,ksd->kgts", qg, kd).astype(jnp.float32) * scale
+            s = jnp.where(mask[None, None], s, -1e30)
+            pa = jax.nn.softmax(s, axis=-1).astype(vd.dtype)
+            attn = jnp.einsum("kgts,ksd->kgtd", pa, vd).reshape(1, H, T, Dh)
         return (finish(x, attn, p), pk, pv), None
 
     x, pool = _scan_layers(body, x, params, pool)
-    last = jax.lax.dynamic_index_in_dim(
-        x[0], (length - 1).astype(jnp.int32), axis=0, keepdims=False
-    )
+    with stage("embed_head"):
+        last = jax.lax.dynamic_index_in_dim(
+            x[0], (length - 1).astype(jnp.int32), axis=0, keepdims=False
+        )
     logits = final(params, last[None])[0]
     return pool, logits
 
@@ -777,28 +794,32 @@ def paged_verify(
 
     pos2d = positions[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
     x = embed(params, tokens, pos2d)  # [B, T, D]
-    rows = jnp.arange(B)
-    bids = tables[rows[:, None], pos2d // block_size]  # [B, T]
-    offs = pos2d % block_size
-    cols = jnp.arange(S)
-    mask = cols[None, None, :] <= pos2d[:, :, None]  # [B, T, S]
+    with stage("pool_write"):
+        rows = jnp.arange(B)
+        bids = tables[rows[:, None], pos2d // block_size]  # [B, T]
+        offs = pos2d % block_size
+    with stage("attn_core"):
+        cols = jnp.arange(S)
+        mask = cols[None, None, :] <= pos2d[:, :, None]  # [B, T, S]
     scale = 1.0 / (Dh**0.5)
 
     def body(carry, layer):
         x, pk, pv = carry  # pk/pv: the whole pool, [L, N, KH, block, Dh]
         p, l = layer
         q, k, v = qkv(x, p, pos2d)  # q [B,H,T,Dh], k/v [B,KH,T,Dh]
-        kt = k.transpose(0, 2, 1, 3)  # [B, T, KH, Dh]
-        vt = v.transpose(0, 2, 1, 3)
+        with stage("attn_proj"):
+            kt = k.transpose(0, 2, 1, 3)  # [B, T, KH, Dh]
+            vt = v.transpose(0, 2, 1, 3)
         pk, kd = _write_read(pk, l, bids, offs, kt, tables)
         pv, vd = _write_read(pv, l, bids, offs, vt, tables)
-        kd = kd.transpose(0, 2, 1, 3, 4).reshape(B, KH, S, Dh)
-        vd = vd.transpose(0, 2, 1, 3, 4).reshape(B, KH, S, Dh)
-        qg = q.reshape(B, KH, group, T, Dh)
-        s = jnp.einsum("bkgtd,bksd->bkgts", qg, kd).astype(jnp.float32)
-        s = jnp.where(mask[:, None, None], s * scale, -1e30)
-        pa = jax.nn.softmax(s, axis=-1).astype(vd.dtype)
-        attn = jnp.einsum("bkgts,bksd->bkgtd", pa, vd).reshape(B, H, T, Dh)
+        with stage("attn_core"):
+            kd = kd.transpose(0, 2, 1, 3, 4).reshape(B, KH, S, Dh)
+            vd = vd.transpose(0, 2, 1, 3, 4).reshape(B, KH, S, Dh)
+            qg = q.reshape(B, KH, group, T, Dh)
+            s = jnp.einsum("bkgtd,bksd->bkgts", qg, kd).astype(jnp.float32)
+            s = jnp.where(mask[:, None, None], s * scale, -1e30)
+            pa = jax.nn.softmax(s, axis=-1).astype(vd.dtype)
+            attn = jnp.einsum("bkgts,bksd->bkgtd", pa, vd).reshape(B, H, T, Dh)
         return (finish(x, attn, p), pk, pv), None
 
     x, pool = _scan_layers(body, x, params, pool)
@@ -845,10 +866,12 @@ def paged_decode(
     attend = decode_attention(attention_kind(cfg), block_size, mesh, interpret)
 
     x = embed(params, last_tokens[:, None], positions[:, None])  # [B,1,D]
-    rows = jnp.arange(B)
-    bids = tables[rows, positions // block_size]  # [B]
-    offs = positions % block_size
-    lengths = positions + 1  # the step's own key is attended
+    with stage("pool_write"):
+        rows = jnp.arange(B)
+        bids = tables[rows, positions // block_size]  # [B]
+        offs = positions % block_size
+    with stage("attn_core"):
+        lengths = positions + 1  # the step's own key is attended
 
     def body(carry, layer):
         x, pk, pv = carry  # pk/pv: the whole pool, [L, N, KH, block, Dh]
@@ -856,8 +879,12 @@ def paged_decode(
         q, k, v = qkv(x, p, positions[:, None])  # [B,{H,KH},1,Dh]
         pk = _write(pk, l, bids, offs, k[:, :, 0, :])
         pv = _write(pv, l, bids, offs, v[:, :, 0, :])
-        qg = q[:, :, 0, :].reshape(B, KH, group, Dh)
-        attn = attend(qg, pk, pv, l, tables, lengths).reshape(B, H, 1, Dh)
+        with stage("attn_proj"):
+            qg = q[:, :, 0, :].reshape(B, KH, group, Dh)
+        with stage("attn_core"):
+            attn = attend(qg, pk, pv, l, tables, lengths)
+        with stage("attn_proj"):
+            attn = attn.reshape(B, H, 1, Dh)
         return (finish(x, attn, p), pk, pv), None
 
     x, pool = _scan_layers(body, x, params, pool)

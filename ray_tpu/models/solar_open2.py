@@ -48,7 +48,7 @@ import jax.numpy as jnp
 from ray_tpu.models import latent_moe, paged
 from ray_tpu.models.kda import draw_kda, kda_decode, kda_prefill
 from ray_tpu.models.latent_moe import ffn, final_logits, outputs
-from ray_tpu.models.common import _rms_norm
+from ray_tpu.models.common import _rms_norm, stage
 
 Params = dict
 _F32 = jnp.float32
@@ -219,6 +219,7 @@ def draw_params(key: jax.Array, cfg: SolarOpen2Config) -> Params:
 # GQA mixer (keys and values in the block pool, models/paged.py's way)
 
 
+@stage("attn_proj")
 def _qkvg(a, p, cfg: SolarOpen2Config):
     """``a`` [..., D] normed -> ``(q [..., KH, group, Dh], k, v [..., KH, Dh], g
     [..., H Dh])``: nothing is rotated and nothing normed a head."""
@@ -231,6 +232,7 @@ def _qkvg(a, p, cfg: SolarOpen2Config):
     return q, k, v, a @ p["wg"].astype(dt)
 
 
+@stage("attn_proj")
 def _gated_out(o, g, p, cfg: SolarOpen2Config):
     """``W_o (o sigmoid(g))``: ``o`` [..., KH, group, Dh], the gate elementwise
     over the heads' values."""
@@ -312,10 +314,13 @@ def paged_prefill(
 
     pos = start + jnp.arange(T, dtype=jnp.int32)
     valid = jnp.arange(T) < length
-    x = params["wte"].astype(cfg.dtype)[tokens[0]]
+    with stage("embed_head"):
+        x = params["wte"].astype(cfg.dtype)[tokens[0]]
     seen: list = []
     for i, kind, p, l in _layers(params, cfg):
-        a = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
+        mixer_in, mixer_out = ("state_in", "state_out") if kind == KDA else ("attn_proj", "attn_proj")
+        with stage(mixer_in):
+            a = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
         if kind == KDA:
             out, state, conv = paged.state_prefill(
                 lambda S, tail: kda_prefill(a, p, cfg, S, tail, length),
@@ -329,8 +334,11 @@ def paged_prefill(
                 q, pk, pv, l, table, pos, start + length, block_size=block_size
             )
             out = _gated_out(o, g, p, cfg)
-        x = ffn(x + out, p, cfg, i, valid, seen)
-    last = jax.lax.dynamic_index_in_dim(x, (length - 1).astype(jnp.int32), 0, keepdims=False)
+        with stage(mixer_out):
+            x = x + out
+        x = ffn(x, p, cfg, i, valid, seen)
+    with stage("embed_head"):
+        last = jax.lax.dynamic_index_in_dim(x, (length - 1).astype(jnp.int32), 0, keepdims=False)
     logits = final_logits(params, last[None], cfg)[0]
     return outputs({"k": pk, "v": pv, "state": state, "conv": conv}, logits, seen, with_picks)
 
@@ -351,14 +359,20 @@ def paged_decode(
     B = last_tokens.shape[0]
     pk, pv, state, conv = pool["k"], pool["v"], pool["state"], pool["conv"]
     attend = paged.decode_attention(paged.attention_kind(cfg), block_size, None, interpret)
-    keep = None if live is None else ~live
-    bids = tables[jnp.arange(B), positions // block_size]
-    offs = positions % block_size
-    lengths = positions + 1  # the step's own key is attended
-    x = params["wte"].astype(cfg.dtype)[last_tokens]
+    with stage("state_scan"):
+        keep = None if live is None else ~live
+    with stage("pool_write"):
+        bids = tables[jnp.arange(B), positions // block_size]
+        offs = positions % block_size
+    with stage("attn_core"):
+        lengths = positions + 1  # the step's own key is attended
+    with stage("embed_head"):
+        x = params["wte"].astype(cfg.dtype)[last_tokens]
     seen: list = []
     for i, kind, p, l in _layers(params, cfg):
-        a = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
+        mixer_in, mixer_out = ("state_in", "state_out") if kind == KDA else ("attn_proj", "attn_proj")
+        with stage(mixer_in):
+            a = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
         if kind == KDA:
             out, state, conv = paged.state_decode(
                 lambda S, tail: kda_decode(a, p, cfg, S, tail), state, conv, l, B, keep
@@ -367,9 +381,12 @@ def paged_decode(
             q, k, v, g = _qkvg(a, p, cfg)
             pk = paged._write(pk, l, bids, offs, k)
             pv = paged._write(pv, l, bids, offs, v)
-            o = attend(q, pk, pv, jnp.asarray(l, jnp.int32), tables, lengths)
+            with stage("attn_core"):
+                o = attend(q, pk, pv, jnp.asarray(l, jnp.int32), tables, lengths)
             out = _gated_out(o, g, p, cfg)
-        x = ffn(x + out, p, cfg, i, live, seen)
+        with stage(mixer_out):
+            x = x + out
+        x = ffn(x, p, cfg, i, live, seen)
     return outputs(
         {"k": pk, "v": pv, "state": state, "conv": conv}, final_logits(params, x, cfg), seen, with_picks
     )

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ray_tpu.models.common import stage
+
 # TrainState is a plain pytree dict: {"params", "opt_state", "step"} —
 # checkpointable with orbax, shardable leaf-by-leaf, no framework classes.
 TrainState = dict
@@ -81,21 +83,22 @@ def make_train_step(
             )
         grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
         (_, metrics), grads = grad_fn(state["params"], batch)
-        updates, new_opt = optimizer.update(
-            grads, state["opt_state"], state["params"]
-        )
-        new_params = optax.apply_updates(state["params"], updates)
-        if param_shardings is not None:
-            new_params = jax.tree.map(
-                lambda x, s: jax.lax.with_sharding_constraint(x, s),
-                new_params,
-                param_shardings,
+        with stage("optimizer"):
+            updates, new_opt = optimizer.update(
+                grads, state["opt_state"], state["params"]
             )
-        new_state = {
-            "params": new_params,
-            "opt_state": new_opt,
-            "step": state["step"] + 1,
-        }
+            new_params = optax.apply_updates(state["params"], updates)
+            if param_shardings is not None:
+                new_params = jax.tree.map(
+                    lambda x, s: jax.lax.with_sharding_constraint(x, s),
+                    new_params,
+                    param_shardings,
+                )
+            new_state = {
+                "params": new_params,
+                "opt_state": new_opt,
+                "step": state["step"] + 1,
+            }
         return new_state, metrics
 
     donate = ()

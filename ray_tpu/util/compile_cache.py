@@ -3,6 +3,14 @@
 JAX's persistent compilation cache is keyed by its directory among other
 things, so the directory must not move: every worker of a run, and the next
 run, only find what was compiled before at the same path.
+
+**Metadata is part of the key.** By default this jax leaves an instruction's
+metadata (its ``op_name``, the file and line) out of the key, so a program
+that differs from a cached one by its scope names alone (the stages of
+``models/common.py:stage``) would be handed the old executable, with the old
+names, and a device trace read by stage would read another commit's stages or
+none. With the metadata in the key that cannot happen; the price is that an
+edit which moves a traced line compiles its programs again.
 """
 
 from __future__ import annotations
@@ -10,6 +18,8 @@ from __future__ import annotations
 import os
 
 CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+# The environment form of jax_compilation_cache_include_metadata_in_key.
+METADATA_IN_KEY_ENV = "JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY"
 # <checkout>/.jax_cache (listed in .gitignore), from this file's location.
 DEFAULT_CACHE_DIR = os.path.join(
     os.path.dirname(
@@ -23,9 +33,11 @@ def ensure_compile_cache() -> str:
     """Make sure JAX_COMPILATION_CACHE_DIR is set and return it.
 
     A directory placed from outside stands; otherwise the fixed one inside
-    the checkout is used. Only the environment variable is set (jax reads
-    it when it is imported, so call this first), and worker processes
-    inherit it from whoever spawned them."""
+    the checkout is used. Wherever the cache is, an entry is found only by a
+    program of the same metadata (module docstring). Only environment
+    variables are set (jax reads them when it is imported, so call this
+    first), and worker processes inherit them from whoever spawned them."""
+    os.environ[METADATA_IN_KEY_ENV] = "true"
     return os.environ.setdefault(CACHE_DIR_ENV, DEFAULT_CACHE_DIR)
 
 

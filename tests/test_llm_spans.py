@@ -510,6 +510,36 @@ def test_pump_gap_lies_between_two_steps_and_not_after_a_dry_engine():
     assert [s["extra"]["ahead"] for s in steps] == [1, 1, 0, 1, 0]
 
 
+def test_idle_spans_the_dry_spell_and_nothing_while_the_engine_is_busy():
+    """``llm.idle`` runs from the return of the last step of a pump that ran
+    dry to the start of the next pump: with ``llm.step`` and ``llm.pump_gap``
+    it tiles the engine's life. None before the first request, none between
+    the turns of a stream, none after the last pump (nothing has closed it)."""
+    server = LLMServer(llm_config())
+
+    async def stream(n):
+        return [p async for p in server._stream_tokens("hello", SamplingParams(max_tokens=n))]
+
+    async def scenario():
+        await stream(4)
+        await server._pump_task  # ran dry
+        busy = len(of("llm.idle"))
+        await asyncio.sleep(0.05)
+        await stream(3)
+        await server._pump_task
+        return busy
+
+    assert asyncio.run(scenario()) == 0
+    (idle,) = of("llm.idle")
+    turns, gaps = of("llm.step"), of("llm.pump_gap")
+    assert len(turns) == 5
+    assert idle["t"] == end(turns[2]) and idle["dur_s"] >= 0.05
+    assert end(idle) <= turns[3]["t"] < end(idle) + 50 * MS  # the new pump's hop into the executor
+    for gap in gaps:  # a gap and the dry spell never overlap
+        assert end(gap) <= idle["t"] or gap["t"] >= end(idle)
+    assert server._t_ran_dry is not None  # the second dry spell is open still
+
+
 # -- the hop into the replica -------------------------------------------------
 
 
