@@ -465,6 +465,13 @@ class LLMEngine:
                 if paged.state_steps_in_kernel(self.pool["state"], self.mesh)
                 else "state_plain_steps"
             )
+        if self._cache.delta_rule:
+            # Prefill (or chunk) programs launched, by the arm their
+            # delta-rule layers' scan was built with
+            # (paged.prefill_scans_in_kernel: platform, the heads' widths and
+            # the program's bucket decide).
+            self.stats["prefill_scan_kernel_runs"] = 0  # state and chunk held on the chip
+            self.stats["prefill_scan_plain_runs"] = 0  # lax.scan over XLA's fusions
         if self._cache.prefill_in_place:
             # Prefill programs launched, by the arm their attention was built
             # with (paged.prefill_attends_in_kernel: platform, the kinds'
@@ -922,6 +929,9 @@ class LLMEngine:
         if self._moe_arm:
             self.stats[self._moe_arm] += 1
         bucket = toks.shape[1]
+        if self._cache.delta_rule:
+            in_kernel = paged.prefill_scans_in_kernel(self.pool["state"], bucket, self.mesh)
+            self.stats[f"prefill_scan_{'kernel' if in_kernel else 'plain'}_runs"] += 1
         if self._cache.prefill_in_place:
             in_kernel = paged.prefill_attends_in_kernel(
                 self.model_config, self._block_size, bucket, mesh=self.mesh

@@ -14,8 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.common import _rms_norm, stage
-from ray_tpu.ops import state_step
-from ray_tpu.ops.delta_rule import kda_chunked
+from ray_tpu.ops import delta_scan, state_step
 
 Params = dict
 _F32 = jnp.float32
@@ -80,8 +79,10 @@ def _kda_output(h, o, p, cfg):
 
 def kda_prefill(h, p, cfg, S0, tail, length):
     """``h`` [T, D] normed, of which the first ``length`` rows are tokens;
-    ``S0`` [H, d_k, d_v] and ``tail`` [K-1, 3 H d] are the state and the last
-    pre-convolution rows before row 0 (zeros at the start of a sequence).
+    ``S0`` [H, d_k, d_v] (or, where the scan's kernel runs, a
+    :class:`ray_tpu.ops.delta_scan.Held` of it) and ``tail`` [K-1, 3 H d] are
+    the state and the last pre-convolution rows before row 0 (zeros at the
+    start of a sequence).
     Returns ``(out [T, D], S, tail)`` as of row ``length``: padded rows do not
     touch the state."""
     T, K = h.shape[0], cfg.conv_kernel
@@ -94,7 +95,7 @@ def kda_prefill(h, p, cfg, S0, tail, length):
         live = (jnp.arange(T) < length)[:, None]
         g, beta = g * live[..., None], beta * live
     with stage("state_scan"):
-        o, S = kda_chunked(q, k, v, g, beta, S0)
+        o, S = delta_scan.kda(q, k, v, g, beta, S0)
         tail = jax.lax.dynamic_slice_in_dim(x, length, K - 1, axis=0)
     return _kda_output(h, o, p, cfg), S, tail
 

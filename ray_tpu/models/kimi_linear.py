@@ -250,7 +250,7 @@ def span_fields(cfg: KimiLinearConfig, counts, tokens: int, slots: int, decode=N
 
 def cache(cfg: KimiLinearConfig) -> paged.Cache:
     """Latent rows in blocks, a delta-rule state and a tail per slot."""
-    return paged.Cache(slot_state=True, per_head=False)
+    return paged.Cache(slot_state=True, delta_rule=True, per_head=False)
 
 
 def init_pool(cfg: KimiLinearConfig, num_blocks: int, block_size: int, slots=None):
@@ -277,7 +277,7 @@ def _layers(params, cfg):
 
 def paged_prefill(
     params, tokens, length, start, table, pool, cfg: KimiLinearConfig, *,
-    block_size: int, slot=None, with_picks: bool = False,
+    block_size: int, slot=None, with_picks: bool = False, interpret: bool = False,
 ):
     """Prefill positions [start, start + T) of one sequence; operands as
     :func:`ray_tpu.models.paged.paged_prefill`, plus ``slot``, the row of the
@@ -286,7 +286,8 @@ def paged_prefill(
     held; ``start > 0`` continues from the slot's (a later chunk). Returns
     ``(pool, last_logits [vocab] float32, counts int32 [expert layers, 2])``,
     and with ``with_picks`` the chosen experts [expert layers, T, k] (for the
-    benchmark's comparison of routing)."""
+    benchmark's comparison of routing). ``interpret``: the KDA layers' scan
+    kernel in the Pallas interpreter (the tests)."""
     T = tokens.shape[1]
     ckv, state, conv = pool["ckv"], pool["state"], pool["conv"]
     fresh = start == 0
@@ -305,7 +306,7 @@ def paged_prefill(
         if kind == "kda":
             out, state, conv = paged.state_prefill(
                 lambda S, tail: kda_prefill(h, p, cfg, S, tail, length),
-                state, conv, l, slot, fresh,
+                state, conv, l, slot, fresh, scan_rows=T, interpret=interpret,
             )
         else:
             row = mla_latent(h, p, cfg)

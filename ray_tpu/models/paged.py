@@ -54,7 +54,9 @@ and nothing else about a family's cache.
   sequence begins from zero whatever the slot held, a later chunk continues
   from the slot's, a decode step leaves a slot that is not live as it was.
   On a TPU, at tiles that are whole, a decode step holds a slot's tile in
-  VMEM while it is stepped (``ops/state_step.py``): read once, written once.
+  VMEM while it is stepped (``ops/state_step.py``): read once, written once;
+  and a prefill of a delta rule's state (``Cache.delta_rule``) scans its
+  chunks with the state held there too (``ops/delta_scan.py``).
 - *A second table kind that keeps a window*: the blocks in two parts
   (``{"full": {"k", "v"}, "window": {"k", "v"}}``), a slot a table for each
   (``tables [..., kinds, W]``; behind the window a window table points at the
@@ -85,7 +87,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.common import stage
-from ray_tpu.ops import paged_attention, paged_prefill_attention, state_step
+from ray_tpu.ops import delta_scan, paged_attention, paged_prefill_attention, state_step
 
 Params = dict
 
@@ -134,6 +136,9 @@ class Cache:
     # holds a block table a kind.
     retention: tuple = (None,)
     slot_state: bool = False  # a state and a tail per slot beside the blocks
+    # ... and the state is a gated delta rule's [H, d_k, d_v], whose prefill
+    # scan has a kernel arm (:func:`state_prefill`)
+    delta_rule: bool = False
     per_head: bool = True  # rows in blocks: keys and values per head, or latent rows
     hooks: bool = False  # served through kv_hooks by this module's programs
     # The kinds of attention layer over keys and values per head, a table
@@ -611,7 +616,7 @@ def latent_decode_attention(cfg, block_size, mesh, interpret, scale: float):
     )
 
 
-def state_prefill(step, state, conv, l, slot, fresh):
+def state_prefill(step, state, conv, l, slot, fresh, *, scan_rows=None, interpret: bool = False):
     """The prefill side of a state and a tail per slot: layer ``l`` of
     ``state`` [L', slots + 1, ...] and ``conv`` [L', slots + 1, K - 1, C]
     through the family's mixer, ``step(state0, tail0) -> (out, state1,
@@ -619,12 +624,32 @@ def state_prefill(step, state, conv, l, slot, fresh):
     last). ``fresh``: whether the sequence begins with this call (``start ==
     0``, worked out once a program): it then starts from zero and an empty
     tail whatever the slot held, and else from the slot's (a later chunk).
-    Returns ``(out, state, conv)`` with the row written back."""
+    Returns ``(out, state, conv)`` with the row written back.
+
+    ``scan_rows``: given by a family whose state is a gated delta rule's
+    ``[H, d_k, d_v]`` (``Cache.delta_rule``), the rows of the prefill: its
+    step scans through :func:`ray_tpu.ops.delta_scan.kda`
+    (``models/kda.py:kda_prefill``), whose chunked scan has two arms, chosen
+    as :func:`_choose` says: where the program is lowered for a TPU and the
+    rows and heads are the kernel's (:func:`prefill_scans_in_kernel`) ``state0`` is a
+    :class:`ray_tpu.ops.delta_scan.Held`, from which that scans with the
+    state and a chunk's values on the chip, one Pallas call a layer; an array,
+    and the plain ``lax.scan``, elsewhere. ``interpret`` runs the kernel in
+    the Pallas interpreter whatever the platform and the shapes (the tests)."""
     row = state.shape[1] - 1 if slot is None else slot
     with stage("state_scan"):
         state0 = jnp.where(fresh, 0.0, state[l, row])
         tail0 = jnp.where(fresh, 0, conv[l, row])
-    out, state1, tail1 = step(state0, tail0)  # the family's mixer names its own stages
+
+    def kernel(state0, tail0, interpret=False):
+        return step(delta_scan.Held(state0, interpret), tail0)
+
+    # the family's mixer names its own stages
+    delta_rule = scan_rows is not None
+    out, state1, tail1 = _choose(
+        kernel, step, delta_rule and delta_scan.tiles(scan_rows, *state.shape[2:]),
+        delta_rule and interpret,
+    )(state0, tail0)
     with stage("state_scan"):
         return out, state.at[l, row].set(state1), conv.at[l, row].set(tail1.astype(conv.dtype))
 
@@ -670,6 +695,15 @@ def state_decode(step, state, conv, l, rows: int, keep=None, *, interpret: bool 
         return out, state, conv.at[l, :rows].set(tail1.astype(conv.dtype))
 
 
+def prefill_scans_in_kernel(state, tokens: int, mesh=None) -> bool:
+    """Whether a prefill program of ``tokens`` rows built in this process
+    scans a delta rule's ``state`` ``[L', slots + 1, H, d_k, d_v]`` through
+    the kernel of :mod:`ray_tpu.ops.delta_scan` (:func:`state_prefill`'s
+    choice, which platform, rows and shapes make:
+    :func:`ray_tpu.ops.delta_scan.fits`)."""
+    return delta_scan.fits(tokens, *state.shape[2:], mesh)
+
+
 def state_steps_in_kernel(state, mesh=None) -> bool:
     """Whether a decode program built in this process steps a pool's
     ``state`` ``[L', slots + 1, H, a, b]`` through the kernel of
@@ -707,6 +741,8 @@ def paged_prefill(
     block_size: int,
     slot=None,  # scalar int32 — a family with a state per slot: the
     #             sequence's row of it (None: the scratch row)
+    interpret: bool = False,  # a delta-rule family's scan kernel in the
+    #             Pallas interpreter, whatever the platform and shapes (tests)
 ):
     """Prefill positions [start, start+T) into the pool; return
     (pool, last_logits [vocab] f32), and a third value, its counters, from
@@ -719,7 +755,7 @@ def paged_prefill(
     if not cache(cfg).hooks:
         return mod.paged_prefill(
             params, tokens, length, start, table, pool, cfg,
-            block_size=block_size, slot=slot,
+            block_size=block_size, slot=slot, **({"interpret": True} if interpret else {}),
         )
     B, T = tokens.shape
     W = table.shape[0]

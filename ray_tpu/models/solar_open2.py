@@ -267,7 +267,7 @@ def span_fields(cfg: SolarOpen2Config, counts, tokens: int, slots: int, decode=N
 def cache(cfg: SolarOpen2Config) -> paged.Cache:
     """Keys and values per head in blocks (the GQA layers'), a delta-rule
     state and a tail per slot (the KDA layers')."""
-    return paged.Cache(slot_state=True, prefill_in_place=True)
+    return paged.Cache(slot_state=True, delta_rule=True, prefill_in_place=True)
 
 
 def init_pool(cfg: SolarOpen2Config, num_blocks: int, block_size: int, slots=None):
@@ -297,7 +297,7 @@ def _layers(params, cfg: SolarOpen2Config):
 
 def paged_prefill(
     params, tokens, length, start, table, pool, cfg: SolarOpen2Config, *,
-    block_size: int, slot=None, with_picks: bool = False,
+    block_size: int, slot=None, with_picks: bool = False, interpret: bool = False,
 ):
     """Prefill positions [start, start + T) of one sequence; operands as
     :func:`ray_tpu.models.paged.paged_prefill`, plus ``slot``, the row of the
@@ -307,7 +307,9 @@ def paged_prefill(
     attends the rows the earlier chunks left in the pool under ``table``: a
     later chunk. Returns ``(pool, last_logits [vocab] float32, counts int32
     [layers, 2])``, and with ``with_picks`` the chosen experts [layers, T, k]
-    (for the balance and the benchmark's comparison of routing)."""
+    (for the balance and the benchmark's comparison of routing).
+    ``interpret``: the KDA layers' scan kernel in the Pallas interpreter (the
+    tests)."""
     T = tokens.shape[1]
     pk, pv, state, conv = pool["k"], pool["v"], pool["state"], pool["conv"]
     fresh = start == 0
@@ -324,7 +326,7 @@ def paged_prefill(
         if kind == KDA:
             out, state, conv = paged.state_prefill(
                 lambda S, tail: kda_prefill(a, p, cfg, S, tail, length),
-                state, conv, l, slot, fresh,
+                state, conv, l, slot, fresh, scan_rows=T, interpret=interpret,
             )
         else:
             q, k, v, g = _qkvg(a, p, cfg)

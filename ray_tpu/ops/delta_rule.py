@@ -50,7 +50,12 @@ could be inverted for every chunk of a call ahead of the scan and the loop
 left with the state's products; on a v5e that was slower than the parent's
 solve inside the loop, because every intermediate the size of ``k`` then goes
 out to the device's memory and comes back (PERF.md section 6, PR 45). So all
-of it runs inside the scan, a chunk a step.
+of it runs inside the scan, a chunk a step. What keeps a chunk's values on
+the chip is a kernel, and it exists (PR 54): :mod:`ray_tpu.ops.delta_scan`,
+the same mathematics as one Pallas call a layer, which a prefill program
+lowered for a TPU runs in this form's place (``models/paged.py:
+state_prefill``). This form stays the definition: the CPU, a mesh, widths
+that are not a lane tile, and the tests' other arm.
 
 The pair terms ``kk[t, i] = k_t . (k_i exp(G_t - G_i))`` and ``qk[t, i]``
 (``q_t`` for ``k_t``), ``i <= t``, carry a decay per key channel, so they are
@@ -78,8 +83,8 @@ intra-chunk kernels use; on a v5e at ``[2048, 64, 128]`` it was timed against
 8, 32 and 64 (``tools/delta_rule_chip.py``; PERF.md section 6, PR 44).
 
 A position with ``beta = 0`` and ``g = 0`` leaves the state as it was (the
-padded tail of a prefill bucket). State and accumulation are float32; plain
-``jax.numpy``, no kernel.
+padded tail of a prefill bucket). State and accumulation are float32; this
+module is plain ``jax.numpy``, and the kernel beside it is held to it.
 """
 
 from __future__ import annotations

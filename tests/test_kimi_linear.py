@@ -21,7 +21,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from benchmarks.reference import kimi_linear_ref as ref  # noqa: E402
 from ray_tpu.models import kda, kimi_linear as kl, latent_moe, paged  # noqa: E402
 from ray_tpu.models.llama import LlamaConfig  # noqa: E402
-from ray_tpu.ops import delta_rule  # noqa: E402
+from ray_tpu.ops import delta_rule, delta_scan  # noqa: E402
 
 
 def ref_config(cfg: kl.KimiLinearConfig) -> dict:
@@ -46,6 +46,16 @@ def ref_config(cfg: kl.KimiLinearConfig) -> dict:
     )
 
 
+# The chunked scan's two lowerings, held to the same closeness: the plain
+# ``lax.scan`` (the one definition) and the kernel that keeps the state and a
+# chunk's values on the chip, here in the Pallas interpreter.
+ARMS = {
+    "plain": delta_rule.kda_chunked,
+    "kernel_interpreted": functools.partial(delta_scan.kda_scan, interpret=True),
+}
+arms = pytest.mark.parametrize("arm", list(ARMS))
+
+
 def _kda_inputs(key, T, H=3, d=8, decay=(0.001, 1.6), beta_max=1.0):
     ks = jax.random.split(key, 6)
     l2 = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa: E731
@@ -67,12 +77,13 @@ def _kda_inputs(key, T, H=3, d=8, decay=(0.001, 1.6), beta_max=1.0):
     # edges; the last two are the two cells' heads cut down at their width.
     [(T, 3, 8) for T in (1, 15, 16, 17, 63, 64, 79, 130)] + [(70, 2, 128), (40, 4, 128)],
 )
-def test_kda_chunked_is_the_step_is_the_recurrence(T, H, d, beta_max):
+@arms
+def test_kda_chunked_is_the_step_is_the_recurrence(T, H, d, beta_max, arm):
     """``beta`` in (0, 1), Kimi Linear's, and in (0, 2), Solar Open 2's: past
     1 the transition has a negative eigenvalue along ``k``."""
     q, k, v, g, beta, S0 = _kda_inputs(jax.random.key(T), T, H, d, beta_max=beta_max)
     assert float(beta.max()) < beta_max and (T == 1 or float(beta.max()) > beta_max / 2)
-    o_c, S_c = delta_rule.kda_chunked(q, k, v, g, beta, S0)
+    o_c, S_c = ARMS[arm](q, k, v, g, beta, S0)
     S, o_s = S0, []
     for t in range(T):
         o, S = delta_rule.kda_step(q[t], k[t], v[t], g[t], beta[t], S)
@@ -88,7 +99,8 @@ def test_kda_chunked_is_the_step_is_the_recurrence(T, H, d, beta_max):
     "over, decay, beta_max",
     [("chunk", (2.0, 6.0), 1.0), ("chunk", (2.0, 6.0), 2.0), ("sub_block", (6.0, 9.0), 2.0)],
 )
-def test_kda_chunked_survives_decays_no_product_could_be_divided_by(over, decay, beta_max):
+@arms
+def test_kda_chunked_survives_decays_no_product_could_be_divided_by(over, decay, beta_max, arm):
     """exp(-sum of g) over a chunk, or over one sub-block of it, is far past
     float32 here: the chunked form must never form it. Past a sub-block the
     factor ``exp(G_r - G_i)`` of an earlier column underflows while a row's
@@ -101,7 +113,7 @@ def test_kda_chunked_survives_decays_no_product_could_be_divided_by(over, decay,
         G = jnp.cumsum(g[: delta_rule.CHUNK], axis=0)
         assert float(jnp.exp(G[delta_rule.BLOCK] - G[0]).max()) == 0.0
         assert float(jnp.exp(G[delta_rule.BLOCK + 1] - G[delta_rule.BLOCK]).min()) > 0.0
-    o_c, S_c = delta_rule.kda_chunked(q, k, v, g, beta, S0)
+    o_c, S_c = ARMS[arm](q, k, v, g, beta, S0)
     o_r, S_r = ref.kda_recurrence(*(a[None] for a in (q, k, v, g, beta, S0)))
     assert np.isfinite(np.asarray(o_c)).all() and np.isfinite(np.asarray(S_c)).all()
     np.testing.assert_allclose(o_c, o_r[0], rtol=2e-4, atol=2e-5)
@@ -181,7 +193,8 @@ def test_the_inverse_by_blocks_is_the_triangular_solve(H, size):
 
 @pytest.mark.parametrize("decay", [(0.01, 0.1), (0.001, 1.6)], ids=["slow_decay", "initialiser_decay"])
 @pytest.mark.parametrize("T, H, d", [(200, 3, 8), (130, 2, 128)])
-def test_kda_chunked_at_beta_2_on_keys_nearly_parallel(T, H, d, decay):
+@arms
+def test_kda_chunked_at_beta_2_on_keys_nearly_parallel(T, H, d, decay, arm):
     """The system's worst case here: ``beta`` 1.99 at every position and keys
     a few degrees apart, so every entry under the diagonal is near 2 (where
     the decay leaves it: a channel keeps half of itself over a chunk at the
@@ -196,17 +209,18 @@ def test_kda_chunked_at_beta_2_on_keys_nearly_parallel(T, H, d, decay):
     k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
     assert float(jnp.einsum("thd,shd->hts", k, k).min()) > 0.7
     beta = jnp.full((T, H), 1.99)
-    o_c, S_c = delta_rule.kda_chunked(q, k, v, g, beta, S0)
+    o_c, S_c = ARMS[arm](q, k, v, g, beta, S0)
     o_r, S_r = ref.kda_recurrence(*(a[None] for a in (q, k, v, g, beta, S0)))
     np.testing.assert_allclose(o_c, o_r[0], rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(S_c, S_r[0], rtol=2e-4, atol=2e-5)
 
 
-def test_kda_positions_with_beta_0_and_g_0_leave_the_state_alone():
+@arms
+def test_kda_positions_with_beta_0_and_g_0_leave_the_state_alone(arm):
     q, k, v, g, beta, S0 = _kda_inputs(jax.random.key(3), 40)
     live = (jnp.arange(40) < 27)[:, None]
-    _, S_pad = delta_rule.kda_chunked(q, k, v, g * live[..., None], beta * live, S0)
-    _, S_cut = delta_rule.kda_chunked(q[:27], k[:27], v[:27], g[:27], beta[:27], S0)
+    _, S_pad = ARMS[arm](q, k, v, g * live[..., None], beta * live, S0)
+    _, S_cut = ARMS[arm](q[:27], k[:27], v[:27], g[:27], beta[:27], S0)
     np.testing.assert_allclose(S_pad, S_cut, rtol=1e-6, atol=1e-7)
 
 
@@ -216,22 +230,25 @@ def tiny():
     return cfg, kl.init_params(jax.random.key(0), cfg)
 
 
-def test_kda_prefill_padded_tail_and_continuation(tiny):
+@arms
+def test_kda_prefill_padded_tail_and_continuation(tiny, arm):
     """The state and the convolution tail after a padded bucket are those at
     ``length``; a second chunk that continues from them gives what one
-    prefill of the whole gives."""
+    prefill of the whole gives. By either arm of the scan: the kernel's takes
+    the state as :func:`paged.state_prefill` hands it over, held."""
     cfg, params = tiny
     p = params["layers"][0]
     h = jax.random.normal(jax.random.key(1), (48, cfg.d_model))
     H, d = cfg.kda_heads, cfg.kda_head_dim
-    zeros = jnp.zeros((H, d, d)), jnp.zeros((cfg.conv_kernel - 1, cfg.conv_dim))
+    held = (lambda S: S) if arm == "plain" else functools.partial(delta_scan.Held, interpret=True)
+    zeros = held(jnp.zeros((H, d, d))), jnp.zeros((cfg.conv_kernel - 1, cfg.conv_dim))
     whole, S_w, tail_w = kda.kda_prefill(h[:37], p, cfg, *zeros, 37)
     padded, S_p, tail_p = kda.kda_prefill(h, p, cfg, *zeros, 37)
     np.testing.assert_allclose(S_p, S_w, rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(tail_p, tail_w, rtol=1e-6)
     np.testing.assert_allclose(padded[:37], whole, rtol=1e-5, atol=1e-6)
     first, S_1, tail_1 = kda.kda_prefill(h[:16], p, cfg, *zeros, 16)
-    second, S_2, tail_2 = kda.kda_prefill(h[16:37], p, cfg, S_1, tail_1, 21)
+    second, S_2, tail_2 = kda.kda_prefill(h[16:37], p, cfg, held(S_1), tail_1, 21)
     np.testing.assert_allclose(jnp.concatenate([first, second]), whole, rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(S_2, S_w, rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(tail_2, tail_w, rtol=1e-6)
@@ -395,7 +412,8 @@ def test_paged_prefill_and_decode_are_the_reference_forward(tiny, interpret):
     decode steps with a free and a prefilling-like (not live) slot beside
     them: logits against the reference's full forward, the latent layers'
     decode by the gather and by the kernel over live blocks (interpreted:
-    on a TPU these rows of 576 would gather)."""
+    on a TPU these rows of 576 would gather), the KDA layers' prefill scan by
+    the plain loop and by its kernel (interpreted too)."""
     cfg, params = tiny
     c = ref_config(cfg)
     bs, W, B, K = 16, 8, 4, 3
@@ -404,7 +422,7 @@ def test_paged_prefill_and_decode_are_the_reference_forward(tiny, interpret):
     toks = rng.integers(0, cfg.vocab_size, size=(2, max(lens) + K)).astype(np.int32)
     want, inner = ref.forward(params, jnp.asarray(toks), c, inner=True)
     want_picks = inner["picks"]
-    prefill = jax.jit(functools.partial(paged.paged_prefill, cfg=cfg, block_size=bs))
+    prefill = jax.jit(functools.partial(paged.paged_prefill, cfg=cfg, block_size=bs, interpret=interpret))
     decode = jax.jit(functools.partial(paged.paged_decode, cfg=cfg, block_size=bs, interpret=interpret))
     pool = paged.init_block_pool(cfg, 20, bs, B)
     # whatever was in the slots before must not matter

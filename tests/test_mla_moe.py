@@ -361,7 +361,9 @@ def test_pool_is_latent_rows_and_nothing_else(tiny):
     assert (cfg.latent_dim, cfg.pool_row_dim, mm.MlaMoeConfig().pool_row_dim) == (40, 128, 640)
     assert set(pool) == {"ckv"} and pool["ckv"].shape == (cfg.n_layer, 9, 16, 128)
     assert paged.cache(cfg) == paged.Cache(slot_state=False, per_head=False, hooks=False)
-    assert paged.cache(kl.KimiLinearConfig.tiny()) == paged.Cache(slot_state=True, per_head=False, hooks=False)
+    assert paged.cache(kl.KimiLinearConfig.tiny()) == paged.Cache(
+        slot_state=True, delta_rule=True, per_head=False, hooks=False
+    )
     with pytest.raises(ValueError, match="kv_hooks"):
         paged.paged_verify(None, jnp.zeros((1, 2), jnp.int32), None, None, pool, cfg, block_size=16)
 

@@ -141,7 +141,9 @@ def test_paged_prefill_and_decode_are_the_reference_forward(tiny, interpret, chu
     that carry the state and the tail, then three decode steps with a free
     and a not-live slot beside them: logits against the reference's full
     forward, the rows of keys and values where they lie in the pool, and each
-    slot's state and tail after the prompt and after the last step."""
+    slot's state and tail after the prompt and after the last step. The arm
+    is the decode step's kernels' and the KDA layers' prefill scan's alike:
+    plain, or the kernel in the Pallas interpreter."""
     cfg, params = tiny
     c = ref_config(cfg)
     bs, W, B, K = 16, 8, 4, 3
@@ -152,7 +154,7 @@ def test_paged_prefill_and_decode_are_the_reference_forward(tiny, interpret, chu
         ref.forward(params, jnp.asarray(toks[i, : n + K]), c, inner=True, state_at=(n, n + K))
         for i, n in enumerate(lens)
     ]
-    prefill = jax.jit(functools.partial(paged.paged_prefill, cfg=cfg, block_size=bs))
+    prefill = jax.jit(functools.partial(paged.paged_prefill, cfg=cfg, block_size=bs, interpret=interpret))
     decode = jax.jit(functools.partial(paged.paged_decode, cfg=cfg, block_size=bs, interpret=interpret))
     pool = paged.init_block_pool(cfg, 20, bs, B)
     # whatever was in the slots before must not matter: a second request starts from zero

@@ -129,3 +129,34 @@ def test_every_working_operation_carries_one_stage_of_the_vocabulary(
 def test_a_stage_outside_the_vocabulary_is_refused():
     with pytest.raises(ValueError, match="no stage of the vocabulary"):
         common.stage("granite_mlp")
+
+
+def test_the_delta_scan_kernel_is_opened_inside_the_state_scan():
+    """Kimi Linear's prefill at heads of 128 x 128, lowered for a TPU from
+    here: the KDA layers' scans are the call ``kda_scan``, one traced body
+    that each layer calls, and the call's location carries ``st.state_scan``,
+    so a device trace's time by stage reads it there."""
+    from ray_tpu.models import kimi_linear as kl
+
+    cfg = kl.KimiLinearConfig.tiny(n_layer=2, kda_head_dim=128, max_seq=2048)
+    bs, width, slots = 16, 128, 2
+    shapes = jax.eval_shape(
+        lambda key: (kl.init_params(key, cfg), paged.init_block_pool(cfg, width * slots + 1, bs, slots)),
+        jax.random.PRNGKey(0),
+    )
+    table = jnp.arange(1, width + 1, dtype=jnp.int32)
+
+    def run(params, pool, tokens):
+        return paged.paged_prefill(
+            params, tokens, jnp.int32(1500), jnp.int32(0), table, pool, cfg, block_size=bs, slot=jnp.int32(1)
+        )
+
+    traced = jax.jit(run).trace(*shapes, jax.ShapeDtypeStruct((1, 2048), jnp.int32))
+    text = traced.lower(lowering_platforms=("tpu",)).compiler_ir(dialect="hlo").as_hlo_module().to_string()
+    kernels = [line for line in text.splitlines() if "custom-call(" in line and "tpu_custom_call" in line]
+    assert len(kernels) == 1 and OP_NAME.search(kernels[0]).group(1) == "kda_scan/pallas_call"
+    # The compiler puts the body where it is called, under the caller's name.
+    sites = [line for line in text.splitlines() if " call(" in line and "jit(_scan)" in line]
+    assert len(sites) == len(cfg.kda_layers) == 2
+    for line in sites:
+        assert STAGE.findall(OP_NAME.search(line).group(1)) == ["state_scan"], line

@@ -362,7 +362,8 @@ def test_solar_open2s_served_programs_compile_for_one_chip(v5e_2x2, program):
     """``solar_open2``'s two programs at the benchmark's shapes (one period of
     the published widths, 40 of 320 experts held, 32 slots of 18,432
     positions), lowered for the chip: the 2,048-token chunk that continues a
-    prompt (three chunked delta-rule scans at 64 heads, the GQA layer's
+    prompt (three delta-rule scans at 64 heads, each one call of the scan's
+    kernel over operands that lie as the projection left them, the GQA layer's
     attention one call of the prefill kernel over the table) and the decode step (the
     attention kernel over the live blocks, once: one GQA layer; the state read
     and written by slot), the pool donated and aliased, and weights, cache
@@ -399,17 +400,17 @@ def test_solar_open2s_served_programs_compile_for_one_chip(v5e_2x2, program):
         # stretch's scores are a value of the program.
         assert calls.count("paged_prefill_attention") == cfg.layers_of(so.GQA) == 1
         assert f"f32[{cfg.n_kv_head},{cfg.n_head // cfg.n_kv_head},512,512]" not in text
-        # The scans' pair terms go by sub-blocks (ops/delta_rule.py): the
-        # compiler re-forms no decay for every pair of a chunk's positions,
-        # for one chunk or with the call's 32 in front.
+        # Each KDA layer's scan is the kernel (ops/delta_scan.py), once: the
+        # state and a chunk's values stay on the chip, so the program holds
+        # no operand re-laid head-major [n, H, C, d], no decay for every pair
+        # of a chunk's positions, and no solver.
         H, C, d = cfg.kda_heads, delta_rule.CHUNK, cfg.kda_head_dim
         n = 2048 // C
+        assert calls.count("kda_scan") == cfg.layers_of(so.KDA) == 3
         shapes = {tuple(map(int, s.split(","))) for s in re.findall(r"f32\[([\d,]+)\]", text)}
-        assert (H, d) == (64, 128) and (H, C, d) in shapes  # the pattern can match
-        # (The chunked inputs, [n, H, C, d], end the same way here: H == C.)
-        assert (n, H, C, d) in shapes and n * H * C * d < H * C * C * d
+        assert (H, d) == (64, 128) and (2048, H * d) in shapes  # the pattern can match
+        assert (n, H, C, d) not in shapes
         assert not [s for s in shapes if s[-3:] == (C, C, d) and math.prod(s) >= H * C * C * d]
-        # The chunk's system is inverted by products: no solver is called.
         assert not re.search("triangular-solve|TriangularSolve|InvertDiagBlocks", text)
     else:
         compiled = jax.jit(
@@ -758,6 +759,66 @@ def test_the_state_step_kernel_compiles_at_served_shapes(v5e_2x2, cell):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= math.prod(state.shape) * 4
     assert mem.temp_size_in_bytes < 2**20
+
+
+@pytest.mark.parametrize("tokens, heads", [(2048, 64), (2048, 32), (4096, 32)])
+def test_the_delta_scan_kernel_compiles_at_served_shapes(v5e_2x2, tokens, heads):
+    """``ops.delta_scan`` at the two KDA cells' heads (64 and 32 of 128 x 128)
+    over a chunk of 2,048 tokens, the smallest program that takes it, and twice that, lowered
+    for the chip: its blocks are whole tiles, what it keeps in VMEM (the
+    blocks twice over, the state, the scratch) is under the limit it asks
+    for, the call keeps its name (a device trace lists it as
+    ``kda_scan.<n>``), and the operands go in as they lie, ``[T, H d]``: the
+    program beside the call holds nothing re-laid head-major."""
+    from ray_tpu.ops import delta_rule, delta_scan
+
+    d = 128
+    assert delta_scan.tiles(tokens, heads, d, d) and not delta_scan.tiles(1024, heads, d, d)
+    one = jax.sharding.SingleDeviceSharding(v5e_2x2[0])
+    sds = lambda s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one)  # noqa: E731
+    flat = sds((tokens, heads * d))
+    compiled = jax.jit(functools.partial(delta_scan._scan, interpret=False)).lower(
+        flat, flat, flat, flat, sds((tokens, heads)), sds((heads, d, d))
+    ).compile()
+    text = compiled.as_text()
+    assert mosaic_calls(text) == ["kda_scan"]
+    G, C = delta_scan.head_group(heads), delta_rule.CHUNK
+    held = 4 * G * (2 * 5 * C * d + 2 * 2 * d * d + d * d + 6 * C * d)
+    assert G == 4 and held < delta_scan._VMEM_LIMIT_BYTES // 8
+    assert f"f32[{tokens // C},{heads},{C},{d}]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+
+@pytest.mark.parametrize("bucket", [2048, 1024, 512])
+def test_kimi_linears_prefill_program_scans_through_the_kernel(v5e_2x2, bucket):
+    """Kimi Linear's prefill at the benchmark's shapes, the three buckets its
+    prompts fall in, lowered for the chip: the scan's kernel once a KDA layer
+    in the largest, and the plain loop alone in the two below it, which would
+    not pay for the kernel's lowering at every start."""
+    from benchmarks import harness
+    from ray_tpu.models import paged
+
+    found = harness.cell("serve-batch-kimilinear")
+    c, mix = harness.config_of(found), harness.traffic_of(found)
+    e = mix["engine"]
+    assert bucket in e["prefill_buckets"]
+    cfg = harness.family(c).model_config(c, mix)
+    bs, N, B = e["kv_block_size"], e["num_kv_blocks"], e["max_slots"]
+    one = jax.sharding.SingleDeviceSharding(v5e_2x2[0])
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)  # noqa: E731
+    on_chip = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
+    mod = paged.family(cfg)
+    params = on_chip(jax.eval_shape(lambda k: mod.init_params(k, cfg), jax.random.key(0)))
+    pool = on_chip(jax.eval_shape(lambda: paged.init_block_pool(cfg, N, bs, B)))
+    i32 = jnp.int32
+    compiled = jax.jit(
+        functools.partial(paged.paged_prefill, cfg=cfg, block_size=bs), donate_argnums=5
+    ).lower(
+        params, sds((1, bucket), i32), sds((), i32), sds((), i32), sds((e["max_seq"] // bs,), i32), pool,
+        slot=sds((), i32),
+    ).compile()
+    scans = mosaic_calls(compiled.as_text()).count("kda_scan")
+    assert len(cfg.kda_layers) == 7 and scans == (7 if bucket >= 2048 else 0)
 
 
 @pytest.mark.parametrize(

@@ -1,12 +1,21 @@
-"""The delta rule's chunked prefill alone, on the chip, by the rows of a block
-or against another copy of the module.
+"""The delta rule's chunked prefill alone, on the chip: its two arms side by
+side, the plain arm by the rows of a block or against another copy of the
+module.
 
-    python tools/delta_rule_chip.py [--blocks 8,16,32,64] [--heads 64,32] [--tokens 2048]
+    python tools/delta_rule_chip.py [--heads 64,32] [--tokens 2048,512] [--groups 4,8,16]
+    python tools/delta_rule_chip.py --blocks 8,16,32,64
     python tools/delta_rule_chip.py --against _parent/ray_tpu/ops/delta_rule.py
 
-``ops.delta_rule.kda_chunked`` at the shapes of the two cells that run it
+The scan at the shapes of the two cells that run it
 (`serve-longdoc-solaropen2`: 64 heads a layer; `serve-batch-kimilinear`: 32;
-keys and values 128 wide, a bucket of 2,048 tokens = 32 chunks), traced anew
+keys and values 128 wide, a chunk of 2,048 tokens and Kimi Linear's smallest
+served bucket, 512), a line an arm: ``plain``, ``ops.delta_rule.kda_chunked``
+(the ``lax.scan``), and ``kernel``, ``ops.delta_scan.kda_scan`` (one Pallas
+call, the state and a chunk's values held on the chip) at its own heads a grid
+step or at each of ``--groups``, with ``kernel_ms`` the call alone beside
+``device_ms``, which also holds the fusions that lay the tool's ``[T, H, d]``
+operands flat (in a model's program the projection's fusion leaves them so),
+and ``first_call_s`` the seconds to trace, lower and compile it. The plain arm is traced anew
 under each setting of :data:`ops.delta_rule.BLOCK`. A block of
 :data:`CHUNK` rows forms every pair term elementwise over ``[H, C, C, d_k]``,
 as before PR 44, beside a product with no column left to take (10.6 ms at 64
@@ -26,6 +35,7 @@ of standard output is one JSON list.
 from __future__ import annotations
 
 import argparse
+import functools
 import importlib.util
 import json
 import os
@@ -40,7 +50,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from benchmarks import trace_reduce  # noqa: E402
-from ray_tpu.ops import delta_rule  # noqa: E402
+from ray_tpu.ops import delta_rule, delta_scan  # noqa: E402
 
 CALLS = 8
 WIDTH = 128
@@ -102,7 +112,11 @@ def main() -> int:
         help="another delta_rule.py to time beside this checkout's; may be given again",
     )
     ap.add_argument("--heads", default="64,32", help="comma-separated heads a layer")
-    ap.add_argument("--tokens", type=int, default=2048)
+    ap.add_argument("--tokens", default="2048,512", help="comma-separated tokens a call")
+    ap.add_argument(
+        "--groups", default=None,
+        help="comma-separated heads a grid step for the kernel arm (default: the module's own)",
+    )
     ap.add_argument("--ops", type=int, default=12, help="operations printed a line, longest first")
     args = ap.parse_args()
     if jax.default_backend() != "tpu":
@@ -115,25 +129,39 @@ def main() -> int:
     else:
         blocks = lambda module: [8, 16, 32, delta_rule.CHUNK]  # noqa: E731
     out = []
-    for H in map(int, args.heads.split(",")):
-        operands = inputs(jax.random.key(H), args.tokens, H)
+    groups = list(map(int, args.groups.split(","))) if args.groups else [None]
+    for H, T in ((H, T) for H in map(int, args.heads.split(",")) for T in map(int, args.tokens.split(","))):
+        operands = inputs(jax.random.key(H), T, H)
         first = None
-        for (name, module), block in ((m, b) for m in modules for b in blocks(m[1])):
-            module.BLOCK = block
-            jax.clear_caches()  # the scan keeps its body's trace by the function
-            run = jax.jit(module.kda_chunked)
-            o, S = run(*operands)
+
+        def line(run, **named):
+            nonlocal first
+            t = time.perf_counter()
+            o, S = jax.block_until_ready(run(*operands))
+            first_call_s = time.perf_counter() - t
             first = first or (o, S)
             host_ms, device_ms, ops = time_calls(run, operands, args.ops)
             row = {
-                "module": name, "heads": H, "tokens": args.tokens, "block": block,
+                **named, "heads": H, "tokens": T, "first_call_s": round(first_call_s, 2),
                 "host_ms": round(host_ms, 3), "device_ms": round(device_ms, 3),
+                "kernel_ms": sum(ms for name, ms in ops if name.startswith("kda_scan")) or None,
                 "o_diff": float(jnp.max(jnp.abs(o - first[0]))),
                 "S_diff": float(jnp.max(jnp.abs(S - first[1]))),
                 "ops_ms": ops,
             }
             print(json.dumps(row), flush=True)
             out.append(row)
+
+        for (name, module), block in ((m, b) for m in modules for b in blocks(m[1])):
+            module.BLOCK = block
+            jax.clear_caches()  # the scan keeps its body's trace by the function
+            line(jax.jit(module.kda_chunked), arm="plain", module=name, block=block)
+        for group in (g for g in groups if g is None or H % g == 0):
+            jax.clear_caches()
+            line(
+                jax.jit(functools.partial(delta_scan.kda_scan, group=group)),
+                arm="kernel", module="this checkout", group=group or delta_scan.head_group(H),
+            )
     print(json.dumps(out))
     return 0
 
