@@ -20,12 +20,12 @@ import contextlib
 import jax
 import jax.numpy as jnp
 
-# The stages of a device program, one vocabulary for every family's prefill
-# and decode programs and for the train step: what a reader of a device trace
-# (benchmarks/stage_time.py) files an operation's time under. PERF.md section
-# 3 says which functions open which.
+# The stages of a device program, one vocabulary for every family's prefill and
+# decode programs and for the train step: what a reader of a device trace
+# (benchmarks/stage_time.py) files an operation's time under (PERF.md section 3).
 STAGES = (
     "attn_proj",  # q/k/v/gate projections, head norms, rotation, MLA's low-rank pairs and absorbs, W_o
+    "attn_select",  # an indexer in front of attention: its projections, rotation, scores and top-k
     "attn_core",  # scores and the weighted sum over the table: kernel, fold or gather, the sink
     "pool_write",  # rows or blocks of keys, values or latent rows into the pool
     "state_in",  # a recurrent mixer up to its scan: in-projection, convolution, forming q,k,v,g,beta / B,C,dt

@@ -1,7 +1,7 @@
 """Latent attention in every layer and a routed mixture of experts (the
-DeepSeek-V3 family's block, ``model_type: deepseek_v3`` / ``axk1``): fourth
-model family of the serving tier, and the first whose cache is latent rows in
-blocks *and nothing else*.
+DeepSeek-V3 block, ``model_type: deepseek_v3`` / ``axk1``; ``deepseek_v32``, it
+behind an indexer, lives in :mod:`ray_tpu.models.deepseek_v32`): fourth family
+of the serving tier, the first whose cache is latent rows *and nothing else*.
 
 Block, layers numbered from 1: ``x += MLA(RMSNorm(x)); x += FFN(RMSNorm(x))``;
 the first ``first_k_dense`` layers' FFN is a dense SwiGLU, the others' a routed

@@ -5,12 +5,11 @@ python/ray/llm/_internal/serve/engines/vllm/vllm_models.py:89), redesigned
 for XLA's static shapes instead of CUDA paged-attention kernels:
 
 - **Block tables.** A request owns ``[W]`` int32 physical block ids, ``W =
-  max_seq // block``, so HBM goes by blocks actually used and not by
-  ``max_seq`` a slot. Block 0 is scratch: padded and garbage writes land there
-  and are never read. W, block and the prefill bucket are compile-time
-  constants, positions and tables traced operands: two compiled programs
-  (prefill per bucket, decode). A pooled prefix is a list of block ids, shared
-  by host-side refcount with no device copy.
+  max_seq // block``: HBM goes by blocks used, not by ``max_seq`` a slot.
+  Block 0 is scratch: padded and garbage writes land there and are never read.
+  W, block and the prefill bucket are compile-time constants, positions and
+  tables traced operands: two compiled programs (prefill per bucket, decode).
+  A pooled prefix is a list of block ids, shared by host-side refcount.
 - **Scatter, then attend.** New rows are scattered into their (layer, block,
   offset) homes before anything reads them. *Prefill and verify* gather the
   request's blocks back into a dense row (a transient) and run the training
@@ -19,12 +18,10 @@ for XLA's static shapes instead of CUDA paged-attention kernels:
   slot's table over its ``ceil((position + 1) / block)`` live blocks
   (``ops/paged_attention.py``); elsewhere decode gathers too, and that gather
   is what the tests hold the kernel to. Identical math (bf16 operands, float32
-  scores and softmax, the mask ``col <= position``): logit parity with the
-  training forward position by position.
-- **The pool is written in place.** The layer scan carries the whole pool and
-  scans over the layer index. A caller that donates the pool (the engine, the
-  speculative decoder) gets its buffer back as the output and must rebind it;
-  one that does not (``benchmarks/check.py``) pays one copy of it at entry.
+  scores and softmax, ``col <= position``): logit parity position by position.
+- **The pool is written in place.** The layer scan carries the whole pool. A
+  caller that donates it (the engine, the speculative decoder) gets its buffer
+  back as the output and must rebind it; one that does not pays one copy.
 
 **What a pool is made of.** Four parts; which of them a family has is its
 record's business (:class:`Cache`, :func:`cache`), and the engine reads that
@@ -46,7 +43,8 @@ and nothing else about a family's cache.
   position for all heads, under the same tables and ``BlockManager``. A cache
   like keys and values (stale rows masked by position, prefixes shared,
   prompts in chunks); decode attends in place where the rows are whole lane
-  tiles (:func:`latent_decode_attention`).
+  tiles (:func:`latent_decode_attention`). A family with an indexer keeps
+  its index keys beside them, ``"ikv"``, under the same table.
 - *A state and a tail per slot*, ``"state": [L', slots + 1, ...]`` float32 and
   ``"conv": [L', slots + 1, K - 1, C]``, which no block table reaches: slot
   ``b``'s are row ``b``, row ``slots`` is scratch. A stale state is not masked,
@@ -68,11 +66,10 @@ such a family is served without the prefix cache.
 
 Family dispatch is by the configuration's ``family`` name (:func:`family`).
 GPT-2 (learned-position MHA) and Llama (RoPE GQA) share everything here and
-each supplies a small hook table (``kv_hooks``), because GQA with group=1 *is*
-MHA; speculative verification, the disaggregated handoff and tensor
-parallelism reach these two only (:meth:`Cache.why_not`). Every other family
-brings ``init_pool``, ``paged_prefill``, ``paged_decode``, ``span_fields`` and
-its record, ``cache(cfg)``, in its module.
+each supplies a small hook table (``kv_hooks``): GQA with group=1 *is* MHA;
+speculative verification, the handoff and tensor parallelism reach these two
+only (:meth:`Cache.why_not`). Every other family brings ``init_pool``, its
+programs, ``span_fields`` and its record, ``cache(cfg)``, in its module.
 """
 
 from __future__ import annotations
@@ -102,6 +99,7 @@ _FAMILIES = {
     "solar_open2": "ray_tpu.models.solar_open2",
     "mimo_v2": "ray_tpu.models.mimo_v2",
     "granitemoehybrid": "ray_tpu.models.granite_hybrid",
+    "deepseek_v32": "ray_tpu.models.deepseek_v32",
 }
 
 
@@ -149,6 +147,8 @@ class Cache:
     # Prefill attends those layers through :func:`prefill_attention`, the
     # pool read where it lies (a family without gathers its table whole).
     prefill_in_place: bool = False
+    # Decode attends rows an indexer chose one by one: no arm walks a table.
+    selects_rows: bool = False
 
     @property
     def shares_prefixes(self) -> bool:
@@ -372,11 +372,11 @@ def decode_attends_in_place(cfg, block_size: int, *, mesh=None) -> bool:
     same choice, a kind of layer at a time: in place only if every kind's
     shapes fit) or latent rows, whose width the configuration gives
     (``pool_row_dim``: its programs call :func:`latent_decode_attention`).
-    Decided by what the code can see, like
-    ``ops.attention.uses_flash_kernel``; nothing a user sets reaches it."""
-    if jax.default_backend() != "tpu":
-        return False
+    Decided by what the code can see; nothing a user sets reaches it. False
+    for a family whose decode reads rows chosen one by one (``selects_rows``)."""
     record = cache(cfg)
+    if jax.default_backend() != "tpu" or record.selects_rows:
+        return False
     if not record.per_head:
         return _latent_kernel_fits(cfg, block_size, mesh)
     return all(_kernel_fits(kind, block_size, mesh) for kind in record.kinds or (attention_kind(cfg),))
@@ -892,7 +892,7 @@ def paged_decode(
     if not cache(cfg).hooks:
         return mod.paged_decode(
             params, last_tokens, positions, tables, pool, cfg,
-            block_size=block_size, live=live, interpret=interpret,
+            block_size=block_size, live=live, **({"interpret": True} if interpret else {}),
         )
     B = last_tokens.shape[0]
     W = tables.shape[1]

@@ -528,6 +528,10 @@ def _tiny_model_of(family):
         from ray_tpu.models.granite_hybrid import GraniteHybridConfig
 
         return GraniteHybridConfig.tiny(max_seq=128)
+    if family == "deepseek_v32":
+        from ray_tpu.models.deepseek_v32 import DeepseekV32Config
+
+        return DeepseekV32Config.tiny(max_seq=128)
     return _family_model(family)[0]
 
 
@@ -579,7 +583,7 @@ def test_a_family_is_looked_up_once_and_its_record_is_what_its_pool_holds(name):
         rows = part["kv" if packed else "k"] if record.per_head else part["ckv"]
         # [layers, blocks, KH, block, Dh], or [layers, blocks, block, row width]: no head axis
         assert rows.ndim == (5 if record.per_head else 4) and rows.shape[-2] == 16
-    assert record.shares_prefixes == (name in ("gpt2", "llama", "mla_moe"))
+    assert record.shares_prefixes == (name in ("gpt2", "llama", "mla_moe", "deepseek_v32"))
     assert (record.why_not(name, "x") is None) == record.hooks
 
 

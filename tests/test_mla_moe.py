@@ -271,14 +271,24 @@ def test_the_expert_layer_gives_what_it_gave_before_it_learnt_a_latent(monkeypat
     golden.assert_as_before("mla_moe", latent_moe.moe_ffn(h, p, share, valid))
 
 
+@pytest.mark.parametrize("family", ["mla_moe", "deepseek_v32"])
 @pytest.mark.parametrize("skew", [False, True])
-def test_the_four_shares_add_up_to_the_uncut_layer(tiny, skew):
+def test_the_four_shares_add_up_to_the_uncut_layer(tiny, skew, family):
     """Four chips hold two of the eight experts each (a chip's share is one
     group here; the router still ranks all four groups). Their routed parts,
     with the shared expert (which every chip computes alike) counted once,
-    are what the uncut reference layer gives."""
+    are what the uncut reference layer gives. ``deepseek_v32``: the same
+    layer drawn by that family, with a selection bias (``noaux_tc``: it moves
+    the choice and not the weights) that every share carries whole."""
     cfg, params = tiny
+    if family == "deepseek_v32":
+        from ray_tpu.models import deepseek_v32
+
+        cfg = deepseek_v32.DeepseekV32Config.tiny()
+        params = deepseek_v32.init_params(jax.random.key(0), cfg)
     p = params["layers"][2]
+    if "router_bias" in p:
+        p = {**p, "router_bias": 0.3 * jax.random.normal(jax.random.key(9), p["router_bias"].shape)}
     if skew:  # every token's best expert is 6: the chip that holds it does most of the work
         p = {**p, "router": p["router"].at[:, 6].set(p["router"][:, 6] * 0 + 0.5)}
     h = jax.random.normal(jax.random.key(6), (40, cfg.d_model))

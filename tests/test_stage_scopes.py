@@ -33,6 +33,7 @@ FAMILIES = {
     "solar_open2": ("solar_open2", "SolarOpen2Config", ATTENTION | STATE | EXPERTS),
     "mimo_v2": ("mimo_v2", "MimoV2Config", ATTENTION | EXPERTS | {"mlp"}),
     "granitemoehybrid": ("granite_hybrid", "GraniteHybridConfig", ATTENTION | STATE | {"mlp"}),
+    "deepseek_v32": ("deepseek_v32", "DeepseekV32Config", ATTENTION | EXPERTS | {"mlp", "attn_select"}),
 }
 TRAIN_STAGES = {"attn_proj", "attn_core", "mlp", "embed_head", "optimizer"}
 # The operations that do a program's work: each must say which stage it is.

@@ -1045,3 +1045,55 @@ def test_an_accepted_cells_programs_lower_as_before_scales_and_packed_heads(v5e_
     writes = [line for line in text.splitlines() if by_block in line]
     assert len(writes) == 2 * attention_layers and all("unique_indices = false" in w for w in writes)
     assert "inserted_window_dims = [0, 1, 2, 3]" not in text  # a row of a head an update
+
+
+def test_the_selected_attention_kernel_compiles_at_the_served_shapes(v5e_2x2):
+    """``ops.selected_attention.fold_step`` at DeepSeek-V3.2-Exp's chunk: 128
+    heads of 192 (no whole lane tile: the block is as wide as the array), 2,048
+    queries, a stretch of 1,024 keys, values of 128; the carry aliased in and
+    out, so the call holds no copy of it."""
+    from ray_tpu.ops import selected_attention as sa
+
+    H, T, S = 128, 2048, 1024
+    assert sa.fits(H, T, S, 128, jnp.bfloat16)
+    one = jax.sharding.SingleDeviceSharding(v5e_2x2[0])
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)  # noqa: E731
+    carry = tuple(sds(a.shape, a.dtype) for a in jax.eval_shape(lambda: sa.carry(H, T, 128)))
+    compiled = jax.jit(functools.partial(sa.fold_step, scale=0.1), donate_argnums=4).lower(
+        sds((H, T, 192), jnp.bfloat16), sds((H, S, 192), jnp.bfloat16), sds((H, S, 128), jnp.bfloat16),
+        sds((T, S), jnp.bool_), carry,
+    ).compile()
+    assert mosaic_calls(compiled.as_text()) == [sa.NAME]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= H * T * 128 * 4  # the weighted values go back where they came from
+    assert mem.temp_size_in_bytes < 256 * 2**20  # the mask as a bias and the operands re-laid: no scores (1 GB)
+
+
+def test_deepseek_v32s_chunk_program_attends_through_the_kernel_and_its_decode_gathers(v5e_2x2):
+    """The family's 2,048-token prefill and its decode step at the published
+    widths, two layers deep, lowered for the chip with the pool donated: a
+    layer's attention is one ``selected_attention_fold`` call inside the
+    stretches' loop, decode has no attention kernel (rows chosen one by one),
+    and both write the two pool parts in place."""
+    from ray_tpu.models import deepseek_v32 as dv, paged
+
+    cfg = dv.DeepseekV32Config(vocab_size=16160, n_layer=2, first_k_dense=1, experts_held=8, max_seq=34816)
+    B, bs, N = 16, 16, 34817
+    one = jax.sharding.SingleDeviceSharding(v5e_2x2[0])
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)  # noqa: E731
+    on_chip = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)  # noqa: E731
+    params = on_chip(jax.eval_shape(lambda k: dv.draw_params(k, cfg), jax.random.key(0)))
+    pool = on_chip(jax.eval_shape(lambda: dv.init_pool(cfg, N, bs)))
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(pool))
+    W = cfg.max_seq // bs
+    prefill = jax.jit(functools.partial(paged.paged_prefill, cfg=cfg, block_size=bs), donate_argnums=5).lower(
+        params, sds((1, 2048), jnp.int32), sds((), jnp.int32), sds((), jnp.int32), sds((W,), jnp.int32), pool,
+    ).compile()
+    calls = mosaic_calls(prefill.as_text())
+    assert calls.count("selected_attention_fold") == cfg.n_layer
+    assert prefill.memory_analysis().alias_size_in_bytes >= pool_bytes
+    decode = jax.jit(functools.partial(paged.paged_decode, cfg=cfg, block_size=bs), donate_argnums=4).lower(
+        params, sds((B,), jnp.int32), sds((B,), jnp.int32), sds((B, W), jnp.int32), pool, live=sds((B,), jnp.bool_),
+    ).compile()
+    assert not [c for c in mosaic_calls(decode.as_text()) if "attention" in c]
+    assert decode.memory_analysis().alias_size_in_bytes >= pool_bytes
