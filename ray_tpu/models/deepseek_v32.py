@@ -35,7 +35,9 @@ queries score the index keys of the table a stretch at a time, the positions a
 query keeps are found as a *threshold* (the ``index_topk``-th largest score of
 the row, by counting passes over the scores' bits: no sort), and attention
 is ``latent_moe.mla_prefill``'s fold over expanded keys and values under one
-more mask: on a TPU a stretch a Pallas call that keeps the scores on the chip
+more mask: on a TPU one Pallas call a layer a run of queries, which walks the
+table's live stretches itself, expands a head's keys and values from the
+latent rows on the chip and keeps scores and running softmax there
 (:mod:`ray_tpu.ops.selected_attention`), XLA's einsums elsewhere. **Decode**
 (:func:`select_decode`): a slot's query scores its table's index keys,
 ``lax.top_k`` names the rows, and the absorbed attention runs over those rows
@@ -64,8 +66,9 @@ _F32 = jnp.float32
 
 # Positions of the table that one step of the index scores takes ([T, J, 512]
 # float32 is 268 MB at a chunk of 2,048 and 64 index heads) and that one step
-# of the plain fold expands per head; and that one call of the attention's
-# kernel folds (the carry goes through HBM between calls: fewer, longer calls).
+# of the plain fold expands per head; and that the attention's kernel copies
+# to the chip and folds at a time (its rows, its mask and a head's expanded
+# keys and values are VMEM: PERF.md section 6, PR 57, has the sizes tried).
 KEY_POSITIONS = 512
 KERNEL_KEY_POSITIONS = 1024
 # Queries whose scores against the whole table are held at once ([2048, 34816]
@@ -305,16 +308,21 @@ def attend_selected(q, ckv, l: int, table, pos, n_keys, keep, p, cfg, *, block_s
     <= position``), up to the last position that holds a row. Two arms,
     chosen as :func:`ray_tpu.models.paged._choose` says: where the program is
     lowered for a TPU and the shapes are the kernel's
-    (:func:`ray_tpu.ops.selected_attention.fits`) a stretch of
-    ``KERNEL_KEY_POSITIONS`` is one Pallas call that keeps the scores on the
-    chip; :func:`_fold_selected`'s XLA einsums over stretches of
-    ``KEY_POSITIONS`` elsewhere. ``interpret`` runs the kernel in the Pallas
-    interpreter whatever the platform and the shapes (the tests)."""
+    (:func:`ray_tpu.ops.selected_attention.fits`) the whole fold is one
+    Pallas call, which is handed the queries, ``wkvb``, the pool, the table
+    and the mask as they lie and walks the live stretches of
+    ``KERNEL_KEY_POSITIONS`` itself; :func:`_fold_selected`'s XLA einsums
+    over stretches of ``KEY_POSITIONS`` elsewhere. ``interpret`` runs the
+    kernel in the Pallas interpreter whatever the platform and the shapes
+    (the tests)."""
     T = q.shape[0]
     H, dv, dt = cfg.n_head, cfg.v_head_dim, cfg.dtype
     blocks = lambda positions: math.gcd(table.shape[0], max(1, positions // block_size))  # noqa: E731
     static = dict(cfg=cfg, block_size=block_size)
-    fits = selected_attention.fits(H, T, blocks(KERNEL_KEY_POSITIONS) * block_size, dv, dt)
+    fits = selected_attention.fits(
+        H, T, blocks(KERNEL_KEY_POSITIONS) * block_size, dv, dt,
+        nope=cfg.qk_nope_head_dim, rank=cfg.kv_lora_rank, block_size=block_size,
+    )
     with stage("attn_core"):
         operands = (q, ckv, jnp.asarray(l, jnp.int32), table, pos, jnp.asarray(n_keys, jnp.int32), keep,
                     p["wkvb"].astype(dt))
@@ -345,19 +353,12 @@ def _steps(pos, n_keys, Kb: int):
 
 
 def _kernel_selected(q, ckv, l, table, pos, n_keys, keep, wkvb, *, cfg, nb, block_size, interpret=False):
-    """[T, H, d_v]: every stretch one :func:`selected_attention.fold_step`
-    over all the queries, the carry in HBM between them."""
-    T, H, dv = q.shape[0], cfg.n_head, cfg.v_head_dim
-    Kb = nb * block_size
-    qh = q.transpose(1, 0, 2)
-
-    def step(j, carried):
-        k, v = _stretch(ckv, l, table, j, wkvb, cfg, nb, block_size)
-        kept = jax.lax.dynamic_slice_in_dim(keep, j * Kb, Kb, axis=1)
-        return selected_attention.fold_step(qh, k, v, kept, carried, scale=cfg.softmax_scale, interpret=interpret)
-
-    carried = jax.lax.fori_loop(0, _steps(pos, n_keys, Kb), step, selected_attention.carry(H, T, dv))
-    return selected_attention.result(carried).astype(cfg.dtype).transpose(1, 0, 2)
+    """[T, H, d_v]: :func:`ray_tpu.ops.selected_attention.attend`, one call
+    that walks the live stretches of ``nb`` blocks itself."""
+    return selected_attention.attend(
+        q, wkvb, ckv, l, table, keep, _steps(pos, n_keys, nb * block_size),
+        scale=cfg.softmax_scale, nope=cfg.qk_nope_head_dim, pages=nb, interpret=interpret,
+    )
 
 
 def _fold_selected(q, ckv, l, table, pos, n_keys, keep, wkvb, *, cfg, nb, block_size):

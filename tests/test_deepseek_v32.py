@@ -304,20 +304,71 @@ def test_the_kernel_arm_of_a_chunks_attention_is_the_fold(tiny):
 
 
 def test_one_stretch_through_the_kernel_is_one_step_of_a_running_softmax():
-    """``selected_attention.fold_step`` alone, in the interpreter: two
-    stretches folded one after the other are the softmax over both under the
-    mask, rows that keep nothing of the first stretch included."""
+    """``selected_attention.attend`` alone, in the interpreter: two stretches
+    walked by one call are the softmax over both under the mask, rows that
+    keep nothing of the first stretch included; a third stretch of the table,
+    not live, is not read (its blocks hold NaN)."""
     from ray_tpu.ops import selected_attention as sa
 
-    H, T, S, Dk, Dv = 4, 16, 32, 24, 16
-    keys = jax.random.split(jax.random.key(0), 4)
-    q, k, v = (jax.random.normal(a, s) for a, s in zip(keys, [(H, T, Dk), (H, 2 * S, Dk), (H, 2 * S, Dv)]))
-    keep = jax.random.bernoulli(keys[3], 0.3, (T, 2 * S)).at[:, -1].set(True).at[:4, :S].set(False)
-    carry = sa.carry(H, T, Dv)
-    for j in range(2):
-        cols = slice(j * S, (j + 1) * S)
-        carry = sa.fold_step(q, k[:, cols], v[:, cols], keep[:, cols], carry, scale=0.3, interpret=True)
-    s = jnp.where(keep[None], jnp.einsum("htd,hsd->hts", q, k) * 0.3, -jnp.inf)
-    want = jnp.einsum("hts,hsd->htd", jax.nn.softmax(s, axis=-1), v)
-    np.testing.assert_allclose(sa.result(carry), want, rtol=2e-4, atol=2e-6)
+    H, T, S, dn, dr, Dv, R, bs = 4, 16, 32, 16, 8, 16, 32, 16
+    keys = jax.random.split(jax.random.key(0), 5)
+    q = jax.random.normal(keys[0], (T, H, dn + dr))
+    wkvb = jax.random.normal(keys[1], (R, H * (dn + Dv))) * 0.2
+    pool = jax.random.normal(keys[2], (2, 7, bs, 128)).at[:, 5:].set(jnp.nan)
+    table = jnp.asarray([3, 1, 4, 2, 5, 6], jnp.int32)
+    keep = jax.random.bernoulli(keys[3], 0.3, (T, 3 * S)).at[:, 2 * S - 1].set(True).at[:4, :S].set(False)
+    got = sa.attend(
+        q, wkvb, pool, jnp.asarray(1), table, keep, jnp.asarray(2), scale=0.3, nope=dn, pages=S // bs, interpret=True
+    )
+    rows = pool[1, table[:4]].reshape(2 * S, -1)
+    kv = (rows[:, :R] @ wkvb).reshape(2 * S, H, dn + Dv)
+    k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(rows[:, None, R : R + dr], (2 * S, H, dr))], axis=-1)
+    s = jnp.where(keep[None, :, : 2 * S], jnp.einsum("thd,shd->hts", q, k) * 0.3, -jnp.inf)
+    want = jnp.einsum("hts,shd->thd", jax.nn.softmax(s, axis=-1), kv[..., dn:])
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-6)
     assert sa.fits(128, 2048, 512, 128, jnp.bfloat16) and not sa.fits(2, 64, 512, 16, jnp.float32)
+
+
+@pytest.mark.parametrize(
+    "start, length, case",
+    [
+        (0, 32, "selected"),  # one live stretch of the table's four
+        (32, 32, "selected"),  # a start in the middle: two live
+        (96, 32, "selected"),  # the table's last stretch
+        (64, 20, "selected"),  # a ragged last chunk: rows behind the length are padding
+        (64, 32, "empty"),  # rows whose selection leaves a whole stretch empty, the first among them
+        (64, 32, "causal"),  # index_topk >= the context: the mask is the causal one
+    ],
+    ids=["start-0", "middle", "last-stretch", "ragged", "empty-stretch", "causal"],
+)
+def test_the_kernel_walks_the_live_stretches_as_the_fold_does(tiny, start, length, case):
+    """``_kernel_selected`` (one call that walks the stretches itself, in the
+    interpreter) against ``_fold_selected`` (XLA's loop) on the same
+    operands: 32 queries over a scattered table of four stretches of 32
+    positions; blocks behind the live stretches hold NaN, which neither reads."""
+    cfg, params = tiny
+    bs, T, nb = 16, 32, 2
+    keys = jax.random.split(jax.random.key(start + length), 4)
+    table = jnp.asarray([5, 2, 7, 3, 1, 4, 6, 8], jnp.int32)
+    S = table.shape[0] * bs
+    pos = start + jnp.arange(T, dtype=jnp.int32)
+    n_keys = jnp.asarray(start + length, jnp.int32)
+    live = (min(start + length - 1, start + T - 1) // (nb * bs) + 1) * nb  # blocks
+    ckv = jax.random.normal(keys[0], (cfg.n_layer, 9, bs, cfg.pool_row_dim)).at[:, table[live:]].set(jnp.nan)
+    q = jax.random.normal(keys[1], (T, cfg.n_head, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim))
+    seen = jnp.arange(S)[None, :] <= pos[:, None]
+    if case == "causal":
+        keep = seen
+    else:
+        scores = jax.random.normal(keys[2], (T, S))
+        if case == "empty":  # nothing of the first stretch for four rows, nothing of the second for four more
+            scores = scores.at[:4, :32].set(-10.0).at[4:8, 32:64].set(-10.0)
+        keep = dv.kept_mask(jnp.where(seen, scores, -jnp.inf), seen, cfg.index_topk)
+        if case == "empty":
+            assert not keep[:4, :32].any() and not keep[4:8, 32:64].any() and keep[:8].any(axis=1).all()
+    args = (q, ckv, jnp.asarray(1), table, pos, n_keys, keep, params["layers"][1]["wkvb"])
+    static = dict(cfg=cfg, block_size=bs)
+    want = dv._fold_selected(*args, nb=1, **static)
+    got = dv._kernel_selected(*args, nb=nb, interpret=True, **static)
+    assert np.isfinite(np.asarray(want[:length])).all()
+    np.testing.assert_allclose(got[:length], want[:length], rtol=2e-4, atol=2e-6)

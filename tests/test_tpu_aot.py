@@ -1048,33 +1048,37 @@ def test_an_accepted_cells_programs_lower_as_before_scales_and_packed_heads(v5e_
 
 
 def test_the_selected_attention_kernel_compiles_at_the_served_shapes(v5e_2x2):
-    """``ops.selected_attention.fold_step`` at DeepSeek-V3.2-Exp's chunk: 128
-    heads of 192 (no whole lane tile: the block is as wide as the array), 2,048
-    queries, a stretch of 1,024 keys, values of 128; the carry aliased in and
-    out, so the call holds no copy of it."""
+    """``ops.selected_attention.attend`` at DeepSeek-V3.2-Exp's chunk: 2,048
+    queries of 128 heads of 192 (no whole lane tile: a head's columns are cut
+    from the cell's block on the chip), ``wkvb`` as the model holds it, the
+    pool and the mask left in HBM, stretches of 1,024 positions. One Mosaic
+    call, and beside it the mask at a byte an entry and the queries re-laid:
+    no running softmax in HBM (268 MB), no expanded keys, no scores."""
+    from ray_tpu.models import deepseek_v32 as dv
     from ray_tpu.ops import selected_attention as sa
 
-    H, T, S = 128, 2048, 1024
-    assert sa.fits(H, T, S, 128, jnp.bfloat16)
+    H, T, bs, W = 128, 2048, 16, 34816 // 16
+    pages = dv.KERNEL_KEY_POSITIONS // bs
+    assert sa.fits(H, T, pages * bs, 128, jnp.bfloat16)
     one = jax.sharding.SingleDeviceSharding(v5e_2x2[0])
     sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one)  # noqa: E731
-    carry = tuple(sds(a.shape, a.dtype) for a in jax.eval_shape(lambda: sa.carry(H, T, 128)))
-    compiled = jax.jit(functools.partial(sa.fold_step, scale=0.1), donate_argnums=4).lower(
-        sds((H, T, 192), jnp.bfloat16), sds((H, S, 192), jnp.bfloat16), sds((H, S, 128), jnp.bfloat16),
-        sds((T, S), jnp.bool_), carry,
+    compiled = jax.jit(functools.partial(sa.attend, scale=0.1, nope=128, pages=pages)).lower(
+        sds((T, H, 192), jnp.bfloat16), sds((512, H * 256), jnp.bfloat16), sds((6, W + 1, bs, 640), jnp.bfloat16),
+        sds((), jnp.int32), sds((W,), jnp.int32), sds((T, W * bs), jnp.bool_), sds((), jnp.int32),
     ).compile()
     assert mosaic_calls(compiled.as_text()) == [sa.NAME]
-    mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= H * T * 128 * 4  # the weighted values go back where they came from
-    assert mem.temp_size_in_bytes < 256 * 2**20  # the mask as a bias and the operands re-laid: no scores (1 GB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2**20
 
 
 def test_deepseek_v32s_chunk_program_attends_through_the_kernel_and_its_decode_gathers(v5e_2x2):
     """The family's 2,048-token prefill and its decode step at the published
     widths, two layers deep, lowered for the chip with the pool donated: a
-    layer's attention is one ``selected_attention_fold`` call inside the
-    stretches' loop, decode has no attention kernel (rows chosen one by one),
-    and both write the two pool parts in place."""
+    layer's attention is one ``selected_attention_fold`` call that walks the
+    stretches itself (no XLA loop round it: no running softmax ``[128, 2048,
+    128]`` float32 and no expanded keys ``[128, 1024, 192]`` among the
+    program's buffers, and fewer temporaries than the program of PR 56, which
+    had both), decode has no attention kernel (rows chosen one by one), and
+    both write the two pool parts in place."""
     from ray_tpu.models import deepseek_v32 as dv, paged
 
     cfg = dv.DeepseekV32Config(vocab_size=16160, n_layer=2, first_k_dense=1, experts_held=8, max_seq=34816)
@@ -1089,9 +1093,13 @@ def test_deepseek_v32s_chunk_program_attends_through_the_kernel_and_its_decode_g
     prefill = jax.jit(functools.partial(paged.paged_prefill, cfg=cfg, block_size=bs), donate_argnums=5).lower(
         params, sds((1, 2048), jnp.int32), sds((), jnp.int32), sds((), jnp.int32), sds((W,), jnp.int32), pool,
     ).compile()
-    calls = mosaic_calls(prefill.as_text())
+    text = prefill.as_text()
+    calls = mosaic_calls(text)
     assert calls.count("selected_attention_fold") == cfg.n_layer
-    assert prefill.memory_analysis().alias_size_in_bytes >= pool_bytes
+    assert "f32[128,2048,128]" not in text and "bf16[128,1024,192]" not in text
+    mem = prefill.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 1_295_683_584  # the parent's, a loop of calls a layer
     decode = jax.jit(functools.partial(paged.paged_decode, cfg=cfg, block_size=bs), donate_argnums=4).lower(
         params, sds((B,), jnp.int32), sds((B,), jnp.int32), sds((B, W), jnp.int32), pool, live=sds((B,), jnp.bool_),
     ).compile()
